@@ -1,0 +1,1040 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that distllm-tpu still starts on the chip.
+
+One process, one TPU v5e chip, no child that touches JAX. Every weight and
+every text is made from ``--seed``; nothing is downloaded. Phases, each
+printed as one JSON line (``{"phase": ..., "ok": ...}``):
+
+- ``device``  — ``jax.devices()`` must be a TPU whose kind is in the peaks
+  table; prints the versions and the compile-cache directory in use;
+- ``kernels`` — every Pallas kernel of the main path, compiled (never
+  interpreted) at the real widths, against its XLA twin on the device;
+- ``embed``   — ``distllm_tpu.distributed_embedding`` driven the way its
+  ``main`` drives it, PubMedBERT widths and depth, against the same model
+  on the XLA attention path in float32;
+- ``serve``   — the OpenAI-compatible server from ``chat_server.build_app``
+  on a local port, engine made by ``TpuGenerator`` with the settings of
+  ``examples/chat/chat_server.rag.yaml`` at Mistral-7B-Instruct-v0.3
+  widths, then asserts on the engine: Pallas attention, no ``*_fallback``
+  telemetry, native scheduler and allocator, a prefix-cache hit.
+
+``--chips 4`` runs ONLY the across-chips phases and what they are compared
+with: ``tp4`` (``tensor_parallel_size: 4`` against the one-chip engine) and
+``index4`` (``TpuIndexV2`` with ``mesh: {data: -1}`` against the unsharded
+index).
+
+Any phase that fails makes the exit code non-zero and suppresses the last
+line, which on success is exactly
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}``.
+Without a TPU the script prints "no TPU" and exits 2.
+"""
+
+from __future__ import annotations
+
+import argparse
+import asyncio
+import dataclasses
+import gc
+import json
+import shutil
+import sys
+import time
+import traceback
+from pathlib import Path
+
+REPO = Path(__file__).resolve().parent
+# Checkpoints, corpora and outputs of one run (14.5 GB at 7B widths): on the
+# checkout's own disk, never a RAM-backed temp dir. Listed in .gitignore,
+# removed when the run ends.
+WORK = REPO / '.chip_smoke_work'
+
+# Mistral-7B-Instruct-v0.3 (config.json of the published checkpoint).
+MISTRAL_7B = {
+    'model_type': 'mistral',
+    'vocab_size': 32768,
+    'hidden_size': 4096,
+    'num_hidden_layers': 32,
+    'num_attention_heads': 32,
+    'num_key_value_heads': 8,
+    'head_dim': 128,
+    'intermediate_size': 14336,
+    'max_position_embeddings': 32768,
+    'rope_theta': 1000000.0,
+    'rms_norm_eps': 1e-05,
+    'sliding_window': None,
+    'tie_word_embeddings': False,
+    'torch_dtype': 'bfloat16',
+}
+# PubMedBERT (microsoft/BiomedNLP-BiomedBERT-base-uncased-abstract).
+PUBMEDBERT = {
+    'model_type': 'bert',
+    'vocab_size': 30522,
+    'hidden_size': 768,
+    'num_hidden_layers': 12,
+    'num_attention_heads': 12,
+    'intermediate_size': 3072,
+    'max_position_embeddings': 512,
+    'type_vocab_size': 2,
+    'layer_norm_eps': 1e-12,
+    'hidden_act': 'gelu',
+}
+
+
+@dataclasses.dataclass
+class Sizes:
+    """Everything a tiny CPU rehearsal shrinks; the defaults are the run."""
+
+    mistral: dict = dataclasses.field(default_factory=lambda: dict(MISTRAL_7B))
+    bert: dict = dataclasses.field(default_factory=lambda: dict(PUBMEDBERT))
+    embed_chunks: int = 3072
+    embed_batch: int = 64
+    embed_words: tuple[int, int] = (120, 260)
+    max_tokens: int = 128  # the example's 1024 would be most of the budget
+    max_model_len: int | None = None  # None = the generator's default
+    kernel_batch: int = 32
+    kernel_blocks: int = 1024
+    kernel_ctx: int = 512
+    encoder_shapes: tuple = ((64, 256), (64, 160))
+    index_rows: int = 1_000_000
+    index_queries: int = 32
+    tp_steps: int = 8
+    interpret: bool = False  # CPU rehearsal only: Pallas interpreter
+
+
+class SmokeFailure(AssertionError):
+    pass
+
+
+def check(cond: bool, message: str) -> None:
+    if not cond:
+        raise SmokeFailure(message)
+
+
+def emit(obj: dict) -> None:
+    print(json.dumps(obj), flush=True)
+
+
+_START = time.perf_counter()
+
+
+def note(message: str) -> None:
+    """Progress on stderr, so a phase that dies or runs long says where."""
+    print(f'[chip_smoke +{time.perf_counter() - _START:.0f}s] {message}',
+          file=sys.stderr, flush=True)
+
+
+def run_phase(name: str, fn, *args) -> bool:
+    note(f'phase {name} starts')
+    start = time.perf_counter()
+    try:
+        fields = fn(*args) or {}
+        ok = True
+    except Exception as exc:
+        traceback.print_exc(file=sys.stderr)
+        fields = {'error': f'{type(exc).__name__}: {exc}'[:2000]}
+        ok = False
+    emit({
+        'phase': name, 'ok': ok,
+        'seconds': round(time.perf_counter() - start, 1), **fields,
+    })
+    return ok
+
+
+# ------------------------------------------------------------------ device
+
+
+def phase_device(cache_dir: str) -> dict:
+    import jax
+    import jaxlib
+
+    from distllm_tpu.observability.roofline import device_peaks
+    from distllm_tpu.utils import compile_cache_entries
+
+    device = jax.devices()[0]
+    peak_flops, peak_bw = device_peaks(device)  # raises on an unknown kind
+    return {
+        'platform': device.platform,
+        'device_kind': device.device_kind,
+        'count': len(jax.devices()),
+        'jax': jax.__version__,
+        'jaxlib': jaxlib.__version__,
+        'libtpu': device.client.platform_version.replace('\n', ' ')[:200],
+        'peak_flops': peak_flops,
+        'peak_hbm_bytes_per_s': peak_bw,
+        'compile_cache_dir': cache_dir,
+        'compile_cache_entries': compile_cache_entries(),
+    }
+
+
+# ----------------------------------------------------------------- kernels
+
+# Stated bf16 tolerance of a kernel against its XLA twin: both accumulate
+# in fp32 and round the output once to bf16 (8 bits of mantissa, 2^-8 =
+# 0.0039 relative), and the probabilities are rounded to bf16 before the
+# PV product on one side or the other.
+KERNEL_ATOL = 2e-2
+KERNEL_RTOL = 2e-2
+
+
+def _max_err(out, ref, valid=None) -> tuple[float, bool]:
+    import numpy as np
+
+    out = np.asarray(out, np.float32)
+    ref = np.asarray(ref, np.float32)
+    if valid is not None:
+        out, ref = out[valid], ref[valid]
+    finite = bool(np.isfinite(out).all())
+    err = np.abs(out - ref)
+    within = bool((err <= KERNEL_ATOL + KERNEL_RTOL * np.abs(ref)).all())
+    return float(err.max()), finite and within
+
+
+def phase_kernels(sizes: Sizes, seed: int) -> dict:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distllm_tpu.ops.encoder_attention import (
+        encoder_attention,
+        encoder_attention_reference,
+    )
+    from distllm_tpu.ops.paged_attention import (
+        QuantizedKV,
+        ragged_paged_attention_pallas,
+        ragged_paged_attention_xla,
+    )
+
+    m = sizes.mistral
+    nh, nkv, hd = (
+        m['num_attention_heads'], m['num_key_value_heads'], m['head_dim']
+    )
+    b, nb = sizes.kernel_batch, sizes.kernel_blocks
+    rng = np.random.default_rng(seed)
+    cases = {}
+    for kv_name, block_size in (('bf16', 16), ('int8', 32)):
+        max_blocks = sizes.kernel_ctx // block_size
+        shape = (nb, block_size, nkv, hd)
+        if kv_name == 'bf16':
+            k_cache = jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+            v_cache = jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+        else:
+            k_cache, v_cache = (
+                QuantizedKV(
+                    jnp.asarray(rng.integers(-127, 128, size=shape), jnp.int8),
+                    jnp.asarray(
+                        rng.uniform(0.005, 0.02, size=(nb, nkv)), jnp.float32
+                    ),
+                )
+                for _ in range(2)
+            )
+        # Block 0 is the engine's trash block; tables point at scattered
+        # real blocks like the allocator produces.
+        tables = jnp.asarray(
+            rng.integers(1, nb, size=(b, max_blocks)), jnp.int32
+        )
+        for span in (1, 16):
+            ctx = jnp.asarray(
+                rng.integers(span, sizes.kernel_ctx + 1, size=(b,)), jnp.int32
+            )
+            # Ragged rows: every other row carries fewer live queries.
+            q_lens = jnp.asarray(
+                np.where(np.arange(b) % 2, span, max(1, span // 2)), jnp.int32
+            )
+            pos = (ctx - q_lens)[:, None] + jnp.arange(span)[None, :]
+            q = jnp.asarray(rng.normal(size=(b, span, nh, hd)), jnp.bfloat16)
+            kernel = jax.jit(
+                lambda q, k, v, bt, c, p, ql: ragged_paged_attention_pallas(
+                    q, k, v, bt, c, p, q_lens=ql, interpret=sizes.interpret
+                )
+            )
+            twin = jax.jit(
+                lambda q, k, v, bt, c, p, ql: ragged_paged_attention_xla(
+                    q, k, v, bt, c, p, q_lens=ql
+                )
+            )
+            operands = (q, k_cache, v_cache, tables, ctx, pos, q_lens)
+            if not sizes.interpret:
+                check(
+                    'tpu_custom_call' in kernel.lower(*operands).compile()
+                    .as_text(),
+                    f'ragged {kv_name} span {span}: no tpu_custom_call',
+                )
+            valid = np.arange(span)[None, :] < np.asarray(q_lens)[:, None]
+            err, ok = _max_err(kernel(*operands), twin(*operands), valid)
+            cases[f'ragged_{kv_name}_block{block_size}_span{span}'] = err
+            check(ok, f'ragged {kv_name} span {span}: max abs err {err}')
+
+    hidden, heads = sizes.bert['hidden_size'], sizes.bert['num_attention_heads']
+    for eb, es in sizes.encoder_shapes:
+        q, k, v = (
+            jnp.asarray(rng.normal(size=(eb, es, hidden)), jnp.bfloat16)
+            for _ in range(3)
+        )
+        lens = rng.integers(es // 2, es + 1, size=(eb,))
+        mask = jnp.asarray(np.arange(es)[None, :] < lens[:, None], jnp.int32)
+        kernel = jax.jit(
+            lambda q, k, v, m: encoder_attention(
+                q, k, v, m, num_heads=heads, interpret=sizes.interpret
+            )
+        )
+        twin = jax.jit(
+            lambda q, k, v, m: encoder_attention_reference(
+                q, k, v, m, num_heads=heads
+            )
+        )
+        if not sizes.interpret:
+            check(
+                'tpu_custom_call' in kernel.lower(q, k, v, mask).compile()
+                .as_text(),
+                f'encoder S={es}: no tpu_custom_call',
+            )
+        valid = np.asarray(mask, bool)  # pad QUERY rows are discarded too
+        err, ok = _max_err(kernel(q, k, v, mask), twin(q, k, v, mask), valid)
+        cases[f'encoder_b{eb}_s{es}_d{hidden}'] = err
+        check(ok, f'encoder attention S={es}: max abs err {err}')
+    return {
+        'atol': KERNEL_ATOL, 'rtol': KERNEL_RTOL, 'max_abs_err': cases,
+        'compiled': not sizes.interpret,
+    }
+
+
+# ------------------------------------------------------- seed-made inputs
+
+
+def write_tokenizer(model_dir: Path, vocab_size: int, max_length: int) -> None:
+    """WordLevel ``tokenizer.json`` whose vocabulary covers every id the
+    model can emit, so any sampled token decodes to a word."""
+    from tokenizers import Tokenizer
+    from tokenizers.models import WordLevel
+    from tokenizers.pre_tokenizers import Whitespace
+
+    vocab = {'[UNK]': 0, '[PAD]': 1}
+    vocab.update({f'w{i}': i for i in range(2, vocab_size)})
+    tok = Tokenizer(WordLevel(vocab, unk_token='[UNK]'))
+    tok.pre_tokenizer = Whitespace()
+    tok.save(str(model_dir / 'tokenizer.json'))
+    (model_dir / 'tokenizer_config.json').write_text(json.dumps({
+        'tokenizer_class': 'PreTrainedTokenizerFast',
+        'pad_token': '[PAD]', 'unk_token': '[UNK]',
+        'model_max_length': max_length,
+    }))
+
+
+def make_words(rng, count: int, vocab_size: int) -> str:
+    return ' '.join(f'w{i}' for i in rng.integers(2, vocab_size, size=count))
+
+
+def _normal_bf16(key, shape, scale=0.02):
+    import jax
+    import jax.numpy as jnp
+
+    return (jax.random.normal(key, shape, jnp.float32) * scale).astype(
+        jnp.bfloat16
+    )
+
+
+def write_bert_checkpoint(model_dir: Path, hf: dict, seed: int) -> None:
+    import jax
+    import ml_dtypes
+    import numpy as np
+
+    from distllm_tpu.models.loader import save_checkpoint
+
+    model_dir.mkdir(parents=True, exist_ok=True)
+    (model_dir / 'config.json').write_text(json.dumps(hf))
+    h, inter = hf['hidden_size'], hf['intermediate_size']
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 1024))
+    bf16 = ml_dtypes.bfloat16
+
+    def normal(*shape):
+        return np.asarray(_normal_bf16(next(keys), shape))
+
+    def ln(prefix):
+        return {
+            f'{prefix}.weight': np.ones((h,), bf16),
+            f'{prefix}.bias': np.zeros((h,), bf16),
+        }
+
+    def lin(prefix, out_dim, in_dim):
+        return {
+            f'{prefix}.weight': normal(out_dim, in_dim),
+            f'{prefix}.bias': normal(out_dim),
+        }
+
+    state = {
+        'embeddings.word_embeddings.weight': normal(hf['vocab_size'], h),
+        'embeddings.position_embeddings.weight': normal(
+            hf['max_position_embeddings'], h
+        ),
+        'embeddings.token_type_embeddings.weight': normal(
+            hf['type_vocab_size'], h
+        ),
+        **ln('embeddings.LayerNorm'),
+    }
+    for i in range(hf['num_hidden_layers']):
+        p = f'encoder.layer.{i}'
+        state.update(lin(f'{p}.attention.self.query', h, h))
+        state.update(lin(f'{p}.attention.self.key', h, h))
+        state.update(lin(f'{p}.attention.self.value', h, h))
+        state.update(lin(f'{p}.attention.output.dense', h, h))
+        state.update(ln(f'{p}.attention.output.LayerNorm'))
+        state.update(lin(f'{p}.intermediate.dense', inter, h))
+        state.update(lin(f'{p}.output.dense', h, inter))
+        state.update(ln(f'{p}.output.LayerNorm'))
+    save_checkpoint(state, model_dir)
+    write_tokenizer(
+        model_dir, hf['vocab_size'], hf['max_position_embeddings']
+    )
+
+
+def write_mistral_checkpoint(model_dir: Path, hf: dict, seed: int) -> float:
+    """HF-named bf16 safetensors, one shard per layer, made on the device
+    from the seed (14.5 GB at 32 layers). Returns the GB written."""
+    import jax
+    import ml_dtypes
+    import numpy as np
+    from safetensors.numpy import save_file
+
+    model_dir.mkdir(parents=True, exist_ok=True)
+    (model_dir / 'config.json').write_text(json.dumps(hf))
+    h, inter = hf['hidden_size'], hf['intermediate_size']
+    q_out = hf['num_attention_heads'] * hf['head_dim']
+    kv_out = hf['num_key_value_heads'] * hf['head_dim']
+    shapes = {
+        'self_attn.q_proj': (q_out, h), 'self_attn.k_proj': (kv_out, h),
+        'self_attn.v_proj': (kv_out, h), 'self_attn.o_proj': (h, q_out),
+        'mlp.gate_proj': (inter, h), 'mlp.up_proj': (inter, h),
+        'mlp.down_proj': (h, inter),
+    }
+    ones = np.ones((h,), ml_dtypes.bfloat16)
+
+    @jax.jit
+    def layer_weights(key):
+        keys = jax.random.split(key, len(shapes))
+        return {
+            name: _normal_bf16(k, shape)
+            for k, (name, shape) in zip(keys, shapes.items())
+        }
+
+    root = jax.random.PRNGKey(seed)
+    written = 0
+    for i in range(hf['num_hidden_layers']):
+        state = {
+            f'model.layers.{i}.{name}.weight': np.asarray(w)
+            for name, w in layer_weights(jax.random.fold_in(root, i)).items()
+        }
+        state[f'model.layers.{i}.input_layernorm.weight'] = ones
+        state[f'model.layers.{i}.post_attention_layernorm.weight'] = ones
+        save_file(state, str(model_dir / f'model-layer{i:03d}.safetensors'))
+        written += sum(a.nbytes for a in state.values())
+    k_embed, k_head = jax.random.split(jax.random.fold_in(root, 1 << 20))
+    state = {
+        'model.embed_tokens.weight': np.asarray(
+            _normal_bf16(k_embed, (hf['vocab_size'], h))
+        ),
+        'model.norm.weight': ones,
+        'lm_head.weight': np.asarray(
+            _normal_bf16(k_head, (hf['vocab_size'], h))
+        ),
+    }
+    save_file(state, str(model_dir / 'model-embed.safetensors'))
+    written += sum(a.nbytes for a in state.values())
+    write_tokenizer(model_dir, hf['vocab_size'], 1 << 20)
+    return written / 1e9
+
+
+# ------------------------------------------------------------------- embed
+
+
+def phase_embed(sizes: Sizes, seed: int) -> dict:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distllm_tpu import distributed_embedding
+    from distllm_tpu.embed import get_encoder, get_pooler
+    from distllm_tpu.models import bert
+    from distllm_tpu.ops.encoder_attention import resolve_use_pallas
+    from distllm_tpu.registry import registry
+
+    work = WORK / 'embed'
+    model_dir = work / 'pubmedbert'
+    write_bert_checkpoint(model_dir, sizes.bert, seed)
+    rng = np.random.default_rng(seed)
+    (work / 'inputs').mkdir(parents=True)
+    lo, hi = sizes.embed_words
+    with open(work / 'inputs' / 'corpus.jsonl', 'w') as fh:
+        for i in range(sizes.embed_chunks):
+            words = make_words(
+                rng, int(rng.integers(lo, hi + 1)), sizes.bert['vocab_size']
+            )
+            fh.write(json.dumps({'text': words, 'path': f'doc{i}'}) + '\n')
+    encoder_config = {
+        'name': 'auto',
+        'pretrained_model_name_or_path': str(model_dir),
+        'half_precision': True,
+    }
+    config = distributed_embedding.Config(
+        input_dir=work / 'inputs',
+        output_dir=work / 'out',
+        glob_patterns=['*.jsonl'],
+        dataset_config={'name': 'jsonl', 'batch_size': sizes.embed_batch},
+        encoder_config=encoder_config,
+        pooler_config={'name': 'mean'},
+        embedder_config={'name': 'full_sequence', 'normalize_embeddings': True},
+        writer_config={'name': 'numpy'},
+        compute_config={'name': 'local'},
+    )
+    start = time.perf_counter()
+    check(distributed_embedding.run_embedding(config) == 0, 'driver failed')
+    embed_s = time.perf_counter() - start
+
+    shards = sorted((work / 'out' / 'embeddings').iterdir())
+    check(len(shards) == 1, f'expected one output shard, found {len(shards)}')
+    emb = np.load(shards[0] / 'embeddings.npy')
+    texts = list(np.load(shards[0] / 'text.npy', allow_pickle=True))
+    hidden = sizes.bert['hidden_size']
+    check(
+        emb.shape == (sizes.embed_chunks, hidden),
+        f'shard shape {emb.shape}, expected {(sizes.embed_chunks, hidden)}',
+    )
+    check(bool(np.isfinite(emb).all()), 'non-finite embeddings in the shard')
+
+    # The same model on the XLA attention path in float32, a handful of
+    # rows. The encoder is the driver's own (the warm-start registry hands
+    # back the instance the worker built), so the weights are the ones
+    # that produced the shard.
+    encoder = get_encoder(encoder_config, register=True)
+    pooler = get_pooler({'name': 'mean'})
+    rows = [int(i) for i in rng.integers(0, sizes.embed_chunks, size=8)]
+    batch = encoder.tokenizer([texts[i] for i in rows])
+    seq_len = int(batch.input_ids.shape[1])
+    model_cfg = encoder.model_cfg
+    attn_path = (
+        'pallas'
+        if resolve_use_pallas(
+            'auto', seq_len, hidden, model_cfg.num_heads, model_cfg.dtype
+        )
+        else 'xla'
+    )
+    cfg32 = model_cfg.model_copy(update={'dtype': 'float32'})
+    params32 = jax.tree.map(lambda x: jnp.asarray(x, jnp.float32),
+                            encoder.params)
+    hidden32 = jax.jit(
+        lambda p, ids, mask: bert.apply(p, cfg32, ids, mask, attn_impl='xla')
+    )(params32, batch.input_ids, batch.attention_mask)
+    ref = np.asarray(pooler.pool(hidden32, batch.attention_mask), np.float32)
+    ref = ref / np.linalg.norm(ref, axis=1, keepdims=True)
+    cosines = [float(ref[j] @ emb[i]) for j, i in enumerate(rows)]
+    check(min(cosines) >= 0.99, f'cosine vs fp32 XLA reference: {cosines}')
+    check(
+        sizes.interpret or attn_path == 'pallas',
+        f'encoder attention ran on {attn_path!r} at S={seq_len}',
+    )
+    registry().clear()  # frees the encoder's HBM before the serve phase
+    return {
+        'shard': str(shards[0].relative_to(work)),
+        'shape': list(emb.shape),
+        'finite': True,
+        'min_cosine_vs_fp32_xla': min(cosines),
+        'rows_compared': len(rows),
+        'encoder_attention': attn_path,
+        'embed_seconds': round(embed_s, 1),
+    }
+
+
+# ------------------------------------------------------------------- serve
+
+
+def generator_settings(model_dir: Path, sizes: Sizes) -> dict:
+    """``generator_config`` of the documented start
+    (examples/chat/chat_server.rag.yaml), pointed at the seed-made
+    checkpoint, with the prefix cache on."""
+    import yaml
+
+    example = yaml.safe_load(
+        (REPO / 'examples' / 'chat' / 'chat_server.rag.yaml').read_text()
+    )
+    settings = dict(example['generator_config'])
+    settings['pretrained_model_name_or_path'] = str(model_dir)
+    settings['max_tokens'] = sizes.max_tokens
+    settings['enable_prefix_cache'] = True
+    if sizes.max_model_len is not None:
+        settings['max_model_len'] = sizes.max_model_len
+    return settings
+
+
+def _memory_stats() -> dict:
+    """The device's own byte counts, where the backend reports them."""
+    import jax
+
+    stats = jax.devices()[0].memory_stats() or {}
+    return {
+        key: stats.get(key)
+        for key in ('bytes_in_use', 'peak_bytes_in_use', 'bytes_limit')
+    }
+
+
+async def _drive_server(app, prompts: dict[str, str]) -> dict:
+    """Start the app on a free local port, send the requests one after the
+    other (the shared-prefix pair must not race), stop the server."""
+    import aiohttp
+    from aiohttp import web
+
+    runner = web.AppRunner(app)
+    await runner.setup()
+    site = web.TCPSite(runner, '127.0.0.1', 0)
+    await site.start()
+    port = site._server.sockets[0].getsockname()[1]
+    base = f'http://127.0.0.1:{port}'
+    out: dict = {'requests': {}}
+    timeout = aiohttp.ClientTimeout(total=900)
+    try:
+        async with aiohttp.ClientSession(timeout=timeout) as session:
+            async with session.get(f'{base}/health') as resp:
+                out['health'] = resp.status
+            for name, prompt in prompts.items():
+                start = time.perf_counter()
+                async with session.post(
+                    f'{base}/v1/chat/completions',
+                    json={'messages': [{'role': 'user', 'content': prompt}]},
+                ) as resp:
+                    body = await resp.json()
+                    content = (
+                        body['choices'][0]['message']['content']
+                        if resp.status == 200 else ''
+                    )
+                    note(f'serve: request {name} answered {resp.status}')
+                    out['requests'][name] = {
+                        'status': resp.status,
+                        'prompt_words': len(prompt.split()),
+                        'content_words': len(content.split()),
+                        'seconds': round(time.perf_counter() - start, 2),
+                    }
+            async with session.get(f'{base}/metrics') as resp:
+                out['metrics_status'] = resp.status
+                out['metrics_text'] = await resp.text()
+    finally:
+        await runner.cleanup()
+    return out
+
+
+def _metric_total(text: str, name: str) -> float:
+    total = 0.0
+    for line in text.splitlines():
+        if line.startswith(name) and not line.startswith('#'):
+            total += float(line.rsplit(' ', 1)[1])
+    return total
+
+
+def phase_serve(sizes: Sizes, seed: int) -> dict:
+    import numpy as np
+
+    from distllm_tpu import chat_server
+    from distllm_tpu.chat import ChatAppConfig
+    from distllm_tpu.generate.engine import kv_cache
+    from distllm_tpu.registry import registry
+    from distllm_tpu.utils import compile_cache_entries
+
+    model_dir = WORK / 'mistral'
+    start = time.perf_counter()
+    written_gb = write_mistral_checkpoint(model_dir, sizes.mistral, seed)
+    write_s = time.perf_counter() - start
+    note(f'serve: wrote {written_gb:.2f} GB of checkpoint in {write_s:.0f}s')
+    settings = generator_settings(model_dir, sizes)
+    entries_before = compile_cache_entries()
+
+    start = time.perf_counter()
+    app = chat_server.build_app(ChatAppConfig(generator_config=settings))
+    load_s = time.perf_counter() - start
+    note(f'serve: server and engine built in {load_s:.0f}s')
+    generator = registry().active['generator']
+    engine = generator.engine
+    memory_after_load = _memory_stats()
+
+    rng = np.random.default_rng(seed + 1)
+    vocab = sizes.mistral['vocab_size']
+    max_len = engine.config.max_model_len
+    prefix = make_words(rng, max_len // 8, vocab)
+    prompts = {
+        'shared_prefix_a': f'{prefix} {make_words(rng, 24, vocab)}',
+        'shared_prefix_b': f'{prefix} {make_words(rng, 40, vocab)}',
+        'short': make_words(rng, 12, vocab),
+        # The template adds a few tokens; the engine keeps the last
+        # max_model_len - 1 of a longer prompt.
+        'near_max_model_len': make_words(
+            rng, max_len - sizes.max_tokens - 64, vocab
+        ),
+    }
+    prompts['shared_prefix_a_again'] = prompts['shared_prefix_a']
+    served = asyncio.run(_drive_server(app, prompts))
+    metrics_text = served.pop('metrics_text')
+    entries_after = compile_cache_entries()
+
+    check(served['health'] == 200, f"/health answered {served['health']}")
+    for name, result in served['requests'].items():
+        check(result['status'] == 200, f'{name}: HTTP {result["status"]}')
+        check(result['content_words'] > 0, f'{name}: empty content')
+    generated = _metric_total(metrics_text, 'distllm_engine_generated_tokens')
+    check(served['metrics_status'] == 200 and generated > 0,
+          '/metrics shows no generated tokens')
+
+    telemetry = dict(engine.telemetry)
+    check(
+        sizes.interpret or telemetry.get('attn_backend') == 'pallas',
+        f"attn_backend resolved to {telemetry.get('attn_backend')!r}",
+    )
+    fallbacks = sorted(k for k in telemetry if k.endswith('_fallback'))
+    check(not fallbacks, f'fallback telemetry: '
+          f'{ {k: telemetry[k] for k in fallbacks} }')
+    scheduler = type(engine.sched._inner).__name__
+    allocator = type(kv_cache.make_allocator(16)).__name__
+    check(scheduler == 'NativeScheduler', f'scheduler is {scheduler}')
+    check(allocator == 'NativeBlockAllocator', f'allocator is {allocator}')
+    hit_blocks = int(engine.prefix_cache.stats['hit_blocks'])
+    check(hit_blocks > 0, 'the prefix cache counted no hit')
+
+    fields = {
+        'layers': sizes.mistral['num_hidden_layers'],
+        'layer_cut': sizes.mistral['num_hidden_layers']
+        != MISTRAL_7B['num_hidden_layers'],
+        'checkpoint_gb': round(written_gb, 2),
+        'checkpoint_write_seconds': round(write_s, 1),
+        'load_seconds': round(load_s, 1),
+        'settings': {
+            k: v for k, v in settings.items()
+            if k != 'pretrained_model_name_or_path'
+        },
+        'max_model_len': max_len,
+        'num_blocks': engine.config.num_blocks,
+        'kv_pool_gib': round(engine.kv.hbm_bytes / 2**30, 3),
+        'memory_after_load': memory_after_load,
+        'memory_after_requests': _memory_stats(),
+        'health': served['health'],
+        'requests': served['requests'],
+        'first_request_seconds': served['requests']['shared_prefix_a'][
+            'seconds'],
+        'repeated_request_seconds': served['requests'][
+            'shared_prefix_a_again']['seconds'],
+        'generated_tokens_metric': generated,
+        'attn_backend': telemetry.get('attn_backend'),
+        'telemetry': telemetry,
+        'scheduler': scheduler,
+        'allocator': allocator,
+        'prefix_cache_hit_blocks': hit_blocks,
+        'compile_cache_entries_before': entries_before,
+        'compile_cache_entries_after': entries_after,
+    }
+    registry().clear()  # shuts the engine down and frees its arrays
+    return fields
+
+
+# ------------------------------------------------------------- four chips
+
+
+def _greedy_engine_tokens(generator, prompt_ids: list[list[int]], steps: int):
+    from distllm_tpu.generate.engine import SamplingParams
+
+    return generator.engine.generate_ids(
+        prompt_ids, SamplingParams(temperature=0.0, max_tokens=steps)
+    )
+
+
+def _prefill_logits(engine, prompt: list[int]):
+    """Last-position prefill logits through the engine's own jitted
+    prefill (the bucket the serving path would use)."""
+    import numpy as np
+
+    from distllm_tpu.models.tokenizer import pick_bucket
+
+    bucket = pick_bucket(len(prompt), engine.prefill_buckets)
+    ids = np.zeros((1, bucket), np.int32)
+    ids[0, : len(prompt)] = prompt
+    mask = (np.arange(bucket)[None, :] < len(prompt)).astype(np.int32)
+    logits, _, _ = engine._prefill(
+        engine.params, engine._put(ids), engine._put(mask),
+        engine._put(np.asarray([len(prompt) - 1], np.int32)),
+    )
+    return np.asarray(logits, np.float32)[0]
+
+
+# TP-4 against one chip, stated before the comparison is made. Both
+# engines score the SAME token prefixes (the one-chip engine's greedy
+# tokens, teacher-forced), so the two logit vectors differ only by bf16
+# reduction order: sharded matmuls sum four partial products, 32 layers
+# deep. A wrong shard gives unrelated logits, a relative RMS difference
+# near sqrt(2); reduction order gives a few hundredths.
+TP_REL_RMS = 0.15
+# Greedy tokens may part where that noise reorders the top two: the
+# one-chip gap between the two choices is then within a few RMS
+# differences (both logits carry the noise).
+TP_TIE_RMS_MULTIPLE = 6.0
+
+
+def _step_logits(engine, prompt: list[int], tokens: list[int]) -> list:
+    """``logits[s]`` follows ``prompt + tokens[:s]`` (teacher-forced)."""
+    return [
+        _prefill_logits(engine, prompt + tokens[:s])
+        for s in range(len(tokens))
+    ]
+
+
+def phase_tp4(sizes: Sizes, seed: int) -> dict:
+    import jax
+    import numpy as np
+
+    from distllm_tpu.generate import get_generator
+
+    model_dir = WORK / 'mistral'
+    written_gb = write_mistral_checkpoint(model_dir, sizes.mistral, seed)
+    note(f'tp4: wrote {written_gb:.2f} GB of checkpoint')
+    rng = np.random.default_rng(seed + 2)
+    vocab = sizes.mistral['vocab_size']
+    prompts = [
+        [int(t) for t in rng.integers(2, vocab, size=n)] for n in (48, 96)
+    ]
+
+    def build(tp: int):
+        settings = generator_settings(model_dir, sizes)
+        settings['tensor_parallel_size'] = tp
+        return get_generator(settings, register=False)
+
+    # One chip first; shut it down and free its arrays before the mesh
+    # engine loads, or device 0 holds both and runs out.
+    start = time.perf_counter()
+    single = build(1)
+    single_load_s = time.perf_counter() - start
+    note(f'tp4: one-chip engine built in {single_load_s:.0f}s')
+    single_tokens = [
+        [int(t) for t in row]
+        for row in _greedy_engine_tokens(single, prompts, sizes.tp_steps)
+    ]
+    single_logits = [
+        _step_logits(single.engine, p, t)
+        for p, t in zip(prompts, single_tokens)
+    ]
+    single_backend = dict(single.engine.telemetry)
+    single.shutdown()
+    del single
+    gc.collect()
+
+    start = time.perf_counter()
+    sharded = build(4)
+    sharded_load_s = time.perf_counter() - start
+    note(f'tp4: TP-4 engine built in {sharded_load_s:.0f}s')
+    engine = sharded.engine
+    # Per-device bytes of the sharded weights, read from the arrays' own
+    # addressable shards: "everything on the first device" cannot pass.
+    per_device: dict[int, int] = {}
+    total = 0
+    for leaf in jax.tree.leaves(engine.params):
+        total += leaf.nbytes
+        for shard in leaf.addressable_shards:
+            per_device[shard.device.id] = (
+                per_device.get(shard.device.id, 0) + shard.data.nbytes
+            )
+    shares = {d: b / total for d, b in sorted(per_device.items())}
+    check(len(shares) == 4, f'weights live on {len(shares)} devices')
+    # Replicated leaves (the embedding table, norm scales) put each share
+    # a little above a quarter.
+    check(max(shares.values()) < 0.35, f'per-device weight share {shares}')
+
+    sharded_tokens = [
+        [int(t) for t in row]
+        for row in _greedy_engine_tokens(sharded, prompts, sizes.tp_steps)
+    ]
+    sharded_logits = [
+        _step_logits(engine, p, t) for p, t in zip(prompts, single_tokens)
+    ]
+    telemetry = dict(engine.telemetry)
+    sharded.shutdown()
+
+    agreement = []
+    for one, four, logits1, logits4 in zip(
+        single_tokens, sharded_tokens, single_logits, sharded_logits
+    ):
+        same = next(
+            (i for i, (a, b) in enumerate(zip(one, four)) if a != b), len(one)
+        )
+        rel_rms = [
+            float(np.sqrt(np.mean((l1 - l4) ** 2)) / l1.std())
+            for l1, l4 in zip(logits1, logits4)
+        ]
+        record = {
+            'tokens_one_chip': one,
+            'tokens_tp4': four,
+            'agree_first_steps': same,
+            'logits_rel_rms_diff_per_step': [round(r, 4) for r in rel_rms],
+        }
+        check(
+            max(rel_rms) < TP_REL_RMS,
+            f'logits differ like a wrong shard: {record}',
+        )
+        check(same >= 1, f'greedy tokens never agree: {record}')
+        if same < len(one):
+            l1, l4 = logits1[same], logits4[same]
+            rms_diff = float(np.sqrt(np.mean((l1 - l4) ** 2)))
+            gap = float(l1[one[same]] - l1[four[same]])
+            record.update(
+                parted_at_step=same,
+                one_chip_gap_between_the_two_tokens=gap,
+                logits_rms_diff_at_that_step=rms_diff,
+            )
+            check(
+                abs(gap) <= TP_TIE_RMS_MULTIPLE * rms_diff,
+                f'tokens part without a near-tie: {record}',
+            )
+        agreement.append(record)
+    return {
+        'layers': sizes.mistral['num_hidden_layers'],
+        'checkpoint_gb': round(written_gb, 2),
+        'one_chip_load_seconds': round(single_load_s, 1),
+        'tp4_load_seconds': round(sharded_load_s, 1),
+        'weight_bytes_total': total,
+        'weight_share_per_device': shares,
+        'one_chip_backends': single_backend,
+        'tp4_backends': telemetry,
+        'rel_rms_limit': TP_REL_RMS,
+        'tie_rms_multiple': TP_TIE_RMS_MULTIPLE,
+        'greedy': agreement,
+    }
+
+
+def phase_index4(sizes: Sizes, seed: int) -> dict:
+    import numpy as np
+    from datasets import Dataset
+
+    from distllm_tpu.rag.search import TpuIndexV2Config
+
+    work = WORK / 'index'
+    dim = sizes.bert['hidden_size']
+    rng = np.random.default_rng(seed + 3)
+    shard_rows = 1 << 16
+    first = None
+    for part, lo in enumerate(range(0, sizes.index_rows, shard_rows)):
+        rows = rng.standard_normal(
+            (min(shard_rows, sizes.index_rows - lo), dim), dtype=np.float32
+        )
+        rows /= np.linalg.norm(rows, axis=1, keepdims=True)
+        if first is None:
+            first = rows[:4096].copy()
+        Dataset.from_dict({'embeddings': rows}).save_to_disk(
+            str(work / 'dataset' / f'{part:05d}')
+        )
+    # Queries are noisy copies of corpus rows, so the corpus has real
+    # nearest neighbours (pure-random vectors have none).
+    src = first[rng.integers(0, len(first), size=sizes.index_queries)]
+    queries = src + (0.5 / np.sqrt(dim)) * rng.standard_normal(
+        src.shape, dtype=np.float32
+    )
+    queries /= np.linalg.norm(queries, axis=1, keepdims=True)
+
+    out = {'rows': sizes.index_rows, 'dim': dim, 'queries': len(queries)}
+    for precision in ('float32', 'int8'):
+        ids = {}
+        for name, mesh in (('unsharded', None), ('sharded', {'data': -1})):
+            start = time.perf_counter()
+            index = TpuIndexV2Config(
+                dataset_dir=work / 'dataset',
+                index_dir=work / 'index_files',
+                precision=precision,
+                mesh=mesh,
+            ).get_index()
+            results = index.search(queries, top_k=10, score_threshold=-1.0)
+            ids[name] = results.total_indices
+            out[f'{precision}_{name}_seconds'] = round(
+                time.perf_counter() - start, 1
+            )
+            if mesh is not None:
+                arrays = (
+                    index._int8 if precision == 'int8' else (index._corpus,)
+                )
+                devices = {
+                    s.device.id for a in arrays for s in a.addressable_shards
+                }
+                out[f'{precision}_sharded_devices'] = len(devices)
+                check(len(devices) == 4, f'{precision}: index on {devices}')
+            del index
+            gc.collect()
+        check(
+            all(len(row) == 10 for row in ids['sharded']),
+            f'{precision}: sharded search returned short rows',
+        )
+        out[f'{precision}_top10_equal'] = ids['sharded'] == ids['unsharded']
+        check(
+            out[f'{precision}_top10_equal'],
+            f'{precision}: sharded top-10 ids differ from unsharded',
+        )
+    return out
+
+
+# -------------------------------------------------------------------- main
+
+
+def run(chips: int, seed: int, sizes: Sizes) -> int:
+    from distllm_tpu.utils import enable_compile_cache
+
+    cache_dir = enable_compile_cache()
+    import jax
+
+    # Cache every compile, however short: a second start then compiles
+    # nothing, and "no new entries" is a clean check (jax's default skips
+    # compiles under a second, which straddle the threshold run to run).
+    jax.config.update('jax_persistent_cache_min_compile_time_secs', 0.0)
+
+    devices = jax.devices()
+    if devices[0].platform != 'tpu':
+        print(
+            f'no TPU: jax found platform {devices[0].platform!r} '
+            f'({len(devices)} device(s)); chip_smoke.py runs on the chip only',
+            flush=True,
+        )
+        return 2
+    if len(devices) != chips:
+        print(
+            f'no TPU set-up for this run: {len(devices)} chip(s) attached, '
+            f'--chips {chips} asked for', flush=True,
+        )
+        return 2
+
+    shutil.rmtree(WORK, ignore_errors=True)
+    WORK.mkdir(parents=True)
+    try:
+        ok = run_phase('device', phase_device, cache_dir)
+        if chips == 4:
+            phases = (('tp4', phase_tp4), ('index4', phase_index4))
+        else:
+            phases = (
+                ('kernels', phase_kernels), ('embed', phase_embed),
+                ('serve', phase_serve),
+            )
+        for name, fn in phases:
+            ok = run_phase(name, fn, sizes, seed) and ok
+    finally:
+        shutil.rmtree(WORK, ignore_errors=True)
+    if not ok:
+        return 1
+    emit({
+        'ok': True,
+        'device': {
+            'platform': devices[0].platform,
+            'kind': devices[0].device_kind,
+            'count': len(devices),
+        },
+    })
+    return 0
+
+
+def main(argv: list[str] | None = None) -> int:
+    parser = argparse.ArgumentParser(description=__doc__.split('\n\n')[0])
+    parser.add_argument('--seed', type=int, default=0)
+    parser.add_argument(
+        '--chips', type=int, choices=(1, 4), default=1,
+        help='4 = only the across-chips phases (tp4, index4)',
+    )
+    args = parser.parse_args(argv)
+    return run(args.chips, args.seed, Sizes())
+
+
+if __name__ == '__main__':
+    raise SystemExit(main())

@@ -220,9 +220,9 @@ def chat_with_model(config: ChatAppConfig, input_fn=input, echo=print) -> None:
 
 
 def main(argv: list[str] | None = None) -> int:
-    from distllm_tpu.utils import apply_platform_env
+    from distllm_tpu.utils import enable_compile_cache
 
-    apply_platform_env()
+    enable_compile_cache()
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument('--config', required=True, type=Path)
     args = parser.parse_args(argv)

@@ -673,9 +673,9 @@ def build_app(config: ChatAppConfig):
 
 
 def main(argv: list[str] | None = None) -> int:
-    from distllm_tpu.utils import apply_platform_env
+    from distllm_tpu.utils import enable_compile_cache
 
-    apply_platform_env()
+    enable_compile_cache()
     from aiohttp import web
 
     parser = argparse.ArgumentParser(description=__doc__)

@@ -50,9 +50,9 @@ def _build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv: list[str] | None = None) -> int:
-    from distllm_tpu.utils import apply_platform_env
+    from distllm_tpu.utils import enable_compile_cache
 
-    apply_platform_env()
+    enable_compile_cache()
     parser = _build_parser()
     args = parser.parse_args(argv)
     if not args.command:

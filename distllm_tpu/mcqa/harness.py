@@ -380,9 +380,9 @@ def run_mcqa(config: MCQAConfig) -> dict[str, Any]:
 
 
 def main(argv: list[str] | None = None) -> int:
-    from distllm_tpu.utils import apply_platform_env
+    from distllm_tpu.utils import enable_compile_cache
 
-    apply_platform_env()
+    enable_compile_cache()
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument('--config', required=True, type=Path)
     args = parser.parse_args(argv)

@@ -108,7 +108,7 @@ def gelu_tanh(x: jnp.ndarray) -> jnp.ndarray:
 
     The exact erf lowers to a long VPU polynomial that costs 19% of a
     BERT-base embed forward on a v5e (measured: MFU 0.622 exact vs 0.790
-    tanh, ``chipback_r05/probe_embed_ablation.log``). The tanh form's max
+    tanh, builder record of 2026-07-31, in git history). The tanh form's max
     deviation from erf-GELU is ~3e-3 near |x|=2 — the same order as bf16's
     representation step there, so it is a REAL (if small) numerics change,
     not a free lunch; that is why it is an explicit activation choice

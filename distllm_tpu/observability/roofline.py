@@ -28,7 +28,7 @@ stream, with no per-architecture formula to drift.
 
 Peaks come from a device-kind table (TPU generations), overridable with
 ``DISTLLM_PEAK_FLOPS`` / ``DISTLLM_PEAK_BW_BYTES`` for new silicon. On
-non-TPU backends (the CPU smoke tier) order-of-magnitude placeholder peaks
+platform 'cpu' (the test tier) order-of-magnitude placeholder peaks
 keep the gauges populated — the *absolute* CPU numbers are meaningless,
 but the per-kind ratios and the plumbing they exercise are exactly what
 the smoke tests pin.
@@ -51,30 +51,37 @@ DEVICE_PEAKS: dict[str, tuple[float, float]] = {
     'TPU v6e': (918e12, 1.64e12),
 }
 
-# Order-of-magnitude placeholders for backends not in the table (CPU smoke
-# runs): a few-core server class machine. Documented as placeholders —
-# utilization numbers on such backends exercise the plumbing, not the
-# silicon.
-FALLBACK_PEAKS = (1e12, 1e11)
+# Order-of-magnitude placeholder for platform 'cpu' ONLY (the test tier):
+# a few-core server class machine. Utilization numbers computed from it
+# exercise the plumbing, not the silicon, and are never device metrics.
+CPU_PLACEHOLDER_PEAKS = (1e12, 1e11)
 
 
 def device_peaks(device) -> tuple[float, float]:
     """``(peak_flops, peak_hbm_bytes_per_s)`` for a jax device.
 
     Env overrides ``DISTLLM_PEAK_FLOPS`` / ``DISTLLM_PEAK_BW_BYTES`` win
-    over the table (new silicon, calibrated numbers); unknown kinds fall
-    back to :data:`FALLBACK_PEAKS`.
+    over the table (new silicon, calibrated numbers). A device kind that
+    is not in :data:`DEVICE_PEAKS` raises, except on platform ``cpu``,
+    which gets :data:`CPU_PLACEHOLDER_PEAKS`.
     """
-    kind = (getattr(device, 'device_kind', '') or '').lower()
+    kind = device.device_kind.lower()
     flops = bw = None
     best = -1
     for name, (f, b) in DEVICE_PEAKS.items():
         if kind.startswith(name.lower()) and len(name) > best:
             best, flops, bw = len(name), f, b
-    if flops is None:
-        flops, bw = FALLBACK_PEAKS
     env_flops = os.environ.get('DISTLLM_PEAK_FLOPS')
     env_bw = os.environ.get('DISTLLM_PEAK_BW_BYTES')
+    if flops is None and device.platform == 'cpu':
+        flops, bw = CPU_PLACEHOLDER_PEAKS
+    if flops is None and not (env_flops and env_bw):
+        raise ValueError(
+            f'device kind {device.device_kind!r} (platform '
+            f'{device.platform!r}) is not in DEVICE_PEAKS; add its '
+            'published peaks, or set DISTLLM_PEAK_FLOPS and '
+            'DISTLLM_PEAK_BW_BYTES'
+        )
     if env_flops:
         flops = float(env_flops)
     if env_bw:

@@ -41,7 +41,6 @@ from __future__ import annotations
 
 import contextlib
 import itertools
-import os
 import sys
 import threading
 import time
@@ -93,19 +92,11 @@ class CompileWatcher:
         Before/after deltas per phase reveal whether a cold start HIT the
         preflight-seeded cache or re-lowered everything (the same signal
         bench.py's ``warm_start`` field reports per stage)."""
-        jax = sys.modules.get('jax')
-        if jax is None:
+        if 'jax' not in sys.modules:
             return None
-        try:
-            cache_dir = jax.config.jax_compilation_cache_dir
-        except Exception:
-            return None
-        if not cache_dir:
-            return None
-        try:
-            return len(os.listdir(cache_dir))
-        except OSError:
-            return None
+        from distllm_tpu.utils import compile_cache_entries
+
+        return compile_cache_entries()
 
     @contextlib.contextmanager
     def phase(self, kind: str, shape: str, *, compiles: bool = True,
@@ -213,6 +204,6 @@ def record_backend_init(watcher: CompileWatcher | None = None):
     with watcher.phase('backend_init', 'devices', compiles=False) as fields:
         devices = jax.devices()
         fields['platform'] = devices[0].platform
-        fields['device_kind'] = getattr(devices[0], 'device_kind', '')
+        fields['device_kind'] = devices[0].device_kind
         fields['num_devices'] = len(devices)
     return devices

@@ -58,20 +58,11 @@ class XlaCost:
         }
 
 
-def normalize_cost_analysis(raw) -> dict:
-    """``cost_analysis()`` returns a dict on recent jax and ``[dict]`` on
-    older versions (scripts/probe_decode_hlo.py handles the same split);
-    collapse both to one dict, ``{}`` when absent."""
-    if isinstance(raw, list):
-        raw = raw[0] if raw else {}
-    return raw if isinstance(raw, dict) else {}
-
-
 def executable_cost(compiled, source: str = 'aot') -> XlaCost | None:
     """Price a compiled executable; ``None`` when the backend reports no
     FLOPs (cost analysis unsupported)."""
     try:
-        cost = normalize_cost_analysis(compiled.cost_analysis())
+        cost = compiled.cost_analysis() or {}
     except Exception:
         return None
     flops = cost.get('flops')
@@ -89,7 +80,9 @@ def price_callable(fn, *args) -> XlaCost | None:
     (identical HLO to the wrapper's own compile, so a configured
     persistent compilation cache makes it a disk hit). Returns ``None``
     on any failure — pricing is telemetry, never load-bearing."""
-    if hasattr(fn, 'cost_analysis'):
+    import jax
+
+    if isinstance(fn, jax.stages.Compiled):
         return executable_cost(fn, source='aot')
     try:
         compiled = fn.lower(*args).compile()

@@ -3,21 +3,3 @@ top-k retrieval, quantization. XLA implementations are the portable baseline;
 Pallas kernels provide the TPU fast paths (same signatures, tested against
 each other)."""
 
-
-def tpu_compiler_params(**kwargs):
-    """Build Pallas TPU compiler params across the jax 0.4.x/0.5 rename
-    (``TPUCompilerParams`` -> ``CompilerParams``) — one home so every
-    kernel resolves the installed spelling the same way and a missing
-    class fails with the actual requirement instead of a bare
-    ``NoneType is not callable``."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(
-        pltpu, 'CompilerParams', getattr(pltpu, 'TPUCompilerParams', None)
-    )
-    if cls is None:
-        raise ImportError(
-            'jax.experimental.pallas.tpu exposes neither CompilerParams '
-            'nor TPUCompilerParams; this jax version is unsupported'
-        )
-    return cls(**kwargs)

@@ -265,7 +265,7 @@ def quantize_pytree(
         # jax.Array is a zero-copy view into the device/host buffer, which
         # delete() below would free out from under the quantizer.
         host = np.array(leaf, dtype=np.float32, copy=True)
-        if delete_source and hasattr(leaf, 'delete'):
+        if delete_source and isinstance(leaf, jax.Array):
             leaf.delete()
         if mode == 'int8':
             return quantize_int8(host, out_dtype)

@@ -4,7 +4,7 @@ Why this exists (measured): ``common.dense`` used to call
 ``QTensor.dequantize()`` and feed the bf16 result to the dot. Inside the
 unrolled decode loop XLA materializes both the converted weight AND the
 scale-multiplied copy in HBM — per layer, per step. The int8 serving run
-that motivated this (`chipback_r05/bench_run1.json`) decoded 16-step
+that motivated this (builder record of 2026-07-31, in git history) decoded 16-step
 windows in 1242 ms at batch 128 against a ~200 ms weights+KV streaming
 floor: the "quantized" model was streaming ~3x the bytes of the bf16 one.
 
@@ -16,7 +16,7 @@ The fix has two tiers, chosen by :func:`int8_dense`:
   `quantization.quantize_int8` reduces only the input dim), the full-size
   elementwise multiply on the weight is gone, and XLA fuses the int8→bf16
   convert into the dot's weight stream. Measured at the 7B unrolled
-  16-step decode window (`chipback_r05/probe_decode_int8.log`): 315 ms at
+  16-step decode window (builder record of 2026-07-31, in git history): 315 ms at
   batch 32 = 1623 tok/s, vs 465 ms bf16 and 1242 ms for the old
   dequant-before-dot serving path.
 - **Pallas kernel** (:func:`int8_matmul_pallas`): streams int8 tiles
@@ -42,7 +42,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from distllm_tpu.ops import tpu_compiler_params
 
 BACKENDS = ('auto', 'pallas', 'xla', 'interpret')
 
@@ -155,7 +154,7 @@ def int8_matmul_pallas(
         out_specs=pl.BlockSpec((m_pad, bn), lambda j, kk: (0, j)),
         out_shape=jax.ShapeDtypeStruct((m_pad, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((m_pad, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'arbitrary'),
         ),
         interpret=interpret,

@@ -41,11 +41,6 @@ def _sharded_topk(score_fn, row_count, operands, in_specs, k, mesh):
     score matrix) and reduced with one final ``top_k``. Both the exact
     fp32 and the int8 tiers route here so the offset/merge math has one
     home."""
-    try:
-        from jax import shard_map
-    except ImportError:  # jax < 0.5 spelling of the same API
-        from jax.experimental.shard_map import shard_map
-
     n_shards = mesh.shape['data']
     shard_rows = row_count // n_shards
 
@@ -56,7 +51,7 @@ def _sharded_topk(score_fn, row_count, operands, in_specs, k, mesh):
         offset = jax.lax.axis_index('data') * shard_rows
         return s, i + offset
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         per_shard,
         mesh=mesh,
         in_specs=in_specs,
@@ -209,7 +204,7 @@ def pack_sign_bits(embeddings: np.ndarray) -> np.ndarray:
 
 # Corpora past this row count switch the per-chunk candidate selection
 # from exact lax.top_k (a full bitonic sort over the chunk — measured
-# 12.5 s for one 10M-row ubinary scan, chipback_r05) to the TPU-native
+# 12.5 s for one 10M-row ubinary scan, builder record of 2026-07-31, in git history) to the TPU-native
 # jax.lax.approx_max_k (~0.95 per-element recall). Quantized-tier
 # candidates feed an oversampled fp32 rescore, so serving quality is set
 # by top1/rescore behavior, not the last near-tie in the candidate set.
@@ -230,7 +225,7 @@ def group_rows(arr: np.ndarray, chunk: int) -> np.ndarray:
     dispatch ``lax.scan`` whose chunk slabs are contiguous scan slices.
     Measured on the chip at 10M x 768 int8: 32 ms/scan grouped vs
     seconds for the python slice-per-chunk loop over a monolithic
-    device array (chipback_r05/probe_retrieval_scan.log and the
+    device array (builder record of 2026-07-31, in git history and the
     prof_slice experiments behind it).
     """
     n = arr.shape[0]

@@ -21,9 +21,9 @@ from distllm_tpu.observability.instruments import log_event
 
 
 def main(argv: list[str] | None = None) -> int:
-    from distllm_tpu.utils import apply_platform_env
+    from distllm_tpu.utils import enable_compile_cache
 
-    apply_platform_env()
+    enable_compile_cache()
     parser = argparse.ArgumentParser(description='distllm-tpu fabric worker')
     parser.add_argument('--coordinator', required=True, help='tcp://host:port')
     parser.add_argument('--heartbeat-interval', type=float, default=5.0)

@@ -127,8 +127,14 @@ class TpuIndexV2:
 
     def _chunk(self, lo: int) -> np.ndarray:
         hi = min(lo + self._CHUNK_ROWS, len(self.dataset))
+        # Arrow → numpy directly: the default format hands back Python
+        # lists of floats (50M objects per chunk at 768 dims), which made
+        # a 1M-row build take tens of minutes.
         rows = np.asarray(
-            self.dataset[lo:hi]['embeddings'], dtype=np.float32
+            self.dataset.with_format('numpy', columns=['embeddings'])[lo:hi][
+                'embeddings'
+            ],
+            dtype=np.float32,
         )
         if self.config.normalize:
             norms = np.linalg.norm(rows, axis=1, keepdims=True)
@@ -197,7 +203,7 @@ class TpuIndexV2:
             # into [G, chunk, H/8] (ops/topk.group_rows), then one
             # device_put: the grouped layout rides hamming_topk's single-
             # dispatch lax.scan (~32 ms at 10M rows vs seconds for a
-            # sliced-chunk loop — chipback_r05). NO second fp32 host
+            # sliced-chunk loop — builder record of 2026-07-31, in git history). NO second fp32 host
             # copy: rescore candidates are gathered per query batch from
             # the arrow-mmap'd dataset.
             self._packed = jnp.asarray(group_rows(

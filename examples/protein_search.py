@@ -24,11 +24,11 @@ import argparse
 import json
 from pathlib import Path
 
-from distllm_tpu.utils import apply_platform_env
+from distllm_tpu.utils import enable_compile_cache
 
 
 def main() -> None:
-    apply_platform_env()
+    enable_compile_cache()
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument('--dataset_dir', type=Path, required=True,
                         help='Merged embedding dataset (build via embed + merge).')

@@ -1,43 +1,38 @@
-"""Compile-only preflight of the serving path against a v5e topology.
+"""Compile-only preflight of the serving path against a described v5e.
 
-Round 2's blind spot: the Pallas decode kernel only failed ON the chip
-(Mosaic lowering + HBM budgeting are invisible to CPU interpret tests).
-The locally installed libtpu can build a COMPILE-ONLY PJRT topology
-(``jax.experimental.topologies``) with no hardware attached, so every
-serving executable — bf16 batch-32 and int8 batch-128 fused decode
-windows, both attention backends, with the engine's AUTO-layout
-compile — can be validated for lowering errors and HBM fit before a
-single chip-second is spent. Run before benching; see also
-tests/test_aot_tpu.py for the small-dims CI version.
+The Mosaic lowering and the HBM budget are invisible to CPU interpret
+tests. The installed libtpu compiles for a chip that is described and not
+attached (``jax.experimental.topologies``), so every serving executable —
+bf16 batch-32 and int8 batch-128 fused decode windows, both attention
+backends, with the engine's AUTO-layout compile, and the tensor-parallel
+window on the four-chip ``v5e:2x2`` mesh with the shardings
+``TpuGenerator`` applies — is checked for lowering errors and memory fit
+before a chip-second is spent. A compile that passes is not a chip run.
+See tests/test_aot_tpu.py for the kernel-only tier-1 version.
+
+Run: ``JAX_PLATFORMS=cpu python scripts/aot_preflight.py [single] [multichip] [embed]``
+(no argument = all three sets).
 """
 
 import os
-os.environ.pop('JAX_PLATFORMS', None)
+os.environ.setdefault('JAX_PLATFORMS', 'cpu')
+os.environ.setdefault('TPU_LOG_DIR', 'disabled')
 import numpy as np
 import jax
-jax.config.update('jax_platforms', 'cpu')
 import jax.numpy as jnp
 from jax.experimental import topologies
 from jax.experimental.layout import Format, Layout
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 import pathlib, sys
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 import time
 
-# Seed the repo's persistent compilation cache: if the runtime produces
-# matching keys, the bench's multi-minute warmup reuses these compiles.
-try:
-    jax.config.update(
-        'jax_compilation_cache_dir',
-        str(pathlib.Path(__file__).resolve().parent.parent / '.jax_cache'),
-    )
-    jax.config.update('jax_persistent_cache_min_compile_time_secs', 0.0)
-except Exception:
-    pass
+# A compile for a described chip is written to the persistent cache but
+# cannot be read back by a chip, so this script keeps the cache off.
+jax.config.update('jax_enable_compilation_cache', False)
 
-topo = topologies.get_topology_desc(platform='tpu', topology_name='v5e:2x2x1')
-mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1), ('x',))
-s = NamedSharding(mesh, P())
+topo = topologies.get_topology_desc(platform='tpu', topology_name='v5e:2x2')
+s = SingleDeviceSharding(topo.devices[0])
 
 def sds(shape, dtype):
     return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=s)
@@ -60,12 +55,12 @@ def window_args(params_tree, B, nb, R):
         sds((B,), jnp.int32), sds((B,), jnp.uint32),
     )
 
-# Match the engine's decode_layer_unroll so the seeded cache keys hit at
-# serve time; export DISTLLM_PREFLIGHT_LAYER_UNROLL=0 when serving with
-# decode_layer_unroll=False (the escape hatch from the longer compile).
+# Match the engine's decode_layer_unroll; export
+# DISTLLM_PREFLIGHT_LAYER_UNROLL=0 to check decode_layer_unroll=False.
 _LAYER_UNROLL = os.environ.get('DISTLLM_PREFLIGHT_LAYER_UNROLL', '1') != '0'
 
 failures: list[str] = []
+SETS = sys.argv[1:] or ['single', 'multichip', 'embed']
 
 
 def compile_window(params_tree, B, nb, R, backend, label):
@@ -80,21 +75,21 @@ def compile_window(params_tree, B, nb, R, backend, label):
                          in_shardings=(Format(Layout.AUTO),) + (Format(),) * 12)
         compiled = jitted.lower(*window_args(params_tree, B, nb, R)).compile()
         mem = compiled.memory_analysis()
-        tmp_b = getattr(mem, 'temp_size_in_bytes', None)
         print(f'{label}: AOT OK ({time.perf_counter()-t:.0f}s) '
-              f'temp={tmp_b/1e9 if tmp_b else "?"}GB', flush=True)
+              f'temp={mem.temp_size_in_bytes/1e9:.3f}GB', flush=True)
     except Exception as exc:
         print(f'{label}: FAILED {repr(exc)[:400]}', flush=True)
         failures.append(label)
 
-bf16_params = jax.tree.map(lambda x: sds(x.shape, x.dtype), mshapes)
-compile_window(bf16_params, 32, 712, 32, 'pallas', 'bf16 B=32 pallas AUTO-layout')
-compile_window(bf16_params, 32, 712, 32, 'xla', 'bf16 B=32 xla AUTO-layout')
+if 'single' in SETS:
+    bf16_params = jax.tree.map(lambda x: sds(x.shape, x.dtype), mshapes)
+    compile_window(bf16_params, 32, 712, 32, 'pallas', 'bf16 B=32 pallas AUTO-layout')
+    compile_window(bf16_params, 32, 712, 32, 'xla', 'bf16 B=32 xla AUTO-layout')
 
-qparams = quantize_pytree_abstract(mshapes, make_leaf=sds)
-compile_window(qparams, 128, 2840, 32, 'pallas', 'int8 B=128 pallas AUTO-layout')
-compile_window(qparams, 128, 2840, 32, 'xla', 'int8 B=128 xla AUTO-layout')
-print('SINGLE-CHIP CASES DONE', flush=True)
+    qparams = quantize_pytree_abstract(mshapes, make_leaf=sds)
+    compile_window(qparams, 128, 2840, 32, 'pallas', 'int8 B=128 pallas AUTO-layout')
+    compile_window(qparams, 128, 2840, 32, 'xla', 'int8 B=128 xla AUTO-layout')
+    print('SINGLE-CHIP CASES DONE', flush=True)
 
 
 # ---- multi-chip lowering: SP ring attention + TP decode on real v5e devices
@@ -105,8 +100,7 @@ def compile_multichip() -> None:
 
     t = time.perf_counter()
     try:
-        devs = np.asarray(topo.devices).reshape(1, 2, 2)[:, :, :1]
-        sp_mesh = Mesh(devs.reshape(1, 2), ('data', 'seq'))
+        sp_mesh = Mesh(np.asarray(topo.devices[:2]).reshape(1, 2), ('data', 'seq'))
         rs = NamedSharding(sp_mesh, P(None, 'seq', None, None))
         ms = NamedSharding(sp_mesh, P(None, 'seq'))
         B, S, N, H = 2, 256, 8, 128
@@ -128,47 +122,67 @@ def compile_multichip() -> None:
 
     t = time.perf_counter()
     try:
-        tp_mesh = Mesh(np.asarray(topo.devices[:2]).reshape(2), ('model',))
+        # The mesh and shardings TpuGenerator builds for
+        # tensor_parallel_size=4 (tpu_backend.py): make_mesh over the
+        # first four devices, param_specs on the model axis, KV pages
+        # sharded over the kv-head dim, step inputs replicated.
+        from distllm_tpu.parallel.mesh import MeshSpec, make_mesh
+
+        tp_mesh = make_mesh(
+            MeshSpec(data=1, model=4), devices=list(topo.devices)[:4]
+        )
         repl = NamedSharding(tp_mesh, P())
         kvs = NamedSharding(tp_mesh, P(None, None, None, 'model'))
-        from distllm_tpu.parallel.sharding import shard_pytree  # noqa: F401
-        specs = mistral.param_specs(mcfg)
-        def spec_sharding(spec, leaf):
-            return jax.ShapeDtypeStruct(
-                leaf.shape, leaf.dtype, sharding=NamedSharding(tp_mesh, spec)
-            )
         tp_params = jax.tree.map(
-            spec_sharding, specs, mshapes,
+            lambda spec, leaf: jax.ShapeDtypeStruct(
+                leaf.shape, leaf.dtype, sharding=NamedSharding(tp_mesh, spec)
+            ),
+            mistral.param_specs(mcfg, mshapes), mshapes,
             is_leaf=lambda x: isinstance(x, P),
         )
-        B = 8
-        ksh = (mcfg.num_layers, 64, bs, mcfg.num_kv_heads, mcfg.head_size)
+        B, nb, R = 32, 640, 256
+        ksh = (mcfg.num_layers, nb, bs, mcfg.num_kv_heads, mcfg.head_size)
         def r(shape, dtype):
             return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=repl)
-        jax.jit(
-            lambda p, i, po, c, k, v, bt, sl, tmp, tp_, mp, ky:
+        compiled = jax.jit(
+            lambda p, i, po, c, k, v, bt, sl, tmp, tp_, mp, tk, sd:
                 mistral.decode_loop(
-                    p, mcfg, i, po, k, v, bt, c, sl, tmp, tp_, mp, ky,
-                    num_steps=4, attn_backend='xla', max_table_positions=512,
-                    sampling_top_window=64, layer_unroll=_LAYER_UNROLL),
+                    p, mcfg, i, po, k, v, bt, c, sl, tmp, tp_, mp, tk, sd,
+                    num_steps=8, attn_backend='xla', max_table_positions=4096,
+                    sampling_top_window=0, layer_unroll=_LAYER_UNROLL),
             donate_argnums=(4, 5),
         ).lower(
             tp_params, r((B,), jnp.int32), r((B,), jnp.int32),
             r((B,), jnp.int32),
             jax.ShapeDtypeStruct(ksh, jnp.bfloat16, sharding=kvs),
             jax.ShapeDtypeStruct(ksh, jnp.bfloat16, sharding=kvs),
-            r((B, 32), jnp.int32), r((B,), jnp.int32), r((B,), jnp.float32),
-            r((B,), jnp.float32), r((B,), jnp.float32), r((2,), jnp.uint32),
+            r((B, R), jnp.int32), r((B,), jnp.int32), r((B,), jnp.float32),
+            r((B,), jnp.float32), r((B,), jnp.float32), r((B,), jnp.int32),
+            r((B,), jnp.uint32),
         ).compile()
-        print(f'TP=2 decode window v5e: AOT OK '
-              f'({time.perf_counter()-t:.0f}s)', flush=True)
+        mem = compiled.memory_analysis()
+        text = compiled.as_text()
+        collectives = {
+            op: text.count(f' {op}(') + text.count(f' {op}-start(')
+            for op in ('all-reduce', 'all-gather', 'reduce-scatter',
+                       'all-to-all', 'collective-permute')
+        }
+        print(f'TP=4 decode window v5e:2x2: AOT OK '
+              f'({time.perf_counter()-t:.0f}s) per-device '
+              f'args={mem.argument_size_in_bytes/1e9:.2f}GB '
+              f'temp={mem.temp_size_in_bytes/1e9:.3f}GB '
+              f'collectives={collectives} '
+              f'tpu_custom_call={"tpu_custom_call" in text}', flush=True)
+        if not collectives['all-reduce']:
+            raise RuntimeError('no all-reduce in a tensor-parallel window')
     except Exception as exc:
-        print(f'TP=2 decode window: FAILED {repr(exc)[:400]}', flush=True)
+        print(f'TP=4 decode window: FAILED {repr(exc)[:400]}', flush=True)
         failures.append('tp')
 
 
-compile_multichip()
-print('MULTICHIP DONE', flush=True)
+if 'multichip' in SETS:
+    compile_multichip()
+    print('MULTICHIP DONE', flush=True)
 
 
 # ---- embed-stage executables: the bench's other warmup set. Mirrors
@@ -204,7 +218,8 @@ def compile_embed_set() -> None:
                 failures.append(f'embed-{label}-{S}')
 
 
-compile_embed_set()
-print('EMBED SET DONE' + (f' ({len(failures)} FAILED)' if failures else ''),
+if 'embed' in SETS:
+    compile_embed_set()
+print('PREFLIGHT DONE' + (f' ({len(failures)} FAILED)' if failures else ''),
       flush=True)
 sys.exit(1 if failures else 0)

@@ -1,10 +1,8 @@
-"""Bench trajectory gate: diff any set of BENCH_r*.json records.
+"""Bench trajectory gate: diff any set of driver bench records.
 
-The repo carries one official bench record per round (``BENCH_r01.json``
-.. ``BENCH_r05.json``) plus interim chipback fragments, and until now the
-only way to read the trajectory was eyeballing JSON — which is how a
-184 → 830 tok/s improvement and two all-zero rounds coexisted with no
-gate noticing either. This script turns the record pile into a gate:
+Reading a trajectory by eyeballing JSON lets an improvement and an
+all-zero round coexist with no gate noticing either. This script turns a
+pile of records into a gate:
 
 - load any set of record files (the driver-contract JSON: ``{"n", "cmd",
   "rc", "parsed": {...}}``, or a bare metrics object), oldest first;
@@ -17,15 +15,15 @@ gate noticing either. This script turns the record pile into a gate:
   record carrying each gated metric; exit nonzero when a throughput /
   MFU / goodput metric fell (or a latency / warmup metric rose) by more
   than ``--threshold`` (default 5%). Metrics present earlier but missing
-  from the newest record are reported as *lost* — a warning by default
-  (the r03–r05 tail is known-bad), a failure under ``--strict-missing``.
+  from the newest record are reported as *lost* — a warning by default,
+  a failure under ``--strict-missing``.
 
 Usage::
 
-    python scripts/benchdiff.py BENCH_r01.json BENCH_r02.json
-    python scripts/benchdiff.py BENCH_r*.json --markdown TRAJECTORY.md
-    python scripts/benchdiff.py r02.json candidate.json --threshold 0.03
-    python scripts/benchdiff.py BENCH_r*.json --emit-baseline baseline.json
+    python scripts/benchdiff.py older.json newer.json
+    python scripts/benchdiff.py records/*.json --markdown TRAJECTORY.md
+    python scripts/benchdiff.py older.json candidate.json --threshold 0.03
+    python scripts/benchdiff.py records/*.json --emit-baseline baseline.json
 
 ``--emit-baseline`` distills the newest record that carried metrics into
 the **baseline envelope** the runtime regression sentinel consumes
@@ -208,7 +206,7 @@ def main(argv: list[str] | None = None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     parser.add_argument(
         'records', nargs='+',
-        help='record files, oldest first (BENCH_r01.json BENCH_r02.json ...)',
+        help='record files, oldest first',
     )
     parser.add_argument(
         '--threshold', type=float, default=0.05,

@@ -12,9 +12,6 @@ import pathlib as _pl
 import sys as _sys
 _sys.path.insert(0, str(_pl.Path(__file__).resolve().parent.parent))
 
-from distllm_tpu.utils import apply_platform_env
-
-apply_platform_env()
 
 import time
 

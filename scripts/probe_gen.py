@@ -18,9 +18,6 @@ _sys.path.insert(0, str(_pl.Path(__file__).resolve().parent.parent))
 
 import time
 
-from distllm_tpu.utils import apply_platform_env
-
-apply_platform_env()
 
 import jax
 import numpy as np

@@ -24,9 +24,6 @@ import time
 
 _sys.path.insert(0, str(_pl.Path(__file__).resolve().parent.parent))
 
-from distllm_tpu.utils import apply_platform_env
-
-apply_platform_env()
 
 import functools
 

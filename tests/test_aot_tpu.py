@@ -3,10 +3,19 @@
 The locally installed libtpu can build a compile-only PJRT topology
 (``jax.experimental.topologies``), which catches the class of failures CPU
 interpret mode cannot: Mosaic lowering rejections (block-shape rules, DMA
-patterns) and HBM budgeting. Round 2's flagship regression — a Pallas
-decode kernel that silently failed only on the real chip — is exactly what
-these tests pin down in CI. Small dims keep each compile to a few seconds;
-``scripts/aot_preflight.py`` runs the full 7B serving matrix.
+patterns) and HBM budgeting.
+
+Two tiers. Unmarked: each Pallas kernel of the main path alone, at the
+real widths (Mistral-7B serving, PubMedBERT embedding), asserting the
+kernel is in the compiled program (``tpu_custom_call``) — 0.1-2 s each,
+so every tier-1 run compiles for the chip. ``slow``: whole windows and
+forwards around those kernels; ``scripts/aot_preflight.py`` runs the full
+7B serving matrix.
+
+The topology is described ONLY inside the module-scoped fixture: only one
+process may hold libtpu, every xdist worker imports this file, and a
+module that touches the topology at import gives the workers different
+tests to collect.
 """
 
 import numpy as np
@@ -40,27 +49,110 @@ def _compile(build, mosaic_kernel: bool = True):
 @pytest.fixture(scope='module')
 def v5e():
     from jax.experimental import topologies
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
 
     try:
         topo = topologies.get_topology_desc(
-            platform='tpu', topology_name='v5e:2x2x1'
+            platform='tpu', topology_name='v5e:2x2'
         )
     except Exception as exc:  # no libtpu / unsupported platform
         pytest.skip(f'no compile-only TPU topology available: {exc!r}')
-    mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1), ('x',))
-    sharding = NamedSharding(mesh, PartitionSpec())
+    sharding = SingleDeviceSharding(topo.devices[0])
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=sharding)
 
-    return sds
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip (the next run warns and
+    # compiles again): keep the cache off around this module.
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update('jax_enable_compilation_cache', False)
+    compilation_cache.reset_cache()
+    yield sds
+    jax.config.update('jax_enable_compilation_cache', cache_was_on)
+    compilation_cache.reset_cache()
 
 
-# ~10 min Mosaic compile on this container's toolchain (measured
-# 2026-08-02) — far past the fast tier's "few seconds per compile" design
-# budget, so it runs in the slow tier; the fast tier keeps the same
-# kernel's interpret-mode coverage (tests/test_encoder_attention.py).
+def _assert_kernel_compiled(compiled) -> None:
+    assert 'tpu_custom_call' in compiled.as_text(), (
+        'no Pallas kernel in the compiled program'
+    )
+
+
+# ---- kernel-only compiles at the real widths (tier-1, seconds each) ----
+
+# Mistral-7B-Instruct-v0.3 attention widths at the serving batch.
+_B, _NH, _NKV, _HD = 32, 32, 8, 128
+
+
+@pytest.mark.parametrize('span', [1, 16], ids=['span1', 'span16'])
+@pytest.mark.parametrize(
+    'kv,block_size', [('bf16', 16), ('int8', 32)], ids=['bf16', 'int8']
+)
+def test_ragged_kernel_compiles_at_7b_widths(v5e, kv, block_size, span):
+    """Decode (span 1) and chunk/verify (span 16) rows over a bf16 pool
+    at block 16 and the int8 ``QuantizedKV`` pool at block 32 (int8 at
+    block 16 is refused by the kernel's own sublane contract)."""
+    from distllm_tpu.ops.paged_attention import (
+        QuantizedKV,
+        ragged_paged_attention_pallas,
+    )
+
+    num_blocks, max_blocks = 712, 512 // block_size
+    shape = (num_blocks, block_size, _NKV, _HD)
+    if kv == 'int8':
+        pool = QuantizedKV(
+            v5e(shape, jnp.int8), v5e((num_blocks, _NKV), jnp.float32)
+        )
+    else:
+        pool = v5e(shape, jnp.bfloat16)
+    compiled = jax.jit(
+        lambda q, k, v, bt, ctx, pos, ql: ragged_paged_attention_pallas(
+            q, k, v, bt, ctx, pos, q_lens=ql
+        )
+    ).lower(
+        v5e((_B, span, _NH, _HD), jnp.bfloat16), pool, pool,
+        v5e((_B, max_blocks), jnp.int32), v5e((_B,), jnp.int32),
+        v5e((_B, span), jnp.int32), v5e((_B,), jnp.int32),
+    ).compile()
+    _assert_kernel_compiled(compiled)
+
+
+@pytest.mark.parametrize(
+    'm,k,n', [(32, 4096, 14336), (128, 4096, 32000)],
+    ids=['mlp_up_b32', 'lm_head_b128'],
+)
+def test_int8_matmul_kernel_compiles_at_7b_widths(v5e, m, k, n):
+    """Off the main path ('auto' means XLA there), so this compile is the
+    kernel's only chip-facing check."""
+    from distllm_tpu.ops.quantized_matmul import int8_matmul_pallas
+
+    compiled = int8_matmul_pallas.lower(
+        v5e((m, k), jnp.bfloat16), v5e((k, n), jnp.int8),
+        v5e((1, n), jnp.float32),
+    ).compile()
+    _assert_kernel_compiled(compiled)
+
+
+@pytest.mark.parametrize(
+    'b,s,d', [(64, 256, 768), (64, 160, 768)], ids=['s256', 's160']
+)
+def test_encoder_kernel_compiles_at_pubmedbert_widths(v5e, b, s, d):
+    """160 is a fine-ladder rung that is NOT a multiple of 128."""
+    from distllm_tpu.ops.encoder_attention import encoder_attention
+
+    compiled = jax.jit(
+        lambda q, k, v, m: encoder_attention(q, k, v, m, num_heads=12)
+    ).lower(
+        v5e((b, s, d), jnp.bfloat16), v5e((b, s, d), jnp.bfloat16),
+        v5e((b, s, d), jnp.bfloat16), v5e((b, s), jnp.int32),
+    ).compile()
+    _assert_kernel_compiled(compiled)
+
+
+# ---- whole windows and forwards (slow tier) ----
+
 @pytest.mark.slow
 def test_encoder_attention_compiles_for_tpu(v5e):
     from distllm_tpu.ops.encoder_attention import encoder_attention
@@ -78,13 +170,6 @@ def test_encoder_attention_compiles_for_tpu(v5e):
     ).compile()
 
 
-# Moved to the slow tier with the encoder compile (PR 2 precedent): now
-# that the pallas variants REALLY compile (the ISSUE-3 xfail used to
-# short-circuit them), the five Mosaic window compiles cost ~8 min on
-# this container — measured 2026-08-04 blowing the 870 s tier-1 budget
-# mid-suite (DOTS 483 -> 225). The fast tier keeps the same kernel's
-# interpret-mode parity + engine identity coverage
-# (tests/test_ragged_attention.py).
 @pytest.mark.slow
 @pytest.mark.parametrize('backend', ['pallas', 'xla'])
 def test_decode_window_compiles_for_tpu(v5e, backend):

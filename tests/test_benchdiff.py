@@ -1,7 +1,8 @@
 """Bench trajectory gate (``scripts/benchdiff.py``): the fast-tier smoke
-runs it over the REAL in-repo BENCH_r01/r02 records (the known
-embed/gen deltas must appear, exit 0) and over an injected regression
-(exit nonzero) — the acceptance shape of the ISSUE 11 tentpole."""
+runs it over two driver-shaped records built in ``tmp_path`` — one that
+crashed before emitting, one clean full record (the known embed/gen
+deltas must appear, exit 0) — and over an injected regression (exit
+nonzero) — the acceptance shape of the ISSUE 11 tentpole."""
 
 from __future__ import annotations
 
@@ -11,12 +12,58 @@ import subprocess
 import sys
 from pathlib import Path
 
+import pytest
+
 REPO = Path(__file__).resolve().parent.parent
 BENCHDIFF = REPO / 'scripts' / 'benchdiff.py'
 
 _spec = importlib.util.spec_from_file_location('benchdiff', BENCHDIFF)
 benchdiff = importlib.util.module_from_spec(_spec)
 _spec.loader.exec_module(benchdiff)
+
+
+# The two record shapes the driver writes: a run that died before its
+# contract line (rc 1, traceback tail, nothing parsed) and a clean one.
+_R01 = {
+    'n': 1,
+    'cmd': 'if [ -f bench.py ]; then python bench.py; else exit 0; fi',
+    'rc': 1,
+    'tail': 'Traceback (most recent call last):\n  ...\n'
+    'jaxlib.xla_extension.XlaRuntimeError: UNAVAILABLE\n',
+    'parsed': None,
+}
+_R02 = {
+    'n': 2,
+    'cmd': 'if [ -f bench.py ]; then python bench.py; else exit 0; fi',
+    'rc': 0,
+    'parsed': {
+        'metric': 'embeddings/sec/chip',
+        'value': 1619.88,
+        'unit': 'emb/s',
+        'vs_baseline': 0.585,
+        'mfu': 0.463,
+        'device': 'TPU v5 lite',
+        'gen_metric': 'gen tokens/sec/chip',
+        'gen_value': 184.18,
+        'gen_unit': 'tok/s',
+        'gen_vs_baseline': 0.093,
+        'gen_mfu': 0.0135,
+        'gen_n_tokens': 8192,
+        'gen_attn_backend': 'xla',
+    },
+}
+_R02['tail'] = json.dumps(_R02['parsed']) + '\n'
+
+
+@pytest.fixture
+def records(tmp_path):
+    """``(r01_path, r02_path)`` written under ``tmp_path``."""
+    paths = []
+    for name, record in (('BENCH_r01.json', _R01), ('BENCH_r02.json', _R02)):
+        path = tmp_path / name
+        path.write_text(json.dumps(record, indent=2))
+        paths.append(path)
+    return tuple(paths)
 
 
 def _run(*args):
@@ -26,11 +73,11 @@ def _run(*args):
     )
 
 
-def test_real_r01_r02_records_pass_and_report_known_deltas():
+def test_r01_r02_records_pass_and_report_known_deltas(records):
     """r01 crashed before emitting (no metrics); r02 is the last clean
     full record: 1619.88 emb/s and 184.18 tok/s appear as new metrics,
     and a new metric is never a regression."""
-    proc = _run(REPO / 'BENCH_r01.json', REPO / 'BENCH_r02.json')
+    proc = _run(records[0], records[1])
     assert proc.returncode == 0, proc.stdout + proc.stderr
     out = proc.stdout
     assert '| value |' in out and '1619.88' in out
@@ -42,7 +89,7 @@ def test_real_r01_r02_records_pass_and_report_known_deltas():
     assert 'r01' in out and 'no metrics' in out
 
 
-def test_injected_regression_exits_nonzero(tmp_path):
+def test_injected_regression_exits_nonzero(tmp_path, records):
     fake = {
         'n': 6,
         'rc': 0,
@@ -57,7 +104,7 @@ def test_injected_regression_exits_nonzero(tmp_path):
     candidate = tmp_path / 'BENCH_r06.json'
     candidate.write_text(json.dumps(fake))
     proc = _run(
-        REPO / 'BENCH_r01.json', REPO / 'BENCH_r02.json', candidate,
+        records[0], records[1], candidate,
         '--markdown', tmp_path / 'trajectory.md',
     )
     assert proc.returncode == 1, proc.stdout + proc.stderr
@@ -123,15 +170,12 @@ def test_non_finite_metrics_never_crash_or_silently_pass(tmp_path):
     assert proc.returncode == 1
 
 
-def test_library_surface_matches_cli():
-    records = [
-        benchdiff.load_record(REPO / 'BENCH_r01.json'),
-        benchdiff.load_record(REPO / 'BENCH_r02.json'),
-    ]
-    assert records[0]['metrics'] == {}
-    assert records[1]['metrics']['value'] == 1619.88
-    assert records[1]['metrics']['gen_value'] == 184.18
-    regressions, lost = benchdiff.diff_records(records, threshold=0.05)
+def test_library_surface_matches_cli(records):
+    loaded = [benchdiff.load_record(path) for path in records]
+    assert loaded[0]['metrics'] == {}
+    assert loaded[1]['metrics']['value'] == 1619.88
+    assert loaded[1]['metrics']['gen_value'] == 184.18
+    regressions, lost = benchdiff.diff_records(loaded, threshold=0.05)
     assert regressions == [] and lost == []
     assert benchdiff.gate_direction('gen_value') == 'higher'
     assert benchdiff.gate_direction('gen_load_ttft_p95_s') == 'lower'
@@ -216,14 +260,14 @@ def test_gen_history_gate_directions():
     assert benchdiff.gate_direction('gen_history_shed_requests') is None
 
 
-def test_emit_baseline_distills_newest_usable_record(tmp_path):
+def test_emit_baseline_distills_newest_usable_record(tmp_path, records):
     """--emit-baseline (ISSUE 18 satellite): r02 is the newest record
     carrying envelope-source metrics, so its gen_value becomes the tok_s
     baseline — through the SAME extraction code the runtime sentinel
     loads, so gate and sentinel cannot disagree on what a record says."""
     out = tmp_path / 'baseline.json'
     proc = _run(
-        REPO / 'BENCH_r01.json', REPO / 'BENCH_r02.json',
+        records[0], records[1],
         '--emit-baseline', out,
     )
     assert proc.returncode == 0, proc.stdout + proc.stderr
@@ -237,10 +281,10 @@ def test_emit_baseline_distills_newest_usable_record(tmp_path):
     # record emits and exits 0 (nothing to diff), and a pile with no
     # usable metrics emits the EMPTY envelope (the sentinel's counted
     # disarm mode), never a crash.
-    solo = _run(REPO / 'BENCH_r02.json', '--emit-baseline', out)
+    solo = _run(records[1], '--emit-baseline', out)
     assert solo.returncode == 0, solo.stdout + solo.stderr
     assert json.loads(out.read_text())['source'] == 'r02'
-    empty = _run(REPO / 'BENCH_r01.json', '--emit-baseline', out)
+    empty = _run(records[0], '--emit-baseline', out)
     assert empty.returncode == 0, empty.stdout + empty.stderr
     doc = json.loads(out.read_text())
     assert doc['metrics'] == {} and doc['source'] == ''

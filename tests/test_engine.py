@@ -931,7 +931,7 @@ def test_mixed_config_validation():
         EngineConfig(enable_mixed_batching=True)
     with pytest.raises(ValueError, match='>= 1'):
         EngineConfig(max_window_prefill_seqs=0)
-    # defer_prefill alone stays a legal (tunnel-only) opt-in.
+    # defer_prefill alone stays a legal opt-in.
     assert EngineConfig(defer_prefill=True).defer_prefill
 
 

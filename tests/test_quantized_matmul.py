@@ -2,8 +2,7 @@
 
 The serving claim under test: ``int8_dense`` computes the same thing as
 ``x @ QTensor.dequantize()`` while never materializing a float weight —
-the property that fixed the 6x-off-floor int8 decode windows
-(chipback_r05/bench_run1.json, ops/quantized_matmul.py docstring).
+the property ``ops/quantized_matmul.py``'s docstring states.
 """
 
 from __future__ import annotations

@@ -45,6 +45,29 @@ def _disarm_injector():
     injector.disarm()
 
 
+@pytest.fixture
+def _fake_clock(monkeypatch):
+    """Deterministic clock for the deadline tests: the engine's
+    ``time.monotonic`` and the injector's ``time.sleep`` share one fake
+    clock that only an injected ``slow_window`` stall advances, so a
+    deadline fires exactly when a stall was injected and never because
+    the machine (six test workers on shared cores) was slow."""
+    import types
+
+    from distllm_tpu.generate.engine import engine as engine_mod
+    from distllm_tpu.resilience import faults as faults_mod
+
+    clock = types.SimpleNamespace(now=1000.0)
+
+    def sleep(seconds):
+        clock.now += seconds
+
+    shim = types.SimpleNamespace(monotonic=lambda: clock.now, sleep=sleep)
+    monkeypatch.setattr(engine_mod, 'time', shim)
+    monkeypatch.setattr(faults_mod, 'time', shim)
+    return clock
+
+
 # ------------------------------------------------------------ faults unit
 class TestFaultInjector:
     def test_inert_by_default(self):
@@ -339,7 +362,7 @@ class TestChaosMatrix:
         )
 
     def test_slow_window_deadline_times_out_and_frees(
-        self, _disarm_injector
+        self, _disarm_injector, _fake_clock
     ):
         """A stalled window loop: the per-request deadline fires, the
         request finishes with a timeout status, and its blocks free."""
@@ -359,7 +382,9 @@ class TestChaosMatrix:
         _, _, ref = _tiny_engine()
         assert fresh == ref.generate_ids([PROMPTS[1]], GREEDY)[0]
 
-    def test_deadline_timeout_status_on_request(self, _disarm_injector):
+    def test_deadline_timeout_status_on_request(
+        self, _disarm_injector, _fake_clock
+    ):
         _disarm_injector.arm('slow_window', times=None, delay_s=0.06)
         _, _, engine = _tiny_engine(
             request_deadline_s=0.05, decode_steps=2, **RECOVER

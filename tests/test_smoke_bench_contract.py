@@ -40,7 +40,6 @@ def _bench_env(tmp_path: Path, **extra: str) -> dict[str, str]:
         DISTLLM_BENCH_SMALL='1',
         DISTLLM_BENCH_RECORD_DIR=str(tmp_path),
         DISTLLM_BENCH_BUNDLE_DIR=str(tmp_path / 'bundles'),
-        DISTLLM_BENCH_PROBE_ATTEMPTS='1',
         DISTLLM_BENCH_WATCHDOG_S='0',
     )
     env.update(extra)
@@ -111,8 +110,8 @@ def test_bench_sigterm_mid_stage_still_emits_contract_line(tmp_path):
 
 def test_bench_stage_timeout_truncates_but_never_zeroes(tmp_path):
     """A stage blowing its budget is killed; earlier stages' metrics and
-    the final contract line survive, with the timeout recorded — and the
-    probe satellite: every backend-probe attempt's outcome lands in the
+    the final contract line survive, with the timeout recorded and a
+    non-zero exit code — and the backend probe's outcome lands in the
     record (and therefore in the final line)."""
     proc = subprocess.run(
         [sys.executable, str(BENCH)],
@@ -129,13 +128,16 @@ def test_bench_stage_timeout_truncates_but_never_zeroes(tmp_path):
         ),
         cwd=REPO,
     )
-    assert proc.returncode == 0, proc.stderr[-800:]
+    # A stage that ended in *_error makes the exit code non-zero; the
+    # contract line is printed all the same.
+    assert proc.returncode != 0, proc.stderr[-800:]
     result = _last_json_line(proc.stdout)
     assert result['value'] > 0
     assert result['stages_completed'] == ['embed']
     assert 'timed out' in result['gen_error']
     assert 'interrupted' not in result  # normal exit, not a signal
-    # Probe-ladder satellite: attempts recorded with outcomes.
+    # The one backend probe is recorded with its outcome.
+    assert len(result['probe_attempts']) == 1
     attempt = result['probe_attempts'][0]
     assert attempt['outcome'] == 'ok'
     assert attempt['platform'] == 'cpu'
