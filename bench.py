@@ -236,7 +236,7 @@ def _measure_load_ttft(engine, prompts, probe_prompt, sampling,
     """TTFT of a request injected while the engine is mid-stream at full
     decode batch (``gen_load_ttft_s``) — the interference number mixed
     batching exists to improve: standalone prefill dispatches serialize
-    between decode windows (probe_gen, builder notes of 2026, in git history), so a request
+    between decode windows (scripts/probe_gen.py looks at this), so a request
     arriving under load pays its prefill AGAINST the running stream.
 
     Saturates the batch via ``step()``, waits until every slot is
@@ -1029,8 +1029,7 @@ def _stage_gen_kernel() -> dict:
 
     - tok/s per arm (``gen_kernel_xla_tok_s`` /
       ``gen_kernel_pallas_tok_s``) and their ratio
-      (``gen_kernel_speedup``) — the headline the ROADMAP's r5
-      1101 tok/s isolated-window rate is measured against;
+      (``gen_kernel_speedup``);
     - MEASURED MFU / bandwidth utilization per arm (mean of the
       per-window ``mfu_measured``/``bw_util_measured`` flight fields —
       ``compiled.cost_analysis()`` truth, docs/observability.md) next to
@@ -1265,9 +1264,11 @@ def _stage_gen_load() -> dict:
         return {f'{prefix}skipped': 'DISTLLM_BENCH_LOAD=0'}
     small = bool(os.environ.get('DISTLLM_BENCH_SMALL'))
     if small:
+        # fp32, as in gen_tier and gen_router: the identity check then
+        # holds whichever prefill kernel a request's timing hands it.
         model_cfg = mistral.MistralConfig(
             vocab_size=2048, hidden_size=256, num_layers=4, num_heads=8,
-            num_kv_heads=4, intermediate_size=512, dtype='bfloat16',
+            num_kv_heads=4, intermediate_size=512, dtype='float32',
         )
         # max_model_len 128 keeps the CPU-smoke compile ladder at four
         # prefill buckets — warmup dominates this stage's fast-tier cost.
@@ -1313,17 +1314,17 @@ def _stage_gen_load() -> dict:
     workload = build_workload(load_cfg)
     on = run_loadgen(engine, workload)
     # Attribution must be pure host-side bookkeeping: the SAME workload
-    # replayed on the SAME engine with attribution OFF and ON again must
-    # emit the same tokens. The measured run above is not one of the two
-    # arms: it started on a cold prefix cache, and in bf16 a prompt served
-    # from cached blocks (paged tail prefill) and the same prompt
-    # prefilled whole differ in the last bits, enough to flip a greedy
-    # near-tie. Both replays start on the cache that run left warm.
+    # replayed on the SAME engine with attribution OFF must emit the same
+    # tokens. Both runs start on an empty prefix cache: a prompt served
+    # from cached blocks takes the paged tail prefill and the same prompt
+    # on a cold cache the dense one, two kernels whose bf16 outputs differ
+    # in the last bits (engine._admit says so), enough to flip a greedy
+    # near-tie. With no request live every cached block is evictable.
+    engine._evict_cached_blocks(engine_cfg.num_blocks)
     engine.attribution = False
     off = run_loadgen(engine, workload)
     engine.attribution = True
-    on_replay = run_loadgen(engine, workload)
-    identical = on_replay.tokens_by_request == off.tokens_by_request
+    identical = on.tokens_by_request == off.tokens_by_request
 
     out = {
         f'{prefix}metric': 'open-loop load generation',

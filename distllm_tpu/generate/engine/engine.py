@@ -100,6 +100,12 @@ class SamplingParams:
 _DRAIN = object()
 
 
+def _round_sig(value: float, digits: int = 4) -> float:
+    """Round a utilization to significant digits, not decimal places: a
+    slow window's MFU of 3e-6 must not read as 0.0 in the record."""
+    return float(f'{value:.{digits}g}')
+
+
 def _request_seed(
     engine_seed: int, request_id: int, explicit: int | None
 ) -> int:
@@ -3118,8 +3124,8 @@ class LLMEngine:
                 acc['hbm_bytes'] += cost.hbm_bytes
                 extra = {
                     **extra,
-                    'mfu': round(mfu, 5),
-                    'bw_util': round(bw_util, 5),
+                    'mfu': _round_sig(mfu),
+                    'bw_util': _round_sig(bw_util),
                 }
                 # Measured twin (observability/xla_cost.py): the same
                 # window priced from what XLA actually compiled, plus the
@@ -3150,8 +3156,8 @@ class LLMEngine:
                     )
                     extra = {
                         **extra,
-                        'mfu_measured': round(m_mfu, 5),
-                        'bw_util_measured': round(m_bw, 5),
+                        'mfu_measured': _round_sig(m_mfu),
+                        'bw_util_measured': _round_sig(m_bw),
                     }
         usable = self.config.num_blocks - 1  # block 0 is reserved
         self.flight.record(
@@ -3201,14 +3207,13 @@ class LLMEngine:
             out[kind] = {
                 'windows': int(acc['windows']),
                 'seconds': round(seconds, 4),
-                'mfu': round(
-                    acc['flops'] / seconds / self._cost_model.peak_flops, 5
+                'mfu': _round_sig(
+                    acc['flops'] / seconds / self._cost_model.peak_flops
                 ),
-                'bw_util': round(
+                'bw_util': _round_sig(
                     acc['hbm_bytes']
                     / seconds
-                    / self._cost_model.peak_hbm_bytes,
-                    5,
+                    / self._cost_model.peak_hbm_bytes
                 ),
             }
         return out

@@ -233,8 +233,13 @@ class TpuGenerator:
             params = shard_pytree(
                 params, family.param_specs(model_cfg, params), mesh
             )
+        # The context limit is the engine's, not the tokenizer file's: a
+        # decoder checkpoint's tokenizer carries HF's "unset" sentinel,
+        # which HFTokenizer reads as the encoder default of 512 and would
+        # cut every longer prompt to.
         tokenizer = HFTokenizer(
             config.tokenizer_name or config.pretrained_model_name_or_path,
+            model_max_length=config.max_model_len,
             trust_remote_code=config.trust_remote_code,
         )
         # vLLM parity: checkpoints commonly carry EOS (or EXTRA stop ids

@@ -66,7 +66,7 @@ def dense(
     int8 2-D kernels never dequantize at all: they route through
     :func:`distllm_tpu.ops.quantized_matmul.int8_dense`, which keeps the
     weight int8 across HBM (scale applied to the dot's OUTPUT, convert
-    fused into the weight stream). Measured motivation and tier choice in
+    fused into the weight stream). Motivation and tier choice are in
     that module's docstring. ``qmm_backend`` pins the tier for THIS call;
     ``None`` falls back to the process default
     (``DISTLLM_QMM_BACKEND=auto|pallas|xla|interpret``, read at import) at
@@ -98,7 +98,7 @@ def gelu(x: jnp.ndarray) -> jnp.ndarray:
     Checkpoints trained with erf-GELU get erf-GELU — dtype does not change
     the activation math. Deployments that want the cheaper polynomial opt
     in explicitly with the ``'gelu_tanh'`` activation name (see
-    :func:`gelu_tanh` for the measured trade).
+    :func:`gelu_tanh` for the trade).
     """
     return jax.nn.gelu(x, approximate=False)
 
@@ -107,8 +107,9 @@ def gelu_tanh(x: jnp.ndarray) -> jnp.ndarray:
     """Opt-in tanh-approximated GELU (the HF ``gelu_pytorch_tanh`` form).
 
     The exact erf lowers to a long VPU polynomial that costs 19% of a
-    BERT-base embed forward on a v5e (measured: MFU 0.622 exact vs 0.790
-    tanh, builder record of 2026-07-31, in git history). The tanh form's max
+    BERT-base embed forward on a v5e (MFU 0.622 exact vs 0.790 tanh in a
+    2026-07-31 record on older code, in git history; not re-measured, a
+    hypothesis). The tanh form's max
     deviation from erf-GELU is ~3e-3 near |x|=2 — the same order as bf16's
     representation step there, so it is a REAL (if small) numerics change,
     not a free lunch; that is why it is an explicit activation choice

@@ -35,7 +35,7 @@ def bucket_ladder(
     (120-260 tokens); with length-sorted batching only a handful of rungs are
     ever touched, so the compile count stays small. (The 256-384 range used
     to step by 64: the 320 rung alone cost ~23% padding on 260-token chunk
-    tails — measured, BENCH r2 embed breakdown.)
+    tails in an old embed breakdown, not re-measured.)
 
     ``scheme='pow2'`` (serving prefill): pure doubling — at most
     ``log2(max_length)`` compiled prefill programs, since at serving time

@@ -3,8 +3,8 @@
 Why not XLA SDPA here: at the embed pipeline's hot shape ([512, 256],
 12 heads) XLA materializes the masked ``[B, N, S, S]`` score/softmax
 tensors in HBM — ~0.8 GB per intermediate per layer, several GB of HBM
-traffic that caps the whole forward at ~0.43 MFU (measured,
-``scripts/probe_attn.py``). Why not ``jax.experimental.pallas.ops.tpu.
+traffic that capped the whole forward at ~0.43 MFU in a 2026-07-31
+``scripts/probe_attn.py`` record on older code (not re-measured). Why not ``jax.experimental.pallas.ops.tpu.
 flash_attention``: its ``MIN_BLOCK_SIZE = 128`` forces sequence lengths to
 multiples of 128, which conflicts with the fine bucket ladder (160/224/320
 rungs) that keeps embed padding waste low (``models/tokenizer.py
@@ -31,9 +31,10 @@ Reference parity note: the reference gets this op from flash-attn/SDPA
 inside HF models (``distllm/embed/encoders/auto.py:119-138``, faesm for
 ESM); this is the TPU-native equivalent (SURVEY.md section 2.4 N3).
 
-Routing policy (data: ``scripts/probe_encoder_matrix.py`` on a v5e,
-2026-07-31, builder record of 2026-07-31, in git history; constant token
-budget B*S = 128k per forward):
+Routing policy. The numbers are a ``scripts/probe_encoder_matrix.py``
+record of 2026-07-31 on older code (in git history), NOT re-measured on
+today's code — hypotheses that explain the policy, not results (constant
+token budget B*S = 128k per forward):
 
 - bert-base S=160..512: kernel 538-557k tok/s vs XLA 364-445k
   (+21-52%), and the kernel is FLAT across the bucket ladder where XLA

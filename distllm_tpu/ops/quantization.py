@@ -96,8 +96,8 @@ class QTensor:
         ``self.shape`` only for the trailing dims. Scanning the quantized
         tree is what lets dequantization happen per layer inside the layer
         scan: dequantizing the full 7B stack outside the scan materializes
-        ~13 GiB of bf16 HLO temps and OOMs a 16 GiB chip (measured,
-        BENCH r3 gen_q attempt 1).
+        ~13 GiB of bf16 HLO temps and OOMs a 16 GiB chip (seen once on
+        older code, 2026-07-31 notes in git history; not re-checked).
         """
         if self.kind == 'int8':
             # q keeps the weight's own shape (sliced or not); scale is

@@ -1,29 +1,30 @@
 """Weight-only int8 matmul: dequantize in VMEM, not in HBM.
 
-Why this exists (measured): ``common.dense`` used to call
+Why this exists: ``common.dense`` used to call
 ``QTensor.dequantize()`` and feed the bf16 result to the dot. Inside the
 unrolled decode loop XLA materializes both the converted weight AND the
 scale-multiplied copy in HBM — per layer, per step. The int8 serving run
-that motivated this (builder record of 2026-07-31, in git history) decoded 16-step
+that motivated this (a 2026-07-31 record on older code, in git history;
+none of this module's numbers is re-measured) decoded 16-step
 windows in 1242 ms at batch 128 against a ~200 ms weights+KV streaming
 floor: the "quantized" model was streaming ~3x the bytes of the bf16 one.
 
 The fix has two tiers, chosen by :func:`int8_dense`:
 
 - **XLA scale-after-dot** — the tier ``'auto'`` always picks, because it
-  WINS on hardware: ``(x @ q.astype(dtype)) * scale`` is algebraically
+  won in that record: ``(x @ q.astype(dtype)) * scale`` is algebraically
   identical to ``x @ (q * scale)`` (the int8 scale is per-OUTPUT-channel;
   `quantization.quantize_int8` reduces only the input dim), the full-size
   elementwise multiply on the weight is gone, and XLA fuses the int8→bf16
-  convert into the dot's weight stream. Measured at the 7B unrolled
-  16-step decode window (builder record of 2026-07-31, in git history): 315 ms at
+  convert into the dot's weight stream. In the same record, at the 7B
+  unrolled 16-step decode window: 315 ms at
   batch 32 = 1623 tok/s, vs 465 ms bf16 and 1242 ms for the old
   dequant-before-dot serving path.
 - **Pallas kernel** (:func:`int8_matmul_pallas`): streams int8 tiles
   HBM->VMEM, converts in VMEM, applies the per-output-channel scale once
   to the fp32 accumulator at the last K step. Kept for explicit selection
-  and as the substrate for future fused variants, but it LOSES to the XLA
-  tier everywhere measured (same log: 720 ms/window at batch 32, 1676 ms
+  and as the substrate for future fused variants, but it lost to the XLA
+  tier everywhere that record looked (same log: 720 ms/window at batch 32, 1676 ms
   at batch 128; 5.4x slower than bf16 on the 4096x32000 lm_head, where
   its 256-wide N tiles yield 2000 grid steps) — so 'auto' never picks it.
 
@@ -183,8 +184,8 @@ def int8_dense(
 ) -> jnp.ndarray:
     """``x @ dequant(q, scale)`` for a 2-D int8 QTensor, any leading dims.
 
-    ``backend``: 'auto' == 'xla' (scale-after-dot — measured fastest tier,
-    module docstring), 'pallas' / 'interpret' force the Pallas kernel
+    ``backend``: 'auto' == 'xla' (scale-after-dot — the fastest tier in
+    the old record, module docstring), 'pallas' / 'interpret' force the Pallas kernel
     (compiled / interpret mode).
     """
     if backend not in BACKENDS:

@@ -203,9 +203,9 @@ def pack_sign_bits(embeddings: np.ndarray) -> np.ndarray:
 
 
 # Corpora past this row count switch the per-chunk candidate selection
-# from exact lax.top_k (a full bitonic sort over the chunk — measured
-# 12.5 s for one 10M-row ubinary scan, builder record of 2026-07-31, in git history) to the TPU-native
-# jax.lax.approx_max_k (~0.95 per-element recall). Quantized-tier
+# from exact lax.top_k (a full bitonic sort over the chunk — 12.5 s for
+# one 10M-row ubinary scan in a 2026-07-31 record on older code, in git
+# history; not re-measured) to the TPU-native jax.lax.approx_max_k (~0.95 per-element recall). Quantized-tier
 # candidates feed an oversampled fp32 rescore, so serving quality is set
 # by top1/rescore behavior, not the last near-tie in the candidate set.
 APPROX_TOPK_MIN_ROWS = 1 << 20
@@ -223,10 +223,9 @@ def group_rows(arr: np.ndarray, chunk: int) -> np.ndarray:
 
     Do this ONCE at index build: the grouped tensors ride a single-
     dispatch ``lax.scan`` whose chunk slabs are contiguous scan slices.
-    Measured on the chip at 10M x 768 int8: 32 ms/scan grouped vs
-    seconds for the python slice-per-chunk loop over a monolithic
-    device array (builder record of 2026-07-31, in git history and the
-    prof_slice experiments behind it).
+    A 2026-07-31 record on older code (in git history; not re-measured)
+    has 32 ms/scan grouped at 10M x 768 int8 against seconds for the
+    python slice-per-chunk loop over a monolithic device array.
     """
     n = arr.shape[0]
     pad = (-n) % chunk

@@ -202,8 +202,8 @@ class TpuIndexV2:
             # Packed bits are corpus/32 bytes — assemble on host, GROUP
             # into [G, chunk, H/8] (ops/topk.group_rows), then one
             # device_put: the grouped layout rides hamming_topk's single-
-            # dispatch lax.scan (~32 ms at 10M rows vs seconds for a
-            # sliced-chunk loop — builder record of 2026-07-31, in git history). NO second fp32 host
+            # dispatch lax.scan (ops/topk.group_rows has the old record's
+            # numbers; not re-measured). NO second fp32 host
             # copy: rescore candidates are gathered per query batch from
             # the arrow-mmap'd dataset.
             self._packed = jnp.asarray(group_rows(

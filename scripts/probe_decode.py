@@ -2,7 +2,7 @@
 
 probe_gen times the full serving loop; this times ONE fused decode window
 dispatch in isolation across the knobs that matter, to localize the gap
-between the measured window time and the ~283 ms weight-streaming floor
+between the window time on the chip and the ~283 ms weight-streaming floor
 (14.5 GB x 16 steps / 819 GB/s):
 
 - attention backend: pallas vs xla

@@ -1,15 +1,15 @@
 """Localize the decode-window gap WITHOUT hardware: AOT cost analysis.
 
-r3 measured the bf16 batch-32 fused 16-step window at ~845 ms on chip vs
-the ~283 ms weight-streaming floor (builder notes of 2026, in git history) and the chip died
-before scripts/probe_decode.py could run. The compiled executable itself
-can testify meanwhile: compile the exact serving window against the v5e
+A 2026-07-31 record on older code (in git history; not re-measured, a
+hypothesis) had the bf16 batch-32 fused 16-step window at ~845 ms against
+the ~283 ms weight-streaming floor. The compiled executable itself can
+testify without a chip: compile the exact serving window against the v5e
 topology (libtpu, no chip) and read
 
 - ``cost_analysis()`` bytes accessed -> a bandwidth-bound time prediction
   (bytes / 819 GB/s). If this lands near the floor, the compiled graph is
   fine and the gap is runtime-side (dispatch stalls, host latency). If it
-  lands near the measured 845 ms, the extra HBM traffic is IN the graph —
+  lands near those 845 ms, the extra HBM traffic is IN the graph —
   and the HLO says which ops carry it.
 - HLO op census: copies / transposes / all-to-alls and the largest
   fusions, to name the traffic carriers.

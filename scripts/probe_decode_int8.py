@@ -5,7 +5,8 @@ unrolled window, 4 windows chained, one host sync) but with int8-quantized weigh
 serving batch (128, 2840 blocks — bench gen_q dims) and the bf16 batch
 (32) for cross-reference.
 
-Context (builder record of 2026-07-31, in git history): run 1 served int8 via dequant-before-dot at
+Context (a 2026-07-31 record on older code, in git history; not
+re-measured, a hypothesis): run 1 served int8 via dequant-before-dot at
 1242 ms/window; run 2 picked up the Pallas in-VMEM-dequant kernel and got
 SLOWER (2046 ms). The isolated-matmul probe can't see why (dispatch-bound
 at 1.3 ms/call), so this times the real window per tier. Floor at batch

@@ -4,7 +4,8 @@ The AOT census of the bench's fused embed graph (B=512, S=256 bf16
 BERT-base) shows the exact-erf GELU lowering as fp32 elementwise chains
 over the [B, S, 3072] intermediate and fp32 LayerNorm stats — VPU work
 and conversion traffic that may explain the 0.58-0.63 steady-state MFU
-plateau (builder notes of 2026, in git history). This measures the forward with each
+plateau of a 2026-07-31 record on older code (in git history; not
+re-measured). This measures the forward with each
 suspect ablated, on the real chip:
 
 - full         : production graph

@@ -1,6 +1,7 @@
 """Generation-loop breakdown on the real chip.
 
-bf16 7B at batch 32 measured 605 tok/s (BENCH r3 interim) against a
+A 2026-07-31 record on older code (in git history; not re-measured, a
+hypothesis) had bf16 7B at batch 32 at 605 tok/s against a
 ~1,800 tok/s weight-bandwidth roofline (14.5 GB reads / 819 GB/s * batch
 32 * 16-step window => >=283 ms/window floor). This instruments the
 pipelined loop to see where the other ~550 ms/window goes: host-side

@@ -11,7 +11,8 @@ the chip. Variants:
                  (|err| <= ~0.5% relative; the fp32 rescore absorbs it)
 
 Hypothesis under test: XLA TPU emulates integer dots (the 10M ubinary
-scan measured seconds, not the ~50 ms its byte traffic predicts); bf16
+scan took seconds in a 2026-07-31 record on older code, not the ~50 ms
+its byte traffic predicts; not re-measured); bf16
 keeps the scan on the native MXU path.
 """
 

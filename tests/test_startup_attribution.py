@@ -323,12 +323,7 @@ def test_measured_gauges_and_ratios_published_per_step():
     for record in decode:
         assert record['mfu_measured'] > 0
         assert record['bw_util_measured'] > 0
-        # The record rounds to five places, which a slow window on a
-        # loaded test machine rounds to 0.0; the gauge keeps the value.
-        assert record['mfu'] >= 0
-    from distllm_tpu.observability import instruments
-
-    assert instruments.ENGINE_MFU.labels(kind='decode').value > 0
+        assert record['mfu'] > 0
     # Prefill dispatches at varying (batch, bucket) shapes: the priced
     # largest-shape executable must NOT be published over their wall
     # time (it would inflate by the shape ratio) — cost is visible via
