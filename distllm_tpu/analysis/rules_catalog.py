@@ -335,3 +335,54 @@ class CompilePhaseCatalogRule(_CatalogRule):
                     f'compile phase {first.value!r} is not registered in '
                     'instruments.COMPILE_PHASES',
                 )
+
+
+@register
+class StepSpanCatalogRule(_CatalogRule):
+    """Every serving-path step span the package opens (a string literal —
+    or a conditional between string literals — as the first argument of a
+    ``.mark(...)`` / ``.inside(...)`` / ``._span(...)`` call, ``observability/steps.py``
+    ``StepSpan``) must be registered in ``instruments.STEP_SPANS``. The
+    span is a ``distllm:<name>`` annotation that trace reductions group
+    idle gaps by; a name minted at a call site would be a gap nobody's
+    reader knows."""
+
+    id = 'step-span-catalog'
+    description = 'step span missing from instruments.STEP_SPANS'
+    catalog_label = 'step-span'
+
+    def catalog(self, project: Project) -> frozenset[str]:
+        return project.frozenset_catalog('STEP_SPANS')
+
+    def check(self, source: SourceFile, project: Project):
+        assert source.tree is not None
+        registered = self.catalog(project)
+        if not registered:
+            return
+        for node in source.nodes():
+            if not (isinstance(node, ast.Call) and node.args):
+                continue
+            func = node.func
+            if not (
+                isinstance(func, ast.Attribute)
+                and func.attr in ('mark', 'inside', '_span')
+            ):
+                continue
+            first = node.args[0]
+            branches = (
+                (first.body, first.orelse)
+                if isinstance(first, ast.IfExp)
+                else (first,)
+            )
+            for branch in branches:
+                if (
+                    isinstance(branch, ast.Constant)
+                    and isinstance(branch.value, str)
+                    and branch.value not in registered
+                ):
+                    yield self.diag(
+                        source,
+                        node.lineno,
+                        f'step span {branch.value!r} is not registered in '
+                        'instruments.STEP_SPANS',
+                    )

@@ -56,6 +56,7 @@ from distllm_tpu.generate.engine.scheduler import (
 from distllm_tpu.models import mistral
 from distllm_tpu.models.tokenizer import bucket_ladder, pick_bucket
 from distllm_tpu.observability import instruments as _metrics
+from distllm_tpu.observability import steps as _steps
 from distllm_tpu.observability import xla_cost as _xla_cost
 from distllm_tpu.observability.flight import get_flight_recorder
 from distllm_tpu.observability.startup import (
@@ -195,6 +196,16 @@ class Request:
     t_admit: float = 0.0
     t_first_token: float = 0.0
     t_finish: float = 0.0
+    # --- prefill/preemption accounting ('request' flight record) ---
+    # Counted where it happens: every prefill dispatch the request rode
+    # (by route: dense, paged, chunk, or mixed for a chunk that rode a
+    # decode window), the tokens it prefilled there (re-prefill after
+    # preemption included), the seconds of those steps up to its first
+    # token, and how often it was preempted.
+    preemptions: int = 0
+    prefill_tokens: int = 0
+    prefill_first_s: float = 0.0
+    routes: dict = field(default_factory=dict)
     # Propagated request id (the server's X-Request-Id), captured from
     # tracing.request_scope at add_request; carried on the 'request'
     # flight record so one id correlates server spans, engine lifecycle,
@@ -546,9 +557,9 @@ class EngineConfig(BaseConfig):
     # distllm_engine_bandwidth_utilization). Pure host-side bookkeeping —
     # token output is bit-identical on vs off (the gen_load bench stage
     # asserts it). Off sheds the record fields, profiler annotations, and
-    # roofline math; the raw time.monotonic() reads at the dispatch sites
-    # stay (nanoseconds — gating them would complicate every window path
-    # for nothing measurable).
+    # roofline math; the step spans' time.monotonic() reads stay
+    # (nanoseconds — gating them would complicate every window path for
+    # nothing measurable).
     attribution: bool = True
     # Metric-history sampler (docs/observability.md "Metric history &
     # sampling"): > 0 makes THIS engine own a background
@@ -629,7 +640,7 @@ class LLMEngine:
         # phase it died in. The first engine in a process also pays (and
         # attributes) the real backend init here; later calls are
         # near-instant cache-hit records.
-        self._compile_watcher = get_compile_watcher()
+        self._compile_watcher = get_compile_watcher().listen()
         # Per-engine dedup scope: a rebuilt engine's jit wrappers really
         # recompile, so its phases must start cold in the watcher.
         self._compile_scope = self._compile_watcher.new_scope()
@@ -694,6 +705,9 @@ class LLMEngine:
         )
         self._requests: dict[int, Request] = {}
         self._next_id = itertools.count()
+        # Step counter: the ``seq`` of every step span (flight records and
+        # distllm: annotations alike, docs/observability.md).
+        self._step_seq = itertools.count()
         self._finished: dict[int, Request] = {}
         # Serving-loop counters (windows, prefill dispatches, EOS-overshoot
         # waste); generate_ids folds them into ``telemetry`` per run so the
@@ -1392,7 +1406,7 @@ class LLMEngine:
 
         Every shape in the ladder runs under a compile-watcher phase
         (docs/observability.md "Startup & compile attribution"): one
-        ``compile`` flight record + ``distllm_compile_seconds{kind,shape}``
+        ``compile`` flight record + ``distllm_compile_seconds{kind,shape,path}``
         observation per (kind, batch, bucket), cache-hit marked on the
         re-warmup / persistent-cache fast paths — so a 22–45 min cold
         warmup (or a wedge inside it) is attributable shape by shape.
@@ -1419,13 +1433,17 @@ class LLMEngine:
                 with watch.phase(
                     'prefill', f'b{b}x{bucket}{qtag}', scope=self._compile_scope
                 ):
-                    logits, k_all, v_all = self._prefill(
+                    # Through _call, as in serving: a program lowered again
+                    # there is compared with the signature it had here.
+                    logits, k_all, v_all = self._call(
+                        self._prefill,
                         self.params,
                         self._put(ids),
                         self._put(mask),
                         self._put(last_pos),
                     )
-                    self.kv.k, self.kv.v = self._write_prefill(
+                    self.kv.k, self.kv.v = self._call(
+                        self._write_prefill,
                         self.kv.k,
                         self.kv.v,
                         k_all,
@@ -1458,7 +1476,8 @@ class LLMEngine:
                             np.ones((b,), np.int32),
                             np.zeros((b,), np.int32),
                         )
-                        pg_logits, self.kv.k, self.kv.v = self._prefill_paged(
+                        pg_logits, self.kv.k, self.kv.v = self._call(
+                            self._prefill_paged,
                             self.params,
                             ids_dev,
                             pos_dev,
@@ -2229,7 +2248,7 @@ class LLMEngine:
         quantized = isinstance(k_dev, QuantizedKV)
         t_fetch = time.monotonic()
         ks_host = vs_host = None
-        with self._annotate('fetch'):
+        with self._span('fetch'):
             # distlint: disable=host-sync-in-hot-path -- the spill tier's ONE designed fetch point: evicted ref==0 blocks must cross to host RAM before their pool blocks are reused, and eviction only fires on pool-pressure shortfalls
             k_host = np.asarray(k_dev.data if quantized else k_dev)
             # distlint: disable=host-sync-in-hot-path -- second half of the same designed spill fetch (V plane of the one padded gather above)
@@ -2354,7 +2373,7 @@ class LLMEngine:
                 v_dev = QuantizedKV(v_dev, vs_dev)
             else:
                 k_dev, v_dev, idx_dev = self._put_many(k_host, v_host, idx)
-            with self._annotate('promote'):
+            with self._span('promote'):
                 self.kv.k, self.kv.v = self._write_promoted(
                     self.kv.k, self.kv.v, k_dev, v_dev, idx_dev
                 )
@@ -2435,7 +2454,7 @@ class LLMEngine:
             if not block and not token.is_ready():
                 continue  # still in flight; keep overlapping
             t_wait = time.monotonic()
-            with self._annotate('fetch'):
+            with self._span('fetch'):
                 # distlint: disable=host-sync-in-hot-path -- the promotion path's ONE designed completion sync: a one-element probe of the post-scatter pool proves the promoted KV landed before the tail prefill (and any decode window) reads it
                 np.asarray(token)
             wait_s = time.monotonic() - t_wait
@@ -2733,7 +2752,8 @@ class LLMEngine:
         sampled token is discarded.
         """
         _metrics.ENGINE_PREFILL_BATCH.observe(len(requests))
-        t_start = time.monotonic()
+        step = self._begin_step()
+        step.mark('plan')
         b = 1
         while b < len(requests):
             b *= 2
@@ -2750,7 +2770,7 @@ class LLMEngine:
             lengths[i] = len(prompt)
             block_rows[i] = self._block_row(request.request_id)
 
-        t_host = time.monotonic()
+        step.mark('put')
         (
             ids_dev,
             mask_dev,
@@ -2758,33 +2778,37 @@ class LLMEngine:
             block_rows_dev,
             lengths_dev,
         ) = self._put_many(ids, mask, last_pos, block_rows, lengths)
-        t_put = time.monotonic()
-        with self._annotate('prefill'):
-            last_logits, k_all, v_all = self._prefill(
-                self.params, ids_dev, mask_dev, last_pos_dev
-            )
-            self.kv.k, self.kv.v = self._write_prefill(
-                self.kv.k,
-                self.kv.v,
-                k_all,
-                v_all,
-                block_rows_dev,
-                lengths_dev,
-            )
-        t_dispatch = time.monotonic()
+        step.mark('prefill')
+        last_logits, k_all, v_all = self._call(
+            self._prefill, self.params, ids_dev, mask_dev, last_pos_dev
+        )
+        self.kv.k, self.kv.v = self._call(
+            self._write_prefill,
+            self.kv.k,
+            self.kv.v,
+            k_all,
+            v_all,
+            block_rows_dev,
+            lengths_dev,
+        )
+        step.mark('emit')
         # Full prompt blocks just entered the paged cache — adopt them
         # into the prefix cache BEFORE emission (a max_tokens=1 request
         # finishes inside _emit_prefill, after which its row is gone).
         for request in requests:
             self._insert_prompt_blocks(request)
-        emitted = self._emit_prefill(requests, last_logits, b, defer_to)
+        self._note_prefill(
+            [(r, int(n)) for r, n in zip(requests, lengths)], 'dense'
+        )
+        emitted = self._emit_prefill(
+            requests, last_logits, b, defer_to, step
+        )
+        step.close()
+        self._note_prefill_seconds(requests, step.t1 - step.t0, step.t0)
         self._record_step(
-            'prefill', t_start, batch=len(requests),
-            tokens=int(lengths.sum()),
-            **self._attribution_fields(
-                t_start, t_host, t_put, t_dispatch,
-                rids=[r.request_id for r in requests],
-            ),
+            'prefill', step, batch=len(requests),
+            tokens=int(lengths.sum()), route='dense',
+            **self._rids_field(requests),
         )
         return emitted
 
@@ -2794,6 +2818,7 @@ class LLMEngine:
         last_logits,
         b: int,
         defer_to,
+        step: _steps.StepSpan,
     ) -> list[tuple[int, int]]:
         """Sample + emit each prefilled request's first token.
 
@@ -2804,7 +2829,11 @@ class LLMEngine:
             b - len(requests)
         )
         if defer_to is None:
+            # The synchronous path's host sync: the device runs the
+            # prefill while the host waits here.
+            step.mark('fetch')
             tokens = np.asarray(self._sample_device(last_logits, slots))
+            step.mark('emit')
             emitted = []
             for i, request in enumerate(requests):
                 token = int(tokens[i])
@@ -2903,7 +2932,8 @@ class LLMEngine:
             _metrics.ENGINE_PREFILL_CHUNK_TOKENS.observe(ntok)
             emitted.extend(
                 self._dispatch_prefill_paged(
-                    [(request, start, ntok)], bucket, defer_to, sample=final
+                    [(request, start, ntok)], bucket, defer_to, sample=final,
+                    route='chunk',
                 )
             )
             start += ntok
@@ -2917,13 +2947,14 @@ class LLMEngine:
         bucket: int,
         defer_to=None,
         sample: bool = True,
+        route: str = 'paged',
     ) -> list[tuple[int, int]]:
         """Paged-path prefill with the recovery contract (see
         ``_run_prefill_batch``): mark-for-retry on failure, then raise."""
         try:
             self._faults.fail('dispatch')
             return self._dispatch_prefill_paged_inner(
-                spans, bucket, defer_to, sample
+                spans, bucket, defer_to, sample, route
             )
         except Exception:
             self._mark_prefill_retry([r for r, _, _ in spans])
@@ -2935,6 +2966,7 @@ class LLMEngine:
         bucket: int,
         defer_to=None,
         sample: bool = True,
+        route: str = 'paged',
     ) -> list[tuple[int, int]]:
         """One padded paged-context prefill dispatch.
 
@@ -2942,19 +2974,22 @@ class LLMEngine:
         K/V lands in the request's own blocks at absolute positions, and
         its queries attend to everything before them through the paged
         cache. ``sample=False`` (intermediate chunks) skips emission.
+        ``route`` names the record's route: ``paged`` (a tail behind
+        cached blocks) or ``chunk`` (one chunk of a long tail).
         """
         requests = [r for r, _, _ in spans]
         _metrics.ENGINE_PREFILL_BATCH.observe(len(requests))
         self._stats['prefill_dispatches'] += 1
         _metrics.ENGINE_PREFILL_DISPATCHES.inc()
-        t_start = time.monotonic()
+        step = self._begin_step()
+        step.mark('plan')
         b = 1
         while b < len(spans):
             b *= 2
         ids, positions, block_rows, context_lens, tail_lens = (
             self._span_host_arrays(spans, bucket, b)
         )
-        t_host = time.monotonic()
+        step.mark('put')
         (
             ids_dev,
             positions_dev,
@@ -2964,36 +2999,34 @@ class LLMEngine:
         ) = self._put_many(
             ids, positions, block_rows, context_lens, tail_lens
         )
-        t_put = time.monotonic()
-        with self._annotate('prefill'):
-            last_logits, self.kv.k, self.kv.v = self._prefill_paged(
-                self.params,
-                ids_dev,
-                positions_dev,
-                self.kv.k,
-                self.kv.v,
-                block_rows_dev,
-                context_lens_dev,
-                tail_lens_dev,
-            )
-        t_dispatch = time.monotonic()
-        attrib = self._attribution_fields(
-            t_start, t_host, t_put, t_dispatch,
-            rids=[r.request_id for r in requests],
+        step.mark('prefill')
+        last_logits, self.kv.k, self.kv.v = self._call(
+            self._prefill_paged,
+            self.params,
+            ids_dev,
+            positions_dev,
+            self.kv.k,
+            self.kv.v,
+            block_rows_dev,
+            context_lens_dev,
+            tail_lens_dev,
         )
-        chunk_tokens = int(tail_lens.sum())
-        if not sample:
-            self._record_step(
-                'prefill', t_start, batch=len(requests),
-                tokens=chunk_tokens, **attrib,
+        step.mark('emit')
+        self._note_prefill([(r, ntok) for r, _, ntok in spans], route)
+        emitted: list[tuple[int, int]] = []
+        if sample:
+            for request in requests:
+                self._insert_prompt_blocks(request)
+            emitted = self._emit_prefill(
+                requests, last_logits, b, defer_to, step
             )
-            return []
-        for request in requests:
-            self._insert_prompt_blocks(request)
-        emitted = self._emit_prefill(requests, last_logits, b, defer_to)
+        step.close()
+        self._note_prefill_seconds(requests, step.t1 - step.t0, step.t0)
         self._record_step(
-            'prefill', t_start, batch=len(requests), tokens=chunk_tokens,
-            **attrib,
+            'prefill', step, batch=len(requests),
+            tokens=int(tail_lens.sum()), route=route,
+            kv_blocks=self._kv_blocks(context_lens),
+            **self._rids_field(requests),
         )
         return emitted
 
@@ -3038,52 +3071,105 @@ class LLMEngine:
             self.sched.lend_prefix(rid, lent)
             request.num_borrowed_blocks = lent
 
-    def _annotate(self, kind: str):
-        """``jax.profiler.TraceAnnotation`` around a dispatch when
-        attribution is on: profiler captures (``DISTLLM_BENCH_PROFILE``)
-        then carry a ``distllm:<kind>`` host slice over every device
-        launch, tying XPlane device time back to engine step kinds."""
-        if not self.attribution:
-            return contextlib.nullcontext()
-        try:
-            return jax.profiler.TraceAnnotation(f'distllm:{kind}')
-        # distlint: disable=swallowed-exception -- annotations are optional decoration on profiler-less backends; the nullcontext fallback changes no behavior and profiler availability is reported by the capture layer
-        except Exception:  # pragma: no cover - profiler-less backends
-            return contextlib.nullcontext()
+    def _begin_step(self) -> _steps.StepSpan:
+        """The span of a new step (observability/steps.py): its ``mark``
+        calls are the ONE set of clock reads behind both the flight
+        record's split (``host_s``, ``put_s``, ``dispatch_s``, ``fetch_s``,
+        ``emit_s``, ``admit_s``) and the ``distllm:<span>`` annotations of
+        a profiler capture. Attribution off keeps the reads and opens no
+        annotation."""
+        return _steps.StepSpan(next(self._step_seq), self.attribution)
 
-    def _attribution_fields(
-        self, t_start, t_host, t_put, t_dispatch, *, fetch_s=None, rids=None,
-    ) -> dict:
-        """The device/host step split for one flight record (empty when
-        attribution is off): ``host_s`` (plan build), ``put_s``
-        (host→device transfer), ``dispatch_s`` (jit call; async backends
-        return before the device finishes), plus ``fetch_s`` (device→host
-        token fetch, where pipelined in-flight time surfaces) and the
-        participating ``rids`` when the caller knows them."""
+    def _begin_root(self) -> _steps.StepSpan:
+        """The root of the span tree, ``distllm:serve``, open over one
+        ``step()`` call or one pass of the pipelined loop: an idle gap of
+        the device that runs through several phases, none of which holds
+        half of it, still falls under an engine span."""
+        root = self._begin_step()
+        root.mark('serve')
+        return root
+
+    @contextlib.contextmanager
+    def _span(self, name: str):
+        """A lone ``distllm:<name>`` span outside any step record (tier
+        spill fetch, promotion scatter and its completion sync)."""
+        step = self._begin_step()
+        step.mark(name)
+        try:
+            yield step
+        finally:
+            step.close()
+
+    def _kv_blocks(self, *context_lens: np.ndarray) -> int:
+        """KV blocks one step of the paged kernel reads for a dispatch's
+        rows (its ``context_lens`` host arrays): the blocks that hold each
+        row's context (a pad row has a context of one token and reads the
+        trash block). Worked out when the step's record is written, after
+        its spans have closed."""
+        bs = self.config.block_size
+        return sum(int(((c + (bs - 1)) // bs).sum()) for c in context_lens)
+
+    def _rids_field(self, requests: list[Request]) -> dict:
         if not self.attribution:
             return {}
-        fields = {
-            'host_s': round(t_host - t_start, 6),
-            'put_s': round(t_put - t_host, 6),
-            'dispatch_s': round(t_dispatch - t_put, 6),
-        }
-        if fetch_s is not None:
-            fields['fetch_s'] = round(fetch_s, 6)
-        if rids is not None:
-            fields['rids'] = list(rids)
-        return fields
+        return {'rids': [r.request_id for r in requests]}
 
-    def _record_step(self, kind: str, t_start: float, *, batch: int,
-                     tokens: int, **extra) -> None:
+    @staticmethod
+    def _note_prefill(pairs: list[tuple[Request, int]], route: str) -> None:
+        """Count one prefill dispatch on each request that rides it
+        (before emission: a request may finish at its first token)."""
+        for request, ntok in pairs:
+            request.prefill_tokens += ntok
+            request.routes[route] = request.routes.get(route, 0) + 1
+
+    @staticmethod
+    def _note_prefill_seconds(requests: list[Request], seconds: float,
+                              t0: float) -> None:
+        """Charge a closed prefill step (or a window that carried chunk
+        rows) to those of its requests still without a first token
+        (``prefill_first_s``): a re-prefill after preemption is not part
+        of the wait for the first token."""
+        for request in requests:
+            if not request.t_first_token or request.t_first_token >= t0:
+                request.prefill_first_s += seconds
+
+    @staticmethod
+    def _call(fn, *args):
+        """Run one of the engine's jit entry points, leaving its function
+        and arguments where the compile watcher finds them: a compile
+        that fires inside the call records the arguments' signature
+        (shape, dtype, weak type, committed, sharding, layout). A call
+        that compiles nothing pays for one thread-local store."""
+        _steps.set_call(fn, args)
+        try:
+            return fn(*args)
+        finally:
+            _steps.set_call(None, None)
+
+    def _sched_gauges(self) -> dict:
+        """Scheduler state stamped on a step record."""
+        usable = self.config.num_blocks - 1  # block 0 is reserved
+        return {
+            'queue_depth': self.sched.num_waiting,
+            'running': self.sched.num_running,
+            'kv_occupancy': round(
+                (usable - self.sched.num_free_blocks) / usable, 4
+            ) if usable > 0 else 0.0,
+        }
+
+    def _record_step(self, kind: str, step: _steps.StepSpan, *, batch: int,
+                     tokens: int, duration_s: float | None = None,
+                     gauges: dict | None = None, **extra) -> None:
         """One flight-ring record + metrics pair per engine step.
 
-        ``duration_s`` for prefill is the host-side dispatch (+ sync
-        emission on the synchronous path); for decode/mixed it spans
-        dispatch → host fetch, so pipelined in-flight time is included —
-        the wall clock a stalled window would actually burn. ``extra``
-        carries kind-specific fields (the ``mixed`` kind adds
-        prefill_tokens/prefill_rows; with attribution on, every kind adds
-        the host/put/dispatch/fetch timing split).
+        ``duration_s`` for prefill is the whole step (plan to the end of
+        emission); for decode/mixed/spec the caller passes dispatch ->
+        host fetch, so pipelined in-flight time is included — the wall
+        clock a stalled window would actually burn. ``extra`` carries
+        kind-specific fields (the ``mixed`` kind adds
+        prefill_tokens/prefill_rows). With attribution on, the closed
+        ``step`` adds ``seq``, ``t0_s``/``t1_s`` and the seconds of its
+        child spans (docs/observability.md "Serving-path spans").
 
         With attribution on, the analytic roofline prices the step
         (observability/roofline.py) and the record carries ``mfu`` /
@@ -3091,7 +3177,10 @@ class LLMEngine:
         ``distllm_engine_mfu`` / ``distllm_engine_bandwidth_utilization``
         gauges and the per-kind ``roofline_summary()`` accumulators.
         """
-        duration_s = time.monotonic() - t_start
+        if duration_s is None:
+            duration_s = step.t1 - step.t0
+        if self.attribution:
+            extra = {**extra, **step.fields()}
         _metrics.ENGINE_STEPS.labels(kind=kind).inc()
         _metrics.ENGINE_STEP_SECONDS.labels(kind=kind).observe(duration_s)
         # EWMA-measured TTFT-predictor inputs (resilience/admission.py),
@@ -3159,18 +3248,13 @@ class LLMEngine:
                         'mfu_measured': _round_sig(m_mfu),
                         'bw_util_measured': _round_sig(m_bw),
                     }
-        usable = self.config.num_blocks - 1  # block 0 is reserved
         self.flight.record(
             kind,
             duration_s=round(duration_s, 6),
             batch=batch,
             occupancy=round(batch / self.config.max_num_seqs, 4),
             tokens=tokens,
-            queue_depth=self.sched.num_waiting,
-            running=self.sched.num_running,
-            kv_occupancy=round(
-                (usable - self.sched.num_free_blocks) / usable, 4
-            ) if usable > 0 else 0.0,
+            **(gauges if gauges is not None else self._sched_gauges()),
             **extra,
         )
 
@@ -3248,16 +3332,26 @@ class LLMEngine:
         requests' ``output_ids``.
         """
         emitted: list[tuple[int, int]] = []
+        root = self._begin_root()
         try:
             self._expire_deadlines()
+            # The iteration's span: admission, then the window it plans,
+            # dispatches, fetches and emits (prefill dispatches inside
+            # admission are steps of their own).
+            span = self._begin_step()
+            span.mark('admit')
             emitted = self._admit()
             if self.sched.num_running == 0:
+                span.close()
                 return emitted
-            window = self._dispatch_window(None)
-            if window is not _DRAIN:
+            window = self._dispatch_window(None, span)
+            if window is _DRAIN:
+                span.close()
+            else:
                 emitted.extend(self._process_window(window))
             return emitted
         except Exception as exc:
+            _steps.abandon()
             # A sync step has no in-flight deque: whatever window the
             # failed step dispatched is lost with its device-side tokens.
             # Clear the unacked lag and roll chunk progress back (the
@@ -3272,6 +3366,8 @@ class LLMEngine:
             if not self._recover(exc):
                 raise
             return emitted
+        finally:
+            root.close()
 
     def _window_budget(self, request: Request, unacked: int, k: int) -> int:
         """Tokens this request may still generate in a new window, after
@@ -3324,7 +3420,9 @@ class LLMEngine:
             short += max(0, target - len(self.sched.block_row(rid)))
         return short
 
-    def _dispatch_window(self, carried_ids) -> dict | object:
+    def _dispatch_window(
+        self, carried_ids, step: _steps.StepSpan
+    ) -> dict | object:
         """Plan and dispatch one fused decode window (no host sync).
 
         ``carried_ids`` is the previous window's device-side last-token
@@ -3335,7 +3433,10 @@ class LLMEngine:
         and dispatch through the fused mixed executable. Returns the
         in-flight window record, or ``_DRAIN`` when every running slot's
         budget is already covered by in-flight windows AND no chunk work
-        is pending (caller should process one).
+        is pending (caller should process one). ``step`` is the loop
+        iteration's span, open since admission: planning starts its
+        ``distllm:plan`` child here, and the window record carries it to
+        ``_process_window``.
 
         ``draft_k > 0`` routes to the speculative verify window instead
         (docs/speculative.md): one ragged dispatch scoring every row's
@@ -3343,13 +3444,13 @@ class LLMEngine:
         they process synchronously, so host state is always current.
         """
         if self.config.draft_k:
-            return self._dispatch_spec_window()
+            return self._dispatch_spec_window(step)
         # Injection site 'dispatch' (docs/resilience.md): fires BEFORE
         # any state mutation (key split, unacked counts, chunk progress),
         # so a recovery retry replans from unchanged state — the
         # simulation boundary for an XLA dispatch raise.
         self._faults.fail('dispatch')
-        t_start = time.monotonic()
+        step.mark('plan')
         k = self.config.decode_steps
         kmax = self._window_kmax()
         decode_rids = None
@@ -3375,31 +3476,19 @@ class LLMEngine:
             # Eviction pressure beats preemption: unreferenced cached
             # blocks are free capacity, so spend those before recompute-
             # preempting a running sequence.
-            self._evict_cached_blocks(
-                self._reserve_shortfall(kmax) - self.sched.num_free_blocks
-            )
+            short = self._reserve_shortfall(kmax) - self.sched.num_free_blocks
+            short -= self._evict_cached_blocks(short)
             if self._faults.fire('sched_exhausted') is not None:
                 # Injection site 'sched_exhausted': the pool-pressure
                 # hazard, without needing a pool actually sized to hit it.
                 raise SchedulerExhausted(
                     'injected scheduler exhaustion', preempted=[]
                 )
-            try:
-                preempted = self.sched.prepare_decode(kmax, decode_rids)
-            except SchedulerExhausted as exc:
-                # Preemptions performed before the fatal exhaustion are not
-                # rolled back; sync their states so a caller that catches
-                # and continues sees engine state consistent with the
-                # scheduler.
-                for rid in exc.preempted:
-                    self._on_preempt(self._requests[rid])
-                raise
-            for rid in preempted:
-                # The pipelined loop drains in-flight windows before any
-                # dispatch that could preempt, so victims never have
-                # unacked device-side tokens OR in-flight chunk writes;
-                # recompute preemption re-prefills them.
-                self._on_preempt(self._requests[rid])
+            # The pipelined loop drains in-flight windows before any
+            # dispatch that could preempt, so victims never have unacked
+            # device-side tokens OR in-flight chunk writes; recompute
+            # preemption re-prefills them.
+            self._prepare_decode(step, short, kmax, decode_rids)
         # A chunk-only window (no decode-ready rows) skips prepare_decode
         # entirely: chunk writes land in admission-granted blocks, so it
         # must neither allocate nor preempt. Planned AFTER preemption so
@@ -3455,11 +3544,14 @@ class LLMEngine:
             ids, override_mask, positions, context_lens, block_tables,
             steps_left, temperature, top_p, min_p, top_k, seeds,
         ]
+        context_arrays = [context_lens]
         if chunk_plan:
-            host_arrays.extend(self._build_chunk_arrays(chunk_plan))
-        t_host = time.monotonic()
+            chunk_arrays = self._build_chunk_arrays(chunk_plan)
+            context_arrays.append(chunk_arrays[3])
+            host_arrays.extend(chunk_arrays)
+        step.mark('put')
         devs = self._put_many(*host_arrays)
-        t_put = time.monotonic()
+        step.mark('mixed' if chunk_plan else 'decode')
         (
             ids_dev,
             override_dev,
@@ -3478,29 +3570,29 @@ class LLMEngine:
         chunk_tokens = None
         chunk_entries: list[tuple[int, int, int, int, bool]] = []
         if chunk_plan:
-            with self._annotate('mixed'):
-                (
-                    tokens,
-                    self.kv.k,
-                    self.kv.v,
-                    last_ids,
-                    chunk_tokens,
-                ) = self._mixed_window(
-                    self.params,
-                    ids_dev,
-                    positions_dev,
-                    context_lens_dev,
-                    self.kv.k,
-                    self.kv.v,
-                    block_tables_dev,
-                    steps_left_dev,
-                    temperature_dev,
-                    top_p_dev,
-                    min_p_dev,
-                    top_k_dev,
-                    seeds_dev,
-                    *devs[11:],
-                )
+            (
+                tokens,
+                self.kv.k,
+                self.kv.v,
+                last_ids,
+                chunk_tokens,
+            ) = self._call(
+                self._mixed_window,
+                self.params,
+                ids_dev,
+                positions_dev,
+                context_lens_dev,
+                self.kv.k,
+                self.kv.v,
+                block_tables_dev,
+                steps_left_dev,
+                temperature_dev,
+                top_p_dev,
+                min_p_dev,
+                top_k_dev,
+                seeds_dev,
+                *devs[11:],
+            )
             ridden = 0
             for i, (request, start, ntok) in enumerate(chunk_plan):
                 request.prefill_sent = start + ntok
@@ -3516,22 +3608,22 @@ class LLMEngine:
             _metrics.MIXED_PREFILL_TOKENS_PER_WINDOW.observe(ridden)
             _metrics.MIXED_PREFILL_ROWS.observe(len(chunk_plan))
         else:
-            with self._annotate('decode'):
-                tokens, self.kv.k, self.kv.v, last_ids = self._decode_window(
-                    self.params,
-                    ids_dev,
-                    positions_dev,
-                    context_lens_dev,
-                    self.kv.k,
-                    self.kv.v,
-                    block_tables_dev,
-                    steps_left_dev,
-                    temperature_dev,
-                    top_p_dev,
-                    min_p_dev,
-                    top_k_dev,
-                    seeds_dev,
-                )
+            tokens, self.kv.k, self.kv.v, last_ids = self._call(
+                self._decode_window,
+                self.params,
+                ids_dev,
+                positions_dev,
+                context_lens_dev,
+                self.kv.k,
+                self.kv.v,
+                block_tables_dev,
+                steps_left_dev,
+                temperature_dev,
+                top_p_dev,
+                min_p_dev,
+                top_k_dev,
+                seeds_dev,
+            )
         for _, rid, steps in plan:
             if steps:
                 self._unacked[rid] = self._unacked.get(rid, 0) + steps
@@ -3540,20 +3632,23 @@ class LLMEngine:
         _metrics.ENGINE_DECODE_UTILIZATION.observe(
             sum(1 for _, _, steps in plan if steps > 0) / b
         )
+        # The window is in flight: its span resumes at the fetch.
+        t_dispatch = step.pause()
         return {
             'tokens': tokens,
             'plan': plan,
             'last_ids': last_ids,
-            't_dispatch': time.monotonic(),
+            't_dispatch': t_dispatch,
             'chunk_tokens': chunk_tokens,
             'chunk_plan': chunk_entries,
-            # Attribution: the plan/put/dispatch split, completed with the
-            # fetch time when _process_window syncs the tokens.
-            'timing': (t_start, t_host, t_put, time.monotonic()),
+            'context_lens': context_arrays,
+            # The step's span so far (admit/plan/put/dispatch), completed
+            # with fetch and emit when _process_window syncs the tokens.
+            'step': step,
         }
 
     # ------------------------------------------- speculative verify windows
-    def _dispatch_spec_window(self) -> dict | object:
+    def _dispatch_spec_window(self, step: _steps.StepSpan) -> dict | object:
         """Plan and dispatch one speculative verify window
         (docs/speculative.md).
 
@@ -3570,7 +3665,7 @@ class LLMEngine:
         can ride.
         """
         self._faults.fail('dispatch')  # same site as the classic window
-        t_start = time.monotonic()
+        step.mark('plan')
         cfg = self.config
         draft_k = cfg.draft_k
         drafts_by_rid: dict[int, list[int]] = {}
@@ -3599,26 +3694,17 @@ class LLMEngine:
             # tokens of coverage; 1 keeps the classic single-step floor.
             row_ks.append(max(1, len(drafts)))
         if decode_rids:
-            self._evict_cached_blocks(
-                self._reserve_shortfall(
-                    1, row_ks=dict(zip(decode_rids, row_ks))
-                )
-                - self.sched.num_free_blocks
-            )
-            try:
-                preempted = self.sched.prepare_decode(
-                    1, decode_rids, row_ks
-                )
-            except SchedulerExhausted as exc:
-                for rid in exc.preempted:
-                    self._on_preempt(self._requests[rid])
-                raise
-            for rid in preempted:
-                # Spec windows process synchronously, so victims never
-                # have in-flight tokens; recompute preemption re-prefills
-                # them (preemption mid-draft: the un-dispatched draft is
-                # simply dropped with the rest of the row's state).
-                self._on_preempt(self._requests[rid])
+            short = self._reserve_shortfall(
+                1, row_ks=dict(zip(decode_rids, row_ks))
+            ) - self.sched.num_free_blocks
+            short -= self._evict_cached_blocks(short)
+            # Spec windows process synchronously, so victims never have
+            # in-flight tokens; recompute preemption re-prefills them
+            # (preemption mid-draft: the un-dispatched draft is simply
+            # dropped with the rest of the row's state).
+            for rid in self._prepare_decode(
+                step, short, 1, decode_rids, row_ks
+            ):
                 drafts_by_rid.pop(rid, None)
         chunk_plan = self._plan_window_chunks()
 
@@ -3665,33 +3751,34 @@ class LLMEngine:
             ids, positions, block_rows, context_lens, tail_lens,
             temperature, top_p, min_p, top_k, seeds,
         ]
+        context_arrays = [context_lens]
         if chunk_plan:
-            host_arrays.extend(self._build_chunk_arrays(chunk_plan))
-        t_host = time.monotonic()
+            chunk_arrays = self._build_chunk_arrays(chunk_plan)
+            context_arrays.append(chunk_arrays[3])
+            host_arrays.extend(chunk_arrays)
+        step.mark('put')
         devs = self._put_many(*host_arrays)
-        t_put = time.monotonic()
+        step.mark('spec')
         chunk_tokens = None
         chunk_entries: list[tuple[int, int, int, int, bool]] = []
         if chunk_plan:
-            with self._annotate('spec'):
-                tokens, self.kv.k, self.kv.v, chunk_tokens = (
-                    self._spec_mixed_window(
-                        self.params,
-                        devs[0],  # span ids
-                        devs[1],  # span positions
-                        devs[3],  # context_lens
-                        self.kv.k,
-                        self.kv.v,
-                        devs[2],  # block tables
-                        devs[4],  # span_lens
-                        devs[5],
-                        devs[6],
-                        devs[7],
-                        devs[8],  # top_k
-                        devs[9],  # seeds
-                        *devs[10:],
-                    )
-                )
+            tokens, self.kv.k, self.kv.v, chunk_tokens = self._call(
+                self._spec_mixed_window,
+                self.params,
+                devs[0],  # span ids
+                devs[1],  # span positions
+                devs[3],  # context_lens
+                self.kv.k,
+                self.kv.v,
+                devs[2],  # block tables
+                devs[4],  # span_lens
+                devs[5],
+                devs[6],
+                devs[7],
+                devs[8],  # top_k
+                devs[9],  # seeds
+                *devs[10:],
+            )
             ridden = 0
             for i, (request, start, ntok) in enumerate(chunk_plan):
                 request.prefill_sent = start + ntok
@@ -3709,37 +3796,39 @@ class LLMEngine:
             _metrics.MIXED_PREFILL_TOKENS_PER_WINDOW.observe(ridden)
             _metrics.MIXED_PREFILL_ROWS.observe(len(chunk_plan))
         else:
-            with self._annotate('spec'):
-                tokens, self.kv.k, self.kv.v, _ = self._spec_window(
-                    self.params,
-                    devs[0],
-                    devs[1],
-                    devs[3],
-                    self.kv.k,
-                    self.kv.v,
-                    devs[2],
-                    devs[4],
-                    devs[5],
-                    devs[6],
-                    devs[7],
-                    devs[8],
-                    devs[9],
-                )
+            tokens, self.kv.k, self.kv.v, _ = self._call(
+                self._spec_window,
+                self.params,
+                devs[0],
+                devs[1],
+                devs[3],
+                self.kv.k,
+                self.kv.v,
+                devs[2],
+                devs[4],
+                devs[5],
+                devs[6],
+                devs[7],
+                devs[8],
+                devs[9],
+            )
         ndrafted = sum(len(d) for _, _, d in plan)
         self._stats['spec_windows'] += 1
         self._stats['spec_draft_tokens'] += ndrafted
         _metrics.SPEC_WINDOWS.inc()
         if ndrafted:
             _metrics.SPEC_DRAFT_TOKENS.inc(ndrafted)
+        t_dispatch = step.pause()
         return {
             'spec': True,
             'tokens': tokens,
             'plan': plan,
             'chunk_tokens': chunk_tokens,
             'chunk_plan': chunk_entries,
-            't_dispatch': time.monotonic(),
+            't_dispatch': t_dispatch,
             'last_ids': None,
-            'timing': (t_start, t_host, t_put, time.monotonic()),
+            'context_lens': context_arrays,
+            'step': step,
         }
 
     def _process_spec_window(self, window: dict) -> list[tuple[int, int]]:
@@ -3763,11 +3852,12 @@ class LLMEngine:
         needs no rollback — it sits at positions every later dispatch
         overwrites before attending or masks out).
         """
-        t_fetch = time.monotonic()
-        with self._annotate('fetch'):
-            # distlint: disable=host-sync-in-hot-path -- the spec window's ONE designed fetch point: emission needs the verified tokens + accept length on host, and spec windows process synchronously (depth 1)
-            tokens = np.asarray(window['tokens'])  # [B, S+1] packed
-        fetch_s = time.monotonic() - t_fetch
+        step = window['step']
+        step.mark('fetch')
+        # distlint: disable=host-sync-in-hot-path -- the spec window's ONE designed fetch point: emission needs the verified tokens + accept length on host, and spec windows process synchronously (depth 1)
+        tokens = np.asarray(window['tokens'])  # [B, S+1] packed
+        window['duration_s'] = step.mark('emit') - window['t_dispatch']
+        gauges = self._sched_gauges()
         emitted: list[tuple[int, int]] = []
         drafted = accepted = rows = 0
         sampled_rows = resampled = 0
@@ -3820,20 +3910,58 @@ class LLMEngine:
                 n for *_, n, _ in chunk_entries
             )
             extra['prefill_rows'] = len(chunk_entries)
-        if window.get('timing'):
-            ts, th, tp, td = window['timing']
-            extra.update(self._attribution_fields(
-                ts, th, tp, td, fetch_s=fetch_s,
-            ))
-        self._record_step(
-            'spec', window['t_dispatch'], batch=rows, tokens=len(emitted),
-            **extra,
-        )
+        ntokens = len(emitted)
         emitted.extend(self._process_chunk_entries(window))
+        step.close()
+        self._record_step(
+            'spec', step, batch=rows, tokens=ntokens,
+            duration_s=window['duration_s'], gauges=gauges,
+            kv_blocks=self._kv_blocks(*window['context_lens']), **extra,
+        )
         return emitted
 
-    def _on_preempt(self, request: Request) -> None:
+    def _prepare_decode(self, step: _steps.StepSpan, short: int, k: int,
+                        rids, ks=None) -> list[int]:
+        """``sched.prepare_decode`` and the sync of its victims; under a
+        ``distllm:preempt`` span when blocks are still ``short`` after
+        eviction, which is when it preempts. Preemptions performed before
+        a fatal exhaustion are not rolled back: their states are synced
+        too, so a caller that catches and continues sees engine state
+        consistent with the scheduler."""
+        span = step.inside('preempt') if short > 0 else contextlib.nullcontext()
+        with span:
+            try:
+                preempted = self.sched.prepare_decode(k, rids, ks)
+            except SchedulerExhausted as exc:
+                self._note_preempted(exc.preempted, k, step)
+                raise
+            self._note_preempted(preempted, k, step)
+        return preempted
+
+    def _note_preempted(self, rids: list[int], k: int,
+                        step: _steps.StepSpan) -> None:
+        """Sync the victims of one ``prepare_decode`` and write its
+        ``preempt`` record: per rid the tokens whose KV was freed and must
+        be prefilled again."""
+        if not rids:
+            return
+        lost = [self._on_preempt(self._requests[rid]) for rid in rids]
+        self.flight.record(
+            'preempt',
+            rids=list(rids),
+            tokens_lost=lost,
+            k=k,
+            free_blocks=self.sched.num_free_blocks,
+            running=self.sched.num_running,
+            queue_depth=self.sched.num_waiting,
+            seq=step.seq,
+        )
+
+    def _on_preempt(self, request: Request) -> int:
+        """Fold one recompute preemption into the request; returns the
+        tokens it lost (``num_tokens`` less the cached prefix it keeps)."""
         request.state = RequestState.WAITING
+        request.preemptions += 1
         # A promotion in flight for the victim is simply dropped: its
         # scatter is already dispatched (ordering protects later readers)
         # and the blocks it adopted are borrowed — preemption keeps them,
@@ -3857,6 +3985,7 @@ class LLMEngine:
         # distlint: disable=swallowed-exception -- membership-probe control flow: the victim simply was not mid-prefill, nothing degraded
         except ValueError:
             pass
+        return request.num_tokens - request.num_cached_tokens
 
     def _process_window(self, window: dict) -> list[tuple[int, int]]:
         """Fetch one window's tokens (the only host sync in the decode
@@ -3872,32 +4001,19 @@ class LLMEngine:
         # where a wedged device fetch would, so watchdogs and per-request
         # deadlines see exactly what they would see in production.
         self._faults.maybe_sleep('slow_window')
-        t_fetch = time.monotonic()
-        with self._annotate('fetch'):
-            # distlint: disable=host-sync-in-hot-path -- the window loop's ONE designed fetch point: processing happens a window late, after the next dispatch is already in flight (pipeline_depth hides this sync)
-            tokens = np.asarray(window['tokens'])  # [K, B]
-        fetch_s = time.monotonic() - t_fetch
+        # A deferred prefill's fetch record carries no step of its own
+        # (its prefill record is written): its two spans ride a bare one.
+        recorded = 'step' in window
+        step = window['step'] if recorded else self._begin_step()
+        step.mark('fetch')
+        # distlint: disable=host-sync-in-hot-path -- the window loop's ONE designed fetch point: processing happens a window late, after the next dispatch is already in flight (pipeline_depth hides this sync)
+        tokens = np.asarray(window['tokens'])  # [K, B]
+        t_fetched = step.mark('emit')
         emitted: list[tuple[int, int]] = []
         chunk_entries = window.get('chunk_plan') or []
-        if 't_dispatch' in window:  # prefill fetch records carry no clock
-            extra = {}
-            if chunk_entries:
-                extra = {
-                    'prefill_tokens': sum(n for *_, n, _ in chunk_entries),
-                    'prefill_rows': len(chunk_entries),
-                }
-            if window.get('timing'):
-                ts, th, tp, td = window['timing']
-                extra.update(self._attribution_fields(
-                    ts, th, tp, td, fetch_s=fetch_s,
-                ))
-            self._record_step(
-                'mixed' if chunk_entries else 'decode',
-                window['t_dispatch'],
-                batch=sum(1 for _, _, s in window['plan'] if s > 0),
-                tokens=sum(s for _, _, s in window['plan']),
-                **extra,
-            )
+        if recorded:
+            window['duration_s'] = t_fetched - window['t_dispatch']
+            gauges = self._sched_gauges()
         for slot, rid, steps in window['plan']:
             if rid in self._unacked:
                 self._unacked[rid] = max(0, self._unacked[rid] - steps)
@@ -3917,6 +4033,23 @@ class LLMEngine:
                     _metrics.ENGINE_OVERSHOOT_TOKENS.inc(steps - i - 1)
                     break  # finished mid-window
         emitted.extend(self._process_chunk_entries(window))
+        step.close()
+        if recorded:
+            extra = {}
+            if chunk_entries:
+                extra = {
+                    'prefill_tokens': sum(n for *_, n, _ in chunk_entries),
+                    'prefill_rows': len(chunk_entries),
+                }
+            self._record_step(
+                'mixed' if chunk_entries else 'decode',
+                step,
+                batch=sum(1 for _, _, s in window['plan'] if s > 0),
+                tokens=sum(s for _, _, s in window['plan']),
+                duration_s=window['duration_s'], gauges=gauges,
+                kv_blocks=self._kv_blocks(*window['context_lens']),
+                **extra,
+            )
         return emitted
 
     def _process_chunk_entries(self, window: dict) -> list[tuple[int, int]]:
@@ -3937,6 +4070,12 @@ class LLMEngine:
                 continue  # preempted during an abnormal drain
             request.prefill_done = max(
                 request.prefill_done, start + ntok
+            )
+            # A chunk that rode this window is a prefill dispatch of its
+            # request, on the 'mixed' route, for the window's seconds.
+            self._note_prefill([(request, ntok)], 'mixed')
+            self._note_prefill_seconds(
+                [request], window['duration_s'], window['t_dispatch']
             )
             if final:
                 # Freshly prefilled full prompt blocks enter the
@@ -4001,6 +4140,7 @@ class LLMEngine:
                 process_one()
 
         self._drain_hook = drain_one
+        root = self._begin_root()
         try:
             while self.has_unfinished or inflight:
                 if self._expired_requests():
@@ -4017,10 +4157,13 @@ class LLMEngine:
                 # join the in-flight deque instead of blocking the decode
                 # pipeline. See EngineConfig.defer_prefill for why the
                 # default is the synchronous path.
+                span = self._begin_step()
+                span.mark('admit')
                 self._admit(
                     defer_to=inflight if self.config.defer_prefill else None
                 )
                 if self.sched.num_running == 0:
+                    span.close()
                     if inflight:
                         process_one()
                     continue
@@ -4033,8 +4176,9 @@ class LLMEngine:
                     if self._evict_cached_blocks(short):
                         continue
                     process_one()
-                window = self._dispatch_window(self._carried)
+                window = self._dispatch_window(self._carried, span)
                 if window is _DRAIN:
+                    span.close()
                     if inflight:
                         process_one()
                     continue
@@ -4043,6 +4187,7 @@ class LLMEngine:
                 if len(inflight) >= depth:
                     process_one()
         except BaseException:
+            _steps.abandon()
             # Keep catch-and-continue recovery sound (the SchedulerExhausted
             # contract): fold every dispatched window back into request
             # state so no _unacked counts, device-side tokens, or in-flight
@@ -4051,6 +4196,7 @@ class LLMEngine:
                 try:
                     process_one()
                 except Exception as drain_exc:
+                    _steps.abandon()
                     # Abnormal drain: the in-flight windows cannot be
                     # folded back — their device-side tokens are lost
                     # (KV writes at positions >= num_tokens are
@@ -4076,6 +4222,7 @@ class LLMEngine:
                     request.prefill_sent = request.prefill_done
             raise
         finally:
+            root.close()
             self._drain_hook = None
 
     # ------------------------------------- crash-domain recovery (faults)
@@ -4240,8 +4387,8 @@ class LLMEngine:
         t_dev, tp_dev, mp_dev, tk_dev, sd_dev, ct_dev = self._put_many(
             temperature, top_p, min_p, top_k, seeds, counters
         )
-        return self._sample(
-            logits, t_dev, tp_dev, mp_dev, tk_dev, sd_dev, ct_dev
+        return self._call(
+            self._sample, logits, t_dev, tp_dev, mp_dev, tk_dev, sd_dev, ct_dev
         )
 
     def _emit_token(self, request: Request, token: int) -> None:
@@ -4338,6 +4485,19 @@ class LLMEngine:
             # this one record (t_wall is the finish instant).
             e2e_s=round(request.t_finish - request.t_enqueue, 6),
             cached_tokens=request.num_cached_tokens,
+            # Counted where it happened (docs/observability.md
+            # "Serving-path spans"): preemptions, the prefill dispatches
+            # it rode by route, all tokens prefilled for it (re-prefill
+            # included), the seconds of the prefill steps before its
+            # first token, and admission and first token on the step
+            # records' clock.
+            preemptions=request.preemptions,
+            prefill_tokens=request.prefill_tokens,
+            routes=dict(request.routes),
+            prefill_first_s=round(request.prefill_first_s, 6),
+            t_admit_s=round(request.t_admit, 6) if request.t_admit else None,
+            t_first_s=round(request.t_first_token, 6)
+            if request.t_first_token else None,
         )
 
     # -------------------------------------------------------------- offline

@@ -652,15 +652,10 @@ class InstrumentedScheduler:
             )
             raise
         if preempted:
+            # The 'preempt' flight record is the engine's to write
+            # (LLMEngine._note_preempted): only it knows the tokens each
+            # victim loses.
             self._m.SCHED_PREEMPTIONS.inc(len(preempted))
-            self._flight.record(
-                'preempt',
-                rids=list(preempted),
-                k=k,
-                free_blocks=self._inner.num_free_blocks,
-                running=self._inner.num_running,
-                queue_depth=self._inner.num_waiting,
-            )
         self._sync()
         return preempted
 

@@ -381,15 +381,18 @@ COMPILE_SECONDS = _registry.histogram(
     'distllm_compile_seconds',
     'Wall time per startup/compile phase (observability/startup.py), by '
     'phase kind and shape label — the warmup ladder, backend init, '
-    'weight-layout migration, and quantization made attributable.',
-    labelnames=('kind', 'shape'),
+    'weight-layout migration, and quantization made attributable '
+    '(path="startup") — and per program compiled on the serving path '
+    '(path="serving": kind is the step span the compile fell in, shape '
+    'the program).',
+    labelnames=('kind', 'shape', 'path'),
     buckets=log_buckets(1e-3, 3600.0),
 )
 COMPILE_CACHE_HITS = _registry.counter(
     'distllm_compile_cache_hits_total',
     'Compile phases served from a cache fast path: repeat (kind, shape) '
-    'in this process, or zero new persistent-compilation-cache entries '
-    'while a cache dir is configured.',
+    'in this process, or every program the phase compiled came out of '
+    "the persistent compilation cache (jax's own cache-hit event).",
 )
 
 # ------------------------------------------------ profiler capture helper
@@ -441,7 +444,11 @@ FLIGHT_KINDS = frozenset({
                    # (endpoint/blocks/bytes/fetch_s; docs/routing.md)
     'event',    # rare irregular events (scheduler exhaustion, ...)
     'compile',  # one startup/compile phase (observability/startup.py):
-                # backend init, warmup ladder shapes, layout migration
+                # backend init, warmup ladder shapes, layout migration —
+                # or one compiled program (jax's backend-compile event:
+                # program/duration_s/cache_hit, path startup|serving;
+                # at startup its phase/shape, on the serving path
+                # during/seq, and relowered/changed for an engine call)
     'fault',    # one injected fault firing (resilience/faults.py:
                 # site/fired/call — the chaos schedule made attributable)
     'recovery', # one serving-loop retry after a failed dispatch
@@ -454,6 +461,29 @@ FLIGHT_KINDS = frozenset({
     'regression',  # runtime sentinel firing: a live history window
                    # degraded past threshold vs the BENCH baseline
                    # envelope (metric/baseline/live/window_s fields)
+})
+
+# Catalog of serving-path step spans (observability/steps.py), beside
+# FLIGHT_KINDS: every name the engine passes to ``StepSpan.mark(...)`` /
+# ``StepSpan.inside(...)`` — the ``distllm:<span>`` host annotations of
+# the device trace, each feeding one flight field of its step's record —
+# must be listed here (enforced by tests/test_lint.py). The benchmark's
+# trace reduction groups idle gaps by these names.
+STEP_SPANS = frozenset({
+    'serve',    # root: one step() call or one pass of the pipelined loop;
+                # no field (its children hold the seconds)
+    'admit',    # _admit; its prefill steps nest inside     -> admit_s
+    'plan',     # host plan building                        -> host_s
+    'put',      # host->device transfer of the plan arrays  -> put_s
+    'prefill',  # the jit call of a prefill dispatch        -> dispatch_s
+    'decode',   # ... of a decode window                    -> dispatch_s
+    'mixed',    # ... of a chunk-carrying window            -> dispatch_s
+    'spec',     # ... of a speculative verify window        -> dispatch_s
+    'promote',  # ... of a KV-tier promotion scatter        -> dispatch_s
+    'fetch',    # device->host token fetch (the host sync)  -> fetch_s
+    'emit',     # folding fetched tokens into requests      -> emit_s
+    'preempt',  # prepare_decode when it preempts, nested in plan
+                #                                           -> preempt_s
 })
 
 # Catalog of startup/compile phase kinds (observability/startup.py),

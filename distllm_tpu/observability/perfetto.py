@@ -201,12 +201,17 @@ def to_trace_events(
             ))
         elif kind == 'compile':
             # Startup track: one slice per compile phase (warmup shapes,
-            # backend init, layout migration — observability/startup.py).
+            # backend init, layout migration — observability/startup.py)
+            # and, nested inside it or alone on the serving path, one per
+            # compiled program.
             # Deliberately NOT a host-gap window: the gap track measures
             # serving-loop idleness, not the compile ladder.
             duration = float(record.get('duration_s') or 0.0)
             start = float(t_wall) - duration
-            name = f"{record.get('phase', 'compile')}:{record.get('shape', '')}"
+            name = (
+                f"{record.get('phase') or record.get('path', 'compile')}:"
+                f"{record.get('program') or record.get('shape', '')}"
+            )
             events.append(_slice(
                 name, us(start), duration * 1e6,
                 pid, _STARTUP_TID, args, cat='startup',

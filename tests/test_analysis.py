@@ -43,6 +43,7 @@ FAKE_INSTRUMENTS = (
     "FLIGHT_KINDS = frozenset({'decode', 'prefill'})\n"
     "TRACE_EVENT_CATEGORIES = frozenset({'engine'})\n"
     "COMPILE_PHASES = frozenset({'warmup'})\n"
+    "STEP_SPANS = frozenset({'plan', 'decode'})\n"
 )
 
 
@@ -491,6 +492,28 @@ class TestCompilePhaseCatalog:
         diags = run_rules(
             "def f(w):\n    with w.phase('warmup', 'shape'):\n        pass\n",
             ['compile-phase-catalog'],
+        )
+        assert diags == []
+
+
+class TestStepSpanCatalog:
+    def test_violation(self):
+        diags = run_rules(
+            "def f(step, k):\n"
+            "    step.mark('rogue')\n"
+            "    step.mark('decode' if k else 'mixed')\n"
+            "    with step.inside('also_rogue'):\n        pass\n",
+            ['step-span-catalog'],
+        )
+        assert rule_ids_of(diags) == ['step-span-catalog'] * 3
+
+    def test_clean(self):
+        diags = run_rules(
+            "def f(step, k, name):\n"
+            "    step.mark('plan')\n"
+            "    step.mark('decode' if k else 'plan')\n"
+            "    step.mark(name)\n",
+            ['step-span-catalog'],
         )
         assert diags == []
 

@@ -31,7 +31,7 @@ from distllm_tpu.analysis.core import SYNTAX_ERROR
 
 REPO = Path(__file__).resolve().parent.parent
 
-# All twelve registered rules, enforced in tier-1. Pinned by id so a rule
+# All thirteen registered rules, enforced in tier-1. Pinned by id so a rule
 # silently falling out of the registry fails here instead of passing
 # vacuously.
 EXPECTED_RULES = frozenset(
@@ -43,6 +43,7 @@ EXPECTED_RULES = frozenset(
         'flight-kind-catalog',
         'trace-category-catalog',
         'compile-phase-catalog',
+        'step-span-catalog',
         'host-sync-in-hot-path',
         'traced-python-branch',
         'lock-discipline',

@@ -181,32 +181,97 @@ def test_compile_watcher_names_the_phase_in_progress():
     assert watch.state()['phases'][-1]['note'] == 'wedged here'
 
 
-def test_non_compiling_phase_never_claims_persistent_cache_hit(tmp_path):
-    """With a persistent compilation cache dir configured, a phase that
-    does work but no XLA compilation (compiles=False) must not read its
-    zero cache delta as a 'hit' — a cold migrate/allocate would
-    otherwise poison the warm-start evidence."""
-    old = jax.config.jax_compilation_cache_dir
-    jax.config.update('jax_compilation_cache_dir', str(tmp_path))
+def _jax_compiles(program: str, *, from_cache: bool, seconds=0.01) -> None:
+    """What jax reports when it compiles ``program`` (or loads it from the
+    persistent cache): its own monitoring events, in its own order."""
+    from jax import monitoring
+
+    if from_cache:
+        monitoring.record_event('/jax/compilation_cache/cache_hits')
+        monitoring.record_event_duration_secs(
+            '/jax/compilation_cache/cache_retrieval_time_sec', seconds / 2
+        )
+    monitoring.record_event_duration_secs(
+        '/jax/core/compile/backend_compile_duration', seconds,
+        fun_name=program,
+    )
+
+
+def test_non_compiling_phase_never_claims_persistent_cache_hit():
+    """A listening watcher marks a phase from jax's own cache events. A
+    phase that does work but no XLA compilation (compiles=False) must not
+    read "no program missed the cache" as a 'hit' — a cold
+    migrate/allocate would otherwise poison the warm-start evidence."""
+    recorder = FlightRecorder()
+    watch = CompileWatcher(recorder=recorder).listen()
     try:
-        recorder = FlightRecorder()
-        watch = CompileWatcher(recorder=recorder)
         with watch.phase('kv_allocate', 'blocks8', compiles=False):
             pass
         no_compile = _compile_records(recorder)[-1]
-        assert no_compile['persistent_cache_delta'] == 0
+        assert no_compile['programs'] == 0
         assert not no_compile['cache_hit']
-        # A COMPILING phase with zero delta IS the warm-persistent-cache
-        # fast path (nothing new was lowered to disk).
+        # A COMPILING phase whose every program came out of the
+        # persistent cache IS the warm fast path ...
         with watch.phase('decode_window', 'b1x1'):
-            pass
-        assert _compile_records(recorder)[-1]['cache_hit']
+            _jax_compiles('jit(window_fn)', from_cache=True)
+            _jax_compiles('jit(merge)', from_cache=True)
+        program, _, warm = _compile_records(recorder)[-3:]
+        assert warm['cache_hit']
+        assert (warm['programs'], warm['cache_hits']) == (2, 2)
+        # ... each program a record of its own, inside the phase's.
+        assert program['program'] == 'jit(window_fn)'
+        assert program['cache_hit'] and program['path'] == 'startup'
+        # ``phase`` means on a program's record what it means on the
+        # phase's own: the phase kind it belongs to.
+        assert (program['phase'], program['shape']) == (
+            'decode_window', 'b1x1'
+        )
+        assert 'program' not in warm and 'path' not in warm
+        # ... and one that missed is not.
+        with watch.phase('decode_window', 'b1x2'):
+            _jax_compiles('jit(window_fn)', from_cache=True)
+            _jax_compiles('jit(merge)', from_cache=False)
+        cold = _compile_records(recorder)[-1]
+        assert not cold['cache_hit']
+        assert (cold['programs'], cold['cache_hits']) == (2, 1)
+        # The cache-hit event does not leak into the next compile.
+        assert not _compile_records(recorder)[-2]['cache_hit']
         # Process-repeat still marks non-compiling phases.
         with watch.phase('kv_allocate', 'blocks8', compiles=False):
             pass
         assert _compile_records(recorder)[-1]['cache_hit']
     finally:
-        jax.config.update('jax_compilation_cache_dir', old)
+        watch.unlisten()
+
+
+def test_watcher_that_does_not_listen_knows_only_process_repeat():
+    """Without jax's events a phase cannot tell a cache load from a
+    compile, so only the process-repeat path may call it a hit (the
+    directory count that used to guess is gone)."""
+    recorder = FlightRecorder()
+    watch = CompileWatcher(recorder=recorder)
+    with watch.phase('decode_window', 'b1x1'):
+        _jax_compiles('jit(window_fn)', from_cache=True)
+    (first,) = _compile_records(recorder)  # and no program record
+    assert not first['cache_hit'] and 'programs' not in first
+    with watch.phase('decode_window', 'b1x1'):
+        pass
+    assert _compile_records(recorder)[-1]['cache_hit']
+
+
+def test_compile_outside_a_phase_is_a_serving_record():
+    recorder = FlightRecorder()
+    watch = CompileWatcher(recorder=recorder).listen()
+    try:
+        _jax_compiles('jit(prefill_paged_fn)', from_cache=False, seconds=0.2)
+    finally:
+        watch.unlisten()
+    (record,) = _compile_records(recorder)
+    assert record['path'] == 'serving' and 'phase' not in record
+    assert record['program'] == 'jit(prefill_paged_fn)'
+    assert record['duration_s'] == 0.2 and not record['cache_hit']
+    assert record['during'] is None and record['seq'] is None
+    assert 'relowered' not in record  # no engine call was in flight
 
 
 def test_phase_scope_namespaces_process_dedup():
