@@ -456,8 +456,15 @@ class HostSyncInHotPathRule(Rule):
             'LLMEngine._recover',
             'LLMEngine._expire_deadlines',
             'LLMEngine._sample_device',
-            'LLMEngine._window_kmax',
+            'LLMEngine._window_reserve',
             'LLMEngine._window_budget',
+            'LLMEngine._budget_left',
+            # The decode-budget gate runs at every admission attempt of
+            # both loops (scheduler.py "Admission by decode budget"):
+            # host arithmetic over at most max_num_seqs rows.
+            'LLMEngine._decode_budget_admits',
+            'LLMEngine._budget_row',
+            'LLMEngine._head_waits_on_inflight',
             'LLMEngine._reserve_shortfall',
             # KV-tier spill/promotion (docs/prefix_caching.md "Tier
             # hierarchy"): runs inside the serving loop under pool

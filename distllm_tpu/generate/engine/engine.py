@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import contextlib
 import itertools
+import math
 import time
 from dataclasses import dataclass, field
 from enum import Enum
@@ -49,8 +50,10 @@ from distllm_tpu.generate.engine.kv_cache import (
     block_digests,
 )
 from distllm_tpu.generate.engine.scheduler import (
+    BudgetRow,
     InstrumentedScheduler,
     SchedulerExhausted,
+    decode_budget_fits,
     make_scheduler,
 )
 from distllm_tpu.models import mistral
@@ -206,6 +209,13 @@ class Request:
     prefill_tokens: int = 0
     prefill_first_s: float = 0.0
     routes: dict = field(default_factory=dict)
+    # ``num_tokens`` at the latest admission (-1 = never admitted): while
+    # it still reads the same, the prefill this admission owes has not
+    # emitted its token (the decode-budget walk counts that token).
+    admit_tokens: int = -1
+    # The decode-budget look-ahead has made this request wait at least
+    # once (counted once a request in ``_stats``).
+    budget_deferred: bool = False
     # Propagated request id (the server's X-Request-Id), captured from
     # tracing.request_scope at add_request; carried on the 'request'
     # flight record so one id correlates server spans, engine lifecycle,
@@ -1156,6 +1166,13 @@ class LLMEngine:
         # Set by _run_to_completion: lets chunked prefill retire one
         # in-flight decode window between chunks.
         self._drain_hook = None
+        # Windows in flight behind a dispatch: depth - 1 inside the
+        # pipelined loop, 0 under step(). A row's blocks come back that
+        # many dispatches after its last window (the decode-budget walk).
+        self._windows_behind = 0
+        # The decode-budget look-ahead's verdict on the waiting head at
+        # the latest admission attempt (True = it made the head wait).
+        self._head_deferred = False
         # Device-side last-token vector carried across the pipelined loop;
         # deferred prefill scatters freshly sampled first tokens into it.
         self._carried = None
@@ -2076,6 +2093,7 @@ class LLMEngine:
             while (rid := self._admit_next_evicting()) is not None:
                 request = self._requests[rid]
                 request.state = RequestState.RUNNING
+                request.admit_tokens = request.num_tokens
                 if request.t_admit == 0.0:  # first admission only, not
                     request.t_admit = time.monotonic()  # preemption retries
                     _metrics.REQUEST_QUEUE_WAIT.observe(
@@ -2153,9 +2171,12 @@ class LLMEngine:
             emitted.extend(self._run_prefill_paged(paged, defer_to))
 
     def _admit_next_evicting(self) -> int | None:
-        """``admit_next`` with prefix-cache eviction pressure: when
-        admission stalls on blocks while unreferenced cached blocks exist,
-        evict just enough (LRU) and retry."""
+        """``admit_next`` behind the decode-budget gate, with prefix-cache
+        eviction pressure: when admission stalls on blocks while
+        unreferenced cached blocks exist, evict just enough (LRU) and
+        retry."""
+        if not self._decode_budget_admits():
+            return None
         while True:
             try:
                 rid = self.sched.admit_next()
@@ -2167,6 +2188,102 @@ class LLMEngine:
                 return rid
             if not self._evict_for_admission():
                 return None
+
+    def _decode_budget_admits(self) -> bool:
+        """May the waiting head join the running rows? Only if the pool
+        can carry all of them to the end of their budgets
+        (``scheduler.decode_budget_fits``; the policy is at the top of
+        scheduler.py). With nothing running the head is always tried."""
+        self._head_deferred = False
+        head = self.sched.waiting_head()
+        running = self.sched.running()
+        if head is None or not running:
+            return True
+        if len(running) >= self.config.max_num_seqs:
+            return True  # no slot: the scheduler's own deferral
+        rows = [self._budget_row(self._requests[rid]) for _, rid in running]
+        rows.append(self._budget_row(self._requests[head]))
+        spare = self.sched.num_free_blocks
+        if self.prefix_cache is not None:
+            spare += self.prefix_cache.num_evictable
+        if decode_budget_fits(
+            rows, spare, self.config.block_size, self._window_steps,
+            self._windows_behind,
+        ):
+            return True
+        self._head_deferred = True
+        _metrics.SCHED_DEFERRED.labels(reason='decode_budget').inc()
+        self._stats['budget_deferrals'] += 1
+        request = self._requests[head]
+        if not request.budget_deferred:
+            request.budget_deferred = True
+            self._stats['budget_deferred_requests'] += 1
+        return False
+
+    def _head_waits_on_inflight(self) -> bool:
+        """Did the look-ahead just make the head wait while a running
+        row has its last tokens in flight? That row's blocks come back
+        when its window is fetched, so the pipelined loop fetches before
+        it dispatches: the head then joins the next window, not the one
+        after, and no window carries only the rows that outlive a wave."""
+        if not self._head_deferred:
+            return False
+        k = self.config.decode_steps
+        return any(
+            unacked and not self._window_budget(
+                self._requests[rid], unacked, k
+            )
+            for rid, unacked in self._unacked.items()
+            if rid in self._requests
+        )
+
+    @property
+    def _window_steps(self) -> int:
+        """Tokens a row can emit in one window (a speculative window
+        emits up to ``1 + draft_k``)."""
+        cfg = self.config
+        return 1 + cfg.draft_k if cfg.draft_k else cfg.decode_steps
+
+    def _budget_row(self, request: Request) -> BudgetRow:
+        """The request as the decode-budget walk sees it: where it is,
+        how far it is expected to go, what it holds and what it keeps."""
+        rid = request.request_id
+        tokens = request.num_tokens
+        generated = len(request.output_ids)
+        left = self._budget_left(request)
+        unacked = self._unacked.get(rid, 0)
+        use = self._ewma.get('budget_use', 1.0)
+        if use < 1.0:
+            # Budgets that are not tight (max_tokens far beyond where
+            # answers stop): walk to the expected end, the share of
+            # their budgets that finished requests used. A row already
+            # past it is given one more window.
+            expected = math.ceil(use * (left + generated)) - generated
+            left = min(left, max(expected, unacked + self._window_steps))
+        if (
+            request.state is RequestState.WAITING
+            or tokens == request.admit_tokens
+        ):
+            # Its prefill is still to run (or rides mixed windows, or
+            # waits for a promotion) and emits one token of the budget.
+            length, steps = tokens + 1, left - 1
+        else:
+            length, steps = tokens + unacked, left - unacked
+        kept = request.num_borrowed_blocks
+        if kept:
+            kept -= min(kept, self.prefix_cache.num_sole(rid))
+        return BudgetRow(
+            length, max(0, steps), len(self.sched.block_row(rid)), kept
+        )
+
+    def _budget_use_was_low(self) -> None:
+        """The pool ran short under rows the walk had admitted: the
+        expected share of a budget was too low, so double it (capped at
+        the whole budget, where the walk is exact)."""
+        if 'budget_use' in self._ewma:
+            self._ewma['budget_use'] = min(
+                1.0, 2.0 * self._ewma['budget_use']
+            )
 
     def _evict_for_admission(self) -> bool:
         if (
@@ -3375,48 +3492,54 @@ class LLMEngine:
         request's prefill tail is still riding mixed windows."""
         if not self._decode_ready(request):
             return 0
-        budget = min(
-            request.params.max_tokens - len(request.output_ids) - unacked,
-            self.config.max_model_len - request.num_tokens - unacked,
+        return max(0, min(k, self._budget_left(request) - unacked))
+
+    def _budget_left(self, request: Request) -> int:
+        """Tokens the request may still emit (those in flight included)
+        before ``max_tokens`` or ``max_model_len`` ends it."""
+        return min(
+            request.params.max_tokens - len(request.output_ids),
+            self.config.max_model_len - request.num_tokens,
         )
-        return max(0, min(k, budget))
 
-    def _window_kmax(self) -> int:
-        """Per-sequence reservation target for the next window: inflight
-        (unacked) tokens plus this window's steps, maxed over the batch."""
+    def _window_reserve(self) -> dict[int, int]:
+        """Per decode-ready running row, the tokens beyond ``num_tokens``
+        its blocks must cover once the next window has run: what is in
+        flight (unacked) plus this window's steps, capped by the row's
+        own budget (``decode_loop`` routes the writes of a row past its
+        budget to the trash block). A row reserves for itself, never for
+        the batch's largest window: the decode-budget walk
+        (scheduler.py) counts on a row never holding more than its own
+        end needs."""
         k = self.config.decode_steps
-        kmax = 1
-        for _, rid in self.sched.running():
-            request = self._requests[rid]
-            unacked = self._unacked.get(rid, 0)
-            kmax = max(kmax, unacked + self._window_budget(request, unacked, k))
-        return kmax
-
-    def _reserve_shortfall(self, kmax: int, row_ks=None) -> int:
-        """Blocks ``prepare_decode(kmax)`` would need beyond what running
-        sequences already own — used by the pipelined loop to guarantee no
-        preemption happens while windows are in flight (preempting a
-        sequence whose blocks an in-flight window still writes to would
-        let a re-allocation corrupt another sequence's KV). ``row_ks``
-        (speculative windows) replaces the uniform ``kmax`` with each
-        row's own headroom; rows absent from it take no decode extension
-        this window."""
-        bs = self.config.block_size
-        short = 0
+        reserve = {}
         for _, rid in self.sched.running():
             request = self._requests[rid]
             if not self._decode_ready(request):
-                # Mixed prefill rows take no decode steps this window and
-                # their chunk writes land in blocks granted at admission
-                # (the full prompt is budgeted up front) — mirrors
-                # prepare_decode(kmax, rids=decode-ready) below, so the
-                # pipelined drain-before-preempt guard and the scheduler
-                # agree on the shortfall.
+                # Mixed prefill rows, promotion-pending rows and rows
+                # whose failed prefill awaits its retry take no decode
+                # steps this window, and their blocks were granted at
+                # admission: extending them would allocate (and possibly
+                # preempt) for rows that write nothing.
                 continue
-            k_row = kmax if row_ks is None else row_ks.get(rid)
-            if k_row is None:
-                continue  # not participating in this spec window
-            target = -(-(request.num_tokens + k_row) // bs)
+            unacked = self._unacked.get(rid, 0)
+            reserve[rid] = max(
+                1, unacked + self._window_budget(request, unacked, k)
+            )
+        return reserve
+
+    def _reserve_shortfall(self, row_ks: dict[int, int]) -> int:
+        """Blocks ``prepare_decode`` would need beyond what the rows of
+        ``row_ks`` (rid -> headroom in tokens) already own — used by the
+        pipelined loop to guarantee no preemption happens while windows
+        are in flight (preempting a sequence whose blocks an in-flight
+        window still writes to would let a re-allocation corrupt another
+        sequence's KV). Running rows absent from ``row_ks`` take no
+        decode extension this window."""
+        bs = self.config.block_size
+        short = 0
+        for rid, k_row in row_ks.items():
+            target = -(-(self._requests[rid].num_tokens + k_row) // bs)
             short += max(0, target - len(self.sched.block_row(rid)))
         return short
 
@@ -3452,35 +3575,19 @@ class LLMEngine:
         self._faults.fail('dispatch')
         step.mark('plan')
         k = self.config.decode_steps
-        kmax = self._window_kmax()
-        decode_rids = None
-        if (
-            self.config.enable_mixed_batching
-            or self._promoting
-            or self._pending_prefill
-        ):
-            # Promotion-pending rows mirror mixed prefill rows: they take
-            # no decode steps this window and their blocks were budgeted
-            # at admission, so they must be excluded from the k-token
-            # guarantee — otherwise prepare_decode would allocate (and
-            # possibly preempt) for rows _reserve_shortfall skipped,
-            # breaking the pipelined drain-before-preempt invariant.
-            # Pending-prefill rows (a failed prefill dispatch awaiting
-            # its recovery retry) are gated the same way: decode must
-            # not read KV their prefill never wrote.
-            decode_rids = [
-                rid for _, rid in self.sched.running()
-                if self._decode_ready(self._requests[rid])
-            ]
-        if decode_rids is None or decode_rids:
+        row_ks = self._window_reserve()
+        if row_ks:
             # Eviction pressure beats preemption: unreferenced cached
             # blocks are free capacity, so spend those before recompute-
             # preempting a running sequence.
-            short = self._reserve_shortfall(kmax) - self.sched.num_free_blocks
+            short = (
+                self._reserve_shortfall(row_ks) - self.sched.num_free_blocks
+            )
             short -= self._evict_cached_blocks(short)
             if self._faults.fire('sched_exhausted') is not None:
                 # Injection site 'sched_exhausted': the pool-pressure
                 # hazard, without needing a pool actually sized to hit it.
+                self._budget_use_was_low()
                 raise SchedulerExhausted(
                     'injected scheduler exhaustion', preempted=[]
                 )
@@ -3488,7 +3595,10 @@ class LLMEngine:
             # dispatch that could preempt, so victims never have unacked
             # device-side tokens OR in-flight chunk writes; recompute
             # preemption re-prefills them.
-            self._prepare_decode(step, short, kmax, decode_rids)
+            self._prepare_decode(
+                step, short, max(row_ks.values()), list(row_ks),
+                list(row_ks.values()),
+            )
         # A chunk-only window (no decode-ready rows) skips prepare_decode
         # entirely: chunk writes land in admission-granted blocks, so it
         # must neither allocate nor preempt. Planned AFTER preemption so
@@ -3695,7 +3805,7 @@ class LLMEngine:
             row_ks.append(max(1, len(drafts)))
         if decode_rids:
             short = self._reserve_shortfall(
-                1, row_ks=dict(zip(decode_rids, row_ks))
+                dict(zip(decode_rids, row_ks))
             ) - self.sched.num_free_blocks
             short -= self._evict_cached_blocks(short)
             # Spec windows process synchronously, so victims never have
@@ -3933,8 +4043,11 @@ class LLMEngine:
             try:
                 preempted = self.sched.prepare_decode(k, rids, ks)
             except SchedulerExhausted as exc:
+                self._budget_use_was_low()
                 self._note_preempted(exc.preempted, k, step)
                 raise
+            if preempted:
+                self._budget_use_was_low()
             self._note_preempted(preempted, k, step)
         return preempted
 
@@ -4140,6 +4253,7 @@ class LLMEngine:
                 process_one()
 
         self._drain_hook = drain_one
+        self._windows_behind = depth - 1
         root = self._begin_root()
         try:
             while self.has_unfinished or inflight:
@@ -4167,10 +4281,14 @@ class LLMEngine:
                     if inflight:
                         process_one()
                     continue
+                if inflight and self._head_waits_on_inflight():
+                    span.close()
+                    process_one()
+                    continue
                 # Never let a dispatch preempt while windows are in flight.
                 # Evictable cached blocks count as free capacity first.
                 while inflight and (
-                    short := self._reserve_shortfall(self._window_kmax())
+                    short := self._reserve_shortfall(self._window_reserve())
                     - self.sched.num_free_blocks
                 ) > 0:
                     if self._evict_cached_blocks(short):
@@ -4224,6 +4342,7 @@ class LLMEngine:
         finally:
             root.close()
             self._drain_hook = None
+            self._windows_behind = 0
 
     # ------------------------------------- crash-domain recovery (faults)
     def _recover(self, exc: Exception) -> bool:
@@ -4434,6 +4553,13 @@ class LLMEngine:
     def _finish(self, request: Request) -> None:
         request.state = RequestState.FINISHED
         request.t_finish = time.monotonic()
+        # What it used of its budget, for the decode-budget walk's
+        # expected ends (1.0 until something has finished; 1.0 again
+        # wherever requests run to max_tokens).
+        used = len(request.output_ids)
+        self._ewma_update(
+            'budget_use', used / (used + self._budget_left(request))
+        )
         self._observe_lifecycle(request)
         _metrics.ENGINE_REQUESTS_FINISHED.inc()
         self.sched.finish(request.request_id)

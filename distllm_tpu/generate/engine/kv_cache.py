@@ -328,6 +328,15 @@ class PrefixCache:
     def num_evictable(self) -> int:
         return len(self._evictable)
 
+    def num_sole(self, rid: int) -> int:
+        """Blocks only ``rid`` references: what its ``release`` would make
+        evictable (the decode-budget walk counts them as capacity that
+        comes back when the request finishes)."""
+        return sum(
+            1 for digest in self._held.get(rid, ())
+            if self._entries[digest].refcount == 1
+        )
+
     @property
     def num_shared(self) -> int:
         return sum(1 for e in self._entries.values() if len(e.holders) >= 2)

@@ -32,6 +32,36 @@ Policy contract (both implementations, tested in lockstep):
   identical blocks) — how a speculative window's rejected-suffix
   reservation is rolled back to the never-drafted state.
 - Block 0 is the reserved trash block and is never allocated.
+- ``waiting_head`` names the request ``admit_next`` would try, without
+  moving it (``None`` on an empty queue).
+
+Admission by decode budget (the engine's gate in front of ``admit_next``,
+``LLMEngine._admit_next_evicting``). The test above grants a prompt and
+one token; a request whose ``max_tokens`` the pool cannot carry would be
+admitted, outgrow the pool at the next ``prepare_decode`` and be
+preempted: with 96 prompts waiting on a 10,240-token pool that was 90
+preemptions a call. So before the head is admitted next to running rows
+the engine asks :func:`decode_budget_fits`: if every running row and the
+head decode to the end of their budgets, does the pool ever run short?
+The scheduler knows no budget; the engine, which does, describes each
+row as a :class:`BudgetRow` and passes free plus evictable blocks as
+``spare``. The walk visits the rows in the order they finish. At each
+finish the rows still alive hold the reservation the engine makes for
+them by then (``LLMEngine._window_reserve``: what is in flight plus the
+window's steps, capped by the row's own budget, row by row), and a
+finished row's blocks are capacity again: its owned tail free, its
+borrowed prefix evictable unless another request also holds it. No
+watermark and no safety factor: with tight budgets the walk
+refuses only an admission that ends in a preemption. With nothing
+running the head is always tried, so :class:`SchedulerExhausted` keeps
+its meaning, and recompute preemption stays as the net under what the
+walk cannot see (per-row speculative headroom, an injected fault, a
+budget estimate that was low). The pipelined loop learns of a finish
+when it fetches the row's last window, a dispatch late (``behind``); when
+the walk makes the head wait while such a window is in flight, the loop
+fetches it before it dispatches the next
+(``LLMEngine._head_waits_on_inflight``), so the head joins the next
+window and none carries only the rows that outlive a wave.
 
 Borrowed prefixes (automatic prefix caching, docs/prefix_caching.md): a
 request's block row may start with blocks OWNED BY THE PREFIX CACHE —
@@ -54,7 +84,7 @@ from __future__ import annotations
 import ctypes
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Protocol
+from typing import NamedTuple, Protocol
 
 
 class SchedulerExhausted(RuntimeError):
@@ -71,12 +101,71 @@ class SchedulerExhausted(RuntimeError):
         self.preempted = list(preempted or [])
 
 
+class BudgetRow(NamedTuple):
+    """One row of the decode-budget walk, as the engine sees it now."""
+
+    # Tokens the row has once every window already dispatched has landed
+    # (``num_tokens + unacked``); a prefill still to run counts its token.
+    length: int
+    # Decode steps still to dispatch before the row's (expected) end.
+    steps: int
+    # Blocks in the row now.
+    held: int
+    # Blocks of the row that stay out of reach when it finishes: borrowed
+    # prefix blocks that another request references too.
+    kept: int
+
+
+def decode_budget_fits(
+    rows: 'list[BudgetRow]', spare: int, block_size: int, k: int,
+    behind: int = 0,
+) -> bool:
+    """Can ``rows`` all decode to their ends within ``spare`` more blocks?
+
+    ``k`` is the steps of a window, ``behind`` the windows in flight
+    behind a dispatch (``pipeline_depth - 1`` in the pipelined loop, 0
+    under ``step()``). Dispatch ``j`` (0 = the next one) has reserved a
+    live row ``min(length + (j + 1) * k, length + steps)`` tokens: the
+    engine reserves each row its own window, capped by its own end. A
+    row's last window is dispatch ``ceil(steps / k) - 1``, and its blocks
+    come back when that window is processed, ``behind`` dispatches later.
+    Demand only grows between two finishes, so it is checked at the last
+    dispatch each row is alive at.
+    """
+
+    def need(row: BudgetRow, j: int) -> int:
+        tokens = row.length + min((j + 1) * k, row.steps)
+        return max(row.held, -(-tokens // block_size))
+
+    # Every row at its end at once (a prefill that ends its request holds
+    # what admission grants, which is ``length``): the common case.
+    if sum(need(row, row.steps) - row.held for row in rows) <= spare:
+        return True
+    last = [-(-row.steps // k) - 1 + behind for row in rows]
+    order = sorted(range(len(rows)), key=last.__getitem__)
+    returned = 0
+    alive = 0
+    for j in sorted({j for j in last if j >= 0}):
+        while last[order[alive]] < j:
+            gone = rows[order[alive]]
+            # It took ``peak - held`` of the spare blocks and gives
+            # ``peak - kept`` back.
+            returned += gone.held - gone.kept
+            alive += 1
+        grown = sum(need(rows[i], j) - rows[i].held for i in order[alive:])
+        if grown - returned > spare:
+            return False
+    return True
+
+
 class Scheduler(Protocol):
     def add(
         self, rid: int, num_tokens: int, cached_blocks: 'list[int] | tuple' = ()
     ) -> None: ...
 
     def admit_next(self) -> int | None: ...
+
+    def waiting_head(self) -> int | None: ...
 
     def prepare_decode(
         self,
@@ -181,6 +270,9 @@ class PyScheduler:
         req.slot = slot
         self._slots[slot] = rid
         return rid
+
+    def waiting_head(self) -> int | None:
+        return self._waiting[0] if self._waiting else None
 
     def _free_owned(self, req: _PyRequest) -> None:
         self._free.extend(req.blocks[req.num_borrowed :])
@@ -374,6 +466,8 @@ class NativeScheduler:
         lib.sched_num_borrowed.argtypes = [ctypes.c_void_p, ctypes.c_int64]
         lib.sched_admit_next.restype = ctypes.c_int64
         lib.sched_admit_next.argtypes = [ctypes.c_void_p]
+        lib.sched_waiting_head.restype = ctypes.c_int64
+        lib.sched_waiting_head.argtypes = [ctypes.c_void_p]
         lib.sched_prepare_decode_k.restype = ctypes.c_int32
         lib.sched_prepare_decode_k.argtypes = [
             ctypes.c_void_p,
@@ -460,6 +554,10 @@ class NativeScheduler:
                 'request needs more KV blocks than are free with nothing '
                 'running; increase num_blocks'
             )
+        return None if rid < 0 else rid
+
+    def waiting_head(self) -> int | None:
+        rid = int(self._lib.sched_waiting_head(self._handle))
         return None if rid < 0 else rid
 
     def prepare_decode(
@@ -625,8 +723,11 @@ class InstrumentedScheduler:
             self._m.SCHED_ADMITTED.inc()
             self._sync()
         elif self._inner.num_waiting:
-            self._m.SCHED_DEFERRED.inc()
+            self._m.SCHED_DEFERRED.labels(reason='capacity').inc()
         return rid
+
+    def waiting_head(self) -> int | None:
+        return self._inner.waiting_head()
 
     def prepare_decode(
         self,

@@ -11,7 +11,10 @@
 //
 // Policy (must stay in lockstep with PyScheduler):
 //   - admit_next: pop the head of the waiting queue into the lowest free
-//     slot if blocks for (num_tokens + 1) are available.
+//     slot if blocks for (num_tokens + 1) are available. waiting_head
+//     names that request without moving it: the engine's decode-budget
+//     gate (scheduler.py, "Admission by decode budget") reads it before
+//     it lets admit_next run.
 //   - prepare_decode(k): every running sequence gets capacity for k more
 //     tokens (k > 1 backs the engine's multi-step fused decode windows,
 //     where K tokens are generated per dispatch); on OOM, preempt the
@@ -385,6 +388,12 @@ int32_t sched_num_free(void* h) {
 
 int32_t sched_num_running(void* h) {
     return static_cast<Scheduler*>(h)->num_running();
+}
+
+// Head of the waiting queue (the request admit_next would try), or -1.
+int64_t sched_waiting_head(void* h) {
+    auto* s = static_cast<Scheduler*>(h);
+    return s->waiting.empty() ? -1 : s->waiting.front();
 }
 
 int32_t sched_num_waiting(void* h) {

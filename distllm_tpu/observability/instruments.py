@@ -705,8 +705,14 @@ SCHED_ADMITTED = _registry.counter(
 )
 SCHED_DEFERRED = _registry.counter(
     'distllm_scheduler_deferred_total',
-    'Admission attempts deferred (no free slot or insufficient blocks).',
+    'Admission attempts deferred, by reason: capacity = the scheduler '
+    'had no free slot or too few blocks for the prompt, decode_budget = '
+    'the engine\'s look-ahead found that the pool could not carry the '
+    'running rows and the waiting head to the end of their budgets.',
+    labelnames=('reason',),
 )
+for _reason in ('capacity', 'decode_budget'):
+    SCHED_DEFERRED.labels(reason=_reason)
 SCHED_PREEMPTIONS = _registry.counter(
     'distllm_scheduler_preemptions_total',
     'Running requests recompute-preempted back to the waiting queue.',

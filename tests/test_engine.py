@@ -185,7 +185,7 @@ def test_paged_kv_cache_container():
 
 
 # ----------------------------------------------------------------- engine
-def _tiny_engine(num_blocks=64, max_num_seqs=4, max_model_len=64):
+def _tiny_engine(num_blocks=64, max_num_seqs=4, max_model_len=64, **cfg_kwargs):
     cfg = mistral.MistralConfig(
         vocab_size=64,
         hidden_size=32,
@@ -213,6 +213,7 @@ def _tiny_engine(num_blocks=64, max_num_seqs=4, max_model_len=64):
             max_num_seqs=max_num_seqs,
             max_model_len=max_model_len,
             prefer_native_allocator=False,
+            **cfg_kwargs,
         ),
     )
     return cfg, params, engine
@@ -316,14 +317,32 @@ def test_engine_continuous_batching_join_leave():
     assert seen[r1] == ref
 
 
+def _expect_short_answers(engine):
+    """As if finished requests had used none of their budgets: admission's
+    look-ahead (scheduler.py, "Admission by decode budget") then sees one
+    window ahead, as the admission rule before it saw one token, so a
+    small pool runs short under rows that run to ``max_tokens`` and
+    recompute preemption, the net under a low estimate, has to catch it.
+    Returns a callable that says how many victims there were since."""
+    from distllm_tpu.observability import instruments
+
+    engine._ewma['budget_use'] = 0.0
+    before = instruments.SCHED_PREEMPTIONS.value
+    return lambda: instruments.SCHED_PREEMPTIONS.value - before
+
+
 def test_engine_preemption_under_block_pressure():
     """Tiny block pool forces recompute preemption; outputs still correct
     and complete (no tokens lost across preemption)."""
     # 7 usable blocks, 3 seqs needing 3 blocks each -> guaranteed pressure.
-    cfg, params, engine = _tiny_engine(num_blocks=8, max_num_seqs=3, max_model_len=32)
+    cfg, params, engine = _tiny_engine(
+        num_blocks=8, max_num_seqs=3, max_model_len=32, decode_steps=2
+    )
+    victims = _expect_short_answers(engine)
     sp = SamplingParams(temperature=0.0, max_tokens=6)
     prompts = [[5, 9, 12, 4], [7, 3, 22, 31], [1, 2, 3, 4]]
     outs = engine.generate_ids(prompts, sp)
+    assert victims() > 0
     for prompt, out in zip(prompts, outs):
         ref = _dense_greedy_reference(cfg, params, prompt, 6)
         assert out == ref
@@ -466,12 +485,16 @@ def test_engine_decode_steps_variants_match_dense():
 def test_engine_pipelined_preemption_pressure_matches_dense():
     """A pool too small for all sequences forces recompute preemption mid-
     pipeline; the drain-before-preempt rule must keep results exact."""
-    cfg, params, engine = _tiny_engine(num_blocks=14, max_num_seqs=3)
+    cfg, params, engine = _tiny_engine(
+        num_blocks=10, max_num_seqs=3, decode_steps=2
+    )
+    victims = _expect_short_answers(engine)
     prompts = [[5, 9, 12], [7, 3, 22, 31], [1, 2, 3, 4, 5]]
-    n = 6
+    n = 12
     outs = engine.generate_ids(
         prompts, SamplingParams(temperature=0.0, max_tokens=n)
     )
+    assert victims() > 0
     for prompt, out in zip(prompts, outs):
         assert out == _dense_greedy_reference(cfg, params, prompt, n)
 
@@ -830,9 +853,11 @@ def test_mixed_windows_match_dense_reference_and_preemption():
     )
     _, on = _mixed_ab_engines(
         cfg, mistral.init, num_blocks=20, max_num_seqs=3, max_model_len=64,
-        prefill_chunk_tokens=4,
+        prefill_chunk_tokens=4, decode_steps=2,
     )
+    victims = _expect_short_answers(on)
     outs = _run_stagger(on, cfg.vocab_size)
+    assert victims() > 0
     prompts = _stagger_prompts(cfg.vocab_size)
     # Dense gold references for the two longest-prompt requests (the ones
     # whose chunk rides + preemption interact); the full-matrix identity

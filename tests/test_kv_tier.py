@@ -241,17 +241,21 @@ def test_promotion_survives_warmup_and_preemption_pressure():
         num_blocks=14,
         max_num_seqs=3,
         max_model_len=48,
-        decode_steps=4,
+        decode_steps=2,
         pipeline_depth=2,
     )
     engine.warmup()
     assert engine.sched.num_running == 0
+    from test_engine import _expect_short_answers
+
+    victims = _expect_short_answers(engine)
     stem = list(range(1, 13))
     prompts = [stem + [20 + i] for i in range(3)] + [PROMPT_B[:9]]
     for _ in range(2):  # second pass re-arrives after eviction/spill
         outs = engine.generate_ids(prompts, GREEDY)
         for p, o in zip(prompts, outs):
             assert o == _dense_greedy(cfg, params, p, 4), p
+    assert victims() > 0
 
 
 def test_tier_config_validation():

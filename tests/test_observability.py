@@ -662,7 +662,8 @@ def test_instrumented_scheduler_publishes_metrics():
     )
     assert instruments.KV_BLOCKS_TOTAL.value == 8
     admitted_before = instruments.SCHED_ADMITTED.value
-    deferred_before = instruments.SCHED_DEFERRED.value
+    deferred = instruments.SCHED_DEFERRED.labels(reason='capacity')
+    deferred_before = deferred.value
 
     sched.add(0, 4)
     sched.add(1, 4)
@@ -672,7 +673,7 @@ def test_instrumented_scheduler_publishes_metrics():
     assert sched.admit_next() == 1
     assert sched.admit_next() is None  # no free slot -> deferred
     assert instruments.SCHED_ADMITTED.value == admitted_before + 2
-    assert instruments.SCHED_DEFERRED.value == deferred_before + 1
+    assert deferred.value == deferred_before + 1
     assert instruments.SCHED_RUNNING.value == 2
     assert instruments.SCHED_QUEUE_DEPTH.value == 1
     assert instruments.KV_BLOCKS_IN_USE.value == 4  # 2 blocks per request

@@ -107,7 +107,10 @@ def drive(sched, seed: int, steps: int = 200):
                 live.discard(rid)
                 trace.append(('finish', rid))
         trace.append(
-            ('state', sched.num_free_blocks, sched.num_running, sched.num_waiting)
+            (
+                'state', sched.num_free_blocks, sched.num_running,
+                sched.num_waiting, sched.waiting_head(),
+            )
         )
     # Block rows of everything still live (allocation order must agree too).
     for rid in sorted(live):

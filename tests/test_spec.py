@@ -366,12 +366,15 @@ def test_acceptance_preemption_mid_draft():
     verify windows; outputs stay exact and no blocks leak."""
     cfg = _tiny_cfg()
     params = mistral.init(jax.random.PRNGKey(0), cfg)
+    from test_engine import _expect_short_answers
+
     eng = _engine(
-        cfg, params, draft_k=4, num_blocks=14, max_num_seqs=3,
+        cfg, params, draft_k=4, num_blocks=10, max_num_seqs=3,
         max_model_len=64,
     )
+    victims = _expect_short_answers(eng)
     prompts = [[5, 9, 12], [7, 3, 22, 31], [1, 2, 3, 4, 5]]
-    n = 6
+    n = 12
     rids = [
         eng.add_request(p, SamplingParams(temperature=0.0, max_tokens=n))
         for p in prompts
@@ -380,7 +383,8 @@ def test_acceptance_preemption_mid_draft():
     for prompt, rid in zip(prompts, rids):
         ref = _dense_greedy_reference(cfg, params, prompt, n)
         assert eng._finished.pop(rid).output_ids == ref
-    assert eng.sched.num_free_blocks == 13  # no leaks
+    assert victims() > 0
+    assert eng.sched.num_free_blocks == 9  # no leaks
 
 
 def test_temperature_rows_draft_with_sampled_verification():

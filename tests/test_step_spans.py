@@ -247,13 +247,29 @@ def test_small_pool_preempts_and_every_lost_token_is_counted():
     preemption."""
     engine = _engine(
         num_blocks=24, enable_prefix_cache=True, prefill_chunk_tokens=16,
+        decode_steps=4,
     )
+    # Admission looks ahead to the end of every budget and would not let
+    # this pool run short. Preemption is the net under a look-ahead whose
+    # estimate was low, so teach it one: an answer that stops within 2
+    # tokens of a budget of 40. The walk then looks one window ahead
+    # (4 tokens), and these requests run to 16. (To 24 the victims, which
+    # keep their prompt blocks while they wait, pin this pool until a
+    # lone running row exhausts it: PERF.md section 7.)
+    probe = _prompts((6,), seed=1)
+    stop = _engine().generate_ids(
+        probe, SamplingParams(temperature=0.0, max_tokens=2)
+    )[0][1]
+    engine.generate_ids(probe, SamplingParams(
+        temperature=0.0, max_tokens=40, stop_token_ids=[stop]
+    ))
+    assert engine._ewma['budget_use'] <= 2 / 40
     before = engine.flight.total_recorded
     prompts = _prompts((10, 20, 30, 12, 25, 18))
     outputs = engine.generate_ids(
-        prompts, SamplingParams(temperature=0.0, max_tokens=12)
+        prompts, SamplingParams(temperature=0.0, max_tokens=16)
     )
-    assert all(len(o) == 12 for o in outputs)
+    assert all(len(o) == 16 for o in outputs)
     records = _since(engine, before)
     prefills = [r for r in records if r['kind'] == 'prefill']
     preempts = [r for r in records if r['kind'] == 'preempt']
