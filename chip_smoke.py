@@ -12,6 +12,11 @@ printed as one JSON line (``{"phase": ..., "ok": ...}``):
 - ``embed``   — ``distllm_tpu.distributed_embedding`` driven the way its
   ``main`` drives it, PubMedBERT widths and depth, against the same model
   on the XLA attention path in float32;
+- ``hybrid``  — one ``granitemoehybrid`` request pair through
+  ``LLMEngine.generate_ids`` at the widths of
+  ``benchmarks/configs/granite-4.0-h-small.json``: the prompts admitted
+  whole, the long one prefilled in two spans, the state pool's bytes, the
+  KV pool over the one attention layer, the share of routed pairs held;
 - ``serve``   — the OpenAI-compatible server from ``chat_server.build_app``
   on a local port, engine made by ``TpuGenerator`` with the settings of
   ``examples/chat/chat_server.rag.yaml`` at Mistral-7B-Instruct-v0.3
@@ -540,6 +545,111 @@ def phase_embed(seed: int) -> dict:
 
 
 # ------------------------------------------------------------------- serve
+
+
+# ------------------------------------------------------------------ hybrid
+HYBRID_CONFIG = REPO / 'benchmarks/configs/granite-4.0-h-small.json'
+HYBRID_PROMPT_TOKENS = 700  # two spans: 512 and 188, state carried between
+HYBRID_OUTPUT_TOKENS = 24
+
+
+def phase_hybrid(seed: int) -> dict:
+    """One ``granitemoehybrid`` request through ``generate_ids`` at the
+    benchmark configuration's widths: what the engine admitted, how it
+    prefilled, and the state pool it holds beside the KV pool."""
+    import jax
+    import numpy as np
+
+    from distllm_tpu.generate.engine.engine import (
+        EngineConfig,
+        LLMEngine,
+        SamplingParams,
+    )
+    from distllm_tpu.models import granite_hybrid
+
+    model = json.loads(HYBRID_CONFIG.read_text())
+    cfg = granite_hybrid.GraniteHybridConfig.from_hf_config(model).model_copy(
+        update={'dtype': model['dtype']}
+    )
+    params = granite_hybrid.init_on_device(jax.random.PRNGKey(seed % 2**31), cfg)
+
+    class NoTokenizer:
+        eos_id = None
+
+    start = time.perf_counter()
+    # The engine moves device-resident weights into the decode window's
+    # layouts with tiny jitted identities. One loaded back from the
+    # persistent cache comes out in the DEFAULT layout (jax 0.9.0; on the
+    # chip: the second of two equal leaves, and every leaf of the next
+    # process), and the window then rejects the weights. This script
+    # caches every compile; while this engine is built it keeps jax's own
+    # floor, under which those identities are never written.
+    floor = jax.config.jax_persistent_cache_min_compile_time_secs
+    jax.config.update('jax_persistent_cache_min_compile_time_secs', 1.0)
+    try:
+        engine = LLMEngine(
+            cfg, params, NoTokenizer(), EngineConfig(**model['engine']),
+            own_params=True,
+        )
+    finally:
+        jax.config.update('jax_persistent_cache_min_compile_time_secs', floor)
+    del params
+    build_s = time.perf_counter() - start
+    note(f'hybrid: engine built in {build_s:.0f}s')
+    settings = model['engine']
+    per_slot = cfg.num_mamba_layers * (
+        cfg.mamba_n_heads * cfg.mamba_d_head * cfg.mamba_d_state * 4
+        + (cfg.mamba_d_conv - 1) * cfg.conv_dim * 2
+    )
+    check(
+        engine.telemetry['state_pool_bytes'] == settings['max_num_seqs'] * per_slot,
+        f"state pool holds {engine.telemetry['state_pool_bytes']} bytes, "
+        f"{settings['max_num_seqs']} slots of {per_slot} expected",
+    )
+    check(engine.telemetry['attn_backend'] == 'pallas',
+          f"attn_backend resolved to {engine.telemetry['attn_backend']!r}")
+    check(engine.kv.shape[0] == cfg.num_paged_layers == 1,
+          f'KV pool over {engine.kv.shape[0]} layers')
+    rng = np.random.default_rng(seed)
+    prompt = [int(t) for t in rng.integers(0, cfg.vocab_size, HYBRID_PROMPT_TOKENS)]
+    before = engine.flight.total_recorded
+    start = time.perf_counter()
+    out = engine.generate_ids(
+        [prompt, prompt[:40]],
+        SamplingParams(temperature=0.0, max_tokens=HYBRID_OUTPUT_TOKENS),
+    )
+    generate_s = time.perf_counter() - start
+    records = engine.flight.snapshot()[-(engine.flight.total_recorded - before):]
+    requests = [r for r in records if r['kind'] == 'request']
+    windows = [r for r in records if r['kind'] == 'decode']
+    check([len(o) for o in out] == [HYBRID_OUTPUT_TOKENS] * 2,
+          f'generated {[len(o) for o in out]} tokens')
+    check(all(0 <= t < cfg.vocab_size for o in out for t in o),
+          'a token outside the held vocabulary')
+    # Nothing between generate_ids and the scheduler cut the prompt.
+    check(sorted(r['prompt_tokens'] for r in requests) == [40, HYBRID_PROMPT_TOKENS],
+          f"admitted {[r['prompt_tokens'] for r in requests]} prompt tokens")
+    routes = {
+        (r['route'], r['tokens']) for r in records if r['kind'] == 'prefill'
+    }
+    check(routes == {('chunk', 512), ('chunk', 188), ('paged', 40)},
+          f'prefill dispatches {sorted(routes)}')
+    pairs = sum(r['moe_pairs'] for r in windows)
+    held = sum(r['moe_pairs_held'] for r in windows)
+    check(pairs == cfg.num_layers * cfg.experts_per_token
+          * sum(r['tokens'] for r in windows),
+          f'{pairs} routed pairs over the decode windows')
+    check(0.3 < held / pairs < 0.7, f'{held} of {pairs} pairs held')
+    memory = _memory_stats()
+    engine.shutdown()
+    del engine
+    gc.collect()
+    return {
+        'build_s': round(build_s, 1), 'generate_s': round(generate_s, 1),
+        'state_pool_bytes': settings['max_num_seqs'] * per_slot,
+        'moe_held_pair_share': round(held / pairs, 4),
+        'memory': memory,
+    }
 
 
 def generator_settings(model_dir: Path) -> dict:
@@ -1112,7 +1222,7 @@ def run(chips: int, seed: int) -> int:
         else:
             phases = (
                 ('kernels', phase_kernels), ('embed', phase_embed),
-                ('serve', phase_serve),
+                ('hybrid', phase_hybrid), ('serve', phase_serve),
             )
         for name, fn in phases:
             ok = run_phase(name, fn, seed) and ok

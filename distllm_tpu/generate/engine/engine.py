@@ -47,6 +47,7 @@ from distllm_tpu.generate.engine.kv_cache import (
     PagedKVCache,
     PeerKVTier,
     PrefixCache,
+    StatePool,
     block_digests,
 )
 from distllm_tpu.generate.engine.scheduler import (
@@ -622,6 +623,14 @@ class LLMEngine:
     block on pytree structure (``models/mistral.py _mlp_block``), so
     dense SwiGLU and MoE families serve through one engine — mirroring
     the reference, whose vLLM backend serves both.
+
+    A model whose config has ``state_spec()`` (``models/granite_hybrid.py``:
+    recurrent layers between attention layers) is a HYBRID: its sequences
+    hold KV pages for the paged layers only and one slot of a ``StatePool``
+    beside them, its programs are its own family's, and every prefill takes
+    the paged route. What needs a snapshot of the recurrent state at a block
+    boundary (prefix cache, KV tiers, mixed and speculative windows) is
+    refused for it at construction.
     """
 
     def __init__(
@@ -683,11 +692,23 @@ class LLMEngine:
             'bf16': 'bfloat16', 'fp32': 'float32', 'int8': 'int8',
         }.get(cfg.kv_cache_dtype, model_cfg.dtype)
 
+        # What a sequence holds, asked of the model family: KV pages for
+        # its paged layers and, for a hybrid, a fixed state tree. The pool
+        # is allocated after the weight migration, like the KV pool.
+        self.state_pool = None
+        if hasattr(model_cfg, 'state_spec'):
+            self._refuse_for_hybrid(cfg, mesh, kv_pool_dtype)
+            self.state_pool = StatePool(
+                model_cfg.state_spec(), cfg.max_num_seqs, lazy=True
+            )
+
         # Lazy: the pool is materialized only after the (transient-heavy)
         # weight-layout migration below, so migration headroom isn't
         # squeezed by an idle 1-6 GiB of zeros.
         self.kv = PagedKVCache(
-            num_layers=model_cfg.num_layers,
+            num_layers=getattr(
+                model_cfg, 'num_paged_layers', model_cfg.num_layers
+            ),
             num_blocks=cfg.num_blocks,
             block_size=cfg.block_size,
             num_kv_heads=model_cfg.num_kv_heads,
@@ -983,6 +1004,24 @@ class LLMEngine:
             )
 
         self._prefill_paged = jax.jit(prefill_paged_fn, donate_argnums=(3, 4))
+        if self.state_pool is not None:
+            from distllm_tpu.models import granite_hybrid
+
+            def hybrid_prefill_fn(
+                params, ids, pos, k, v, bt, ctx, tails, state, slots
+            ):
+                return granite_hybrid.prefill_paged(
+                    params, model, ids, pos, k, v, bt, ctx, tails, state,
+                    slots, max_table_positions=_max_tables,
+                    attn_backend=attn_backend,
+                )
+
+            self._prefill_paged = jax.jit(
+                hybrid_prefill_fn, donate_argnums=(3, 4, 8)
+            )
+            # Every hybrid prefill takes the paged route: one family of
+            # programs carries the state from span to span.
+            self._prefill = None
         # Batched COW: copy shared blocks' K/V (all layers) into the
         # requests' private copies in one dispatch. tree.map for the
         # same reason as the tier jits above: a quantized source block's
@@ -1010,7 +1049,30 @@ class LLMEngine:
                 layer_unroll=cfg.decode_layer_unroll,
             )
 
-        self._decode_window = jax.jit(window_fn, donate_argnums=(4, 5))
+        if self.state_pool is not None:
+
+            def hybrid_window_fn(
+                params, ids, pos, ctx, k, v, bt, steps_left, temp, top_p,
+                min_p, top_k, seeds, state,
+            ):
+                return granite_hybrid.decode_loop(
+                    params, model, ids, pos, k, v, bt, ctx, steps_left,
+                    temp, top_p, min_p, top_k, seeds, num_steps=num_steps,
+                    attn_backend=attn_backend,
+                    max_table_positions=max_tables,
+                    sampling_top_window=cfg.sampling_top_window,
+                    layer_unroll=cfg.decode_layer_unroll, state=state,
+                )
+
+            window_fn = hybrid_window_fn
+
+        # The pools a window updates in place: K, V and a hybrid's state.
+        self._window_donate = (
+            (4, 5) if self.state_pool is None else (4, 5, 13)
+        )
+        self._decode_window = jax.jit(
+            window_fn, donate_argnums=self._window_donate
+        )
 
         # Mixed serving windows: chunk rows + the decode scan in ONE
         # dispatch (mistral.mixed_window; docs/serving.md). Built only
@@ -1141,6 +1203,14 @@ class LLMEngine:
             scope=self._compile_scope,
         ):
             self.kv.allocate()
+        if self.state_pool is not None:
+            with self._compile_watcher.phase(
+                'state_allocate', f'slots{cfg.max_num_seqs}', compiles=False,
+                scope=self._compile_scope,
+            ):
+                self.state_pool.allocate()
+            self.telemetry['state_pool_slots'] = self.state_pool.slots
+            self.telemetry['state_pool_bytes'] = self.state_pool.hbm_bytes
         # Merge host-known overrides (fresh admissions) into the device-
         # carried last-token vector between pipelined windows.
         self._merge_ids = jax.jit(
@@ -1204,9 +1274,85 @@ class LLMEngine:
                 # Param leaves report GLOBAL size/bytes under TP; the
                 # roofline scales the peaks by the mesh size to match.
                 num_devices=mesh.size if mesh is not None else 1,
+                # Routed experts: FLOPs count the parameters a token
+                # reaches, not the whole bank.
+                experts_per_token=getattr(model, 'experts_per_token', None),
             )
         except Exception as exc:
             self.telemetry['roofline_fallback'] = repr(exc)[:300]
+
+    @staticmethod
+    def _refuse_for_hybrid(cfg: EngineConfig, mesh, kv_pool_dtype) -> None:
+        """A hybrid model's sequence is its KV pages AND the recurrent
+        state at its last token. Whatever reuses, moves or rewinds KV
+        blocks without that state would serve wrong tokens, so each such
+        pairing is refused here, by name, until state snapshots exist."""
+        refused = {
+            'enable_prefix_cache': cfg.enable_prefix_cache
+            and 'a cached block is only a prefix with the recurrent state '
+            'at its boundary',
+            'host_kv_tier_bytes': bool(cfg.host_kv_tier_bytes)
+            and 'a spilled block is only a prefix with the recurrent state '
+            'at its boundary',
+            'enable_mixed_batching': cfg.enable_mixed_batching
+            and 'chunk rows inside a decode window would have to carry '
+            'recurrent state between windows',
+            'draft_k': bool(cfg.draft_k)
+            and 'a rejected draft would have to rewind the recurrent state',
+            'kv_cache_dtype=int8': jnp.dtype(kv_pool_dtype) == jnp.dtype(jnp.int8)
+            and 'the hybrid attention path has no quantized-page route',
+            'quantization': bool(cfg.quantization)
+            and 'the hybrid parameter tree has no quantized route',
+            'mesh': mesh is not None
+            and 'the recurrent state pool and the grouped expert matmul '
+            'have no partitioning',
+        }
+        for setting, why in refused.items():
+            if why:
+                raise ValueError(
+                    f'{setting} cannot serve a hybrid model (recurrent '
+                    f'layers beside attention layers): {why}; state '
+                    'snapshots are not implemented'
+                )
+
+    def _call_prefill_paged(self, ids, pos, bt, ctx, tails, slots=None):
+        """Dispatch the paged prefill program over device arrays and fold
+        its pools back; returns the last logits. ``slots`` is each row's
+        slot of a hybrid's state pool (the pool's size for a pad row)."""
+        if self.state_pool is None:
+            last_logits, self.kv.k, self.kv.v = self._call(
+                self._prefill_paged, self.params, ids, pos, self.kv.k,
+                self.kv.v, bt, ctx, tails,
+            )
+        else:
+            (
+                last_logits, self.kv.k, self.kv.v, self.state_pool.state,
+            ) = self._call(
+                self._prefill_paged, self.params, ids, pos, self.kv.k,
+                self.kv.v, bt, ctx, tails, self.state_pool.state, slots,
+            )
+        return last_logits
+
+    def _call_decode_window(self, *plan):
+        """Dispatch the decode window over its plan's device arrays (ids,
+        positions, context_lens, block_tables, steps_left, the sampling
+        rows) and fold its pools back. Returns ``(tokens, last_ids,
+        moe_pairs)``, the last None unless the family counts them."""
+        ids, pos, ctx, *rest = plan
+        if self.state_pool is None:
+            tokens, self.kv.k, self.kv.v, last_ids = self._call(
+                self._decode_window, self.params, ids, pos, ctx, self.kv.k,
+                self.kv.v, *rest,
+            )
+            return tokens, last_ids, None
+        (
+            tokens, self.kv.k, self.kv.v, last_ids, self.state_pool.state,
+            pairs,
+        ) = self._call(
+            self._decode_window, self.params, ids, pos, ctx, self.kv.k,
+            self.kv.v, *rest, self.state_pool.state,
+        )
+        return tokens, last_ids, pairs
 
     def _put(self, x):
         """Host value → device array, replicated over the mesh under TP."""
@@ -1256,10 +1402,13 @@ class LLMEngine:
             sds((b,), i32),  # top_k
             sds((b,), jnp.uint32),  # seeds
         )
+        if self.state_pool is not None:
+            shapes += (self.state_pool.spec(),)
         jitted = jax.jit(
             window_fn,
-            donate_argnums=(4, 5),
-            in_shardings=(Format(Layout.AUTO),) + (Format(),) * 12,
+            donate_argnums=self._window_donate,
+            in_shardings=(Format(Layout.AUTO),)
+            + (Format(),) * (len(shapes) - 1),
         )
         compiled = jitted.lower(*shapes).compile()
         return compiled, compiled.input_formats[0][0]
@@ -1447,31 +1596,36 @@ class LLMEngine:
                 last_pos = np.zeros((b,), np.int32)
                 lengths = np.zeros((b,), np.int32)  # all writes -> trash
                 block_rows = np.zeros((b, self.max_blocks_per_seq), np.int32)
-                with watch.phase(
-                    'prefill', f'b{b}x{bucket}{qtag}', scope=self._compile_scope
-                ):
-                    # Through _call, as in serving: a program lowered again
-                    # there is compared with the signature it had here.
-                    logits, k_all, v_all = self._call(
-                        self._prefill,
-                        self.params,
-                        self._put(ids),
-                        self._put(mask),
-                        self._put(last_pos),
-                    )
-                    self.kv.k, self.kv.v = self._call(
-                        self._write_prefill,
-                        self.kv.k,
-                        self.kv.v,
-                        k_all,
-                        v_all,
-                        self._put(block_rows),
-                        self._put(lengths),
-                    )
-                    np.asarray(self._sample_device(logits, [None] * b))
+                # A hybrid has no dense route: all its prefills are paged.
+                if self._prefill is not None:
+                    with watch.phase(
+                        'prefill', f'b{b}x{bucket}{qtag}',
+                        scope=self._compile_scope,
+                    ):
+                        # Through _call, as in serving: a program lowered
+                        # again there is compared with the signature it
+                        # had here.
+                        logits, k_all, v_all = self._call(
+                            self._prefill,
+                            self.params,
+                            self._put(ids),
+                            self._put(mask),
+                            self._put(last_pos),
+                        )
+                        self.kv.k, self.kv.v = self._call(
+                            self._write_prefill,
+                            self.kv.k,
+                            self.kv.v,
+                            k_all,
+                            v_all,
+                            self._put(block_rows),
+                            self._put(lengths),
+                        )
+                        np.asarray(self._sample_device(logits, [None] * b))
                 if (
                     self.prefix_cache is not None
                     or self.config.prefill_chunk_tokens
+                    or self._prefill is None
                 ):
                     # Paged-context prefill shapes (cache-hit tails and
                     # chunks dispatch through prefill_paged): tail_lens 0
@@ -1493,16 +1647,12 @@ class LLMEngine:
                             np.ones((b,), np.int32),
                             np.zeros((b,), np.int32),
                         )
-                        pg_logits, self.kv.k, self.kv.v = self._call(
-                            self._prefill_paged,
-                            self.params,
-                            ids_dev,
-                            pos_dev,
-                            self.kv.k,
-                            self.kv.v,
-                            rows_dev,
-                            ctx_dev,
-                            tails_dev,
+                        pg_logits = self._call_prefill_paged(
+                            ids_dev, pos_dev, rows_dev, ctx_dev, tails_dev,
+                            # Pad rows: a hybrid's state writes are dropped.
+                            self._put(np.full(
+                                (b,), self.config.max_num_seqs, np.int32
+                            )) if self.state_pool is not None else None,
                         )
                         np.asarray(
                             self._sample_device(pg_logits, [None] * b)
@@ -1576,13 +1726,10 @@ class LLMEngine:
             'decode_window', f'b{bsz}x{self.config.decode_steps}{qtag}',
             scope=self._compile_scope,
         ):
-            tokens, self.kv.k, self.kv.v, _ = self._decode_window(
-                self.params,
+            tokens, _, _ = self._call_decode_window(
                 self._put(np.zeros((bsz,), np.int32)),
                 self._put(np.zeros((bsz,), np.int32)),
                 self._put(np.ones((bsz,), np.int32)),
-                self.kv.k,
-                self.kv.v,
                 self._put(np.zeros((bsz, self.max_blocks_per_seq), np.int32)),
                 self._put(np.zeros((bsz,), np.int32)),
                 self._put(np.zeros((bsz,), np.float32)),
@@ -1784,6 +1931,11 @@ class LLMEngine:
         :meth:`measured_costs` alone.
         """
         if self._cost_model is None:
+            return
+        if self.state_pool is not None:
+            self.telemetry.setdefault(
+                'xla_cost_skipped', 'hybrid programs are not priced'
+            )
             return
         cfg = self.config
         bsz = cfg.max_num_seqs
@@ -2140,6 +2292,7 @@ class LLMEngine:
                 tail = request.num_tokens - request.num_cached_tokens
                 paged_route = bool(
                     request.num_cached_tokens or (chunk and tail > chunk)
+                    or self._prefill is None
                 )
                 if ride and paged_route:
                     # Only paged-route tails ride windows: their spans go
@@ -3106,28 +3259,18 @@ class LLMEngine:
         ids, positions, block_rows, context_lens, tail_lens = (
             self._span_host_arrays(spans, bucket, b)
         )
+        host_arrays = [ids, positions, block_rows, context_lens, tail_lens]
+        if self.state_pool is not None:
+            # Each row's slot of the state pool: the scheduler's slot of
+            # its sequence; a pad row's lies past the pool.
+            slot_of = {rid: slot for slot, rid in self.sched.running()}
+            slots = np.full((b,), self.state_pool.slots, np.int32)
+            slots[: len(requests)] = [slot_of[r.request_id] for r in requests]
+            host_arrays.append(slots)
         step.mark('put')
-        (
-            ids_dev,
-            positions_dev,
-            block_rows_dev,
-            context_lens_dev,
-            tail_lens_dev,
-        ) = self._put_many(
-            ids, positions, block_rows, context_lens, tail_lens
-        )
+        devs = self._put_many(*host_arrays)
         step.mark('prefill')
-        last_logits, self.kv.k, self.kv.v = self._call(
-            self._prefill_paged,
-            self.params,
-            ids_dev,
-            positions_dev,
-            self.kv.k,
-            self.kv.v,
-            block_rows_dev,
-            context_lens_dev,
-            tail_lens_dev,
-        )
+        last_logits = self._call_prefill_paged(*devs)
         step.mark('emit')
         self._note_prefill([(r, ntok) for r, _, ntok in spans], route)
         emitted: list[tuple[int, int]] = []
@@ -3678,6 +3821,7 @@ class LLMEngine:
         if carried_ids is not None:
             ids_dev = self._merge_ids(carried_ids, override_dev, ids_dev)
         chunk_tokens = None
+        moe_pairs = None
         chunk_entries: list[tuple[int, int, int, int, bool]] = []
         if chunk_plan:
             (
@@ -3718,14 +3862,10 @@ class LLMEngine:
             _metrics.MIXED_PREFILL_TOKENS_PER_WINDOW.observe(ridden)
             _metrics.MIXED_PREFILL_ROWS.observe(len(chunk_plan))
         else:
-            tokens, self.kv.k, self.kv.v, last_ids = self._call(
-                self._decode_window,
-                self.params,
+            tokens, last_ids, moe_pairs = self._call_decode_window(
                 ids_dev,
                 positions_dev,
                 context_lens_dev,
-                self.kv.k,
-                self.kv.v,
                 block_tables_dev,
                 steps_left_dev,
                 temperature_dev,
@@ -3752,6 +3892,9 @@ class LLMEngine:
             'chunk_tokens': chunk_tokens,
             'chunk_plan': chunk_entries,
             'context_lens': context_arrays,
+            # A hybrid's extra: the window's (routed, held) expert pairs,
+            # fetched with its tokens.
+            'moe_pairs': moe_pairs,
             # The step's span so far (admit/plan/put/dispatch), completed
             # with fetch and emit when _process_window syncs the tokens.
             'step': step,
@@ -4121,6 +4264,10 @@ class LLMEngine:
         step.mark('fetch')
         # distlint: disable=host-sync-in-hot-path -- the window loop's ONE designed fetch point: processing happens a window late, after the next dispatch is already in flight (pipeline_depth hides this sync)
         tokens = np.asarray(window['tokens'])  # [K, B]
+        moe_pairs = window.get('moe_pairs')
+        if moe_pairs is not None:
+            # distlint: disable=host-sync-in-hot-path -- two int32 the window's program wrote with the tokens fetched one line up: ready, never waited for
+            moe_pairs = np.asarray(moe_pairs)
         t_fetched = step.mark('emit')
         emitted: list[tuple[int, int]] = []
         chunk_entries = window.get('chunk_plan') or []
@@ -4153,6 +4300,11 @@ class LLMEngine:
                 extra = {
                     'prefill_tokens': sum(n for *_, n, _ in chunk_entries),
                     'prefill_rows': len(chunk_entries),
+                }
+            if moe_pairs is not None:
+                extra = {
+                    'moe_pairs': int(moe_pairs[0]),
+                    'moe_pairs_held': int(moe_pairs[1]),
                 }
             self._record_step(
                 'mixed' if chunk_entries else 'decode',
@@ -4624,7 +4776,16 @@ class LLMEngine:
             t_admit_s=round(request.t_admit, 6) if request.t_admit else None,
             t_first_s=round(request.t_first_token, 6)
             if request.t_first_token else None,
+            **self._state_slot_field(request),
         )
+
+    def _state_slot_field(self, request: Request) -> dict:
+        """The slot of a hybrid's state pool a request held when it
+        finished (it is freed right after this record; the pool keeps what
+        the slot held until its next holder's first prefill span)."""
+        if self.state_pool is None:
+            return {}
+        return {'state_slot': self.sched.slot(request.request_id)}
 
     # -------------------------------------------------------------- offline
     def generate_ids(

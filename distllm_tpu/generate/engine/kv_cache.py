@@ -29,6 +29,11 @@ keyed by the same chained digests and exchange the same ``.kvblock`` v2
 payload (:func:`encode_kvblock` / :func:`decode_kvblock`); the disk
 tier's digest-named files persist warm prefixes across engine restarts.
 
+:class:`StatePool` holds what a sequence of a hybrid model keeps beside
+its pages: the recurrent layers' fixed state, one slot a running sequence
+(the scheduler's slot), per-layer buffers the decode window rewrites in
+place. :class:`PagedKVCache` is then built over the paged layers only.
+
 Mixed serving windows (docs/serving.md) write prefill-chunk K/V inside
 decode dispatches; those writes always land in blocks the owning request
 was granted at admission (the full prompt is budgeted up front), so no
@@ -1052,3 +1057,52 @@ class PagedKVCache:
         return int(sum(
             leaf.nbytes for leaf in jax.tree.leaves((self.k, self.v))
         ))
+
+
+class StatePool:
+    """Device-resident fixed state of the sequences of a model with
+    recurrent layers, beside their KV pages (pure container, like
+    ``PagedKVCache``).
+
+    ``spec`` is what ONE sequence holds (the model family's
+    ``state_spec()``: a pytree of ``ShapeDtypeStruct``, one leaf per
+    recurrent layer and kind of state); the pool is that tree with a leading
+    ``[slots]`` on every leaf, each leaf a buffer of its own so that the
+    decode window rewrites it whole and in place. A slot IS the scheduler's
+    slot of a running sequence (the decode batch's row), so taking and
+    freeing one is the scheduler's admission, finish and preemption; there
+    is no second free-list here to fall out of step. A sequence's first
+    prefill span starts from zero state whatever its slot held, and a pad
+    row's slot (``slots``, one past the pool) is dropped on write.
+    """
+
+    def __init__(self, spec, slots: int, lazy: bool = False) -> None:
+        self.slots = slots
+        self.seq_spec = spec
+        self.state = None
+        if not lazy:
+            self.allocate()
+
+    def spec(self):
+        """Shape/dtype pytree of the pool (AOT compilation input)."""
+        return jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct((self.slots, *s.shape), s.dtype),
+            self.seq_spec,
+        )
+
+    def allocate(self) -> None:
+        if self.state is None:
+            self.state = jax.tree.map(
+                lambda s: jnp.zeros(s.shape, s.dtype), self.spec()
+            )
+
+    @property
+    def bytes_per_slot(self) -> int:
+        return int(sum(
+            int(np.prod(s.shape)) * jnp.dtype(s.dtype).itemsize
+            for s in jax.tree.leaves(self.seq_spec)
+        ))
+
+    @property
+    def hbm_bytes(self) -> int:
+        return self.slots * self.bytes_per_slot

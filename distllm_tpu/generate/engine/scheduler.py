@@ -63,6 +63,15 @@ fetches it before it dispatches the next
 (``LLMEngine._head_waits_on_inflight``), so the head joins the next
 window and none carries only the rows that outlive a wave.
 
+Two kinds of state (a hybrid model, ``kv_cache.StatePool``). A sequence of
+a model with recurrent layers holds blocks of its PAGED layers' pool and one
+slot of the state pool. The slot is this scheduler's slot: ``admit_next``
+takes the lowest free one, ``finish`` and preemption free it, so the
+look-ahead's budget for a sequence is blocks of the paged layers plus one
+slot, and "no free slot" is the deferral it already had. Where only one
+layer in ten has pages (4 KiB a token), the pool rarely bounds the batch;
+``max_num_seqs`` and the state bytes behind each slot do.
+
 Borrowed prefixes (automatic prefix caching, docs/prefix_caching.md): a
 request's block row may start with blocks OWNED BY THE PREFIX CACHE —
 attached at ``add`` (cache hit) or marked afterwards with ``lend_prefix``

@@ -20,7 +20,7 @@ def decoder_families() -> dict:
     from these rows plus the encoder-only families
     (``embed/encoders/auto.py``) — a new decoder lands in one place.
     """
-    from distllm_tpu.models import gemma, mistral, mixtral
+    from distllm_tpu.models import gemma, granite_hybrid, mistral, mixtral
 
     return {
         'mistral': (mistral.MistralConfig, mistral),
@@ -29,6 +29,9 @@ def decoder_families() -> dict:
         'mixtral': (mixtral.MixtralConfig, mixtral),
         'gemma': (gemma.GemmaConfig, gemma),
         'gemma2': (gemma.GemmaConfig, gemma),
+        'granitemoehybrid': (
+            granite_hybrid.GraniteHybridConfig, granite_hybrid
+        ),
     }
 
 

@@ -1,0 +1,841 @@
+"""Granite 4.0-H (``granitemoehybrid``): Mamba-2 mixers with an attention
+mixer every few layers, a routed expert layer plus a shared gated MLP in
+every layer, no positional encoding, Granite's four multipliers.
+
+Two kinds of layer, so two stacked parameter trees (``params['mamba']``,
+``params['attention']``); the forwards walk ``cfg.layer_types`` and scan each
+run of equal layers. A sequence holds two kinds of state: K/V pages for the
+attention layers (``PagedKVCache`` over ``cfg.num_paged_layers`` layers) and,
+for every Mamba layer, a fixed recurrent state (``cfg.state_spec()``): the
+SSM state ``[heads, head_dim, d_state]`` in float32 and the last
+``d_conv - 1`` columns of the convolution's input in the model's dtype. The
+state pool is a tuple of one array per Mamba layer, ``[slots, ...]``: the
+decode window updates whole buffers in place, prefill gathers and scatters
+the rows of the slots it runs.
+
+The attention mixers call the serving attention entry points that
+``models/mistral.py`` calls (``common.sdpa``, ``ragged_paged_attention``,
+``write_chunk_kv``, ``write_token_kv``) with ``scale=attention_multiplier``
+and no rotation. The expert layer is ``models/moe.py``: a chip may hold a
+share of the routed experts (``first_local_expert``, ``num_local_experts``)
+while the router ranks all ``num_experts``.
+
+Equations (transformers ``models/granitemoehybrid``):
+
+    x = E[ids] * embedding_multiplier
+    x = x + residual_multiplier * mixer(rms(x))
+    x = x + residual_multiplier * (moe(rms(x)) + shared(rms(x)))
+    logits = rms(x) @ E^T / logits_scaling
+
+    Mamba-2: [z, xBC, dt] = h @ W_in; xBC = silu(causal_conv(xBC) + b)
+             -> x, B, C; dt = softplus(dt + dt_bias); A = -exp(A_log)
+             S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T; y_t = S_t C_t + D x_t
+             out = (rms(y * silu(z)) * w) @ W_out
+"""
+
+from __future__ import annotations
+
+from typing import Literal
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import PartitionSpec as P
+
+from distllm_tpu.models import common
+from distllm_tpu.models.mistral import _kv_layer, _kv_layer_update
+from distllm_tpu.models.moe import routed_experts
+from distllm_tpu.utils import BaseConfig
+
+F32 = jnp.float32
+
+
+class GraniteHybridConfig(BaseConfig):
+    name: Literal['granitemoehybrid'] = 'granitemoehybrid'
+    vocab_size: int = 100352
+    hidden_size: int = 4096
+    layer_types: tuple[Literal['mamba', 'attention'], ...] = ('mamba',)
+    num_heads: int = 32
+    num_kv_heads: int = 8
+    head_dim: int | None = None
+    mamba_n_heads: int = 128
+    mamba_d_head: int = 64
+    mamba_d_state: int = 128
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 256
+    intermediate_size: int = 768  # width of one routed expert
+    shared_intermediate_size: int = 1536
+    # The router ranks num_experts; this chip holds num_local_experts of
+    # them, ids first_local_expert onward (all of them by default).
+    num_experts: int = 72
+    num_local_experts: int = 72
+    first_local_expert: int = 0
+    experts_per_token: int = 10
+    embedding_multiplier: float = 12.0
+    attention_multiplier: float = 0.0078125
+    residual_multiplier: float = 0.22
+    logits_scaling: float = 16.0
+    rms_norm_eps: float = 1e-5
+    max_position_embeddings: int = 131072
+    tie_word_embeddings: bool = True
+    dtype: str = 'bfloat16'
+
+    @property
+    def num_layers(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def num_paged_layers(self) -> int:
+        """Layers that own KV pages: the attention layers."""
+        return self.layer_types.count('attention')
+
+    @property
+    def num_mamba_layers(self) -> int:
+        return self.layer_types.count('mamba')
+
+    @property
+    def head_size(self) -> int:
+        return self.head_dim or self.hidden_size // self.num_heads
+
+    @property
+    def d_inner(self) -> int:
+        return self.mamba_n_heads * self.mamba_d_head
+
+    @property
+    def conv_dim(self) -> int:
+        return self.d_inner + 2 * self.mamba_d_state
+
+    def layer_runs(self) -> list[tuple[str, int, int]]:
+        """``(kind, first index in its kind's tree, count)`` of every run of
+        equal consecutive layers."""
+        runs: list[list] = []
+        seen = {'mamba': 0, 'attention': 0}
+        for kind in self.layer_types:
+            if runs and runs[-1][0] == kind:
+                runs[-1][2] += 1
+            else:
+                runs.append([kind, seen[kind], 1])
+            seen[kind] += 1
+        return [tuple(r) for r in runs]
+
+    def state_spec(self) -> dict:
+        """What one sequence holds beside its KV pages: per Mamba layer the
+        shape and dtype of its SSM state and of its convolution state. The
+        SSM state is float32 and the convolution state the model's dtype;
+        that is this module's, not a setting."""
+        n = self.num_mamba_layers
+        ssm = jax.ShapeDtypeStruct(
+            (self.mamba_n_heads, self.mamba_d_head, self.mamba_d_state), F32
+        )
+        conv = jax.ShapeDtypeStruct(
+            (self.mamba_d_conv - 1, self.conv_dim), jnp.dtype(self.dtype)
+        )
+        return {'ssm': (ssm,) * n, 'conv': (conv,) * n}
+
+    @classmethod
+    def from_hf_config(cls, hf: dict) -> 'GraniteHybridConfig':
+        if hf.get('position_embedding_type', 'nope') != 'nope':
+            raise ValueError(
+                'granitemoehybrid: only position_embedding_type "nope" is '
+                f"implemented, got {hf['position_embedding_type']!r}"
+            )
+        if hf.get('mamba_n_groups', 1) != 1:
+            raise ValueError(
+                'granitemoehybrid: only mamba_n_groups 1 is implemented, '
+                f"got {hf['mamba_n_groups']}"
+            )
+        if hf.get('mamba_proj_bias', False) or hf.get('attention_bias', False):
+            raise ValueError('granitemoehybrid: projection biases not implemented')
+        d_inner = hf['mamba_n_heads'] * hf['mamba_d_head']
+        if d_inner != hf.get('mamba_expand', 2) * hf['hidden_size']:
+            raise ValueError(
+                'granitemoehybrid: mamba_n_heads * mamba_d_head must equal '
+                'mamba_expand * hidden_size'
+            )
+        held = hf['num_local_experts']
+        return cls(
+            vocab_size=hf['vocab_size'],
+            hidden_size=hf['hidden_size'],
+            layer_types=tuple(hf['layer_types']),
+            num_heads=hf['num_attention_heads'],
+            num_kv_heads=hf.get('num_key_value_heads', hf['num_attention_heads']),
+            mamba_n_heads=hf['mamba_n_heads'],
+            mamba_d_head=hf['mamba_d_head'],
+            mamba_d_state=hf['mamba_d_state'],
+            mamba_d_conv=hf['mamba_d_conv'],
+            mamba_chunk_size=hf.get('mamba_chunk_size', 256),
+            intermediate_size=hf['intermediate_size'],
+            shared_intermediate_size=hf['shared_intermediate_size'],
+            # Two keys beside the published ones state a chip's share.
+            num_experts=hf.get('num_routed_experts', held),
+            num_local_experts=held,
+            first_local_expert=hf.get('first_local_expert', 0),
+            experts_per_token=hf['num_experts_per_tok'],
+            embedding_multiplier=hf['embedding_multiplier'],
+            attention_multiplier=hf['attention_multiplier'],
+            residual_multiplier=hf['residual_multiplier'],
+            logits_scaling=hf['logits_scaling'],
+            rms_norm_eps=hf.get('rms_norm_eps', 1e-5),
+            max_position_embeddings=hf.get('max_position_embeddings', 131072),
+            tie_word_embeddings=hf.get('tie_word_embeddings', True),
+        )
+
+
+# ------------------------------------------------------------- parameters
+def _layer_shapes(cfg: GraniteHybridConfig, kind: str) -> dict:
+    """``name -> shape`` of one layer's parameters (kernels ``[in, out]``)."""
+    h, i, s = cfg.hidden_size, cfg.intermediate_size, cfg.shared_intermediate_size
+    e = cfg.num_local_experts
+    shapes = {
+        'ln': (h,), 'mlp_ln': (h,),
+        'router': (h, cfg.num_experts),
+        'gate': (e, h, i), 'up': (e, h, i), 'down': (e, i, h),
+        'shared_gate': (h, s), 'shared_up': (h, s), 'shared_down': (s, h),
+    }
+    if kind == 'attention':
+        q_out = cfg.num_heads * cfg.head_size
+        kv_out = cfg.num_kv_heads * cfg.head_size
+        shapes.update(q=(h, q_out), k=(h, kv_out), v=(h, kv_out), o=(q_out, h))
+    else:
+        shapes.update(
+            in_proj=(h, cfg.d_inner + cfg.conv_dim + cfg.mamba_n_heads),
+            conv=(cfg.mamba_d_conv, cfg.conv_dim), conv_bias=(cfg.conv_dim,),
+            dt_bias=(cfg.mamba_n_heads,), A_log=(cfg.mamba_n_heads,),
+            D=(cfg.mamba_n_heads,), norm=(cfg.d_inner,),
+            out_proj=(cfg.d_inner, h),
+        )
+    return shapes
+
+
+_SCALES = ('ln', 'mlp_ln', 'norm')  # {'scale': ...} leaves
+_VECTORS = ('conv', 'conv_bias', 'dt_bias', 'A_log', 'D')  # bare leaves
+
+
+def _wrap(name: str, leaf):
+    if name in _SCALES:
+        return {'scale': leaf}
+    if name in _VECTORS:
+        return leaf
+    return {'kernel': leaf}
+
+
+def init_on_device(rng: jax.Array, cfg: GraniteHybridConfig) -> dict:
+    """Random parameters made on the device in ``cfg.dtype``: normal(0,
+    0.02) kernels, unit norm scales and ``D``, ``A`` uniform in [1, 16] and
+    ``dt`` log-uniform in [0.001, 0.1] (the published initialisation's
+    ranges), one RNG call per parameter kind."""
+    dtype = jnp.dtype(cfg.dtype)
+
+    @jax.jit
+    def build(key):
+        def leaf(key, name, shape):
+            if name in _SCALES or name == 'D':
+                return jnp.ones(shape, dtype)
+            if name == 'A_log':
+                return jnp.log(jax.random.uniform(key, shape, F32, 1.0, 16.0))
+            if name == 'dt_bias':
+                dt = jnp.exp(jax.random.uniform(
+                    key, shape, F32, np.log(0.001), np.log(0.1)
+                ))
+                return dt + jnp.log(-jnp.expm1(-dt))  # softplus^-1
+            scale = 0.5 if name in ('conv', 'conv_bias') else 0.02
+            return (jax.random.normal(key, shape, F32) * scale).astype(dtype)
+
+        params = {
+            'embed': leaf(
+                jax.random.fold_in(key, 0), 'embed',
+                (cfg.vocab_size, cfg.hidden_size),
+            ),
+            'final_ln': {'scale': jnp.ones((cfg.hidden_size,), dtype)},
+        }
+        for ki, (kind, count) in enumerate((
+            ('mamba', cfg.num_mamba_layers),
+            ('attention', cfg.num_paged_layers),
+        )):
+            kkey = jax.random.fold_in(key, ki + 1)
+            shapes = _layer_shapes(cfg, kind)
+            params[kind] = {
+                name: _wrap(name, leaf(
+                    jax.random.fold_in(kkey, ni), name, (count, *shape)
+                ))
+                for ni, (name, shape) in enumerate(sorted(shapes.items()))
+            }
+        return params
+
+    return build(rng)
+
+
+def param_specs(cfg: GraniteHybridConfig, params: dict | None = None) -> dict:
+    """Expert banks over ``expert``, everything else replicated."""
+    specs = {'embed': P(None, None), 'final_ln': {'scale': P()}}
+    for kind in ('mamba', 'attention'):
+        specs[kind] = {
+            name: _wrap(
+                name,
+                P(None, 'expert', None, None)
+                if name in ('gate', 'up', 'down')
+                else P(*(None,) * (len(shape) + 1)),
+            )
+            for name, shape in _layer_shapes(cfg, kind).items()
+        }
+    return specs
+
+
+def params_from_hf(state: dict[str, np.ndarray], cfg: GraniteHybridConfig) -> dict:
+    """Convert HF ``GraniteMoeHybridForCausalLM`` weights. HF stacks the
+    experts' ``input_linear`` as ``[E, 2 * I, H]``, gate rows first; a chip
+    that holds a share keeps experts ``first_local_expert`` onward."""
+    sd = {k.removeprefix('model.'): np.asarray(v) for k, v in state.items()}
+    lo = cfg.first_local_expert
+    held = slice(lo, lo + cfg.num_local_experts)
+    i, s = cfg.intermediate_size, cfg.shared_intermediate_size
+
+    def t(key):  # torch Linear [out, in] -> [in, out]
+        return np.ascontiguousarray(sd[key].T)
+
+    def layer(li: int, kind: str) -> dict:
+        p = f'layers.{li}'
+        moe_in = sd[f'{p}.block_sparse_moe.input_linear.weight'][held]
+        shared_in = sd[f'{p}.shared_mlp.input_linear.weight']
+        out = {
+            'ln': sd[f'{p}.input_layernorm.weight'],
+            'mlp_ln': sd[f'{p}.post_attention_layernorm.weight'],
+            'router': t(f'{p}.block_sparse_moe.router.layer.weight'),
+            'gate': np.ascontiguousarray(moe_in[:, :i].transpose(0, 2, 1)),
+            'up': np.ascontiguousarray(moe_in[:, i:].transpose(0, 2, 1)),
+            'down': np.ascontiguousarray(
+                sd[f'{p}.block_sparse_moe.output_linear.weight'][held]
+                .transpose(0, 2, 1)
+            ),
+            'shared_gate': np.ascontiguousarray(shared_in[:s].T),
+            'shared_up': np.ascontiguousarray(shared_in[s:].T),
+            'shared_down': t(f'{p}.shared_mlp.output_linear.weight'),
+        }
+        if kind == 'attention':
+            for name in 'qkvo':
+                out[name] = t(f'{p}.self_attn.{name}_proj.weight')
+        else:
+            out.update(
+                in_proj=t(f'{p}.mamba.in_proj.weight'),
+                # torch depthwise Conv1d weight [C, 1, K] -> [K, C]
+                conv=np.ascontiguousarray(sd[f'{p}.mamba.conv1d.weight'][:, 0].T),
+                conv_bias=sd[f'{p}.mamba.conv1d.bias'],
+                dt_bias=sd[f'{p}.mamba.dt_bias'],
+                A_log=sd[f'{p}.mamba.A_log'],
+                D=sd[f'{p}.mamba.D'],
+                norm=sd[f'{p}.mamba.norm.weight'],
+                out_proj=t(f'{p}.mamba.out_proj.weight'),
+            )
+        return {name: _wrap(name, leaf) for name, leaf in out.items()}
+
+    params = {
+        'embed': sd['embed_tokens.weight'][: cfg.vocab_size],
+        'final_ln': {'scale': sd['norm.weight']},
+    }
+    for kind in ('mamba', 'attention'):
+        params[kind] = common.stack_layers([
+            layer(li, kind)
+            for li, lt in enumerate(cfg.layer_types) if lt == kind
+        ])
+    return params
+
+
+# ------------------------------------------------------------ shared parts
+def _norm(x, scale, cfg):
+    return common.rms_norm(x, scale, cfg.rms_norm_eps)
+
+
+def _embed(params, cfg, input_ids):
+    dtype = jnp.dtype(cfg.dtype)
+    x = jnp.asarray(params['embed'])[input_ids].astype(dtype)
+    return x * jnp.asarray(cfg.embedding_multiplier, dtype)
+
+
+_BANKS = ('gate', 'up', 'down')
+
+
+def _mlp(x, lp, cfg, counted, banks, li):
+    """``moe(h) + shared(h)`` of one layer for ``x [T, H]`` (already
+    normed); returns the sum and the layer's pair counts. ``banks`` is the
+    kind's whole tree: the expert banks stay stacked, ``li`` picks the
+    layer inside the grouped matmul (``models/moe.py``)."""
+    routed, pairs = routed_experts(
+        x, lp['router']['kernel'], *(banks[n]['kernel'] for n in _BANKS),
+        cfg.experts_per_token, first_expert=cfg.first_local_expert,
+        counted=counted, layer=li,
+    )
+    shared = common.dense(
+        common.silu(common.dense(x, lp['shared_gate']['kernel']))
+        * common.dense(x, lp['shared_up']['kernel']),
+        lp['shared_down']['kernel'],
+    )
+    return routed + shared, pairs
+
+
+def _finish_layer(x, mixed, lp, cfg, counted, banks, li):
+    """Residual of the mixer's output, then the MLP block."""
+    res = jnp.asarray(cfg.residual_multiplier, x.dtype)
+    x = x + mixed * res
+    normed = _norm(x, lp['mlp_ln']['scale'], cfg)
+    flat = normed.reshape(-1, normed.shape[-1])
+    mlp, pairs = _mlp(flat, lp, cfg, counted.reshape(-1), banks, li)
+    return x + mlp.reshape(x.shape) * res, pairs
+
+
+def logits(params: dict, cfg: GraniteHybridConfig, hidden: jnp.ndarray) -> jnp.ndarray:  # distlint: traced
+    """``hidden`` is already final-normed; tied head over the held rows of
+    the embedding, divided by ``logits_scaling``."""
+    out = common.dense(hidden, jnp.asarray(params['embed']).T).astype(F32)
+    return out / cfg.logits_scaling
+
+
+# ------------------------------------------------------------------ Mamba-2
+def ssd_chunked(x, dt, a, b_in, c_in, ssm0, chunk: int):  # distlint: traced
+    """The Mamba-2 recurrence over a span, evaluated ``chunk`` steps at a
+    time (the SSD form): inside a chunk as one masked matrix product, from
+    chunk to chunk through the carried state. ``x [B, S, H, P]``, ``dt [B,
+    S, H]`` (0 where a position does not count: the state passes through),
+    ``a [H]`` negative, ``b_in``/``c_in [B, S, N]``, ``ssm0 [B, H, P, N]``.
+    Returns ``y [B, S, H, P]`` (without the ``D`` skip) and the state after
+    the last position, float32. Any ``chunk`` gives the same numbers."""
+    bsz, s, h, p = x.shape
+    pad = -s % chunk
+    if pad:
+        x, dt, b_in, c_in = (
+            jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+            for t in (x, dt, b_in, c_in)
+        )
+    n_chunks = (s + pad) // chunk
+
+    def split(t):  # [B, S, ...] -> [C, B, Q, ...]
+        return jnp.moveaxis(
+            t.reshape(bsz, n_chunks, chunk, *t.shape[2:]), 1, 0
+        ).astype(F32)
+
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+
+    def one_chunk(ssm, xs):
+        x_c, dt_c, b_c, c_c = xs  # [B, Q, H, P], [B, Q, H], [B, Q, N] x2
+        cum = jnp.cumsum(dt_c * a, axis=1)  # [B, Q, H], decreasing
+        # From the state the chunk starts with.
+        y = jnp.einsum('bqn,bhpn->bqhp', c_c, ssm) * jnp.exp(cum)[..., None]
+        # Inside the chunk: position i sees j <= i, decayed by cum_i - cum_j.
+        diff = cum[:, :, None, :] - cum[:, None, :, :]  # [B, Qi, Qj, H]
+        decay = jnp.exp(jnp.where(lower[None, :, :, None], diff, -jnp.inf))
+        scores = jnp.einsum('bin,bjn->bij', c_c, b_c)  # [B, Qi, Qj]
+        w = scores[..., None] * decay * dt_c[:, None, :, :]  # [B, Qi, Qj, H]
+        y = y + jnp.einsum('bijh,bjhp->bihp', w, x_c)
+        # The state the next chunk starts with.
+        tail = jnp.exp(cum[:, -1:, :] - cum) * dt_c  # [B, Q, H]
+        ssm = ssm * jnp.exp(cum[:, -1])[..., None, None] + jnp.einsum(
+            'bqh,bqhp,bqn->bhpn', tail, x_c, b_c
+        )
+        return ssm, y
+
+    ssm, y = jax.lax.scan(
+        one_chunk, ssm0.astype(F32), tuple(split(t) for t in (x, dt, b_in, c_in))
+    )
+    y = jnp.moveaxis(y, 0, 1).reshape(bsz, s + pad, h, p)
+    return y[:, :s], ssm
+
+
+def _mamba_inputs(lp, cfg, conv_window):
+    """The convolution of a Mamba mixer and its split. ``conv_window`` is
+    the convolution's input, ``[..., K - 1 + S, conv_dim]``: the carried
+    columns, then the span's own. Returns ``x [..., S, heads, P]``, ``B``
+    and ``C [..., S, N]``, float32."""
+    k = cfg.mamba_d_conv
+    s = conv_window.shape[-2] - (k - 1)
+    w = lp['conv'].astype(F32)
+    win = conv_window.astype(F32)
+    conv = sum(
+        w[j] * jax.lax.slice_in_dim(win, j, j + s, axis=-2) for j in range(k)
+    ) + lp['conv_bias'].astype(F32)
+    xbc = jax.nn.silu(conv)
+    di, n = cfg.d_inner, cfg.mamba_d_state
+    x = xbc[..., :di].reshape(*xbc.shape[:-1], cfg.mamba_n_heads, cfg.mamba_d_head)
+    return x, xbc[..., di:di + n], xbc[..., di + n:]
+
+
+def _mamba_out(y, z, lp, cfg, dtype):
+    """Gate first, then the norm over all of ``d_inner`` (one group), then
+    the output projection."""
+    gated = y.reshape(*y.shape[:-2], cfg.d_inner) * jax.nn.silu(z.astype(F32))
+    normed = common.rms_norm(gated, lp['norm']['scale'], cfg.rms_norm_eps)
+    return common.dense(normed.astype(dtype), lp['out_proj']['kernel'])
+
+
+def _split_in_proj(h, lp, cfg):
+    proj = common.dense(h, lp['in_proj']['kernel'])
+    di, cd = cfg.d_inner, cfg.conv_dim
+    return proj[..., :di], proj[..., di:di + cd], proj[..., di + cd:]
+
+
+def _dt_a(dt_raw, lp):
+    dt = jax.nn.softplus(dt_raw.astype(F32) + lp['dt_bias'].astype(F32))
+    return dt, -jnp.exp(lp['A_log'].astype(F32))
+
+
+def mamba_span(h, lp, cfg, ssm0, conv0, tail_lens):  # distlint: traced
+    """A Mamba-2 mixer over a span ``h [B, S, H]`` that starts from state
+    (``ssm0 [B, heads, P, N]`` float32, ``conv0 [B, K - 1, conv_dim]``) and
+    counts the first ``tail_lens [B]`` positions of each row. Returns the
+    output and the state after each row's last counted position."""
+    with jax.named_scope('distllm.ssm_prefill'):
+        s = h.shape[1]
+        z, xbc, dt_raw = _split_in_proj(h, lp, cfg)
+        window = jnp.concatenate([conv0.astype(xbc.dtype), xbc], axis=1)
+        x, b_in, c_in = _mamba_inputs(lp, cfg, window)
+        dt, a = _dt_a(dt_raw, lp)
+        valid = jnp.arange(s)[None, :] < tail_lens[:, None]
+        dt = jnp.where(valid[..., None], dt, 0.0)
+        y, ssm = ssd_chunked(x, dt, a, b_in, c_in, ssm0, cfg.mamba_chunk_size)
+        y = y + lp['D'].astype(F32)[:, None] * x
+        # The last K - 1 counted columns: carried ones where the row is short.
+        idx = tail_lens[:, None] + jnp.arange(cfg.mamba_d_conv - 1)[None, :]
+        conv = jnp.take_along_axis(window, idx[..., None], axis=1)
+        return _mamba_out(y, z, lp, cfg, h.dtype), ssm, conv.astype(conv0.dtype)
+
+
+def mamba_step(h, lp, cfg, ssm0, conv0, live):  # distlint: traced
+    """One step of the recurrence for ``h [B, H]``; rows that are not
+    ``live`` keep their state."""
+    with jax.named_scope('distllm.ssm_decode'):
+        z, xbc, dt_raw = _split_in_proj(h, lp, cfg)
+        window = jnp.concatenate(
+            [conv0.astype(xbc.dtype), xbc[:, None]], axis=1
+        )  # [B, K, conv_dim]
+        x, b_in, c_in = _mamba_inputs(lp, cfg, window)
+        x, b_in, c_in = x[:, 0], b_in[:, 0], c_in[:, 0]
+        dt, a = _dt_a(dt_raw, lp)  # [B, heads]
+        ssm = (
+            ssm0 * jnp.exp(dt * a)[..., None, None]
+            + (dt[..., None] * x)[..., None] * b_in[:, None, None, :]
+        )
+        y = jnp.sum(ssm * c_in[:, None, None, :], axis=-1)
+        y = y + lp['D'].astype(F32)[:, None] * x
+        out = _mamba_out(y, z, lp, cfg, h.dtype)
+        ssm = jnp.where(live[:, None, None, None], ssm, ssm0).astype(ssm0.dtype)
+        conv = jnp.where(
+            live[:, None, None], window[:, 1:].astype(conv0.dtype), conv0
+        )
+        return out, ssm, conv
+
+
+# ---------------------------------------------------------------- attention
+def _qkv(normed, lp, cfg):
+    heads = lambda t, n: t.reshape(*t.shape[:-1], n, cfg.head_size)  # noqa: E731
+    return (
+        heads(common.dense(normed, lp['q']['kernel']), cfg.num_heads),
+        heads(common.dense(normed, lp['k']['kernel']), cfg.num_kv_heads),
+        heads(common.dense(normed, lp['v']['kernel']), cfg.num_kv_heads),
+    )
+
+
+def _attn_out(attn, lp, cfg):
+    return common.dense(
+        attn.reshape(*attn.shape[:-2], cfg.num_heads * cfg.head_size),
+        lp['o']['kernel'],
+    )
+
+
+# ----------------------------------------------------------------- forwards
+def _layer_at(params, kind, li):
+    """Layer ``li`` of a kind's stacked tree, inside a scan over a run's
+    indices: a slice of the run copied out for ``xs`` would hold the run's
+    expert banks twice."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, li, 0, keepdims=False),
+        {n: leaf for n, leaf in params[kind].items() if n not in _BANKS},
+    )
+
+
+def _run_indices(first, count):
+    return jnp.arange(first, first + count, dtype=jnp.int32)
+
+
+def _gather_state(state, name, first, count, slots):
+    """Rows ``slots`` of layers ``first..first+count`` of the state pool,
+    stacked ``[count, B, ...]`` for a layer scan."""
+    return jnp.stack([state[name][i][slots] for i in range(first, first + count)])
+
+
+def _scatter_state(state, name, first, rows, slots):
+    """Write ``rows [count, B, ...]`` back; a pad row's slot lies past the
+    pool and its write is dropped."""
+    leaves = list(state[name])
+    for j in range(rows.shape[0]):
+        leaves[first + j] = leaves[first + j].at[slots].set(
+            rows[j].astype(leaves[first + j].dtype), mode='drop'
+        )
+    return {**state, name: tuple(leaves)}
+
+
+def apply(  # distlint: traced
+    params: dict,
+    cfg: GraniteHybridConfig,
+    input_ids: jnp.ndarray,  # [B, S], right-padded
+    attention_mask: jnp.ndarray,  # [B, S]
+) -> jnp.ndarray:
+    """Dense causal forward from zero state: ``[B, S]`` -> final-normed
+    hidden states ``[B, S, H]``."""
+    return prefill(params, cfg, input_ids, attention_mask)[0]
+
+
+def prefill(  # distlint: traced
+    params: dict,
+    cfg: GraniteHybridConfig,
+    input_ids: jnp.ndarray,
+    attention_mask: jnp.ndarray,
+):
+    """Dense forward that also returns what a sequence holds afterwards:
+    ``(hidden, k [L_attn, B, S, N_kv, Hd], v, state)`` with ``state`` the
+    ``state_spec`` tree with a leading ``[B]`` on every leaf."""
+    b, s = input_ids.shape
+    tail_lens = attention_mask.astype(jnp.int32).sum(axis=1)
+    valid = attention_mask.astype(bool)
+    mask = common.causal_mask(s, s)[None, None] & valid[:, None, None, :]
+    x = _embed(params, cfg, input_ids)
+    spec = cfg.state_spec()
+    ks, vs, ssms, convs = [], [], [], []
+
+    def mamba_layer(x, li):
+        lp = _layer_at(params, 'mamba', li)
+        ssm0 = jnp.zeros((b, *spec['ssm'][0].shape), F32)
+        conv0 = jnp.zeros((b, *spec['conv'][0].shape), spec['conv'][0].dtype)
+        mixed, ssm, conv = mamba_span(
+            _norm(x, lp['ln']['scale'], cfg), lp, cfg, ssm0, conv0, tail_lens
+        )
+        x, _ = _finish_layer(x, mixed, lp, cfg, valid, params['mamba'], li)
+        return x, (ssm, conv)
+
+    def attn_layer(x, li):
+        lp = _layer_at(params, 'attention', li)
+        q, k, v = _qkv(_norm(x, lp['ln']['scale'], cfg), lp, cfg)
+        attn = common.sdpa(q, k, v, mask=mask, scale=cfg.attention_multiplier)
+        x, _ = _finish_layer(
+            x, _attn_out(attn, lp, cfg), lp, cfg, valid, params['attention'], li
+        )
+        return x, (k, v)
+
+    for kind, first, count in cfg.layer_runs():
+        run = _run_indices(first, count)
+        if kind == 'mamba':
+            x, (ssm, conv) = jax.lax.scan(mamba_layer, x, run)
+            ssms.extend(ssm)
+            convs.extend(conv)
+        else:
+            x, (k, v) = jax.lax.scan(attn_layer, x, run)
+            ks.append(k)
+            vs.append(v)
+    hidden = _norm(x, params['final_ln']['scale'], cfg)
+    state = {'ssm': tuple(ssms), 'conv': tuple(convs)}
+    return hidden, jnp.concatenate(ks), jnp.concatenate(vs), state
+
+
+def prefill_paged(  # distlint: traced
+    params: dict,
+    cfg: GraniteHybridConfig,
+    input_ids: jnp.ndarray,  # [B, S] tokens of the span (padded)
+    positions: jnp.ndarray,  # [B, S] absolute positions
+    k_cache: jnp.ndarray,  # [L_attn, num_blocks, block_size, N_kv, Hd]
+    v_cache: jnp.ndarray,
+    block_tables: jnp.ndarray,  # [B, max_blocks]
+    context_lens: jnp.ndarray,  # [B] valid tokens incl. this span
+    tail_lens: jnp.ndarray,  # [B] valid tokens in input_ids (0 = pad row)
+    state: dict,  # the state pool: per Mamba layer [slots, ...]
+    slots: jnp.ndarray,  # [B] each row's slot (past the pool = pad row)
+    max_table_positions: int | None = None,
+    attn_backend: str = 'xla',
+):
+    """One span of every row through the paged path: a whole prompt, or
+    one chunk of a long one with the state of the chunk before it. A span
+    that starts at position 0 starts from zero state, whatever its slot
+    held: that is how a slot is zeroed when a sequence takes it. Returns
+    ``(last_logits [B, V] float32, k_cache, v_cache, state)``."""
+    from distllm_tpu.ops.paged_attention import (
+        ragged_paged_attention,
+        write_chunk_kv,
+    )
+
+    del max_table_positions  # no rotation: no table of positions
+    s = input_ids.shape[1]
+    valid = jnp.arange(s)[None, :] < tail_lens[:, None]
+    fresh = positions[:, 0] == 0
+    x = _embed(params, cfg, input_ids)
+
+    def mamba_layer(x, xs):
+        li, ssm0, conv0 = xs
+        lp = _layer_at(params, 'mamba', li)
+        mixed, ssm, conv = mamba_span(
+            _norm(x, lp['ln']['scale'], cfg), lp, cfg, ssm0, conv0, tail_lens
+        )
+        x, _ = _finish_layer(x, mixed, lp, cfg, valid, params['mamba'], li)
+        return x, (ssm, conv)
+
+    def attn_layer(carry, xs):
+        x, k_cache, v_cache = carry
+        li = xs
+        lp = _layer_at(params, 'attention', li)
+        k_l, v_l = _kv_layer(k_cache, li), _kv_layer(v_cache, li)
+        q, k, v = _qkv(_norm(x, lp['ln']['scale'], cfg), lp, cfg)
+        k_l, v_l = write_chunk_kv(k_l, v_l, k, v, block_tables, positions, valid)
+        attn = ragged_paged_attention(
+            q, k_l, v_l, block_tables, context_lens, positions,
+            q_lens=tail_lens, scale=cfg.attention_multiplier,
+            backend=attn_backend,
+        )
+        x, _ = _finish_layer(
+            x, _attn_out(attn, lp, cfg), lp, cfg, valid, params['attention'], li
+        )
+        k_cache = _kv_layer_update(k_cache, k_l, li)
+        v_cache = _kv_layer_update(v_cache, v_l, li)
+        return (x, k_cache, v_cache), None
+
+    for kind, first, count in cfg.layer_runs():
+        run = _run_indices(first, count)
+        if kind == 'mamba':
+            ssm0 = _gather_state(state, 'ssm', first, count, slots)
+            conv0 = _gather_state(state, 'conv', first, count, slots)
+            ssm0 = jnp.where(fresh[None, :, None, None, None], 0.0, ssm0)
+            conv0 = jnp.where(fresh[None, :, None, None], 0, conv0)
+            x, (ssm, conv) = jax.lax.scan(mamba_layer, x, (run, ssm0, conv0))
+            state = _scatter_state(state, 'ssm', first, ssm, slots)
+            state = _scatter_state(state, 'conv', first, conv, slots)
+        elif count == 1:
+            # A static index: a traced one would copy the layer's whole KV
+            # plane out of the pool and back (0.27 GB each way at 8192
+            # blocks), and the published pattern has no longer run.
+            (x, k_cache, v_cache), _ = attn_layer((x, k_cache, v_cache), first)
+        else:
+            (x, k_cache, v_cache), _ = jax.lax.scan(
+                attn_layer, (x, k_cache, v_cache), run
+            )
+    hidden = _norm(x, params['final_ln']['scale'], cfg)
+    last_idx = jnp.maximum(tail_lens - 1, 0)
+    last_hidden = jnp.take_along_axis(hidden, last_idx[:, None, None], axis=1)
+    return logits(params, cfg, last_hidden)[:, 0], k_cache, v_cache, state
+
+
+def _decode_core(
+    params, cfg, input_ids, positions, k_cache, v_cache, block_tables,
+    context_lens, state, live, attn_backend,
+):
+    """One token of every row. The layers are walked unrolled: each Mamba
+    layer's state is a buffer of its own, rewritten whole and in place, and
+    a static slice of the stacked kernels folds into its matmul."""
+    from distllm_tpu.ops.paged_attention import (
+        paged_attention_xla,
+        ragged_paged_attention_pallas,
+        write_token_kv,
+    )
+
+    x = _embed(params, cfg, input_ids)  # [B, H]
+    ssms, convs = list(state['ssm']), list(state['conv'])
+    pairs = jnp.zeros((2,), jnp.int32)
+    seen = {'mamba': 0, 'attention': 0}
+    for kind in cfg.layer_types:
+        i = seen[kind]
+        seen[kind] += 1
+        lp = jax.tree.map(
+            lambda a: a[i],
+            {n: leaf for n, leaf in params[kind].items() if n not in _BANKS},
+        )
+        normed = _norm(x, lp['ln']['scale'], cfg)
+        if kind == 'mamba':
+            mixed, ssms[i], convs[i] = mamba_step(
+                normed, lp, cfg, ssms[i], convs[i], live
+            )
+        else:
+            k_l, v_l = _kv_layer(k_cache, i), _kv_layer(v_cache, i)
+            q, k, v = _qkv(normed, lp, cfg)
+            k_l, v_l = write_token_kv(k_l, v_l, k, v, block_tables, positions)
+            if attn_backend == 'xla':
+                attn = paged_attention_xla(
+                    q, k_l, v_l, block_tables, context_lens,
+                    scale=cfg.attention_multiplier,
+                )
+            else:
+                attn = ragged_paged_attention_pallas(
+                    q[:, None], k_l, v_l, block_tables, context_lens,
+                    q_positions=positions[:, None],
+                    scale=cfg.attention_multiplier,
+                    interpret=attn_backend == 'interpret',
+                )[:, 0]
+            mixed = _attn_out(attn, lp, cfg)
+            k_cache = _kv_layer_update(k_cache, k_l, i)
+            v_cache = _kv_layer_update(v_cache, v_l, i)
+        x, layer_pairs = _finish_layer(
+            x, mixed, lp, cfg, live, params[kind], i
+        )
+        pairs = pairs + layer_pairs
+    hidden = _norm(x, params['final_ln']['scale'], cfg)
+    state = {'ssm': tuple(ssms), 'conv': tuple(convs)}
+    return logits(params, cfg, hidden), k_cache, v_cache, state, pairs
+
+
+def decode_loop(  # distlint: traced
+    params: dict,
+    cfg: GraniteHybridConfig,
+    input_ids: jnp.ndarray,  # [B] last emitted token per slot
+    positions: jnp.ndarray,  # [B]
+    k_cache: jnp.ndarray,
+    v_cache: jnp.ndarray,
+    block_tables: jnp.ndarray,
+    context_lens: jnp.ndarray,
+    steps_left: jnp.ndarray,
+    temperature: jnp.ndarray,
+    top_p: jnp.ndarray,
+    min_p: jnp.ndarray,
+    top_k: jnp.ndarray,
+    seeds: jnp.ndarray,
+    num_steps: int,
+    attn_backend: str = 'xla',
+    max_table_positions: int | None = None,
+    sampling_top_window: int = 0,
+    layer_unroll: bool = True,
+    *,
+    state: dict,
+):
+    """``mistral.decode_loop``'s contract with the state pool beside the KV
+    cache: row ``i`` of the batch is slot ``i`` of the pool (the batch is
+    the scheduler's slots). A row out of budget writes its K/V to the trash
+    block and leaves its state as it is. Returns ``(tokens [num_steps, B],
+    k_cache, v_cache, last_ids, state, moe_pairs [2])``, the last being the
+    window's (routed, held) pair counts over the rows and steps that ran."""
+    from distllm_tpu.ops.sampling import fold_row_keys, sample_tokens
+
+    del max_table_positions, layer_unroll  # no rope table; always unrolled
+
+    def body(carry, _):
+        ids, pos, ctx, k_cache, v_cache, state, live_steps, pairs = carry
+        live = live_steps > 0
+        bt_eff = jnp.where(live[:, None], block_tables, 0)
+        logits_, k_cache, v_cache, state, step_pairs = _decode_core(
+            params, cfg, ids, pos, k_cache, v_cache, bt_eff, ctx, state,
+            live, attn_backend,
+        )
+        token = sample_tokens(
+            logits_, None, temperature, top_p, min_p,
+            top_window=sampling_top_window, top_k=top_k,
+            row_keys=fold_row_keys(seeds, pos + 1),
+        )
+        ids = jnp.where(live, token, ids)
+        pos = jnp.where(live, pos + 1, pos)
+        ctx = jnp.where(live, ctx + 1, ctx)
+        carry = (
+            ids, pos, ctx, k_cache, v_cache, state, live_steps - 1,
+            pairs + step_pairs,
+        )
+        return carry, token
+
+    (ids, _, _, k_cache, v_cache, state, _, pairs), tokens = jax.lax.scan(
+        body,
+        (
+            input_ids, positions, context_lens, k_cache, v_cache, state,
+            steps_left.astype(jnp.int32), jnp.zeros((2,), jnp.int32),
+        ),
+        None,
+        length=num_steps,
+    )
+    return tokens, k_cache, v_cache, ids, state, pairs

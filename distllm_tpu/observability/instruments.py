@@ -497,6 +497,7 @@ COMPILE_PHASES = frozenset({
     'auto_layout',        # AOT decode-window compile with Layout.AUTO
     'migrate_params',     # destructive weight relayout into HBM
     'kv_allocate',        # paged K/V pool materialization
+    'state_allocate',     # a hybrid model's recurrent-state pool
     'prefill',            # one (batch, bucket) prefill warmup shape
     'prefill_paged',      # paged-context prefill twin of that shape
     'cow_copy',           # prefix-cache copy-on-write block copy

@@ -19,7 +19,10 @@ The model is deliberately a *weight-stream* roofline: attention KV traffic
 and activation bytes are omitted (at serving batches on this family they
 are second-order next to 13.5 GiB of weights per pass, and omitting them
 makes the bandwidth-utilization gauge a conservative lower bound). FLOPs
-use the classic ``2 * n_params`` per scored token (matmuls only).
+use the classic ``2 * n_params`` per scored token (matmuls only), with
+``n_params`` the parameters a token is multiplied by: of a routed expert
+bank (``[L, E_held, ...]`` beside a ``router``) the ``experts_per_token /
+E_routed`` share a token reaches, not the whole bank.
 
 Costs come from the engine's *actual* parameter tree — ``sum(leaf.size)``
 and ``sum(leaf.nbytes)`` over ``jax.tree.leaves`` — so quantized codes,
@@ -97,6 +100,28 @@ class StepCost:
     hbm_bytes: float
 
 
+def _unreached_expert_params(tree, experts_per_token: int) -> float:
+    """Parameters of routed expert banks that a token is NOT multiplied
+    by: every dict with a ``router`` holds banks ``gate``/``up``/``down``
+    shaped ``[..., E_held, in, out]``, of which a token reaches
+    ``experts_per_token / E_routed`` (``E_routed`` the router's last dim)."""
+    import jax
+
+    if not isinstance(tree, dict):
+        return 0.0
+    unreached = 0.0
+    if 'router' in tree:
+        routed = jax.tree.leaves(tree['router'])[0].shape[-1]
+        share = min(1.0, experts_per_token / routed)
+        for name in ('gate', 'up', 'down'):
+            for leaf in jax.tree.leaves(tree.get(name, ())):
+                unreached += getattr(leaf, 'size', 0) * (1.0 - share)
+    return unreached + sum(
+        _unreached_expert_params(child, experts_per_token)
+        for child in tree.values()
+    )
+
+
 class CostModel:
     """Per-window-kind FLOPs/bytes model for one engine's weight set.
 
@@ -126,7 +151,8 @@ class CostModel:
 
     @classmethod
     def from_params(
-        cls, params, decode_steps: int, device=None, num_devices: int = 1
+        cls, params, decode_steps: int, device=None, num_devices: int = 1,
+        experts_per_token: int | None = None,
     ) -> 'CostModel':
         """Price the ACTUAL weight set: quantized codes, scales, migrated
         layouts — whatever is in the tree is what streams from HBM.
@@ -136,12 +162,20 @@ class CostModel:
         ``nbytes`` report GLOBAL extents, so the aggregate peaks must
         scale with the mesh or every healthy multi-chip deployment would
         read ``num_devices``x too high.
+
+        ``experts_per_token`` (a model with routed experts): a token is
+        multiplied by that many of the router's experts, so the banks
+        beside a ``router`` count at that share in ``n_params`` (the FLOPs
+        side). The bytes side keeps every bank: a decode batch reaches them
+        all.
         """
         import jax
 
         leaves = jax.tree.leaves(params)
         n_params = sum(getattr(x, 'size', 0) for x in leaves)
         weight_bytes = sum(getattr(x, 'nbytes', 0) for x in leaves)
+        if experts_per_token:
+            n_params -= _unreached_expert_params(params, experts_per_token)
         if device is None:
             device = jax.devices()[0]
         peak_flops, peak_bw = device_peaks(device)
