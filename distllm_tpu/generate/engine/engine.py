@@ -111,6 +111,12 @@ def _round_sig(value: float, digits: int = 4) -> float:
     return float(f'{value:.{digits}g}')
 
 
+def _sampled_rows(temperature: np.ndarray) -> int:
+    """Rows of a dispatch's temperature array that sample: what the
+    device's ``any(temperature > 0)`` sees (0 = its ``argmax`` branch)."""
+    return int(np.count_nonzero(temperature > 0))
+
+
 def _request_seed(
     engine_seed: int, request_id: int, explicit: int | None
 ) -> int:
@@ -287,14 +293,13 @@ class EngineConfig(BaseConfig):
     # Tokens generated per decode dispatch (the fused lax.scan window).
     # 1 restores per-token dispatch; >1 amortizes dispatch+sync latency.
     decode_steps: int = 8
-    # Sampling considers only the top-K logits per step (vLLM's top_k
-    # semantic, applied before top-p). Avoids a full-vocab sort inside the
-    # decode scan — XLA's TPU sort over 32k is a multi-pass bitonic
-    # network paid every step. Probabilities keep the full-vocab
-    # normalizer, so top-p/min-p are exact whenever the cutoff falls
-    # inside the window. Default 0 = exact full-vocab semantics (reference
-    # parity: vLLM's top_k is off by default); serving deployments that
-    # want the fast path set 64 explicitly (bench.py does).
+    # A rank cap on every request (vLLM's top_k semantic, applied before
+    # top-p): the tokens no smaller than the K-th largest logit stay, ties
+    # with it included; probabilities keep the full-vocab normalizer.
+    # Default 0 = no cap (reference parity: vLLM's top_k is off by
+    # default). Not a speed setting: the sampler finds the kept set as a
+    # value threshold and sorts nothing at any K (ops/sampling.py); a cap
+    # adds a second search to the steps of the rows that have one.
     sampling_top_window: int = 0
     # Unroll the layer scan inside decode dispatches. Decode is weight-
     # bandwidth bound and the rolled scan's dynamic-slice of stacked MLP
@@ -3102,7 +3107,9 @@ class LLMEngine:
             # The synchronous path's host sync: the device runs the
             # prefill while the host waits here.
             step.mark('fetch')
-            tokens = np.asarray(self._sample_device(last_logits, slots))
+            tokens = np.asarray(
+                self._sample_device(last_logits, slots, step)
+            )
             step.mark('emit')
             emitted = []
             for i, request in enumerate(requests):
@@ -3116,7 +3123,7 @@ class LLMEngine:
         # window reads them without a host round trip) and the host fetch
         # rides the in-flight deque as a 1-step window record — the same
         # unacked/one-window-late bookkeeping decode EOS already uses.
-        tok_dev = self._sample_device(last_logits, slots)
+        tok_dev = self._sample_device(last_logits, slots, step)
         slot_of = {rid: slot for slot, rid in self.sched.running()}
         slot_idx = np.asarray(
             [slot_of[r.request_id] for r in requests], np.int32
@@ -3792,6 +3799,7 @@ class LLMEngine:
             any_steps = any_steps or steps > 0
         if not any_steps and not chunk_plan:
             return _DRAIN
+        step.counts['sampled_rows'] = _sampled_rows(temperature)
 
         host_arrays = [
             ids, override_mask, positions, context_lens, block_tables,
@@ -4634,8 +4642,12 @@ class LLMEngine:
         del self._requests[rid]
         self._finished[rid] = request
 
-    def _sample_device(self, logits: jnp.ndarray, slots) -> jnp.ndarray:
-        """Sample one token per row on DEVICE (no host sync)."""
+    def _sample_device(
+        self, logits: jnp.ndarray, slots,
+        step: _steps.StepSpan | None = None,
+    ) -> jnp.ndarray:
+        """Sample one token per row on DEVICE (no host sync). ``step``
+        (the prefill step the rows belong to) takes ``sampled_rows``."""
         b = logits.shape[0]
         temperature = np.zeros((b,), np.float32)
         top_p = np.ones((b,), np.float32)
@@ -4655,6 +4667,8 @@ class LLMEngine:
             # the first generated token's index — its PRNG counter — is
             # num_tokens (matches the decode scan's pos + 1 convention).
             counters[i] = request.num_tokens
+        if step is not None:
+            step.counts['sampled_rows'] = _sampled_rows(temperature)
         t_dev, tp_dev, mp_dev, tk_dev, sd_dev, ct_dev = self._put_many(
             temperature, top_p, min_p, top_k, seeds, counters
         )

@@ -65,8 +65,9 @@ class TpuGeneratorConfig(BaseConfig):
     sampling_top_window: int | None = Field(
         default=None,
         ge=0,
-        description='Sample from the top-K logits per step instead of '
-        'sorting the full vocab (0 = exact full-vocab semantics).',
+        description='Rank cap on every request: keep the tokens no smaller '
+        'than the K-th largest logit, before top-p (0 = no cap). Not a '
+        'speed setting: the sampler sorts nothing at any K.',
     )
     decode_layer_unroll: bool | None = Field(
         default=None,

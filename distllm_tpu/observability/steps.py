@@ -91,7 +91,7 @@ class StepSpan:
     """The clock reads of one engine step. ``annotate=False`` (attribution
     off) keeps the reads and the seconds and opens no annotation."""
 
-    __slots__ = ('seq', 'annotate', 't0', 't1', 'seconds')
+    __slots__ = ('seq', 'annotate', 't0', 't1', 'seconds', 'counts')
 
     def __init__(self, seq: int, annotate: bool = True) -> None:
         self.seq = seq
@@ -99,6 +99,9 @@ class StepSpan:
         self.t0 = clock()
         self.t1: float | None = None
         self.seconds: dict[str, float] = {}
+        # What the dispatch knew of its rows when it built their arrays
+        # (``sampled_rows``); rides to the record with the seconds.
+        self.counts: dict[str, int] = {}
 
     def _push(self, name: str, now: float) -> None:
         annotation = _annotation(name, self.seq) if self.annotate else None
@@ -151,8 +154,8 @@ class StepSpan:
 
     def fields(self) -> dict:
         """The record's share: ``seq``, ``t0_s``/``t1_s`` on the shared
-        clock, and the seconds of each child span."""
-        out = {'seq': self.seq, 't0_s': round(self.t0, 6)}
+        clock, the seconds of each child span, and the step's counts."""
+        out = {'seq': self.seq, 't0_s': round(self.t0, 6), **self.counts}
         if self.t1 is not None:
             out['t1_s'] = round(self.t1, 6)
         for field, seconds in self.seconds.items():

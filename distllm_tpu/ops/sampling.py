@@ -8,22 +8,37 @@ fp32 logits; each sequence carries its own parameters so one decode batch can
 mix sampling configs (continuous batching requirement).
 
 This runs INSIDE the engine's fused decode scan (one sample per decode
-step), so it is written for the TPU hot path: ONE implementation over the
-``top_window`` largest logits (``jax.lax.top_k``), with ``top_window = V``
-recovering the exact full-vocabulary semantics (top_k(V) is a descending
-sort). Probabilities always use the full-vocab logsumexp normalizer, so
-top-p prefixes and min-p thresholds are exact whenever the top-p cutoff
-falls inside the window; min-p is a pure log-space comparison
-(``prob >= min_p * max_prob  <=>  logit >= max_logit + log(min_p)``) — no
-softmax materialization. Per-request ``top_k`` is a rank mask over the same
-descending window (0 disables, a bitwise no-op).
+step) and once a prefill dispatch, so it is written for the TPU hot path:
+ONE implementation that never orders the vocabulary. Nothing the sampler
+returns needs an order. The kept set of a row is ``{x >= t}`` for one value
+``t``, the largest of three thresholds over the temperature-scaled logits
+``x``:
 
-Why a window at all: XLA's TPU sort over V=32k is a multi-pass bitonic
-network, paid once per decode step inside a 16-step window scan. A
-``top_window`` of 64 (the engine's recommended serving setting; vLLM's
-``top_k`` semantic, applied before top-p) replaces it with one
-``lax.top_k`` pass. The library default is 0 (= exact) to preserve
-reference parity for pure-temperature sampling.
+- top-p: a token is in the nucleus iff the probability mass of the tokens
+  strictly above it is under ``top_p``, so ``t_p`` is the smallest float
+  ``v`` with ``sum(p[x > v]) < top_p``. That sum never rises with ``v``:
+  a bisection over the order-preserving integer image of float32 finds
+  ``t_p`` exactly in 32 halvings, each one fused compare, select and row
+  sum over ``[B, V]``. Probabilities use the full-vocabulary logsumexp
+  normalizer.
+- min-p: ``prob >= min_p * max_prob  <=>  x >= max(x) + log(min_p)``, a
+  pure log-space comparison.
+- a rank cap (the request's ``top_k``, the engine's ``top_window``, the
+  smaller where both are set; applied before top-p, probabilities against
+  the full vocabulary): ``t_k`` is the value of the k-th largest logit, the
+  same bisection on an integer count. Every token tied with that value is
+  kept, which is vLLM's rule (it masks ``logits < kth value``); a sorted
+  window would cut ties by index.
+
+A sort (``lax.top_k`` over the vocabulary is XLA's multi-pass bitonic
+network on the TPU, whatever ``k``) and the cumulative sum and gathers
+behind it cost 5 ms a step at ``[96, 50176]``; a halving reads the row
+once, from the chip's fast memory where XLA keeps it (6 us there). What a
+dispatch does not need it does not run: no row with ``top_p < 1`` means a
+top-p search of no passes, no row with a cap a rank search of no passes,
+and a dispatch whose rows are all greedy (``temperature <= 0``) takes the
+``argmax`` branch of a ``lax.cond`` and neither filters nor draws. The ops
+carry the ``distllm.sample`` named scope in a device trace.
 
 PRNG contract (docs/speculative.md "Sampled verification"): the draw for
 the token at absolute sequence index ``i`` of a request uses
@@ -32,13 +47,17 @@ the speculative accept/reject uniform; ``_SAMPLE_FOLD`` tags every
 categorical draw (ordinary sampling, residual resampling, and the bonus
 token). Because the key depends only on (request seed, token index), a
 request's sampled stream is deterministic per (seed, schedule) and
-identical across decode_window / mixed_window / spec_window dispatch.
+identical across decode_window / mixed_window / spec_window dispatch. The
+categorical's Gumbel noise rides on vocabulary position (it rode on rank
+while the sampler sorted), so a stream is a function of (seed, schedule)
+and of this layout.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 _ACCEPT_FOLD = 1
 _SAMPLE_FOLD = 2
@@ -64,6 +83,58 @@ def fold_row_keys(  # distlint: traced
     return jax.vmap(one)(seeds, counters)
 
 
+# Keys under the first and over the second stand for NaNs.
+_NEG_INF_KEY = np.uint32(0x007FFFFF)
+_POS_INF_KEY = np.uint32(0xFF800000)
+
+
+def _float_key(x: jnp.ndarray) -> jnp.ndarray:  # distlint: traced
+    """The order-preserving uint32 image of float32: ``a < b`` as floats
+    implies ``key(a) < key(b)`` (no NaN; ``-0.0`` is the key under
+    ``+0.0``)."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(
+        bits >> 31 == 1, ~bits, bits | jnp.uint32(0x80000000)
+    )
+
+
+def _key_float(key: jnp.ndarray) -> jnp.ndarray:  # distlint: traced
+    """The float32 a key stands for; a key outside the reals' range is the
+    infinity on its side."""
+    key = jnp.clip(key, _NEG_INF_KEY, _POS_INF_KEY)
+    bits = jnp.where(
+        key >> 31 == 1, key & jnp.uint32(0x7FFFFFFF), ~key
+    )
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
+def _lowest_threshold(  # distlint: traced
+    measure, limit: jnp.ndarray, searching: jnp.ndarray
+) -> jnp.ndarray:
+    """Per ``searching`` row, the key of the smallest float32 ``v`` with
+    ``measure(v) < limit`` (all ones where no ``v`` has it); key 0 for the
+    other rows, and no pass at all when no row searches.
+
+    ``measure(v)`` is a ``[B]`` row sum over the tokens with ``x > v``: it
+    never rises with ``v`` (the same reduction tree with more leaves
+    zeroed), so the verdicts over the keys are False.. then True.., and 32
+    halvings build the key from its top bit down. Each pass is one fused
+    compare, select and row sum over ``[B, V]``. The skip is the loop's
+    trip count, not a ``lax.cond`` around it: an operand of a conditional
+    stays in HBM, and then every pass streams the row from there.
+    """
+    def one_pass(i, prefix):
+        bit = jnp.uint32(1) << (31 - i).astype(jnp.uint32)
+        # The largest key that leaves this bit clear.
+        under = measure(_key_float(prefix | (bit - 1))) < limit
+        return jnp.where(under | ~searching, prefix, prefix | bit)
+
+    return jax.lax.fori_loop(
+        0, jnp.where(jnp.any(searching), 32, 0), one_pass,
+        jnp.zeros(limit.shape, jnp.uint32),
+    )
+
+
 def filter_logits(  # distlint: traced
     logits: jnp.ndarray,  # [B, V] fp32
     temperature: jnp.ndarray,  # [B]
@@ -71,44 +142,61 @@ def filter_logits(  # distlint: traced
     min_p: jnp.ndarray,  # [B] (0.0 disables)
     top_k: jnp.ndarray | None = None,  # [B] int32 (0 disables)
     top_window: int = 0,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
+) -> jnp.ndarray:
     """Temperature-scale and filter logits; shared by sampling and verify.
 
-    Returns ``(filtered, top_idx)``: the temperature-scaled logits over the
-    descending ``top_window`` set with every filtered-out entry at ``-inf``
-    (categorical over ``filtered`` samples the served distribution), and the
-    vocab indices of that window. At least one token always survives.
+    Returns the temperature-scaled logits in VOCABULARY order, ``[B, V]``,
+    with every filtered-out entry at ``-inf`` (categorical over a row
+    samples the served distribution). The kept set is the one a descending
+    sort and a cumulative sum give for top-p and min-p, ties included, up
+    to the order in which float32 adds the masses; a rank cap (``top_k``,
+    ``top_window``) keeps every token tied with the k-th largest value. At
+    least one token always survives. Greedy rows (``temperature <= 0``)
+    start no search; nobody reads what they hold beyond their ``argmax``.
     """
     vocab = logits.shape[-1]
-    k = vocab if top_window <= 0 else min(top_window, vocab)
-
-    logits = logits.astype(jnp.float32)
-    safe_temp = jnp.where(temperature > 0, temperature, 1.0)
-    scaled = logits / safe_temp[:, None]
-
-    top_vals, top_idx = jax.lax.top_k(scaled, k)  # descending
-    # Exact probabilities: normalize against the whole vocabulary.
-    lse = jax.scipy.special.logsumexp(scaled, axis=-1, keepdims=True)
-    probs = jnp.exp(top_vals - lse)
-    cumulative = jnp.cumsum(probs, axis=-1)
-    # Keep the smallest prefix with cumulative >= top_p (always >= 1 token).
-    cutoff_idx = jnp.minimum(
-        jnp.sum(cumulative < top_p[:, None], axis=-1), k - 1
-    )
-    cutoff_logit = jnp.take_along_axis(top_vals, cutoff_idx[:, None], axis=-1)
-    filtered = jnp.where(top_vals >= cutoff_logit, top_vals, -jnp.inf)
-    # min-p in log space; log(0) = -inf disables the filter.
-    min_p_threshold = top_vals[:, :1] + jnp.log(
-        jnp.maximum(min_p, 0.0)
+    sampled = temperature > 0
+    scaled = logits.astype(jnp.float32) / jnp.where(
+        sampled, temperature, 1.0
     )[:, None]
-    filtered = jnp.where(top_vals >= min_p_threshold, filtered, -jnp.inf)
-    if top_k is not None:
-        # Rank mask over the descending window; intersects with top-p/min-p
-        # rather than renormalizing first, so top_k == 0 is a bitwise no-op.
-        eff = jnp.where(top_k > 0, jnp.minimum(top_k, k), k)
-        keep = jnp.arange(k)[None, :] < eff[:, None]
-        filtered = jnp.where(keep, filtered, -jnp.inf)
-    return filtered, top_idx
+    top = jnp.max(scaled, axis=-1)
+    # min-p in log space; log(0) = -inf disables the filter.
+    threshold = _float_key(top + jnp.log(jnp.maximum(min_p, 0.0)))
+
+    # Exact probabilities: normalize against the whole vocabulary. The
+    # masses are computed again in every pass (the select sits under the
+    # exp), so the search streams one array, not two.
+    lse = top + jnp.log(jnp.sum(jnp.exp(scaled - top[:, None]), axis=-1))
+
+    def mass_above(v):
+        log_probs = jnp.where(
+            scaled > v[:, None], scaled - lse[:, None], -jnp.inf
+        )
+        return jnp.sum(jnp.exp(log_probs), axis=-1)
+
+    # top_p >= 1 keeps everything outright: a sum that rounds to 1.0 can
+    # drop nothing.
+    threshold = jnp.maximum(
+        threshold, _lowest_threshold(mass_above, top_p, sampled & (top_p < 1))
+    )
+    if top_k is not None or top_window > 0:
+        cap = jnp.full(temperature.shape, top_window, jnp.int32)
+        if top_k is not None:
+            cap = jnp.where(
+                (top_k > 0) & ((cap <= 0) | (top_k < cap)), top_k, cap
+            )
+
+        def count_above(v):
+            return jnp.sum(scaled > v[:, None], axis=-1, dtype=jnp.int32)
+
+        # The k-th largest value is the smallest v with under k tokens
+        # above it.
+        threshold = jnp.maximum(threshold, _lowest_threshold(
+            count_above, cap, sampled & (cap > 0) & (cap < vocab)
+        ))
+    # The row's largest logit is above no threshold that can be asked for.
+    threshold = _key_float(jnp.minimum(threshold, _float_key(top)))
+    return jnp.where(scaled >= threshold[:, None], scaled, -jnp.inf)
 
 
 def sample_tokens(  # distlint: traced
@@ -123,25 +211,27 @@ def sample_tokens(  # distlint: traced
 ) -> jnp.ndarray:
     """Per-sequence sampling; temperature == 0 rows are greedy.
 
-    ``top_window > 0`` caps the kept set at that many tokens (see module
-    docstring); ``0`` or ``>= V`` is exact. With ``row_keys`` each row draws
-    from its own counter-derived key (the engine's deterministic path);
-    otherwise one batch ``key`` feeds a single categorical (legacy path).
+    ``top_window > 0`` caps the kept set at the tokens no smaller than the
+    ``top_window``-th largest (see module docstring); ``0`` or ``>= V`` is
+    no cap. With ``row_keys`` each row draws from its own counter-derived
+    key (the engine's deterministic path); otherwise one batch ``key``
+    feeds a single categorical (legacy path). A batch with no sampled row
+    runs the ``argmax`` and neither filter nor draw.
     """
-    filtered, top_idx = filter_logits(
-        logits, temperature, top_p, min_p, top_k=top_k,
-        top_window=top_window,
-    )
-    if row_keys is not None:
-        choice = jax.vmap(
-            lambda rk, row: jax.random.categorical(rk, row)
-        )(row_keys, filtered)
-    else:
-        choice = jax.random.categorical(key, filtered, axis=-1)
-    sampled = jnp.take_along_axis(top_idx, choice[:, None], axis=-1)[:, 0]
-    return jnp.where(temperature > 0, sampled, top_idx[:, 0]).astype(
-        jnp.int32
-    )
+    def draw():
+        filtered = filter_logits(
+            logits, temperature, top_p, min_p, top_k=top_k,
+            top_window=top_window,
+        )
+        if row_keys is not None:
+            choice = jax.vmap(jax.random.categorical)(row_keys, filtered)
+        else:
+            choice = jax.random.categorical(key, filtered, axis=-1)
+        return jnp.where(temperature > 0, choice.astype(jnp.int32), greedy)
+
+    with jax.named_scope('distllm.sample'):
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return jax.lax.cond(jnp.any(temperature > 0), draw, lambda: greedy)
 
 
 def sample_tokens_windowed(  # distlint: traced
@@ -196,11 +286,11 @@ def verify_spans(  # distlint: traced
     def rep(x):
         return jnp.repeat(x, s)
 
-    filtered, top_idx = filter_logits(
-        flat, rep(temperature), rep(top_p), rep(min_p), top_k=rep(top_k),
-        top_window=top_window,
-    )
-    kw = filtered.shape[-1]
+    with jax.named_scope('distllm.sample'):
+        filtered = filter_logits(
+            flat, rep(temperature), rep(top_p), rep(min_p), top_k=rep(top_k),
+            top_window=top_window,
+        ).reshape(b, s, vocab)
     # The token produced at span position i has absolute index pos_i + 1 —
     # the same counter the decode scan uses for that token, so sampled
     # streams agree across dispatch flavors.
@@ -208,9 +298,7 @@ def verify_spans(  # distlint: traced
     u_keys = fold_row_keys(rep(seeds), counters, _ACCEPT_FOLD)
     s_keys = fold_row_keys(rep(seeds), counters, _SAMPLE_FOLD)
 
-    filtered = filtered.reshape(b, s, kw)
-    top_idx = top_idx.reshape(b, s, kw)
-    cand = top_idx[:, :, 0]  # greedy candidate per position
+    cand = jnp.argmax(span_logits, axis=-1)  # greedy candidate per position
 
     m = jnp.maximum(span_lens - 1, 0)  # drafts per row
     drafts = jnp.concatenate(
@@ -220,10 +308,9 @@ def verify_spans(  # distlint: traced
 
     # log p̃(draft) under the filtered target; -inf when the draft fell
     # outside the kept set (q point mass outside supp(p̃) never accepts).
-    match = top_idx == drafts[:, :, None]
     logz = jax.scipy.special.logsumexp(filtered, axis=-1)
-    draft_val = jnp.max(jnp.where(match, filtered, -jnp.inf), axis=-1)
-    log_p_draft = draft_val - logz
+    draft_val = jnp.take_along_axis(filtered, drafts[:, :, None], axis=-1)
+    log_p_draft = draft_val[:, :, 0] - logz
 
     u = jax.vmap(jax.random.uniform)(u_keys).reshape(b, s)
     sampled_row = temperature[:, None] > 0
@@ -234,16 +321,14 @@ def verify_spans(  # distlint: traced
     # (categorical renormalizes). The bonus slot (past the drafts) and rows
     # whose kept set is exactly {draft} — where acceptance is certain and
     # the residual is empty — sample the full filtered target instead.
-    residual = jnp.where(match, -jnp.inf, filtered)
+    is_draft = jnp.arange(vocab)[None, None, :] == drafts[:, :, None]
+    residual = jnp.where(is_draft, -jnp.inf, filtered)
     res_valid = jnp.any(jnp.isfinite(residual), axis=-1)
     use_residual = pos_in_draft & res_valid
     corr_src = jnp.where(use_residual[:, :, None], residual, filtered)
-    choice = jax.vmap(jax.random.categorical)(
-        s_keys, corr_src.reshape(b * s, kw)
+    corr_sampled = jax.vmap(jax.random.categorical)(
+        s_keys, corr_src.reshape(b * s, vocab)
     ).reshape(b, s)
-    corr_sampled = jnp.take_along_axis(
-        top_idx, choice[:, :, None], axis=-1
-    )[:, :, 0]
     correction = jnp.where(sampled_row, corr_sampled, cand)
 
     out = jnp.where(accept, drafts, correction).astype(jnp.int32)

@@ -523,8 +523,9 @@ def test_sampling_windowed_matches_exact_when_cutoff_inside_window():
     temp = jnp.full(32, 0.8)
     top_p = jnp.full(32, 0.9)
     min_p = jnp.zeros(32)
-    # Exact same draws are not guaranteed (different categorical index
-    # spaces), so compare supports over many keys.
+    # The window changes no threshold here, so the draws themselves agree
+    # (one categorical over the vocabulary either way); compare supports
+    # over many keys as well.
     exact_set, win_set = set(), set()
     for i in range(40):
         k = jax.random.PRNGKey(i)
@@ -543,16 +544,26 @@ def test_sampling_windowed_matches_exact_when_cutoff_inside_window():
 def test_sampling_windowed_truncates_flat_distribution_to_window():
     from distllm_tpu.ops.sampling import sample_tokens_windowed
 
-    logits = jnp.zeros((64, 128))  # uniform: top-p needs ~all tokens
+    # Nearly uniform, no two logits equal: top-p needs ~all tokens, the
+    # window caps the support at the 16 largest.
+    logits = jnp.tile(jnp.arange(128.0)[None, :] * 1e-3, (64, 1))
     toks = np.asarray(
         sample_tokens_windowed(
             logits, jax.random.PRNGKey(0), jnp.ones(64),
             jnp.full(64, 0.99), jnp.zeros(64), 16,
         )
     )
-    # All draws land in SOME 16-token window (ties make the exact ids
-    # unspecified, but support size is capped).
-    assert len(set(toks.tolist())) <= 16
+    assert set(toks.tolist()) <= set(range(112, 128))
+    assert len(set(toks.tolist())) > 8  # still samples across the window
+    # Tokens tied with the window's smallest value all stay (vLLM's rule:
+    # mask what is under the k-th value), so a flat row keeps its support.
+    flat = np.asarray(
+        sample_tokens_windowed(
+            jnp.zeros((64, 128)), jax.random.PRNGKey(0), jnp.ones(64),
+            jnp.full(64, 0.99), jnp.zeros(64), 16,
+        )
+    )
+    assert len(set(flat.tolist())) > 16
 
 
 def test_sampling_windowed_greedy_and_engine_path():

@@ -99,25 +99,18 @@ def _expected_probs(logits_row, temperature=1.0, top_p=1.0, min_p=0.0,
                     top_k=0):
     """The served distribution p̃ as a dense [V] numpy vector, via the
     same filter_logits the kernels use."""
-    vocab = len(logits_row)
-    filtered, top_idx = filter_logits(
+    filtered = filter_logits(
         jnp.asarray(logits_row, jnp.float32)[None, :],
         jnp.asarray([temperature], jnp.float32),
         jnp.asarray([top_p], jnp.float32),
         jnp.asarray([min_p], jnp.float32),
         top_k=jnp.asarray([top_k], jnp.int32),
     )
-    filtered = np.asarray(filtered)[0]
-    top_idx = np.asarray(top_idx)[0]
+    filtered = np.asarray(filtered)[0]  # vocabulary order
     finite = np.isfinite(filtered)
-    probs_win = np.zeros_like(filtered)
-    probs_win[finite] = np.exp(
-        filtered[finite] - filtered[finite].max()
-    )
-    probs_win /= probs_win.sum()
-    dense = np.zeros(vocab)
-    dense[top_idx] = probs_win
-    return dense
+    dense = np.zeros_like(filtered, dtype=np.float64)
+    dense[finite] = np.exp(filtered[finite] - filtered[finite].max())
+    return dense / dense.sum()
 
 
 def _chi_square(counts, probs, n):

@@ -238,6 +238,38 @@ def test_step_loop_stamps_every_step_record_and_attribution_off_sheds_them():
     engine.shutdown()
 
 
+@time_limit(120)
+@pytest.mark.parametrize('temperatures,expected', [
+    ((0.0, 0.0, 0.0), {0}),  # the sampler's argmax branch, every dispatch
+    ((0.7, 0.7, 0.7), {1, 2, 3}),
+    ((0.0, 0.7, 0.0), {0, 1}),
+])
+def test_sampled_rows_counts_what_the_sampler_cond_sees(temperatures, expected):
+    """``sampled_rows`` is the dispatch's rows with temperature > 0, on
+    decode and on sampling prefill records alike (0 = the dispatch ran
+    ``argmax`` only); it never exceeds the rows the dispatch carried."""
+    engine = _engine()
+    before = engine.flight.total_recorded
+    for prompt, t in zip(_prompts((6, 11, 8)), temperatures):
+        engine.add_request(
+            prompt, SamplingParams(temperature=t, top_p=0.9, max_tokens=9)
+        )
+    while engine.has_unfinished:
+        engine.step()
+    records = [
+        r for r in _since(engine, before) if r['kind'] in ('prefill', 'decode')
+    ]
+    assert {r['kind'] for r in records} == {'prefill', 'decode'}
+    assert all('sampled_rows' in r for r in records)
+    assert {r['sampled_rows'] for r in records} <= expected
+    assert max(r['sampled_rows'] for r in records) == max(expected)
+    decodes = [r for r in records if r['kind'] == 'decode']
+    assert all(r['sampled_rows'] <= r['running'] for r in decodes)
+    if all(t > 0 for t in temperatures):
+        assert all(r['sampled_rows'] == r['batch'] for r in decodes)
+    engine.shutdown()
+
+
 # ------------------------------------------- preemption and prefill routes
 @time_limit(180)
 def test_small_pool_preempts_and_every_lost_token_is_counted():
