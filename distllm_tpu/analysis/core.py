@@ -390,10 +390,9 @@ def default_source_paths(root: Path) -> list[Path]:
         + list((root / 'scripts').glob('*.py'))
         + list((root / 'tests').glob('*.py'))
     )
-    for extra in ('bench.py', '__graft_entry__.py'):
-        candidate = root / extra
-        if candidate.exists():
-            paths.append(candidate)
+    graft_entry = root / '__graft_entry__.py'
+    if graft_entry.exists():
+        paths.append(graft_entry)
     return sorted(p for p in paths if '__pycache__' not in p.parts)
 
 

@@ -38,8 +38,8 @@ Observability surface (docs/observability.md):
 - ``GET /debug/slo`` — the ``slo_status()`` ok/warn/page document
   (multi-window burn rates over ``distllm_request_slo_total``) plus the
   regression-sentinel state; arm the sentinel with
-  ``DISTLLM_BASELINE=<envelope path>`` (written by
-  ``scripts/benchdiff.py --emit-baseline``) — a missing baseline is a
+  ``DISTLLM_BASELINE=<envelope path>`` (the JSON of
+  ``observability.baseline.build_envelope``) — a missing baseline is a
   counted disarm, never a startup failure;
 - ``GET /debug/bundle`` — dump a full debug bundle (flight ring + metrics
   + traces + perfetto.json + startup.json + history.json + slo.json) to
@@ -457,8 +457,8 @@ def build_app(config: ChatAppConfig):
         the router never parses Prometheus text per routing decision.
         ``/metrics`` stays unchanged for scrapes. Reads THIS app's drain
         flag and THIS engine's scheduler — unlike the process-wide
-        gauges, correct even with several in-process replicas (the bench
-        topology). Always 200: a draining replica still answers, the
+        gauges, correct even with several in-process replicas (the
+        topology of ``tests/test_router.py``). Always 200: a draining replica still answers, the
         body says to route away."""
         engine = getattr(session.generator, 'engine', None)
         sched = getattr(engine, 'sched', None)

@@ -304,8 +304,8 @@ class EngineConfig(BaseConfig):
     # Unroll the layer scan inside decode dispatches. Decode is weight-
     # bandwidth bound and the rolled scan's dynamic-slice of stacked MLP
     # kernels is materialized by XLA (~3x HBM traffic on most of the
-    # weights — AOT HLO census, scripts/probe_decode_hlo.py); unrolling
-    # folds the slices into the matmuls. The unrolled window compiles
+    # weights — read off the compiled HLO on older code, not re-measured);
+    # unrolling folds the slices into the matmuls. The unrolled window compiles
     # slower than the rolled one (about half a minute per 7B decode shape
     # compile-only for a described v5e, scripts/aot_preflight.py), which
     # the persistent compilation cache (utils.enable_compile_cache) pays
@@ -564,14 +564,14 @@ class EngineConfig(BaseConfig):
     # near-tied logit differently, so cross-KERNEL token identity is
     # only guaranteed in fp32, while drafting-on vs drafting-off inside
     # the verify kernel is bit-identical in any dtype
-    # (docs/speculative.md; the gen_spec bench stage asserts it).
+    # (docs/speculative.md; tests/test_spec.py asserts it).
     spec_draft_source: str = 'prompt_lookup'
     # Serving-path attribution (docs/observability.md): per-window
     # host/put/dispatch/fetch timing split on flight records,
     # jax.profiler.TraceAnnotation labels on every dispatch kind, and the
     # analytic roofline gauges (distllm_engine_mfu /
     # distllm_engine_bandwidth_utilization). Pure host-side bookkeeping —
-    # token output is bit-identical on vs off (the gen_load bench stage
+    # token output is bit-identical on vs off (tests/test_loadgen.py
     # asserts it). Off sheds the record fields, profiler annotations, and
     # roofline math; the step spans' time.monotonic() reads stay
     # (nanoseconds — gating them would complicate every window path for
@@ -1182,9 +1182,9 @@ class LLMEngine:
             # layout-conversion copies of the stacked q/k/v kernels (1.5 GB
             # at 7B dims) inside every window dispatch — enough to overflow
             # a v5e's HBM next to the weights, and pure wasted bandwidth.
-            # Prefill is layout-agnostic (measured:
-            # scripts/probe_prefill_layout.py — 0.13 GiB temp either way),
-            # so the migrated layout serves every executable.
+            # Prefill is layout-agnostic (0.13 GiB of temporaries either
+            # way when it was measured, on older code), so the migrated
+            # layout serves every executable.
             # A compile failure here raises: serving 7B on the default
             # layout is the HBM overflow described above, not a fallback.
             with self._compile_watcher.phase(
@@ -2759,8 +2759,7 @@ class LLMEngine:
 
     def tier_summary(self) -> dict:
         """Host/disk KV-tier counters and promotion-overlap efficiency
-        (empty when the tier is disabled) — what the ``gen_tier`` bench
-        stage checkpoints next to warm/cold TTFT."""
+        (empty when the tier is disabled)."""
         if self.kv_tier is None:
             return {}
         wait = self._tier_times['promote_wait_s']
@@ -3538,7 +3537,7 @@ class LLMEngine:
         """Aggregate roofline view per window kind:
         ``{kind: {windows, seconds, mfu, bw_util}}`` with mfu/bw_util the
         time-weighted means (total flops/bytes over total seconds over
-        the device peaks) — what the ``gen_load`` bench stage checkpoints.
+        the device peaks).
         ``baseline`` (a prior :meth:`roofline_snapshot`) subtracts
         earlier windows so the summary covers one measured interval.
         Empty when the cost model was unavailable (and nothing

@@ -42,8 +42,8 @@ class TpuGeneratorConfig(BaseConfig):
         description='Weight-only quantized serving; True means nf4 (the '
         "reference's bitsandbytes NF4 option).",
     )
-    # Serving perf knobs (same surface the bench exercises — production
-    # configs must be able to turn on what the measured numbers used).
+    # Serving perf knobs (production configs must be able to turn on what
+    # the measured numbers used).
     # Defaults are None = inherit EngineConfig's documented defaults, so
     # one place owns each default and reference-parity semantics (exact
     # full-vocab sampling) hold unless a config opts in.

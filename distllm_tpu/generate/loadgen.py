@@ -32,10 +32,8 @@ an asyncio loop, and TTFT is measured from the SCHEDULED arrival instant
 (never the actual send) — the same coordinated-omission correction the
 in-process driver applies to ``t_enqueue``.
 
-Used by the ``gen_load`` / ``gen_router`` bench stages
-(``DISTLLM_BENCH_LOAD=0`` / ``DISTLLM_BENCH_ROUTER=0`` skip) and the
-``scripts/loadgen.py`` CLI (``--endpoint http://...`` selects the HTTP
-mode); knobs documented in ``docs/observability.md``.
+Used by the ``scripts/loadgen.py`` CLI (``--endpoint http://...`` selects
+the HTTP mode); knobs documented in ``docs/observability.md``.
 """
 
 from __future__ import annotations
@@ -99,7 +97,7 @@ class LoadgenConfig:
     # (docs/speculative.md "Sampled verification").
     top_p: float = 1.0
     # Engine paged-pool override (blocks), consumed by the engine-building
-    # callers (scripts/loadgen.py CLI, the bench gen_tier stage) rather
+    # callers (scripts/loadgen.py CLI, tests/test_kv_tier.py) rather
     # than by build_workload: sizing the pool BELOW the workload's warm
     # working set forces HBM-tier eviction, so CPU smokes can exercise
     # the prefix-cache spill/promote tiers with tiny prompts instead of
@@ -177,8 +175,8 @@ class LoadReport:
     # Resilience accounting (docs/resilience.md): arrivals refused by
     # SLO-aware admission control, requests quarantined to FAILED
     # (dispatch failures / deadline timeouts), and the engine's
-    # retry/recovery counts over this run — what the gen_chaos stage
-    # gates (recoveries, goodput-under-fault) and reports (shed rate).
+    # retry/recovery counts over this run (recoveries,
+    # goodput-under-fault, shed rate: tests/test_resilience.py).
     shed_requests: int = 0
     shed_rate: float | None = None
     failed_requests: int = 0
@@ -187,14 +185,14 @@ class LoadReport:
     quarantined: int = 0
     tokens_by_request: list[list[int]] = field(default_factory=list)
     # Schedule-relative TTFT per ARRIVAL, aligned to the workload order
-    # (None = shed at admission or never emitted). What lets the
-    # gen_tier stage compare warm-session TTFT across tier-on/off arms
-    # request by request; tokens_by_request is aligned the same way
+    # (None = shed at admission or never emitted), so two
+    # runs of one workload compare request by request;
+    # tokens_by_request is aligned the same way
     # (shed arrivals contribute an empty list).
     ttft_by_request: list = field(default_factory=list)
 
     def to_fragment(self, prefix: str) -> dict:
-        """Flatten into ``{prefix}key`` fields for a bench stage record."""
+        """Flatten into ``{prefix}key`` fields of one JSON report line."""
         out = {
             f'{prefix}requests': self.requests,
             f'{prefix}tokens': self.tokens,
@@ -405,7 +403,7 @@ def run_http_loadgen(
 ) -> HttpLoadReport:
     """Replay ``workload`` open-loop against an OpenAI-compatible HTTP
     endpoint (chat_server or the router). Blocking facade over the
-    asyncio driver — call from synchronous code (CLI, bench stages)."""
+    asyncio driver — call from synchronous code (the CLI)."""
     return asyncio.run(
         _run_http_async(
             endpoint,

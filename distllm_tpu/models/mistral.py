@@ -616,9 +616,9 @@ def _decode_core(
     bandwidth bound, and the rolled scan's per-iteration dynamic-slice of
     the stacked MLP kernels is MATERIALIZED by XLA as a ~0.35 GB/layer
     temp (read slab + write temp + read temp ≈ 3x traffic on 78% of the
-    weights — found via AOT HLO census, scripts/probe_decode_hlo.py,
-    matching a ~3x gap to the weight-streaming roofline in 2026-07-31
-    notes on older code, in git history; not re-measured). Unrolling turns those into static slices that
+    weights — read off the compiled HLO on older code, 2026-07-31; not
+    re-measured, and no cell runs the rolled window: ROADMAP D2).
+    Unrolling turns those into static slices that
     fold into the matmuls. Prefill keeps the rolled scan: compute-bound,
     and the slice traffic amortizes over the whole token batch.
     """

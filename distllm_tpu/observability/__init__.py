@@ -12,7 +12,7 @@ Three layers, all dependency-free:
   ``log_event`` stdout funnel;
 - :mod:`~distllm_tpu.observability.flight` — the flight-recorder layer
   (ISSUE 3 tentpole): bounded per-engine-step ring, stall watchdog, debug
-  bundles, crash-proof ``RunRecord`` + ``Deadline`` for the bench contract;
+  bundles;
 - :mod:`~distllm_tpu.observability.perfetto` — Perfetto/Chrome trace-event
   export of the flight + span rings and per-request lifecycles (ISSUE 10
   tentpole; ``GET /debug/perfetto``, ``perfetto.json`` in bundles);
@@ -27,8 +27,7 @@ Three layers, all dependency-free:
   ``distllm_engine_mfu_measured`` gauges and the analytic-vs-measured
   calibration ratios;
 - :mod:`~distllm_tpu.observability.profiling` — the bounded
-  ``jax.profiler`` capture helper (``GET /debug/xprof``,
-  ``DISTLLM_BENCH_PROFILE``);
+  ``jax.profiler`` capture helper (``GET /debug/xprof``);
 - :mod:`~distllm_tpu.observability.history` — the bounded metric-history
   ring + background sampler (ISSUE 18 tentpole): retained time series
   over the live registry (``GET /debug/history``, ``history.json`` in
@@ -36,9 +35,8 @@ Three layers, all dependency-free:
 - :mod:`~distllm_tpu.observability.slo` — multi-window multi-burn-rate
   SLO engine over the history (``distllm_slo_burn_rate{window}``,
   ``slo_status()`` ok/warn/page, ``GET /debug/slo``);
-- :mod:`~distllm_tpu.observability.baseline` — BENCH-record parsing +
-  the baseline envelope, shared with ``scripts/benchdiff.py`` so the
-  offline gate and the runtime sentinel can never disagree on parsing;
+- :mod:`~distllm_tpu.observability.baseline` — the baseline envelope
+  the sentinel compares against: its schema, maker and reader;
 - :mod:`~distllm_tpu.observability.sentinel` — the runtime regression
   sentinel: live history windows vs the baseline envelope, firing the
   ``regression`` flight kind + ``distllm_sentinel_regressions_total``.
@@ -52,13 +50,10 @@ from __future__ import annotations
 
 from distllm_tpu.observability.baseline import (
     build_envelope,
-    envelope_from_records,
     load_envelope,
 )
 from distllm_tpu.observability.flight import (
-    Deadline,
     FlightRecorder,
-    RunRecord,
     StallWatchdog,
     dump_debug_bundle,
     get_flight_recorder,
@@ -122,7 +117,6 @@ __all__ = [
     'CompileWatcher',
     'CostModel',
     'Counter',
-    'Deadline',
     'FlightRecorder',
     'Gauge',
     'Histogram',
@@ -131,7 +125,6 @@ __all__ = [
     'MetricsRegistry',
     'ProfilerCapture',
     'RegressionSentinel',
-    'RunRecord',
     'Span',
     'StallWatchdog',
     'TraceBuffer',
@@ -143,7 +136,6 @@ __all__ = [
     'dump_debug_bundle',
     'dump_traces',
     'end_span',
-    'envelope_from_records',
     'get_compile_watcher',
     'get_flight_recorder',
     'get_metrics_history',

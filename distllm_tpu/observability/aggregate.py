@@ -166,7 +166,7 @@ def host_label(path: str | Path, seen: 'set[str] | None' = None) -> str:
     """Readable per-process label for one capture file.
 
     Multi-replica captures conventionally land as
-    ``<replica-id>/flight.jsonl`` (the bench) or
+    ``<replica-id>/flight.jsonl`` or
     ``capture-<host>.jsonl`` — a bare ``Path(path).name`` collapses the
     former to N identical ``flight.jsonl`` process groups, which is
     exactly the unreadable-merge bug this fixes. Generic stems

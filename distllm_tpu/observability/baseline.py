@@ -1,25 +1,16 @@
-"""Shared BENCH-record parsing + the sentinel baseline envelope.
+"""The sentinel's baseline envelope: schema, maker and reader.
 
-One parser for two consumers, so they can never disagree on what a
-record says:
+The runtime regression sentinel (``observability/sentinel.py``) compares
+*live* history windows against a **baseline envelope**: one value and one
+direction for each metric it knows how to read live. This module owns the
+envelope's schema, :func:`build_envelope` (a flat dict of measured numbers
+in, an envelope out) and :func:`load_envelope` (a file in, a validated
+envelope or ``None`` out). Nothing in the repo writes an envelope file
+today (ROADMAP D7): an operator who wants the sentinel armed serialises
+``build_envelope(...)`` of numbers they trust to ``DISTLLM_BASELINE``.
 
-- the offline trajectory gate (``scripts/benchdiff.py``) diffs
-  BENCH_r*.json records round over round and exits nonzero on a
-  regression;
-- the runtime regression sentinel (``observability/sentinel.py``)
-  compares *live* history windows against a **baseline envelope**
-  distilled from the newest record that actually carried metrics
-  (``scripts/benchdiff.py --emit-baseline``).
-
-Everything here is dependency-free (no jax, no registry import): the
-benchdiff CLI runs it standalone, and the sentinel imports it inside a
-serving process.
-
-Record shape: the driver-contract JSON ``{"n", "cmd", "rc",
-"parsed": {...}}`` or a bare metrics object; records that died before
-emitting (``parsed: null``) parse to an explicitly empty metrics dict,
-never a crash — the gate and the sentinel both must survive a crashed
-round.
+Dependency-free (no jax, no registry import): the sentinel imports it
+inside a serving process.
 """
 
 from __future__ import annotations
@@ -30,121 +21,25 @@ from pathlib import Path
 
 ENVELOPE_SCHEMA = 'distllm-baseline-envelope/v1'
 
-# Direction of "better" per gated metric. Matching is by substring /
-# suffix on the flattened key; anything unmatched is informational only
-# (shown in the benchdiff table, never gated or sentinelled) — counts,
-# batch sizes, cache-entry bookkeeping must not fail a round.
-# 'mfu_measured' / 'bw_util_measured' gate the per-kind XLA-measured
-# roofline columns the gen_kernel A/B stage records
-# (gen_kernel_{xla,pallas}_{mfu,bw_util}_measured,
-# docs/observability.md "Measured vs analytic MFU") so a kernel
-# regression — measured utilization falling on the same workload — trips
-# the trajectory gate even when tok/s noise hides it.
-_LOWER_BETTER_TOKENS = ('ttft', 'tpot', 'queue_wait', 'warmup_secs')
-_HIGHER_BETTER_SUFFIXES = ('value', 'mfu', 'vs_baseline')
-# 'promotion_overlap' gates the gen_tier stage's KV-tier prefetch
-# efficiency (1 - blocking wait / promotion span, docs/prefix_caching.md
-# "Tier hierarchy"): overlap falling means host→device promotions stopped
-# hiding behind decode windows. The stage's warm-TTFT metrics gate
-# lower-better via the 'ttft' token (gen_tier_warm_ttft_s /
-# gen_tier_cold_ttft_s), and gen_tier_warm_ttft_speedup higher-better via
-# the 'speedup' override, so a tier regression trips the gate from
-# either side. Raw spill/promotion COUNTS stay informational — workload-
-# dependent volume, not quality.
-#
-# 'recoveries' gates the gen_chaos stage (docs/resilience.md): fewer
-# recoveries on the SAME deterministic fault schedule means injected
-# faults stopped being survived — requests started failing (or the
-# schedule stopped firing) instead of retrying back to identical tokens.
-# Goodput-under-fault gates through the existing 'goodput' token
-# (gen_chaos_goodput_tokens). Shed counts/rates stay INFORMATIONAL by
-# design: shed volume is offered-load policy, not quality — a round that
-# sheds more under a heavier schedule is not a regression ('shed_rate'
-# deliberately matches no gated token).
-# 'greedy_match' gates the gen_kvq stage's ACCURACY arm (docs/serving.md
-# "Quantized KV cache"): the fraction of the int8-KV arm's greedy tokens
-# matching the bf16-KV arm's on the same workload. Falling match fraction
-# is a QUALITY regression — the compression got lossier — and trips the
-# trajectory gate exactly like a throughput fall; the stage records the
-# divergence rather than asserting it away, and this token is what keeps
-# that honesty enforceable round over round. Direction rule: higher is
-# better (1.0 = bit-identical streams), so the generic higher-better
-# machinery applies; a tolerance is the gate --threshold, not a
-# stage-side epsilon.
-_HIGHER_BETTER_TOKENS = (
-    'goodput', 'accept_rate', 'hit_rate', 'tok_s', 'mfu_measured',
-    'bw_util_measured', 'promotion_overlap', 'recoveries', 'greedy_match',
-)
+# Direction of "better" for an envelope metric, by substring of its name.
+_LOWER_BETTER_TOKENS = ('ttft', 'tpot')
 
 
-def gate_direction(key: str) -> str | None:
-    """``'higher'`` / ``'lower'`` for gated metrics, ``None`` for
-    informational ones. Lower-better tokens win ties (``gen_load_ttft_s``
-    is a latency even though the stage also reports values) — EXCEPT
-    ``speedup``, which outranks them: speedups are ratios-of-latencies
-    named after their numerator (``gen_prefix_ttft_speedup``,
-    ``gen_kernel_speedup``), so the 'ttft' substring alone would gate a
-    warm-start IMPROVEMENT as a regression."""
+def gate_direction(key: str) -> str:
+    """``'lower'`` for the latency metrics, ``'higher'`` for every other
+    envelope metric (throughput and the measured roofline shares)."""
     k = key.lower()
-    if 'speedup' in k:
-        return 'higher'
     if any(token in k for token in _LOWER_BETTER_TOKENS):
         return 'lower'
-    if k.endswith(_HIGHER_BETTER_SUFFIXES):
-        return 'higher'
-    if any(token in k for token in _HIGHER_BETTER_TOKENS):
-        return 'higher'
-    return None
-
-
-def extract_metrics(parsed) -> dict[str, float]:
-    """Numeric metrics from one record's parsed payload (flat dict in;
-    bools and non-numerics dropped; ``None``/missing payload → empty)."""
-    if not isinstance(parsed, dict):
-        return {}
-    out: dict[str, float] = {}
-    for key, value in parsed.items():
-        if isinstance(value, bool) or not isinstance(value, (int, float)):
-            continue
-        # bench records round-trip NaN/inf through json (allow_nan): a
-        # degenerate 0/0 mfu must not crash the gate, and NaN compares
-        # False against every threshold — drop it as "not reported"
-        # rather than let it silently pass.
-        if not math.isfinite(value):
-            continue
-        out[key] = float(value)
-    return out
-
-
-def load_record(path: str | Path) -> dict:
-    """One record file → ``{'name', 'metrics', 'error'}``. Accepts the
-    driver-contract wrapper (``parsed`` payload) or a bare metrics
-    object; unreadable/unparseable files become an empty record with the
-    error noted — the gate must be able to diff across a crashed round."""
-    path = Path(path)
-    name = path.stem.replace('BENCH_', '')
-    try:
-        doc = json.loads(path.read_text())
-    except (OSError, json.JSONDecodeError) as exc:
-        return {'name': name, 'metrics': {}, 'error': repr(exc)[:200]}
-    payload = doc.get('parsed', doc) if isinstance(doc, dict) else None
-    metrics = extract_metrics(payload)
-    error = None
-    if isinstance(payload, dict) and payload.get('error'):
-        error = str(payload['error'])[:200]
-    elif not metrics:
-        error = 'no metrics in record (crashed before emitting?)'
-    return {'name': name, 'metrics': metrics, 'error': error}
+    return 'higher'
 
 
 # ------------------------------------------------- the baseline envelope
-# Sentinel metric → record keys that can supply its baseline, best first.
-# The names mirror instruments.SENTINEL_METRIC_LABELS (single owner of
-# the counter label set); this table owns only the record-key mapping.
-# gen_load / gen_history keys are loadgen-measured serving numbers (the
-# closest analog of live traffic); the bare gen_value / gen_mfu keys are
-# the official per-round record's throughput columns, kept as fallbacks
-# so even an r02-era record yields a usable envelope.
+# Sentinel metric → keys of the measured-numbers dict that can supply its
+# baseline, best first. The names mirror instruments.SENTINEL_METRIC_LABELS
+# (single owner of the counter label set); this table owns only the key
+# mapping. The gen_load / gen_history keys are loadgen-measured serving
+# numbers (the closest analog of live traffic).
 ENVELOPE_SOURCE_KEYS: dict[str, tuple[str, ...]] = {
     'tok_s': ('gen_load_tok_s', 'gen_history_tok_s', 'gen_value'),
     'ttft_p95_s': ('gen_load_ttft_p95', 'gen_history_ttft_p95'),
@@ -155,7 +50,7 @@ ENVELOPE_SOURCE_KEYS: dict[str, tuple[str, ...]] = {
 
 
 def build_envelope(metrics: dict[str, float], *, source: str = '') -> dict:
-    """Distill one record's flat metrics into the baseline envelope the
+    """Distill a flat dict of measured numbers into the baseline envelope the
     runtime sentinel consumes. Metrics with no source key present are
     simply absent (the sentinel skips them); an all-absent envelope is
     valid and disarms the sentinel (counted), never raises."""
@@ -174,20 +69,6 @@ def build_envelope(metrics: dict[str, float], *, source: str = '') -> dict:
         'source': source,
         'metrics': envelope_metrics,
     }
-
-
-def envelope_from_records(records: list[dict]) -> dict:
-    """Envelope from the NEWEST record carrying any envelope-source
-    metric — exactly the record benchdiff would gate against. Zero
-    usable records (the r03–r05 tail, or an empty history) yields an
-    empty envelope, not a crash."""
-    for record in reversed(records):
-        envelope = build_envelope(
-            record.get('metrics') or {}, source=record.get('name', '')
-        )
-        if envelope['metrics']:
-            return envelope
-    return {'schema': ENVELOPE_SCHEMA, 'source': '', 'metrics': {}}
 
 
 def load_envelope(path: str | Path) -> dict | None:

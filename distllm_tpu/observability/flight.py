@@ -1,9 +1,4 @@
-"""Flight recorder + crash-proof run records (ISSUE 3 tentpole).
-
-Rounds 3–5 each had real measurements and an empty official record: the
-bench composed its one JSON line only after the *last* stage, so any
-timeout, wedge, or signal lost everything. This module is the layer that
-makes "numbers or an explanation" a structural property instead of a hope:
+"""Flight recorder, stall watchdog and debug bundles.
 
 - :class:`FlightRecorder` — a bounded, thread-safe ring of per-engine-step
   records (step kind, batch occupancy, token counts, duration, queue depth,
@@ -17,13 +12,7 @@ makes "numbers or an explanation" a structural property instead of a hope:
   callback dumps a debug bundle; it never kills the watched work.
 - :func:`dump_debug_bundle` — flight ring + metrics exposition + trace ring
   (+ best-effort ``jax.profiler`` device-memory capture) written to one
-  directory, so a dead stage still explains itself.
-- :class:`RunRecord` — an append-only JSONL run record plus an atomically
-  rewritten composed snapshot. Each completed bench stage lands on disk the
-  moment it finishes; the driver-contract line is composed from whatever
-  the record holds at emission time (normal exit, deadline, or signal).
-- :class:`Deadline` — a global wall-clock budget from which per-stage
-  budgets and retry-ladder shares are derived.
+  directory, so a dead process still explains itself.
 
 Everything here is dependency-free and safe to import on any backend.
 """
@@ -125,8 +114,8 @@ def dump_debug_bundle(
     extra: dict | None = None,
 ) -> dict[str, str]:
     """Write the full observability state to ``directory`` and return the
-    written paths. Called by the watchdog on stall, by bench stages on
-    failure/SIGTERM, and by ``GET /debug/bundle`` on demand.
+    written paths. Called by the watchdog on stall and by
+    ``GET /debug/bundle`` on demand.
 
     Contents: ``flight.jsonl`` (engine-step ring), ``metrics.prom``
     (Prometheus exposition snapshot), ``traces.jsonl`` (span ring),
@@ -285,12 +274,11 @@ class StallWatchdog:
     so an engine that stops dispatching windows (wedged backend, deadlocked
     host loop) trips the dog without any engine-side wiring. The default
     ``on_stall`` dumps a bundle to ``bundle_dir`` and logs it — it never
-    kills the watched work (the stage budget / deadline does that); it
-    exists so the corpse carries evidence.
+    kills the watched work; it exists so the corpse carries evidence.
 
     Fires at most ``max_fires`` times (default 1) per arm; ``beat()``
     force-marks progress for work that is alive but quiet. Use as a
-    context manager around a stage, or ``start()``/``stop()`` manually.
+    context manager around the work, or ``start()``/``stop()`` manually.
     """
 
     def __init__(
@@ -388,124 +376,3 @@ class StallWatchdog:
 
     def __exit__(self, *exc_info) -> None:
         self.stop()
-
-
-# -------------------------------------------------------------- run record
-class RunRecord:
-    """Append-only on-disk run record with a composed snapshot.
-
-    ``record(stage, fragment)`` appends one JSON line
-    ``{"stage": ..., "t_wall": ..., "fragment": {...}}`` to ``path``
-    (write + flush + fsync — the line is durable the moment the call
-    returns) and atomically rewrites ``snapshot_path`` with the merged
-    view of every fragment so far. A crash between stages loses nothing;
-    a crash *mid-write* loses at most the in-flight stage (the JSONL
-    reader skips a torn final line).
-
-    ``compose()`` merges fragments in record order (later keys win) — the
-    exact dict the bench's driver-contract line is built from.
-    """
-
-    def __init__(
-        self, path: str | Path, snapshot_path: str | Path | None = None
-    ) -> None:
-        self.path = Path(path)
-        self.snapshot_path = (
-            Path(snapshot_path)
-            if snapshot_path is not None
-            else self.path.with_name(self.path.stem + '_snapshot.json')
-        )
-        self.path.parent.mkdir(parents=True, exist_ok=True)
-        self._lock = threading.Lock()
-
-    def record(self, stage: str, fragment: dict) -> None:
-        line = json.dumps(
-            {'stage': stage, 't_wall': time.time(), 'fragment': fragment},
-            default=str,
-        )
-        with self._lock:
-            with open(self.path, 'a') as handle:
-                handle.write(line + '\n')
-                handle.flush()
-                os.fsync(handle.fileno())
-        self.write_snapshot()
-
-    def entries(self) -> list[dict]:
-        """Replay the JSONL (torn/corrupt lines skipped, order kept)."""
-        try:
-            text = self.path.read_text()
-        except OSError:
-            return []
-        out = []
-        for line in text.splitlines():
-            if not line.strip():
-                continue
-            try:
-                out.append(json.loads(line))
-            except json.JSONDecodeError:
-                continue  # torn final line from a mid-write crash
-        return out
-
-    def stages(self) -> list[str]:
-        """Stage names in first-recorded order (duplicates collapsed)."""
-        seen: list[str] = []
-        for entry in self.entries():
-            if entry.get('stage') not in seen:
-                seen.append(entry.get('stage'))
-        return seen
-
-    def compose(self) -> dict:
-        merged: dict = {}
-        for entry in self.entries():
-            fragment = entry.get('fragment')
-            if isinstance(fragment, dict):
-                merged.update(fragment)
-        return merged
-
-    def write_snapshot(self) -> None:
-        """Atomically rewrite the composed snapshot (tmp + rename)."""
-        tmp = self.snapshot_path.with_name(self.snapshot_path.name + '.tmp')
-        try:
-            tmp.write_text(json.dumps(self.compose(), default=str))
-            os.replace(tmp, self.snapshot_path)
-        except OSError:
-            pass  # snapshot is a convenience view; the JSONL is the record
-
-
-# ---------------------------------------------------------------- deadline
-class Deadline:
-    """A global wall-clock budget that derives per-stage shares.
-
-    ``remaining()`` never goes below zero; ``budget(nominal, floor=...)``
-    is the pattern bench stages use: spend up to ``nominal`` seconds but
-    never past the deadline (minus a small reserve kept for composing and
-    emitting the final record).
-    """
-
-    def __init__(self, total_s: float, reserve_s: float = 15.0) -> None:
-        if total_s <= 0:
-            raise ValueError('total_s must be > 0')
-        self.total_s = float(total_s)
-        self.reserve_s = float(reserve_s)
-        self._start = time.monotonic()
-
-    def elapsed(self) -> float:
-        return time.monotonic() - self._start
-
-    def remaining(self) -> float:
-        return max(0.0, self.total_s - self.reserve_s - self.elapsed())
-
-    @property
-    def expired(self) -> bool:
-        return self.remaining() <= 0.0
-
-    def budget(self, nominal_s: float, floor_s: float = 0.0) -> float:
-        """Clamp a nominal stage budget into the remaining window.
-
-        Returns 0 when less than ``floor_s`` is left — the caller should
-        skip the stage (and say so) rather than start doomed work.
-        """
-        remaining = self.remaining()
-        if remaining < max(floor_s, 1e-9):
-            return 0.0
-        return min(float(nominal_s), remaining)

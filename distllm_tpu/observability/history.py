@@ -2,8 +2,7 @@
 
 The registry (``metrics.py``) is instantaneous — a scrape says what the
 counters read *now*, nothing about five minutes ago — and every other
-telemetry layer is offline (benchdiff gates after the run, Perfetto is
-post-mortem). This module is the retention layer in between (ISSUE 18
+telemetry layer is offline (Perfetto is post-mortem). This module is the retention layer in between (ISSUE 18
 tentpole): a dependency-free, bounded, thread-safe time-series ring that
 periodically folds a full ``MetricsRegistry.collect()`` snapshot into
 per-series point deques, so a serving process can answer "is this
@@ -399,8 +398,8 @@ class HistorySampler:
     A tick that raises is counted and never kills the thread. Exactly
     one sampler should own a history at a time — the chat server owns
     the process singleton in serving, the engine only when
-    ``EngineConfig.history_interval_s`` > 0, bench/loadgen own it in
-    scripted runs."""
+    ``EngineConfig.history_interval_s`` > 0, ``scripts/loadgen.py`` owns
+    it in scripted runs."""
 
     def __init__(
         self,

@@ -399,7 +399,7 @@ COMPILE_CACHE_HITS = _registry.counter(
 PROFILER_CAPTURES = _registry.counter(
     'distllm_profiler_captures_total',
     'Bounded jax.profiler captures (observability/profiling.py; '
-    'GET /debug/xprof, DISTLLM_BENCH_PROFILE), by outcome '
+    'GET /debug/xprof), by outcome '
     'ok/error/rejected.',
     labelnames=('outcome',),
 )
@@ -650,7 +650,7 @@ for _window in SLO_BURN_WINDOW_LABELS:
 
 # --------------------------- runtime regression sentinel (sentinel.py)
 # The live metrics the sentinel compares against the baseline envelope
-# (scripts/benchdiff.py --emit-baseline). Single owner: sentinel.py's
+# (observability/baseline.py). Single owner: sentinel.py's
 # live-extractor table and the counter pre-registration both iterate it.
 SENTINEL_METRIC_LABELS = (
     'tok_s', 'ttft_p95_s', 'tpot_p95_s', 'mfu_measured', 'bw_util_measured',
@@ -659,7 +659,7 @@ SENTINEL_REGRESSIONS = _registry.counter(
     'distllm_sentinel_regressions_total',
     'Live-window regressions detected by the runtime sentinel, by '
     'baseline metric: a trailing history window degraded past the '
-    'sentinel threshold vs the BENCH baseline envelope. One count per '
+    'sentinel threshold vs the baseline envelope. One count per '
     'degradation episode (latched until the metric recovers).',
     labelnames=('metric',),
 )

@@ -75,8 +75,8 @@ def quantile_from_cumulative(
 
     Also the delta-quantile building block: subtract two
     ``cumulative_counts()`` snapshots element-wise and pass the result, and
-    the estimate covers only the observations between them (how the
-    ``gen_load`` bench stage isolates its own traffic from warmup's).
+    the estimate covers only the observations between them (how
+    ``run_loadgen`` isolates its own traffic from warmup's).
     """
     if not 0.0 <= q <= 1.0:
         raise ValueError(f'quantile must be in [0, 1], got {q}')

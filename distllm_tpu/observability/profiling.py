@@ -19,9 +19,6 @@ session); concurrent starts are *rejected*, not queued. Consumers:
 
 - ``GET /debug/xprof?seconds=N`` on the chat server — on-demand blocking
   capture of a live serving process, returns the trace directory;
-- ``bench.py``'s ``DISTLLM_BENCH_PROFILE`` stage profiling — routed
-  through :meth:`start`/:meth:`stop` so an unsupported-backend error
-  downgrades to a telemetry note instead of a dead stage;
 - debug bundles — the capture state (active/last_error/total) rides
   ``startup.json`` so a bundle says whether a capture was in flight.
 

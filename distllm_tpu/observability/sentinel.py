@@ -1,16 +1,13 @@
-"""Runtime regression sentinel: live history vs the BENCH baseline.
+"""Runtime regression sentinel: live history vs a baseline envelope.
 
-The offline trajectory gate (``scripts/benchdiff.py``) only speaks
-after a round completes; this module connects that baseline to runtime.
-A :class:`RegressionSentinel` loads a **baseline envelope** — the
-distilled tok/s, TTFT/TPOT quantile, and measured-roofline numbers of
-the newest BENCH record, written by ``benchdiff.py --emit-baseline``
-through the SAME extraction code (``observability/baseline.py``), so
-gate and sentinel can never disagree on parsing — and, on every history
-tick, compares each envelope metric against the live trailing window.
+A :class:`RegressionSentinel` loads a **baseline envelope** — tok/s,
+TTFT/TPOT quantiles and measured-roofline numbers of a run someone
+trusts, in the schema of ``observability/baseline.py`` — and, on every
+history tick, compares each envelope metric against the live trailing
+window. Nothing in the repo writes an envelope file today (ROADMAP D7).
 
-A live window that degrades past ``threshold`` (default 20% — looser
-than the offline gate's 5% because live windows are noisy) fires ONE
+A live window that degrades past ``threshold`` (default 20%: live
+windows are noisy) fires ONE
 ``regression`` flight record and one
 ``distllm_sentinel_regressions_total{metric}`` count, then latches
 until the metric recovers (no once-per-tick alarm storms). Windows

@@ -307,7 +307,7 @@ class CompileWatcher:
 
         ``scope`` namespaces the process-repeat dedup: each engine
         passes its own scope, because a SECOND engine in one process
-        (bench A/B stages, the quantization fallback ladder) builds new
+        (an A/B of two engines, the quantization fallback ladder) builds new
         jit wrappers whose warmup really recompiles — the same (kind,
         shape) under a fresh scope must not read as a hit. The
         persistent-cache signal is deliberately scope-free (that cache

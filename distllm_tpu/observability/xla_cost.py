@@ -6,8 +6,7 @@ weight bytes per pass. That is a *model*, and ROADMAP item 1 (the Pallas
 ragged-attention kernel) needs *measured* device cost truth before it can
 claim a win over it: a kernel that cuts real HBM traffic moves
 ``cost_analysis()`` bytes, not the hand math. This module prices each
-compiled serving executable via ``compiled.cost_analysis()`` — previously
-used only by the offline ``scripts/probe_decode_hlo.py`` census — and
+compiled serving executable via ``compiled.cost_analysis()`` and
 publishes the measured twins of the analytic gauges:
 
 - ``distllm_engine_mfu_measured{kind}`` /
@@ -15,8 +14,7 @@ publishes the measured twins of the analytic gauges:
   utilization from what XLA compiled, beside the analytic gauges;
 - ``distllm_engine_roofline_flops_ratio{kind}`` /
   ``distllm_engine_roofline_bytes_ratio{kind}`` — measured / analytic
-  per dispatch, so calibration drift is a visible number instead of a
-  probe-script investigation. FLOPs near 1.0 = calibrated; bytes > 1.0
+  per dispatch, so calibration drift is a visible number. FLOPs near 1.0 = calibrated; bytes > 1.0
   is expected (KV + activation traffic the weight-stream model omits),
   and a jump means the compiled graph carries traffic the model cannot
   see (layout churn, materialized slices — the r03 845 ms window).

@@ -4,8 +4,8 @@ Why not XLA SDPA here: at the embed pipeline's hot shape ([512, 256],
 12 heads) XLA materializes the masked ``[B, N, S, S]`` score/softmax
 tensors in HBM — ~0.8 GB per intermediate per layer, several GB of HBM
 traffic that capped the whole forward at ~0.43 MFU in a 2026-07-31
-``scripts/probe_attn.py`` record on older code (not re-measured). Why not ``jax.experimental.pallas.ops.tpu.
-flash_attention``: its ``MIN_BLOCK_SIZE = 128`` forces sequence lengths to
+record on older code (not re-measured). Why not
+``jax.experimental.pallas.ops.tpu.flash_attention``: its ``MIN_BLOCK_SIZE = 128`` forces sequence lengths to
 multiples of 128, which conflicts with the fine bucket ladder (160/224/320
 rungs) that keeps embed padding waste low (``models/tokenizer.py
 bucket_ladder``).
@@ -31,8 +31,8 @@ Reference parity note: the reference gets this op from flash-attn/SDPA
 inside HF models (``distllm/embed/encoders/auto.py:119-138``, faesm for
 ESM); this is the TPU-native equivalent (SURVEY.md section 2.4 N3).
 
-Routing policy. The numbers are a ``scripts/probe_encoder_matrix.py``
-record of 2026-07-31 on older code (in git history), NOT re-measured on
+Routing policy. The numbers are a record of 2026-07-31 on older code
+(an encoder-by-shape matrix; PERF.md section 7), NOT re-measured on
 today's code — hypotheses that explain the policy, not results (constant
 token budget B*S = 128k per forward):
 

@@ -34,7 +34,7 @@ faults. Determinism: each site fires on an explicit call schedule
 (``after`` skipped calls, then up to ``times`` fires) and/or a seeded
 per-site ``random.Random`` probability, so the same arming + the same
 call sequence reproduces the same fault pattern (what makes the
-``gen_chaos`` bench stage's fault-off token-identity check meaningful).
+fault-off token-identity check of ``tests/test_resilience.py`` meaningful).
 
 Arming: programmatic (:meth:`FaultInjector.arm`) or the
 ``DISTLLM_FAULTS`` env var, a comma-separated list of site clauses::

@@ -15,7 +15,7 @@ Policies (``RouterConfig.policy``):
 - ``least_loaded`` — lightest ``GET /loadinfo`` queue (queue_depth, then
   in-flight, then KV occupancy), probed with a short-TTL cache so one
   routing decision never burns a round trip on a warm entry.
-- ``round_robin`` — the baseline rotation (the bench's control arm).
+- ``round_robin`` — the baseline rotation (a control arm).
 
 Health integration: a background probe loop polls each replica's
 ``/health``; connection failure or a non-ready answer removes it from
@@ -390,7 +390,7 @@ def build_router_app(config: RouterConfig):
     app.router.add_get('/metrics', metrics)
     app.on_startup.append(_start)
     app.on_cleanup.append(_stop)
-    # Exposed for tests/bench: drive rotation state directly.
+    # Exposed for tests: drive rotation state directly.
     app['router_replicas'] = replicas
     app['router_affinity'] = affinity
     app['router_config'] = config

@@ -14,7 +14,18 @@ Run: ``JAX_PLATFORMS=cpu python scripts/aot_preflight.py [single] [multichip] [e
 (no argument = all three sets).
 """
 
+import argparse
 import os
+
+_ALL_SETS = ['single', 'multichip', 'embed']
+_parser = argparse.ArgumentParser(
+    description=__doc__, formatter_class=argparse.RawDescriptionHelpFormatter
+)
+_parser.add_argument('sets', nargs='*', help=f'of {_ALL_SETS}; default all')
+SETS = _parser.parse_args().sets or _ALL_SETS  # before libtpu is loaded
+if set(SETS) - set(_ALL_SETS):
+    _parser.error(f'unknown set in {SETS}: choose from {_ALL_SETS}')
+
 os.environ.setdefault('JAX_PLATFORMS', 'cpu')
 os.environ.setdefault('TPU_LOG_DIR', 'disabled')
 import numpy as np
@@ -60,7 +71,6 @@ def window_args(params_tree, B, nb, R):
 _LAYER_UNROLL = os.environ.get('DISTLLM_PREFLIGHT_LAYER_UNROLL', '1') != '0'
 
 failures: list[str] = []
-SETS = sys.argv[1:] or ['single', 'multichip', 'embed']
 
 
 def compile_window(params_tree, B, nb, R, backend, label):

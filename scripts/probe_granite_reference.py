@@ -28,6 +28,7 @@ that RMS, and the largest token gap (``reference.token_gaps``).
 
 from __future__ import annotations
 
+import argparse
 import json
 import sys
 from pathlib import Path
@@ -198,23 +199,23 @@ def check(model: dict, seeds: list[int], arms: list[str]) -> None:
 
 
 def main() -> int:
+    parser = argparse.ArgumentParser(
+        description=__doc__,
+        formatter_class=argparse.RawDescriptionHelpFormatter,
+    )
+    parser.add_argument('mode', choices=('check', 'logits'))
+    parser.add_argument('args', nargs='*', metavar='config.json | seed')
+    parser.add_argument('--arms', default='program,bf16_state,sqrt_scale')
+    opts = parser.parse_intermixed_args()
     enable_compile_cache()
-    mode, *args = sys.argv[1:]
+    args = opts.args
     config = ROOT / 'benchmarks/configs/granite-4.0-h-small.json'
     if args and args[0].endswith('.json'):  # a toy size, to rehearse on the CPU
         config = Path(args.pop(0))
-    arms = ['program', 'bf16_state', 'sqrt_scale']
-    if args and args[0] == '--arms':
-        arms = args[1].split(',')
-        args = args[2:]
+    arms = opts.arms.split(',')
     seeds = [int(a) for a in args] or [3100000019]
     model = json.loads(config.read_text())
-    if mode == 'check':
-        check(model, seeds, arms)
-    elif mode == 'logits':
-        logits(model, seeds, arms)
-    else:
-        raise SystemExit(__doc__)
+    {'check': check, 'logits': logits}[opts.mode](model, seeds, arms)
     return 0
 
 

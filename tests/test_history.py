@@ -13,8 +13,7 @@ from distllm_tpu.observability import instruments as _metrics
 from distllm_tpu.observability.baseline import (
     ENVELOPE_SCHEMA,
     build_envelope,
-    envelope_from_records,
-    extract_metrics,
+    gate_direction,
     load_envelope,
 )
 from distllm_tpu.observability.flight import FlightRecorder
@@ -396,12 +395,14 @@ def test_slo_status_verdicts_and_gauges():
 
 
 # --------------------------------------------------------------- baseline
-def test_extract_metrics_drops_non_numeric():
-    metrics = extract_metrics({
-        'tok_s': 100.0, 'n': 3, 'ok': True, 'name': 'r', 'bad': float('nan'),
-    })
-    assert metrics == {'tok_s': 100.0, 'n': 3.0}
-    assert extract_metrics(None) == {}
+def test_gate_direction_latencies_lower_everything_else_higher():
+    """Every metric the sentinel can read live has a direction."""
+    assert {
+        name: gate_direction(name) for name in _metrics.SENTINEL_METRIC_LABELS
+    } == {
+        'tok_s': 'higher', 'ttft_p95_s': 'lower', 'tpot_p95_s': 'lower',
+        'mfu_measured': 'higher', 'bw_util_measured': 'higher',
+    }
 
 
 def test_build_envelope_prefers_best_source_key():
@@ -424,20 +425,17 @@ def test_build_envelope_prefers_best_source_key():
     assert 'mfu_measured' not in envelope['metrics']
 
 
-def test_envelope_from_records_newest_usable_wins():
-    records = [
-        {'name': 'r01', 'metrics': {'gen_value': 100.0}},
-        {'name': 'r02', 'metrics': {'gen_value': 184.0}},
-        {'name': 'r03', 'metrics': {}},  # the crashed tail
-    ]
-    envelope = envelope_from_records(records)
-    assert envelope['source'] == 'r02'
-    assert envelope['metrics']['tok_s']['value'] == 184.0
-    empty = envelope_from_records([{'name': 'r03', 'metrics': {}}])
-    assert empty['metrics'] == {}
-    assert envelope_from_records([]) == {
-        'schema': ENVELOPE_SCHEMA, 'source': '', 'metrics': {},
+def test_build_envelope_without_a_source_key_is_empty_and_disarms():
+    """Numbers under no key the table knows make a valid, empty envelope:
+    the sentinel counts a disarm and never raises."""
+    envelope = build_envelope({'unrelated': 3.0, 'gen_tok': 1.0}, source='x')
+    assert envelope == {
+        'schema': ENVELOPE_SCHEMA, 'source': 'x', 'metrics': {},
     }
+    _, history = _fresh()
+    sentinel = RegressionSentinel(history, envelope=envelope)
+    assert not sentinel.armed
+    assert sentinel.evaluate(now=1000.0) == []
 
 
 def test_load_envelope_roundtrip_and_degraded_modes(tmp_path):
@@ -666,45 +664,107 @@ def test_build_info_and_uptime_instruments():
     assert 'distllm_server_uptime_seconds' in rendered
 
 
-def test_gen_history_stage_cpu_smoke(tmp_path):
-    """Acceptance smoke (ISSUE 18): the gen_history bench stage completes
-    on CPU — the injected slow_window slowdown trips the sentinel, the
-    clean arm trips nothing, the latch holds (no re-fire storm), burn
-    gauges move under the overload arm, history on/off runs are
-    token-identical, and the sampler thread does not leak. Run directly:
-    ``JAX_PLATFORMS=cpu DISTLLM_BENCH_SMALL=1 python bench.py --stage
-    gen_history``."""
-    import os
-    import subprocess
-    import sys
-    from pathlib import Path
+def test_gen_history_stage_cpu_smoke():
+    """The telemetry scenario end to end: real traffic from the open-loop
+    load generator over one warmed engine (``serving_smoke.build_engine``)
+    with the process history ring and its sampler thread live.
 
-    repo = Path(__file__).resolve().parent.parent
-    env = dict(os.environ)
-    env.update(
-        JAX_PLATFORMS='cpu',
-        DISTLLM_BENCH_SMALL='1',
-        DISTLLM_BENCH_RECORD_DIR=str(tmp_path),
-        DISTLLM_BENCH_BUNDLE_DIR=str(tmp_path / 'bundles'),
-        DISTLLM_BENCH_WATCHDOG_S='0',
+    - the ring retains windows of the run (tokens, TTFT and TPOT quantiles);
+    - a sentinel calibrated on those recorded windows, by value, stays quiet
+      when it judges the same windows (the old stage timed a second run and
+      compared two clocks; a CPU's two readings differ by more than any
+      threshold worth having, PERF.md);
+    - with the sampler stopped the same schedule emits the same tokens:
+      history is observation only;
+    - a ``slow_window`` sleep long enough that no machine can serve half the
+      calibrated rate under it fires the sentinel on ``tok_s``, once: the
+      second pass is latched;
+    - with an SLO no request can meet every request misses, the 60 s burn
+      gauge leaves zero and the verdict is ``page``; with admission control
+      on, the same SLO sheds;
+    - no sampler thread outlives ``stop()``."""
+    from distllm_tpu.generate.loadgen import build_workload, run_loadgen
+    from distllm_tpu.observability.sentinel import LIVE_EXTRACTORS
+    from distllm_tpu.resilience import get_fault_injector
+    from serving_smoke import build_engine, workload_config
+
+    interval_s = 0.05
+    engine = build_engine(ttft_slo_s=30.0)
+    history = get_metrics_history()
+    history.clear()  # this test's windows, not an earlier test's tail
+    sampler = HistorySampler(history, interval_s=interval_s)
+    injector = get_fault_injector()
+    try:
+        workload = build_workload(workload_config())
+        sampler.start()
+        clean = run_loadgen(engine, workload)
+        sampler.stop()
+        history.sample_once()  # fold the tail; the ring is now still
+        assert history.samples >= 3
+
+        # Calibrate on the recorded windows, then judge the same windows.
+        now = time.time()
+        window_s = clean.elapsed_s + 4 * interval_s
+        recorded = {
+            name: LIVE_EXTRACTORS[name](history, window_s, now)
+            for name in ('tok_s', 'ttft_p95_s', 'tpot_p95_s')
+        }
+        assert all(v is not None and v > 0 for v in recorded.values()), recorded
+        envelope = build_envelope(
+            {
+                'gen_history_tok_s': recorded['tok_s'],
+                'gen_history_ttft_p95': recorded['ttft_p95_s'],
+                'gen_history_tpot_p95': recorded['tpot_p95_s'],
+            },
+            source='the clean arm, as the ring recorded it',
+        )
+        assert len(envelope['metrics']) == 3
+        quiet = RegressionSentinel(
+            history, envelope=envelope, threshold=0.05, window_s=window_s
+        )
+        assert quiet.evaluate(now) == []
+
+        identity = run_loadgen(engine, workload)  # no sampler thread runs
+        assert identity.tokens_by_request == clean.tokens_by_request
+
+        # Slow arm. A window emits at most rows x (steps + 1) tokens (a
+        # prefill's first token beside each row's steps) and now lasts at
+        # least delay_s, so the rate is at most a quarter of the calibrated
+        # one whatever the machine does; the sentinel fires under a half.
+        rows, steps = engine.config.max_num_seqs, engine.config.decode_steps
+        delay_s = rows * (steps + 1) / (0.25 * recorded['tok_s'])
+        history.clear()
+        sampler.start()
+        injector.arm('slow_window', times=10**6, delay_s=delay_s, after=0)
+        slow = run_loadgen(
+            engine, build_workload(workload_config(num_requests=6))
+        )
+        injector.disarm()
+        sampler.stop()
+        history.sample_once()
+        alarm = RegressionSentinel(
+            history, envelope=envelope, threshold=0.5,
+            window_s=slow.elapsed_s + 4 * interval_s,
+        )
+        fired = alarm.evaluate()
+        assert 'tok_s' in [event['metric'] for event in fired], fired
+        assert alarm.evaluate() == []  # one alarm an episode
+
+        # Overload: every request is served and misses its SLO.
+        engine.config.ttft_slo_s = 1e-9
+        sampler.start()
+        overload = run_loadgen(engine, workload)
+        sampler.stop()
+        history.sample_once()
+        assert overload.slo_missed == len(workload) and overload.slo_met == 0
+        assert update_burn_gauges(history)['60s'] > 0
+        assert slo_status(history)['verdict'] == 'page'
+        engine.admission_control = True
+        assert run_loadgen(engine, workload).shed_requests > 0
+    finally:
+        injector.disarm()
+        sampler.stop()
+        engine.shutdown()
+    assert not any(
+        t.name == SAMPLER_THREAD_NAME for t in threading.enumerate()
     )
-    env.pop('DISTLLM_FAULTS', None)  # the stage arms its own slowdown
-    env.pop('DISTLLM_BENCH_HISTORY', None)  # the skip knob must not hide it
-    proc = subprocess.run(
-        [sys.executable, str(repo / 'bench.py'), '--stage', 'gen_history'],
-        capture_output=True, text=True, timeout=420, cwd=repo, env=env,
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    fragment = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert 'gen_history_error' not in fragment, (
-        fragment.get('gen_history_error')
-    )
-    assert fragment['gen_history_tokens_identical'] is True
-    assert fragment['gen_history_clean_regressions'] == 0
-    assert fragment['gen_history_slow_regressions'] >= 1
-    assert fragment['gen_history_slow_relatch_regressions'] == 0
-    assert fragment['gen_history_burn_60s'] > 0
-    assert fragment['gen_history_slo_verdict'] == 'page'
-    assert fragment['gen_history_shed_requests'] > 0
-    assert fragment['gen_history_sampler_leaked'] is False
-    assert fragment['gen_history_tok_s'] > 0

@@ -267,46 +267,139 @@ def test_tier_config_validation():
         )
 
 
-# -------------------------------------------- gen_tier bench stage (smoke)
-@pytest.mark.slow  # two engine warmups + two open-loop arms (~2 min); the
-# fast tier covers the same contract in-process via the engine tests above
-def test_gen_tier_stage_cpu_smoke(tmp_path):
-    """Acceptance smoke: at a paged pool sized below the warm working
-    set, the gen_tier fragment shows (1) warm-session TTFT with the tier
-    on below the tier-off cold TTFT, (2) >= 1 recorded spill and >= 1
-    promotion, and (3) tier on/off token identity under greedy fp32.
-    Run directly: ``JAX_PLATFORMS=cpu DISTLLM_BENCH_SMALL=1 python
-    bench.py --stage gen_tier``."""
-    import json
-    import os
-    import subprocess
-    import sys
-    from pathlib import Path
+# ------------------------------------------------ KV-tier serving smoke
+def _last_request(engine) -> dict:
+    return [
+        r for r in engine.flight.snapshot() if r['kind'] == 'request'
+    ][-1]
 
-    repo = Path(__file__).resolve().parent.parent
-    env = dict(os.environ)
-    env.update(
-        JAX_PLATFORMS='cpu',
-        DISTLLM_BENCH_SMALL='1',
-        DISTLLM_BENCH_RECORD_DIR=str(tmp_path),
-        DISTLLM_BENCH_BUNDLE_DIR=str(tmp_path / 'bundles'),
-        DISTLLM_BENCH_WATCHDOG_S='0',
+
+def test_gen_tier_stage_cpu_smoke(tmp_path):
+    """The KV-tier scenario end to end over ``serving_smoke.build_engine``
+    (the tier-on engine warmed) and one ``disk_kv_tier_dir``.
+
+    Open loop: four warm sessions of a four-block prefix against a pool of
+    19 usable blocks that two running rows can fill, so session prefixes
+    cannot stay resident: they spill and are promoted back, by
+    construction. Tier on and tier off serve the same schedule to the same
+    tokens. Then one request at a time: a prompt whose prefix the pool has
+    since evicted is served from the tier (its ``request`` record shows the
+    four prefix blocks as ``cached_tokens`` and a prefill of the tail only,
+    by the paged route), where the tier-off engine prefills all of it; and
+    a fresh engine over the same directory does the same from disk.
+
+    Whether a promotion is FASTER than the prefill it replaces is a
+    question for a cell on the chip (ROADMAP R3); a CPU's clock cannot
+    answer it and this test reads no clock."""
+    from distllm_tpu.generate.loadgen import build_workload, run_loadgen
+    from distllm_tpu.observability import instruments as _m
+    from serving_smoke import MODEL, build_engine, workload_config
+
+    pool = dict(num_blocks=20)
+    tier = dict(
+        host_kv_tier_bytes=64 << 20, disk_kv_tier_dir=str(tmp_path / 'tier')
     )
-    proc = subprocess.run(
-        [sys.executable, str(repo / 'bench.py'), '--stage', 'gen_tier'],
-        capture_output=True, text=True, timeout=420, cwd=repo, env=env,
+    workload = build_workload(
+        workload_config(
+            num_sessions=4, warm_fraction=0.75, prefix_tokens=32,
+            prompt_tokens=(4, 16), output_tokens=(4, 8),
+        )
     )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    fragment = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert 'gen_tier_error' not in fragment, fragment.get('gen_tier_error')
-    assert fragment['gen_tier_tokens_identical'] is True
-    assert fragment['gen_tier_spills'] >= 1
-    assert fragment['gen_tier_promotions'] >= 1
-    assert (
-        fragment['gen_tier_warm_ttft_s'] < fragment['gen_tier_cold_ttft_s']
+    rng = np.random.default_rng(5)
+    prompts = [
+        [int(t) for t in rng.integers(1, MODEL.vocab_size, size=37)]
+        for _ in range(7)
+    ]
+    greedy = SamplingParams(temperature=0.0, max_tokens=4)
+
+    def serve(engine):
+        """The open-loop run, then the seven prompts one at a time and the
+        first again: each leaves four blocks cached, and six push all four
+        of the first one's out (eviction takes a chain's oldest block
+        first: fewer would leave its tail on the device, unreachable
+        behind a spilled head). Returns the run's report, every output,
+        and the ``request`` record of the repeated prompt."""
+        report = run_loadgen(engine, workload)
+        outs = [engine.generate_ids([p], greedy)[0] for p in prompts]
+        again = engine.generate_ids([prompts[0]], greedy)[0]
+        return report, outs + [again], _last_request(engine)
+
+    on = build_engine(**pool, **tier)
+    try:
+        on_report, on_outs, on_record = serve(on)
+        summary = on.tier_summary()
+    finally:
+        on.shutdown()
+    off = build_engine(warm=False, **pool)
+    try:
+        off_report, off_outs, off_record = serve(off)
+    finally:
+        off.shutdown()
+
+    assert on_report.tokens_by_request == off_report.tokens_by_request
+    assert on_outs == off_outs and on_outs[0] == on_outs[-1]
+    assert summary['spills'] >= 1 and summary['spilled_blocks'] >= 1
+    assert summary['promotions'] >= 1 and summary['promoted_blocks'] >= 1
+    assert summary['disk_blocks'] >= 1
+    assert 0.0 <= summary['promotion_overlap'] <= 1.0
+    assert on_report.warm_prefix_hit_tokens > 0
+    # The repeated prompt: 32 of its 37 tokens came from the tier.
+    assert on_record['prompt_tokens'] == off_record['prompt_tokens'] == 37
+    assert on_record['cached_tokens'] == 32
+    assert on_record['prefill_tokens'] == 5
+    assert on_record['routes'] == {'paged': 1}
+    assert off_record['cached_tokens'] == 0
+    assert off_record['prefill_tokens'] == 37
+    assert off_record['routes'] == {'dense': 1}
+
+    # Warm restart: a fresh engine over the same directory finds the
+    # first engine's spills on disk.
+    disk_before = _m.PREFIX_TIER_PROMOTIONS.labels(tier='disk').value
+    fresh = build_engine(warm=False, **pool, **tier)
+    try:
+        got = fresh.generate_ids([prompts[0]], greedy)[0]
+        record = _last_request(fresh)
+    finally:
+        fresh.shutdown()
+    assert got == on_outs[0]
+    assert record['cached_tokens'] == 32 and record['prefill_tokens'] == 5
+    assert _m.PREFIX_TIER_PROMOTIONS.labels(tier='disk').value > disk_before
+
+
+def test_peer_kv_handoff_token_identity():
+    """The peer tier end to end (docs/routing.md "Peer KV handoff"; the old
+    benchmark's router stage was its only exercise): engine A spills a
+    prompt's blocks to its host tier and serves them over the fabric; a
+    cold engine B that lists A as a peer adopts them like a disk promotion
+    and emits the tokens of the dense reference."""
+    from distllm_tpu.observability import instruments as _m
+
+    cfg, params, a = _tiny_engine(
+        host_kv_tier_bytes=64 << 20,
+        peer_kv_serve_endpoint='tcp://127.0.0.1:0',
+        **TIER_POOL,
     )
-    assert fragment['gen_tier_warm_ttft_speedup'] > 1.0
-    assert 0.0 <= fragment['gen_tier_promotion_overlap'] <= 1.0
+    want = _dense_greedy(cfg, params, PROMPT_A, 4)
+    peer_hits_before = _m.PREFIX_TIER_HITS.labels(tier='peer').value
+    try:
+        assert a.generate_ids([PROMPT_A], GREEDY)[0] == want
+        a.generate_ids([PROMPT_B], GREEDY)  # evicts A's blocks to the tier
+        assert a.tier_summary()['spilled_blocks'] > 0
+        _, _, b = _tiny_engine(
+            host_kv_tier_bytes=64 << 20,
+            peer_kv_endpoints=(a.peer_kv_endpoint,),
+            **TIER_POOL,
+        )
+        try:
+            assert b.generate_ids([PROMPT_A], GREEDY)[0] == want
+            assert b.tier_summary()['peer_fetched_blocks'] >= 1
+            assert _last_request(b)['cached_tokens'] > 0
+        finally:
+            b.shutdown()
+        assert a.tier_summary()['peer_served_blocks'] >= 1
+    finally:
+        a.shutdown()
+    assert _m.PREFIX_TIER_HITS.labels(tier='peer').value > peer_hits_before
 
 
 def test_tier_metrics_exported(tmp_path):

@@ -11,10 +11,8 @@ import time
 import pytest
 
 from distllm_tpu.observability import (
-    Deadline,
     FlightRecorder,
     MetricsRegistry,
-    RunRecord,
     StallWatchdog,
     TraceBuffer,
     dump_debug_bundle,
@@ -591,51 +589,6 @@ def test_stall_watchdog_default_dumps_bundle(tmp_path):
         time.sleep(0.5)
     assert (tmp_path / 'stall' / 'meta.json').exists()
     assert instruments.WATCHDOG_STALLS.value == stalls_before + 1
-
-
-# ------------------------------------------------------------- run record
-def test_run_record_incremental_and_snapshot(tmp_path):
-    record = RunRecord(tmp_path / 'BENCH_partial.jsonl')
-    record.record('embed', {'metric': 'emb/s', 'value': 100.0})
-    # The JSONL line is durable immediately (fsync'd append).
-    lines = (tmp_path / 'BENCH_partial.jsonl').read_text().splitlines()
-    assert len(lines) == 1
-    record.record('gen', {'gen_value': 800.0})
-    assert record.stages() == ['embed', 'gen']
-    composed = record.compose()
-    assert composed == {'metric': 'emb/s', 'value': 100.0, 'gen_value': 800.0}
-    # Snapshot is the composed view, rewritten atomically per record().
-    snapshot = json.loads(record.snapshot_path.read_text())
-    assert snapshot == composed
-    # A fresh reader (crash recovery) replays the same state from disk.
-    replay = RunRecord(tmp_path / 'BENCH_partial.jsonl')
-    assert replay.compose() == composed
-
-
-def test_run_record_skips_torn_final_line(tmp_path):
-    record = RunRecord(tmp_path / 'rec.jsonl')
-    record.record('embed', {'value': 1.0})
-    with open(record.path, 'a') as handle:
-        handle.write('{"stage": "gen", "fragment": {"gen_va')  # torn write
-    assert record.stages() == ['embed']
-    assert record.compose() == {'value': 1.0}
-
-
-# --------------------------------------------------------------- deadline
-def test_deadline_budgets_and_expiry():
-    deadline = Deadline(100.0, reserve_s=10.0)
-    assert not deadline.expired
-    # Nominal budget clamps to remaining (90s window left).
-    assert deadline.budget(3600.0) <= 90.0
-    assert deadline.budget(5.0) == 5.0
-    # Below the floor: skip signal.
-    assert deadline.budget(3600.0, floor_s=1000.0) == 0.0
-    tiny = Deadline(0.05, reserve_s=0.0)
-    time.sleep(0.1)
-    assert tiny.expired
-    assert tiny.budget(10.0) == 0.0
-    with pytest.raises(ValueError):
-        Deadline(0)
 
 
 # ---------------------------------------------------------------- log_event

@@ -558,39 +558,56 @@ def test_loadgen_chaos_smoke(_disarm_injector):
     assert chaos.quarantined == 0 and chaos.failed_requests == 0
 
 
-def test_gen_chaos_stage_cpu_smoke(tmp_path):
-    """Acceptance smoke: the gen_chaos bench stage completes on CPU with
-    nonzero goodput while faults are firing, every armed fault fired, at
-    least one recovery, no quarantines, and chaos/clean token identity
-    (greedy fp32). Run directly: ``JAX_PLATFORMS=cpu
-    DISTLLM_BENCH_SMALL=1 python bench.py --stage gen_chaos``."""
-    import json
-    import os
-    import subprocess
-    import sys
-    from pathlib import Path
+def test_gen_chaos_stage_cpu_smoke(_disarm_injector):
+    """The chaos scenario end to end, three arms on ONE warmed engine
+    (``serving_smoke.build_engine``): the open-loop schedule served clean;
+    the same schedule with a dispatch raise, a window stall and an injected
+    scheduler exhaustion armed on fixed call counts while load keeps
+    arriving; and a denser schedule under admission control with an SLO no
+    request can meet. Every armed site fired, at least one recovery, no
+    quarantine and no failed request (the schedule is survivable by
+    construction), goodput while faults fired, chaos tokens bit-identical
+    to the clean arm (greedy float32: recovery replays, it does not
+    approximate), and the overload arm sheds."""
+    from distllm_tpu.generate.loadgen import build_workload, run_loadgen
+    from serving_smoke import build_engine, workload_config
 
-    repo = Path(__file__).resolve().parent.parent
-    env = dict(os.environ)
-    env.update(
-        JAX_PLATFORMS='cpu',
-        DISTLLM_BENCH_SMALL='1',
-        DISTLLM_BENCH_RECORD_DIR=str(tmp_path),
-        DISTLLM_BENCH_BUNDLE_DIR=str(tmp_path / 'bundles'),
-        DISTLLM_BENCH_WATCHDOG_S='0',
+    fault_schedule = (
+        ('dispatch', dict(times=2, after=4)),
+        ('slow_window', dict(times=2, delay_s=0.02, after=2)),
+        ('sched_exhausted', dict(times=1, after=10)),
     )
-    env.pop('DISTLLM_FAULTS', None)  # the stage arms its own schedule
-    proc = subprocess.run(
-        [sys.executable, str(repo / 'bench.py'), '--stage', 'gen_chaos'],
-        capture_output=True, text=True, timeout=420, cwd=repo, env=env,
+    engine = build_engine(
+        ttft_slo_s=30.0, request_deadline_s=60.0, max_dispatch_retries=3,
+        retry_backoff_s=0.01,
     )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    fragment = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert 'gen_chaos_error' not in fragment, fragment.get('gen_chaos_error')
-    assert fragment['gen_chaos_tokens_identical'] is True
-    assert fragment['gen_chaos_goodput_tokens'] > 0
-    assert fragment['gen_chaos_faults_injected'] >= 3
-    assert fragment['gen_chaos_recoveries'] >= 1
-    assert fragment['gen_chaos_quarantined'] == 0
-    assert fragment['gen_chaos_shed_requests'] > 0  # overload arm shed
-    assert 0 < fragment['gen_chaos_shed_rate'] <= 1
+    try:
+        workload = build_workload(workload_config())
+        clean = run_loadgen(engine, workload)
+        # The chaos arm starts where the clean arm did: on an empty cache.
+        engine._evict_cached_blocks(engine.config.num_blocks)
+        for site, kwargs in fault_schedule:
+            _disarm_injector.arm(site, **kwargs)
+        chaos = run_loadgen(engine, workload)
+        fired = {
+            site: _disarm_injector.fired(site) for site, _ in fault_schedule
+        }
+        _disarm_injector.disarm()
+
+        engine.config.ttft_slo_s = 1e-9  # no prediction can meet it
+        engine.admission_control = True
+        overload = run_loadgen(
+            engine,
+            build_workload(
+                workload_config(seed=1, num_requests=32, rate_rps=400.0)
+            ),
+        )
+    finally:
+        engine.shutdown()
+    assert all(count >= 1 for count in fired.values()), fired
+    assert chaos.tokens_by_request == clean.tokens_by_request
+    assert chaos.recoveries >= 1 and chaos.window_retries >= 1
+    assert chaos.quarantined == 0 and chaos.failed_requests == 0
+    assert chaos.goodput_tokens > 0
+    assert overload.shed_requests > 0 and 0 < overload.shed_rate <= 1
+    assert len(overload.tokens_by_request) == 32  # a shed keeps its slot
