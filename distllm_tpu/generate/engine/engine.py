@@ -3147,8 +3147,10 @@ class LLMEngine:
         self, requests: list[Request], defer_to=None
     ) -> list[tuple[int, int]]:
         """Prefill requests through the paged-context path: cache hits
-        prefill only their uncached tail, and tails longer than
-        ``prefill_chunk_tokens`` split into sequential bucketed chunks."""
+        prefill only their uncached tail. Tails of at most
+        ``prefill_chunk_tokens`` batch by bucket up to the bucket's cap;
+        the longer ones of the round go, together, through
+        ``_run_prefill_chunked``."""
         if not requests:
             return []
         self._resolve_cow(
@@ -3180,41 +3182,95 @@ class LLMEngine:
                 emitted.extend(
                     self._dispatch_prefill_paged(spans, bucket, defer_to)
                 )
-        for request in chunked:
-            emitted.extend(self._run_prefill_chunked(request, defer_to))
+        emitted.extend(self._run_prefill_chunked(chunked, defer_to))
         return emitted
 
     def _run_prefill_chunked(
-        self, request: Request, defer_to=None
+        self, requests: list[Request], defer_to=None
     ) -> list[tuple[int, int]]:
-        """Prefill one long uncached tail as sequential bucketed chunks.
+        """Prefill the long uncached tails of one admission round as
+        bucketed chunks, in lockstep rounds: round *r* holds chunk *r* of
+        every request that still has one.
 
         Each chunk attends over the KV already in the paged cache (the
-        cached prefix plus earlier chunks), so splitting is exact. Only
-        the final chunk samples; between chunks the pipelined loop may
-        retire an in-flight decode window (``_drain_hook``) so a long
-        prompt cannot stall decode for its whole prefill.
+        cached prefix plus earlier chunks; a hybrid's chunk starts from
+        the state its row's chunk before left in its slot), so splitting
+        is exact, and rows of one dispatch share nothing but the weights.
+        A round's spans group by ``(bucket, final)``: only a final chunk
+        samples. **A group dispatches together only when it is full**:
+        ``_prefill_batch_cap(bucket)`` spans go as one dispatch, which
+        reads the weights once for all of them; what is left after the
+        full groups goes one span a dispatch. So a chunk dispatch has
+        ``cap`` rows or one and no other count: ``(bucket, cap)`` is a
+        program ``warmup()`` lists and whole tails already run, while a
+        partial group's ``(bucket, 2)`` could be a program no warm-up
+        met, compiled inside a request's wait for its first token. The
+        remainder goes before the full groups, so that the round's first
+        dispatch is the one-row program whenever there is a remainder: a
+        lone long prompt in a served stream sends that program right
+        after a decode window, and a program that takes the KV pools is
+        lowered once for each commitment of them (``PERF.md`` section 6,
+        PR 24), so a warm-up call of several long prompts has to meet it
+        in that place too.
+
+        After every dispatch that is not final the pipelined loop may
+        retire an in-flight decode window (``_drain_hook``) so long
+        prompts cannot stall decode for their whole prefill. A failed
+        dispatch marks its own requests for retry
+        (``_dispatch_prefill_paged``); the others of the round that still
+        have a chunk to go are marked here, since their KV is part
+        written and nothing else would come back for them.
         """
         chunk = self.config.prefill_chunk_tokens
-        start = request.num_cached_tokens
-        total = request.num_tokens
         emitted: list[tuple[int, int]] = []
-        while start < total:
-            ntok = min(chunk, total - start)
-            final = start + ntok >= total
-            bucket = pick_bucket(ntok, self.prefill_buckets)
-            self._stats['prefill_chunks'] += 1
-            _metrics.ENGINE_PREFILL_CHUNKS.inc()
-            _metrics.ENGINE_PREFILL_CHUNK_TOKENS.observe(ntok)
-            emitted.extend(
-                self._dispatch_prefill_paged(
-                    [(request, start, ntok)], bucket, defer_to, sample=final,
-                    route='chunk',
-                )
-            )
-            start += ntok
-            if not final and self._drain_hook is not None:
-                self._drain_hook()
+        # Where each request's next chunk starts; gone once its final
+        # chunk has been dispatched.
+        pending = {r.request_id: (r, r.num_cached_tokens) for r in requests}
+        try:
+            while pending:
+                groups: dict[tuple[int, bool], list] = {}
+                for request, start in pending.values():
+                    ntok = min(chunk, request.num_tokens - start)
+                    final = start + ntok >= request.num_tokens
+                    bucket = pick_bucket(ntok, self.prefill_buckets)
+                    groups.setdefault((bucket, final), []).append(
+                        (request, start, ntok)
+                    )
+                batches: list[tuple[int, bool, list]] = []
+                for bucket, final in sorted(groups):
+                    spans = groups[bucket, final]
+                    cap = self._prefill_batch_cap(bucket)
+                    # The remainder goes first: see the docstring.
+                    rest = len(spans) % cap
+                    batches += [(bucket, final, [s]) for s in spans[:rest]]
+                    batches += [
+                        (bucket, final, spans[i : i + cap])
+                        for i in range(rest, len(spans), cap)
+                    ]
+                for bucket, final, batch in batches:
+                    self._stats['prefill_chunks'] += len(batch)
+                    self._stats['prefill_chunk_groups'] += len(batch) > 1
+                    _metrics.ENGINE_PREFILL_CHUNKS.inc(len(batch))
+                    for _, _, ntok in batch:
+                        _metrics.ENGINE_PREFILL_CHUNK_TOKENS.observe(ntok)
+                    emitted.extend(
+                        self._dispatch_prefill_paged(
+                            batch, bucket, defer_to, sample=final,
+                            route='chunk',
+                        )
+                    )
+                    for request, start, ntok in batch:
+                        if final:
+                            del pending[request.request_id]
+                        else:
+                            pending[request.request_id] = (
+                                request, start + ntok,
+                            )
+                    if not final and self._drain_hook is not None:
+                        self._drain_hook()
+        except Exception:
+            self._mark_prefill_retry([r for r, _ in pending.values()])
+            raise
         return emitted
 
     def _dispatch_prefill_paged(
