@@ -17,6 +17,10 @@ printed as one JSON line (``{"phase": ..., "ok": ...}``):
   ``benchmarks/configs/granite-4.0-h-small.json``: the prompts admitted
   whole, the long one prefilled in two spans, the state pool's bytes, the
   KV pool over the one attention layer, the share of routed pairs held;
+- ``windowed`` — a toy ``laguna`` (head dim 128, 6 and 8 queries a KV head,
+  window 64) through ``LLMEngine.generate_ids``: a prompt prefilled past
+  window + chunk, decode with the window group freeing behind itself, one
+  preemption and re-admission, the tokens against the plain reference;
 - ``serve``   — the OpenAI-compatible server from ``chat_server.build_app``
   on a local port, engine made by ``TpuGenerator`` with the settings of
   ``examples/chat/chat_server.rag.yaml`` at Mistral-7B-Instruct-v0.3
@@ -41,6 +45,7 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import contextlib
 import gc
 import json
 import shutil
@@ -553,6 +558,34 @@ HYBRID_PROMPT_TOKENS = 700  # two spans: 512 and 188, state carried between
 HYBRID_OUTPUT_TOKENS = 24
 
 
+@contextlib.contextmanager
+def _jax_cache_floor():
+    """While an engine that owns its weights is built: the engine moves
+    device-resident weights into the decode window's layouts with tiny
+    jitted identities. One loaded back from the persistent cache comes out
+    in the DEFAULT layout (jax 0.9.0; on the chip: the second of two equal
+    leaves, and every leaf of the next process), and the programs then
+    reject the weights. This script caches every compile; inside this
+    block it keeps jax's own floor, under which those identities are never
+    written, and first removes the ones an earlier run left in the cache
+    (``phase_serve``'s generator migrates its weights outside this block):
+    the floor stops a write, not a load."""
+    import jax
+
+    cache_dir = jax.config.jax_compilation_cache_dir
+    for entry in Path(cache_dir).glob('jit__lambda*') if cache_dir else ():
+        if entry.is_dir():
+            shutil.rmtree(entry, ignore_errors=True)
+        else:
+            entry.unlink(missing_ok=True)
+    floor = jax.config.jax_persistent_cache_min_compile_time_secs
+    jax.config.update('jax_persistent_cache_min_compile_time_secs', 1.0)
+    try:
+        yield
+    finally:
+        jax.config.update('jax_persistent_cache_min_compile_time_secs', floor)
+
+
 def phase_hybrid(seed: int) -> dict:
     """One ``granitemoehybrid`` request through ``generate_ids`` at the
     benchmark configuration's widths: what the engine admitted, how it
@@ -577,22 +610,11 @@ def phase_hybrid(seed: int) -> dict:
         eos_id = None
 
     start = time.perf_counter()
-    # The engine moves device-resident weights into the decode window's
-    # layouts with tiny jitted identities. One loaded back from the
-    # persistent cache comes out in the DEFAULT layout (jax 0.9.0; on the
-    # chip: the second of two equal leaves, and every leaf of the next
-    # process), and the window then rejects the weights. This script
-    # caches every compile; while this engine is built it keeps jax's own
-    # floor, under which those identities are never written.
-    floor = jax.config.jax_persistent_cache_min_compile_time_secs
-    jax.config.update('jax_persistent_cache_min_compile_time_secs', 1.0)
-    try:
+    with _jax_cache_floor():
         engine = LLMEngine(
             cfg, params, NoTokenizer(), EngineConfig(**model['engine']),
             own_params=True,
         )
-    finally:
-        jax.config.update('jax_persistent_cache_min_compile_time_secs', floor)
     del params
     build_s = time.perf_counter() - start
     note(f'hybrid: engine built in {build_s:.0f}s')
@@ -649,6 +671,130 @@ def phase_hybrid(seed: int) -> dict:
         'state_pool_bytes': settings['max_num_seqs'] * per_slot,
         'moe_held_pair_share': round(held / pairs, 4),
         'memory': memory,
+    }
+
+
+# ---------------------------------------------------------------- windowed
+WINDOWED_MODEL = {
+    'model_type': 'laguna', 'vocab_size': 512, 'hidden_size': 256,
+    'intermediate_size': 512, 'num_hidden_layers': 5,
+    'num_attention_heads': 12, 'num_key_value_heads': 2, 'head_dim': 128,
+    'max_position_embeddings': 4096, 'attention_bias': False,
+    'rms_norm_eps': 1e-6, 'num_experts': 4, 'num_routed_experts': 8,
+    'first_local_expert': 0, 'num_experts_per_tok': 2,
+    'moe_intermediate_size': 128, 'shared_expert_intermediate_size': 128,
+    'tie_word_embeddings': False, 'gating': True, 'sliding_window': 64,
+    'rope_parameters': {
+        'full_attention': {
+            'rope_theta': 500000, 'rope_type': 'yarn', 'factor': 8,
+            'original_max_position_embeddings': 128, 'beta_slow': 1,
+            'beta_fast': 8, 'attention_factor': 1.2,
+            'partial_rotary_factor': 0.5,
+        },
+        'sliding_attention': {
+            'rope_type': 'default', 'rope_theta': 10000,
+            'partial_rotary_factor': 1,
+        },
+    },
+    'layer_types': ['full_attention'] + ['sliding_attention'] * 3
+    + ['full_attention'],
+    'moe_apply_router_weight_on_input': False,
+    'mlp_layer_types': ['dense'] + ['sparse'] * 4,
+    'moe_routed_scaling_factor': 2.5,
+    'num_attention_heads_per_layer': [12, 16, 16, 16, 12],
+}
+WINDOWED_PROMPT_TOKENS = 300  # past window + chunk (64 + 128)
+WINDOWED_OUTPUT_TOKENS = 40
+# Token gap to the float32 reference, bf16 program: the benchmark cell's
+# limit on the largest gap (benchmarks/reference_laguna.py).
+WINDOWED_GAP_LIMIT = 0.85
+
+
+def phase_windowed(seed: int) -> dict:
+    """A toy model with a windowed cache group through ``generate_ids``:
+    prefill past the window, decode, one preemption, against the reference."""
+    import jax
+    import numpy as np
+
+    from benchmarks import reference_laguna
+    from distllm_tpu.generate.engine.engine import (
+        EngineConfig,
+        LLMEngine,
+        SamplingParams,
+    )
+    from distllm_tpu.models import laguna
+
+    cfg = laguna.LagunaConfig.from_hf_config(WINDOWED_MODEL)
+    params = laguna.init_on_device(jax.random.PRNGKey(seed % 2**31), cfg)
+
+    class NoTokenizer:
+        eos_id = None
+
+    # 42 usable blocks of 16 tokens: two rows of 300 + 40 tokens need 44.
+    # The engine owns the weights, as the benchmark's driver and
+    # TpuGenerator have it: they are moved into the decode window's
+    # layouts, but for the 12- and 16-wide gate kernels, which stay in the
+    # device's default (``engine.auto_layout_formats``: left to the window,
+    # the 12-wide one took a layout the prefill program failed on, "expected
+    # parameter 6 of size 12288 ... got 16384"). The reference gets the
+    # same tree made again from the seed.
+    with _jax_cache_floor():
+        engine = LLMEngine(
+            cfg, params, NoTokenizer(),
+            EngineConfig(block_size=16, num_blocks=43, max_num_seqs=2,
+                         max_model_len=512, prefill_chunk_tokens=128,
+                         enable_prefix_cache=False, attn_backend='auto'),
+            own_params=True,
+        )
+    del params
+    check(engine.telemetry['attn_backend'] == 'pallas',
+          f"attn_backend resolved to {engine.telemetry['attn_backend']!r}")
+    pools = engine.telemetry['kv_pools']
+    check((pools['full']['layers'], pools['window']['layers'],
+           pools['window']['window']) == (2, 3, 64), f'pools {pools}')
+    # As if finished requests had used none of their budgets: the look-ahead
+    # then admits both rows, and the pool runs short under them.
+    engine._ewma['budget_use'] = 0.0
+    rng = np.random.default_rng(seed)
+    prompts = [
+        [int(t) for t in rng.integers(4, cfg.vocab_size, WINDOWED_PROMPT_TOKENS)]
+        for _ in range(2)
+    ]
+    before = engine.flight.total_recorded
+    out = engine.generate_ids(
+        prompts, SamplingParams(temperature=0.0, max_tokens=WINDOWED_OUTPUT_TOKENS)
+    )
+    records = engine.flight.snapshot()[-(engine.flight.total_recorded - before):]
+    check([len(o) for o in out] == [WINDOWED_OUTPUT_TOKENS] * 2,
+          f'generated {[len(o) for o in out]} tokens')
+    preempts = [r for r in records if r['kind'] == 'preempt']
+    check(len(preempts) >= 1, f'{len(preempts)} preemptions, one expected')
+    decodes = [r for r in records if r['kind'] == 'decode']
+    bound = engine._window_decode_bound
+    check(all(r['kv_blocks_window'] <= 2 * bound for r in decodes),
+          f'window group holds over its bound of {bound} a row')
+    check(max(r['kv_blocks_full'] for r in decodes)
+          > 2 * max(r['kv_blocks_window'] for r in decodes),
+          'the window group holds as much as the full group')
+    freed = sum(r['window_blocks_freed'] for r in records if 'window_blocks_freed' in r)
+    check(freed > 0 and engine.window_blocks.num_held == 0,
+          f'{freed} blocks freed, {engine.window_blocks.num_held} still held')
+    engine.shutdown()
+    del engine
+    gc.collect()
+    params = laguna.init_on_device(jax.random.PRNGKey(seed % 2**31), cfg)
+    gaps = []
+    for prompt, tokens in zip(prompts, out):
+        ids = np.asarray([prompt + tokens[:-1]], np.int32)
+        at = len(prompt) - 1 + np.arange(len(tokens))[None]
+        logits = reference_laguna.laguna_logits(
+            params, WINDOWED_MODEL, ids, at
+        )
+        gaps.append(float(reference_laguna.token_gaps(logits, [tokens]).max()))
+    check(max(gaps) <= WINDOWED_GAP_LIMIT, f'token gaps {gaps}')
+    return {
+        'preemptions': len(preempts), 'window_blocks_freed': freed,
+        'token_gap_max_std': round(max(gaps), 4),
     }
 
 
@@ -1222,7 +1368,8 @@ def run(chips: int, seed: int) -> int:
         else:
             phases = (
                 ('kernels', phase_kernels), ('embed', phase_embed),
-                ('hybrid', phase_hybrid), ('serve', phase_serve),
+                ('hybrid', phase_hybrid), ('windowed', phase_windowed),
+                ('serve', phase_serve),
             )
         for name, fn in phases:
             ok = run_phase(name, fn, seed) and ok

@@ -30,6 +30,7 @@ place in HBM (no per-step cache copies).
 from __future__ import annotations
 
 import contextlib
+import importlib
 import itertools
 import math
 import time
@@ -42,6 +43,8 @@ import numpy as np
 from pydantic import field_validator, model_validator
 
 from distllm_tpu.generate.engine.kv_cache import (
+    WindowBlocks,
+    window_bound,
     DiskKVTier,
     HostKVTier,
     PagedKVCache,
@@ -620,6 +623,28 @@ class EngineConfig(BaseConfig):
         return v
 
 
+def auto_layout_formats(params):
+    """What the decode window's AOT compile asks for, leaf by leaf of the
+    weights: ``Layout.AUTO``, but the device's default layout for a leaf
+    whose minor dimension does not fill a lane tile (under 128). Such a
+    leaf has nothing to gain from a layout of the window's own, and the
+    one the window picks for it is not one every other program runs: for a
+    ``bf16[2, 256, 12]`` gate kernel the device's default is ``(2, 0, 1)``
+    in ``T(2,128)`` tiles (12288 bytes) and the window chose ``(0, 2, 1)``
+    in ``T(8,128)`` (16384); the prefill program, jitted over the migrated
+    tree, then failed at its first dispatch (``expected parameter 6 of
+    size 12288 ... got 16384``; on the chip, PR 30). Kept at the default,
+    the leaf is the same buffer to the window and to every other program,
+    and migration leaves it where it is."""
+    from jax.experimental.layout import Format, Layout
+
+    return jax.tree.map(
+        lambda x: Format()
+        if x.shape and x.shape[-1] < 128 else Format(Layout.AUTO),
+        params,
+    )
+
+
 class LLMEngine:
     """Drives a Mistral-family decoder with paged KV + continuous batching.
 
@@ -629,13 +654,17 @@ class LLMEngine:
     dense SwiGLU and MoE families serve through one engine — mirroring
     the reference, whose vLLM backend serves both.
 
-    A model whose config has ``state_spec()`` (``models/granite_hybrid.py``:
-    recurrent layers between attention layers) is a HYBRID: its sequences
-    hold KV pages for the paged layers only and one slot of a ``StatePool``
-    beside them, its programs are its own family's, and every prefill takes
-    the paged route. What needs a snapshot of the recurrent state at a block
-    boundary (prefix cache, KV tiers, mixed and speculative windows) is
-    refused for it at construction.
+    What a sequence holds is the model's to say, in one description
+    (``model_cfg.cache_spec()``, ``models.common.CacheSpec``), and the
+    pools, tables, programs and refusals are built from it (docs/serving.md
+    "Cache groups"). A ``state`` (``models/granite_hybrid.py``: recurrent
+    layers between attention layers) makes a HYBRID: its sequences hold KV
+    pages for the paged layers only and one slot of a ``StatePool`` beside
+    them. A windowed paged group (``models/laguna.py``) gets a pool, a
+    table and an allocator of its own (``kv_cache.WindowBlocks``), and its
+    sequences hold only the blocks a query still sees. What cannot be right
+    yet with either (prefix cache, KV tiers, mixed and speculative windows,
+    an int8 pool, a mesh) is refused at construction, by name.
     """
 
     def __init__(
@@ -697,35 +726,50 @@ class LLMEngine:
             'bf16': 'bfloat16', 'fp32': 'float32', 'int8': 'int8',
         }.get(cfg.kv_cache_dtype, model_cfg.dtype)
 
-        # What a sequence holds, asked of the model family: KV pages for
-        # its paged layers and, for a hybrid, a fixed state tree. The pool
-        # is allocated after the weight migration, like the KV pool.
+        # What a sequence holds, asked of the model's config in one
+        # description (``models.common.CacheSpec``): the paged groups, the
+        # first of them the scheduler's; a fixed state tree, or none; the
+        # module whose programs serve it. Pools, tables, programs and
+        # refusals below come from that, never from the family's name.
+        # The pools are allocated after the weight migration.
+        spec = model_cfg.cache_spec()
+        self.cache_spec = spec
+        self._refuse_unservable(spec, cfg, mesh, kv_pool_dtype)
+        self._programs = importlib.import_module(spec.programs)
         self.state_pool = None
-        if hasattr(model_cfg, 'state_spec'):
-            self._refuse_for_hybrid(cfg, mesh, kv_pool_dtype)
+        if spec.state is not None:
             self.state_pool = StatePool(
-                model_cfg.state_spec(), cfg.max_num_seqs, lazy=True
+                spec.state, cfg.max_num_seqs, lazy=True
             )
 
         # Lazy: the pool is materialized only after the (transient-heavy)
         # weight-layout migration below, so migration headroom isn't
         # squeezed by an idle 1-6 GiB of zeros.
-        self.kv = PagedKVCache(
-            num_layers=getattr(
-                model_cfg, 'num_paged_layers', model_cfg.num_layers
-            ),
-            num_blocks=cfg.num_blocks,
-            block_size=cfg.block_size,
-            num_kv_heads=model_cfg.num_kv_heads,
-            head_dim=model_cfg.head_size,
-            dtype=kv_pool_dtype,
-            sharding=kv_sharding,
-            lazy=True,
-        )
+        def pool(group, num_blocks):
+            return PagedKVCache(
+                num_layers=group.num_layers,
+                num_blocks=num_blocks,
+                block_size=cfg.block_size,
+                num_kv_heads=model_cfg.num_kv_heads,
+                head_dim=model_cfg.head_size,
+                dtype=kv_pool_dtype,
+                sharding=kv_sharding,
+                lazy=True,
+                layer_buffers=spec.layer_buffers,
+            )
+
+        self.kv = pool(spec.paged[0], cfg.num_blocks)
         self.max_blocks_per_seq = self.kv.blocks_needed(cfg.max_model_len)
         self.prefill_buckets = bucket_ladder(
             cfg.max_model_len, cfg.prefill_min_bucket, scheme='pow2'
         )
+        # A windowed group has a pool and a table of its own; who holds
+        # which of its blocks is ``WindowBlocks``' (kv_cache.py), beside
+        # the scheduler that owns the first group's.
+        self.window_kv = None
+        self.window_blocks = None
+        if spec.windowed:
+            self._build_window_group(spec.windowed[0], pool)
 
         # All admission / preemption / block-budget decisions live in the
         # scheduler (native C++ core, Python twin fallback); the wrapper
@@ -1002,30 +1046,28 @@ class LLMEngine:
         )
         _max_tables = cfg.max_model_len
 
-        def prefill_paged_fn(params, ids, pos, k, v, bt, ctx, tails):
-            return mistral.prefill_paged(
-                params, model, ids, pos, k, v, bt, ctx, tails,
+        # The family's two serving programs (``spec.programs``), under the
+        # names the family's traces and metrics know them by. ``extra`` is
+        # a hybrid's (state pool, slots); with several paged groups ``k``,
+        # ``v`` and ``bt`` are tuples, one entry a group.
+        programs, prefix = self._programs, spec.program_prefix
+        donate_state = spec.state is not None
+
+        def prefill_paged_fn(params, ids, pos, k, v, bt, ctx, tails, *extra):
+            return programs.prefill_paged(
+                params, model, ids, pos, k, v, bt, ctx, tails, *extra,
                 max_table_positions=_max_tables, attn_backend=attn_backend,
             )
 
-        self._prefill_paged = jax.jit(prefill_paged_fn, donate_argnums=(3, 4))
-        if self.state_pool is not None:
-            from distllm_tpu.models import granite_hybrid
-
-            def hybrid_prefill_fn(
-                params, ids, pos, k, v, bt, ctx, tails, state, slots
-            ):
-                return granite_hybrid.prefill_paged(
-                    params, model, ids, pos, k, v, bt, ctx, tails, state,
-                    slots, max_table_positions=_max_tables,
-                    attn_backend=attn_backend,
-                )
-
-            self._prefill_paged = jax.jit(
-                hybrid_prefill_fn, donate_argnums=(3, 4, 8)
-            )
-            # Every hybrid prefill takes the paged route: one family of
-            # programs carries the state from span to span.
+        if prefix:
+            prefill_paged_fn.__name__ = f'{prefix}prefill_fn'
+        self._prefill_paged = jax.jit(
+            prefill_paged_fn,
+            donate_argnums=(3, 4, 8) if donate_state else (3, 4),
+        )
+        if not spec.dense_prefill:
+            # Every prefill takes the paged route: one family of programs
+            # carries what a sequence holds from span to span.
             self._prefill = None
         # Batched COW: copy shared blocks' K/V (all layers) into the
         # requests' private copies in one dispatch. tree.map for the
@@ -1044,37 +1086,20 @@ class LLMEngine:
 
         def window_fn(
             params, ids, pos, ctx, k, v, bt, steps_left, temp, top_p, min_p,
-            top_k, seeds,
+            top_k, seeds, *state,
         ):
-            return mistral.decode_loop(
+            return programs.decode_loop(
                 params, model, ids, pos, k, v, bt, ctx, steps_left,
                 temp, top_p, min_p, top_k, seeds, num_steps=num_steps,
                 attn_backend=attn_backend, max_table_positions=max_tables,
                 sampling_top_window=cfg.sampling_top_window,
                 layer_unroll=cfg.decode_layer_unroll,
+                **({'state': state[0]} if state else {}),
             )
 
-        if self.state_pool is not None:
-
-            def hybrid_window_fn(
-                params, ids, pos, ctx, k, v, bt, steps_left, temp, top_p,
-                min_p, top_k, seeds, state,
-            ):
-                return granite_hybrid.decode_loop(
-                    params, model, ids, pos, k, v, bt, ctx, steps_left,
-                    temp, top_p, min_p, top_k, seeds, num_steps=num_steps,
-                    attn_backend=attn_backend,
-                    max_table_positions=max_tables,
-                    sampling_top_window=cfg.sampling_top_window,
-                    layer_unroll=cfg.decode_layer_unroll, state=state,
-                )
-
-            window_fn = hybrid_window_fn
-
+        window_fn.__name__ = f'{prefix}window_fn'
         # The pools a window updates in place: K, V and a hybrid's state.
-        self._window_donate = (
-            (4, 5) if self.state_pool is None else (4, 5, 13)
-        )
+        self._window_donate = (4, 5, 13) if donate_state else (4, 5)
         self._decode_window = jax.jit(
             window_fn, donate_argnums=self._window_donate
         )
@@ -1208,6 +1233,16 @@ class LLMEngine:
             scope=self._compile_scope,
         ):
             self.kv.allocate()
+            if self.window_kv is not None:
+                self.window_kv.allocate()
+        if len(spec.paged) > 1:
+            self.telemetry['kv_pools'] = {
+                group.name: {
+                    'layers': group.num_layers, 'window': group.window,
+                    'blocks': kv.num_blocks, 'bytes': kv.hbm_bytes,
+                }
+                for group, kv in zip(spec.paged, (self.kv, self.window_kv))
+            }
         if self.state_pool is not None:
             with self._compile_watcher.phase(
                 'state_allocate', f'slots{cfg.max_num_seqs}', compiles=False,
@@ -1287,55 +1322,152 @@ class LLMEngine:
             self.telemetry['roofline_fallback'] = repr(exc)[:300]
 
     @staticmethod
-    def _refuse_for_hybrid(cfg: EngineConfig, mesh, kv_pool_dtype) -> None:
-        """A hybrid model's sequence is its KV pages AND the recurrent
-        state at its last token. Whatever reuses, moves or rewinds KV
-        blocks without that state would serve wrong tokens, so each such
-        pairing is refused here, by name, until state snapshots exist."""
+    def _refuse_unservable(
+        spec, cfg: EngineConfig, mesh, kv_pool_dtype
+    ) -> None:
+        """Settings that cannot be right yet with what the model's
+        ``cache_spec()`` declares, each refused by name with its reason.
+
+        A model with recurrent state: a sequence is its KV pages AND the
+        state at its last token, so whatever reuses, moves or rewinds KV
+        blocks without that state would serve wrong tokens. A model with a
+        windowed group: a sequence holds only the blocks its next query
+        sees, so whatever keeps, shares or moves a sequence's blocks by
+        their index in the sequence would find the trash block there."""
+        int8 = jnp.dtype(kv_pool_dtype) == jnp.dtype(jnp.int8)
+        if spec.state is not None:
+            refused = {
+                'enable_prefix_cache': cfg.enable_prefix_cache
+                and 'a cached block is only a prefix with the recurrent state '
+                'at its boundary',
+                'host_kv_tier_bytes': bool(cfg.host_kv_tier_bytes)
+                and 'a spilled block is only a prefix with the recurrent state '
+                'at its boundary',
+                'enable_mixed_batching': cfg.enable_mixed_batching
+                and 'chunk rows inside a decode window would have to carry '
+                'recurrent state between windows',
+                'draft_k': bool(cfg.draft_k)
+                and 'a rejected draft would have to rewind the recurrent state',
+                'kv_cache_dtype=int8': int8
+                and 'the hybrid attention path has no quantized-page route',
+                'quantization': bool(cfg.quantization)
+                and 'the hybrid parameter tree has no quantized route',
+                'mesh': mesh is not None
+                and 'the recurrent state pool and the grouped expert matmul '
+                'have no partitioning',
+            }
+            for setting, why in refused.items():
+                if why:
+                    raise ValueError(
+                        f'{setting} cannot serve a hybrid model (recurrent '
+                        f'layers beside attention layers): {why}; state '
+                        'snapshots are not implemented'
+                    )
+        if not spec.windowed:
+            return
+        if spec.paged[0].window is not None or len(spec.paged) > 2:
+            raise ValueError(
+                'cache groups '
+                f'{[(g.name, g.window) for g in spec.paged]} cannot be '
+                'served: the first group must hold whole contexts (its '
+                'blocks are the scheduler\'s) and one windowed group may '
+                'follow it; windows of several sizes have no allocator yet'
+            )
         refused = {
-            'enable_prefix_cache': cfg.enable_prefix_cache
-            and 'a cached block is only a prefix with the recurrent state '
-            'at its boundary',
             'host_kv_tier_bytes': bool(cfg.host_kv_tier_bytes)
-            and 'a spilled block is only a prefix with the recurrent state '
-            'at its boundary',
+            and 'a spilled or promoted block names one pool; the host, disk '
+            'and peer tiers know no second',
+            'enable_prefix_cache': cfg.enable_prefix_cache
+            and 'a cached prefix has its windowed blocks freed behind it',
             'enable_mixed_batching': cfg.enable_mixed_batching
-            and 'chunk rows inside a decode window would have to carry '
-            'recurrent state between windows',
+            and 'chunk rows inside a decode window would hold window + chunk '
+            'blocks that the window\'s admission gate does not count',
             'draft_k': bool(cfg.draft_k)
-            and 'a rejected draft would have to rewind the recurrent state',
-            'kv_cache_dtype=int8': jnp.dtype(kv_pool_dtype) == jnp.dtype(jnp.int8)
-            and 'the hybrid attention path has no quantized-page route',
+            and 'a rejected draft rewinds positions past blocks already '
+            'freed behind the window',
+            'kv_cache_dtype=int8': int8
+            and 'a freed block\'s scale row would outlive its holder',
             'quantization': bool(cfg.quantization)
-            and 'the hybrid parameter tree has no quantized route',
+            and 'the family\'s parameter trees have no quantized route',
             'mesh': mesh is not None
-            and 'the recurrent state pool and the grouped expert matmul '
-            'have no partitioning',
+            and 'the second pool and the grouped expert matmul have no '
+            'partitioning',
         }
         for setting, why in refused.items():
             if why:
                 raise ValueError(
-                    f'{setting} cannot serve a hybrid model (recurrent '
-                    f'layers beside attention layers): {why}; state '
-                    'snapshots are not implemented'
+                    f'{setting} cannot serve a model with a windowed cache '
+                    f'group: {why}'
                 )
+
+    def _build_window_group(self, group, pool) -> None:
+        """The pool, the allocator and the constants of the windowed
+        group. A windowed sequence's demand is constant: ``decode_bound``
+        blocks once it decodes, and up to ``bound(bucket)`` while one of
+        its prefill spans is dispatched, given back down to the window as
+        soon as that dispatch is issued."""
+        cfg = self.config
+
+        def bound(span):
+            return window_bound(group.window, cfg.block_size, span)
+
+        span = cfg.prefill_chunk_tokens or cfg.max_model_len
+        self._window_decode_bound = bound(cfg.decode_steps)
+        # What the rows of one prefill dispatch hold above that.
+        self._window_prefill_reserve = max(
+            self._prefill_batch_cap(bucket)
+            * max(0, bound(bucket) - self._window_decode_bound)
+            for bucket in self.prefill_buckets
+            if bucket <= pick_bucket(span, self.prefill_buckets)
+        )
+        # The trash block, every slot at its constant, one prefill
+        # dispatch: the pool never makes a request wait, so admission asks
+        # nothing of it, and ``WindowBlocks.cover`` running short is a bug.
+        num_blocks = (
+            1 + cfg.max_num_seqs * self._window_decode_bound
+            + self._window_prefill_reserve
+        )
+        self.window_kv = pool(group, num_blocks)
+        self.window_blocks = WindowBlocks(
+            num_blocks, cfg.block_size, group.window
+        )
+
+    def _pools(self):
+        """The ``k`` and ``v`` operands of a serving program: the pool's
+        arrays, or one entry a paged group."""
+        if self.window_kv is None:
+            return self.kv.k, self.kv.v
+        return (self.kv.k, self.window_kv.k), (self.kv.v, self.window_kv.v)
+
+    def _fold_pools(self, k, v) -> None:
+        if self.window_kv is None:
+            self.kv.k, self.kv.v = k, v
+        else:
+            (self.kv.k, self.window_kv.k), (self.kv.v, self.window_kv.v) = k, v
+
+    def _group_tables(self, tables, window_tables=None):
+        """The ``bt`` operand: the first group's tables, with the windowed
+        group's beside them where there is one (``tables`` again where the
+        caller's are all trash)."""
+        if self.window_kv is None:
+            return tables
+        return (tables, tables if window_tables is None else window_tables)
 
     def _call_prefill_paged(self, ids, pos, bt, ctx, tails, slots=None):
         """Dispatch the paged prefill program over device arrays and fold
         its pools back; returns the last logits. ``slots`` is each row's
         slot of a hybrid's state pool (the pool's size for a pad row)."""
-        if self.state_pool is None:
-            last_logits, self.kv.k, self.kv.v = self._call(
-                self._prefill_paged, self.params, ids, pos, self.kv.k,
-                self.kv.v, bt, ctx, tails,
-            )
-        else:
-            (
-                last_logits, self.kv.k, self.kv.v, self.state_pool.state,
-            ) = self._call(
-                self._prefill_paged, self.params, ids, pos, self.kv.k,
-                self.kv.v, bt, ctx, tails, self.state_pool.state, slots,
-            )
+        k, v = self._pools()
+        extra = (
+            () if self.state_pool is None else (self.state_pool.state, slots)
+        )
+        last_logits, k, v, *state = self._call(
+            self._prefill_paged, self.params, ids, pos, k, v, bt, ctx, tails,
+            *extra,
+        )
+        self._fold_pools(k, v)
+        if state:
+            (self.state_pool.state,) = state
         return last_logits
 
     def _call_decode_window(self, *plan):
@@ -1344,20 +1476,16 @@ class LLMEngine:
         rows) and fold its pools back. Returns ``(tokens, last_ids,
         moe_pairs)``, the last None unless the family counts them."""
         ids, pos, ctx, *rest = plan
-        if self.state_pool is None:
-            tokens, self.kv.k, self.kv.v, last_ids = self._call(
-                self._decode_window, self.params, ids, pos, ctx, self.kv.k,
-                self.kv.v, *rest,
-            )
-            return tokens, last_ids, None
-        (
-            tokens, self.kv.k, self.kv.v, last_ids, self.state_pool.state,
-            pairs,
-        ) = self._call(
-            self._decode_window, self.params, ids, pos, ctx, self.kv.k,
-            self.kv.v, *rest, self.state_pool.state,
+        k, v = self._pools()
+        extra = () if self.state_pool is None else (self.state_pool.state,)
+        tokens, k, v, last_ids, *more = self._call(
+            self._decode_window, self.params, ids, pos, ctx, k, v, *rest,
+            *extra,
         )
-        return tokens, last_ids, pairs
+        self._fold_pools(k, v)
+        if extra:
+            self.state_pool.state = more.pop(0)
+        return tokens, last_ids, more[0] if more else None
 
     def _put(self, x):
         """Host value → device array, replicated over the mesh under TP."""
@@ -1382,7 +1510,7 @@ class LLMEngine:
         Non-destructive: returns ``(compiled_window, chosen_formats)``;
         the caller decides whether to run the destructive migration.
         """
-        from jax.experimental.layout import Format, Layout
+        from jax.experimental.layout import Format
 
         b = self.config.max_num_seqs
         sds = jax.ShapeDtypeStruct
@@ -1392,14 +1520,18 @@ class LLMEngine:
         def spec(tree):
             return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
 
+        pools = jax.tree.map(
+            lambda pool: pool.spec(),
+            self._group_tables(self.kv, self.window_kv),
+        )
         shapes = (
             spec(self.params),
             sds((b,), i32),  # ids
             sds((b,), i32),  # positions
             sds((b,), i32),  # context_lens
-            self.kv.spec(),
-            self.kv.spec(),
-            sds((b, self.max_blocks_per_seq), i32),
+            pools,
+            pools,
+            self._group_tables(sds((b, self.max_blocks_per_seq), i32)),
             sds((b,), i32),  # steps_left
             sds((b,), f32),
             sds((b,), f32),
@@ -1412,7 +1544,7 @@ class LLMEngine:
         jitted = jax.jit(
             window_fn,
             donate_argnums=self._window_donate,
-            in_shardings=(Format(Layout.AUTO),)
+            in_shardings=(auto_layout_formats(shapes[0]),)
             + (Format(),) * (len(shapes) - 1),
         )
         compiled = jitted.lower(*shapes).compile()
@@ -1653,7 +1785,8 @@ class LLMEngine:
                             np.zeros((b,), np.int32),
                         )
                         pg_logits = self._call_prefill_paged(
-                            ids_dev, pos_dev, rows_dev, ctx_dev, tails_dev,
+                            ids_dev, pos_dev, self._group_tables(rows_dev),
+                            ctx_dev, tails_dev,
                             # Pad rows: a hybrid's state writes are dropped.
                             self._put(np.full(
                                 (b,), self.config.max_num_seqs, np.int32
@@ -1735,7 +1868,9 @@ class LLMEngine:
                 self._put(np.zeros((bsz,), np.int32)),
                 self._put(np.zeros((bsz,), np.int32)),
                 self._put(np.ones((bsz,), np.int32)),
-                self._put(np.zeros((bsz, self.max_blocks_per_seq), np.int32)),
+                self._group_tables(self._put(
+                    np.zeros((bsz, self.max_blocks_per_seq), np.int32)
+                )),
                 self._put(np.zeros((bsz,), np.int32)),
                 self._put(np.zeros((bsz,), np.float32)),
                 self._put(np.ones((bsz,), np.float32)),
@@ -1937,9 +2072,11 @@ class LLMEngine:
         """
         if self._cost_model is None:
             return
-        if self.state_pool is not None:
+        if self.state_pool is not None or self.window_kv is not None:
             self.telemetry.setdefault(
                 'xla_cost_skipped', 'hybrid programs are not priced'
+                if self.state_pool is not None
+                else 'programs over several cache groups are not priced'
             )
             return
         cfg = self.config
@@ -3322,6 +3459,10 @@ class LLMEngine:
             self._span_host_arrays(spans, bucket, b)
         )
         host_arrays = [ids, positions, block_rows, context_lens, tail_lens]
+        window_fields: dict = {}
+        if self.window_blocks is not None:
+            window_rows, window_fields = self._window_span_tables(spans, b)
+            host_arrays.insert(3, window_rows)
         if self.state_pool is not None:
             # Each row's slot of the state pool: the scheduler's slot of
             # its sequence; a pad row's lies past the pool.
@@ -3330,9 +3471,17 @@ class LLMEngine:
             slots[: len(requests)] = [slot_of[r.request_id] for r in requests]
             host_arrays.append(slots)
         step.mark('put')
-        devs = self._put_many(*host_arrays)
+        devs = list(self._put_many(*host_arrays))
         step.mark('prefill')
+        if window_fields:
+            devs[2:4] = [(devs[2], devs[3])]  # the two groups' tables
         last_logits = self._call_prefill_paged(*devs)
+        if window_fields:
+            # Issued: what the rows' next queries no longer see goes back.
+            window_fields['window_blocks_freed'] += sum(
+                self.window_blocks.trim_behind(r.request_id, start + ntok)
+                for r, start, ntok in spans
+            )
         step.mark('emit')
         self._note_prefill([(r, ntok) for r, _, ntok in spans], route)
         emitted: list[tuple[int, int]] = []
@@ -3344,13 +3493,48 @@ class LLMEngine:
             )
         step.close()
         self._note_prefill_seconds(requests, step.t1 - step.t0, step.t0)
+        kv_blocks = self._kv_blocks(context_lens)
+        if window_fields:
+            window_fields['kv_blocks_full'] = kv_blocks
         self._record_step(
             'prefill', step, batch=len(requests),
-            tokens=int(tail_lens.sum()), route=route,
-            kv_blocks=self._kv_blocks(context_lens),
-            **self._rids_field(requests),
+            tokens=int(tail_lens.sum()), route=route, kv_blocks=kv_blocks,
+            **window_fields, **self._rids_field(requests),
         )
         return emitted
+
+    def _window_span_tables(self, spans, rows: int):
+        """The windowed group's side of a paged prefill dispatch: each
+        span's sequence made to hold what the span's queries read and
+        write (``WindowBlocks.cover``), the table rows, and the record's
+        fields for the group."""
+        blocks = self.window_blocks
+        tables = np.zeros((rows, self.max_blocks_per_seq), np.int32)
+        freed = live = 0
+        for i, (request, start, ntok) in enumerate(spans):
+            if request is None or ntok <= 0:
+                continue
+            live += 1
+            freed += blocks.cover(request.request_id, start, start + ntok)
+            blocks.table_row(request.request_id, tables[i])
+        return tables, self._window_fields(tables, live, freed)
+
+    @staticmethod
+    def _window_fields(tables: np.ndarray, live: int, freed: int) -> dict:
+        """``kv_blocks_window``: the windowed group's blocks a dispatch's
+        rows hold, a row without a sequence counted for the trash block it
+        reads, as ``kv_blocks`` counts it."""
+        return {
+            'kv_blocks_window': int(np.count_nonzero(tables))
+            + tables.shape[0] - live,
+            'window_blocks_freed': freed,
+        }
+
+    def _release_window_blocks(self, rid: int) -> None:
+        """With the scheduler's finish and preemption: the sequence's
+        blocks of the windowed group go back too."""
+        if self.window_blocks is not None:
+            self.window_blocks.release(rid)
 
     def _resolve_cow(self, requests: list[Request]) -> None:
         """Copy-on-write for aligned full-cover hits: duplicate each
@@ -3827,6 +4011,10 @@ class LLMEngine:
         top_k = np.zeros((b,), np.int32)
         seeds = np.zeros((b,), np.uint32)
         override_mask = np.zeros((b,), bool)
+        window_blocks = self.window_blocks
+        if window_blocks is not None:
+            window_tables = np.zeros_like(block_tables)
+            window_freed = window_live = 0
         plan: list[tuple[int, int, int]] = []
         any_steps = False
         for slot, request in running:
@@ -3837,6 +4025,13 @@ class LLMEngine:
             positions[slot] = total - 1
             context_lens[slot] = total
             block_tables[slot] = self._block_row(rid)
+            if window_blocks is not None and steps:
+                # The window's queries sit at total - 1 onward, one a step.
+                window_live += 1
+                window_freed += window_blocks.cover(
+                    rid, total - 1, total - 1 + steps
+                )
+                window_blocks.table_row(rid, window_tables[slot])
             steps_left[slot] = steps
             temperature[slot] = request.params.temperature
             top_p[slot] = request.params.top_p
@@ -3861,6 +4056,12 @@ class LLMEngine:
             steps_left, temperature, top_p, min_p, top_k, seeds,
         ]
         context_arrays = [context_lens]
+        window_fields: dict = {}
+        if window_blocks is not None:
+            host_arrays.append(window_tables)  # never beside a chunk plan
+            window_fields = self._window_fields(
+                window_tables, window_live, window_freed
+            )
         if chunk_plan:
             chunk_arrays = self._build_chunk_arrays(chunk_plan)
             context_arrays.append(chunk_arrays[3])
@@ -3881,6 +4082,8 @@ class LLMEngine:
             top_k_dev,
             seeds_dev,
         ) = devs[:11]
+        if window_blocks is not None:
+            block_tables_dev = (block_tables_dev, devs[11])
         if carried_ids is not None:
             ids_dev = self._merge_ids(carried_ids, override_dev, ids_dev)
         chunk_tokens = None
@@ -3958,6 +4161,8 @@ class LLMEngine:
             # A hybrid's extra: the window's (routed, held) expert pairs,
             # fetched with its tokens.
             'moe_pairs': moe_pairs,
+            # With a windowed cache group: what its rows held of it.
+            'window_fields': window_fields,
             # The step's span so far (admit/plan/put/dispatch), completed
             # with fetch and emit when _process_window syncs the tokens.
             'step': step,
@@ -4281,6 +4486,7 @@ class LLMEngine:
         tokens it lost (``num_tokens`` less the cached prefix it keeps)."""
         request.state = RequestState.WAITING
         request.preemptions += 1
+        self._release_window_blocks(request.request_id)
         # A promotion in flight for the victim is simply dropped: its
         # scatter is already dispatched (ordering protects later readers)
         # and the blocks it adopted are borrowed — preemption keeps them,
@@ -4369,14 +4575,16 @@ class LLMEngine:
                     'moe_pairs': int(moe_pairs[0]),
                     'moe_pairs_held': int(moe_pairs[1]),
                 }
+            kv_blocks = self._kv_blocks(*window['context_lens'])
+            if window.get('window_fields'):
+                extra.update(window['window_fields'], kv_blocks_full=kv_blocks)
             self._record_step(
                 'mixed' if chunk_entries else 'decode',
                 step,
                 batch=sum(1 for _, _, s in window['plan'] if s > 0),
                 tokens=sum(s for _, _, s in window['plan']),
                 duration_s=window['duration_s'], gauges=gauges,
-                kv_blocks=self._kv_blocks(*window['context_lens']),
-                **extra,
+                kv_blocks=kv_blocks, **extra,
             )
         return emitted
 
@@ -4683,6 +4891,7 @@ class LLMEngine:
             output_tokens=len(request.output_ids),
         )
         self.sched.finish(rid)
+        self._release_window_blocks(rid)
         if self.prefix_cache is not None:
             self.prefix_cache.release(rid)
         self._promoting.pop(rid, None)
@@ -4784,6 +4993,7 @@ class LLMEngine:
         self._observe_lifecycle(request)
         _metrics.ENGINE_REQUESTS_FINISHED.inc()
         self.sched.finish(request.request_id)
+        self._release_window_blocks(request.request_id)
         if self.prefix_cache is not None:
             # Drop this request's references; ref==0 blocks become LRU-
             # evictable but KEEP their KV — that persistence is what makes
@@ -4846,6 +5056,7 @@ class LLMEngine:
             t_first_s=round(request.t_first_token, 6)
             if request.t_first_token else None,
             **self._state_slot_field(request),
+            **self._kv_ends_field(request),
         )
 
     def _state_slot_field(self, request: Request) -> dict:
@@ -4855,6 +5066,23 @@ class LLMEngine:
         if self.state_pool is None:
             return {}
         return {'state_slot': self.sched.slot(request.request_id)}
+
+    def _kv_ends_field(self, request: Request) -> dict:
+        """For a model with a windowed cache group: the ids of two blocks
+        of the full-context group the request held when it finished, its
+        first and the one that holds the last position it wrote (its last
+        token was never fed). Both are freed right after this record; the
+        pool keeps what a freed block held until its next holder writes it,
+        which is how the benchmark's check reads the K/V a finished request
+        left."""
+        if self.window_kv is None:
+            return {}
+        row = self.sched.block_row(request.request_id)
+        written = len(request.prompt_ids) + len(request.output_ids) - 1
+        if not row or written < 1:
+            return {}
+        tail = min((written - 1) // self.config.block_size, len(row) - 1)
+        return {'kv_first_block': row[0], 'kv_tail_block': row[tail]}
 
     # -------------------------------------------------------------- offline
     def generate_ids(

@@ -34,6 +34,11 @@ its pages: the recurrent layers' fixed state, one slot a running sequence
 (the scheduler's slot), per-layer buffers the decode window rewrites in
 place. :class:`PagedKVCache` is then built over the paged layers only.
 
+:class:`WindowBlocks` is the free list and the per-sequence holdings of a
+WINDOWED paged group's pool (a model whose ``cache_spec()`` declares one,
+docs/serving.md "Cache groups"): a sequence holds only the blocks a query of
+its next dispatch still sees, and gives back the rest.
+
 Mixed serving windows (docs/serving.md) write prefill-chunk K/V inside
 decode dispatches; those writes always land in blocks the owning request
 was granted at admission (the full prompt is budgeted up front), so no
@@ -989,10 +994,19 @@ class PagedKVCache:
         dtype: str = 'bfloat16',
         sharding=None,
         lazy: bool = False,
+        layer_buffers: bool = False,
     ) -> None:
         self.shape = (num_layers, num_blocks, block_size, num_kv_heads, head_dim)
+        # One buffer a layer (``models.common.CacheSpec.layer_buffers``):
+        # ``k`` and ``v`` are then tuples of ``shape[1:]`` arrays.
+        self.layer_buffers = layer_buffers
         self.dtype = jnp.dtype(dtype)
         self.quantized = self.dtype == jnp.dtype(jnp.int8)
+        if layer_buffers and (self.quantized or sharding is not None):
+            raise ValueError(
+                'a pool of one buffer a layer has no int8 and no sharded '
+                'form yet'
+            )
         # Symmetric per-block-per-KV-head scales: one fp32 per (layer,
         # block, kv head), for K and V independently (the two pool arrays
         # each carry their own scale plane — the ``[L, blocks, 2, nkv]``
@@ -1008,6 +1022,12 @@ class PagedKVCache:
 
     def _zeros(self):
         from distllm_tpu.ops.paged_attention import QuantizedKV
+
+        if self.layer_buffers:
+            return tuple(
+                jnp.zeros(self.shape[1:], dtype=self.dtype)
+                for _ in range(self.shape[0])
+            )
 
         if self._sharding is None:
             data = jnp.zeros(self.shape, dtype=self.dtype)
@@ -1040,6 +1060,10 @@ class PagedKVCache:
     def spec(self):
         """Shape/dtype pytree for one pool array (AOT compilation input):
         a bare ShapeDtypeStruct, or a QuantizedKV of them when int8."""
+        if self.layer_buffers:
+            return (
+                jax.ShapeDtypeStruct(self.shape[1:], self.dtype),
+            ) * self.shape[0]
         data = jax.ShapeDtypeStruct(self.shape, self.dtype)
         if not self.quantized:
             return data
@@ -1106,3 +1130,116 @@ class StatePool:
     @property
     def hbm_bytes(self) -> int:
         return self.slots * self.bytes_per_slot
+
+
+def window_bound(window: int, block_size: int, span: int) -> int:
+    """Most blocks a windowed sequence holds while ``span`` tokens of it are
+    dispatched: those that ``window - 1 + span`` tokens touch at the worst
+    alignment."""
+    return -(-(window - 1 + max(1, span)) // block_size) + 1
+
+
+class WindowBlocks:
+    """Who holds which block of a WINDOWED paged group's pool (a
+    ``models.common.PagedGroup`` with a ``window``): the free list and, for
+    every sequence, the blocks a query of its next dispatch can still see.
+
+    A query at position ``p`` of a windowed layer sees keys ``p - window < j
+    <= p``. So before a dispatch whose queries of a sequence lie at
+    positions ``[start, stop)``, :meth:`cover` frees every block wholly
+    behind ``start - window + 1`` and allocates up to the block of ``stop -
+    1``; the sequence's table row then carries the trash block (0) for every
+    entry behind the window, which the paged kernel never fetches (it skips
+    the chunks behind a window) and the XLA twin masks. Dispatches run on
+    the device in the order they were issued, so a block freed while
+    dispatch ``n + 1`` is planned is no longer read by anything issued
+    before its next holder writes it.
+
+    What a sequence holds is bounded whatever its length: ``bound(span)``
+    blocks while a span of ``span`` tokens is dispatched. The engine gates
+    admission on that constant (``scheduler.decode_budget_fits``, asked of
+    this pool as of the scheduler's), so :meth:`cover` never runs short and
+    nothing is preempted for this pool; the scheduler's preemption and
+    finish release a sequence here too (:meth:`release`). Pure host state:
+    the arrays are a ``PagedKVCache`` over the group's layers.
+    """
+
+    def __init__(self, num_blocks: int, block_size: int, window: int) -> None:
+        if num_blocks < 2:
+            raise ValueError('need >= 2 blocks (block 0 is reserved)')
+        self.num_blocks = num_blocks
+        self.block_size = block_size
+        self.window = window
+        self._free = list(range(num_blocks - 1, 0, -1))
+        # rid -> {index of the block in the sequence -> block id}
+        self._rows: dict[int, dict[int, int]] = {}
+        self.freed_total = 0
+
+    def bound(self, span: int) -> int:
+        """:func:`window_bound` of this pool's window and block size."""
+        return window_bound(self.window, self.block_size, span)
+
+    def first_visible_block(self, position: int) -> int:
+        """Index of the block that holds the oldest key a query at
+        ``position`` sees."""
+        return max(0, position - self.window + 1) // self.block_size
+
+    def cover(self, rid: int, start: int, stop: int) -> int:
+        """Make ``rid`` hold exactly the blocks that queries at positions
+        ``[start, stop)`` read or write; returns how many it gave back."""
+        row = self._rows.setdefault(rid, {})
+        lo = self.first_visible_block(start)
+        hi = (max(stop, start + 1) - 1) // self.block_size
+        freed = self._drop(row, [i for i in row if i < lo or i > hi])
+        missing = [i for i in range(lo, hi + 1) if i not in row]
+        if len(missing) > len(self._free):
+            raise RuntimeError(
+                f'windowed KV pool exhausted: sequence {rid} needs '
+                f'{len(missing)} more blocks, {len(self._free)} are free '
+                '(admission is gated on every sequence holding its bound: '
+                'this is a bug, not load)'
+            )
+        for i in missing:
+            row[i] = self._free.pop()
+        return freed
+
+    def trim_behind(self, rid: int, position: int) -> int:
+        """Give back what a query at ``position`` (the sequence's next) no
+        longer sees; returns how many blocks that was."""
+        row = self._rows.get(rid)
+        if not row:
+            return 0
+        lo = self.first_visible_block(position)
+        return self._drop(row, [i for i in row if i < lo])
+
+    def _drop(self, row: dict[int, int], indices: list[int]) -> int:
+        for i in sorted(indices, reverse=True):
+            self._free.append(row.pop(i))
+        self.freed_total += len(indices)
+        return len(indices)
+
+    def release(self, rid: int) -> None:
+        """The sequence finished, failed or was preempted: all of its
+        blocks go back (a preempted one prefills again from position 0)."""
+        row = self._rows.pop(rid, None)
+        if row:
+            self._free.extend(row[i] for i in sorted(row, reverse=True))
+
+    def table_row(self, rid: int, out: np.ndarray) -> np.ndarray:
+        """``rid``'s table row written into ``out`` (zeros): the block id
+        at every index it holds, the trash block everywhere else."""
+        for i, block in self._rows.get(rid, {}).items():
+            if i < out.shape[0]:
+                out[i] = block
+        return out
+
+    def held(self, rid: int) -> int:
+        return len(self._rows.get(rid, ()))
+
+    @property
+    def num_free(self) -> int:
+        return len(self._free)
+
+    @property
+    def num_held(self) -> int:
+        return sum(len(row) for row in self._rows.values())

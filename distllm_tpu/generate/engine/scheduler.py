@@ -72,6 +72,15 @@ slot, and "no free slot" is the deferral it already had. Where only one
 layer in ten has pages (4 KiB a token), the pool rarely bounds the batch;
 ``max_num_seqs`` and the state bytes behind each slot do.
 
+A second pool (a model with a WINDOWED cache group, ``kv_cache.WindowBlocks``,
+docs/serving.md "Cache groups"). This scheduler owns the blocks of the
+model's first, full-context group and knows no other. A windowed group's
+blocks are the engine's to cover and free, dispatch by dispatch; what a row
+holds of them is a constant, so the engine sizes that pool for every slot
+at its constant (``LLMEngine._build_window_group``) and admission asks
+nothing of it. ``finish`` and preemption here are followed by a release
+there.
+
 Borrowed prefixes (automatic prefix caching, docs/prefix_caching.md): a
 request's block row may start with blocks OWNED BY THE PREFIX CACHE —
 attached at ``add`` (cache hit) or marked afterwards with ``lend_prefix``

@@ -20,7 +20,13 @@ def decoder_families() -> dict:
     from these rows plus the encoder-only families
     (``embed/encoders/auto.py``) — a new decoder lands in one place.
     """
-    from distllm_tpu.models import gemma, granite_hybrid, mistral, mixtral
+    from distllm_tpu.models import (
+        gemma,
+        granite_hybrid,
+        laguna,
+        mistral,
+        mixtral,
+    )
 
     return {
         'mistral': (mistral.MistralConfig, mistral),
@@ -32,6 +38,7 @@ def decoder_families() -> dict:
         'granitemoehybrid': (
             granite_hybrid.GraniteHybridConfig, granite_hybrid
         ),
+        'laguna': (laguna.LagunaConfig, laguna),
     }
 
 

@@ -10,11 +10,60 @@ live in ``distllm_tpu.ops`` and slot in via the ``attn_impl`` argument.
 
 from __future__ import annotations
 
+from dataclasses import dataclass
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+
+@dataclass(frozen=True)
+class PagedGroup:
+    """Layers of a decoder that share one paged K/V pool and one block
+    table. ``window`` None: a query sees its whole context and a sequence
+    holds blocks for all of it. ``window`` w: a query at position ``p`` sees
+    keys ``p - w < j <= p``, and the sequence holds only the blocks such a
+    query can still see (``generate/engine/kv_cache.WindowBlocks``)."""
+
+    name: str
+    num_layers: int
+    window: int | None = None
+
+
+@dataclass(frozen=True)
+class CacheSpec:
+    """What one sequence of a decoder holds while it is served, as its
+    config declares it (``cache_spec()``); the serving engine builds its
+    pools, block tables, programs and refusals from this and from nothing
+    else about the family.
+
+    ``paged``: the paged groups, the full-context one first (its blocks
+    are the scheduler's). ``state``: a pytree of ``ShapeDtypeStruct``, the
+    fixed recurrent state of one sequence, or None. ``programs``: the
+    module whose ``prefill_paged`` and ``decode_loop`` serve the family.
+    With one group those take bare ``k_cache``, ``v_cache`` and
+    ``block_tables``; with several, a tuple of each, one entry a group.
+    ``program_prefix`` names the family's compiled programs
+    (``jit_<prefix>window_fn``). ``dense_prefill``: whether the module's
+    ``prefill`` (whole prompts, K/V scattered afterwards) may serve short
+    fresh prompts; without it every prefill takes the paged route.
+    ``layer_buffers``: a group's ``k_cache`` and ``v_cache`` are tuples of
+    one ``[num_blocks, block_size, N_kv, Hd]`` buffer a layer, which the
+    programs write whole and in place, and not one ``[L, ...]`` array whose
+    layers they slice out and write back.
+    """
+
+    paged: tuple[PagedGroup, ...]
+    programs: str
+    state: object | None = None
+    program_prefix: str = ''
+    dense_prefill: bool = True
+    layer_buffers: bool = False
+
+    @property
+    def windowed(self) -> tuple[PagedGroup, ...]:
+        return tuple(g for g in self.paged if g.window is not None)
 
 
 def layer_norm(
@@ -211,11 +260,15 @@ def rope_frequencies(
     ``rope_scaling`` follows the HF config field: ``{'rope_type':
     'llama3', 'factor', 'low_freq_factor', 'high_freq_factor',
     'original_max_position_embeddings'}`` (Llama-3 frequency-banded
-    interpolation) or ``{'rope_type': 'linear', 'factor'}``. Unknown
+    interpolation), ``{'rope_type': 'linear', 'factor'}`` or ``{'rope_type':
+    'yarn', 'factor', 'original_max_position_embeddings', 'beta_fast',
+    'beta_slow', 'attention_factor'}`` (``head_dim`` is then the ROTATED
+    width of a partially rotated head). Unknown
     types raise — silently ignoring a checkpoint's scaling would produce
     wrong positions for every token past the original context.
     """
     inv_freq = 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float64) / head_dim))
+    scale = 1.0  # on cos and sin: YaRN's attention factor
     if rope_scaling:
         kind = rope_scaling.get('rope_type', rope_scaling.get('type'))
         if kind in (None, 'default'):
@@ -234,13 +287,45 @@ def rope_frequencies(
             smooth = (orig / wavelen - low) / (high - low)
             smooth = np.clip(smooth, 0.0, 1.0)
             inv_freq = (1.0 - smooth) * inv_freq / factor + smooth * inv_freq
+        elif kind == 'yarn':
+            # HF _compute_yarn_parameters: dims that turn more than
+            # beta_fast times over the original context keep their
+            # frequency, those that turn less than beta_slow times are
+            # interpolated by 1/factor, a linear ramp between; cos and sin
+            # carry the attention factor (0.1 ln(factor) + 1 when absent).
+            factor = float(rope_scaling['factor'])
+            orig = float(rope_scaling['original_max_position_embeddings'])
+            fast = float(rope_scaling.get('beta_fast') or 32)
+            slow = float(rope_scaling.get('beta_slow') or 1)
+            scale = rope_scaling.get('attention_factor')
+            scale = 0.1 * np.log(factor) + 1.0 if scale is None else float(scale)
+
+            def turns_dim(turns):
+                return head_dim * np.log(orig / (turns * 2 * np.pi)) / (
+                    2 * np.log(theta)
+                )
+
+            low, high = turns_dim(fast), turns_dim(slow)
+            if rope_scaling.get('truncate', True):
+                low, high = np.floor(low), np.ceil(high)
+            low, high = max(low, 0.0), min(high, head_dim - 1.0)
+            if low == high:
+                high += 0.001
+            ramp = np.clip(
+                (np.arange(head_dim // 2, dtype=np.float64) - low)
+                / (high - low), 0.0, 1.0,
+            )
+            inv_freq = inv_freq / factor * ramp + inv_freq * (1.0 - ramp)
         else:
             raise NotImplementedError(
-                f'rope_scaling type {kind!r} (supported: linear, llama3)'
+                f'rope_scaling type {kind!r} (supported: linear, llama3, yarn)'
             )
     t = np.arange(max_len, dtype=np.float64)
     freqs = np.outer(t, inv_freq)
-    return np.cos(freqs).astype(np.float32), np.sin(freqs).astype(np.float32)
+    return (
+        (np.cos(freqs) * scale).astype(np.float32),
+        (np.sin(freqs) * scale).astype(np.float32),
+    )
 
 
 def apply_rope(

@@ -132,6 +132,18 @@ class GraniteHybridConfig(BaseConfig):
         )
         return {'ssm': (ssm,) * n, 'conv': (conv,) * n}
 
+    def cache_spec(self) -> common.CacheSpec:
+        """K/V pages for the attention layers, the recurrent state beside
+        them, this module's programs, and no dense prefill: one family of
+        programs carries the state from span to span."""
+        return common.CacheSpec(
+            paged=(common.PagedGroup('kv', self.num_paged_layers),),
+            state=self.state_spec(),
+            programs=__name__,
+            program_prefix='hybrid_',
+            dense_prefill=False,
+        )
+
     @classmethod
     def from_hf_config(cls, hf: dict) -> 'GraniteHybridConfig':
         if hf.get('position_embedding_type', 'nope') != 'nope':

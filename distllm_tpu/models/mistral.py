@@ -71,6 +71,15 @@ class MistralConfig(BaseConfig):
     def head_size(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
 
+    def cache_spec(self) -> common.CacheSpec:
+        """One paged group over every layer, held for the whole context: a
+        ``sliding_window`` here is a mask over a sequence's blocks, not a
+        reason to free them."""
+        return common.CacheSpec(
+            paged=(common.PagedGroup('kv', self.num_layers),),
+            programs=__name__,
+        )
+
     @classmethod
     def from_hf_config(cls, hf: dict) -> 'MistralConfig':
         return cls(
@@ -619,7 +628,12 @@ def _decode_core(
     weights — read off the compiled HLO on older code, 2026-07-31; not
     re-measured, and no cell runs the rolled window: ROADMAP D2).
     Unrolling turns those into static slices that
-    fold into the matmuls. Prefill keeps the rolled scan: compute-bound,
+    fold into the matmuls. The K/V planes are another matter: a layer
+    sliced out of the stacked pool for the kernel call is copied out and
+    back, rolled or unrolled (read off the HLO compiled for a v5e at
+    Laguna-XS.2's pool sizes, PR 30: 0.3 GB a plane), which is why a
+    family may ask for one buffer a layer (``CacheSpec.layer_buffers``,
+    ``models/laguna.py``); this family's pool stays stacked. Prefill keeps the rolled scan: compute-bound,
     and the slice traffic amortizes over the whole token batch.
     """
     from distllm_tpu.ops.paged_attention import (

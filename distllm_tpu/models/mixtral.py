@@ -52,6 +52,13 @@ class MixtralConfig(BaseConfig):
     def head_size(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
 
+    def cache_spec(self) -> common.CacheSpec:
+        """Served by ``models/mistral.py``'s programs, as ``MistralConfig``."""
+        return common.CacheSpec(
+            paged=(common.PagedGroup('kv', self.num_layers),),
+            programs='distllm_tpu.models.mistral',
+        )
+
     @classmethod
     def from_hf_config(cls, hf: dict) -> 'MixtralConfig':
         return cls(

@@ -41,6 +41,7 @@ def routed_experts(  # distlint: traced
     first_expert: int = 0,
     counted: jnp.ndarray | None = None,  # [T] bool: rows that count
     layer=None,
+    routed_scale: float = 1.0,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """``sum_e g_e expert_e(x)`` over the held experts among a token's top-k.
 
@@ -54,6 +55,10 @@ def routed_experts(  # distlint: traced
     grouped matmul is a kernel call that reads its operand whole, and a
     layer sliced out of the stack for it would be copied (216 MB a bank at
     Granite's widths, three banks a layer, every step).
+
+    ``routed_scale`` multiplies the normalised gates (a family's
+    ``routed_scaling_factor``). A shared expert is the caller's: every chip
+    of the expert axis computes it alike, so it is added once, outside.
     """
     dtype = x.dtype
     tokens, k = x.shape[0], experts_per_token
@@ -70,6 +75,8 @@ def routed_experts(  # distlint: traced
         )
         top_logits, top_idx = jax.lax.top_k(logits, k)
         weights = jax.nn.softmax(top_logits, axis=-1)  # [T, k] float32
+        if routed_scale != 1.0:
+            weights = weights * routed_scale
         local = top_idx - first_expert
         is_held = (local >= 0) & (local < held)
         # Pairs sorted by held expert; pairs of absent experts go last,

@@ -763,6 +763,10 @@ def ragged_paged_attention_pallas(
         # scratch + double-buffered KV pages comfortably inside VMEM at
         # 7B dims while still feeding the MXU full tiles.
         span_tile = max(1, 512 // group)
+        # A power of two: with 6 queries a KV head 85 positions would be
+        # 510 rows, which Mosaic refuses (a block's rows must be a
+        # multiple of 8), and the spans' pow2 buckets must divide evenly.
+        span_tile = 1 << (span_tile.bit_length() - 1)
     span_tile = min(span_tile, s)
     num_q_tiles = -(-s // span_tile)
 

@@ -359,3 +359,82 @@ def test_int8_decode_window_compiles_for_tpu(v5e):
         # A whole-tree dequant would materialize the full bf16 stack
         # (float_stack_bytes) as temps; per-layer dequant stays well under.
         assert temp < float_stack_bytes // 2
+
+
+def test_window_and_prefill_agree_on_a_narrow_gate_layout(v5e):
+    """An engine that owns its weights moves them into the layouts the
+    decode window's AOT compile chose, and every other program then takes
+    that tree. For a leaf whose minor dimension does not fill a lane tile
+    the window's own choice is one the prefill program could not run (on
+    the chip, PR 30: a 12-wide gate kernel, ``expected parameter 6 of size
+    12288 ... got 16384``), so ``auto_layout_formats`` keeps such a leaf
+    in the device's default layout: window and prefill then ask for the
+    same buffer. Toy ``laguna`` widths with 12 and 16 query heads."""
+    from jax.experimental.layout import Format, Layout
+
+    from distllm_tpu.generate.engine.engine import auto_layout_formats
+    from distllm_tpu.models import laguna
+    from chip_smoke import WINDOWED_MODEL  # the smoke's toy: 12 and 16 heads
+
+    cfg = laguna.LagunaConfig.from_hf_config(WINDOWED_MODEL)
+    shapes = jax.eval_shape(
+        lambda: laguna.init_on_device(jax.random.PRNGKey(0), cfg)
+    )
+    params = jax.tree.map(lambda a: v5e(a.shape, a.dtype), shapes)
+    bare = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), shapes)
+    b, table, block = 2, 32, 16
+    i32, f32 = jnp.int32, jnp.float32
+
+    def pools(*layers_blocks):
+        return tuple(
+            (v5e((blocks, block, cfg.num_kv_heads, cfg.head_dim),
+                 jnp.bfloat16),) * layers
+            for layers, blocks in layers_blocks
+        )
+
+    k = pools((cfg.count('full'), 43), (cfg.count('window'), 30))
+
+    def window_fn(params, ids, pos, ctx, k, v, bt, steps_left, *sampling):
+        return laguna.decode_loop(
+            params, cfg, ids, pos, k, v, bt, ctx, steps_left, *sampling,
+            num_steps=2, attn_backend='pallas', max_table_positions=512,
+        )
+
+    def window_formats(param_formats):
+        rows = (v5e((b,), i32),) * 3
+        return jax.jit(
+            window_fn, donate_argnums=(4, 5),
+            in_shardings=(param_formats,) + (Format(),) * 12,
+        ).lower(
+            bare, *rows, k, k, (v5e((b, table), i32),) * 2, v5e((b,), i32),
+            v5e((b,), f32), v5e((b,), f32), v5e((b,), f32), v5e((b,), i32),
+            v5e((b,), jnp.uint32),
+        ).compile().input_formats[0][0]
+
+    prefill = jax.jit(
+        lambda params, ids, pos, k, v, bt, ctx, tails: laguna.prefill_paged(
+            params, cfg, ids, pos, k, v, bt, ctx, tails,
+            max_table_positions=512, attn_backend='pallas',
+        ), donate_argnums=(3, 4),
+    ).lower(
+        params, v5e((1, 128), i32), v5e((1, 128), i32), k, k,
+        (v5e((1, table), i32),) * 2, v5e((1,), i32), v5e((1,), i32),
+    ).compile().input_formats[0][0]
+
+    def narrow(tree):
+        return {
+            jax.tree_util.keystr(path): str(fmt.layout)
+            for (path, fmt), shape in zip(
+                jax.tree_util.tree_flatten_with_path(tree)[0],
+                jax.tree.leaves(shapes),
+            ) if shape.shape[-1] < 128
+        }
+
+    kept = narrow(window_formats(auto_layout_formats(bare)))
+    assert "['full']['attn_gate']['kernel']" in kept
+    assert kept == narrow(prefill)
+    # What the rule is there for: left to itself the window takes the
+    # 12-wide gate in a layout that is not the device's default.
+    auto = narrow(window_formats(Format(Layout.AUTO)))
+    gate = "['full']['attn_gate']['kernel']"
+    assert auto[gate] != kept[gate]

@@ -135,9 +135,9 @@ def test_llama3_rope_scaling_matches_hf(np_rng):
 def test_rope_scaling_unknown_type_raises():
     from distllm_tpu.models import common as jcommon
 
-    with pytest.raises(NotImplementedError, match='yarn'):
+    with pytest.raises(NotImplementedError, match='longrope'):
         jcommon.rope_frequencies(
-            64, 32, 1e4, {'rope_type': 'yarn', 'factor': 4.0}
+            64, 32, 1e4, {'rope_type': 'longrope', 'factor': 4.0}
         )
 
 

@@ -522,6 +522,53 @@ def test_hybrid_warmup_compiles_every_shape_and_serves_after():
     assert_teacher_forced(hf, params, [prompt], out)
 
 
+def _assert_programs_lower_as_the_parents(engine, cfg):
+    """The decode window and the paged prefill, lowering for lowering: the
+    engine's programs against functions written as the parent commit wrote
+    them (its own name, its own signature, ``mistral``'s entry points by
+    name), over the engine's own operands."""
+    from distllm_tpu.models import mistral
+
+    econf = engine.config
+
+    def window_fn(
+        params, ids, pos, ctx, k, v, bt, steps_left, temp, top_p, min_p,
+        top_k, seeds,
+    ):
+        return mistral.decode_loop(
+            params, cfg, ids, pos, k, v, bt, ctx, steps_left,
+            temp, top_p, min_p, top_k, seeds, num_steps=econf.decode_steps,
+            attn_backend='xla', max_table_positions=econf.max_model_len,
+            sampling_top_window=econf.sampling_top_window,
+            layer_unroll=econf.decode_layer_unroll,
+        )
+
+    def prefill_paged_fn(params, ids, pos, k, v, bt, ctx, tails):
+        return mistral.prefill_paged(
+            params, cfg, ids, pos, k, v, bt, ctx, tails,
+            max_table_positions=econf.max_model_len, attn_backend='xla',
+        )
+
+    b, width = econf.max_num_seqs, engine.max_blocks_per_seq
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
+    f32 = lambda *shape: jnp.zeros(shape, jnp.float32)  # noqa: E731
+    window_args = (
+        engine.params, i32(b), i32(b), i32(b), engine.kv.k, engine.kv.v,
+        i32(b, width), i32(b), f32(b), f32(b), f32(b), i32(b),
+        jnp.zeros((b,), jnp.uint32),
+    )
+    prefill_args = (
+        engine.params, i32(1, 8), i32(1, 8), engine.kv.k, engine.kv.v,
+        i32(1, width), i32(1), i32(1),
+    )
+    for parent, ours, args, donate in (
+        (window_fn, engine._decode_window, window_args, (4, 5)),
+        (prefill_paged_fn, engine._prefill_paged, prefill_args, (3, 4)),
+    ):
+        want = jax.jit(parent, donate_argnums=donate).lower(*args).as_text()
+        assert ours.lower(*args).as_text() == want, parent.__name__
+
+
 # (g) a model without recurrent layers is served as before.
 def test_mistral_pool_and_programs_are_what_they_were():
     from distllm_tpu.models import mistral
@@ -539,6 +586,17 @@ def test_mistral_pool_and_programs_are_what_they_were():
     assert engine.state_pool is None
     assert engine.kv.shape == (3, 32, 4, 2, 8)  # every layer owns pages
     assert 'state_pool_bytes' not in engine.telemetry
+    # One group, no window: one stacked pool whose blocks are the
+    # scheduler's, one table a row, no second allocator.
+    assert [(g.name, g.num_layers, g.window) for g in engine.cache_spec.paged] == [
+        ('kv', 3, None)
+    ]
+    assert engine.window_kv is None and engine.window_blocks is None
+    assert 'kv_pools' not in engine.telemetry
+    assert engine.kv.k.shape == engine.kv.shape  # stacked, not a buffer a layer
+    assert engine._pools() == (engine.kv.k, engine.kv.v)
+    assert engine._group_tables('tables') == 'tables'
+    _assert_programs_lower_as_the_parents(engine, cfg)
     compiled = []
 
     def on_duration(event, seconds, **kw):
