@@ -219,7 +219,7 @@ def phase_kernels(seed: int) -> dict:
     cases = {}
     for kv_name, block_size in (('bf16', 16), ('int8', 32)):
         max_blocks = KERNEL_CTX // block_size
-        shape = (nb, block_size, nkv, hd)
+        shape = (nb, block_size, nkv * hd)  # head-folded, as the pool stores it
         if kv_name == 'bf16':
             k_cache = jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
             v_cache = jnp.asarray(rng.normal(size=shape), jnp.bfloat16)

@@ -483,6 +483,7 @@ class HostSyncInHotPathRule(Rule):
             # sync added here would fire per admitted prefill.
             '_write_prefill_all_layers',
             '_write_prefill_all_layers_quantized',
+            '_gather_blocks_all_layers',
         ),
         'distllm_tpu/models/mistral.py': (
             'mixed_window',

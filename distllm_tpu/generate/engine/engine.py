@@ -73,7 +73,9 @@ from distllm_tpu.observability.startup import (
 from distllm_tpu.ops.paged_attention import (
     KV_QUANT_MAX,
     QuantizedKV,
+    fold_heads,
     quantize_kv_rows,
+    unfold_heads,
 )
 from distllm_tpu.ops.sampling import fold_row_keys, sample_tokens
 from distllm_tpu.resilience.admission import (
@@ -699,9 +701,11 @@ class LLMEngine:
         self._compile_scope = self._compile_watcher.new_scope()
         record_backend_init(self._compile_watcher)
 
-        # Tensor parallelism: K/V pages shard over the kv-head dim on the
+        # Tensor parallelism: K/V pages shard over the kv heads on the
         # mesh's model axis (same split as the attention heads in
-        # param_specs), so paged gather/scatter stays local per shard;
+        # param_specs; dim 3 of the pool is a token's ``N_kv * Hd`` row,
+        # whole heads in contiguous runs, so each shard holds whole heads),
+        # so paged gather/scatter stays local per shard;
         # host-built step inputs (ids / positions / block tables) are
         # replicated explicitly — committed single-device arrays would
         # conflict with mesh-sharded params inside the jitted step.
@@ -888,7 +892,14 @@ class LLMEngine:
             last_hidden = jnp.take_along_axis(
                 hidden, last_pos[:, None, None], axis=1
             )
-            return mistral.logits(params, model, last_hidden)[:, 0], k, v
+            # K and V leave as the rows the pool stores: folded here, the
+            # scatter program behind this one (``_write_prefill``, lowered
+            # again for each commitment of the pools) has no relayout of
+            # its updates to compile.
+            return (
+                mistral.logits(params, model, last_hidden)[:, 0],
+                fold_heads(k), fold_heads(v),
+            )
 
         self._prefill = jax.jit(prefill_fn)
 
@@ -1029,9 +1040,7 @@ class LLMEngine:
         # their block axis at axis 1, so one lambda moves both planes —
         # spills and promotions transport quantized blocks natively,
         # never through a dequantized copy.
-        self._gather_blocks = jax.jit(
-            lambda k, v, idx: jax.tree.map(lambda c: c[:, idx], (k, v))
-        )
+        self._gather_blocks = jax.jit(_gather_blocks_all_layers)
         self._write_promoted = jax.jit(
             lambda k, v, kp, vp, idx: jax.tree.map(
                 lambda c, p: c.at[:, idx].set(p.astype(c.dtype)),
@@ -1240,6 +1249,8 @@ class LLMEngine:
                 group.name: {
                     'layers': group.num_layers, 'window': group.window,
                     'blocks': kv.num_blocks, 'bytes': kv.hbm_bytes,
+                    # a block as a layer's buffer stores it
+                    'block_shape': list(kv.pool_shape[2:]),
                 }
                 for group, kv in zip(spec.paged, (self.kv, self.window_kv))
             }
@@ -1436,14 +1447,14 @@ class LLMEngine:
         """The ``k`` and ``v`` operands of a serving program: the pool's
         arrays, or one entry a paged group."""
         if self.window_kv is None:
-            return self.kv.k, self.kv.v
-        return (self.kv.k, self.window_kv.k), (self.kv.v, self.window_kv.v)
+            return self.kv.k_pool, self.kv.v_pool
+        return (self.kv.k_pool, self.window_kv.k_pool), (self.kv.v_pool, self.window_kv.v_pool)
 
     def _fold_pools(self, k, v) -> None:
         if self.window_kv is None:
-            self.kv.k, self.kv.v = k, v
+            self.kv.k_pool, self.kv.v_pool = k, v
         else:
-            (self.kv.k, self.window_kv.k), (self.kv.v, self.window_kv.v) = k, v
+            (self.kv.k_pool, self.window_kv.k_pool), (self.kv.v_pool, self.window_kv.v_pool) = k, v
 
     def _group_tables(self, tables, window_tables=None):
         """The ``bt`` operand: the first group's tables, with the windowed
@@ -1749,10 +1760,10 @@ class LLMEngine:
                             self._put(mask),
                             self._put(last_pos),
                         )
-                        self.kv.k, self.kv.v = self._call(
+                        self.kv.k_pool, self.kv.v_pool = self._call(
                             self._write_prefill,
-                            self.kv.k,
-                            self.kv.v,
+                            self.kv.k_pool,
+                            self.kv.v_pool,
                             k_all,
                             v_all,
                             self._put(block_rows),
@@ -1808,8 +1819,8 @@ class LLMEngine:
                 src_dev, dst_dev = self._put_many(
                     np.zeros((1,), np.int32), np.zeros((1,), np.int32)
                 )
-                self.kv.k, self.kv.v = self._cow_copy(
-                    self.kv.k, self.kv.v, src_dev, dst_dev
+                self.kv.k_pool, self.kv.v_pool = self._cow_copy(
+                    self.kv.k_pool, self.kv.v_pool, src_dev, dst_dev
                 )
         if self.kv_tier is not None:
             # Warm the tier's gather (spill fetch) / scatter (promotion
@@ -1817,7 +1828,8 @@ class LLMEngine:
             # trash block 0, so writes and reads touch no real state;
             # without this the first pool-pressure spill would pay the
             # compile inside the serving loop it interrupts.
-            num_layers, _, bs_, n_kv, head_dim = self.kv.shape
+            num_layers, _, bs_, folded = self.kv.pool_shape
+            n_kv = self.kv.shape[3]
             npad = 1
             cap = self._pow2(self.max_blocks_per_seq)
             while npad <= cap:
@@ -1827,8 +1839,7 @@ class LLMEngine:
                 ):
                     idx = np.zeros((npad,), np.int32)
                     zeros = np.zeros(
-                        (num_layers, npad, bs_, n_kv, head_dim),
-                        dtype=self.kv.dtype,
+                        (num_layers, npad, bs_, folded), dtype=self.kv.dtype
                     )
                     if self.kv.quantized:
                         # Promotion operands for an int8 pool are
@@ -1847,13 +1858,13 @@ class LLMEngine:
                         k_dev, v_dev, idx_dev = self._put_many(
                             zeros, zeros, idx
                         )
-                    self.kv.k, self.kv.v = self._write_promoted(
-                        self.kv.k, self.kv.v, k_dev, v_dev, idx_dev
+                    self.kv.k_pool, self.kv.v_pool = self._write_promoted(
+                        self.kv.k_pool, self.kv.v_pool, k_dev, v_dev, idx_dev
                     )
                     gk, gv = self._gather_blocks(
-                        self.kv.k, self.kv.v, self._put(idx)
+                        self.kv.k_pool, self.kv.v_pool, self._put(idx)
                     )
-                    np.asarray(self._probe(self.kv.k))
+                    np.asarray(self._probe(self.kv.k_pool))
                     np.asarray(self._probe(gk))
                     np.asarray(self._probe(gv))
                 npad *= 2
@@ -1912,14 +1923,14 @@ class LLMEngine:
                     'mixed_window', f'b{bsz}x{bucket}c{cb}{qtag}',
                     scope=self._compile_scope,
                 ):
-                    mixed_tokens, self.kv.k, self.kv.v, _, _ = (
+                    mixed_tokens, self.kv.k_pool, self.kv.v_pool, _, _ = (
                         self._mixed_window(
                             self.params,
                             self._put(np.zeros((bsz,), np.int32)),
                             self._put(np.zeros((bsz,), np.int32)),
                             self._put(np.ones((bsz,), np.int32)),
-                            self.kv.k,
-                            self.kv.v,
+                            self.kv.k_pool,
+                            self.kv.v_pool,
                             self._put(
                                 np.zeros(
                                     (bsz, self.max_blocks_per_seq), np.int32
@@ -1958,13 +1969,13 @@ class LLMEngine:
             with watch.phase(
                 'spec_window', f'b{bsz}s{span}{qtag}', scope=self._compile_scope
             ):
-                spec_tokens, self.kv.k, self.kv.v, _ = self._spec_window(
+                spec_tokens, self.kv.k_pool, self.kv.v_pool, _ = self._spec_window(
                     self.params,
                     self._put(np.zeros((bsz, span), np.int32)),
                     self._put(np.zeros((bsz, span), np.int32)),
                     self._put(np.ones((bsz,), np.int32)),
-                    self.kv.k,
-                    self.kv.v,
+                    self.kv.k_pool,
+                    self.kv.v_pool,
                     self._put(
                         np.zeros((bsz, self.max_blocks_per_seq), np.int32)
                     ),
@@ -1991,14 +2002,14 @@ class LLMEngine:
                     'spec_mixed_window', f'b{bsz}s{span}x{bucket}c{cb}{qtag}',
                     scope=self._compile_scope,
                 ):
-                    spec_tokens, self.kv.k, self.kv.v, _ = (
+                    spec_tokens, self.kv.k_pool, self.kv.v_pool, _ = (
                         self._spec_mixed_window(
                             self.params,
                             self._put(np.zeros((bsz, span), np.int32)),
                             self._put(np.zeros((bsz, span), np.int32)),
                             self._put(np.ones((bsz,), np.int32)),
-                            self.kv.k,
-                            self.kv.v,
+                            self.kv.k_pool,
+                            self.kv.v_pool,
                             self._put(
                                 np.zeros(
                                     (bsz, self.max_blocks_per_seq), np.int32
@@ -2109,7 +2120,7 @@ class LLMEngine:
         targets.append((
             'decode',
             self._decode_window,
-            (self.params, zi(bsz), zi(bsz), oi(bsz), self.kv.k, self.kv.v,
+            (self.params, zi(bsz), zi(bsz), oi(bsz), self.kv.k_pool, self.kv.v_pool,
              bt, zi(bsz), zf(bsz), of(bsz), zf(bsz), zi(bsz), zu(bsz)),
         ))
         if self._spec_window is not None:
@@ -2118,7 +2129,7 @@ class LLMEngine:
                 'spec',
                 self._spec_window,
                 (self.params, zi(bsz, span), zi(bsz, span), oi(bsz),
-                 self.kv.k, self.kv.v, bt, zi(bsz), zf(bsz), of(bsz),
+                 self.kv.k_pool, self.kv.v_pool, bt, zi(bsz), zf(bsz), of(bsz),
                  zf(bsz), zi(bsz), zu(bsz)),
             ))
         if self._mixed_window is not None and not cfg.draft_k:
@@ -2131,8 +2142,8 @@ class LLMEngine:
                 targets.append((
                     'mixed',
                     self._mixed_window,
-                    (self.params, zi(bsz), zi(bsz), oi(bsz), self.kv.k,
-                     self.kv.v, bt, zi(bsz), zf(bsz), of(bsz), zf(bsz),
+                    (self.params, zi(bsz), zi(bsz), oi(bsz), self.kv.k_pool,
+                     self.kv.v_pool, bt, zi(bsz), zf(bsz), of(bsz), zf(bsz),
                      zi(bsz), zu(bsz),
                      zi(cb, mb), zi(cb, mb), zi(cb, self.max_blocks_per_seq),
                      oi(cb), zi(cb), zf(cb), of(cb), zf(cb), zi(cb),
@@ -2655,7 +2666,7 @@ class LLMEngine:
         for i, (_, bid) in enumerate(entries):
             idx[i] = bid
         k_dev, v_dev = self._gather_blocks(
-            self.kv.k, self.kv.v, self._put(idx)
+            self.kv.k_pool, self.kv.v_pool, self._put(idx)
         )
         quantized = isinstance(k_dev, QuantizedKV)
         t_fetch = time.monotonic()
@@ -2665,6 +2676,10 @@ class LLMEngine:
             k_host = np.asarray(k_dev.data if quantized else k_dev)
             # distlint: disable=host-sync-in-hot-path -- second half of the same designed spill fetch (V plane of the one padded gather above)
             v_host = np.asarray(v_dev.data if quantized else v_dev)
+            # A block leaves the pool in its logical shape (a view of the
+            # fetched blocks): what the tiers and ``.kvblock`` files hold.
+            k_host = unfold_heads(k_host, self.kv.shape[3])
+            v_host = unfold_heads(v_host, self.kv.shape[3])
             if quantized:
                 # distlint: disable=host-sync-in-hot-path -- scale rows of the same designed spill fetch (4 bytes per block per KV head, riding the gather already paid for)
                 ks_host = np.asarray(k_dev.scale)
@@ -2779,17 +2794,22 @@ class LLMEngine:
                 # QuantizedKV leaves — promotion is int8-to-int8
                 # bit-exact, scales intact.
                 k_dev, v_dev, ks_dev, vs_dev, idx_dev = self._put_many(
-                    k_host, v_host, ks_host, vs_host, idx
+                    fold_heads(k_host), fold_heads(v_host), ks_host, vs_host,
+                    idx,
                 )
                 k_dev = QuantizedKV(k_dev, ks_dev)
                 v_dev = QuantizedKV(v_dev, vs_dev)
             else:
-                k_dev, v_dev, idx_dev = self._put_many(k_host, v_host, idx)
-            with self._span('promote'):
-                self.kv.k, self.kv.v = self._write_promoted(
-                    self.kv.k, self.kv.v, k_dev, v_dev, idx_dev
+                # Folded on the host (a view): the blocks enter the pool
+                # in the rows it stores.
+                k_dev, v_dev, idx_dev = self._put_many(
+                    fold_heads(k_host), fold_heads(v_host), idx
                 )
-            token = self._probe(self.kv.k)
+            with self._span('promote'):
+                self.kv.k_pool, self.kv.v_pool = self._write_promoted(
+                    self.kv.k_pool, self.kv.v_pool, k_dev, v_dev, idx_dev
+                )
+            token = self._probe(self.kv.k_pool)
         except Exception as exc:
             _metrics.PREFIX_TIER_ERRORS.labels(tier='host').inc()
             self._stats['tier_promotion_failures'] += 1
@@ -3193,10 +3213,10 @@ class LLMEngine:
         last_logits, k_all, v_all = self._call(
             self._prefill, self.params, ids_dev, mask_dev, last_pos_dev
         )
-        self.kv.k, self.kv.v = self._call(
+        self.kv.k_pool, self.kv.v_pool = self._call(
             self._write_prefill,
-            self.kv.k,
-            self.kv.v,
+            self.kv.k_pool,
+            self.kv.v_pool,
             k_all,
             v_all,
             block_rows_dev,
@@ -3555,8 +3575,8 @@ class LLMEngine:
         src_dev, dst_dev = self._put_many(
             np.asarray(srcs, np.int32), np.asarray(dsts, np.int32)
         )
-        self.kv.k, self.kv.v = self._cow_copy(
-            self.kv.k, self.kv.v, src_dev, dst_dev
+        self.kv.k_pool, self.kv.v_pool = self._cow_copy(
+            self.kv.k_pool, self.kv.v_pool, src_dev, dst_dev
         )
 
     def _insert_prompt_blocks(self, request: Request) -> None:
@@ -4092,8 +4112,8 @@ class LLMEngine:
         if chunk_plan:
             (
                 tokens,
-                self.kv.k,
-                self.kv.v,
+                self.kv.k_pool,
+                self.kv.v_pool,
                 last_ids,
                 chunk_tokens,
             ) = self._call(
@@ -4102,8 +4122,8 @@ class LLMEngine:
                 ids_dev,
                 positions_dev,
                 context_lens_dev,
-                self.kv.k,
-                self.kv.v,
+                self.kv.k_pool,
+                self.kv.v_pool,
                 block_tables_dev,
                 steps_left_dev,
                 temperature_dev,
@@ -4283,14 +4303,14 @@ class LLMEngine:
         chunk_tokens = None
         chunk_entries: list[tuple[int, int, int, int, bool]] = []
         if chunk_plan:
-            tokens, self.kv.k, self.kv.v, chunk_tokens = self._call(
+            tokens, self.kv.k_pool, self.kv.v_pool, chunk_tokens = self._call(
                 self._spec_mixed_window,
                 self.params,
                 devs[0],  # span ids
                 devs[1],  # span positions
                 devs[3],  # context_lens
-                self.kv.k,
-                self.kv.v,
+                self.kv.k_pool,
+                self.kv.v_pool,
                 devs[2],  # block tables
                 devs[4],  # span_lens
                 devs[5],
@@ -4317,14 +4337,14 @@ class LLMEngine:
             _metrics.MIXED_PREFILL_TOKENS_PER_WINDOW.observe(ridden)
             _metrics.MIXED_PREFILL_ROWS.observe(len(chunk_plan))
         else:
-            tokens, self.kv.k, self.kv.v, _ = self._call(
+            tokens, self.kv.k_pool, self.kv.v_pool, _ = self._call(
                 self._spec_window,
                 self.params,
                 devs[0],
                 devs[1],
                 devs[3],
-                self.kv.k,
-                self.kv.v,
+                self.kv.k_pool,
+                self.kv.v_pool,
                 devs[2],
                 devs[4],
                 devs[5],
@@ -5172,10 +5192,23 @@ class LLMEngine:
         self.kv = None
 
 
+def _gather_blocks_all_layers(k_cache, v_cache, block_ids):
+    """Blocks ``block_ids`` of every layer of a stacked pool, ``[L, n,
+    ...]`` a leaf. The gather names (layer, block) pairs: a slice over the
+    layer axis (``c[:, block_ids]``) makes the TPU compiler move that axis
+    of the whole head-folded pool inward first, a copy of the pool."""
+    return jax.tree.map(
+        lambda c: c[jnp.arange(c.shape[0])[:, None], block_ids[None, :]],
+        (k_cache, v_cache),
+    )
+
+
 def _write_prefill_all_layers(
     k_cache, v_cache, k_seq, v_seq, block_rows, lengths
 ):
-    """Scatter ``[L, B, S, N_kv, Hd]`` prefill K/V into the paged cache.
+    """Scatter ``[L, B, S, N_kv * Hd]`` prefill K/V (rows already folded,
+    as the engine's dense prefill program returns them) into the paged
+    cache.
 
     ``block_rows`` is ``[B, R]`` and ``lengths`` ``[B]``; positions at or
     beyond a row's length (padding rows have length 0) write to the
@@ -5195,27 +5228,28 @@ def _write_prefill_all_layers(
         0,
     )
     offsets = jnp.where(valid, positions % block_size, 0)
-    flat_blocks = block_ids.reshape(-1)
-    flat_offsets = offsets.reshape(-1)
+    # A row a (layer, block, offset): a window that spans the layer axis
+    # as well (``.at[:, blocks, offsets]``) makes the TPU compiler move
+    # the layer axis of the whole pool inward for the scatter and back, a
+    # copy of the pool each way (0.67 GB of temporaries at mistral7b's
+    # sizes).
+    at = (
+        jnp.arange(num_layers)[:, None],
+        block_ids.reshape(1, -1),
+        offsets.reshape(1, -1),
+    )
     if quantized:
         return _write_prefill_all_layers_quantized(
-            k_cache, v_cache, k_seq, v_seq, block_rows, lengths,
-            valid, flat_blocks, flat_offsets,
+            k_cache, v_cache, k_seq, v_seq, block_rows, lengths, valid, at
         )
-    k_flat = k_seq.reshape(num_layers, batch * seq_len, *k_seq.shape[3:])
-    v_flat = v_seq.reshape(num_layers, batch * seq_len, *v_seq.shape[3:])
-    k_cache = k_cache.at[:, flat_blocks, flat_offsets].set(
-        k_flat.astype(k_cache.dtype)
-    )
-    v_cache = v_cache.at[:, flat_blocks, flat_offsets].set(
-        v_flat.astype(v_cache.dtype)
-    )
+    rows = (num_layers, batch * seq_len, -1)
+    k_cache = k_cache.at[at].set(k_seq.reshape(rows).astype(k_cache.dtype))
+    v_cache = v_cache.at[at].set(v_seq.reshape(rows).astype(v_cache.dtype))
     return k_cache, v_cache
 
 
 def _write_prefill_all_layers_quantized(
-    k_cache, v_cache, k_seq, v_seq, block_rows, lengths,
-    valid, flat_blocks, flat_offsets,
+    k_cache, v_cache, k_seq, v_seq, block_rows, lengths, valid, at
 ):
     """Quantized twin of :func:`_write_prefill_all_layers`.
 
@@ -5235,6 +5269,7 @@ def _write_prefill_all_layers_quantized(
     flat_phys = phys.reshape(-1)
 
     def write_one(cache, seq):
+        seq = unfold_heads(seq, cache.scale.shape[-1])  # a scale a KV head
         amax = jnp.max(jnp.abs(seq.astype(jnp.float32)), axis=-1)
         amax = jnp.where(valid[None, :, :, None], amax, 0.0)
         blk_amax = jnp.pad(
@@ -5247,8 +5282,9 @@ def _write_prefill_all_layers_quantized(
         # Each token row quantizes against ITS block's scale.
         scale_tok = jnp.repeat(new_scale, block_size, axis=2)[:, :, :seq_len]
         q = quantize_kv_rows(seq, scale_tok)
-        q_flat = q.reshape(num_layers, batch * seq_len, *q.shape[3:])
-        data = cache.data.at[:, flat_blocks, flat_offsets].set(q_flat)
+        data = cache.data.at[at].set(
+            q.reshape(num_layers, batch * seq_len, -1)
+        )
         return QuantizedKV(data, scale)
 
     return write_one(k_cache, k_seq), write_one(v_cache, v_seq)

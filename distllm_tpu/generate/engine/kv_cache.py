@@ -1,9 +1,13 @@
 """Paged KV cache: HBM block pool, block allocator, automatic prefix cache.
 
 The TPU replacement for vLLM's paged KV memory management (SURVEY.md
-section 2.4 N1): K/V live as ``[L, num_blocks, block_size, N_kv, Hd]`` device
-arrays; sequences own lists of block ids. Block 0 is the reserved TRASH
-block — padded scatter writes land there (see ``ops/paged_attention``).
+section 2.4 N1): K/V live as ``[L, num_blocks, block_size, N_kv * Hd]``
+device arrays, a token's heads folded into one row as the paged kernel reads
+them (``ops/paged_attention``; a reshape of the two minor dims of a pool is a
+copy of it on the TPU, so no serving program makes one); sequences own lists
+of block ids. Block 0 is the reserved TRASH block — padded scatter writes
+land there. A block OUTSIDE the pool (a tier's entry, a ``.kvblock`` payload,
+what a host reader gets) has the logical ``[.., block_size, N_kv, Hd]``.
 
 The allocator is the C++ free-list/refcount implementation in
 ``distllm_tpu/native/block_allocator.cpp`` (ctypes), with a drop-in Python
@@ -967,8 +971,54 @@ def make_allocator(num_blocks: int, prefer_native: bool = True) -> BlockAllocato
     return PyBlockAllocator(num_blocks)
 
 
+class _PoolView:
+    """``PagedKVCache.k`` / ``.v``: what a HOST reader indexes, a layer and
+    then block ids, ``kv.k[layer][block_ids]``, giving those blocks on the
+    host in the logical shape ``[.., block_size, N_kv, Hd]`` (a
+    ``QuantizedKV`` of such blocks and their ``[.., N_kv]`` scales for an
+    int8 pool). Only the blocks asked for are gathered (one device gather,
+    as indexing a layer's buffer was; they are unfolded on the host), never
+    a buffer: a pool may fill the device. The programs' operands are
+    ``k_pool`` / ``v_pool``."""
+
+    def __init__(self, cache: 'PagedKVCache', pool, layer=None) -> None:
+        self._cache = cache
+        self._pool = pool
+        self._layer = layer
+
+    def __len__(self) -> int:
+        return self._cache.shape[0 if self._layer is None else 1]
+
+    def __getitem__(self, index):
+        from distllm_tpu.ops.paged_attention import QuantizedKV, unfold_heads
+
+        if self._layer is None:
+            layer = range(len(self))[index]
+            return _PoolView(self._cache, self._pool, layer)
+        blocks = self._gather(self._pool, self._layer, np.asarray(index))
+        num_kv_heads = self._cache.shape[3]
+        if self._cache.quantized:
+            return QuantizedKV(
+                unfold_heads(np.asarray(blocks.data), num_kv_heads),
+                np.asarray(blocks.scale),
+            )
+        return unfold_heads(np.asarray(blocks), num_kv_heads)
+
+    def _gather(self, pool, layer: int, block_ids):
+        """The blocks ``block_ids`` of ``layer`` as the pool stores them."""
+        if self._cache.layer_buffers:
+            return pool[layer][block_ids]
+        return jax.tree.map(lambda c: c[layer, block_ids], pool)
+
+
 class PagedKVCache:
     """Device-resident paged K/V arrays (pure container).
+
+    ``k_pool`` and ``v_pool`` are the arrays the serving programs take and
+    give back, stored ``pool_shape = [L, num_blocks, block_size, N_kv *
+    Hd]`` (a tuple of ``pool_shape[1:]`` buffers with ``layer_buffers``);
+    ``shape`` stays the logical 5-tuple, and ``k`` / ``v`` are the host
+    reader's view (:class:`_PoolView`).
 
     Block *accounting* — who owns which block, admission, preemption — is
     the scheduler's job (``engine/scheduler.py`` over the native C++ core);
@@ -976,7 +1026,7 @@ class PagedKVCache:
 
     With ``dtype='int8'`` each pool array is a
     :class:`~distllm_tpu.ops.paged_attention.QuantizedKV` — int8 data of
-    the same paged shape plus a per-block-per-KV-head fp32 scale array
+    the same stored shape plus a per-block-per-KV-head fp32 scale array
     ``[num_layers, num_blocks, num_kv_heads]`` (docs/serving.md
     "Quantized KV cache"). QuantizedKV is a NamedTuple pytree, so every
     jitted engine path that treats the pool as an opaque carry (scan,
@@ -997,8 +1047,12 @@ class PagedKVCache:
         layer_buffers: bool = False,
     ) -> None:
         self.shape = (num_layers, num_blocks, block_size, num_kv_heads, head_dim)
+        self.pool_shape = (
+            num_layers, num_blocks, block_size, num_kv_heads * head_dim
+        )
         # One buffer a layer (``models.common.CacheSpec.layer_buffers``):
-        # ``k`` and ``v`` are then tuples of ``shape[1:]`` arrays.
+        # ``k_pool`` and ``v_pool`` are then tuples of ``pool_shape[1:]``
+        # arrays.
         self.layer_buffers = layer_buffers
         self.dtype = jnp.dtype(dtype)
         self.quantized = self.dtype == jnp.dtype(jnp.int8)
@@ -1015,28 +1069,36 @@ class PagedKVCache:
         self._sharding = sharding
         self.block_size = block_size
         self.num_blocks = num_blocks
-        self.k = None
-        self.v = None
+        self.k_pool = None
+        self.v_pool = None
         if not lazy:
             self.allocate()
+
+    @property
+    def k(self) -> _PoolView:
+        return _PoolView(self, self.k_pool)
+
+    @property
+    def v(self) -> _PoolView:
+        return _PoolView(self, self.v_pool)
 
     def _zeros(self):
         from distllm_tpu.ops.paged_attention import QuantizedKV
 
         if self.layer_buffers:
             return tuple(
-                jnp.zeros(self.shape[1:], dtype=self.dtype)
-                for _ in range(self.shape[0])
+                jnp.zeros(self.pool_shape[1:], dtype=self.dtype)
+                for _ in range(self.pool_shape[0])
             )
 
         if self._sharding is None:
-            data = jnp.zeros(self.shape, dtype=self.dtype)
+            data = jnp.zeros(self.pool_shape, dtype=self.dtype)
         else:
             # Allocate directly into the sharded layout: under tensor
             # parallelism num_blocks is sized against AGGREGATE HBM, so a
             # transient full-size allocation on one device would OOM.
             data = jax.jit(
-                lambda: jnp.zeros(self.shape, dtype=self.dtype),
+                lambda: jnp.zeros(self.pool_shape, dtype=self.dtype),
                 out_shardings=self._sharding,
             )()
         if not self.quantized:
@@ -1049,12 +1111,12 @@ class PagedKVCache:
     def allocate(self) -> None:
         """Materialize the pool arrays (``lazy=True`` defers this so the
         engine can run transient-heavy weight migrations first)."""
-        if self.k is not None:
+        if self.k_pool is not None:
             return
         from distllm_tpu.observability import instruments
 
-        self.k = self._zeros()
-        self.v = self._zeros()
+        self.k_pool = self._zeros()
+        self.v_pool = self._zeros()
         instruments.KV_HBM_BYTES.set(self.hbm_bytes)
 
     def spec(self):
@@ -1062,9 +1124,9 @@ class PagedKVCache:
         a bare ShapeDtypeStruct, or a QuantizedKV of them when int8."""
         if self.layer_buffers:
             return (
-                jax.ShapeDtypeStruct(self.shape[1:], self.dtype),
-            ) * self.shape[0]
-        data = jax.ShapeDtypeStruct(self.shape, self.dtype)
+                jax.ShapeDtypeStruct(self.pool_shape[1:], self.dtype),
+            ) * self.pool_shape[0]
+        data = jax.ShapeDtypeStruct(self.pool_shape, self.dtype)
         if not self.quantized:
             return data
         from distllm_tpu.ops.paged_attention import QuantizedKV
@@ -1079,7 +1141,8 @@ class PagedKVCache:
     @property
     def hbm_bytes(self) -> int:
         return int(sum(
-            leaf.nbytes for leaf in jax.tree.leaves((self.k, self.v))
+            leaf.nbytes
+            for leaf in jax.tree.leaves((self.k_pool, self.v_pool))
         ))
 
 

@@ -49,7 +49,7 @@ class CacheSpec:
     ``prefill`` (whole prompts, K/V scattered afterwards) may serve short
     fresh prompts; without it every prefill takes the paged route.
     ``layer_buffers``: a group's ``k_cache`` and ``v_cache`` are tuples of
-    one ``[num_blocks, block_size, N_kv, Hd]`` buffer a layer, which the
+    one ``[num_blocks, block_size, N_kv * Hd]`` buffer a layer, which the
     programs write whole and in place, and not one ``[L, ...]`` array whose
     layers they slice out and write back.
     """

@@ -650,7 +650,7 @@ def prefill_paged(  # distlint: traced
     cfg: GraniteHybridConfig,
     input_ids: jnp.ndarray,  # [B, S] tokens of the span (padded)
     positions: jnp.ndarray,  # [B, S] absolute positions
-    k_cache: jnp.ndarray,  # [L_attn, num_blocks, block_size, N_kv, Hd]
+    k_cache: jnp.ndarray,  # [L_attn, num_blocks, block_size, N_kv * Hd]
     v_cache: jnp.ndarray,
     block_tables: jnp.ndarray,  # [B, max_blocks]
     context_lens: jnp.ndarray,  # [B] valid tokens incl. this span

@@ -564,7 +564,7 @@ def prefill_paged(  # distlint: traced
     cfg: LagunaConfig,
     input_ids: jnp.ndarray,  # [B, S] tokens of the span (padded)
     positions: jnp.ndarray,  # [B, S] absolute positions
-    k_cache,  # (full, window): per layer [num_blocks_kind, block_size, N_kv, Hd]
+    k_cache,  # (full, window): per layer [num_blocks_kind, block_size, N_kv * Hd]
     v_cache,
     block_tables,  # (full, window): [B, max_blocks] each
     context_lens: jnp.ndarray,  # [B] valid tokens incl. this span

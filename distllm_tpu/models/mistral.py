@@ -361,7 +361,7 @@ def prefill_paged(  # distlint: traced
     cfg: MistralConfig,
     input_ids: jnp.ndarray,  # [B, S] uncached tail tokens (padded)
     positions: jnp.ndarray,  # [B, S] absolute position of each tail token
-    k_cache: jnp.ndarray,  # [L, num_blocks, block_size, N_kv, Hd]
+    k_cache: jnp.ndarray,  # [L, num_blocks, block_size, N_kv * Hd]
     v_cache: jnp.ndarray,
     block_tables: jnp.ndarray,  # [B, max_blocks]
     context_lens: jnp.ndarray,  # [B] total valid tokens incl. this tail
@@ -751,7 +751,7 @@ def decode_step(  # distlint: traced
     cfg: MistralConfig,
     input_ids: jnp.ndarray,  # [B] one new token per sequence
     positions: jnp.ndarray,  # [B] 0-based index of that token
-    k_cache: jnp.ndarray,  # [L, num_blocks, block_size, N_kv, Hd]
+    k_cache: jnp.ndarray,  # [L, num_blocks, block_size, N_kv * Hd]
     v_cache: jnp.ndarray,
     block_tables: jnp.ndarray,  # [B, max_blocks]
     context_lens: jnp.ndarray,  # [B] valid tokens incl. the new one

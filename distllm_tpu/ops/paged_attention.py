@@ -2,11 +2,19 @@
 
 The reference delegates this to vLLM's CUDA paged-attention
 (``generate/generators/vllm_backend.py``; SURVEY.md section 2.4 N1). Here the
-KV cache lives in HBM as fixed-size blocks::
+KV cache lives in HBM as fixed-size blocks, stored HEAD-FOLDED, the layout
+the Pallas kernel reads (each KV head a 128-aligned lane band of a token's
+row)::
 
-    k_cache, v_cache : [num_blocks, block_size, num_kv_heads, head_dim]
+    k_cache, v_cache : [num_blocks, block_size, num_kv_heads * head_dim]
 
-and each sequence owns a row of ``block_tables`` (block ids, padded) plus a
+No function here reshapes a cache: under the TPU's tiled layout a reshape of
+the two minor dims is a copy of the whole buffer, not a bitcast. The writers
+fold the NEW rows (``[.., num_kv_heads, head_dim]`` activations), the XLA
+twins unfold the pages they GATHERED, and ``num_kv_heads`` is the folded
+width over the queries' ``head_dim``.
+
+Each sequence owns a row of ``block_tables`` (block ids, padded) plus a
 ``context_lens`` entry (valid tokens). Every serving dispatch — decode
 windows, mixed prefill+decode, chunked/prefix-cache tail prefill, and
 speculative verification — funnels through the RAGGED per-row-query-span
@@ -91,10 +99,11 @@ class QuantizedKV(NamedTuple):
     """Int8 paged-KV container: block data plus per-block-per-KV-head scales.
 
     ``data`` keeps the paged layout (``[..., num_blocks, block_size,
-    num_kv_heads, head_dim]`` int8) and ``scale`` a parallel fp32 array
-    with the block-size and head-dim axes dropped (``[..., num_blocks,
-    num_kv_heads]``) — symmetric quantization, ``x ≈ data * scale`` with
-    ``scale = absmax / 127`` over the block's live rows per KV head. The
+    num_kv_heads * head_dim]`` int8) and ``scale`` a parallel fp32 array
+    with the block-size axis dropped and one entry a KV head (``[...,
+    num_blocks, num_kv_heads]``) — symmetric quantization, ``x ≈ data *
+    scale`` with ``scale = absmax / 127`` over the block's live rows per
+    KV head. The
     engine's pool carries a leading layer axis on both members; per-layer
     slices inside the model scans drop it.
 
@@ -102,7 +111,7 @@ class QuantizedKV(NamedTuple):
     existing k/v argument slots through ``jax.jit`` (donation applies to
     both leaves), ``lax.scan`` carries, and ``jax.tree.map``-written
     block ops (gather/scatter/copy treat data and scale uniformly
-    because the block axis is axis -4 of ``data`` and axis -2 of
+    because the block axis is axis -3 of ``data`` and axis -2 of
     ``scale`` — axis 1 of each for the engine's pool). Full-precision
     caches stay bare arrays: every op in this module dispatches on
     ``isinstance(cache, QuantizedKV)`` so the unquantized paths emit
@@ -129,6 +138,21 @@ def _kv_data(cache):
     return cache.data if isinstance(cache, QuantizedKV) else cache
 
 
+def fold_heads(rows):  # distlint: traced
+    """``[..., num_kv_heads, head_dim]`` -> ``[..., num_kv_heads *
+    head_dim]``: a token's row as the pool stores it. For rows and blocks
+    (new K/V, gathered pages, a tier's payload), never for a pool."""
+    return rows.reshape(*rows.shape[:-2], rows.shape[-2] * rows.shape[-1])
+
+
+def unfold_heads(rows, num_kv_heads: int):  # distlint: traced
+    """:func:`fold_heads`'s inverse, for rows and blocks taken OUT of a
+    pool."""
+    return rows.reshape(
+        *rows.shape[:-1], num_kv_heads, rows.shape[-1] // num_kv_heads
+    )
+
+
 def quantize_kv_rows(rows, scale):  # distlint: traced
     """Quantize ``rows`` (``[..., num_kv_heads, head_dim]``) against a
     per-KV-head ``scale`` (``[..., num_kv_heads]``). Zero scales (fresh
@@ -143,30 +167,31 @@ def quantize_kv_rows(rows, scale):  # distlint: traced
 
 def _rescale_int8_blocks(data, old_scale, new_scale):  # distlint: traced
     """Re-express int8 block rows quantized at ``old_scale`` in units of
-    ``new_scale`` (``data [..., block_size, num_kv_heads, head_dim]``,
-    scales ``[..., num_kv_heads]``). Appends only ever GROW a block's
-    running absmax (``new_scale >= old_scale``), so the ratio is <= 1 and
+    ``new_scale`` (``data [..., block_size, num_kv_heads * head_dim]``
+    gathered blocks, scales ``[..., num_kv_heads]``). Appends only ever
+    GROW a block's running absmax (``new_scale >= old_scale``), so the ratio is <= 1 and
     the rounded product stays in range; zero ``new_scale`` (fresh or
     trash blocks) zeroes the stale rows."""
     denom = jnp.where(new_scale > 0, new_scale, 1.0)
     ratio = jnp.where(new_scale > 0, old_scale / denom, 0.0)
-    out = jnp.round(data.astype(jnp.float32) * ratio[..., None, :, None])
-    return jnp.clip(out, -KV_QUANT_MAX, KV_QUANT_MAX).astype(jnp.int8)
+    heads = unfold_heads(data, new_scale.shape[-1]).astype(jnp.float32)
+    out = jnp.round(heads * ratio[..., None, :, None])
+    out = jnp.clip(out, -KV_QUANT_MAX, KV_QUANT_MAX).astype(jnp.int8)
+    return fold_heads(out)
 
 
-def _gather_kv_blocks(cache, block_tables):  # distlint: traced
+def _gather_kv_blocks(cache, block_tables, head_dim):  # distlint: traced
     """Gather ``[B, max_blocks, block_size, num_kv_heads, head_dim]``
-    blocks for attention, dequantizing int8 caches in the same fused
-    expression (XLA folds the scale multiply into the gather consumers —
-    no separate dequant pass or fp32 cache copy is ever materialized).
-    Bare-array caches take the exact pre-int8 gather."""
+    blocks for attention (the gathered pages unfolded, never the cache),
+    dequantizing int8 caches in the same fused expression (XLA folds the
+    scale multiply into the gather consumers — no separate dequant pass
+    or fp32 cache copy is ever materialized)."""
+    data = _kv_data(cache)
+    pages = unfold_heads(data[block_tables], data.shape[-1] // head_dim)
     if isinstance(cache, QuantizedKV):
         scales = cache.scale[block_tables]  # [B, max_blocks, num_kv_heads]
-        return (
-            cache.data[block_tables].astype(jnp.float32)
-            * scales[:, :, None, :, None]
-        )
-    return cache[block_tables]
+        return pages.astype(jnp.float32) * scales[:, :, None, :, None]
+    return pages
 
 
 def resolve_attn_backend(
@@ -202,7 +227,7 @@ def resolve_attn_backend(
 
 def paged_attention_xla(  # distlint: traced
     q: jnp.ndarray,  # [B, num_heads, head_dim]
-    k_cache: jnp.ndarray,  # [num_blocks, block_size, num_kv_heads, head_dim]
+    k_cache: jnp.ndarray,  # [num_blocks, block_size, num_kv_heads * head_dim]
     v_cache: jnp.ndarray,
     block_tables: jnp.ndarray,  # [B, max_blocks] int32
     context_lens: jnp.ndarray,  # [B] int32 (valid tokens incl. current)
@@ -220,16 +245,17 @@ def paged_attention_xla(  # distlint: traced
     the scaled scores before masking (both gemma2).
     """
     b, num_heads, head_dim = q.shape
-    _, block_size, num_kv_heads, _ = _kv_data(k_cache).shape
+    _, block_size, folded = _kv_data(k_cache).shape
+    num_kv_heads = folded // head_dim
     max_blocks = block_tables.shape[1]
     group = num_heads // num_kv_heads
 
     # [B, max_blocks, block_size, Nkv, Hd] -> [B, T, Nkv, Hd]
     # (int8 caches dequantize inside the gather expression)
-    k = _gather_kv_blocks(k_cache, block_tables).reshape(
+    k = _gather_kv_blocks(k_cache, block_tables, head_dim).reshape(
         b, max_blocks * block_size, num_kv_heads, head_dim
     )
-    v = _gather_kv_blocks(v_cache, block_tables).reshape(
+    v = _gather_kv_blocks(v_cache, block_tables, head_dim).reshape(
         b, max_blocks * block_size, num_kv_heads, head_dim
     )
 
@@ -260,7 +286,7 @@ def paged_attention_xla(  # distlint: traced
 
 def ragged_paged_attention_xla(  # distlint: traced
     q: jnp.ndarray,  # [B, S, num_heads, head_dim] per-row query spans
-    k_cache: jnp.ndarray,  # [num_blocks, block_size, num_kv_heads, head_dim]
+    k_cache: jnp.ndarray,  # [num_blocks, block_size, num_kv_heads * head_dim]
     v_cache: jnp.ndarray,
     block_tables: jnp.ndarray,  # [B, max_blocks] int32
     context_lens: jnp.ndarray,  # [B] total valid tokens incl. the span
@@ -301,14 +327,15 @@ def ragged_paged_attention_xla(  # distlint: traced
     against.
     """
     b, s, num_heads, head_dim = q.shape
-    _, block_size, num_kv_heads, _ = _kv_data(k_cache).shape
+    _, block_size, folded = _kv_data(k_cache).shape
+    num_kv_heads = folded // head_dim
     max_blocks = block_tables.shape[1]
     group = num_heads // num_kv_heads
 
-    k = _gather_kv_blocks(k_cache, block_tables).reshape(
+    k = _gather_kv_blocks(k_cache, block_tables, head_dim).reshape(
         b, max_blocks * block_size, num_kv_heads, head_dim
     )
-    v = _gather_kv_blocks(v_cache, block_tables).reshape(
+    v = _gather_kv_blocks(v_cache, block_tables, head_dim).reshape(
         b, max_blocks * block_size, num_kv_heads, head_dim
     )
     qg = q.reshape(b, s, num_kv_heads, group, head_dim).astype(jnp.float32)
@@ -345,7 +372,7 @@ def ragged_paged_attention_xla(  # distlint: traced
 
 def paged_prefill_attention_xla(  # distlint: traced
     q: jnp.ndarray,  # [B, S, num_heads, head_dim] tail queries
-    k_cache: jnp.ndarray,  # [num_blocks, block_size, num_kv_heads, head_dim]
+    k_cache: jnp.ndarray,  # [num_blocks, block_size, num_kv_heads * head_dim]
     v_cache: jnp.ndarray,
     block_tables: jnp.ndarray,  # [B, max_blocks] int32
     context_lens: jnp.ndarray,  # [B] total valid tokens incl. the tail
@@ -378,10 +405,11 @@ def _ragged_paged_attn_kernel(
     #   q_start_ref,  # [B] int32 — absolute position of row's first query
     #   q_lens_ref,  # [B] int32 — valid queries per row (0 = fully padded)
     #   window_ref,  # [1] int32 — sliding window; <= 0 disables
-    # array operands. The KV caches arrive HEAD-FOLDED: the caller
-    # bitcast-reshapes [num_blocks, block_size, num_kv_heads, head_dim]
-    # to [num_blocks, block_size, num_kv_heads * head_dim] (row-major —
-    # free), so each KV head occupies a 128-aligned LANE band. This is
+    # array operands. The KV caches arrive HEAD-FOLDED, as the pool stores
+    # them: [num_blocks, block_size, num_kv_heads * head_dim], so each KV
+    # head occupies a 128-aligned LANE band. (Folding a four-dim cache at
+    # the call was no bitcast under the TPU's tiled layout but a copy of
+    # the whole buffer, a call: why the pool is stored so.) This is
     # the layout trick that retires the Mosaic rejections the decode-only
     # kernel died on (both reproduced + pinpointed on this container's
     # toolchain, 2026-08-04): slicing the kv-head dim out of the MIDDLE
@@ -682,7 +710,7 @@ def _ragged_paged_attn_kernel(
 
 def ragged_paged_attention_pallas(
     q: jnp.ndarray,  # [B, S, num_heads, head_dim] per-row query spans
-    k_cache: jnp.ndarray,  # [num_blocks, block_size, num_kv_heads, head_dim]
+    k_cache: jnp.ndarray,  # [num_blocks, block_size, num_kv_heads * head_dim]
     v_cache: jnp.ndarray,
     block_tables: jnp.ndarray,  # [B, max_blocks] int32
     context_lens: jnp.ndarray,  # [B] total valid tokens incl. the span
@@ -729,7 +757,8 @@ def ragged_paged_attention_pallas(
     quantized = isinstance(k_cache, QuantizedKV)
     k_data, v_data = _kv_data(k_cache), _kv_data(v_cache)
     b, s, num_heads, head_dim = q.shape
-    num_blocks, block_size, num_kv_heads, _ = k_data.shape
+    _, block_size, folded = k_data.shape
+    num_kv_heads = folded // head_dim
     max_blocks = block_tables.shape[1]
     group = num_heads // num_kv_heads
     if head_dim % 128 and not interpret:
@@ -792,17 +821,11 @@ def ragged_paged_attention_pallas(
     qg = qg.transpose(0, 2, 1, 3, 4).reshape(
         b, num_kv_heads, s * group, head_dim
     )
-    # Head-folded cache view: [nb, bs, Nkv, Hd] -> [nb, bs, Nkv*Hd] is a
-    # row-major bitcast (no copy), and inside the kernel each head is a
-    # 128-aligned lane band — the layout that keeps whole-page DMA
-    # descriptors contiguous AND per-head slices tile-aligned (see the
-    # kernel docstring for the two Mosaic rejections this designs out).
-    k_folded = k_data.reshape(
-        num_blocks, block_size, num_kv_heads * head_dim
-    )
-    v_folded = v_data.reshape(
-        num_blocks, block_size, num_kv_heads * head_dim
-    )
+    # The caches go to the kernel as they lie, head-folded [nb, bs,
+    # Nkv*Hd]: inside the kernel each head is a 128-aligned lane band —
+    # the layout that keeps whole-page DMA descriptors contiguous AND
+    # per-head slices tile-aligned (see the kernel docstring for the two
+    # Mosaic rejections this designs out).
     extra_operands = []
     if quantized:
         if num_kv_heads > 128:
@@ -838,13 +861,11 @@ def ragged_paged_attention_pallas(
     )
     kv_scratch = [
         pltpu.VMEM(
-            (2, pages_per_chunk * block_size,
-             num_kv_heads * head_dim),
+            (2, pages_per_chunk * block_size, folded),
             k_data.dtype,
         ),
         pltpu.VMEM(
-            (2, pages_per_chunk * block_size,
-             num_kv_heads * head_dim),
+            (2, pages_per_chunk * block_size, folded),
             v_data.dtype,
         ),
     ]
@@ -891,8 +912,8 @@ def ragged_paged_attention_pallas(
         q_lens.astype(jnp.int32),
         window_arr,
         qg,
-        k_folded,
-        v_folded,
+        k_data,
+        v_data,
         *extra_operands,
     )
     return (
@@ -1017,7 +1038,7 @@ def _write_token_kv_quantized(k_cache, v_cache, new_k, new_v, block_ids,
         )
         data = cache.data.at[block_ids].set(blocks)
         data = data.at[block_ids, offsets].set(
-            quantize_kv_rows(new, new_scale)
+            fold_heads(quantize_kv_rows(new, new_scale))
         )
         return QuantizedKV(data, cache.scale.at[block_ids].set(new_scale))
 
@@ -1033,7 +1054,9 @@ def write_token_kv(  # distlint: traced
     positions: jnp.ndarray,  # [B] token index being written
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Scatter one new token's K/V per sequence into its paged block
-    (quantizing at write time for int8 :class:`QuantizedKV` pools)."""
+    (quantizing at write time for int8 :class:`QuantizedKV` pools). The
+    new rows are folded to the pool's ``num_kv_heads * head_dim`` rows;
+    the pool is only scattered into."""
     block_size = _kv_data(k_cache).shape[1]
     batch = positions.shape[0]
     block_ids = block_tables[jnp.arange(batch), positions // block_size]
@@ -1042,8 +1065,12 @@ def write_token_kv(  # distlint: traced
         return _write_token_kv_quantized(
             k_cache, v_cache, new_k, new_v, block_ids, offsets
         )
-    k_cache = k_cache.at[block_ids, offsets].set(new_k.astype(k_cache.dtype))
-    v_cache = v_cache.at[block_ids, offsets].set(new_v.astype(v_cache.dtype))
+    k_cache = k_cache.at[block_ids, offsets].set(
+        fold_heads(new_k).astype(k_cache.dtype)
+    )
+    v_cache = v_cache.at[block_ids, offsets].set(
+        fold_heads(new_v).astype(v_cache.dtype)
+    )
     return k_cache, v_cache
 
 
@@ -1079,8 +1106,8 @@ def write_chunk_kv(  # distlint: traced
         )
     flat_blocks = block_ids.reshape(-1)
     flat_offsets = offsets.reshape(-1)
-    k_flat = new_k.reshape(b * s, *new_k.shape[2:])
-    v_flat = new_v.reshape(b * s, *new_v.shape[2:])
+    k_flat = fold_heads(new_k).reshape(b * s, -1)
+    v_flat = fold_heads(new_v).reshape(b * s, -1)
     k_cache = k_cache.at[flat_blocks, flat_offsets].set(
         k_flat.astype(k_cache.dtype)
     )
@@ -1151,9 +1178,9 @@ def _write_chunk_kv_quantized(k_cache, v_cache, new_k, new_v, block_tables,
         scale_tok = jnp.take_along_axis(
             new_scale, tb[:, :, None], axis=1
         )  # [B, S, nkv]
-        q = quantize_kv_rows(new, scale_tok)
+        q = fold_heads(quantize_kv_rows(new, scale_tok))
         data = data.at[block_ids.reshape(-1), offsets.reshape(-1)].set(
-            q.reshape(b * s, *q.shape[2:])
+            q.reshape(b * s, -1)
         )
         return QuantizedKV(data, scale)
 
@@ -1186,8 +1213,12 @@ def write_prefill_kv(  # distlint: traced
             k_cache, v_cache, k_seq, v_seq, block_table_row, length,
             block_ids, offsets, valid,
         )
-    k_cache = k_cache.at[block_ids, offsets].set(k_seq.astype(k_cache.dtype))
-    v_cache = v_cache.at[block_ids, offsets].set(v_seq.astype(v_cache.dtype))
+    k_cache = k_cache.at[block_ids, offsets].set(
+        fold_heads(k_seq).astype(k_cache.dtype)
+    )
+    v_cache = v_cache.at[block_ids, offsets].set(
+        fold_heads(v_seq).astype(v_cache.dtype)
+    )
     return k_cache, v_cache
 
 
@@ -1219,7 +1250,7 @@ def _write_prefill_kv_quantized(k_cache, v_cache, k_seq, v_seq,
         scale = cache.scale.at[phys].set(new_scale)
         scale_tok = jnp.repeat(new_scale, block_size, axis=0)[:seq_len]
         data = cache.data.at[block_ids, offsets].set(
-            quantize_kv_rows(seq, scale_tok)
+            fold_heads(quantize_kv_rows(seq, scale_tok))
         )
         return QuantizedKV(data, scale)
 

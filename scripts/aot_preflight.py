@@ -56,7 +56,7 @@ mshapes = jax.eval_shape(lambda: mistral.init_on_device(jax.random.PRNGKey(0), m
 bs = 16
 
 def window_args(params_tree, B, nb, R):
-    kshape = (mcfg.num_layers, nb, bs, mcfg.num_kv_heads, mcfg.head_size)
+    kshape = (mcfg.num_layers, nb, bs, mcfg.num_kv_heads * mcfg.head_size)
     return (
         params_tree, sds((B,), jnp.int32), sds((B,), jnp.int32),
         sds((B,), jnp.int32), sds(kshape, jnp.bfloat16),
@@ -151,7 +151,7 @@ def compile_multichip() -> None:
             is_leaf=lambda x: isinstance(x, P),
         )
         B, nb, R = 32, 640, 256
-        ksh = (mcfg.num_layers, nb, bs, mcfg.num_kv_heads, mcfg.head_size)
+        ksh = (mcfg.num_layers, nb, bs, mcfg.num_kv_heads * mcfg.head_size)
         def r(shape, dtype):
             return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=repl)
         compiled = jax.jit(

@@ -56,8 +56,8 @@ def program_logits(params, cfg, prompt, backend, round_state):
     """Logits of the ``OUTPUT_TOKENS`` greedy steps after ``prompt`` and the
     tokens taken, through the paged prefill and the decode step."""
     blocks = (len(prompt) + OUTPUT_TOKENS) // BLOCK + 2
-    shape = (cfg.num_paged_layers, blocks + 1, BLOCK, cfg.num_kv_heads,
-             cfg.head_size)
+    shape = (cfg.num_paged_layers, blocks + 1, BLOCK,
+             cfg.num_kv_heads * cfg.head_size)
     k, v = (jnp.zeros(shape, jnp.dtype(cfg.dtype)) for _ in range(2))
     table = jnp.arange(1, blocks + 1, dtype=jnp.int32)[None]
     state = StatePool(cfg.state_spec(), 1).state

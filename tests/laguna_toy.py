@@ -109,7 +109,7 @@ def paged_logits(cfg, params, tokens, n_prompt, *, chunk=8, backend='xla',
     width = -(-total // BLOCK)
 
     def pool(layers, blocks):
-        shape = (blocks, BLOCK, cfg.num_kv_heads, cfg.head_dim)
+        shape = (blocks, BLOCK, cfg.num_kv_heads * cfg.head_dim)  # head-folded
         if int8:
             return tuple(
                 QuantizedKV(

@@ -100,7 +100,7 @@ def test_ragged_kernel_compiles_at_7b_widths(v5e, kv, block_size, span):
     )
 
     num_blocks, max_blocks = 712, 512 // block_size
-    shape = (num_blocks, block_size, _NKV, _HD)
+    shape = (num_blocks, block_size, _NKV * _HD)  # head-folded, as stored
     if kv == 'int8':
         pool = QuantizedKV(
             v5e(shape, jnp.int8), v5e((num_blocks, _NKV), jnp.float32)
@@ -190,7 +190,7 @@ def test_decode_window_compiles_for_tpu(v5e, backend):
     )
     params = jax.tree.map(lambda x: v5e(x.shape, x.dtype), shapes)
     b, nb, bs, rows = 8, 64, 16, 16
-    kshape = (cfg.num_layers, nb, bs, cfg.num_kv_heads, cfg.head_size)
+    kshape = (cfg.num_layers, nb, bs, cfg.num_kv_heads * cfg.head_size)
     cache_bytes = 2 * int(np.prod(kshape)) * 2  # k + v, bf16
     temps = {}
     for layer_unroll in (False, True):
@@ -287,7 +287,7 @@ def test_ragged_paged_attention_compiles_for_tpu(v5e):
         lambda: mistral.init_on_device(jax.random.PRNGKey(0), cfg)
     )
     params = jax.tree.map(lambda x: v5e(x.shape, x.dtype), shapes)
-    kshape = (cfg.num_layers, nb, bs, cfg.num_kv_heads, cfg.head_size)
+    kshape = (cfg.num_layers, nb, bs, cfg.num_kv_heads * cfg.head_size)
     _compile(
         lambda: jax.jit(
             lambda p, i, po, k, v, bt, c, t: mistral.prefill_paged(
@@ -333,7 +333,7 @@ def test_int8_decode_window_compiles_for_tpu(v5e):
         if isinstance(leaf, QTensor)
     )
     b, nb, bs, rows = 8, 64, 16, 16
-    kshape = (cfg.num_layers, nb, bs, cfg.num_kv_heads, cfg.head_size)
+    kshape = (cfg.num_layers, nb, bs, cfg.num_kv_heads * cfg.head_size)
     compiled = _compile(
         lambda: jax.jit(
             lambda p, i, po, c, k, v, bt, sl, t, tp, mp, tk, sd:
@@ -387,7 +387,7 @@ def test_window_and_prefill_agree_on_a_narrow_gate_layout(v5e):
 
     def pools(*layers_blocks):
         return tuple(
-            (v5e((blocks, block, cfg.num_kv_heads, cfg.head_dim),
+            (v5e((blocks, block, cfg.num_kv_heads * cfg.head_dim),
                  jnp.bfloat16),) * layers
             for layers, blocks in layers_blocks
         )
@@ -438,3 +438,218 @@ def test_window_and_prefill_agree_on_a_narrow_gate_layout(v5e):
     auto = narrow(window_formats(Format(Layout.AUTO)))
     gate = "['full']['attn_gate']['kernel']"
     assert auto[gate] != kept[gate]
+
+
+# ---- the pools go to the kernel as they lie (tier-1, ~10 s a program) ----
+
+def _hlo_defs(text: str) -> dict:
+    """``name -> (result type, opcode, the rest of the line)`` of every
+    instruction of a compiled program's text."""
+    import re
+
+    defs = {}
+    for line in text.splitlines():
+        m = re.match(r'^\s*(?:ROOT )?%(\S+) = (.*)$', line)
+        if not m:
+            continue
+        rest, depth = m.group(2), 0
+        for i, ch in enumerate(rest):
+            depth += (ch == '(') - (ch == ')')
+            if ch == ' ' and depth == 0:
+                break
+        call = rest[i + 1:]
+        defs[m.group(1)] = (rest[:i], call.partition('(')[0], call)
+    return defs
+
+
+def _holds(result_type: str, shape: tuple) -> bool:
+    """Does an instruction's result (a tuple's members too) hold an array
+    of ``shape``'s size?"""
+    import re
+
+    size = int(np.prod(shape))
+    return any(
+        int(np.prod([int(d) for d in dims.split(',')])) == size
+        for dims in re.findall(r'bf16\[([0-9,]+)\]', result_type)
+    )
+
+
+def _assert_pools_go_to_the_kernel_as_they_lie(compiled, buffers) -> None:
+    """No relayout of a pool-sized array, and the paged kernel reads the
+    pools themselves: (1) no ``reshape``, ``copy`` or ``transpose`` whose
+    result is the size of one of ``buffers``; (2) each K and V operand of
+    each paged kernel call is, behind bitcasts and the compiler's own
+    staging of a buffer through its fast memory (``copy-start`` /
+    ``copy-done``), a parameter, a loop's carry, or the in-place write (a
+    ``scatter``, alone or fused)."""
+    import re
+
+    text = compiled.as_text()
+    defs = _hlo_defs(text)
+    relayouts = [
+        f'%{name} = {result[:40]} {opcode}'
+        for name, (result, opcode, _) in defs.items()
+        if opcode in ('reshape', 'copy', 'transpose')
+        and any(_holds(result, shape) for shape in buffers)
+    ]
+    assert not relayouts, relayouts
+
+    def writes_in_place(call: str) -> bool:
+        callee = re.search(r'calls=%(\S+?)[,\s]', call + ' ')
+        body = text.partition(f'\n%{callee.group(1)} (')[2].partition('\n}')[0]
+        return ' scatter(' in body
+
+    kernels = [
+        call for _, opcode, call in defs.values()
+        if opcode == 'custom-call' and 'tpu_custom_call' in call
+    ]
+    assert kernels, 'no Pallas kernel in the compiled program'
+    pools_read = 0
+    for call in kernels:
+        operands = re.match(r'custom-call\(([^)]*)\)', call).group(1)
+        for operand in operands.split(', '):
+            name = operand.rpartition('%')[2]  # past an /*index=n*/ note
+            if not any(_holds(defs[name][0], shape) for shape in buffers):
+                continue
+            while defs[name][1] in ('bitcast', 'copy-done', 'copy-start'):
+                name = re.match(
+                    r'[a-z\-]+\(%([^,)\s]+)', defs[name][2]
+                ).group(1)
+            result, opcode, producer = defs[name]
+            assert opcode in ('parameter', 'get-tuple-element', 'scatter') or (
+                opcode == 'fusion' and writes_in_place(producer)
+            ), f'%{name} = {result[:40]} {producer[:80]}'
+            pools_read += 1
+    assert pools_read >= 2  # a K and a V at the least
+
+
+@pytest.fixture(scope='module')
+def laguna_cell(v5e):
+    """The laguna cell's configuration cut to one period of layers (one
+    full layer, three window layers; the dense MLP and three sparse), the
+    parameters and the pools at the cell's sizes: 9600 and 1757 blocks."""
+    import json
+    from pathlib import Path
+
+    from distllm_tpu.models import laguna
+
+    root = Path(__file__).resolve().parents[1]
+    hf = json.loads((root / 'benchmarks/configs/laguna-xs.2.json').read_text())
+    hf['num_hidden_layers'] = 4
+    for key in ('layer_types', 'mlp_layer_types', 'num_attention_heads_per_layer'):
+        hf[key] = hf[key][:4]
+    cfg = laguna.LagunaConfig.from_hf_config(hf)
+    shapes = jax.eval_shape(
+        lambda: laguna.init_on_device(jax.random.PRNGKey(0), cfg)
+    )
+    params = jax.tree.map(lambda a: v5e(a.shape, a.dtype), shapes)
+    buffers = [
+        (blocks, 16, cfg.num_kv_heads * cfg.head_dim)
+        for blocks in (9600, 1757)
+    ]
+    pools = tuple(
+        (v5e(shape, jnp.bfloat16),) * cfg.count(kind)
+        for kind, shape in zip(('full', 'window'), buffers)
+    )
+    return laguna, cfg, params, pools, buffers
+
+
+def test_laguna_decode_window_reads_the_pools_as_they_lie(v5e, laguna_cell):
+    laguna, cfg, params, pools, buffers = laguna_cell
+    b, i32, f32 = 48, jnp.int32, jnp.float32
+
+    def window_fn(params, ids, pos, ctx, k, v, bt, steps_left, *sampling):
+        return laguna.decode_loop(
+            params, cfg, ids, pos, k, v, bt, ctx, steps_left, *sampling,
+            num_steps=8, attn_backend='pallas', max_table_positions=8448,
+        )
+
+    compiled = jax.jit(window_fn, donate_argnums=(4, 5)).lower(
+        params, v5e((b,), i32), v5e((b,), i32), v5e((b,), i32), pools, pools,
+        (v5e((b, 528), i32),) * 2, v5e((b,), i32), v5e((b,), f32),
+        v5e((b,), f32), v5e((b,), f32), v5e((b,), i32), v5e((b,), jnp.uint32),
+    ).compile()
+    _assert_pools_go_to_the_kernel_as_they_lie(compiled, buffers)
+
+
+def test_laguna_chunk_prefill_reads_the_pools_as_they_lie(v5e, laguna_cell):
+    """The ``(512, 4)`` program: four rows of a 512-token span."""
+    laguna, cfg, params, pools, buffers = laguna_cell
+    i32 = jnp.int32
+    compiled = jax.jit(
+        lambda params, ids, pos, k, v, bt, ctx, tails: laguna.prefill_paged(
+            params, cfg, ids, pos, k, v, bt, ctx, tails,
+            max_table_positions=8448, attn_backend='pallas',
+        ), donate_argnums=(3, 4),
+    ).lower(
+        params, v5e((4, 512), i32), v5e((4, 512), i32), pools, pools,
+        (v5e((4, 528), i32),) * 2, v5e((4,), i32), v5e((4,), i32),
+    ).compile()
+    _assert_pools_go_to_the_kernel_as_they_lie(compiled, buffers)
+
+
+def test_mistral_decode_window_reshapes_no_plane(v5e):
+    """One layer's worth of the 7B decode window over a stacked pool: the
+    layer's plane is still sliced out and written back (the next issue's),
+    but no ``reshape`` of a plane is left."""
+    from distllm_tpu.models import mistral
+
+    cfg = mistral.MistralConfig(dtype='bfloat16', num_layers=1)
+    shapes = jax.eval_shape(
+        lambda: mistral.init_on_device(jax.random.PRNGKey(0), cfg)
+    )
+    params = jax.tree.map(lambda a: v5e(a.shape, a.dtype), shapes)
+    b, blocks = 32, 640
+    plane = (blocks, 16, cfg.num_kv_heads * cfg.head_size)
+    pool = v5e((1, *plane), jnp.bfloat16)
+    i32, f32 = jnp.int32, jnp.float32
+    compiled = jax.jit(
+        lambda p, i, po, c, k, v, bt, sl, tmp, tp_, mp, tk, sd:
+            mistral.decode_loop(
+                p, cfg, i, po, k, v, bt, c, sl, tmp, tp_, mp, tk, sd,
+                num_steps=8, attn_backend='pallas', max_table_positions=4096,
+            ),
+        donate_argnums=(4, 5),
+    ).lower(
+        params, v5e((b,), i32), v5e((b,), i32), v5e((b,), i32), pool, pool,
+        v5e((b, 256), i32), v5e((b,), i32), v5e((b,), f32), v5e((b,), f32),
+        v5e((b,), f32), v5e((b,), i32), v5e((b,), jnp.uint32),
+    ).compile()
+    _assert_kernel_compiled(compiled)
+    reshapes = [
+        f'%{name} = {result[:40]}'
+        for name, (result, opcode, _) in _hlo_defs(compiled.as_text()).items()
+        if opcode == 'reshape' and _holds(result, plane)
+    ]
+    assert not reshapes, reshapes
+
+
+@pytest.mark.parametrize('program', ['write_prefill', 'gather_blocks'])
+def test_stacked_pool_programs_copy_no_pool(v5e, program):
+    """The two programs that touch every layer of a stacked pool at once,
+    at ``mistral7b``'s sizes (a 0.67 GB pool beside 14.5 GB of weights: a
+    copy of it does not fit). Written with a window over the layer axis
+    (``.at[:, blocks, offsets]``, ``c[:, ids]``) the TPU compiler moves
+    that axis of the whole head-folded pool inward and back: 671 MB of
+    temporaries, and ``RESOURCE_EXHAUSTED`` at the cell's first dense
+    prefill (on the chip, PR 31). As (layer, block, offset) rows: none."""
+    from distllm_tpu.generate.engine.engine import (
+        _gather_blocks_all_layers,
+        _write_prefill_all_layers,
+    )
+
+    pool = v5e((32, 640, 16, _NKV * _HD), jnp.bfloat16)
+    if program == 'write_prefill':
+        # K and V as the engine's dense prefill program hands them over:
+        # rows already folded, so this program (lowered again inside a
+        # served window for each commitment of the pools) relayouts nothing
+        seq = v5e((32, 1, 512, _NKV * _HD), jnp.bfloat16)
+        compiled = jax.jit(_write_prefill_all_layers, donate_argnums=(0, 1)).lower(
+            pool, pool, seq, seq, v5e((1, 256), jnp.int32), v5e((1,), jnp.int32)
+        ).compile()
+    else:
+        compiled = jax.jit(_gather_blocks_all_layers).lower(
+            pool, pool, v5e((8,), jnp.int32)
+        ).compile()
+    plane = 640 * 16 * _NKV * _HD * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < plane

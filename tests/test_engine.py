@@ -29,9 +29,16 @@ from distllm_tpu.ops.sampling import sample_tokens
 
 # ------------------------------------------------------------ paged attn
 def _random_cache(rng, num_blocks=8, block_size=4, nkv=2, hd=8):
-    k = rng.normal(size=(num_blocks, block_size, nkv, hd)).astype(np.float32)
-    v = rng.normal(size=(num_blocks, block_size, nkv, hd)).astype(np.float32)
+    """Head-folded, as the pool stores a layer."""
+    k = rng.normal(size=(num_blocks, block_size, nkv * hd)).astype(np.float32)
+    v = rng.normal(size=(num_blocks, block_size, nkv * hd)).astype(np.float32)
     return jnp.asarray(k), jnp.asarray(v)
+
+
+def _heads(rows, nkv=2):
+    """Rows taken out of a cache, ``[.., nkv * hd] -> [.., nkv, hd]``."""
+    rows = np.asarray(rows)
+    return rows.reshape(*rows.shape[:-1], nkv, -1)
 
 
 def _dense_reference(q, k, v, context_len):
@@ -61,8 +68,8 @@ def test_paged_attention_matches_dense(rng):
     )
 
     for seq, (blocks, ctx) in enumerate([((2, 5), 6), ((7,), 3)]):
-        k_lin = np.concatenate([np.asarray(k_cache[b]) for b in blocks])
-        v_lin = np.concatenate([np.asarray(v_cache[b]) for b in blocks])
+        k_lin = np.concatenate([_heads(k_cache[b]) for b in blocks])
+        v_lin = np.concatenate([_heads(v_cache[b]) for b in blocks])
         ref = _dense_reference(np.asarray(q[seq]), k_lin, v_lin, ctx)
         np.testing.assert_allclose(out[seq], ref, atol=1e-5, rtol=1e-4)
 
@@ -86,8 +93,8 @@ def test_paged_attention_pallas_interpret_matches_xla(rng):
 
 
 def test_write_token_and_prefill_kv(rng):
-    k_cache = jnp.zeros((4, 4, 2, 3))
-    v_cache = jnp.zeros((4, 4, 2, 3))
+    k_cache = jnp.zeros((4, 4, 2 * 3))
+    v_cache = jnp.zeros((4, 4, 2 * 3))
     # prefill 6 tokens into blocks [1, 2] (padded seq of 8)
     k_seq = jnp.asarray(rng.normal(size=(8, 2, 3)).astype(np.float32))
     v_seq = jnp.asarray(rng.normal(size=(8, 2, 3)).astype(np.float32))
@@ -95,8 +102,8 @@ def test_write_token_and_prefill_kv(rng):
     k_cache, v_cache = write_prefill_kv(
         k_cache, v_cache, k_seq, v_seq, row, jnp.int32(6)
     )
-    np.testing.assert_allclose(np.asarray(k_cache[1]), np.asarray(k_seq[:4]))
-    np.testing.assert_allclose(np.asarray(k_cache[2][:2]), np.asarray(k_seq[4:6]))
+    np.testing.assert_allclose(_heads(k_cache[1]), np.asarray(k_seq[:4]))
+    np.testing.assert_allclose(_heads(k_cache[2][:2]), np.asarray(k_seq[4:6]))
     # slot beyond length stays zero (trash block ate the padding)
     np.testing.assert_allclose(np.asarray(k_cache[2][2:]), 0.0)
 
@@ -179,9 +186,121 @@ def test_paged_kv_cache_container():
         num_layers=2, num_blocks=8, block_size=4, num_kv_heads=2,
         head_dim=4, dtype='float32',
     )
-    assert kv.k.shape == (2, 8, 4, 2, 4)
+    assert kv.shape == (2, 8, 4, 2, 4)  # the logical shape
+    assert kv.k_pool.shape == kv.pool_shape == (2, 8, 4, 8)  # stored head-folded
+    # the host's view: a layer, then block ids, in the logical shape
+    assert len(kv.k) == 2 and kv.v[1][[3, 5]].shape == (2, 4, 2, 4)
     assert kv.blocks_needed(10) == 3
     assert kv.hbm_bytes == 2 * 2 * 8 * 4 * 2 * 4 * 4
+
+
+def _layer_of(pool, layer, layer_buffers):
+    if layer_buffers:
+        return pool[layer]
+    return jax.tree.map(lambda c: c[layer], pool)
+
+
+def _with_layer(pool, layer, buf, layer_buffers):
+    if layer_buffers:
+        return tuple(buf if i == layer else b for i, b in enumerate(pool))
+    return jax.tree.map(lambda c, b: c.at[layer].set(b), pool, buf)
+
+
+@pytest.mark.parametrize('form', ['stacked', 'layer_buffers', 'int8'])
+@pytest.mark.parametrize('writer', ['token', 'chunk', 'prefill'])
+def test_writers_fold_the_new_rows_and_the_host_view_unfolds_blocks(
+    rng, writer, form
+):
+    """Each writer folds the NEW rows (``[.., N_kv, Hd]``) into the pool's
+    ``N_kv * Hd`` rows; what the host's view gives back for a layer and
+    block ids is the rows in their logical shape, for both pool forms and
+    the int8 container (a ``QuantizedKV`` of such blocks and their
+    scales)."""
+    from distllm_tpu.ops.paged_attention import QuantizedKV, write_chunk_kv
+
+    layer_buffers = form == 'layer_buffers'
+    kv = PagedKVCache(
+        num_layers=2, num_blocks=6, block_size=4, num_kv_heads=2, head_dim=8,
+        dtype='int8' if form == 'int8' else 'float32',
+        layer_buffers=layer_buffers,
+    )
+    assert jax.tree.leaves(kv.k_pool)[0].shape[-2:] == (4, 16)  # folded
+    rows = rng.normal(size=(8, 2, 8)).astype(np.float32)
+    row = jnp.asarray([3, 5, 0, 0], jnp.int32)  # 8 tokens into blocks 3, 5
+    k_l = _layer_of(kv.k_pool, 1, layer_buffers)
+    v_l = _layer_of(kv.v_pool, 1, layer_buffers)
+    if writer == 'token':
+        for t in range(8):
+            k_l, v_l = write_token_kv(
+                k_l, v_l, jnp.asarray(rows[t:t + 1]),
+                jnp.asarray(2 * rows[t:t + 1]), row[None],
+                jnp.asarray([t], jnp.int32),
+            )
+    elif writer == 'chunk':
+        for start in (0, 4):
+            k_l, v_l = write_chunk_kv(
+                k_l, v_l, jnp.asarray(rows[None, start:start + 4]),
+                jnp.asarray(2 * rows[None, start:start + 4]), row[None],
+                jnp.arange(start, start + 4)[None], jnp.ones((1, 4), bool),
+            )
+    else:
+        k_l, v_l = write_prefill_kv(
+            k_l, v_l, jnp.asarray(rows), jnp.asarray(2 * rows), row,
+            jnp.int32(8),
+        )
+    kv.k_pool = _with_layer(kv.k_pool, 1, k_l, layer_buffers)
+    kv.v_pool = _with_layer(kv.v_pool, 1, v_l, layer_buffers)
+
+    want = rows.reshape(2, 4, 2, 8)  # [blocks, block_size, N_kv, Hd]
+    got_k, got_v = kv.k[1][[3, 5]], kv.v[1][[3, 5]]
+    if form == 'int8':
+        assert isinstance(got_k, QuantizedKV)
+        assert got_k.data.shape == (2, 4, 2, 8) and got_k.scale.shape == (2, 2)
+        for got, scaled in ((got_k, want), (got_v, 2 * want)):
+            scale = np.asarray(got.scale)[:, None, :, None]
+            deq = np.asarray(got.data, np.float32) * scale
+            # an append re-rounds the rows before it: a step and a half
+            assert (np.abs(deq - scaled) <= 1.5 * scale + 1e-6).all()
+        untouched = np.asarray(kv.k[0][[3, 5]].data)
+    else:
+        assert got_k.shape == (2, 4, 2, 8)
+        np.testing.assert_array_equal(np.asarray(got_k), want)
+        np.testing.assert_array_equal(np.asarray(got_v), 2 * want)
+        # block ids of any shape: [rows, 2] gives [rows, 2, block, N_kv, Hd]
+        ends = kv.k[1][np.asarray([[3, 5], [5, 3]])]
+        assert ends.shape == (2, 2, 4, 2, 8)
+        np.testing.assert_array_equal(np.asarray(ends[1, 0]), want[1])
+        untouched = np.asarray(kv.k[0][[3, 5]])
+    assert not untouched.any()  # the other layer
+
+
+@pytest.mark.parametrize('layer_buffers', [False, True], ids=['stacked', 'layer_buffers'])
+def test_host_view_gathers_the_blocks_asked_for_and_no_buffer(layer_buffers):
+    """``kv.k[layer][block_ids]`` is a gather of those blocks and a reshape
+    of the gathered blocks: nothing it computes is the size of a layer's
+    buffer (the laguna cell's pools fill 91% of the device)."""
+    from distllm_tpu.generate.engine.kv_cache import _PoolView
+
+    kv = PagedKVCache(
+        num_layers=3, num_blocks=64, block_size=4, num_kv_heads=2, head_dim=8,
+        dtype='float32', layer_buffers=layer_buffers,
+    )
+    ids = np.asarray([[7, 9], [1, 63]])
+    view = _PoolView(kv, kv.k_pool)
+    jaxpr = jax.make_jaxpr(lambda pool: view._gather(pool, 2, ids))(kv.k_pool)
+    asked = ids.size * 4 * 2 * 8
+    sizes = [
+        int(np.prod(var.aval.shape))
+        for eqn in jaxpr.jaxpr.eqns for var in eqn.outvars
+    ]
+    assert sizes and max(sizes) <= asked < 64 * 4 * 2 * 8
+    # ... which come back to the host and are unfolded there
+    got = kv.k[2][ids]
+    assert isinstance(got, np.ndarray) and got.shape == (2, 2, 4, 2, 8)
+    with pytest.raises(IndexError):
+        kv.k[3]
+    with pytest.raises(AttributeError):
+        kv.k = kv.k_pool  # the programs' operands are k_pool / v_pool
 
 
 # ----------------------------------------------------------------- engine
@@ -704,10 +823,10 @@ def test_ragged_paged_attention_decode_rows_match_decode_kernel(rng):
     # Chunk row: each query vs a dense causal reference over its prefix.
     for j, pos in enumerate([2, 3, 4]):
         k_lin = np.concatenate(
-            [np.asarray(k_cache[7]), np.asarray(k_cache[3])]
+            [_heads(k_cache[7]), _heads(k_cache[3])]
         )
         v_lin = np.concatenate(
-            [np.asarray(v_cache[7]), np.asarray(v_cache[3])]
+            [_heads(v_cache[7]), _heads(v_cache[3])]
         )
         ref = _dense_reference(np.asarray(q[1, j]), k_lin, v_lin, pos + 1)
         np.testing.assert_allclose(out[1, j], ref, atol=1e-5, rtol=1e-4)

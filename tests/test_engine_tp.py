@@ -146,3 +146,43 @@ def test_tp2_gemma2_matches_single_device():
     mesh = make_mesh(MeshSpec(data=1, model=2), devices=jax.devices()[:2])
     tp = _generate(cfg, params, mesh, prompts)
     assert single == tp
+
+
+def test_tp2_pool_shards_hold_whole_heads(model):
+    """The pool's dim 3 is a token's ``N_kv * Hd`` row, whole heads in
+    contiguous runs: ``P(None, None, None, 'model')`` gives each of two
+    shards one of the two KV heads, the split the attention heads have.
+    Read after a served prompt: shard ``i`` is head ``i`` of every row."""
+    cfg, params = model
+    mesh = make_mesh(MeshSpec(data=1, model=2), devices=jax.devices()[:2])
+    sharded = shard_pytree(params, mistral.param_specs(cfg, params), mesh)
+    engine = LLMEngine(
+        cfg, sharded, _Tok(),
+        EngineConfig(block_size=4, num_blocks=16, max_num_seqs=2,
+                     max_model_len=32, prefill_min_bucket=8),
+        mesh=mesh,
+    )
+    prompt = list(range(1, 9))
+    engine.generate_ids([prompt], SamplingParams(temperature=0.0, max_tokens=2))
+    head_dim = cfg.head_size
+    assert engine.kv.k_pool.shape == (2, 16, 4, 2 * head_dim)
+    shards = sorted(
+        engine.kv.k_pool.addressable_shards, key=lambda s: s.index[3].start
+    )
+    assert [s.data.shape for s in shards] == [(2, 16, 4, head_dim)] * 2
+    ids = np.asarray([prompt], np.int32)
+    _, k_all, _ = mistral.prefill(params, cfg, ids, np.ones_like(ids))
+    want = np.asarray(k_all)[:, 0]  # [L, 8, N_kv, Hd]
+    written = np.asarray(engine.kv.k[0][np.arange(16)]).reshape(64, 2, head_dim)
+    # the prompt's two blocks, wherever the allocator put them
+    start = next(
+        b * 4 for b in range(1, 16)
+        if np.allclose(written[b * 4], want[0, 0], atol=1e-5)
+    )
+    np.testing.assert_allclose(written[start:start + 4], want[0, :4], atol=1e-5)
+    for head, shard in enumerate(shards):
+        np.testing.assert_allclose(
+            np.asarray(shard.data)[0].reshape(64, head_dim)[start:start + 4],
+            want[0, :4, head], atol=1e-5,
+        )
+    engine.shutdown()

@@ -119,12 +119,15 @@ def test_records_and_telemetry_carry_the_groups():
         hf_over=dict(num_experts=4, num_routed_experts=8)
     )
     pools = engine.telemetry['kv_pools']
+    n_kv, head_dim = engine.kv.shape[3:]
     assert pools['full'] == {
         'layers': 2, 'window': None, 'blocks': 64, 'bytes': engine.kv.hbm_bytes,
+        'block_shape': [engine.kv.block_size, n_kv * head_dim],  # as stored
     }
     assert pools['window']['layers'] == 4 and pools['window']['window'] == WINDOW
     assert pools['window']['blocks'] == engine.window_blocks.num_blocks
-    assert isinstance(engine.kv.k, tuple) and len(engine.window_kv.k) == 4
+    assert isinstance(engine.kv.k_pool, tuple) and len(engine.window_kv.k_pool) == 4
+    assert len(engine.kv.k) == 2 and len(engine.window_kv.v) == 4  # the host's view
     before = engine.flight.total_recorded
     rng = np.random.default_rng(2)
     outputs = engine.generate_ids(

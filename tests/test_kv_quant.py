@@ -39,13 +39,19 @@ from distllm_tpu.ops.paged_attention import (
 
 
 def _dequant(cache: QuantizedKV) -> np.ndarray:
-    data = np.asarray(cache.data, np.float32)
+    """``[blocks, block_size, nkv, hd]`` float32 of a head-folded cache."""
     scale = np.asarray(cache.scale, np.float32)
+    data = np.asarray(cache.data, np.float32)
+    data = data.reshape(*data.shape[:2], scale.shape[-1], -1)
     return data * scale[:, None, :, None]
 
 
+def _folded(blocks: np.ndarray) -> jnp.ndarray:
+    return jnp.asarray(blocks.reshape(*blocks.shape[:2], -1))
+
+
 def _zero_quant_cache(num_blocks=4, block_size=4, nkv=2, hd=8):
-    data = jnp.zeros((num_blocks, block_size, nkv, hd), jnp.int8)
+    data = jnp.zeros((num_blocks, block_size, nkv * hd), jnp.int8)
     scale = jnp.zeros((num_blocks, nkv), jnp.float32)
     return QuantizedKV(data, scale)
 
@@ -172,7 +178,7 @@ def test_write_chunk_kv_quantized_block_aligned_matches_prefill(rng):
 
 # -------------------------------------------------- fused-dequant attention
 def _random_quant_cache(rng, num_blocks=8, block_size=4, nkv=2, hd=8):
-    data = rng.integers(-127, 128, size=(num_blocks, block_size, nkv, hd))
+    data = rng.integers(-127, 128, size=(num_blocks, block_size, nkv * hd))
     scale = rng.uniform(0.01, 0.1, size=(num_blocks, nkv))
     return QuantizedKV(
         jnp.asarray(data.astype(np.int8)),
@@ -193,7 +199,7 @@ def test_paged_attention_xla_int8_matches_dequantized_cache(rng):
     )
     dense = np.asarray(
         paged_attention_xla(
-            q, jnp.asarray(_dequant(k_cache)), jnp.asarray(_dequant(v_cache)),
+            q, _folded(_dequant(k_cache)), _folded(_dequant(v_cache)),
             block_tables, context_lens,
         )
     )
@@ -351,8 +357,8 @@ def test_engine_int8_pool_bytes_halve():
     # pool the same layout lands at ~0.5. Either way it must be well
     # under the full-precision pool.
     assert ratio < 0.5
-    assert isinstance(q.kv.k, QuantizedKV)
-    assert q.kv.k.scale.shape == (2, 64, 2)
+    assert isinstance(q.kv.k_pool, QuantizedKV)
+    assert q.kv.k_pool.scale.shape == (2, 64, 2)
 
 
 def test_paged_kv_cache_int8_spec_is_quantized_pytree():

@@ -676,3 +676,58 @@ def test_disk_tier_warm_restart_bit_exact(tmp_path):
         _m.PREFIX_TIER_PROMOTIONS.labels(tier='disk').value
         > disk_promos_before
     )
+
+
+def test_kvblock_written_in_the_logical_layout_promotes_into_a_folded_pool(tmp_path):
+    """The pool stores a token's heads folded into one row; a ``.kvblock``
+    file keeps the block shape it had before, ``[L, block_size, N_kv,
+    Hd]``, so a directory written before that change still loads. The
+    files here are built by hand: the header bytes are pinned, the body is
+    the dense forward's K then V of the block in that shape. A fresh
+    engine over the directory promotes them (no prefill of those blocks),
+    holds exactly those bytes under the host's view of the pool, and
+    emits the dense reference's tokens."""
+    from distllm_tpu.generate.engine.kv_cache import block_digests, decode_kvblock
+
+    cfg, params, _ = _tiny_engine(**TIER_POOL)
+    ids = np.asarray([PROMPT_A], np.int32)
+    _, k_all, v_all = mistral.prefill(params, cfg, ids, np.ones_like(ids))
+    header = b'{"version":2,"shape":[2,4,2,8],"dtype":"float32","scales":null}\n'
+    blocks = []
+    for i, digest in enumerate(block_digests(PROMPT_A, 4)):
+        k, v = (
+            np.ascontiguousarray(np.asarray(a)[:, 0, 4 * i:4 * i + 4])
+            for a in (k_all, v_all)
+        )
+        assert k.shape == (2, 4, 2, 8) and k.dtype == np.float32
+        payload = header + k.tobytes() + v.tobytes()
+        assert len(payload) == len(header) + 2 * 512
+        got_k, got_v = decode_kvblock(payload)
+        assert got_k.shape == (2, 4, 2, 8) and got_k.tobytes() == k.tobytes()
+        (tmp_path / f'{digest.hex()}.kvblock').write_bytes(payload)
+        blocks.append((k, v))
+
+    _, _, fresh = _tiny_engine(
+        host_kv_tier_bytes=64 << 20, disk_kv_tier_dir=str(tmp_path),
+        **TIER_POOL,
+    )
+    assert fresh.kv.k_pool.shape == (2, 12, 4, 16)  # folded
+    rid = fresh.add_request(list(PROMPT_A), GREEDY)
+    while fresh.has_unfinished:
+        fresh.step()
+    request = fresh._finished[rid]
+    assert request.output_ids == _dense_greedy(cfg, params, PROMPT_A, 4)
+    assert fresh._stats['tier_promotions'] >= 1
+    promoted = fresh._stats['tier_promoted_blocks']
+    assert promoted >= 5 and fresh._stats['prefix_hit_tokens'] >= 4 * promoted
+    # The promoted blocks are still cached: under the host's view they hold
+    # the files' bytes, layer by layer.
+    held = fresh.prefix_cache.match(block_digests(PROMPT_A, 4))[:promoted]
+    assert len(held) == promoted
+    for layer in range(2):
+        got_k = np.asarray(fresh.kv.k[layer][held])
+        got_v = np.asarray(fresh.kv.v[layer][held])
+        assert got_k.shape == (promoted, 4, 2, 8)
+        for j in range(promoted):
+            assert got_k[j].tobytes() == blocks[j][0][layer].tobytes()
+            assert got_v[j].tobytes() == blocks[j][1][layer].tobytes()

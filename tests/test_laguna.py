@@ -327,7 +327,7 @@ def test_serving_programs_lower_each_kind_of_layer_once():
     called a layer, and not one copy of its text a layer."""
     hf, cfg, params = tiny()
     pools = tuple(
-        (jnp.zeros((9, BLOCK, cfg.num_kv_heads, cfg.head_dim), jnp.float32),)
+        (jnp.zeros((9, BLOCK, cfg.num_kv_heads * cfg.head_dim), jnp.float32),)
         * cfg.count(kind)
         for kind in ('full', 'window')
     )

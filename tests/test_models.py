@@ -192,14 +192,16 @@ def test_qwen2_decode_matches_prefill(np_rng):
     want = np.asarray(jmistral.logits(params, cfg, hidden))[0, -1]
 
     from distllm_tpu.generate.engine.engine import _write_prefill_all_layers
+    from distllm_tpu.ops.paged_attention import fold_heads
 
     bs, nb = 4, 8
-    kshape = (cfg.num_layers, nb, bs, cfg.num_kv_heads, cfg.head_size)
+    kshape = (cfg.num_layers, nb, bs, cfg.num_kv_heads * cfg.head_size)
     k_cache = jnp.zeros(kshape, jnp.float32)
     v_cache = jnp.zeros(kshape, jnp.float32)
     table = jnp.asarray([[1, 2, 0, 0]], jnp.int32)
     k_cache, v_cache = _write_prefill_all_layers(
-        k_cache, v_cache, k, v, table, jnp.asarray([6], jnp.int32)
+        k_cache, v_cache, fold_heads(k), fold_heads(v), table,
+        jnp.asarray([6], jnp.int32)
     )
     lg, _, _ = jmistral.decode_step(
         params, cfg, jnp.asarray(ids[:, -1]), jnp.asarray([5], jnp.int32),
@@ -356,6 +358,7 @@ def test_mixtral_serving_decode_matches_apply(np_rng):
     engine-facing prefill + greedy decode_step reproduce apply()'s
     next-token logits (MoE routing inside the decode layer loop)."""
     from distllm_tpu.generate.engine.engine import _write_prefill_all_layers
+    from distllm_tpu.ops.paged_attention import fold_heads
     from distllm_tpu.models import mixtral as jmix
 
     cfg = jmix.MixtralConfig(
@@ -374,12 +377,13 @@ def test_mixtral_serving_decode_matches_apply(np_rng):
     want = np.asarray(jmix.logits(params, cfg, hidden))[0, -1]
 
     bs, nb = 4, 8
-    kshape = (cfg.num_layers, nb, bs, cfg.num_kv_heads, cfg.head_size)
+    kshape = (cfg.num_layers, nb, bs, cfg.num_kv_heads * cfg.head_size)
     k_cache = jnp.zeros(kshape, jnp.float32)
     v_cache = jnp.zeros(kshape, jnp.float32)
     table = jnp.asarray([[1, 2, 0, 0]], jnp.int32)
     k_cache, v_cache = _write_prefill_all_layers(
-        k_cache, v_cache, k, v, table, jnp.asarray([6], jnp.int32)
+        k_cache, v_cache, fold_heads(k), fold_heads(v), table,
+        jnp.asarray([6], jnp.int32)
     )
     for unroll in (False, True):
         lg, _, _ = jmix.decode_step(

@@ -30,11 +30,12 @@ from distllm_tpu.ops.paged_attention import (
 
 def _setup(rng, *, num_blocks=12, block_size=4, nkv=2, nh=4, hd=8, b=3,
            s=5):
+    # Head-folded, as the pool stores a layer: [blocks, block_size, nkv*hd].
     k = jnp.asarray(
-        rng.normal(size=(num_blocks, block_size, nkv, hd)).astype(np.float32)
+        rng.normal(size=(num_blocks, block_size, nkv * hd)).astype(np.float32)
     )
     v = jnp.asarray(
-        rng.normal(size=(num_blocks, block_size, nkv, hd)).astype(np.float32)
+        rng.normal(size=(num_blocks, block_size, nkv * hd)).astype(np.float32)
     )
     max_blocks = 8
     # Block 0 is the trash block by engine convention; tables point at
@@ -61,7 +62,11 @@ def _assert_parity(out, ref, q_lens, s):
     )
 
 
-@pytest.mark.parametrize('nh,nkv', [(4, 4), (4, 2), (8, 2)])
+# 1, 2, and the serving groups: 4 (mistral7b, granite), 6 and 8 (laguna's
+# full and window layers) queries a KV head, over head-folded pools.
+@pytest.mark.parametrize(
+    'nh,nkv', [(4, 4), (4, 2), (8, 2), (12, 2), (16, 2)]
+)
 @pytest.mark.parametrize(
     'window',
     [None, 3, 'traced', 'traced_zero'],

@@ -263,8 +263,8 @@ def test_decode_window_lowers_to_no_sort():
     params = mistral.init(jax.random.PRNGKey(0), cfg)
     b, blocks, block_size = 2, 8, 4
     cache = jnp.zeros(
-        (cfg.num_layers, blocks, block_size, cfg.num_kv_heads,
-         cfg.hidden_size // cfg.num_heads), jnp.float32,
+        (cfg.num_layers, blocks, block_size,
+         cfg.num_kv_heads * (cfg.hidden_size // cfg.num_heads)), jnp.float32,
     )
     ints = jnp.ones((b,), jnp.int32)
     floats = jnp.ones((b,), jnp.float32)

@@ -131,10 +131,10 @@ def test_ragged_decode_row_ignores_stale_suffix_kv(rng):
 
     block_size = 4
     k_cache = jnp.asarray(
-        rng.normal(size=(8, block_size, 2, 8)).astype(np.float32)
+        rng.normal(size=(8, block_size, 2 * 8)).astype(np.float32)
     )
     v_cache = jnp.asarray(
-        rng.normal(size=(8, block_size, 2, 8)).astype(np.float32)
+        rng.normal(size=(8, block_size, 2 * 8)).astype(np.float32)
     )
     block_tables = jnp.asarray([[2, 5]], dtype=jnp.int32)
     q = jnp.asarray(rng.normal(size=(1, 1, 4, 8)).astype(np.float32))

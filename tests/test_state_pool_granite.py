@@ -553,12 +553,12 @@ def _assert_programs_lower_as_the_parents(engine, cfg):
     i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
     f32 = lambda *shape: jnp.zeros(shape, jnp.float32)  # noqa: E731
     window_args = (
-        engine.params, i32(b), i32(b), i32(b), engine.kv.k, engine.kv.v,
+        engine.params, i32(b), i32(b), i32(b), engine.kv.k_pool, engine.kv.v_pool,
         i32(b, width), i32(b), f32(b), f32(b), f32(b), i32(b),
         jnp.zeros((b,), jnp.uint32),
     )
     prefill_args = (
-        engine.params, i32(1, 8), i32(1, 8), engine.kv.k, engine.kv.v,
+        engine.params, i32(1, 8), i32(1, 8), engine.kv.k_pool, engine.kv.v_pool,
         i32(1, width), i32(1), i32(1),
     )
     for parent, ours, args, donate in (
@@ -593,8 +593,8 @@ def test_mistral_pool_and_programs_are_what_they_were():
     ]
     assert engine.window_kv is None and engine.window_blocks is None
     assert 'kv_pools' not in engine.telemetry
-    assert engine.kv.k.shape == engine.kv.shape  # stacked, not a buffer a layer
-    assert engine._pools() == (engine.kv.k, engine.kv.v)
+    assert engine.kv.k_pool.shape == engine.kv.pool_shape  # stacked, not a buffer a layer
+    assert engine._pools() == (engine.kv.k_pool, engine.kv.v_pool)
     assert engine._group_tables('tables') == 'tables'
     _assert_programs_lower_as_the_parents(engine, cfg)
     compiled = []
