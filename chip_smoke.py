@@ -21,6 +21,12 @@ printed as one JSON line (``{"phase": ..., "ok": ...}``):
   window 64) through ``LLMEngine.generate_ids``: a prompt prefilled past
   window + chunk, decode with the window group freeing behind itself, one
   preemption and re-admission, the tokens against the plain reference;
+- ``latent``  — a toy ``deepseek_v3`` with the published latent widths (a
+  512-wide latent and a 64-wide rope key a token, 32 query heads on the one
+  KV head) through ``LLMEngine.generate_ids``: a pool of one plane a layer,
+  a prompt prefilled past two chunks, decode, one preemption and
+  re-admission, the tokens against the plain reference, and the paged
+  kernel against its XLA twin on a latent plane;
 - ``serve``   — the OpenAI-compatible server from ``chat_server.build_app``
   on a local port, engine made by ``TpuGenerator`` with the settings of
   ``examples/chat/chat_server.rag.yaml`` at Mistral-7B-Instruct-v0.3
@@ -798,6 +804,123 @@ def phase_windowed(seed: int) -> dict:
     }
 
 
+# ------------------------------------------------------------------ latent
+LATENT_MODEL = {
+    'model_type': 'deepseek_v3', 'vocab_size': 512, 'hidden_size': 256,
+    'intermediate_size': 512, 'num_hidden_layers': 3,
+    'num_attention_heads': 32, 'num_key_value_heads': 32, 'head_dim': 64,
+    'qk_nope_head_dim': 128, 'qk_rope_head_dim': 64, 'qk_head_dim': 192,
+    'v_head_dim': 128, 'kv_lora_rank': 512, 'q_lora_rank': None,
+    'max_position_embeddings': 4096, 'attention_bias': False,
+    'hidden_act': 'silu', 'rms_norm_eps': 1e-6, 'first_k_dense_replace': 1,
+    'moe_layer_freq': 1, 'n_routed_experts': 4, 'num_routed_experts': 8,
+    'first_local_expert': 0, 'n_shared_experts': 2, 'num_experts_per_tok': 2,
+    'moe_intermediate_size': 128, 'n_group': 1, 'topk_group': 1,
+    'norm_topk_prob': True, 'scoring_func': 'sigmoid',
+    'topk_method': 'noaux_tc', 'routed_scaling_factor': 2.448,
+    'rope_theta': 1000000, 'rope_scaling': None, 'rope_interleave': True,
+    'tie_word_embeddings': False,
+}
+LATENT_PROMPT_TOKENS = 300  # past two chunks of 128
+LATENT_OUTPUT_TOKENS = 40
+# Token gap to the float32 reference, bf16 program: the windowed phase's
+# limit. (The benchmark cell's own, 3.2, is for a router over 128 experts,
+# where rounding changes the kept set; 2 of 8 here leave it be.)
+LATENT_GAP_LIMIT = 0.85
+
+
+def phase_latent(seed: int) -> dict:
+    """A toy model with a latent cache group through ``generate_ids``:
+    prefill past two chunks, decode, one preemption, against the reference;
+    then the paged kernel against its XLA twin on a latent plane."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmarks import reference_deepseek_v3 as reference
+    from distllm_tpu.generate.engine.engine import (
+        EngineConfig,
+        LLMEngine,
+        SamplingParams,
+    )
+    from distllm_tpu.models import deepseek_v3
+    from distllm_tpu.ops.paged_attention import (
+        ragged_paged_attention_pallas,
+        ragged_paged_attention_xla,
+    )
+
+    cfg = deepseek_v3.DeepseekV3Config.from_hf_config(LATENT_MODEL)
+    params = deepseek_v3.init_on_device(jax.random.PRNGKey(seed % 2**31), cfg)
+
+    class NoTokenizer:
+        eos_id = None
+
+    # 42 usable blocks of 16 tokens: two rows of 300 + 40 tokens need 44.
+    with _jax_cache_floor():
+        engine = LLMEngine(
+            cfg, params, NoTokenizer(),
+            EngineConfig(block_size=16, num_blocks=43, max_num_seqs=2,
+                         max_model_len=512, prefill_chunk_tokens=128,
+                         enable_prefix_cache=False, attn_backend='auto'),
+            own_params=True,
+        )
+    del params
+    check(engine.telemetry['attn_backend'] == 'pallas',
+          f"attn_backend resolved to {engine.telemetry['attn_backend']!r}")
+    pool = engine.telemetry['kv_pools']['latent']
+    check((pool['layers'], pool['block_shape']) == (3, [16, 640])
+          and engine.kv.v_pool == (), f'pool {pool}')
+    check(pool['bytes'] == 43 * 16 * 640 * 2 * 3, f"pool of {pool['bytes']} bytes")
+    engine._ewma['budget_use'] = 0.0  # admit both rows: the pool runs short
+    rng = np.random.default_rng(seed)
+    prompts = [
+        [int(t) for t in rng.integers(4, cfg.vocab_size, LATENT_PROMPT_TOKENS)]
+        for _ in range(2)
+    ]
+    before = engine.flight.total_recorded
+    out = engine.generate_ids(
+        prompts, SamplingParams(temperature=0.0, max_tokens=LATENT_OUTPUT_TOKENS)
+    )
+    records = engine.flight.snapshot()[-(engine.flight.total_recorded - before):]
+    check([len(o) for o in out] == [LATENT_OUTPUT_TOKENS] * 2,
+          f'generated {[len(o) for o in out]} tokens')
+    preempts = [r for r in records if r['kind'] == 'preempt']
+    check(len(preempts) >= 1, f'{len(preempts)} preemptions, one expected')
+    chunks = [r for r in records if r['kind'] == 'prefill']
+    check(len(chunks) >= 3, f'{len(chunks)} prefill dispatches, 3 or more expected')
+    engine.shutdown()
+    del engine
+    gc.collect()
+    params = deepseek_v3.init_on_device(jax.random.PRNGKey(seed % 2**31), cfg)
+    gaps = []
+    for prompt, tokens in zip(prompts, out):
+        ids = np.asarray([prompt + tokens[:-1]], np.int32)
+        at = len(prompt) - 1 + np.arange(len(tokens))[None]
+        logits = reference.deepseek_logits(params, LATENT_MODEL, ids, at)
+        gaps.append(float(reference.token_gaps(logits, [tokens]).max()))
+    check(max(gaps) <= LATENT_GAP_LIMIT, f'token gaps {gaps}')
+    del params
+    # The kernel against its twin: decode rows and a chunk's rows.
+    plane = jnp.asarray(rng.standard_normal((64, 16, 640)), jnp.bfloat16)
+    tables = jnp.asarray(1 + rng.permutation(63)[:60].reshape(3, 20), jnp.int32)
+    ctx = jnp.asarray([300, 17, 129], jnp.int32)
+    worst = 0.0
+    for span in (1, 16):
+        q = jnp.asarray(rng.standard_normal((3, span, 32, 640)) * 0.3, jnp.bfloat16)
+        pos = (ctx - span)[:, None] + jnp.arange(span)[None]
+        kw = dict(scale=192 ** -0.5, value_lanes=512)
+        got = ragged_paged_attention_pallas(q, plane, None, tables, ctx, pos, **kw)
+        want = ragged_paged_attention_xla(q, plane, None, tables, ctx, pos, **kw)
+        err, ok = _max_err(got, want)
+        worst = max(worst, err)
+        check(ok, f'latent kernel vs twin, span {span}: max abs err {err}')
+    return {
+        'preemptions': len(preempts), 'prefill_dispatches': len(chunks),
+        'token_gap_max_std': round(max(gaps), 4),
+        'kernel_vs_twin': round(worst, 5),
+    }
+
+
 def generator_settings(model_dir: Path) -> dict:
     """``generator_config`` of the documented start
     (examples/chat/chat_server.rag.yaml), pointed at the seed-made
@@ -1369,6 +1492,7 @@ def run(chips: int, seed: int) -> int:
             phases = (
                 ('kernels', phase_kernels), ('embed', phase_embed),
                 ('hybrid', phase_hybrid), ('windowed', phase_windowed),
+                ('latent', phase_latent),
                 ('serve', phase_serve),
             )
         for name, fn in phases:

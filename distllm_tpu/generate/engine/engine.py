@@ -760,6 +760,10 @@ class LLMEngine:
                 sharding=kv_sharding,
                 lazy=True,
                 layer_buffers=spec.layer_buffers,
+                # A latent group declares its row; a K/V group's is the
+                # model's ``num_kv_heads * head_size``.
+                row=group.stored_row,
+                value_lanes=group.value_lanes,
             )
 
         self.kv = pool(spec.paged[0], cfg.num_blocks)
@@ -1244,7 +1248,7 @@ class LLMEngine:
             self.kv.allocate()
             if self.window_kv is not None:
                 self.window_kv.allocate()
-        if len(spec.paged) > 1:
+        if len(spec.paged) > 1 or spec.latent:
             self.telemetry['kv_pools'] = {
                 group.name: {
                     'layers': group.num_layers, 'window': group.window,
@@ -1373,6 +1377,41 @@ class LLMEngine:
                         f'{setting} cannot serve a hybrid model (recurrent '
                         f'layers beside attention layers): {why}; state '
                         'snapshots are not implemented'
+                    )
+        if spec.latent:
+            if len(spec.paged) > 1 or spec.paged[0].window is not None:
+                raise ValueError(
+                    'cache groups '
+                    f'{[(g.name, g.window, g.row) for g in spec.paged]} '
+                    'cannot be served: a latent group holds whole contexts '
+                    'and stands alone; latent rows beside other groups or '
+                    'behind a window have no allocator yet'
+                )
+            refused = {
+                'kv_cache_dtype=int8': int8
+                and 'a latent row is keys and values at once, and '
+                'QuantizedKV scales a K head and a V head apart',
+                'host_kv_tier_bytes': bool(cfg.host_kv_tier_bytes)
+                and 'the host, disk and peer tiers and the .kvblock format '
+                'carry a K payload and a V payload a block',
+                'enable_prefix_cache': cfg.enable_prefix_cache
+                and 'the copy of a shared block on write indexes a stacked '
+                'K and V pool, and a latent pool is one buffer a layer',
+                'enable_mixed_batching': cfg.enable_mixed_batching
+                and 'the mixed window is the K/V family\'s program',
+                'draft_k': bool(cfg.draft_k)
+                and 'the speculative window is the K/V family\'s program',
+                'quantization': bool(cfg.quantization)
+                and 'the family\'s parameter trees have no quantized route',
+                'mesh': mesh is not None
+                and 'one latent head cannot be split over the model axis, '
+                'and the grouped expert matmul has no partitioning',
+            }
+            for setting, why in refused.items():
+                if why:
+                    raise ValueError(
+                        f'{setting} cannot serve a model with a latent '
+                        f'cache group: {why}'
                     )
         if not spec.windowed:
             return
@@ -1531,17 +1570,15 @@ class LLMEngine:
         def spec(tree):
             return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
 
-        pools = jax.tree.map(
-            lambda pool: pool.spec(),
-            self._group_tables(self.kv, self.window_kv),
-        )
+        groups = self._group_tables(self.kv, self.window_kv)
+        pools = jax.tree.map(lambda pool: pool.spec(), groups)
         shapes = (
             spec(self.params),
             sds((b,), i32),  # ids
             sds((b,), i32),  # positions
             sds((b,), i32),  # context_lens
             pools,
-            pools,
+            jax.tree.map(lambda pool: pool.spec('v'), groups),
             self._group_tables(sds((b, self.max_blocks_per_seq), i32)),
             sds((b,), i32),  # steps_left
             sds((b,), f32),
@@ -2083,11 +2120,15 @@ class LLMEngine:
         """
         if self._cost_model is None:
             return
-        if self.state_pool is not None or self.window_kv is not None:
+        if (
+            self.state_pool is not None or self.window_kv is not None
+            or self.cache_spec.latent
+        ):
             self.telemetry.setdefault(
                 'xla_cost_skipped', 'hybrid programs are not priced'
                 if self.state_pool is not None
-                else 'programs over several cache groups are not priced'
+                else 'programs over several cache groups, or over a latent '
+                'one, are not priced'
             )
             return
         cfg = self.config
@@ -5088,14 +5129,15 @@ class LLMEngine:
         return {'state_slot': self.sched.slot(request.request_id)}
 
     def _kv_ends_field(self, request: Request) -> dict:
-        """For a model with a windowed cache group: the ids of two blocks
-        of the full-context group the request held when it finished, its
+        """For a model with a windowed or a latent cache group: the ids of
+        two blocks of the full-context group the request held when it
+        finished, its
         first and the one that holds the last position it wrote (its last
         token was never fed). Both are freed right after this record; the
         pool keeps what a freed block held until its next holder writes it,
         which is how the benchmark's check reads the K/V a finished request
         left."""
-        if self.window_kv is None:
+        if self.window_kv is None and not self.cache_spec.latent:
             return {}
         row = self.sched.block_row(request.request_id)
         written = len(request.prompt_ids) + len(request.output_ids) - 1

@@ -979,12 +979,16 @@ class _PoolView:
     int8 pool). Only the blocks asked for are gathered (one device gather,
     as indexing a layer's buffer was; they are unfolded on the host), never
     a buffer: a pool may fill the device. The programs' operands are
-    ``k_pool`` / ``v_pool``."""
+    ``k_pool`` / ``v_pool``. ``lanes`` keeps a row's leading lanes: a
+    latent pool's ``.v`` is its one plane read so."""
 
-    def __init__(self, cache: 'PagedKVCache', pool, layer=None) -> None:
+    def __init__(
+        self, cache: 'PagedKVCache', pool, layer=None, lanes=None
+    ) -> None:
         self._cache = cache
         self._pool = pool
         self._layer = layer
+        self._lanes = lanes
 
     def __len__(self) -> int:
         return self._cache.shape[0 if self._layer is None else 1]
@@ -994,9 +998,11 @@ class _PoolView:
 
         if self._layer is None:
             layer = range(len(self))[index]
-            return _PoolView(self._cache, self._pool, layer)
+            return _PoolView(self._cache, self._pool, layer, self._lanes)
         blocks = self._gather(self._pool, self._layer, np.asarray(index))
         num_kv_heads = self._cache.shape[3]
+        if self._lanes is not None:
+            return np.asarray(blocks)[..., None, :self._lanes]
         if self._cache.quantized:
             return QuantizedKV(
                 unfold_heads(np.asarray(blocks.data), num_kv_heads),
@@ -1032,6 +1038,12 @@ class PagedKVCache:
     jitted engine path that treats the pool as an opaque carry (scan,
     donation, COW gathers) works unchanged; only code that quantizes,
     dequantizes, or inspects ``.shape`` dispatches on the container.
+
+    With ``row`` (a latent group, ``models.common.PagedGroup``) a layer
+    holds ONE plane of ``[num_blocks, block_size, row]`` (``row`` already in
+    whole lane tiles): ``k_pool`` is that plane, ``v_pool`` is ``()``, and
+    the host's ``v`` view reads the first ``value_lanes`` lanes of ``k``'s
+    rows. ``shape`` is the plane's, one KV head of ``row``.
     """
 
     def __init__(
@@ -1045,7 +1057,13 @@ class PagedKVCache:
         sharding=None,
         lazy: bool = False,
         layer_buffers: bool = False,
+        row: int | None = None,
+        value_lanes: int | None = None,
     ) -> None:
+        self.value_lanes = value_lanes if row is not None else None
+        if row is not None:
+            num_kv_heads, head_dim = 1, row
+        self.latent = row is not None
         self.shape = (num_layers, num_blocks, block_size, num_kv_heads, head_dim)
         self.pool_shape = (
             num_layers, num_blocks, block_size, num_kv_heads * head_dim
@@ -1056,10 +1074,12 @@ class PagedKVCache:
         self.layer_buffers = layer_buffers
         self.dtype = jnp.dtype(dtype)
         self.quantized = self.dtype == jnp.dtype(jnp.int8)
-        if layer_buffers and (self.quantized or sharding is not None):
+        if (layer_buffers or self.latent) and (
+            self.quantized or sharding is not None
+        ):
             raise ValueError(
-                'a pool of one buffer a layer has no int8 and no sharded '
-                'form yet'
+                'a pool of one buffer a layer, or of latent rows, has no '
+                'int8 and no sharded form yet'
             )
         # Symmetric per-block-per-KV-head scales: one fp32 per (layer,
         # block, kv head), for K and V independently (the two pool arrays
@@ -1080,6 +1100,8 @@ class PagedKVCache:
 
     @property
     def v(self) -> _PoolView:
+        if self.latent:
+            return _PoolView(self, self.k_pool, lanes=self.value_lanes)
         return _PoolView(self, self.v_pool)
 
     def _zeros(self):
@@ -1116,12 +1138,15 @@ class PagedKVCache:
         from distllm_tpu.observability import instruments
 
         self.k_pool = self._zeros()
-        self.v_pool = self._zeros()
+        self.v_pool = () if self.latent else self._zeros()
         instruments.KV_HBM_BYTES.set(self.hbm_bytes)
 
-    def spec(self):
+    def spec(self, plane: str = 'k'):
         """Shape/dtype pytree for one pool array (AOT compilation input):
-        a bare ShapeDtypeStruct, or a QuantizedKV of them when int8."""
+        a bare ShapeDtypeStruct, or a QuantizedKV of them when int8; ``()``
+        for the V plane a latent pool does not have."""
+        if self.latent and plane == 'v':
+            return ()
         if self.layer_buffers:
             return (
                 jax.ShapeDtypeStruct(self.pool_shape[1:], self.dtype),

@@ -21,6 +21,7 @@ def decoder_families() -> dict:
     (``embed/encoders/auto.py``) — a new decoder lands in one place.
     """
     from distllm_tpu.models import (
+        deepseek_v3,
         gemma,
         granite_hybrid,
         laguna,
@@ -39,6 +40,7 @@ def decoder_families() -> dict:
             granite_hybrid.GraniteHybridConfig, granite_hybrid
         ),
         'laguna': (laguna.LagunaConfig, laguna),
+        'deepseek_v3': (deepseek_v3.DeepseekV3Config, deepseek_v3),
     }
 
 

@@ -24,11 +24,26 @@ class PagedGroup:
     table. ``window`` None: a query sees its whole context and a sequence
     holds blocks for all of it. ``window`` w: a query at position ``p`` sees
     keys ``p - w < j <= p``, and the sequence holds only the blocks such a
-    query can still see (``generate/engine/kv_cache.WindowBlocks``)."""
+    query can still see (``generate/engine/kv_cache.WindowBlocks``).
+
+    ``row`` makes the group LATENT (``models/deepseek_v3.py``): a layer
+    holds ONE plane whose token rows are ``row`` values wide, and a token's
+    value is the first ``value_lanes`` lanes of its row, so there is no V
+    pool. ``row`` None, the default, is a K pool and a V pool whose rows
+    are ``num_kv_heads * head_dim`` wide."""
 
     name: str
     num_layers: int
     window: int | None = None
+    row: int | None = None
+    value_lanes: int | None = None
+
+    @property
+    def stored_row(self) -> int | None:
+        """``row`` in whole 128-lane tiles, as the pool stores it: the
+        TPU's tiled layout holds a 576-wide minor dim in 640 lanes whatever
+        the shape says, and the kernel copies pages by whole tiles."""
+        return None if self.row is None else -(-self.row // 128) * 128
 
 
 @dataclass(frozen=True)
@@ -64,6 +79,11 @@ class CacheSpec:
     @property
     def windowed(self) -> tuple[PagedGroup, ...]:
         return tuple(g for g in self.paged if g.window is not None)
+
+    @property
+    def latent(self) -> bool:
+        """Whether a group declares a row of its own (no V pool)."""
+        return any(g.row is not None for g in self.paged)
 
 
 def layer_norm(

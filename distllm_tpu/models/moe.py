@@ -42,6 +42,8 @@ def routed_experts(  # distlint: traced
     counted: jnp.ndarray | None = None,  # [T] bool: rows that count
     layer=None,
     routed_scale: float = 1.0,
+    scoring: str = 'softmax',
+    select_bias: jnp.ndarray | None = None,  # [E_routed] float32
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """``sum_e g_e expert_e(x)`` over the held experts among a token's top-k.
 
@@ -59,7 +61,17 @@ def routed_experts(  # distlint: traced
     ``routed_scale`` multiplies the normalised gates (a family's
     ``routed_scaling_factor``). A shared expert is the caller's: every chip
     of the expert axis computes it alike, so it is added once, outside.
+
+    ``scoring='sigmoid'`` (DeepSeek-V3's ``noaux_tc`` router): an expert's
+    score is ``sigmoid(logit)``, the k kept are the largest of ``score +
+    select_bias`` (the bias chooses and never weighs), and the gates are
+    the kept SCORES over their sum. ``'softmax'`` with no bias is softmax
+    over the k kept logits, as it was.
     """
+    if scoring not in ('softmax', 'sigmoid'):
+        raise ValueError(f'scoring must be softmax or sigmoid, got {scoring!r}')
+    if scoring == 'softmax' and select_bias is not None:
+        raise ValueError('a selection bias is implemented for sigmoid scoring')
     dtype = x.dtype
     tokens, k = x.shape[0], experts_per_token
     gate, up, down = _bank(gate, dtype), _bank(up, dtype), _bank(down, dtype)
@@ -73,8 +85,17 @@ def routed_experts(  # distlint: traced
             'th,he->te', x.astype(jnp.float32),
             router_kernel.astype(jnp.float32),
         )
-        top_logits, top_idx = jax.lax.top_k(logits, k)
-        weights = jax.nn.softmax(top_logits, axis=-1)  # [T, k] float32
+        if scoring == 'softmax':
+            top_logits, top_idx = jax.lax.top_k(logits, k)
+            weights = jax.nn.softmax(top_logits, axis=-1)  # [T, k] float32
+        else:
+            scores = jax.nn.sigmoid(logits)
+            chosen_by = scores if select_bias is None else (
+                scores + select_bias.astype(jnp.float32)
+            )
+            _, top_idx = jax.lax.top_k(chosen_by, k)
+            kept = jnp.take_along_axis(scores, top_idx, axis=-1)
+            weights = kept / (kept.sum(axis=-1, keepdims=True) + 1e-20)
         if routed_scale != 1.0:
             weights = weights * routed_scale
         local = top_idx - first_expert

@@ -33,6 +33,11 @@ formulation, which has two implementations behind one backend selector
   ``context_lens``, past the row's last query, or before the
   sliding-window start) are skipped: no DMA, no compute.
 
+A LATENT pool (``models.common.PagedGroup.row``) is one plane: the
+callers pass ``v_cache=None`` and ``value_lanes``, keys are the whole rows
+and a token's value is the first ``value_lanes`` lanes of its row, so the
+kernel copies a page across HBM once and the writers write one plane.
+
 Both handle GQA (query heads grouped natively over KV heads), per-row query
 spans with ``q_lens`` padding masks, static or TRACED sliding windows
 (gemma2 alternating layers), ``logit_softcap``, custom score scales, and
@@ -58,8 +63,10 @@ from distllm_tpu.observability.instruments import ATTN_BACKEND_LABELS
 # requirement is only head_dim % 128 == 0 (Mosaic DMA alignment, checked in
 # paged_attention_pallas), but 'auto' backend selection routes through
 # supported_head_dim so untested shapes never auto-enable the kernel —
-# widen this tuple when a new shape gains AOT coverage.
-TESTED_HEAD_DIMS = (128,)
+# widen this tuple when a new shape gains AOT coverage. 640 is a LATENT
+# row (``models/deepseek_v3.py``: 512 latent + 64 rotary values in five
+# whole lane tiles, one KV head, values its first 512 lanes).
+TESTED_HEAD_DIMS = (128, 640)
 
 
 def supported_head_dim(head_dim: int) -> bool:
@@ -234,6 +241,7 @@ def paged_attention_xla(  # distlint: traced
     sliding_window: 'int | jnp.ndarray | None' = None,
     scale: float | None = None,
     logit_softcap: float | None = None,
+    value_lanes: int | None = None,
 ) -> jnp.ndarray:
     """Reference implementation: gather blocks then masked attention.
 
@@ -255,9 +263,12 @@ def paged_attention_xla(  # distlint: traced
     k = _gather_kv_blocks(k_cache, block_tables, head_dim).reshape(
         b, max_blocks * block_size, num_kv_heads, head_dim
     )
-    v = _gather_kv_blocks(v_cache, block_tables, head_dim).reshape(
-        b, max_blocks * block_size, num_kv_heads, head_dim
-    )
+    if v_cache is None:  # latent rows: values are lanes of the keys
+        v = k[..., :value_lanes]
+    else:
+        v = _gather_kv_blocks(v_cache, block_tables, head_dim).reshape(
+            b, max_blocks * block_size, num_kv_heads, head_dim
+        )
 
     qg = q.reshape(b, num_kv_heads, group, head_dim).astype(jnp.float32)
     scores = jnp.einsum('bkgd,btkd->bkgt', qg, k.astype(jnp.float32))
@@ -281,7 +292,7 @@ def paged_attention_xla(  # distlint: traced
     scores = jnp.where(valid[:, None, None, :], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum('bkgt,btkd->bkgd', probs, v.astype(jnp.float32))
-    return out.reshape(b, num_heads, head_dim).astype(q.dtype)
+    return out.reshape(b, num_heads, v.shape[-1]).astype(q.dtype)
 
 
 def ragged_paged_attention_xla(  # distlint: traced
@@ -295,6 +306,7 @@ def ragged_paged_attention_xla(  # distlint: traced
     sliding_window: 'int | jnp.ndarray | None' = None,
     scale: float | None = None,
     logit_softcap: float | None = None,
+    value_lanes: int | None = None,
 ) -> jnp.ndarray:
     """Ragged per-row-query-length attention over paged KV — the shared
     op of prefix-cache tail prefill, chunked prefill, mixed
@@ -335,9 +347,12 @@ def ragged_paged_attention_xla(  # distlint: traced
     k = _gather_kv_blocks(k_cache, block_tables, head_dim).reshape(
         b, max_blocks * block_size, num_kv_heads, head_dim
     )
-    v = _gather_kv_blocks(v_cache, block_tables, head_dim).reshape(
-        b, max_blocks * block_size, num_kv_heads, head_dim
-    )
+    if v_cache is None:  # latent rows: values are lanes of the keys
+        v = k[..., :value_lanes]
+    else:
+        v = _gather_kv_blocks(v_cache, block_tables, head_dim).reshape(
+            b, max_blocks * block_size, num_kv_heads, head_dim
+        )
     qg = q.reshape(b, s, num_kv_heads, group, head_dim).astype(jnp.float32)
     scores = jnp.einsum('bskgd,btkd->bkgst', qg, k.astype(jnp.float32))
     scores = scores * jnp.float32(
@@ -367,7 +382,7 @@ def ragged_paged_attention_xla(  # distlint: traced
     scores = jnp.where(valid[:, None, None, :, :], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum('bkgst,btkd->bskgd', probs, v.astype(jnp.float32))
-    return out.reshape(b, s, num_heads, head_dim).astype(q.dtype)
+    return out.reshape(b, s, num_heads, v.shape[-1]).astype(q.dtype)
 
 
 def paged_prefill_attention_xla(  # distlint: traced
@@ -444,6 +459,7 @@ def _ragged_paged_attn_kernel(
     scale: float,
     logit_softcap: float | None,
     quantized: bool = False,
+    value_lanes: int | None = None,
 ):
     """Grid (B, q_tiles, kv_chunks): one row × one query tile × one chunk
     of KV pages per step.
@@ -464,11 +480,24 @@ def _ragged_paged_attn_kernel(
     decode-only kernel), the chunk's probabilities are folded into the
     fp32 accumulator with the usual ``exp(m_prev - m_new)`` correction,
     and no ``[.., S, T]`` score tensor ever exists.
+
+    With ``value_lanes`` (a latent pool) there is no ``v_cache_ref`` and no
+    ``v_buf`` among the operands: a head's values are the first
+    ``value_lanes`` lanes of its key band, read from the page copy the
+    scores were made from, and ``out_ref``/``acc_ref`` are ``value_lanes``
+    wide.
     """
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    if quantized:
+    latent = value_lanes is not None
+    if latent:
+        (
+            block_tables_ref, context_lens_ref, q_start_ref, q_lens_ref,
+            window_ref, q_ref, k_cache_ref, out_ref, k_buf, sems, acc_ref,
+            m_ref, l_ref,
+        ) = refs
+    elif quantized:
         (
             block_tables_ref, context_lens_ref, q_start_ref, q_lens_ref,
             window_ref, q_ref, k_cache_ref, v_cache_ref, k_scale_ref,
@@ -525,11 +554,12 @@ def _ragged_paged_attn_kernel(
                 k_buf.at[slot, rows_at],
                 sems.at[slot, p, 0],
             ).start()
-            pltpu.make_async_copy(
-                v_cache_ref.at[page_id],
-                v_buf.at[slot, rows_at],
-                sems.at[slot, p, 1],
-            ).start()
+            if not latent:
+                pltpu.make_async_copy(
+                    v_cache_ref.at[page_id],
+                    v_buf.at[slot, rows_at],
+                    sems.at[slot, p, 1],
+                ).start()
             if quantized:
                 # The page's scale row rides the same double-buffered
                 # prefetch: a 128-lane fp32 row per page (512 B) next to
@@ -553,11 +583,12 @@ def _ragged_paged_attn_kernel(
                 k_buf.at[slot, rows_at],
                 sems.at[slot, p, 0],
             ).wait()
-            pltpu.make_async_copy(
-                v_cache_ref.at[0],
-                v_buf.at[slot, rows_at],
-                sems.at[slot, p, 1],
-            ).wait()
+            if not latent:
+                pltpu.make_async_copy(
+                    v_cache_ref.at[0],
+                    v_buf.at[slot, rows_at],
+                    sems.at[slot, p, 1],
+                ).wait()
             if quantized:
                 pltpu.make_async_copy(
                     k_scale_ref.at[0],
@@ -666,7 +697,10 @@ def _ragged_paged_attn_kernel(
             l_ref[h] = l_ref[h] * correction + jnp.sum(
                 probs, axis=-1, keepdims=True
             )
-            vh = v_buf[slot, :, h * head_dim:(h + 1) * head_dim]  # [C, Hd]
+            if latent:  # the values: leading lanes of the key band
+                vh = k_buf[slot, :, h * head_dim:h * head_dim + value_lanes]
+            else:
+                vh = v_buf[slot, :, h * head_dim:(h + 1) * head_dim]  # [C, Hd]
             if quantized:
                 # probs · (v_int8 · s) == (probs · s_per_key) · v_int8:
                 # fold V's per-page scale into the probabilities (one
@@ -723,6 +757,7 @@ def ragged_paged_attention_pallas(
     pages_per_chunk: int | None = None,
     span_tile: int | None = None,
     interpret: bool = False,
+    value_lanes: int | None = None,
 ) -> jnp.ndarray:
     """Fused Pallas TPU kernel twin of :func:`ragged_paged_attention_xla`.
 
@@ -755,7 +790,16 @@ def ragged_paged_attention_pallas(
     from jax.experimental.pallas import tpu as pltpu
 
     quantized = isinstance(k_cache, QuantizedKV)
-    k_data, v_data = _kv_data(k_cache), _kv_data(v_cache)
+    latent = v_cache is None
+    if latent and (quantized or value_lanes is None or value_lanes % 128):
+        raise ValueError(
+            'a latent pool (v_cache None) is read as bare rows whose first '
+            f'value_lanes lanes (whole 128-lane tiles, got {value_lanes}) '
+            'are the values; it has no int8 form'
+        )
+    k_data = _kv_data(k_cache)
+    v_data = None if latent else _kv_data(v_cache)
+    value_dim = value_lanes if latent else q.shape[-1]
     b, s, num_heads, head_dim = q.shape
     _, block_size, folded = k_data.shape
     num_kv_heads = folded // head_dim
@@ -784,14 +828,24 @@ def ragged_paged_attention_pallas(
             "(EngineConfig.block_size) or attn_backend='xla'"
         )
     if pages_per_chunk is None:
-        pages_per_chunk = max(1, 128 // block_size)
+        # A latent plane is ONE head whose rows are 5 lane tiles wide, with
+        # every query head on it: a grid step of 128 keys is too little
+        # work beside the step's own cost, and a row's pages are fetched
+        # again for every query tile. On the chip at Kanana's widths (PR
+        # 32, PERF.md section 6): decode rows read 139 GB/s at 128 keys a
+        # step and 259 GB/s at 1024; a 512-query span at context 8448 ran
+        # 62 TFLOP/s at (512 rows, 128 keys) and 132 at (1024 rows, 512
+        # keys); 2048 rows do not fit VMEM.
+        chunk_tokens = 128 if not latent else 1024 if s == 1 else 512
+        pages_per_chunk = max(1, chunk_tokens // block_size)
     pages_per_chunk = min(pages_per_chunk, max_blocks)
     num_chunks = -(-max_blocks // pages_per_chunk)
     if span_tile is None:
         # ~512 post-GQA query rows per tile keeps q/out/acc + the m/l
         # scratch + double-buffered KV pages comfortably inside VMEM at
-        # 7B dims while still feeding the MXU full tiles.
-        span_tile = max(1, 512 // group)
+        # 7B dims while still feeding the MXU full tiles (1024 rows on a
+        # latent plane's one head, above).
+        span_tile = max(1, (1024 if latent else 512) // group)
         # A power of two: with 6 queries a KV head 85 positions would be
         # 510 rows, which Mosaic refuses (a block's rows must be a
         # multiple of 8), and the spans' pow2 buckets must divide evenly.
@@ -858,16 +912,14 @@ def ragged_paged_attention_pallas(
             None if logit_softcap is None else float(logit_softcap)
         ),
         quantized=quantized,
+        value_lanes=value_lanes if latent else None,
     )
     kv_scratch = [
         pltpu.VMEM(
             (2, pages_per_chunk * block_size, folded),
-            k_data.dtype,
-        ),
-        pltpu.VMEM(
-            (2, pages_per_chunk * block_size, folded),
-            v_data.dtype,
-        ),
+            data.dtype,
+        )
+        for data in ((k_data,) if latent else (k_data, v_data))
     ]
     if quantized:
         kv_scratch += [
@@ -882,18 +934,18 @@ def ragged_paged_attention_pallas(
                 (None, num_kv_heads, rows, head_dim),
                 lambda i, qi, j, *_: (i, 0, qi, 0),
             ),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ] + [pl.BlockSpec(memory_space=pl.ANY)] * len(extra_operands),
+        ] + [pl.BlockSpec(memory_space=pl.ANY)] * (
+            (1 if latent else 2) + len(extra_operands)
+        ),
         out_specs=pl.BlockSpec(
-            (None, num_kv_heads, rows, head_dim),
+            (None, num_kv_heads, rows, value_dim),
             lambda i, qi, j, *_: (i, 0, qi, 0),
         ),
         scratch_shapes=kv_scratch + [
             pltpu.SemaphoreType.DMA(
-                (2, pages_per_chunk, 4 if quantized else 2)
+                (2, pages_per_chunk, 4 if quantized else 1 if latent else 2)
             ),
-            pltpu.VMEM((num_kv_heads, rows, head_dim), jnp.float32),
+            pltpu.VMEM((num_kv_heads, rows, value_dim), jnp.float32),
             pltpu.VMEM((num_kv_heads, rows, 128), jnp.float32),
             pltpu.VMEM((num_kv_heads, rows, 128), jnp.float32),
         ],
@@ -902,7 +954,7 @@ def ragged_paged_attention_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(
-            (b, num_kv_heads, s * group, head_dim), q.dtype
+            (b, num_kv_heads, s * group, value_dim), q.dtype
         ),
         interpret=interpret,
     )(
@@ -912,14 +964,13 @@ def ragged_paged_attention_pallas(
         q_lens.astype(jnp.int32),
         window_arr,
         qg,
-        k_data,
-        v_data,
+        *((k_data,) if latent else (k_data, v_data)),
         *extra_operands,
     )
     return (
-        out.reshape(b, num_kv_heads, s, group, head_dim)
+        out.reshape(b, num_kv_heads, s, group, value_dim)
         .transpose(0, 2, 1, 3, 4)
-        .reshape(b, s, num_heads, head_dim)
+        .reshape(b, s, num_heads, value_dim)
     )
 
 
@@ -936,6 +987,7 @@ def ragged_paged_attention(
     logit_softcap: float | None = None,
     *,
     backend: str = 'xla',
+    value_lanes: int | None = None,
 ) -> jnp.ndarray:
     """THE serving attention callsite: dispatch one ragged paged span
     batch through the selected backend.
@@ -956,6 +1008,7 @@ def ragged_paged_attention(
             q, k_cache, v_cache, block_tables, context_lens, q_positions,
             q_lens=q_lens, sliding_window=sliding_window, scale=scale,
             logit_softcap=logit_softcap, interpret=backend == 'interpret',
+            value_lanes=value_lanes,
         )
     if backend != 'xla':
         raise ValueError(
@@ -966,7 +1019,7 @@ def ragged_paged_attention(
     return ragged_paged_attention_xla(
         q, k_cache, v_cache, block_tables, context_lens, q_positions,
         q_lens=q_lens, sliding_window=sliding_window, scale=scale,
-        logit_softcap=logit_softcap,
+        logit_softcap=logit_softcap, value_lanes=value_lanes,
     )
 
 
@@ -1056,7 +1109,9 @@ def write_token_kv(  # distlint: traced
     """Scatter one new token's K/V per sequence into its paged block
     (quantizing at write time for int8 :class:`QuantizedKV` pools). The
     new rows are folded to the pool's ``num_kv_heads * head_dim`` rows;
-    the pool is only scattered into."""
+    the pool is only scattered into. A latent pool is one plane:
+    ``v_cache`` and ``new_v`` None, ``new_k [B, 1, row]`` the tokens' rows,
+    and None comes back in V's place."""
     block_size = _kv_data(k_cache).shape[1]
     batch = positions.shape[0]
     block_ids = block_tables[jnp.arange(batch), positions // block_size]
@@ -1068,6 +1123,8 @@ def write_token_kv(  # distlint: traced
     k_cache = k_cache.at[block_ids, offsets].set(
         fold_heads(new_k).astype(k_cache.dtype)
     )
+    if v_cache is None:  # distlint: disable=traced-python-branch -- a latent pool's absent V plane is static
+        return k_cache, None
     v_cache = v_cache.at[block_ids, offsets].set(
         fold_heads(new_v).astype(v_cache.dtype)
     )
@@ -1090,6 +1147,7 @@ def write_chunk_kv(  # distlint: traced
     chunk rows riding mixed serving windows): ``valid`` carries the
     per-row raggedness — invalid positions write to the reserved trash
     block 0, the same pad-safety contract as :func:`write_prefill_kv`.
+    A latent pool is one plane (``v_cache`` and ``new_v`` None).
     """
     block_size = _kv_data(k_cache).shape[1]
     b, s = positions.shape
@@ -1107,10 +1165,12 @@ def write_chunk_kv(  # distlint: traced
     flat_blocks = block_ids.reshape(-1)
     flat_offsets = offsets.reshape(-1)
     k_flat = fold_heads(new_k).reshape(b * s, -1)
-    v_flat = fold_heads(new_v).reshape(b * s, -1)
     k_cache = k_cache.at[flat_blocks, flat_offsets].set(
         k_flat.astype(k_cache.dtype)
     )
+    if v_cache is None:  # distlint: disable=traced-python-branch -- a latent pool's absent V plane is static
+        return k_cache, None
+    v_flat = fold_heads(new_v).reshape(b * s, -1)
     v_cache = v_cache.at[flat_blocks, flat_offsets].set(
         v_flat.astype(v_cache.dtype)
     )
@@ -1201,6 +1261,7 @@ def write_prefill_kv(  # distlint: traced
     is reserved by the allocator (never handed to a sequence), so garbage
     writes land there harmlessly. Clamping to a valid slot instead would race
     real data through XLA's nondeterministic duplicate-index scatter.
+    A latent pool is one plane (``v_cache`` and ``v_seq`` None).
     """
     seq_len = k_seq.shape[0]
     block_size = _kv_data(k_cache).shape[1]
@@ -1216,6 +1277,8 @@ def write_prefill_kv(  # distlint: traced
     k_cache = k_cache.at[block_ids, offsets].set(
         fold_heads(k_seq).astype(k_cache.dtype)
     )
+    if v_cache is None:  # distlint: disable=traced-python-branch -- a latent pool's absent V plane is static
+        return k_cache, None
     v_cache = v_cache.at[block_ids, offsets].set(
         fold_heads(v_seq).astype(v_cache.dtype)
     )

@@ -588,6 +588,69 @@ def test_laguna_chunk_prefill_reads_the_pools_as_they_lie(v5e, laguna_cell):
     _assert_pools_go_to_the_kernel_as_they_lie(compiled, buffers)
 
 
+# ---- a latent pool's planes go to the kernel as they lie (PR 32) ----
+
+@pytest.fixture(scope='module')
+def kanana_cell(v5e):
+    """The cell's configuration cut to three layers (the dense one and two
+    sparse), the parameters and the planes at the cell's sizes."""
+    import json
+    from pathlib import Path
+
+    from distllm_tpu.models import deepseek_v3
+
+    root = Path(__file__).resolve().parents[1]
+    hf = json.loads((root / 'benchmarks/configs/kanana-2-30b-a3b.json').read_text())
+    hf['num_hidden_layers'] = 3
+    cfg = deepseek_v3.DeepseekV3Config.from_hf_config(hf)
+    shapes = jax.eval_shape(
+        lambda: deepseek_v3.init_on_device(jax.random.PRNGKey(0), cfg)
+    )
+    params = jax.tree.map(lambda a: v5e(a.shape, a.dtype), shapes)
+    plane = (hf['engine']['num_blocks'], 16, cfg.stored_row)
+    return deepseek_v3, cfg, params, (v5e(plane, jnp.bfloat16),) * 3, plane, hf['engine']
+
+
+def test_decode_window_reads_the_planes_as_they_lie(v5e, kanana_cell):
+    """No op of the decode window has a whole plane as its result but the
+    in-place write, and the kernel reads the planes themselves."""
+    deepseek_v3, cfg, params, planes, plane, engine = kanana_cell
+    b, i32, f32 = engine['max_num_seqs'], jnp.int32, jnp.float32
+
+    def window_fn(params, ids, pos, ctx, k, v, bt, steps_left, *sampling):
+        return deepseek_v3.decode_loop(
+            params, cfg, ids, pos, k, v, bt, ctx, steps_left, *sampling,
+            num_steps=8, attn_backend='pallas', max_table_positions=8448,
+        )
+
+    compiled = jax.jit(window_fn, donate_argnums=(4, 5)).lower(
+        params, v5e((b,), i32), v5e((b,), i32), v5e((b,), i32), planes, (),
+        v5e((b, 528), i32), v5e((b,), i32), v5e((b,), f32), v5e((b,), f32),
+        v5e((b,), f32), v5e((b,), i32), v5e((b,), jnp.uint32),
+    ).compile()
+    _assert_pools_go_to_the_kernel_as_they_lie(compiled, [plane])
+    # the decode calls of the kernel, as the roofline metric's pattern
+    # names them: [rows, 1 KV head, 32 queries, 512 value lanes]
+    assert f'bf16[{b},1,32,512]' in compiled.as_text()
+
+
+def test_chunk_prefill_reads_the_planes_as_they_lie(v5e, kanana_cell):
+    """The ``(512, 4)`` program: four rows of a 512-token span, 16384
+    queries on the one KV head a row."""
+    deepseek_v3, cfg, params, planes, plane, _ = kanana_cell
+    i32 = jnp.int32
+    compiled = jax.jit(
+        lambda params, ids, pos, k, v, bt, ctx, tails: deepseek_v3.prefill_paged(
+            params, cfg, ids, pos, k, v, bt, ctx, tails,
+            max_table_positions=8448, attn_backend='pallas',
+        ), donate_argnums=(3, 4),
+    ).lower(
+        params, v5e((4, 512), i32), v5e((4, 512), i32), planes, (),
+        v5e((4, 528), i32), v5e((4,), i32), v5e((4,), i32),
+    ).compile()
+    _assert_pools_go_to_the_kernel_as_they_lie(compiled, [plane])
+
+
 def test_mistral_decode_window_reshapes_no_plane(v5e):
     """One layer's worth of the 7B decode window over a stacked pool: the
     layer's plane is still sliced out and written back (the next issue's),

@@ -81,6 +81,8 @@ def test_config_reads_the_published_keys():
         ('full', 10, None), ('window', 30, 512),
     ]
     assert spec.state is None and not spec.dense_prefill and spec.layer_buffers
+    # K/V groups both: neither declares a row of its own.
+    assert not spec.latent and all(g.stored_row is None for g in spec.paged)
 
 
 def test_benchmark_configuration_is_the_published_one_but_for_its_cut():

@@ -593,6 +593,10 @@ def test_mistral_pool_and_programs_are_what_they_were():
     ]
     assert engine.window_kv is None and engine.window_blocks is None
     assert 'kv_pools' not in engine.telemetry
+    # The default declaration: K and V rows of num_kv_heads x head_dim, no
+    # row of the group's own (a latent group's), a V pool like the K pool.
+    assert not engine.cache_spec.latent and engine.cache_spec.paged[0].row is None
+    assert not engine.kv.latent and engine.kv.v_pool.shape == engine.kv.k_pool.shape
     assert engine.kv.k_pool.shape == engine.kv.pool_shape  # stacked, not a buffer a layer
     assert engine._pools() == (engine.kv.k_pool, engine.kv.v_pool)
     assert engine._group_tables('tables') == 'tables'
