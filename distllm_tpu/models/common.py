@@ -65,8 +65,9 @@ class CacheSpec:
     fresh prompts; without it every prefill takes the paged route.
     ``layer_buffers``: a group's ``k_cache`` and ``v_cache`` are tuples of
     one ``[num_blocks, block_size, N_kv * Hd]`` buffer a layer, which the
-    programs write whole and in place, and not one ``[L, ...]`` array whose
-    layers they slice out and write back.
+    programs write in place, and not one ``[L, ...]`` array (which its
+    programs hand to the writers and the kernel whole, with the layer
+    whose pages are meant: ``ops.paged_attention``).
     """
 
     paged: tuple[PagedGroup, ...]

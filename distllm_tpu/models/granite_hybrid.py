@@ -43,7 +43,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from distllm_tpu.models import common
-from distllm_tpu.models.mistral import _kv_layer, _kv_layer_update
 from distllm_tpu.models.moe import routed_experts
 from distllm_tpu.utils import BaseConfig
 
@@ -689,19 +688,19 @@ def prefill_paged(  # distlint: traced
         x, k_cache, v_cache = carry
         li = xs
         lp = _layer_at(params, 'attention', li)
-        k_l, v_l = _kv_layer(k_cache, li), _kv_layer(v_cache, li)
         q, k, v = _qkv(_norm(x, lp['ln']['scale'], cfg), lp, cfg)
-        k_l, v_l = write_chunk_kv(k_l, v_l, k, v, block_tables, positions, valid)
+        # the stacked pools whole, with the layer whose pages are meant
+        k_cache, v_cache = write_chunk_kv(
+            k_cache, v_cache, k, v, block_tables, positions, valid, layer=li
+        )
         attn = ragged_paged_attention(
-            q, k_l, v_l, block_tables, context_lens, positions,
+            q, k_cache, v_cache, block_tables, context_lens, positions,
             q_lens=tail_lens, scale=cfg.attention_multiplier,
-            backend=attn_backend,
+            backend=attn_backend, layer=li,
         )
         x, _ = _finish_layer(
             x, _attn_out(attn, lp, cfg), lp, cfg, valid, params['attention'], li
         )
-        k_cache = _kv_layer_update(k_cache, k_l, li)
-        v_cache = _kv_layer_update(v_cache, v_l, li)
         return (x, k_cache, v_cache), None
 
     for kind, first, count in cfg.layer_runs():
@@ -715,9 +714,9 @@ def prefill_paged(  # distlint: traced
             state = _scatter_state(state, 'ssm', first, ssm, slots)
             state = _scatter_state(state, 'conv', first, conv, slots)
         elif count == 1:
-            # A static index: a traced one would copy the layer's whole KV
-            # plane out of the pool and back (0.27 GB each way at 8192
-            # blocks), and the published pattern has no longer run.
+            # No loop for a run of one, which is all the published pattern
+            # has: the layer's weights are a static slice of their stacks.
+            # (The K/V pool is addressed by layer either way, never sliced.)
             (x, k_cache, v_cache), _ = attn_layer((x, k_cache, v_cache), first)
         else:
             (x, k_cache, v_cache), _ = jax.lax.scan(
@@ -759,24 +758,23 @@ def _decode_core(
                 normed, lp, cfg, ssms[i], convs[i], live
             )
         else:
-            k_l, v_l = _kv_layer(k_cache, i), _kv_layer(v_cache, i)
             q, k, v = _qkv(normed, lp, cfg)
-            k_l, v_l = write_token_kv(k_l, v_l, k, v, block_tables, positions)
+            k_cache, v_cache = write_token_kv(
+                k_cache, v_cache, k, v, block_tables, positions, layer=i
+            )
             if attn_backend == 'xla':
                 attn = paged_attention_xla(
-                    q, k_l, v_l, block_tables, context_lens,
-                    scale=cfg.attention_multiplier,
+                    q, k_cache, v_cache, block_tables, context_lens,
+                    scale=cfg.attention_multiplier, layer=i,
                 )
             else:
                 attn = ragged_paged_attention_pallas(
-                    q[:, None], k_l, v_l, block_tables, context_lens,
+                    q[:, None], k_cache, v_cache, block_tables, context_lens,
                     q_positions=positions[:, None],
                     scale=cfg.attention_multiplier,
-                    interpret=attn_backend == 'interpret',
+                    interpret=attn_backend == 'interpret', layer=i,
                 )[:, 0]
             mixed = _attn_out(attn, lp, cfg)
-            k_cache = _kv_layer_update(k_cache, k_l, i)
-            v_cache = _kv_layer_update(v_cache, v_l, i)
         x, layer_pairs = _finish_layer(
             x, mixed, lp, cfg, live, params[kind], i
         )

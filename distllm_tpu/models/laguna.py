@@ -17,9 +17,10 @@ the full layers' pool for its whole context, and blocks of the window
 layers' pool for what a query can still see. The serving programs take a
 pair of each cache operand, ``(full, window)``: ``k_cache``, ``v_cache`` and
 ``block_tables``; a group's ``k_cache`` is a tuple of one buffer a layer
-(``CacheSpec.layer_buffers``), each written whole and in place: a layer
-sliced out of a stacked pool is copied out and back (0.3 GB a plane of the
-full group at the benchmark's size, K and V, every layer of every step). Attention goes through the entry points ``models/
+(``CacheSpec.layer_buffers``), each written in place. (The form was chosen
+when a layer sliced out of a stacked pool was copied out and back; a
+stacked pool is now addressed by layer, ``ops.paged_attention``, and
+either form is free of copies.) Attention goes through the entry points ``models/
 mistral.py`` calls (``common.sdpa``, ``ragged_paged_attention``,
 ``write_chunk_kv``, ``write_token_kv``) with a static window per kind; the
 routed experts are ``models/moe.py``: a chip may hold a share of them

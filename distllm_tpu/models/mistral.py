@@ -290,27 +290,6 @@ def _layer_window_flags(cfg) -> jnp.ndarray:
     return jnp.arange(cfg.num_layers) % 2 == 0
 
 
-def _kv_layer(cache, li):
-    """Layer ``li``'s slice of a stacked KV cache. ``jax.tree.map`` keeps
-    the emitted HLO identical for bare arrays while slicing every member
-    of an int8 ``QuantizedKV`` (data AND its per-block scales) in one
-    expression — the layer scans stay dtype-agnostic."""
-    return jax.tree.map(
-        lambda c: jax.lax.dynamic_index_in_dim(c, li, 0, keepdims=False),
-        cache,
-    )
-
-
-def _kv_layer_update(cache, cache_l, li):
-    """Write a per-layer KV slice back into the stacked cache (the
-    :func:`_kv_layer` inverse, same bare-array/``QuantizedKV`` duality)."""
-    return jax.tree.map(
-        lambda c, cl: jax.lax.dynamic_update_index_in_dim(c, cl, li, 0),
-        cache,
-        cache_l,
-    )
-
-
 def _attn_mask(attention_mask: jnp.ndarray, cfg: MistralConfig) -> jnp.ndarray:
     """Causal x key-validity boolean mask ``[B, 1, S, S]`` (+ sliding window)."""
     seq = attention_mask.shape[1]
@@ -414,8 +393,6 @@ def prefill_paged(  # distlint: traced
     def layer(carry, xs):
         x, k_cache, v_cache = carry
         lp, li, window_l = xs
-        k_cache_l = _kv_layer(k_cache, li)
-        v_cache_l = _kv_layer(v_cache, li)
         normed = _norm(x, lp['attn_ln']['scale'], cfg)
         q = common.split_heads(
             common.dense(
@@ -439,9 +416,11 @@ def prefill_paged(  # distlint: traced
         k = common.apply_rope(k, cos, sin, positions)
         # Write the tail's K/V first, then attend over the paged cache —
         # cached prefix and own chunk through one gather (decode's
-        # write-then-attend order, generalized to S queries).
-        k_cache_l, v_cache_l = write_chunk_kv(
-            k_cache_l, v_cache_l, k, v, block_tables, positions, valid
+        # write-then-attend order, generalized to S queries). The stacked
+        # pools go to both whole, with the layer whose pages are meant: a
+        # layer sliced out would be copied out of the pool and back.
+        k_cache, v_cache = write_chunk_kv(
+            k_cache, v_cache, k, v, block_tables, positions, valid, layer=li
         )
         # q_lens masks PADDING queries (XLA: onto key 0; Pallas: to exact
         # zeros): under a sliding window a pad query past the window's
@@ -451,14 +430,14 @@ def prefill_paged(  # distlint: traced
         # contraction (0 x NaN = NaN). Valid rows are bit-identical with
         # or without the mask.
         attn = ragged_paged_attention(
-            q, k_cache_l, v_cache_l, block_tables, context_lens, positions,
+            q, k_cache, v_cache, block_tables, context_lens, positions,
             q_lens=tail_lens,
             sliding_window=(
                 window_l if alternating else cfg.sliding_window
             ),
             scale=getattr(cfg, 'query_scale', None),
             logit_softcap=getattr(cfg, 'attn_logit_softcap', None),
-            backend=attn_backend,
+            backend=attn_backend, layer=li,
         )
         attn_out = common.dense(
             common.merge_heads(attn), lp['o']['kernel'], qmm_backend=qb
@@ -470,8 +449,6 @@ def prefill_paged(  # distlint: traced
         mlp = _mlp_block(normed2, lp, cfg)
         if getattr(cfg, 'post_norms', False):
             mlp = _norm(mlp, lp['post_mlp_ln']['scale'], cfg)
-        k_cache = _kv_layer_update(k_cache, k_cache_l, li)
-        v_cache = _kv_layer_update(v_cache, v_cache_l, li)
         return (x + mlp, k_cache, v_cache), None
 
     (x, k_cache, v_cache), _ = jax.lax.scan(
@@ -628,13 +605,15 @@ def _decode_core(
     weights — read off the compiled HLO on older code, 2026-07-31; not
     re-measured, and no cell runs the rolled window: ROADMAP D2).
     Unrolling turns those into static slices that
-    fold into the matmuls. The K/V planes are another matter: a layer
-    sliced out of the stacked pool for the kernel call is copied out and
-    back, rolled or unrolled (read off the HLO compiled for a v5e at
-    Laguna-XS.2's pool sizes, PR 30: 0.3 GB a plane), which is why a
-    family may ask for one buffer a layer (``CacheSpec.layer_buffers``,
-    ``models/laguna.py``); this family's pool stays stacked. Prefill keeps the rolled scan: compute-bound,
-    and the slice traffic amortizes over the whole token batch.
+    fold into the matmuls. The K/V pools are never sliced, rolled or
+    unrolled: a layer sliced out of the stacked pool for the kernel call
+    was copied out and back (64 plane copies and write-backs a step at 32
+    layers, 4.27 ms of a 29.61 ms step on the chip, PR 31), so the pool
+    goes to the writer and to the kernel whole, with the layer whose
+    pages are meant (``ops.paged_attention._layer_pages``). A family may
+    instead hold one buffer a layer (``CacheSpec.layer_buffers``,
+    ``models/laguna.py``). Prefill keeps the rolled scan: compute-bound,
+    and the weights' slice traffic amortizes over the whole token batch.
     """
     from distllm_tpu.ops.paged_attention import (
         paged_attention_xla,
@@ -648,29 +627,30 @@ def _decode_core(
 
     if attn_backend == 'xla':
 
-        def attend(q, k_cache_l, v_cache_l, window_l):
+        def attend(q, k_cache, v_cache, window_l, li):
             return paged_attention_xla(
-                q, k_cache_l, v_cache_l, block_tables, context_lens,
+                q, k_cache, v_cache, block_tables, context_lens,
                 # Traced per-layer window only for the alternating pattern;
                 # other families keep the static value so their decode HLO
                 # is unchanged.
                 sliding_window=window_l if alternating else cfg.sliding_window,
                 scale=getattr(cfg, 'query_scale', None),
                 logit_softcap=getattr(cfg, 'attn_logit_softcap', None),
+                layer=li,
             )
     else:
         # A decode row is the ragged kernel's span-1 degenerate case: one
         # query at the token's own position over the whole context. The
         # kernel natively handles softcap / traced per-layer windows /
         # custom scales, so every model family serves through it.
-        def attend(q, k_cache_l, v_cache_l, window_l):
+        def attend(q, k_cache, v_cache, window_l, li):
             return ragged_paged_attention_pallas(
-                q[:, None], k_cache_l, v_cache_l, block_tables,
+                q[:, None], k_cache, v_cache, block_tables,
                 context_lens, q_positions=positions[:, None],
                 sliding_window=window_l if alternating else cfg.sliding_window,
                 scale=getattr(cfg, 'query_scale', None),
                 logit_softcap=getattr(cfg, 'attn_logit_softcap', None),
-                interpret=attn_backend == 'interpret',
+                interpret=attn_backend == 'interpret', layer=li,
             )[:, 0]
 
     # int32 [L] per-layer windows (0 = global) riding the layer scan; only
@@ -681,14 +661,15 @@ def _decode_core(
 
     x = _embed_tokens(params, cfg, input_ids)  # [B, H]
 
-    # The FULL caches ride the scan carry and each layer dynamic-update-
-    # slices its own [num_blocks, bs, Nkv, Hd] plane in place. Rolled
+    # The FULL caches ride the scan carry and each layer scatters its new
+    # rows into its own pages of them, in place. Rolled
     # (layer_unroll=False): XLA aliases while-loop carries, so no second
-    # cache copy is ever materialized. Unrolled: the same DUS chain sits in
-    # straight-line code, where in-place updates rely on XLA's buffer
-    # reuse instead of carry aliasing — tests/test_aot_tpu.py asserts the
-    # unrolled window's temp budget stays cache-copy-free so a missed
-    # reuse cannot land silently. (Scanning the caches as xs/ys instead
+    # cache copy is ever materialized. Unrolled: the same chain of
+    # scatters sits in straight-line code, where in-place updates rely on
+    # XLA's buffer reuse instead of carry aliasing — tests/test_aot_tpu.py
+    # asserts that no op of the unrolled window has a plane as its result
+    # and every pool-sized one is the scatter, so a missed reuse cannot
+    # land silently. (Scanning the caches as xs/ys instead
     # allocates a full stacked output buffer: +1 GB at 7B dims, and one
     # more when a multi-step window scan wraps this — that overflowed the
     # v5e's 16 GB HBM.)
@@ -697,8 +678,6 @@ def _decode_core(
     def layer(carry, xs):
         x, k_cache, v_cache = carry
         lp, li, window_l = xs
-        k_cache_l = _kv_layer(k_cache, li)
-        v_cache_l = _kv_layer(v_cache, li)
         normed = _norm(x, lp['attn_ln']['scale'], cfg)
         q = common.dense(
             normed, lp['q']['kernel'], lp['q'].get('bias'), qmm_backend=qb
@@ -712,10 +691,10 @@ def _decode_core(
         # RoPE at each sequence's own position ([B, 1, N, Hd] view).
         q = common.apply_rope(q[:, None], cos, sin, positions[:, None])[:, 0]
         k = common.apply_rope(k[:, None], cos, sin, positions[:, None])[:, 0]
-        k_cache_l, v_cache_l = write_token_kv(
-            k_cache_l, v_cache_l, k, v, block_tables, positions
+        k_cache, v_cache = write_token_kv(
+            k_cache, v_cache, k, v, block_tables, positions, layer=li
         )
-        attn = attend(q, k_cache_l, v_cache_l, window_l)
+        attn = attend(q, k_cache, v_cache, window_l, li)
         attn_out = common.dense(
             attn.reshape(-1, cfg.num_heads * cfg.head_size),
             lp['o']['kernel'],
@@ -728,8 +707,6 @@ def _decode_core(
         mlp = _mlp_block(normed2, lp, cfg)
         if getattr(cfg, 'post_norms', False):
             mlp = _norm(mlp, lp['post_mlp_ln']['scale'], cfg)
-        k_cache = _kv_layer_update(k_cache, k_cache_l, li)
-        v_cache = _kv_layer_update(v_cache, v_cache_l, li)
         return (x + mlp, k_cache, v_cache), None
 
     (x, k_cache, v_cache), _ = jax.lax.scan(

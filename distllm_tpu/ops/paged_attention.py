@@ -8,9 +8,15 @@ row)::
 
     k_cache, v_cache : [num_blocks, block_size, num_kv_heads * head_dim]
 
-No function here reshapes a cache: under the TPU's tiled layout a reshape of
-the two minor dims is a copy of the whole buffer, not a bitcast. The writers
-fold the NEW rows (``[.., num_kv_heads, head_dim]`` activations), the XLA
+or every layer's at once, a STACKED pool ``[L, num_blocks, ...]`` that
+goes to the writers, the XLA twins and the kernel whole with ``layer=``:
+a stacked pool is addressed, never sliced (:func:`_layer_pages`; the
+operand's rank decides).
+
+No function here reshapes a cache's rows: under the TPU's tiled layout a
+reshape of the two minor dims is a copy of the whole buffer, not a bitcast
+(a merge of the two LEADING dims, the stacked pool's flat view, is one).
+The writers fold the NEW rows (``[.., num_kv_heads, head_dim]`` activations), the XLA
 twins unfold the pages they GATHERED, and ``num_kv_heads`` is the folded
 width over the queries' ``head_dim``.
 
@@ -145,6 +151,50 @@ def _kv_data(cache):
     return cache.data if isinstance(cache, QuantizedKV) else cache
 
 
+def _layer_pages(k_cache, v_cache, layer):  # distlint: traced
+    """``(k_pages, v_pages, base)``: a K and a V pool (V None for a latent
+    pool) as the runs of pages the ops below index, and the id of
+    ``layer``'s block 0 in such a run.
+
+    A layer's own buffer (rank 3, ``CacheSpec.layer_buffers``) is that
+    run already: it comes back as it is with ``base`` None, and nothing
+    is added to a block id. A STACKED pool ``[L, blocks, block_size,
+    folded]`` is addressed, never sliced: a slice handed to the kernel (a
+    custom call wants its operand materialised) or to a scatter is a
+    copy of the layer's whole plane out of the pool and back, every
+    layer of every step. It is viewed as ``[L * blocks, block_size,
+    folded]``, a merge of two untiled leading dims and so a bitcast, and
+    ``base = layer * blocks`` (``layer`` a Python int, or traced under a
+    rolled layer scan) is added to the block ids AFTER dead rows and pad
+    positions were sent to block 0, so a layer's trash block is its own
+    block 0. An int8 pool's scales ``[L, blocks, N_kv]`` are viewed the
+    same way. :func:`_like` gives written pages their shape back.
+    """
+    data = _kv_data(k_cache)
+    if data.ndim == 3:
+        return k_cache, v_cache, None
+    if layer is None:
+        raise ValueError(
+            'a stacked pool [L, blocks, block_size, folded] needs the '
+            'layer whose pages are meant'
+        )
+    k_pages, v_pages = jax.tree.map(
+        lambda a: a.reshape(-1, *a.shape[2:]), (k_cache, v_cache)
+    )
+    return k_pages, v_pages, layer * data.shape[1]
+
+
+def _in_layer(block_ids, base):  # distlint: traced
+    """Block ids of one layer as ids into :func:`_layer_pages`'s run."""
+    return block_ids if base is None else block_ids + base
+
+
+def _like(pages, cache):  # distlint: traced
+    """Written pages in the shape of the pool they were taken from
+    (their own, for a rank-3 buffer; None for a latent pool's V)."""
+    return jax.tree.map(lambda a, c: a.reshape(c.shape), pages, cache)
+
+
 def fold_heads(rows):  # distlint: traced
     """``[..., num_kv_heads, head_dim]`` -> ``[..., num_kv_heads *
     head_dim]``: a token's row as the pool stores it. For rows and blocks
@@ -242,6 +292,7 @@ def paged_attention_xla(  # distlint: traced
     scale: float | None = None,
     logit_softcap: float | None = None,
     value_lanes: int | None = None,
+    layer=None,
 ) -> jnp.ndarray:
     """Reference implementation: gather blocks then masked attention.
 
@@ -250,8 +301,11 @@ def paged_attention_xla(  # distlint: traced
     local/global pattern; 0/negative means no window on that layer).
     ``scale`` overrides the 1/sqrt(head_dim) score scale
     (query_pre_attn_scalar); ``logit_softcap`` applies tanh(s/cap)*cap to
-    the scaled scores before masking (both gemma2).
+    the scaled scores before masking (both gemma2). A stacked pool
+    ``[L, ...]`` goes in whole with its ``layer`` (:func:`_layer_pages`).
     """
+    k_cache, v_cache, base = _layer_pages(k_cache, v_cache, layer)
+    block_tables = _in_layer(block_tables, base)
     b, num_heads, head_dim = q.shape
     _, block_size, folded = _kv_data(k_cache).shape
     num_kv_heads = folded // head_dim
@@ -307,6 +361,7 @@ def ragged_paged_attention_xla(  # distlint: traced
     scale: float | None = None,
     logit_softcap: float | None = None,
     value_lanes: int | None = None,
+    layer=None,
 ) -> jnp.ndarray:
     """Ragged per-row-query-length attention over paged KV — the shared
     op of prefix-cache tail prefill, chunked prefill, mixed
@@ -336,8 +391,11 @@ def ragged_paged_attention_xla(  # distlint: traced
     :func:`ragged_paged_attention`'s ``backend`` argument. This XLA path
     stays the always-available fallback and the identity baseline the
     parity matrix (``tests/test_ragged_attention.py``) pins the kernel
-    against.
+    against. A stacked pool ``[L, ...]`` goes in whole with its ``layer``
+    (:func:`_layer_pages`).
     """
+    k_cache, v_cache, base = _layer_pages(k_cache, v_cache, layer)
+    block_tables = _in_layer(block_tables, base)
     b, s, num_heads, head_dim = q.shape
     _, block_size, folded = _kv_data(k_cache).shape
     num_kv_heads = folded // head_dim
@@ -434,7 +492,8 @@ def _ragged_paged_attn_kernel(
     # slice at a 128 multiple is always tile-aligned.
     #   q_ref,  # [num_kv_heads, span_tile * group, head_dim] (VMEM)
     #   k_cache_ref,  # [num_blocks, block_size, num_kv_heads*head_dim] (HBM)
-    #   v_cache_ref,
+    #   v_cache_ref,  #   a layer's buffer, or a stacked pool's L * blocks
+    #       pages with block_tables_ref counting from the layer's block 0
     #   [k_scale_ref, v_scale_ref]  # quantized only: [num_blocks, 128]
     #       fp32 (HBM) — per-block per-KV-head scales, lane-padded to 128
     #       so each page's scale row DMAs with an aligned minor dim
@@ -758,6 +817,7 @@ def ragged_paged_attention_pallas(
     span_tile: int | None = None,
     interpret: bool = False,
     value_lanes: int | None = None,
+    layer=None,
 ) -> jnp.ndarray:
     """Fused Pallas TPU kernel twin of :func:`ragged_paged_attention_xla`.
 
@@ -785,10 +845,18 @@ def ragged_paged_attention_pallas(
     after GQA flattening) — both bound VMEM. ``interpret=True`` runs the
     same kernel on the Pallas interpreter (CPU-runnable; the
     ``attn_backend='interpret'`` engine tier).
+
+    A STACKED pool ``[L, blocks, block_size, folded]`` goes in whole with
+    its ``layer``: the kernel fetches pages from HBM by id, so it is
+    handed every layer's pages as one run (a bitcast) and ids that start
+    at the layer's block 0 (:func:`_layer_pages`); the kernel itself is
+    the one a rank-3 buffer gets, and a rank-3 buffer lowers as before.
     """
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    k_cache, v_cache, base = _layer_pages(k_cache, v_cache, layer)
+    block_tables = _in_layer(block_tables, base)
     quantized = isinstance(k_cache, QuantizedKV)
     latent = v_cache is None
     if latent and (quantized or value_lanes is None or value_lanes % 128):
@@ -876,7 +944,9 @@ def ragged_paged_attention_pallas(
         b, num_kv_heads, s * group, head_dim
     )
     # The caches go to the kernel as they lie, head-folded [nb, bs,
-    # Nkv*Hd]: inside the kernel each head is a 128-aligned lane band —
+    # Nkv*Hd] (nb = L * blocks for a stacked pool: the bitcast above, and
+    # no slice of it, which would be copied for the call): inside the
+    # kernel each head is a 128-aligned lane band —
     # the layout that keeps whole-page DMA descriptors contiguous AND
     # per-head slices tile-aligned (see the kernel docstring for the two
     # Mosaic rejections this designs out).
@@ -988,6 +1058,7 @@ def ragged_paged_attention(
     *,
     backend: str = 'xla',
     value_lanes: int | None = None,
+    layer=None,
 ) -> jnp.ndarray:
     """THE serving attention callsite: dispatch one ragged paged span
     batch through the selected backend.
@@ -1008,7 +1079,7 @@ def ragged_paged_attention(
             q, k_cache, v_cache, block_tables, context_lens, q_positions,
             q_lens=q_lens, sliding_window=sliding_window, scale=scale,
             logit_softcap=logit_softcap, interpret=backend == 'interpret',
-            value_lanes=value_lanes,
+            value_lanes=value_lanes, layer=layer,
         )
     if backend != 'xla':
         raise ValueError(
@@ -1019,7 +1090,7 @@ def ragged_paged_attention(
     return ragged_paged_attention_xla(
         q, k_cache, v_cache, block_tables, context_lens, q_positions,
         q_lens=q_lens, sliding_window=sliding_window, scale=scale,
-        logit_softcap=logit_softcap, value_lanes=value_lanes,
+        logit_softcap=logit_softcap, value_lanes=value_lanes, layer=layer,
     )
 
 
@@ -1035,6 +1106,7 @@ def paged_attention_pallas(
     logit_softcap: float | None = None,
     pages_per_chunk: int | None = None,
     interpret: bool = False,
+    layer=None,
 ) -> jnp.ndarray:
     """Pallas kernel twin of :func:`paged_attention_xla` — now a thin
     span-1 wrapper over :func:`ragged_paged_attention_pallas` (a decode
@@ -1058,6 +1130,7 @@ def paged_attention_pallas(
         logit_softcap=logit_softcap,
         pages_per_chunk=pages_per_chunk,
         interpret=interpret,
+        layer=layer,
     )[:, 0]
 
 
@@ -1105,30 +1178,36 @@ def write_token_kv(  # distlint: traced
     new_v: jnp.ndarray,
     block_tables: jnp.ndarray,  # [B, max_blocks]
     positions: jnp.ndarray,  # [B] token index being written
+    layer=None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Scatter one new token's K/V per sequence into its paged block
     (quantizing at write time for int8 :class:`QuantizedKV` pools). The
     new rows are folded to the pool's ``num_kv_heads * head_dim`` rows;
     the pool is only scattered into. A latent pool is one plane:
     ``v_cache`` and ``new_v`` None, ``new_k [B, 1, row]`` the tokens' rows,
-    and None comes back in V's place."""
-    block_size = _kv_data(k_cache).shape[1]
+    and None comes back in V's place. A stacked pool ``[L, ...]`` goes in
+    and comes back whole: the rows are written into ``layer``'s pages of
+    it, in place (:func:`_layer_pages`)."""
+    k_pages, v_pages, base = _layer_pages(k_cache, v_cache, layer)
+    block_size = _kv_data(k_pages).shape[1]
     batch = positions.shape[0]
-    block_ids = block_tables[jnp.arange(batch), positions // block_size]
+    block_ids = _in_layer(
+        block_tables[jnp.arange(batch), positions // block_size], base
+    )
     offsets = positions % block_size
-    if isinstance(k_cache, QuantizedKV):
-        return _write_token_kv_quantized(
-            k_cache, v_cache, new_k, new_v, block_ids, offsets
+    if isinstance(k_pages, QuantizedKV):
+        k_pages, v_pages = _write_token_kv_quantized(
+            k_pages, v_pages, new_k, new_v, block_ids, offsets
         )
-    k_cache = k_cache.at[block_ids, offsets].set(
-        fold_heads(new_k).astype(k_cache.dtype)
+        return _like(k_pages, k_cache), _like(v_pages, v_cache)
+    k_pages = k_pages.at[block_ids, offsets].set(
+        fold_heads(new_k).astype(k_pages.dtype)
     )
-    if v_cache is None:  # distlint: disable=traced-python-branch -- a latent pool's absent V plane is static
-        return k_cache, None
-    v_cache = v_cache.at[block_ids, offsets].set(
-        fold_heads(new_v).astype(v_cache.dtype)
-    )
-    return k_cache, v_cache
+    if v_pages is not None:  # distlint: disable=traced-python-branch -- a latent pool's absent V plane is static
+        v_pages = v_pages.at[block_ids, offsets].set(
+            fold_heads(new_v).astype(v_pages.dtype)
+        )
+    return _like(k_pages, k_cache), _like(v_pages, v_cache)
 
 
 def write_chunk_kv(  # distlint: traced
@@ -1139,6 +1218,7 @@ def write_chunk_kv(  # distlint: traced
     block_tables: jnp.ndarray,  # [B, max_blocks]
     positions: jnp.ndarray,  # [B, S] absolute position per tail token
     valid: jnp.ndarray,  # [B, S] bool — padding rows/tokens route to trash
+    layer=None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Scatter a batch of ragged spans' K/V into their paged blocks.
 
@@ -1147,39 +1227,45 @@ def write_chunk_kv(  # distlint: traced
     chunk rows riding mixed serving windows): ``valid`` carries the
     per-row raggedness — invalid positions write to the reserved trash
     block 0, the same pad-safety contract as :func:`write_prefill_kv`.
-    A latent pool is one plane (``v_cache`` and ``new_v`` None).
+    A latent pool is one plane (``v_cache`` and ``new_v`` None). A stacked
+    pool ``[L, ...]`` goes in and comes back whole, written in ``layer``'s
+    pages (:func:`_layer_pages`): that layer's block 0 is the trash.
     """
-    block_size = _kv_data(k_cache).shape[1]
+    k_pages, v_pages, base = _layer_pages(k_cache, v_cache, layer)
+    block_size = _kv_data(k_pages).shape[1]
     b, s = positions.shape
-    block_ids = jnp.where(
-        valid,
-        jnp.take_along_axis(block_tables, positions // block_size, axis=1),
-        0,
+    block_ids = _in_layer(
+        jnp.where(
+            valid,
+            jnp.take_along_axis(block_tables, positions // block_size, axis=1),
+            0,
+        ),
+        base,
     )
     offsets = jnp.where(valid, positions % block_size, 0)
-    if isinstance(k_cache, QuantizedKV):
-        return _write_chunk_kv_quantized(
-            k_cache, v_cache, new_k, new_v, block_tables, positions,
-            valid, block_ids, offsets,
+    if isinstance(k_pages, QuantizedKV):
+        k_pages, v_pages = _write_chunk_kv_quantized(
+            k_pages, v_pages, new_k, new_v, block_tables, positions,
+            valid, block_ids, offsets, base,
         )
+        return _like(k_pages, k_cache), _like(v_pages, v_cache)
     flat_blocks = block_ids.reshape(-1)
     flat_offsets = offsets.reshape(-1)
     k_flat = fold_heads(new_k).reshape(b * s, -1)
-    k_cache = k_cache.at[flat_blocks, flat_offsets].set(
-        k_flat.astype(k_cache.dtype)
+    k_pages = k_pages.at[flat_blocks, flat_offsets].set(
+        k_flat.astype(k_pages.dtype)
     )
-    if v_cache is None:  # distlint: disable=traced-python-branch -- a latent pool's absent V plane is static
-        return k_cache, None
-    v_flat = fold_heads(new_v).reshape(b * s, -1)
-    v_cache = v_cache.at[flat_blocks, flat_offsets].set(
-        v_flat.astype(v_cache.dtype)
-    )
-    return k_cache, v_cache
+    if v_pages is not None:  # distlint: disable=traced-python-branch -- a latent pool's absent V plane is static
+        v_flat = fold_heads(new_v).reshape(b * s, -1)
+        v_pages = v_pages.at[flat_blocks, flat_offsets].set(
+            v_flat.astype(v_pages.dtype)
+        )
+    return _like(k_pages, k_cache), _like(v_pages, v_cache)
 
 
 def _write_chunk_kv_quantized(k_cache, v_cache, new_k, new_v, block_tables,
-                              positions, valid, block_ids,
-                              offsets):  # distlint: traced
+                              positions, valid, block_ids, offsets,
+                              base=None):  # distlint: traced
     """Ragged-span quantize-at-write (the :func:`write_chunk_kv` int8
     path). A row's span covers a CONTIGUOUS run of at most
     ``S // block_size + 1`` blocks (spans are position-consecutive with
@@ -1192,7 +1278,8 @@ def _write_chunk_kv_quantized(k_cache, v_cache, new_k, new_v, block_tables,
     tokens quantized at the final per-block scales. Dead rows / dead
     touched slots route to the trash block 0 exactly like the
     full-precision path (finite garbage, see
-    :func:`_write_token_kv_quantized`)."""
+    :func:`_write_token_kv_quantized`). ``block_ids`` and the caches are
+    :func:`_layer_pages`'s; ``base`` places the touched blocks likewise."""
     block_size = k_cache.data.shape[1]
     b, s = positions.shape
     max_blocks = block_tables.shape[1]
@@ -1204,8 +1291,11 @@ def _write_chunk_kv_quantized(k_cache, v_cache, new_k, new_v, block_tables,
     live = (touched <= last_pos[:, None] // block_size) & (
         last_pos[:, None] >= 0
     )
-    phys = jnp.where(
-        live, jnp.take_along_axis(block_tables, touched_cl, axis=1), 0
+    phys = _in_layer(
+        jnp.where(
+            live, jnp.take_along_axis(block_tables, touched_cl, axis=1), 0
+        ),
+        base,
     )  # [B, nt] physical touched blocks (dead -> trash)
     fresh = touched * block_size >= positions[:, :1]  # span covers row 0
     tb = jnp.clip(
@@ -1254,6 +1344,7 @@ def write_prefill_kv(  # distlint: traced
     v_seq: jnp.ndarray,
     block_table_row: jnp.ndarray,  # [max_blocks]
     length: jnp.ndarray,  # scalar — valid tokens in k_seq
+    layer=None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Scatter a prefilled sequence's K/V into its blocks (pad-safe).
 
@@ -1261,33 +1352,38 @@ def write_prefill_kv(  # distlint: traced
     is reserved by the allocator (never handed to a sequence), so garbage
     writes land there harmlessly. Clamping to a valid slot instead would race
     real data through XLA's nondeterministic duplicate-index scatter.
-    A latent pool is one plane (``v_cache`` and ``v_seq`` None).
+    A latent pool is one plane (``v_cache`` and ``v_seq`` None). A stacked
+    pool ``[L, ...]`` goes in and comes back whole, written in ``layer``'s
+    pages (:func:`_layer_pages`).
     """
+    k_pages, v_pages, base = _layer_pages(k_cache, v_cache, layer)
     seq_len = k_seq.shape[0]
-    block_size = _kv_data(k_cache).shape[1]
+    block_size = _kv_data(k_pages).shape[1]
     positions = jnp.arange(seq_len)
     valid = positions < length
-    block_ids = jnp.where(valid, block_table_row[positions // block_size], 0)
+    block_ids = _in_layer(
+        jnp.where(valid, block_table_row[positions // block_size], 0), base
+    )
     offsets = jnp.where(valid, positions % block_size, 0)
-    if isinstance(k_cache, QuantizedKV):
-        return _write_prefill_kv_quantized(
-            k_cache, v_cache, k_seq, v_seq, block_table_row, length,
-            block_ids, offsets, valid,
+    if isinstance(k_pages, QuantizedKV):
+        k_pages, v_pages = _write_prefill_kv_quantized(
+            k_pages, v_pages, k_seq, v_seq, block_table_row, length,
+            block_ids, offsets, valid, base,
         )
-    k_cache = k_cache.at[block_ids, offsets].set(
-        fold_heads(k_seq).astype(k_cache.dtype)
+        return _like(k_pages, k_cache), _like(v_pages, v_cache)
+    k_pages = k_pages.at[block_ids, offsets].set(
+        fold_heads(k_seq).astype(k_pages.dtype)
     )
-    if v_cache is None:  # distlint: disable=traced-python-branch -- a latent pool's absent V plane is static
-        return k_cache, None
-    v_cache = v_cache.at[block_ids, offsets].set(
-        fold_heads(v_seq).astype(v_cache.dtype)
-    )
-    return k_cache, v_cache
+    if v_pages is not None:  # distlint: disable=traced-python-branch -- a latent pool's absent V plane is static
+        v_pages = v_pages.at[block_ids, offsets].set(
+            fold_heads(v_seq).astype(v_pages.dtype)
+        )
+    return _like(k_pages, k_cache), _like(v_pages, v_cache)
 
 
 def _write_prefill_kv_quantized(k_cache, v_cache, k_seq, v_seq,
                                 block_table_row, length, block_ids,
-                                offsets, valid):  # distlint: traced
+                                offsets, valid, base=None):  # distlint: traced
     """Whole-sequence quantize-at-write (the :func:`write_prefill_kv`
     int8 path). A full prefill writes every block from its offset 0, so
     every touched block is FRESH: each block's scale is simply the
@@ -1301,7 +1397,9 @@ def _write_prefill_kv_quantized(k_cache, v_cache, k_seq, v_seq,
     nt = -(-seq_len // block_size)
     pad = nt * block_size - seq_len
     live_blk = jnp.arange(nt) * block_size < length
-    phys = jnp.where(live_blk, block_table_row[jnp.arange(nt)], 0)
+    phys = _in_layer(
+        jnp.where(live_blk, block_table_row[jnp.arange(nt)], 0), base
+    )
 
     def write_one(cache, seq):
         amax = jnp.max(jnp.abs(seq.astype(jnp.float32)), axis=-1)

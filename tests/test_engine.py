@@ -601,6 +601,104 @@ def test_engine_decode_steps_variants_match_dense():
         assert outs == refs, f'steps={steps} depth={depth}: {outs} != {refs}'
 
 
+def _sliced_out_and_back(monkeypatch):
+    """The path this family's programs took before the pool was addressed:
+    a layer's plane sliced out of the stacked pool, written or read alone,
+    and written back. A write through it cannot touch another layer."""
+    from distllm_tpu.ops import paged_attention as pa
+
+    def slice_of(cache, layer):
+        if layer is None:  # a plane already: the dispatcher's inner call
+            return cache
+        return jax.tree.map(
+            lambda c: jax.lax.dynamic_index_in_dim(c, layer, 0, False), cache
+        )
+
+    def writer(name):
+        whole = getattr(pa, name)
+
+        def sliced(k, v, *args, layer=None):
+            k_l, v_l = whole(slice_of(k, layer), slice_of(v, layer), *args)
+            return tuple(
+                jax.tree.map(
+                    lambda c, cl: jax.lax.dynamic_update_index_in_dim(
+                        c, cl, layer, 0
+                    ), cache, cache_l,
+                ) for cache, cache_l in ((k, k_l), (v, v_l))
+            )
+
+        monkeypatch.setattr(pa, name, sliced)
+
+    def reader(name):
+        whole = getattr(pa, name)
+
+        def sliced(q, k, v, *args, layer=None, **kwargs):
+            return whole(
+                q, slice_of(k, layer), slice_of(v, layer), *args, **kwargs
+            )
+
+        monkeypatch.setattr(pa, name, sliced)
+
+    for name in ('write_token_kv', 'write_chunk_kv'):
+        writer(name)
+    for name in (
+        'paged_attention_xla', 'ragged_paged_attention',
+        'ragged_paged_attention_pallas',
+    ):
+        reader(name)
+
+
+def test_engine_addresses_the_stacked_pool_by_layer(monkeypatch):
+    """A greedy ``generate_ids`` over a 3-layer toy (prefix cache, chunked
+    prefill and decode windows, so the span writer, the token writer and
+    both readers all run on the stacked pool with a layer named): the
+    tokens are the dense forward's and the sliced path's, and every byte
+    of both pools is what the sliced path left, which can only write the
+    layer it was handed: no write strays into a layer it did not name."""
+    cfg = mistral.MistralConfig(
+        vocab_size=64, hidden_size=32, num_layers=3, num_heads=4,
+        num_kv_heads=2, intermediate_size=64, dtype='float32',
+    )
+    params = mistral.init(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(11)
+    shared = [int(t) for t in rng.integers(1, 64, size=9)]
+    prompts = [
+        shared + [int(t) for t in rng.integers(1, 64, size=n)]
+        for n in (2, 12, 5)
+    ]
+    sampling = SamplingParams(temperature=0.0, max_tokens=7)
+
+    def run():
+        class IdTokenizer:
+            eos_id = None
+
+        engine = LLMEngine(
+            cfg, params, IdTokenizer(),
+            EngineConfig(
+                block_size=4, num_blocks=48, max_num_seqs=3, max_model_len=64,
+                decode_steps=4, pipeline_depth=1,
+                attn_backend='interpret',  # the kernel's own wrapper
+                enable_prefix_cache=True, prefill_chunk_tokens=8,
+                prefer_native_allocator=False,
+            ),
+        )
+        outs = engine.generate_ids(prompts, sampling)
+        assert engine.kv.k_pool.shape == (3, 48, 4, 16)  # stacked, folded
+        pools = np.asarray(engine.kv.k_pool), np.asarray(engine.kv.v_pool)
+        engine.shutdown()
+        return outs, pools
+
+    outs, pools = run()
+    assert outs == [_dense_greedy_reference(cfg, params, p, 7) for p in prompts]
+    _sliced_out_and_back(monkeypatch)
+    sliced_outs, sliced_pools = run()
+    assert outs == sliced_outs
+    for got, want in zip(pools, sliced_pools):
+        assert got[:, 1:].any(axis=(1, 2, 3)).all()  # every layer was written
+        # past each layer's trash block, where dead rows land in no order
+        np.testing.assert_array_equal(got[:, 1:], want[:, 1:])
+
+
 def test_engine_pipelined_preemption_pressure_matches_dense():
     """A pool too small for all sequences forces recompute preemption mid-
     pipeline; the drain-before-preempt rule must keep results exact."""

@@ -226,6 +226,74 @@ def test_paged_attention_pallas_interpret_matches_xla_int8(rng):
 
 
 # ------------------------------------------------------- backend resolution
+@pytest.mark.parametrize('writer', ['token', 'chunk', 'prefill'])
+@pytest.mark.parametrize('layer', [0, 2], ids=['first', 'last'])
+def test_stacked_int8_pool_is_addressed_by_layer(rng, writer, layer):
+    """An int8 pool takes the bare pool's path: the stacked ``QuantizedKV``
+    (data ``[L, blocks, ...]`` and scales ``[L, blocks, nkv]``) goes to
+    the writers and the readers whole with its layer, data and scales come
+    out as the layer's own plane gives them, and no other layer moves."""
+    layers, blocks, bs, nkv, hd = 3, 6, 4, 2, 8
+    stack = QuantizedKV(
+        jnp.asarray(
+            rng.integers(-127, 128, size=(layers, blocks, bs, nkv * hd)),
+            jnp.int8,
+        ),
+        jnp.asarray(
+            rng.uniform(0.01, 0.05, size=(layers, blocks, nkv)), jnp.float32
+        ),
+    )
+    plane = jax.tree.map(lambda a: a[layer], stack)
+    new = jnp.asarray(rng.normal(size=(2, 6, nkv, hd)).astype(np.float32))
+    # row 0 lives in blocks 3 and 5; row 1 is dead (the trash block)
+    bt = jnp.asarray([[3, 5, 0], [0, 0, 0]], jnp.int32)
+    if writer == 'token':
+        args = (new[:, 0], new[:, 0], bt, jnp.asarray([5, 2], jnp.int32))
+        write = write_token_kv
+    elif writer == 'chunk':
+        pos = jnp.asarray([[2, 3, 4, 5, 6, 7], [0, 1, 2, 3, 4, 5]], jnp.int32)
+        valid = jnp.asarray([[True] * 5 + [False], [False] * 6])
+        args = (new, new, bt, pos, valid)
+        write = write_chunk_kv
+    else:
+        args = (new[0], new[0], bt[0], jnp.int32(5))
+        write = write_prefill_kv
+    got_k, got_v = jax.jit(
+        lambda k, v, li: write(k, v, *args, layer=li)
+    )(stack, stack, jnp.int32(layer))
+    want_k, _ = jax.jit(lambda k, v: write(k, v, *args))(plane, plane)
+    for got in (got_k, got_v):
+        assert got.data.shape == stack.data.shape
+        assert got.scale.shape == stack.scale.shape
+        # past block 0, where the dead writes land in no settled order
+        np.testing.assert_array_equal(
+            np.asarray(got.data[layer, 1:]), np.asarray(want_k.data[1:])
+        )
+        np.testing.assert_array_equal(
+            np.asarray(got.scale[layer, 1:]), np.asarray(want_k.scale[1:])
+        )
+        for other in set(range(layers)) - {layer}:
+            np.testing.assert_array_equal(
+                np.asarray(got.data[other]), np.asarray(stack.data[other])
+            )
+            np.testing.assert_array_equal(
+                np.asarray(got.scale[other]), np.asarray(stack.scale[other])
+            )
+    # the readers: the layer's pages of the stack, dequantized in the gather
+    q = jnp.asarray(rng.normal(size=(1, 4, hd)).astype(np.float32))
+    ctx = jnp.asarray([7], jnp.int32)
+    ref = paged_attention_xla(q, want_k, want_k, bt[:1], ctx)
+    for out in (
+        paged_attention_xla(q, got_k, got_v, bt[:1], ctx, layer=layer),
+        paged_attention_pallas(
+            q, got_k, got_v, bt[:1], ctx, layer=layer, interpret=True
+        ),
+    ):
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(ref), atol=1e-4, rtol=1e-4
+        )
+
+
 def test_kv_sublane_tile_by_dtype():
     assert kv_sublane_tile('int8') == 32
     assert kv_sublane_tile('bfloat16') == 16

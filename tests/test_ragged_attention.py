@@ -127,6 +127,143 @@ def test_ragged_parity_decode_rows(rng):
         )
 
 
+@pytest.mark.parametrize('layer', [0, 1, 2], ids=['first', 'middle', 'last'])
+@pytest.mark.parametrize('traced', [False, True], ids=['int', 'traced'])
+def test_stacked_pool_is_addressed_by_layer(rng, layer, traced):
+    """A stacked pool ``[L, blocks, block_size, folded]`` goes to the
+    writers and the readers WHOLE, with the layer whose pages are meant
+    (a Python int when the layers are unrolled, traced under a rolled
+    scan). A chunk span, a decode row and a dead row: what is written and
+    read is what the layer's own plane gives, the dead row's write lands
+    in THAT layer's block 0, and no other layer's bytes move."""
+    from distllm_tpu.ops.paged_attention import (
+        paged_attention_pallas,
+        paged_attention_xla,
+        write_chunk_kv,
+        write_token_kv,
+    )
+
+    layers, s = 3, 5
+    q, _, _, bt, ctx, pos, q_lens = _setup(rng, s=s)
+    # row 0 a mid-stream chunk, row 1 one live query, row 2 DEAD: no
+    # queries, and a table the caller has sent to the trash block
+    q_lens = jnp.asarray([s, 1, 0], jnp.int32)
+    ctx = jnp.asarray([17, 9, 0], jnp.int32)
+    pos = jnp.maximum(ctx - q_lens, 0)[:, None] + jnp.arange(s)[None, :]
+    bt = bt.at[2].set(0)
+    stack_k, stack_v = (
+        jnp.asarray(rng.normal(size=(layers, 12, 4, 16)).astype(np.float32))
+        for _ in range(2)
+    )
+    new_k, new_v = (
+        jnp.asarray(rng.normal(size=(3, s, 2, 8)).astype(np.float32))
+        for _ in range(2)
+    )
+    valid = jnp.arange(s)[None, :] < q_lens[:, None]
+    li = jnp.int32(layer) if traced else layer
+
+    def run(fn, *args):
+        """``fn(*args, layer)``, the layer a tracer when asked for."""
+        if traced:
+            return jax.jit(fn)(*args, li)
+        return fn(*args, li)
+
+    def untouched_but(after, before, what):
+        for other in range(layers):
+            if other != layer:
+                np.testing.assert_array_equal(
+                    np.asarray(after[other]), np.asarray(before[other]),
+                    err_msg=f'{what} moved bytes of layer {other}',
+                )
+
+    # --- the chunk writer, against the layer's own plane
+    got_k, got_v = run(
+        lambda k, v, layer: write_chunk_kv(
+            k, v, new_k, new_v, bt, pos, valid, layer=layer
+        ), stack_k, stack_v,
+    )
+    want_k, want_v = write_chunk_kv(
+        stack_k[layer], stack_v[layer], new_k, new_v, bt, pos, valid
+    )
+    assert got_k.shape == stack_k.shape
+    for got, want, before in (
+        (got_k, want_k, stack_k), (got_v, want_v, stack_v)
+    ):
+        # block 0 holds whichever dead position landed last: compare past it
+        np.testing.assert_array_equal(
+            np.asarray(got[layer, 1:]), np.asarray(want[1:])
+        )
+        untouched_but(got, before, 'write_chunk_kv')
+        # the dead positions' rows went to this layer's block 0, offset 0
+        assert not np.array_equal(
+            np.asarray(got[layer, 0, 0]), np.asarray(before[layer, 0, 0])
+        )
+        np.testing.assert_array_equal(
+            np.asarray(got[layer, 0, 1:]), np.asarray(before[layer, 0, 1:])
+        )
+
+    # --- the readers over the written pool: a span, then decode rows
+    ref = ragged_paged_attention_xla(
+        q, want_k, want_v, bt, ctx, pos, q_lens=q_lens
+    )
+    for reader, kwargs in (
+        (ragged_paged_attention_xla, {}),
+        (ragged_paged_attention_pallas, {'interpret': True}),
+    ):
+        out = run(
+            lambda k, v, layer, reader=reader, kwargs=kwargs: reader(
+                q, k, v, bt, ctx, pos, q_lens=q_lens, layer=layer, **kwargs
+            ), got_k, got_v,
+        )
+        _assert_parity(out, ref, q_lens, s)
+
+    # --- the token writer and the decode readers; row 2 out of budget
+    tok_pos = jnp.asarray([16, 8, 3], jnp.int32)
+    tok_ctx = tok_pos + 1
+    tok_k, tok_v = new_k[:, 0], new_v[:, 0]
+    dec_k, dec_v = run(
+        lambda k, v, layer: write_token_kv(
+            k, v, tok_k, tok_v, bt, tok_pos, layer=layer
+        ), got_k, got_v,
+    )
+    one_k, one_v = write_token_kv(
+        got_k[layer], got_v[layer], tok_k, tok_v, bt, tok_pos
+    )
+    np.testing.assert_array_equal(np.asarray(dec_k[layer]), np.asarray(one_k))
+    np.testing.assert_array_equal(np.asarray(dec_v[layer]), np.asarray(one_v))
+    untouched_but(dec_k, got_k, 'write_token_kv')
+    untouched_but(dec_v, got_v, 'write_token_kv')
+    # the dead row's token: block 0 of this layer, at its offset
+    np.testing.assert_array_equal(
+        np.asarray(dec_k[layer, 0, 3]), np.asarray(tok_k[2]).reshape(-1)
+    )
+    ref = paged_attention_xla(q[:2, 0], one_k, one_v, bt[:2], tok_ctx[:2])
+    for reader, kwargs in (
+        (paged_attention_xla, {}),
+        (paged_attention_pallas, {'interpret': True}),
+    ):
+        out = run(
+            lambda k, v, layer, reader=reader, kwargs=kwargs: reader(
+                q[:2, 0], k, v, bt[:2], tok_ctx[:2], layer=layer, **kwargs
+            ), dec_k, dec_v,
+        )
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(ref), atol=1e-5, rtol=1e-4
+        )
+
+
+def test_stacked_pool_needs_its_layer(rng):
+    """The pool's rank decides: a stacked pool with no layer named is
+    refused, by the readers and by the writers alike."""
+    from distllm_tpu.ops.paged_attention import write_token_kv
+
+    q, k, v, bt, ctx, pos, q_lens = _setup(rng)
+    with pytest.raises(ValueError, match='stacked pool'):
+        ragged_paged_attention_xla(q, k[None], v[None], bt, ctx, pos)
+    with pytest.raises(ValueError, match='stacked pool'):
+        write_token_kv(k[None], v[None], q[:, 0, :2], q[:, 0, :2], bt, ctx - 1)
+
+
 def test_ragged_parity_query_tiling_and_chunking(rng):
     """Long spans across multiple query tiles and multi-page KV chunks:
     tiling must be invisible (same values as the untiled XLA gather)."""
