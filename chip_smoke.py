@@ -160,7 +160,6 @@ def phase_device(cache_dir: str) -> dict:
     import jaxlib
 
     from distllm_tpu.observability.roofline import device_peaks
-    from distllm_tpu.utils import compile_cache_entries
 
     device = jax.devices()[0]
     peak_flops, peak_bw = device_peaks(device)  # raises on an unknown kind
@@ -174,7 +173,23 @@ def phase_device(cache_dir: str) -> dict:
         'peak_flops': peak_flops,
         'peak_hbm_bytes_per_s': peak_bw,
         'compile_cache_dir': cache_dir,
-        'compile_cache_entries': compile_cache_entries(),
+        'compiled_so_far': compile_counts(),
+    }
+
+
+def compile_counts() -> dict:
+    """What the process has compiled or loaded so far, by jax's own cache
+    events (``CompileWatcher.summary``): programs, those the persistent
+    cache gave back to XLA for a second or more, and the seconds of
+    loading hits and of compiling the rest."""
+    from distllm_tpu.observability.startup import get_compile_watcher
+
+    summary = get_compile_watcher().summary()
+    return {
+        'programs': summary['programs'],
+        'cache_miss_programs': summary['cache_miss_programs'],
+        'cache_load_s': round(summary['cache_load_s'], 1),
+        'compile_miss_s': round(summary['compile_miss_s'], 1),
     }
 
 
@@ -1021,7 +1036,6 @@ def phase_serve(seed: int) -> dict:
     from distllm_tpu.chat import ChatAppConfig
     from distllm_tpu.generate.engine import kv_cache
     from distllm_tpu.registry import registry
-    from distllm_tpu.utils import compile_cache_entries
 
     model_dir = WORK / 'mistral'
     start = time.perf_counter()
@@ -1029,7 +1043,7 @@ def phase_serve(seed: int) -> dict:
     write_s = time.perf_counter() - start
     note(f'serve: wrote {written_gb:.2f} GB of checkpoint in {write_s:.0f}s')
     settings = generator_settings(model_dir)
-    entries_before = compile_cache_entries()
+    compiled_before = compile_counts()
 
     start = time.perf_counter()
     app = chat_server.build_app(ChatAppConfig(generator_config=settings))
@@ -1057,7 +1071,7 @@ def phase_serve(seed: int) -> dict:
     prompts['shared_prefix_a_again'] = prompts['shared_prefix_a']
     served = asyncio.run(_drive_server(app, engine, prompts))
     metrics_text = served.pop('metrics_text')
-    entries_after = compile_cache_entries()
+    compiled_after = compile_counts()
 
     check(served['health'] == 200, f"/health answered {served['health']}")
     requests = served['requests']
@@ -1134,8 +1148,8 @@ def phase_serve(seed: int) -> dict:
         'scheduler': scheduler,
         'allocator': allocator,
         'prefix_cache_hit_blocks': hit_blocks,
-        'compile_cache_entries_before': entries_before,
-        'compile_cache_entries_after': entries_after,
+        'compiled_before': compiled_before,
+        'compiled_after': compiled_after,
     }
     registry().clear()  # shuts the engine down and frees its arrays
     return fields

@@ -683,22 +683,34 @@ class LLMEngine:
         deletion) may delete the caller's buffers. Required to serve 7B
         bf16 on a 16 GB chip — without it the engine keeps the caller's
         copies alive and falls back to layout-copying dispatches."""
-        self.model_cfg = model_cfg
-        self.params = params
-        self.tokenizer = tokenizer
         self.config = config or EngineConfig()
-        self._own_params = own_params
-        cfg = self.config
         # Startup attribution (docs/observability.md): every expensive
-        # init/warmup phase below lands as a 'compile' flight record, so
-        # a wedged startup — the r03/r04 bench failure mode — names the
-        # phase it died in. The first engine in a process also pays (and
-        # attributes) the real backend init here; later calls are
-        # near-instant cache-hit records.
+        # init/warmup phase lands as a 'compile' flight record, so a
+        # wedged startup — the r03/r04 bench failure mode — names the
+        # phase it died in.
         self._compile_watcher = get_compile_watcher().listen()
         # Per-engine dedup scope: a rebuilt engine's jit wrappers really
         # recompile, so its phases must start cold in the watcher.
         self._compile_scope = self._compile_watcher.new_scope()
+        # The whole construction is one phase around its inner ones: what
+        # it spends under none of them (the scheduler and its native build,
+        # program construction, pricing) is that phase's remainder.
+        family = type(model_cfg).__module__.rpartition('.')[2]
+        with self._compile_watcher.phase(
+            'engine_init', f'{family}:b{self.config.max_num_seqs}',
+            scope=self._compile_scope,
+        ):
+            self._build(model_cfg, params, tokenizer, mesh, own_params)
+
+    def _build(self, model_cfg, params, tokenizer, mesh, own_params) -> None:
+        self.model_cfg = model_cfg
+        self.params = params
+        self.tokenizer = tokenizer
+        self._own_params = own_params
+        cfg = self.config
+        # The first engine in a process also pays (and attributes) the
+        # real backend init here; later calls are near-instant cache-hit
+        # records.
         record_backend_init(self._compile_watcher)
 
         # Tensor parallelism: K/V pages shard over the kv heads on the

@@ -446,9 +446,11 @@ FLIGHT_KINDS = frozenset({
     'compile',  # one startup/compile phase (observability/startup.py):
                 # backend init, warmup ladder shapes, layout migration —
                 # or one compiled program (jax's backend-compile event:
-                # program/duration_s/cache_hit, path startup|serving;
-                # at startup its phase/shape, on the serving path
-                # during/seq, and relowered/changed for an engine call)
+                # program/duration_s/cache_hit, its stages trace_s/
+                # lower_s/cache, path startup|serving; at startup its
+                # phase/shape, on the serving path during/seq, and
+                # relowered/changed for an engine call). Both carry
+                # t0_s/t1_s on the step records' clock
     'fault',    # one injected fault firing (resilience/faults.py:
                 # site/fired/call — the chaos schedule made attributable)
     'recovery', # one serving-loop retry after a failed dispatch
@@ -492,6 +494,8 @@ STEP_SPANS = frozenset({
 # tests/test_lint.py). A phase minted at a call site would fragment the
 # startup schema that debug bundles and the Perfetto startup track replay.
 COMPILE_PHASES = frozenset({
+    'engine_init',        # the whole of LLMEngine.__init__, around the
+                          # phases it opens (startup.summary's stretches)
     'backend_init',       # first jax.devices() touch (PJRT client init)
     'quantize',           # weight-only quantization of the param tree
     'auto_layout',        # AOT decode-window compile with Layout.AUTO

@@ -132,8 +132,14 @@ def enable_compile_cache() -> str:
     ``JAX_COMPILATION_CACHE_DIR`` set: jax already reads it, so nothing is
     set in code. Unset: ``<checkout>/.jax_cache`` — a fixed path, because
     the path is part of the cache key and a directory that moves never
-    hits. Call first thing in every entry point, before the first compile.
+    hits. Call first thing in every entry point, before the first compile:
+    the process's compile watcher listens from here on, so what an entry
+    point compiles before it builds an engine (its weights) is on the
+    start-up account too (``observability/startup.py``).
     """
+    from distllm_tpu.observability.startup import get_compile_watcher
+
+    get_compile_watcher().listen()
     env_dir = os.environ.get('JAX_COMPILATION_CACHE_DIR')
     if env_dir:
         return env_dir
@@ -142,21 +148,6 @@ def enable_compile_cache() -> str:
     cache_dir = str(Path(__file__).resolve().parents[1] / '.jax_cache')
     jax.config.update('jax_compilation_cache_dir', cache_dir)
     return cache_dir
-
-
-def compile_cache_entries() -> int | None:
-    """Entry count of the compilation cache directory jax is configured
-    with (``None`` when no cache is configured or the directory does not
-    exist yet). Before/after deltas show whether a start hit the cache."""
-    import jax
-
-    cache_dir = jax.config.jax_compilation_cache_dir
-    if not cache_dir:
-        return None
-    try:
-        return len(os.listdir(cache_dir))
-    except OSError:
-        return None
 
 
 def batch_data(data: list[T], batch_size: int) -> list[list[T]]:

@@ -232,8 +232,6 @@ def test_enable_compile_cache_places_the_cache(monkeypatch, tmp_path, env_set):
             want = str(Path(utils.__file__).resolve().parents[1] / '.jax_cache')
             assert utils.enable_compile_cache() == want
             assert jax.config.jax_compilation_cache_dir == want
-            entries = utils.compile_cache_entries()
-            assert entries is None or entries >= 0
     finally:
         jax.config.update('jax_compilation_cache_dir', before)
 

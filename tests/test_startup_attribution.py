@@ -2,11 +2,14 @@
 profiler capture (ISSUE 11 tentpole + satellites): one ``compile`` flight
 record per warmup shape with cache-hit marking on re-warmup, the Perfetto
 startup track, measured-vs-analytic MFU gauges from ``cost_analysis()``,
-``startup.json`` in debug bundles, and capture error-safety."""
+``startup.json`` in debug bundles, and capture error-safety. ISSUE 36: a
+program's stages and clock stamps, nested phases under ``engine_init``,
+and the account (``summary``) that outlives the ring and the engine."""
 
 from __future__ import annotations
 
 import json
+import threading
 import time
 
 import jax
@@ -30,6 +33,7 @@ from distllm_tpu.observability import (
     to_trace_events,
     validate_trace_events,
 )
+from distllm_tpu.observability import startup, steps
 from distllm_tpu.observability.perfetto import _STARTUP_TID
 
 
@@ -173,7 +177,7 @@ def test_compile_watcher_names_the_phase_in_progress():
     watch = CompileWatcher(recorder=FlightRecorder())
     with watch.phase('decode_window', 'b32x16') as fields:
         fields['note'] = 'wedged here'
-        active = watch.state()['active']
+        (active,) = watch.state()['active']
         assert active['phase'] == 'decode_window'
         assert active['shape'] == 'b32x16'
         assert active['t_start_wall'] <= time.time()
@@ -181,16 +185,71 @@ def test_compile_watcher_names_the_phase_in_progress():
     assert watch.state()['phases'][-1]['note'] == 'wedged here'
 
 
-def _jax_compiles(program: str, *, from_cache: bool, seconds=0.01) -> None:
-    """What jax reports when it compiles ``program`` (or loads it from the
-    persistent cache): its own monitoring events, in its own order."""
+def test_open_phases_are_a_stack_innermost_last():
+    """``engine_init`` holds the engine's own phases: a bundle dumped in
+    one of them says ``engine_init > auto_layout``, an inner phase's exit
+    leaves the outer one open, and a program names the innermost phase
+    while every open phase counts it."""
+    recorder = FlightRecorder()
+    watch = CompileWatcher(recorder=recorder).listen()
+    try:
+        with watch.phase('engine_init', 'mistral:b4'):
+            with watch.phase('auto_layout', 'b4'):
+                assert [p['phase'] for p in watch.state()['active']] == [
+                    'engine_init', 'auto_layout',
+                ]
+                _jax_compiles('jit(window_fn)', from_cache=True)
+            assert [p['phase'] for p in watch.state()['active']] == [
+                'engine_init'
+            ]
+            _jax_compiles('jit(merge)', from_cache=False)
+        assert watch.state()['active'] is None
+    finally:
+        watch.unlisten()
+    inner_program, inner, outer_program, outer = _compile_records(recorder)
+    assert (inner_program['phase'], inner_program['path']) == (
+        'auto_layout', 'startup'
+    )
+    assert (outer_program['phase'], outer_program['shape']) == (
+        'engine_init', 'mistral:b4'
+    )
+    assert (inner['programs'], inner['cache_hits']) == (1, 1)
+    assert (outer['programs'], outer['cache_hits']) == (2, 1)
+    assert inner['cache_hit'] and not outer['cache_hit']
+    # the inner phase lies inside the outer one on the step records' clock
+    assert outer['t0_s'] <= inner['t0_s'] <= inner['t1_s'] <= outer['t1_s']
+
+
+def _jax_traces(program: str, seconds: float) -> None:
     from jax import monitoring
 
+    monitoring.record_event_duration_secs(
+        '/jax/core/compile/jaxpr_trace_duration', seconds, fun_name=program
+    )
+
+
+def _jax_compiles(program: str, *, from_cache: bool, seconds=0.01,
+                  written=False, trace_s=(), lower_s=None) -> None:
+    """What jax reports when it compiles ``program`` (or loads it from the
+    persistent cache): its own monitoring events, in its own order.
+    ``written``: the compile was asked of the cache, missed and was written
+    to it. ``trace_s``: one trace event each; ``lower_s``: the lowering."""
+    from jax import monitoring
+
+    for traced in trace_s:
+        _jax_traces(program, traced)
+    if lower_s is not None:
+        monitoring.record_event_duration_secs(
+            '/jax/core/compile/jaxpr_to_mlir_module_duration', lower_s,
+            fun_name=program,
+        )
     if from_cache:
         monitoring.record_event('/jax/compilation_cache/cache_hits')
         monitoring.record_event_duration_secs(
             '/jax/compilation_cache/cache_retrieval_time_sec', seconds / 2
         )
+    elif written:
+        monitoring.record_event('/jax/compilation_cache/cache_misses')
     monitoring.record_event_duration_secs(
         '/jax/core/compile/backend_compile_duration', seconds,
         fun_name=program,
@@ -260,13 +319,21 @@ def test_watcher_that_does_not_listen_knows_only_process_repeat():
 
 
 def test_compile_outside_a_phase_is_a_serving_record():
+    """... once an engine has been built. Before the first ``engine_init``
+    has closed, what an entry point compiles under no phase and no step
+    span (its weights) is start-up, and no serving metric may see it."""
     recorder = FlightRecorder()
     watch = CompileWatcher(recorder=recorder).listen()
     try:
+        _jax_compiles('jit(fill)', from_cache=False, seconds=0.3)
+        with watch.phase('engine_init', 'mistral:b4'):
+            pass
         _jax_compiles('jit(prefill_paged_fn)', from_cache=False, seconds=0.2)
     finally:
         watch.unlisten()
-    (record,) = _compile_records(recorder)
+    early, _, record = _compile_records(recorder)
+    assert early['path'] == 'startup' and early['program'] == 'jit(fill)'
+    assert not {'phase', 'during', 'seq'} & set(early)
     assert record['path'] == 'serving' and 'phase' not in record
     assert record['program'] == 'jit(prefill_paged_fn)'
     assert record['duration_s'] == 0.2 and not record['cache_hit']
@@ -337,13 +404,267 @@ def test_compile_series_in_exposition():
         assert f'# TYPE {name} ' in text, name
 
 
+# ------------------------------------- a program's stages and its clock
+def _listening_from_a_clean_slate(recorder) -> CompileWatcher:
+    """A listening watcher whose first program inherits nothing: what this
+    thread traced and never compiled before (another test's engine) is
+    claimed here by a compile that no watcher of the test hears."""
+    CompileWatcher().listen().unlisten()  # jax's listeners are installed
+    _jax_compiles('jit(earlier)', from_cache=False)
+    return CompileWatcher(recorder=recorder).listen()
+
+
+@pytest.mark.parametrize('events, cache', [
+    ({'from_cache': True}, 'hit'),
+    ({'from_cache': False, 'written': True}, 'miss'),
+    ({'from_cache': False}, 'uncached'),
+])
+def test_program_record_says_what_the_persistent_cache_did(events, cache):
+    recorder = FlightRecorder()
+    watch = _listening_from_a_clean_slate(recorder)
+    try:
+        before = steps.clock()
+        _jax_compiles('jit(window_fn)', seconds=0.25, trace_s=(0.5,),
+                      lower_s=0.125, **events)
+        after = steps.clock()
+        _jax_compiles('jit(merge)', from_cache=False)  # claims nothing old
+    finally:
+        watch.unlisten()
+    record, following = _compile_records(recorder)
+    assert record['cache'] == cache
+    assert record['cache_hit'] is (cache == 'hit')
+    assert (record['trace_s'], record['lower_s']) == (0.5, 0.125)
+    # t1_s is the clock read at jax's event, t0_s that less its seconds
+    assert before <= record['t1_s'] <= after
+    assert record['t1_s'] - record['t0_s'] == pytest.approx(0.25, abs=1e-5)
+    assert (following['cache'], following['trace_s'], following['lower_s']) == (
+        'uncached', 0.0, 0.0
+    )
+    # the watcher keeps the same two records itself, outside the ring
+    kept = watch.state()['programs']
+    assert [p['program'] for p in kept] == ['jit(window_fn)', 'jit(merge)']
+    stages = ('cache', 'trace_s', 'lower_s', 'duration_s', 't0_s', 't1_s')
+    assert {k: kept[0][k] for k in stages} == {k: record[k] for k in stages}
+
+
+def test_a_real_jit_has_all_three_stages_on_the_step_clock():
+    recorder = FlightRecorder()
+    watch = _listening_from_a_clean_slate(recorder)
+    inner = jax.jit(lambda x: jax.numpy.tanh(x) * 3)
+
+    def outer(x):  # a new function, so this process has not traced it
+        return inner(x) @ x + 36
+
+    try:
+        before = steps.clock()
+        jax.block_until_ready(jax.jit(outer)(np.ones((8, 8), np.float32)))
+        after = steps.clock()
+    finally:
+        watch.unlisten()
+    (record,) = [
+        r for r in _compile_records(recorder) if r['program'] == 'jit(outer)'
+    ]
+    assert record['trace_s'] > 0 and record['lower_s'] > 0
+    assert before <= record['t0_s'] < record['t1_s'] <= after
+    assert record['cache'] in ('hit', 'miss', 'uncached')
+    assert record['thread'] == threading.current_thread().name
+    # the inner jit was traced inside the outer one's extent, whose seconds
+    # hold it: all three stages fit the wall time of the call
+    whole = record['trace_s'] + record['lower_s'] + record['duration_s']
+    assert whole <= after - before
+
+
+def test_trace_with_no_compile_does_not_leak_to_another_thread():
+    """jax's trace and lowering events are held for the thread they fired
+    on: ``jax.eval_shape`` here never reaches a backend compile, and the
+    next program of another thread must not inherit its seconds."""
+    recorder = FlightRecorder()
+    watch = _listening_from_a_clean_slate(recorder)
+    try:
+        _jax_traces('jit(never_compiled)', 7.0)
+        worker = threading.Thread(
+            target=_jax_compiles, args=('jit(other)',),
+            kwargs={'from_cache': False, 'trace_s': (0.25,)}, name='other',
+        )
+        worker.start()
+        worker.join(timeout=30)
+        assert not worker.is_alive()
+        _jax_compiles('jit(mine)', from_cache=False)
+    finally:
+        watch.unlisten()
+    other, mine = _compile_records(recorder)
+    assert (other['thread'], other['trace_s']) == ('other', 0.25)
+    assert mine['trace_s'] == 7.0  # this thread's next compile claims it
+
+
+def test_two_traces_before_one_compile_are_one_record():
+    """An outer jit that traces an inner one fires two trace events and
+    one backend compile: one record, whose ``trace_s`` holds both."""
+    recorder = FlightRecorder()
+    watch = _listening_from_a_clean_slate(recorder)
+    try:
+        _jax_compiles('jit(outer)', from_cache=False, trace_s=(0.5, 0.25),
+                      lower_s=0.125)
+    finally:
+        watch.unlisten()
+    (record,) = _compile_records(recorder)
+    assert (record['trace_s'], record['lower_s']) == (0.75, 0.125)
+
+
+def test_a_phase_holds_only_what_its_own_thread_compiles():
+    recorder = FlightRecorder()
+    watch = _listening_from_a_clean_slate(recorder)
+    try:
+        with watch.phase('engine_init', 'mistral:b4'):
+            worker = threading.Thread(
+                target=_jax_compiles, args=('jit(reference)',),
+                kwargs={'from_cache': False}, name='ahead',
+            )
+            worker.start()
+            worker.join(timeout=30)
+            assert not worker.is_alive()
+    finally:
+        watch.unlisten()
+    program, phase = _compile_records(recorder)
+    assert program['thread'] == 'ahead' and 'phase' not in program
+    assert phase['programs'] == 0
+
+
+# ------------------------------------------------ the account (summary)
+def test_engine_init_nests_the_engines_phases_and_the_stretches_add_up(
+    monkeypatch,
+):
+    import distllm_tpu.generate.engine.engine as engine_module
+
+    shared = CompileWatcher(recorder=FlightRecorder())
+    monkeypatch.setattr(engine_module, 'get_compile_watcher', lambda: shared)
+    try:
+        engine, _ = _tiny_engine(quantization='int8')
+    finally:
+        shared.unlisten()
+    phases = {p['phase']: p for p in shared.state()['phases']}
+    init = phases.pop('engine_init')
+    assert init['shape'] == 'mistral:b4'
+    # (auto_layout and migrate_params are a TPU's, state_allocate a
+    # hybrid model's: the CPU's tiny mistral opens these three)
+    assert set(phases) == {'backend_init', 'quantize', 'kv_allocate'}
+    for inner in phases.values():
+        assert init['t0_s'] <= inner['t0_s'] <= inner['t1_s'] <= init['t1_s']
+    until = steps.clock()
+    summary = shared.summary(until_s=until)
+    assert summary['process_start_s'] == startup.process_start_s()
+    assert summary['before_engine_s'] == pytest.approx(
+        init['t0_s'] - startup.process_start_s()
+    )
+    assert summary['engine_init_s'] == pytest.approx(init['duration_s'], abs=1e-5)
+    assert (
+        summary['before_engine_s'] + summary['engine_init_s']
+        + summary['after_engine_s']
+    ) == pytest.approx(until - startup.process_start_s(), abs=1e-3)
+    assert 0 <= summary['unphased_init_s'] <= summary['engine_init_s']
+    assert summary['unphased_init_s'] == pytest.approx(
+        init['duration_s'] - sum(p['duration_s'] for p in phases.values())
+        - sum(
+            p['trace_s'] + p['lower_s'] + p['duration_s']
+            for p in shared.state()['programs']
+            if p.get('phase') == 'engine_init'
+        ),
+        abs=1e-4,
+    )
+    engine.shutdown()
+
+
+def test_process_start_is_on_the_step_clock_and_before_the_import():
+    start = startup.process_start_s()
+    assert start == startup.process_start_s()
+    assert start <= startup._IMPORTED_S <= steps.clock()
+    assert steps.clock() - start < 24 * 3600  # this process, not the boot
+
+
+def test_programs_survive_the_engine_and_a_wrapped_ring():
+    recorder = FlightRecorder(capacity=8)
+    watch = _listening_from_a_clean_slate(recorder)
+    try:
+        engine, _ = _tiny_engine()
+        engine._compile_watcher = watch
+        engine.warmup()
+        engine.shutdown()
+    finally:
+        watch.unlisten()
+    del engine
+    assert recorder.total_recorded > recorder.capacity  # the ring wrapped
+    programs = watch.state()['programs']
+    assert len(programs) > 8
+    assert {'jit(window_fn)', 'jit(prefill_fn)'} <= {
+        p['program'] for p in programs
+    }
+    assert all(p['path'] == 'startup' for p in programs)
+    for program in programs:
+        assert {'trace_s', 'lower_s', 'cache', 't0_s', 't1_s', 'thread',
+                'duration_s', 'cache_hit', 't_wall'} <= set(program)
+    summary = watch.summary()
+    assert summary['programs'] == len(programs)
+    assert summary['cache_load_s'] + summary['compile_miss_s'] == pytest.approx(
+        sum(p['duration_s'] for p in programs)
+    )
+    assert json.dumps(watch.state())  # what startup.json writes
+
+
+def test_summary_leaves_out_what_starts_after_the_cut():
+    watch = _listening_from_a_clean_slate(FlightRecorder())
+    try:
+        _jax_compiles('jit(fill)', from_cache=False, written=True, seconds=2.0)
+        with watch.phase('engine_init', 'mistral:b4'):
+            with watch.phase('auto_layout', 'b4'):
+                _jax_compiles('jit(window_fn)', from_cache=True, seconds=0.5)
+            _jax_compiles('jit(loose)', from_cache=False, seconds=0.25,
+                          trace_s=(0.25,))
+        _jax_compiles('jit(prefill_fn)', from_cache=True, seconds=0.125,
+                      trace_s=(1.0,), lower_s=0.5)
+        cut = steps.clock()
+        time.sleep(0.01)
+        _jax_compiles('jit(check)', from_cache=False, written=True,
+                      seconds=0.001)
+        with watch.phase('engine_init', 'mistral:b4'):  # a second engine
+            pass
+    finally:
+        watch.unlisten()
+    whole = watch.summary()
+    assert whole['programs'] == 5 and whole['cache_miss_programs'] == 1
+    summary = watch.summary(until_s=cut)
+    assert summary['until_s'] == cut and summary['programs'] == 4
+    assert summary['cache_load_s'] == pytest.approx(0.625)
+    assert summary['compile_miss_s'] == pytest.approx(2.25)
+    assert summary['trace_lower_s'] == pytest.approx(1.75)
+    # a miss under a second is the cache's floor at work, not an eviction
+    assert summary['cache_miss_programs'] == 1
+    assert summary['after_engine_program_s'] == pytest.approx(1.625)
+    first = next(
+        p for p in watch.state()['phases'] if p['phase'] == 'engine_init'
+    )
+    assert summary['engine_init_s'] == pytest.approx(first['duration_s'], abs=1e-5)
+    assert summary['after_engine_s'] == pytest.approx(cut - first['t1_s'])
+    # under no inner phase and no program: the phase less auto_layout and
+    # the loose program's half second
+    inner = next(
+        p for p in watch.state()['phases'] if p['phase'] == 'auto_layout'
+    )
+    assert summary['unphased_init_s'] == pytest.approx(
+        max(0.0, first['duration_s'] - inner['duration_s'] - 0.5), abs=1e-5
+    )
+    # a watcher that saw no engine: one stretch, and no account of __init__
+    bare = CompileWatcher(recorder=FlightRecorder()).summary(until_s=cut)
+    assert bare['before_engine_s'] == cut - startup.process_start_s()
+    assert bare['engine_init_s'] is None and bare['unphased_init_s'] is None
+
+
 # --------------------------------------------------- debug bundle satellite
 def test_debug_bundle_includes_startup_state(tmp_path):
     paths = dump_debug_bundle(tmp_path / 'bundle', reason='startup test')
     assert 'startup' in paths
     state = json.loads((tmp_path / 'bundle' / 'startup.json').read_text())
     assert set(state) == {'compile', 'profiler'}
-    assert 'active' in state['compile'] and 'phases' in state['compile']
+    assert {'active', 'phases', 'programs'} <= set(state['compile'])
     assert 'captures_total' in state['profiler']
 
 
@@ -356,7 +677,7 @@ def test_debug_bundle_names_dead_phase_mid_stall(tmp_path):
     with watch.phase('migrate_params', 'params'):
         dump_debug_bundle(tmp_path / 'stall', reason='wedged migrate')
     state = json.loads((tmp_path / 'stall' / 'startup.json').read_text())
-    assert state['compile']['active']['phase'] == 'migrate_params'
+    assert state['compile']['active'][-1]['phase'] == 'migrate_params'
 
 
 # ------------------------------------------- measured XLA cost (xla_cost)
