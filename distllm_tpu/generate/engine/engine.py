@@ -76,6 +76,7 @@ from distllm_tpu.ops.paged_attention import (
     fold_heads,
     quantize_kv_rows,
     unfold_heads,
+    walk_keys_a_step,
 )
 from distllm_tpu.ops.sampling import fold_row_keys, sample_tokens
 from distllm_tpu.resilience.admission import (
@@ -1270,6 +1271,24 @@ class LLMEngine:
                 }
                 for group, kv in zip(spec.paged, (self.kv, self.window_kv))
             }
+        # Keys a step of the paged kernel's row walk over each pool (decode
+        # calls under the Pallas backends: ``ops.paged_attention.
+        # walk_keys_a_step``, capped by the table as the kernel caps it);
+        # ``kv_chunks*`` on the decode records is reckoned with them.
+        self._walk_keys = {}
+        if attn_backend in ('pallas', 'interpret'):
+            self._walk_keys = {
+                group.name: min(
+                    walk_keys_a_step(
+                        kv.pool_shape[-1], kv.dtype,
+                        planes=1 if group.row else 2,
+                        block_size=cfg.block_size,
+                    ),
+                    self.max_blocks_per_seq * cfg.block_size,
+                )
+                for group, kv in zip(spec.paged, (self.kv, self.window_kv))
+            }
+            self.telemetry['kv_walk_keys'] = dict(self._walk_keys)
         if self.state_pool is not None:
             with self._compile_watcher.phase(
                 'state_allocate', f'slots{cfg.max_num_seqs}', compiles=False,
@@ -3688,6 +3707,34 @@ class LLMEngine:
         bs = self.config.block_size
         return sum(int(((c + (bs - 1)) // bs).sum()) for c in context_lens)
 
+    def _kv_chunks(self, context_lens: np.ndarray) -> dict:
+        """``kv_chunks*``: the chunks the paged kernel's row walk fetches
+        for a decode dispatch's rows in one step of one layer of each cache
+        group: from the chunk that holds a row's sliding-window floor to the
+        one that holds its context's end (a pad row's one token is one
+        chunk). Against ``rows x ceil(table blocks / pages a chunk)`` it is
+        the share of the grid over chunks that fetched anything. Nothing
+        under a backend that has no walk. Reckoned where ``kv_blocks`` is,
+        after the step's spans have closed."""
+        if not self._walk_keys:
+            return {}
+        fields = {}
+        groups = self.cache_spec.paged
+        for group in groups:
+            keys = self._walk_keys[group.name]
+            floor = 0
+            if group.window is not None:
+                floor = np.maximum(context_lens - group.window, 0)
+            name = 'kv_chunks' if len(groups) == 1 else (
+                'kv_chunks_window' if group.window else 'kv_chunks_full'
+            )
+            fields[name] = int(
+                ((context_lens + keys - 1) // keys - floor // keys).sum()
+            )
+        if len(groups) > 1:
+            fields['kv_chunks'] = fields['kv_chunks_full']
+        return fields
+
     def _rids_field(self, requests: list[Request]) -> dict:
         if not self.attribution:
             return {}
@@ -4651,6 +4698,8 @@ class LLMEngine:
             kv_blocks = self._kv_blocks(*window['context_lens'])
             if window.get('window_fields'):
                 extra.update(window['window_fields'], kv_blocks_full=kv_blocks)
+            if not chunk_entries:  # a mixed window's rows ride a span program
+                extra.update(self._kv_chunks(window['context_lens'][0]))
             self._record_step(
                 'mixed' if chunk_entries else 'decode',
                 step,

@@ -30,14 +30,14 @@ formulation, which has two implementations behind one backend selector
 - :func:`ragged_paged_attention_xla` — gather + masked softmax; XLA fuses
   this well and it is the portable, always-available baseline (also runs on
   CPU for tests) and the bit-exactness reference.
-- :func:`ragged_paged_attention_pallas` — fused Pallas TPU kernel: grid
-  over (row, query tile, KV chunk); block tables are scalar-prefetched and
-  each grid step explicitly DMAs only the row's live KV pages HBM→VMEM
-  with double buffering (issue chunk c+1 while computing chunk c),
-  online-softmax accumulation in fp32 scratch — no ``[.., S, T]`` score
-  tensor is ever materialized. Chunks outside a row's valid window (beyond
-  ``context_lens``, past the row's last query, or before the
-  sliding-window start) are skipped: no DMA, no compute.
+- :func:`ragged_paged_attention_pallas` — fused Pallas TPU kernel: block
+  tables are scalar-prefetched and only a row's live KV pages are DMA'd
+  HBM→VMEM, double-buffered (the next chunk in flight while one is
+  computed), online-softmax accumulation in fp32 scratch — no ``[.., S,
+  T]`` score tensor is ever materialized. A span over one runs a grid over
+  (row, query tile, KV chunk) that skips the chunks a tile cannot see; a
+  span of one (decode rows) WALKS the chunks each row holds under a grid
+  over rows (:func:`_walk_row`): no step exists that fetches nothing.
 
 A LATENT pool (``models.common.PagedGroup.row``) is one plane: the
 callers pass ``v_cache=None`` and ``value_lanes``, keys are the whole rows
@@ -518,10 +518,10 @@ def _ragged_paged_attn_kernel(
     scale: float,
     logit_softcap: float | None,
     quantized: bool = False,
-    value_lanes: int | None = None,
+    value_lanes: int | None = None, walk: bool = False,
 ):
-    """Grid (B, q_tiles, kv_chunks): one row × one query tile × one chunk
-    of KV pages per step.
+    """The SPAN schedule, grid (B, q_tiles, kv_chunks): one row × one query
+    tile × one chunk of KV pages per step (a span of one: ``_walk_row``).
 
     Pages of a chunk are DMA'd HBM→VMEM individually (they are scattered
     by the paged allocator), double-buffered across grid steps: while
@@ -550,25 +550,25 @@ def _ragged_paged_attn_kernel(
     from jax.experimental.pallas import tpu as pltpu
 
     latent = value_lanes is not None
-    if latent:
-        (
-            block_tables_ref, context_lens_ref, q_start_ref, q_lens_ref,
-            window_ref, q_ref, k_cache_ref, out_ref, k_buf, sems, acc_ref,
-            m_ref, l_ref,
-        ) = refs
-    elif quantized:
-        (
-            block_tables_ref, context_lens_ref, q_start_ref, q_lens_ref,
-            window_ref, q_ref, k_cache_ref, v_cache_ref, k_scale_ref,
-            v_scale_ref, out_ref, k_buf, v_buf, ks_buf, vs_buf, sems,
-            acc_ref, m_ref, l_ref,
-        ) = refs
-    else:
-        (
-            block_tables_ref, context_lens_ref, q_start_ref, q_lens_ref,
-            window_ref, q_ref, k_cache_ref, v_cache_ref, out_ref,
-            k_buf, v_buf, sems, acc_ref, m_ref, l_ref,
-        ) = refs
+    # One unpacking for both schedules (:func:`_kernel_refs`: absent
+    # operands are None). ``walk`` (a span of one: decode rows, grid
+    # (rows, 1, 1)) shares this function's scratch reset and ``compute``
+    # and takes its own schedule at :func:`_walk_row`, which copies the
+    # pages a row sees itself: the span schedule's ``issue`` and
+    # ``wait`` then copy nothing. Its statements stay on the lines and
+    # columns they had: the serialized kernel carries both, so a prefill
+    # program lowers to the text it had before the walk and finds its
+    # entry in the compile cache (PERF.md section 6, PR 38).
+    kernel_refs = _kernel_refs(refs, latent, quantized, walk)
+    (
+        block_tables_ref, context_lens_ref, q_start_ref, q_lens_ref,
+        window_ref, q_ref, k_cache_ref, v_cache_ref, k_scale_ref,
+        v_scale_ref, out_ref, k_buf, v_buf, ks_buf, vs_buf, sems,
+        acc_ref, m_ref, l_ref, _slot_ref,
+    ) = kernel_refs
+    span_pages = range(0 if walk else pages_per_chunk)
+
+    # Grid (rows, query tiles, chunks); under the walk (rows, 1, 1).
 
     seq = pl.program_id(0)
     qt = pl.program_id(1)
@@ -603,7 +603,7 @@ def _ragged_paged_attn_kernel(
         # compute mask discards anything outside [lo, hi). One contiguous
         # whole-page descriptor per page (the head fold keeps pages
         # contiguous, so the descriptor count stays 2 per page).
-        for p in range(pages_per_chunk):
+        for p in span_pages:
             logical = ci * pages_per_chunk + p
             page = jnp.clip(logical, 0, jnp.maximum(n_pages - 1, 0))
             page_id = block_tables_ref[seq, page]
@@ -635,7 +635,7 @@ def _ragged_paged_attn_kernel(
                 ).start()
 
     def wait(slot):
-        for p in range(pages_per_chunk):
+        for p in span_pages:
             rows_at = slice(p * block_size, (p + 1) * block_size)
             pltpu.make_async_copy(
                 k_cache_ref.at[0],
@@ -675,8 +675,8 @@ def _ragged_paged_attn_kernel(
     def _():
         issue(c + 1, (c + 1) % 2)
 
-    def compute(slot):
-        # ``slot`` is a PYTHON int (the caller branches on chunk parity):
+    def compute(slot, c=c):
+        # ``slot`` is a PYTHON int here (the caller branches on chunk parity):
         # every KV access below is a static-slot, static-lane-band load
         # straight from the ref. This toolchain's Mosaic rejects a
         # full-plane bf16 load of the folded buffer ("invalid offsets in
@@ -774,7 +774,7 @@ def _ragged_paged_attn_kernel(
             )  # [rows, Hd]
             acc_ref[h] = acc_ref[h] * correction[:, :1] + pv
             m_ref[h] = new_m
-
+    if walk: return _walk_row(kernel_refs, compute, block_size, pages_per_chunk)  # noqa: E701
     @pl.when(chunk_needed(c))
     def _():
         wait(c % 2)
@@ -839,12 +839,12 @@ def ragged_paged_attention_pallas(
     rows are the parity surface (pinned by the interpret-mode matrix in
     ``tests/test_ragged_attention.py``).
 
-    ``pages_per_chunk`` controls how many KV pages one grid step fetches
-    and computes (default: enough for 128 tokens); ``span_tile`` caps the
-    query-span positions per grid tile (default: up to 512 query rows
-    after GQA flattening) — both bound VMEM. ``interpret=True`` runs the
-    same kernel on the Pallas interpreter (CPU-runnable; the
-    ``attn_backend='interpret'`` engine tier).
+    ``pages_per_chunk`` controls how many KV pages one step fetches and
+    computes (default: 128 keys for a span over one, 512 on a latent
+    plane; :func:`walk_keys_a_step` for a span of one); ``span_tile`` caps
+    the query-span positions per grid tile (default: up to 512 query rows
+    after GQA flattening). ``interpret=True`` runs the same kernel on the
+    Pallas interpreter (the ``attn_backend='interpret'`` engine tier).
 
     A STACKED pool ``[L, blocks, block_size, folded]`` goes in whole with
     its ``layer``: the kernel fetches pages from HBM by id, so it is
@@ -895,19 +895,19 @@ def ragged_paged_attention_pallas(
             f'got {block_size}; use block_size={sublane} '
             "(EngineConfig.block_size) or attn_backend='xla'"
         )
+    # A span of one (decode rows) WALKS the chunks each row holds
+    # (:func:`_walk_row`, keys a step by :func:`walk_keys_a_step`); a
+    # longer span keeps the grid over the widest table's chunks.
+    walk = s == 1
     if pages_per_chunk is None:
-        # A latent plane is ONE head whose rows are 5 lane tiles wide, with
-        # every query head on it: a grid step of 128 keys is too little
-        # work beside the step's own cost, and a row's pages are fetched
-        # again for every query tile. On the chip at Kanana's widths (PR
-        # 32, PERF.md section 6): decode rows read 139 GB/s at 128 keys a
-        # step and 259 GB/s at 1024; a 512-query span at context 8448 ran
-        # 62 TFLOP/s at (512 rows, 128 keys) and 132 at (1024 rows, 512
-        # keys); 2048 rows do not fit VMEM.
-        chunk_tokens = 128 if not latent else 1024 if s == 1 else 512
+        # Spans: 128 keys a step over K/V pools, 512 over a latent plane
+        # (ONE head, rows 5 lane tiles wide, every query head on it: a
+        # 512-query span at context 8448 ran 62 TFLOP/s at 128 keys and
+        # 132 at 512 with 1024-row tiles, PR 32, PERF.md section 6).
+        chunk_tokens = _default_keys_a_step(k_data, latent, walk)
         pages_per_chunk = max(1, chunk_tokens // block_size)
     pages_per_chunk = min(pages_per_chunk, max_blocks)
-    num_chunks = -(-max_blocks // pages_per_chunk)
+    num_chunks = 1 if walk else -(-max_blocks // pages_per_chunk)
     if span_tile is None:
         # ~512 post-GQA query rows per tile keeps q/out/acc + the m/l
         # scratch + double-buffered KV pages comfortably inside VMEM at
@@ -982,7 +982,7 @@ def ragged_paged_attention_pallas(
             None if logit_softcap is None else float(logit_softcap)
         ),
         quantized=quantized,
-        value_lanes=value_lanes if latent else None,
+        value_lanes=value_lanes if latent else None, walk=walk,
     )
     kv_scratch = [
         pltpu.VMEM(
@@ -1018,7 +1018,7 @@ def ragged_paged_attention_pallas(
             pltpu.VMEM((num_kv_heads, rows, value_dim), jnp.float32),
             pltpu.VMEM((num_kv_heads, rows, 128), jnp.float32),
             pltpu.VMEM((num_kv_heads, rows, 128), jnp.float32),
-        ],
+        ] + [pltpu.SMEM((1,), jnp.int32)] * walk,  # the walk's slot
     )
     out = pl.pallas_call(
         kernel,
@@ -1092,6 +1092,261 @@ def ragged_paged_attention(
         q_lens=q_lens, sliding_window=sliding_window, scale=scale,
         logit_softcap=logit_softcap, value_lanes=value_lanes, layer=layer,
     )
+
+
+class _KernelRefs(NamedTuple):
+    """The paged kernel's operands by name, None where a call has none (a
+    latent pool has no V, a full-precision pool no scales, the span
+    schedule no ``slot``). The order is the grid spec's: scalar prefetch,
+    arrays, output, scratch (``_ragged_paged_attn_kernel`` says what each
+    holds)."""
+
+    block_tables: object
+    context_lens: object
+    q_start: object
+    q_lens: object
+    window: object
+    q: object
+    k_cache: object
+    v_cache: object
+    k_scale: object
+    v_scale: object
+    out: object
+    k_buf: object
+    v_buf: object
+    ks_buf: object
+    vs_buf: object
+    sems: object
+    acc: object
+    m: object
+    l: object  # noqa: E741 -- the online softmax's denominator
+    slot: object  # [1] int32 SMEM: the buffer slot the walk waits in next
+
+
+def _kernel_refs(refs, latent, quantized, walk) -> _KernelRefs:
+    refs = list(refs)
+
+    def take(n, present=True):
+        return [refs.pop(0) if present else None for _ in range(n)]
+
+    return _KernelRefs(
+        *take(7), *take(1, not latent), *take(2, quantized), *take(2),
+        *take(1, not latent), *take(2, quantized), *take(4), *take(1, walk),
+    )
+
+
+# The walk's chunk: the most keys a step, a power of two of at most
+# WALK_MAX_KEYS, whose two slots of K (and V) pages stay within
+# WALK_BUFFER_BYTES of VMEM and whose copies within WALK_SEMAPHORES (every
+# copy in flight has a DMA semaphore of its own; 512 of them ran out of
+# the chip's 2 KB for them). A whole chunk's copies go WALK_PAGES_A_TURN a
+# loop turn. PERF.md section 6 (PR 38) has the sweep on the chip behind
+# the four.
+WALK_MAX_KEYS = 1024
+WALK_BUFFER_BYTES = 8 << 20
+WALK_SEMAPHORES = 256
+WALK_PAGES_A_TURN = 8
+
+
+def walk_keys_a_step(
+    row_lanes: int, dtype, *, planes: int, block_size: int
+) -> int:
+    """Keys a step of the row walk (:func:`_walk_row`) fetches and folds
+    into the softmax, chosen from what a call's operands show: the folded
+    row width, the stored dtype (an int8 pool's page brings two scale
+    rows), the planes a key's page is copied from (2 for a K and a V
+    pool, 1 for a latent plane) and the block size. Not a setting and not
+    a family's name; ``pages_per_chunk=`` overrides it in tests.
+    """
+    itemsize = jnp.dtype(dtype).itemsize
+    copies = planes + (2 if itemsize == 1 else 0)  # a page's, in one slot
+    keys = min(
+        WALK_MAX_KEYS,
+        WALK_BUFFER_BYTES // (2 * planes * row_lanes * itemsize),
+        WALK_SEMAPHORES // (2 * copies) * block_size,
+    )
+    keys = max(keys, block_size)
+    return 1 << (keys.bit_length() - 1)
+
+
+def _default_keys_a_step(k_data, latent, walk) -> int:
+    if walk:
+        return walk_keys_a_step(
+            k_data.shape[-1], k_data.dtype, planes=1 if latent else 2,
+            block_size=k_data.shape[1],
+        )
+    return 512 if latent else 128
+
+
+def _walk_row(r: _KernelRefs, compute, block_size, pages_per_chunk):
+    """The paged kernel's schedule for a span of one (decode rows): grid
+    over ROWS, and inside a grid step a loop over the chunks that row
+    holds and can see, from the one with its sliding-window floor to the
+    one with its context's end. The trip count is read from the
+    scalar-prefetched lengths, so no step exists that fetches nothing
+    (the grid over the widest table's chunks ran 1,024 steps a
+    ``mistral7b`` decode call of which about 85 fetched); a row with no
+    sequence iterates nothing and emits exact zeros.
+
+    Only the pages the row sees are copied: a chunk wider than what the
+    row has left moves no byte for the rest, and the mask discards what
+    the buffer still holds there. Two buffer slots alternate along the
+    whole CALL's walk (``r.slot`` carries the next one across grid
+    steps, as the buffers and their semaphores are carried): while a
+    chunk is computed the next one's copies are in flight, the row's next
+    chunk or, on its last, the first chunk of the next row that has one.
+    Only the call's first chunk is waited for with nothing behind it.
+
+    ``compute(slot, chunk)`` is the span schedule's own online-softmax
+    block (``_ragged_paged_attn_kernel``), traced once with a traced
+    slot.
+    """
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    seq, rows = pl.program_id(0), pl.num_programs(0)
+    win = r.window[0]
+    # pages a turn: the largest power of two that divides a chunk's pages
+    group = min(pages_per_chunk & -pages_per_chunk, WALK_PAGES_A_TURN)
+    # (pool in HBM, its two-slot buffer) of what a call has: K and V or a
+    # latent plane, and an int8 pool's scale rows.
+    planes, scales = (
+        [pair for pair in pairs if pair[0] is not None]
+        for pairs in (
+            ((r.k_cache, r.k_buf), (r.v_cache, r.v_buf)),
+            ((r.k_scale, r.ks_buf), (r.v_scale, r.vs_buf)),
+        )
+    )
+
+    def visible(row):
+        """Pages ``[p_lo, p_hi)`` of ``row``'s table that its one query
+        sees (``(position - window, position]`` below the context's end):
+        ``p_hi == p_lo`` for a row with no valid query."""
+        q0 = r.q_start[row]
+        lo = jnp.where(win > 0, jnp.maximum(q0 - win + 1, 0), 0)
+        hi = jnp.minimum(r.context_lens[row], q0 + 1)
+        p_lo = lo // block_size
+        live = (r.q_lens[row] > 0) & (hi > lo)
+        return p_lo, jnp.where(live, (hi + block_size - 1) // block_size, p_lo)
+
+    def next_live(row):
+        """The first row at or after ``row`` that sees a page (``rows``
+        when none does): scalar reads only."""
+        def dead(t):
+            p_lo, p_hi = visible(jnp.minimum(t, rows - 1))
+            return (t < rows) & (p_hi <= p_lo)
+
+        return jax.lax.while_loop(dead, lambda t: t + 1, row)
+
+    def copies(row, chunk, p_lo, p_hi, slot, act, whole_chunks=True):
+        """``act`` (start, or wait for) the copy of every page of
+        ``chunk`` within ``[p_lo, p_hi)`` into ``slot``: one contiguous
+        whole-page descriptor a page and plane, and an int8 pool's scale
+        rows beside them. A loop over the pages the row sees; a chunk it
+        sees WHOLE (every chunk of a long row but its last, and its first
+        under a window) goes ``group`` pages a turn, straight-line code the
+        compiler can schedule: on the chip a turn a page cost a latent
+        plane, whose page is 25 ns on the wire, a fifth of the call
+        (PERF.md section 6, PR 38)."""
+        first = chunk * pages_per_chunk
+
+        def one(logical, p):  # ``p``: the page's place in the chunk
+            page_id = r.block_tables[row, logical]
+            at = pl.ds(pl.multiple_of(p * block_size, block_size), block_size)
+            for i, (pool, buf) in enumerate(planes):
+                act(pltpu.make_async_copy(
+                    pool.at[page_id], buf.at[slot, at], r.sems.at[slot, p, i]
+                ))
+            for i, (pool, buf) in enumerate(scales, len(planes)):
+                act(pltpu.make_async_copy(
+                    pool.at[page_id], buf.at[slot, p], r.sems.at[slot, p, i]
+                ))
+
+        def some():
+            def page(logical, carry):
+                one(logical, logical - first)
+                return carry
+
+            jax.lax.fori_loop(
+                jnp.maximum(p_lo, first),
+                jnp.minimum(p_hi, first + pages_per_chunk),
+                page, 0,
+            )
+
+        if not whole_chunks or group == 1:
+            return some()
+        whole = (p_lo <= first) & (first + pages_per_chunk <= p_hi)
+
+        @pl.when(whole)
+        def _():
+            def turn(g, carry):
+                for j in range(group):
+                    one(first + g * group + j, g * group + j)
+                return carry
+
+            jax.lax.fori_loop(0, pages_per_chunk // group, turn, 0)
+
+        pl.when(jnp.logical_not(whole))(some)
+
+    def start(copy):
+        copy.start()
+
+    def wait(copy):
+        copy.wait()
+
+    @pl.when(seq == 0)
+    def _():
+        # What meets a zero probability unmasked must be finite: a page
+        # that is not fetched leaves its band of the value buffer as it
+        # was, and fresh VMEM holds anything. After this only pool bytes
+        # land there.
+        for buf in (planes[-1][1], *(buf for _, buf in scales[1:])):
+            buf[...] = jnp.zeros_like(buf)
+        r.slot[0] = 0
+        first = next_live(0)
+
+        @pl.when(first < rows)
+        def _():
+            p_lo, p_hi = visible(first)
+            copies(
+                first, p_lo // pages_per_chunk, p_lo, p_hi, 0, start,
+                whole_chunks=False,  # once a call: not worth its trace
+            )
+
+    p_lo, p_hi = visible(seq)
+    c_lo = p_lo // pages_per_chunk
+    c_hi = (p_hi + pages_per_chunk - 1) // pages_per_chunk
+
+    @pl.when(p_hi <= p_lo)
+    def _():
+        # No valid query: no chunk, no wait, exact zeros (finite, so a pad
+        # row can never poison a reduction downstream).
+        r.out[...] = jnp.zeros_like(r.out)
+
+    @pl.when(p_hi > p_lo)
+    def _():
+        def chunk(ci, slot):
+            # Before chunk ci is waited for, start what is computed next
+            # into the other slot: this row's chunk ci + 1 or, on its
+            # last chunk, the first chunk of the next row that has one.
+            nxt = jax.lax.cond(
+                ci + 1 < c_hi, lambda: seq, lambda: next_live(seq + 1)
+            )
+
+            @pl.when(nxt < rows)
+            def _():
+                n_lo, n_hi = visible(nxt)
+                n_chunk = jnp.where(nxt == seq, ci + 1, n_lo // pages_per_chunk)
+                copies(nxt, n_chunk, n_lo, n_hi, 1 - slot, start)
+
+            copies(seq, ci, p_lo, p_hi, slot, wait)
+            compute(slot, ci)
+            return 1 - slot
+
+        r.slot[0] = jax.lax.fori_loop(c_lo, c_hi, chunk, r.slot[0])
+        for h in range(r.acc.shape[0]):
+            out = r.acc[h] / jnp.maximum(r.l[h][:, :1], 1e-9)
+            r.out[h] = out.astype(r.out.dtype)
 
 
 def paged_attention_pallas(

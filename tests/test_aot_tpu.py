@@ -80,6 +80,37 @@ def _assert_kernel_compiled(compiled) -> None:
     )
 
 
+def _kernel_schedules(compiled) -> list:
+    """``'walk'`` or ``'grid'`` for each paged-attention call of a
+    compiled program: a serialized Mosaic body names the functions its
+    source lines are in, and only the row walk's names ``_walk_row``."""
+    import base64
+    import re
+
+    bodies = re.findall(
+        r'custom_call_config[^A-Za-z0-9]+body[^A-Za-z0-9]+'
+        r'([A-Za-z0-9+/=]{100,})',
+        compiled.as_text(),
+    )
+    bodies = [base64.b64decode(body) for body in bodies]
+    return [
+        'walk' if b'_walk_row' in body else 'grid'
+        for body in bodies if b'_ragged_paged_attn_kernel' in body
+    ]
+
+
+def _assert_decode_calls_walk(compiled) -> None:
+    """Every paged-attention call of a decode window is a span of one
+    and takes the row walk."""
+    schedules = _kernel_schedules(compiled)
+    assert schedules and set(schedules) == {'walk'}, schedules
+
+
+def _assert_span_calls_keep_the_grid(compiled) -> None:
+    schedules = _kernel_schedules(compiled)
+    assert schedules and set(schedules) == {'grid'}, schedules
+
+
 # ---- kernel-only compiles at the real widths (tier-1, seconds each) ----
 
 # Mistral-7B-Instruct-v0.3 attention widths at the serving batch.
@@ -117,6 +148,61 @@ def test_ragged_kernel_compiles_at_7b_widths(v5e, kv, block_size, span):
         v5e((_B, span), jnp.int32), v5e((_B,), jnp.int32),
     ).compile()
     _assert_kernel_compiled(compiled)
+    assert _kernel_schedules(compiled) == ['walk' if span == 1 else 'grid']
+
+
+def _count_equations(jaxpr) -> int:
+    total = 0
+    for eqn in jaxpr.eqns:
+        total += 1
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, 'jaxpr', sub)
+                if hasattr(sub, 'eqns'):
+                    total += _count_equations(sub)
+    return total
+
+
+def _kernel_equations(span, *, rows, nh, nkv, hd, value_lanes=None):
+    """Equations of the kernel's jaxpr, nested ones counted, as a call
+    at these widths traces it (no topology needed: tracing only)."""
+    from distllm_tpu.ops.paged_attention import ragged_paged_attention_pallas
+
+    sds = jax.ShapeDtypeStruct
+    pool = sds((712, 16, nkv * hd), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(
+        lambda q, k, v, bt, ctx, pos, ql: ragged_paged_attention_pallas(
+            q, k, v, bt, ctx, pos, q_lens=ql, value_lanes=value_lanes
+        )
+    )(
+        sds((rows, span, nh, hd), jnp.bfloat16), pool,
+        None if value_lanes else pool, sds((rows, 256), jnp.int32),
+        sds((rows,), jnp.int32), sds((rows, span), jnp.int32),
+        sds((rows,), jnp.int32),
+    )
+    (call,) = [e for e in jaxpr.eqns if e.primitive.name == 'pallas_call']
+    return _count_equations(call.params['jaxpr'])
+
+
+# A paged prefill bucket's trace and lowering are mostly the kernel's
+# unrolled body, paid once a bucket (28 of them in ``mistral7b.chat_steady``:
+# PERF.md section 5), so the SPAN kernel's size is a set-up cost: these are
+# the parent's numbers (PR 36's tree, counted before the row walk went in).
+@pytest.mark.parametrize('widths,span,parent', [
+    (dict(rows=32, nh=32, nkv=8, hd=128), 16, 1015),
+    (dict(rows=4, nh=32, nkv=8, hd=128), 512, 1015),
+    (dict(rows=4, nh=48, nkv=8, hd=128), 512, 1015),
+    (dict(rows=4, nh=32, nkv=1, hd=640, value_lanes=512), 512, 880),
+], ids=['mistral16', 'mistral512', 'laguna512', 'kanana512'])
+def test_span_kernel_traces_no_more_than_the_parent(widths, span, parent):
+    """A span over one traces the parent's kernel, equation for
+    equation; the span-1 kernel (the row walk: ``compute`` traced once,
+    the page copies a loop) is smaller than it, printed beside it."""
+    spans = _kernel_equations(span, **widths)
+    walks = _kernel_equations(1, **widths)
+    print(f'kernel jaxpr equations: span {span}: {spans}, span 1: {walks}')
+    assert spans == parent
+    assert walks < spans
 
 
 @pytest.mark.parametrize(
@@ -575,6 +661,7 @@ def test_laguna_decode_window_reads_the_pools_as_they_lie(v5e, laguna_cell):
         v5e((b,), f32), v5e((b,), f32), v5e((b,), i32), v5e((b,), jnp.uint32),
     ).compile()
     _assert_pools_go_to_the_kernel_as_they_lie(compiled, buffers)
+    _assert_decode_calls_walk(compiled)
 
 
 def test_laguna_chunk_prefill_reads_the_pools_as_they_lie(v5e, laguna_cell):
@@ -591,6 +678,7 @@ def test_laguna_chunk_prefill_reads_the_pools_as_they_lie(v5e, laguna_cell):
         (v5e((4, 528), i32),) * 2, v5e((4,), i32), v5e((4,), i32),
     ).compile()
     _assert_pools_go_to_the_kernel_as_they_lie(compiled, buffers)
+    _assert_span_calls_keep_the_grid(compiled)
 
 
 # ---- a latent pool's planes go to the kernel as they lie (PR 32) ----
@@ -634,6 +722,7 @@ def test_decode_window_reads_the_planes_as_they_lie(v5e, kanana_cell):
         v5e((b,), f32), v5e((b,), i32), v5e((b,), jnp.uint32),
     ).compile()
     _assert_pools_go_to_the_kernel_as_they_lie(compiled, [plane])
+    _assert_decode_calls_walk(compiled)
     # the decode calls of the kernel, as the roofline metric's pattern
     # names them: [rows, 1 KV head, 32 queries, 512 value lanes]
     assert f'bf16[{b},1,32,512]' in compiled.as_text()
@@ -654,6 +743,7 @@ def test_chunk_prefill_reads_the_planes_as_they_lie(v5e, kanana_cell):
         v5e((4, 528), i32), v5e((4,), i32), v5e((4,), i32),
     ).compile()
     _assert_pools_go_to_the_kernel_as_they_lie(compiled, [plane])
+    _assert_span_calls_keep_the_grid(compiled)
 
 
 def _assert_stacked_pool_is_addressed(compiled, pool) -> None:
@@ -791,8 +881,14 @@ def test_stacked_pool_is_addressed_not_sliced(v5e, program, pool):
     for the kernel call (a custom call wants its operand materialised), a
     layer's plane was copied out of the pool and written back: 128 plane
     fusions and 66 pool-sized ones a step of ``mistral7b``'s window, 4.27
-    ms of a 29.61 ms step on the chip (PR 31)."""
-    _assert_stacked_pool_is_addressed(program(v5e, pool), pool)
+    ms of a 29.61 ms step on the chip (PR 31). The decode windows' calls
+    take the row walk, the span program's keep the grid over chunks."""
+    compiled = program(v5e, pool)
+    _assert_stacked_pool_is_addressed(compiled, pool)
+    if program is _mistral_chunk_prefill:
+        _assert_span_calls_keep_the_grid(compiled)
+    else:
+        _assert_decode_calls_walk(compiled)
 
 
 @pytest.mark.parametrize('program', ['write_prefill', 'gather_blocks'])

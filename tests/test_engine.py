@@ -1291,3 +1291,90 @@ def test_mixed_exception_recovery_rolls_back_inflight_chunk_spans(
         request.request_id == r2
         for request, _, _ in on._plan_window_chunks()
     )
+
+
+# ------------------------------------------------- the row walk's counter
+def _walked_chunks(contexts, keys, window=None, block=4):
+    """Chunks a row walk fetches for rows at ``contexts``, counted page by
+    page: the chunks that hold a page the row's one query sees."""
+    total = 0
+    for ctx in contexts:
+        lo = max(int(ctx) - window, 0) if window else 0
+        pages = range(lo // block, -(-int(ctx) // block))
+        total += len({page // (keys // block) for page in pages})
+    return total
+
+
+@pytest.mark.parametrize('family', ['mistral', 'laguna'])
+def test_decode_records_count_the_chunks_the_walk_fetches(
+    family, monkeypatch
+):
+    """``kv_chunks*`` on ``decode`` records is what the rows' contexts
+    give, group by group, and ``telemetry['kv_walk_keys']`` names the keys
+    a step each pool's walk takes (the rule's, here held to 8 keys, two
+    pages, so that rows span several chunks at toy lengths)."""
+    from distllm_tpu.ops import paged_attention
+
+    monkeypatch.setattr(paged_attention, 'WALK_MAX_KEYS', 8)
+    if family == 'mistral':
+        _, _, engine = _tiny_engine(
+            attn_backend='interpret', decode_steps=4, max_model_len=96,
+        )
+        windows, names = {'kv': None}, {'kv': 'kv_chunks'}
+        prompts = [list(range(1, 38)), list(range(2, 11)), [5]]
+    else:
+        from laguna_toy import WINDOW, make_engine, prompt
+
+        _, _, engine = make_engine(attn_backend='interpret')
+        windows = {'full': None, 'window': WINDOW}
+        names = {'full': 'kv_chunks_full', 'window': 'kv_chunks_window'}
+        rng = np.random.default_rng(3)
+        prompts = [prompt(rng, 41), prompt(rng, 9)]
+    assert engine.telemetry['kv_walk_keys'] == dict.fromkeys(windows, 8)
+
+    seen = []
+    reckon = engine._kv_chunks
+
+    def spy(contexts):
+        fields = reckon(contexts)
+        seen.append((np.array(contexts), fields))
+        return fields
+
+    monkeypatch.setattr(engine, '_kv_chunks', spy)
+    before = engine.flight.total_recorded
+    engine.generate_ids(
+        prompts, SamplingParams(temperature=0.0, max_tokens=9)
+    )
+    assert seen and max(int(c.max()) for c, _ in seen) > 16
+    for contexts, fields in seen:
+        for group, window in windows.items():
+            assert fields[names[group]] == _walked_chunks(
+                contexts, 8, window
+            ), (group, contexts)
+        if family == 'laguna':
+            assert fields['kv_chunks'] == fields['kv_chunks_full']
+    records = engine.flight.snapshot()[before - engine.flight.total_recorded:]
+    decodes = [r for r in records if r['kind'] == 'decode']
+    assert [
+        {k: v for k, v in r.items() if k.startswith('kv_chunks')}
+        for r in decodes
+    ] == [fields for _, fields in seen]
+    # a chunk holds two pages: the walk fetches fewer chunks than blocks,
+    # and no more than one a block
+    assert all(0 < r['kv_chunks'] <= r['kv_blocks'] for r in decodes)
+    engine.shutdown()
+
+
+def test_no_walk_no_chunk_count():
+    """Under the XLA backend nothing walks: no telemetry entry and no
+    ``kv_chunks`` on the records."""
+    _, _, engine = _tiny_engine(attn_backend='xla')
+    assert 'kv_walk_keys' not in engine.telemetry
+    before = engine.flight.total_recorded
+    engine.generate_ids(
+        [[1, 2, 3]], SamplingParams(temperature=0.0, max_tokens=6)
+    )
+    records = engine.flight.snapshot()[before - engine.flight.total_recorded:]
+    decodes = [r for r in records if r['kind'] == 'decode']
+    assert decodes and not any('kv_chunks' in r for r in decodes)
+    engine.shutdown()

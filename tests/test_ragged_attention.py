@@ -127,6 +127,215 @@ def test_ragged_parity_decode_rows(rng):
         )
 
 
+# ---- the row walk: the kernel's schedule for a span of one (decode rows)
+
+# Blocks of 4 tokens, a table of 16: a chunk of 2 pages is 8 keys. One
+# batch holds a row with no sequence, one token, exactly one such chunk,
+# one key past it, a row inside its third chunk, and the widest table.
+_WALK_BS, _WALK_TABLE = 4, 16
+_WALK_CTX = (0, 1, 8, 9, 23, 64)
+
+
+def _walk_setup(rng, *, nh=8, nkv=2, hd=8, ctx=_WALK_CTX, num_blocks=72):
+    b = len(ctx)
+    k, v = (
+        jnp.asarray(
+            rng.normal(size=(num_blocks, _WALK_BS, nkv * hd)), jnp.float32
+        )
+        for _ in range(2)
+    )
+    # every row its own scattered blocks, as the paged allocator hands out
+    bt = jnp.asarray(
+        rng.permutation(num_blocks - 1)[:b * _WALK_TABLE].reshape(
+            b, _WALK_TABLE
+        ) + 1 if b * _WALK_TABLE < num_blocks
+        else rng.integers(1, num_blocks, size=(b, _WALK_TABLE)), jnp.int32,
+    )
+    ctx = jnp.asarray(ctx, jnp.int32)
+    pos = jnp.maximum(ctx - 1, 0)[:, None]
+    q = jnp.asarray(rng.normal(size=(b, 1, nh, hd)), jnp.float32)
+    return q, k, v, bt, ctx, pos, (ctx > 0).astype(jnp.int32)
+
+
+def _window_arg(window):
+    if window == 'traced':
+        return jnp.int32(6)  # starts inside a chunk of 8 keys
+    if window == 'traced_zero':
+        return jnp.int32(0)
+    return window
+
+
+def _assert_walk_parity(out, ref, q_lens):
+    out, ref = np.asarray(out), np.asarray(ref)
+    live = np.asarray(q_lens) > 0
+    assert np.isfinite(out).all()
+    np.testing.assert_allclose(out[live], ref[live], atol=1e-5, rtol=1e-4)
+    assert np.abs(out[~live]).max(initial=0.0) == 0.0  # a pad row: zeros
+
+
+@pytest.mark.parametrize(
+    'window', [None, 6, 'traced', 'traced_zero'],
+    ids=['nowin', 'win6', 'traced', 'traced0'],
+)
+@pytest.mark.parametrize(
+    'pages', [1, 2, 4, 8, 16, None],
+    ids=['keys4', 'keys8', 'keys16', 'keys32', 'keys64', 'rule'],
+)
+def test_row_walk_parity_by_keys_a_step_and_window(rng, pages, window):
+    """Ragged contexts in one batch, every chunk width from one page to
+    the whole table (and the rule's own, capped by the table), static and
+    traced windows that start inside a chunk."""
+    q, k, v, bt, ctx, pos, q_lens = _walk_setup(rng)
+    window = _window_arg(window)
+    ref = ragged_paged_attention_xla(
+        q, k, v, bt, ctx, pos, q_lens=q_lens, sliding_window=window
+    )
+    out = ragged_paged_attention_pallas(
+        q, k, v, bt, ctx, pos, q_lens=q_lens, sliding_window=window,
+        pages_per_chunk=pages, interpret=True,
+    )
+    _assert_walk_parity(out, ref, q_lens)
+
+
+@pytest.mark.parametrize('window', [None, 6], ids=['nowin', 'win6'])
+@pytest.mark.parametrize(
+    'variant',
+    ['stacked', 'stacked_traced', 'latent', 'int8', 'softcap', 'scale'],
+)
+def test_row_walk_parity_by_pool_and_knob(rng, variant, window):
+    """One walk for every span-1 caller: a stacked pool with its layer
+    (a Python int, and traced under a rolled scan), a latent plane with
+    ``value_lanes``, an int8 pool with its scale rows, softcap, a
+    caller's scale."""
+    from distllm_tpu.ops.paged_attention import QuantizedKV
+
+    kwargs, jit_layer = {}, None
+    if variant == 'latent':  # one head of 256 lanes, values its first 128
+        q, k, _, bt, ctx, pos, q_lens = _walk_setup(rng, nh=4, nkv=1, hd=256)
+        v, kwargs = None, {'value_lanes': 128}
+    else:
+        q, k, v, bt, ctx, pos, q_lens = _walk_setup(rng)
+    if variant.startswith('stacked'):
+        other_k, other_v = k[::-1], v[::-1]
+        k, v = jnp.stack([other_k, k, other_v]), jnp.stack([other_v, v, k])
+        jit_layer = jnp.int32(1) if variant == 'stacked_traced' else 1
+    elif variant == 'int8':
+        k, v = (
+            QuantizedKV(
+                jnp.asarray(
+                    rng.integers(-127, 128, size=pool.shape), jnp.int8
+                ),
+                jnp.asarray(
+                    rng.uniform(0.01, 0.03, size=(pool.shape[0], 2)),
+                    jnp.float32,
+                ),
+            )
+            for pool in (k, v)
+        )
+    elif variant == 'softcap':
+        kwargs = {'logit_softcap': 30.0}
+    elif variant == 'scale':
+        kwargs = {'scale': 0.25}
+
+    def run(fn, **more):
+        call = lambda layer: fn(  # noqa: E731
+            q, k, v, bt, ctx, pos, q_lens=q_lens, sliding_window=window,
+            layer=layer, **kwargs, **more,
+        )
+        if variant == 'stacked_traced':
+            return jax.jit(call)(jit_layer)
+        return call(jit_layer)
+
+    out = run(
+        ragged_paged_attention_pallas, pages_per_chunk=2, interpret=True
+    )
+    _assert_walk_parity(out, run(ragged_paged_attention_xla), q_lens)
+
+
+@pytest.mark.parametrize(
+    'nh,nkv,hd', [(8, 2, 8), (12, 2, 8), (16, 2, 8), (32, 1, 256)],
+    ids=['group4', 'group6', 'group8', 'group32_latent'],
+)
+def test_row_walk_parity_by_queries_a_head(rng, nh, nkv, hd):
+    """The head shapes that take the walk in the cells: 4 (mistral7b,
+    granite), 6 and 8 (laguna's full and window layers) queries a KV
+    head, and 32 queries on one latent head (kanana)."""
+    q, k, v, bt, ctx, pos, q_lens = _walk_setup(rng, nh=nh, nkv=nkv, hd=hd)
+    kwargs = {}
+    if nkv == 1:
+        v, kwargs = None, {'value_lanes': 128}
+    ref = ragged_paged_attention_xla(
+        q, k, v, bt, ctx, pos, q_lens=q_lens, **kwargs
+    )
+    out = ragged_paged_attention_pallas(
+        q, k, v, bt, ctx, pos, q_lens=q_lens, pages_per_chunk=4,
+        interpret=True, **kwargs,
+    )
+    _assert_walk_parity(out, ref, q_lens)
+
+
+def _kernel_call(span):
+    """The ``pallas_call`` equation of a traced call at ``span``."""
+    q, k, v, bt, ctx, _, _ = _walk_setup(np.random.default_rng(0))
+    b = q.shape[0]
+    q = jnp.zeros((b, span, *q.shape[2:]), q.dtype)
+    pos = jnp.maximum(ctx - span, 0)[:, None] + jnp.arange(span)[None, :]
+    jaxpr = jax.make_jaxpr(
+        lambda *a: ragged_paged_attention_pallas(
+            *a, pages_per_chunk=2, interpret=True
+        )
+    )(q, k, v, bt, ctx, pos)
+    (call,) = [e for e in jaxpr.eqns if e.primitive.name == 'pallas_call']
+    return call
+
+
+@pytest.mark.parametrize(
+    'span,grid', [(1, (6, 1, 1)), (16, (6, 1, 8)), (2, (6, 1, 8))],
+    ids=['span1_walks', 'span16_grid_over_chunks', 'span2_grid_over_chunks'],
+)
+def test_only_a_span_of_one_walks(span, grid):
+    """The schedule is chosen by what the call shows, its span: one query
+    a row walks under a grid over rows; any longer span keeps the grid
+    (rows, query tiles, chunks of the widest table) and the parent's
+    kernel (its jaxpr's size at the cells' widths is pinned in
+    ``tests/test_aot_tpu.py``)."""
+    call = _kernel_call(span)
+    assert tuple(call.params['grid_mapping'].grid) == grid
+    has_loop = 'while' in str(call.params['jaxpr'])
+    assert has_loop == (span == 1)
+
+
+@pytest.mark.parametrize(
+    'lanes,dtype,planes,block,keys',
+    [
+        (1024, 'bfloat16', 2, 16, 1024),  # mistral7b, granite, laguna
+        (640, 'bfloat16', 1, 16, 1024),  # kanana's latent plane
+        (1024, 'int8', 2, 32, 1024),
+        (4096, 'bfloat16', 2, 16, 256),  # 32 KV heads of 128: VMEM bounds
+        (4096, 'float32', 2, 16, 128),
+        (128, 'float32', 2, 8, 512),  # small blocks: the semaphores do
+        (128, 'float32', 2, 4, 256),
+        (256, 'float32', 1, 4, 512),
+    ],
+)
+def test_walk_keys_a_step_rule(lanes, dtype, planes, block, keys):
+    """Keys a step follow the row's width, the dtype, the planes and the
+    block size: the most that keep two slots of pages in the walk's VMEM
+    allowance and the copies in flight within their semaphores."""
+    from distllm_tpu.ops.paged_attention import (
+        WALK_BUFFER_BYTES,
+        WALK_SEMAPHORES,
+        walk_keys_a_step,
+    )
+
+    got = walk_keys_a_step(lanes, dtype, planes=planes, block_size=block)
+    assert got == keys
+    held = 2 * planes * got * lanes * jnp.dtype(dtype).itemsize
+    copies = planes + 2 * (dtype == 'int8')
+    assert held <= WALK_BUFFER_BYTES
+    assert 2 * copies * (got // block) <= WALK_SEMAPHORES
+
+
 @pytest.mark.parametrize('layer', [0, 1, 2], ids=['first', 'middle', 'last'])
 @pytest.mark.parametrize('traced', [False, True], ids=['int', 'traced'])
 def test_stacked_pool_is_addressed_by_layer(rng, layer, traced):
