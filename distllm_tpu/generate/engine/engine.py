@@ -939,7 +939,8 @@ class LLMEngine:
             # resolve to XLA, never trace into a ValueError. The STORAGE
             # dtype decides the sublane tile: an int8 pool needs
             # block_size % 32 == 0, so int8 + the default block_size=16
-            # quietly keeps the XLA tier under 'auto'.
+            # quietly keeps the XLA tier under 'auto', as does an int8
+            # pool under 64-wide heads at any block size.
             block_size=cfg.block_size, kv_dtype=kv_pool_dtype,
         )
         _sublane = kv_sublane_tile(kv_pool_dtype)
@@ -1261,7 +1262,7 @@ class LLMEngine:
             self.kv.allocate()
             if self.window_kv is not None:
                 self.window_kv.allocate()
-        if len(spec.paged) > 1 or spec.latent:
+        if len(spec.paged) > 1 or spec.latent or spec.state is not None:
             self.telemetry['kv_pools'] = {
                 group.name: {
                     'layers': group.num_layers, 'window': group.window,
@@ -1295,8 +1296,12 @@ class LLMEngine:
                 scope=self._compile_scope,
             ):
                 self.state_pool.allocate()
-            self.telemetry['state_pool_slots'] = self.state_pool.slots
-            self.telemetry['state_pool_bytes'] = self.state_pool.hbm_bytes
+            # One account of the pool; its slots and bytes also under the
+            # two older scalar keys, which granite's benchmark driver and
+            # chip_smoke.py read.
+            pool = self.telemetry['state_pool'] = self.state_pool.describe()
+            self.telemetry['state_pool_slots'] = pool['slots']
+            self.telemetry['state_pool_bytes'] = pool['bytes']
         # Merge host-known overrides (fresh admissions) into the device-
         # carried last-token vector between pipelined windows.
         self._merge_ids = jax.jit(
@@ -5190,7 +5195,8 @@ class LLMEngine:
         return {'state_slot': self.sched.slot(request.request_id)}
 
     def _kv_ends_field(self, request: Request) -> dict:
-        """For a model with a windowed or a latent cache group: the ids of
+        """For a model with a windowed or a latent cache group, or with a
+        state pool: the ids of
         two blocks of the full-context group the request held when it
         finished, its
         first and the one that holds the last position it wrote (its last
@@ -5198,7 +5204,10 @@ class LLMEngine:
         pool keeps what a freed block held until its next holder writes it,
         which is how the benchmark's check reads the K/V a finished request
         left."""
-        if self.window_kv is None and not self.cache_spec.latent:
+        if (
+            self.window_kv is None and not self.cache_spec.latent
+            and self.state_pool is None
+        ):
             return {}
         row = self.sched.block_row(request.request_id)
         written = len(request.prompt_ids) + len(request.output_ids) - 1

@@ -1219,6 +1219,24 @@ class StatePool:
     def hbm_bytes(self) -> int:
         return self.slots * self.bytes_per_slot
 
+    def describe(self) -> dict:
+        """The pool as telemetry states it (``state_pool``): its slots and
+        bytes, and of each kind of leaf of a sequence's state the count,
+        shape and dtype (one kind for a model whose whole state is in its
+        own dtype; a recurrent state in float32 is a second)."""
+        kinds: dict[tuple, int] = {}
+        for leaf in jax.tree.leaves(self.seq_spec):
+            kind = (tuple(leaf.shape), jnp.dtype(leaf.dtype).name)
+            kinds[kind] = kinds.get(kind, 0) + 1
+        return {
+            'slots': self.slots, 'bytes': self.hbm_bytes,
+            'bytes_per_slot': self.bytes_per_slot,
+            'leaves': [
+                {'count': n, 'shape': list(shape), 'dtype': dtype}
+                for (shape, dtype), n in kinds.items()
+            ],
+        }
+
 
 def window_bound(window: int, block_size: int, span: int) -> int:
     """Most blocks a windowed sequence holds while ``span`` tokens of it are

@@ -25,6 +25,7 @@ def decoder_families() -> dict:
         gemma,
         granite_hybrid,
         laguna,
+        lfm2,
         mistral,
         mixtral,
     )
@@ -41,6 +42,7 @@ def decoder_families() -> dict:
         ),
         'laguna': (laguna.LagunaConfig, laguna),
         'deepseek_v3': (deepseek_v3.DeepseekV3Config, deepseek_v3),
+        'lfm2_moe': (lfm2.Lfm2MoeConfig, lfm2),
     }
 
 

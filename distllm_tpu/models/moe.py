@@ -44,6 +44,7 @@ def routed_experts(  # distlint: traced
     routed_scale: float = 1.0,
     scoring: str = 'softmax',
     select_bias: jnp.ndarray | None = None,  # [E_routed] float32
+    norm_eps: float = 1e-20,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """``sum_e g_e expert_e(x)`` over the held experts among a token's top-k.
 
@@ -65,8 +66,10 @@ def routed_experts(  # distlint: traced
     ``scoring='sigmoid'`` (DeepSeek-V3's ``noaux_tc`` router): an expert's
     score is ``sigmoid(logit)``, the k kept are the largest of ``score +
     select_bias`` (the bias chooses and never weighs), and the gates are
-    the kept SCORES over their sum. ``'softmax'`` with no bias is softmax
-    over the k kept logits, as it was.
+    the kept SCORES over their sum plus ``norm_eps`` (the family's
+    published normaliser: 1e-20 for DeepSeek-V3, the default, 1e-6 for
+    ``lfm2_moe``). ``'softmax'`` with no bias is softmax over the k kept
+    logits, as it was.
     """
     if scoring not in ('softmax', 'sigmoid'):
         raise ValueError(f'scoring must be softmax or sigmoid, got {scoring!r}')
@@ -95,7 +98,7 @@ def routed_experts(  # distlint: traced
             )
             _, top_idx = jax.lax.top_k(chosen_by, k)
             kept = jnp.take_along_axis(scores, top_idx, axis=-1)
-            weights = kept / (kept.sum(axis=-1, keepdims=True) + 1e-20)
+            weights = kept / (kept.sum(axis=-1, keepdims=True) + norm_eps)
         if routed_scale != 1.0:
             weights = weights * routed_scale
         local = top_idx - first_expert

@@ -4,7 +4,7 @@ The reference delegates this to vLLM's CUDA paged-attention
 (``generate/generators/vllm_backend.py``; SURVEY.md section 2.4 N1). Here the
 KV cache lives in HBM as fixed-size blocks, stored HEAD-FOLDED, the layout
 the Pallas kernel reads (each KV head a 128-aligned lane band of a token's
-row)::
+row, or two 64-wide heads a band: :func:`_half_tile_heads`)::
 
     k_cache, v_cache : [num_blocks, block_size, num_kv_heads * head_dim]
 
@@ -65,14 +65,14 @@ import jax.numpy as jnp
 from distllm_tpu.observability.instruments import ATTN_BACKEND_LABELS
 
 # Head dims the Pallas kernel is exercised at in CI (tests/test_aot_tpu.py
-# compiles these against a real v5e topology). The kernel's structural
-# requirement is only head_dim % 128 == 0 (Mosaic DMA alignment, checked in
-# paged_attention_pallas), but 'auto' backend selection routes through
-# supported_head_dim so untested shapes never auto-enable the kernel —
+# compiles these for a v5e topology). The kernel needs a head that is whole
+# 128-lane tiles, or 64 wide in a pool row of whole tiles (two heads a tile:
+# _half_tile_heads); 'auto' backend selection routes through
+# supported_head_dim so untested shapes never auto-enable the kernel:
 # widen this tuple when a new shape gains AOT coverage. 640 is a LATENT
 # row (``models/deepseek_v3.py``: 512 latent + 64 rotary values in five
 # whole lane tiles, one KV head, values its first 512 lanes).
-TESTED_HEAD_DIMS = (128, 640)
+TESTED_HEAD_DIMS = (64, 128, 640)
 
 
 def supported_head_dim(head_dim: int) -> bool:
@@ -92,12 +92,12 @@ ATTN_BACKENDS = ('auto', *ATTN_BACKEND_LABELS)
 
 def supports_model(model_cfg) -> bool:
     """May `attn_backend='auto'` select the Pallas kernel for this model?
-
-    The ragged kernel natively implements attention logit softcapping,
-    traced per-layer (gemma2 alternating) sliding windows, and custom
-    score scales, so eligibility is purely the head-dim DMA/CI contract.
-    """
-    return supported_head_dim(model_cfg.head_size)
+    Softcapping, traced sliding windows and custom scales are the kernel's
+    own, so eligibility is the head width's DMA/CI contract: a tested width
+    that is whole 128-lane tiles (a pool row, ``num_kv_heads`` of them, then is)
+    or is 64 wide with an even count (it shares its tile with a neighbour)."""
+    d = model_cfg.head_size
+    return supported_head_dim(d) and not (d % 128 and model_cfg.num_kv_heads % 2)
 
 
 def kv_sublane_tile(kv_dtype) -> int:
@@ -265,7 +265,9 @@ def resolve_attn_backend(
     so a config change after init can never re-route live dispatches.
     'auto' picks the Pallas kernel on TPU for CI-covered head dims —
     AND, when the caller provides the KV block geometry, only when
-    ``block_size`` meets the kernel's sublane-tile DMA contract — and
+    ``block_size`` meets the kernel's sublane-tile DMA contract and the
+    pool is not int8 under heads that share a lane tile (64 wide: the
+    kernel refuses that pool by name) — and
     falls back to the always-available XLA path everywhere else (an
     'auto' config must never trace into the kernel's ValueErrors).
     """
@@ -278,7 +280,10 @@ def resolve_attn_backend(
         return attn_backend
     eligible = jax.default_backend() == 'tpu' and supports_model(model_cfg)
     if eligible and block_size is not None and kv_dtype is not None:
-        eligible = block_size % kv_sublane_tile(kv_dtype) == 0
+        eligible = block_size % kv_sublane_tile(kv_dtype) == 0 and not (
+            model_cfg.head_size % 128
+            and jnp.dtype(kv_dtype) == jnp.dtype(jnp.int8)
+        )
     return 'pallas' if eligible else 'xla'
 
 
@@ -873,12 +878,12 @@ def ragged_paged_attention_pallas(
     num_kv_heads = folded // head_dim
     max_blocks = block_tables.shape[1]
     group = num_heads // num_kv_heads
-    if head_dim % 128 and not interpret:
-        # Mosaic requires HBM DMA slices 128-aligned in the minor dim; the
-        # engine's backend resolution (supports_model) routes such models
-        # to XLA, so reaching here means an explicit 'pallas' pin.
-        raise ValueError(
-            f'pallas paged attention needs head_dim % 128 == 0, got {head_dim}'
+    if head_dim % 128 and (_pairs_heads(head_dim, folded) or not interpret):
+        # two 64-wide heads share a lane tile; any other width raises there
+        return _half_tile_heads(
+            q, k_cache, v_cache, block_tables, context_lens, q_positions,
+            q_lens, sliding_window, scale, logit_softcap, pages_per_chunk,
+            span_tile, interpret,
         )
     # Each page DMAs into a [block_size]-row band of the folded KV buffer,
     # so the band offsets must land on sublane-tile boundaries (16 rows
@@ -1671,3 +1676,74 @@ def _write_prefill_kv_quantized(k_cache, v_cache, k_seq, v_seq,
         return QuantizedKV(data, scale)
 
     return write_one(k_cache, k_seq), write_one(v_cache, v_seq)
+
+
+def _pairs_heads(head_dim: int, folded: int) -> bool:
+    """Whether the Pallas kernel reads this pool's heads two to a lane tile:
+    64-wide heads in a row of whole 128-lane tiles."""
+    return head_dim == 64 and folded % 128 == 0
+
+
+def _half_tile_heads(
+    q, k_pages, v_pages, block_tables, context_lens, q_positions, q_lens,
+    sliding_window, scale, logit_softcap, pages_per_chunk, span_tile,
+    interpret,
+):
+    """64-wide heads through :func:`ragged_paged_attention_pallas`, from
+    the pool as it is stored: rows of ``num_kv_heads * 64`` lanes, no head
+    padded (``k_pages``/``v_pages`` and ``block_tables`` are
+    :func:`_layer_pages`'s, as the kernel's wrapper has them).
+
+    The kernel's unit is a 128-lane band of a page: a load or a DMA of half
+    a tile is what Mosaic refuses or relayouts. So a band is handed to it
+    as ONE key head of 128 dims that ``2 * group`` queries share: the
+    queries of the band's first head widened with zeros behind, those of
+    its second with zeros in front. A widened query's score against the
+    band is its own head's score (the zeros meet the neighbour's lanes);
+    its weighted sum over the band's values holds its head's output in its
+    head's half, and the other half (the neighbour's values under this
+    head's weights) is dropped here. The MXU contracts and emits 128 lanes
+    a pass either way, the softmax rows are the ``num_heads`` rows a
+    kernel of 64-wide bands would have, and a page crosses HBM once. Both
+    schedules (the grid over spans and the row walk) and
+    :func:`walk_keys_a_step` see a pool of ``folded // 128`` heads of 128.
+    An int8 pool keeps one scale a head, which a band of two heads cannot
+    apply to its scores: refused by name.
+    """
+    b, s, num_heads, head_dim = q.shape
+    folded = _kv_data(k_pages).shape[-1]
+    if not _pairs_heads(head_dim, folded):
+        raise ValueError(
+            'pallas paged attention needs heads of whole 128-lane tiles, or '
+            '64-wide heads in a pool row of whole tiles (two heads a tile); '
+            f'got head_dim {head_dim} in rows of {folded} lanes'
+        )
+    if isinstance(k_pages, QuantizedKV):
+        raise ValueError(
+            'kv_cache_dtype=int8 at 64-wide heads is not implemented in the '
+            'pallas kernel (two heads share a lane tile and each has a '
+            "scale of its own); use attn_backend='xla'"
+        )
+    tiles = folded // 128
+    group = num_heads // (2 * tiles)  # queries a 64-wide KV head
+    halves = q.reshape(b, s, tiles, 2, group, head_dim)
+    zeros = jnp.zeros_like(halves[:, :, :, 0])
+    wide = jnp.stack(
+        [
+            jnp.concatenate([halves[:, :, :, 0], zeros], axis=-1),
+            jnp.concatenate([zeros, halves[:, :, :, 1]], axis=-1),
+        ],
+        axis=3,
+    )  # [B, S, tiles, 2, group, 128]: a band's 2 * group queries
+    out = ragged_paged_attention_pallas(
+        wide.reshape(b, s, num_heads, 2 * head_dim), k_pages, v_pages,
+        block_tables, context_lens, q_positions, q_lens=q_lens,
+        sliding_window=sliding_window,
+        scale=head_dim ** -0.5 if scale is None else scale,
+        logit_softcap=logit_softcap, pages_per_chunk=pages_per_chunk,
+        span_tile=span_tile, interpret=interpret,
+    ).reshape(b, s, tiles, 2, group, 2 * head_dim)
+    out = jnp.stack(
+        [out[:, :, :, 0, :, :head_dim], out[:, :, :, 1, :, head_dim:]], axis=3
+    )
+    return out.reshape(b, s, num_heads, head_dim)

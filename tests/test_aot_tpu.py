@@ -920,3 +920,112 @@ def test_stacked_pool_programs_copy_no_pool(v5e, program):
         ).compile()
     plane = 640 * 16 * _NKV * _HD * 2
     assert compiled.memory_analysis().temp_size_in_bytes < plane
+
+
+# ---- 64-wide heads: two to a lane tile of the pool's row (PR 39) ----
+
+@pytest.mark.parametrize('rows, span', [(96, 1), (128, 1), (4, 512)],
+                         ids=['decode_96_rows', 'decode_128_rows',
+                              'prefill_4x512'])
+def test_ragged_kernel_compiles_at_64_wide_heads(v5e, rows, span):
+    """The kernel alone at ``lfm2-8b-a1b``'s attention widths (32 query
+    heads on 8 KV heads of 64 dims, a pool row of 512 lanes, contexts to
+    8,448; the cell's 96 decode rows and the 128 rows and 25,600 blocks a
+    layer of the issue's first size): Mosaic takes the row walk and the
+    grid over spans from the pool as it is stored, no head padded."""
+    from distllm_tpu.ops.paged_attention import ragged_paged_attention_pallas
+
+    pool = v5e((6, 25600, 16, 512), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda q, k, v, bt, ctx, pos, lens: ragged_paged_attention_pallas(
+            q, k, v, bt, ctx, pos, q_lens=lens, layer=3
+        )
+    ).lower(
+        v5e((rows, span, 32, 64), jnp.bfloat16), pool, pool,
+        v5e((rows, 528), jnp.int32), v5e((rows,), jnp.int32),
+        v5e((rows, span), jnp.int32), v5e((rows,), jnp.int32),
+    ).compile()
+    _assert_pools_go_to_the_kernel_as_they_lie(compiled, [(6 * 25600, 16, 512)])
+    if span == 1:
+        _assert_decode_calls_walk(compiled)
+    else:
+        _assert_span_calls_keep_the_grid(compiled)
+
+
+@pytest.fixture(scope='module')
+def lfm2_cell(v5e):
+    """The cell's configuration cut to its first 7 layers (two attention
+    layers, every kind of layer: conv under the dense MLP, conv and
+    attention under the experts), the parameters, the pools and the state
+    at the cell's sizes: 19,200 blocks, 96 slots."""
+    import json
+    from pathlib import Path
+
+    from distllm_tpu.models import lfm2
+
+    root = Path(__file__).resolve().parents[1]
+    hf = json.loads((root / 'benchmarks/configs/lfm2-8b-a1b.json').read_text())
+    hf['layer_types'] = hf['layer_types'][:7]
+    hf['num_hidden_layers'] = 7
+    cfg = lfm2.Lfm2MoeConfig.from_hf_config(hf)
+    shapes = jax.eval_shape(
+        lambda: lfm2.init_on_device(jax.random.PRNGKey(0), cfg)
+    )
+    params = jax.tree.map(lambda a: v5e(a.shape, a.dtype), shapes)
+    engine = hf['engine']
+    pool = (cfg.num_paged_layers, engine['num_blocks'], 16, 512)
+    state = jax.tree.map(
+        lambda a: v5e((engine['max_num_seqs'], *a.shape), a.dtype),
+        cfg.state_spec(),
+    )
+    return lfm2, cfg, params, pool, state, engine
+
+
+def test_lfm2_decode_window_addresses_the_pool(v5e, lfm2_cell):
+    """The decode window at the cell's 96 rows: the stacked pool of 512-
+    lane rows goes to the writers and to the kernel whole (no plane and no
+    pool copied, no head padded to a tile), every call takes the row walk,
+    and the state's buffers are rewritten in place."""
+    lfm2, cfg, params, pool, state, engine = lfm2_cell
+    b, i32, f32 = engine['max_num_seqs'], jnp.int32, jnp.float32
+    assert b == 96
+    pools = v5e(pool, jnp.bfloat16)
+    compiled = jax.jit(
+        lambda p, i, po, c, k, v, bt, sl, tmp, tp_, mp, tk, sd, st:
+            lfm2.decode_loop(
+                p, cfg, i, po, k, v, bt, c, sl, tmp, tp_, mp, tk, sd,
+                num_steps=8, attn_backend='pallas', max_table_positions=8448,
+                state=st,
+            ),
+        donate_argnums=(4, 5, 13),
+    ).lower(
+        params, v5e((b,), i32), v5e((b,), i32), v5e((b,), i32), pools, pools,
+        v5e((b, 528), i32), v5e((b,), i32), v5e((b,), f32), v5e((b,), f32),
+        v5e((b,), f32), v5e((b,), i32), v5e((b,), jnp.uint32), state,
+    ).compile()
+    _assert_stacked_pool_is_addressed(compiled, pool)
+    _assert_decode_calls_walk(compiled)
+    # nothing as large as the weights' smallest bank is left over as a
+    # temporary: the pools and the state are updated where they lie
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
+def test_lfm2_chunk_prefill_addresses_the_pool(v5e, lfm2_cell):
+    """The ``(512, 4)`` program: four rows of a 512-token span through the
+    conv spans (state gathered and scattered by slot) and the grid over
+    spans at 64-wide heads."""
+    lfm2, cfg, params, pool, state, _ = lfm2_cell
+    i32 = jnp.int32
+    pools = v5e(pool, jnp.bfloat16)
+    compiled = jax.jit(
+        lambda p, ids, pos, k, v, bt, ctx, tails, st, slots: lfm2.prefill_paged(
+            p, cfg, ids, pos, k, v, bt, ctx, tails, st, slots,
+            max_table_positions=8448, attn_backend='pallas',
+        ), donate_argnums=(3, 4, 8),
+    ).lower(
+        params, v5e((4, 512), i32), v5e((4, 512), i32), pools, pools,
+        v5e((4, 528), i32), v5e((4,), i32), v5e((4,), i32), state,
+        v5e((4,), i32),
+    ).compile()
+    _assert_stacked_pool_is_addressed(compiled, pool)
+    _assert_span_calls_keep_the_grid(compiled)
