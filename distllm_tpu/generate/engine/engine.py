@@ -60,7 +60,7 @@ from distllm_tpu.generate.engine.scheduler import (
     decode_budget_fits,
     make_scheduler,
 )
-from distllm_tpu.models import mistral
+from distllm_tpu.models import mistral, moe
 from distllm_tpu.models.tokenizer import bucket_ladder, pick_bucket
 from distllm_tpu.observability import instruments as _metrics
 from distllm_tpu.observability import steps as _steps
@@ -1290,6 +1290,26 @@ class LLMEngine:
                 for group, kv in zip(spec.paged, (self.kv, self.window_kv))
             }
             self.telemetry['kv_walk_keys'] = dict(self._walk_keys)
+        # The form of the routed experts' matmuls in each program a
+        # dispatch can run (``models.moe.expert_form``, the rule the
+        # programs themselves trace with: static shapes alone). A config
+        # that says which experts this chip holds is one of the families
+        # that route through ``models/moe.py``; ``moe_form`` on the decode
+        # and prefill records is looked up by the dispatch's row count.
+        self._moe_widths = None
+        if getattr(model_cfg, 'first_local_expert', None) is not None:
+            self._moe_widths = moe.bank_widths(self.params)
+        if self._moe_widths is not None:
+            rows = cfg.max_num_seqs
+            forms = {f'decode({rows})': self._moe_form(rows)}
+            for bucket in self.prefill_buckets:
+                rows = 1
+                while rows <= self._prefill_batch_cap(bucket):
+                    forms[f'prefill({bucket}, {rows})'] = self._moe_form(
+                        bucket * rows
+                    )
+                    rows *= 2
+            self.telemetry['moe_form'] = forms
         if self.state_pool is not None:
             with self._compile_watcher.phase(
                 'state_allocate', f'slots{cfg.max_num_seqs}', compiles=False,
@@ -1555,6 +1575,19 @@ class LLMEngine:
         if state:
             (self.state_pool.state,) = state
         return last_logits
+
+    def _moe_form(self, tokens: int) -> str | None:
+        """``'dense'`` or ``'grouped'`` for a program of ``tokens`` rows, or
+        None for a model without routed experts."""
+        if self._moe_widths is None:
+            return None
+        return moe.expert_form(
+            tokens, self.model_cfg.experts_per_token, *self._moe_widths
+        )
+
+    def _moe_form_field(self, tokens: int) -> dict:
+        form = self._moe_form(tokens)
+        return {} if form is None else {'moe_form': form}
 
     def _call_decode_window(self, *plan):
         """Dispatch the decode window over its plan's device arrays (ids,
@@ -3316,7 +3349,7 @@ class LLMEngine:
         self._record_step(
             'prefill', step, batch=len(requests),
             tokens=int(lengths.sum()), route='dense',
-            **self._rids_field(requests),
+            **self._moe_form_field(b * bucket), **self._rids_field(requests),
         )
         return emitted
 
@@ -3596,7 +3629,8 @@ class LLMEngine:
         self._record_step(
             'prefill', step, batch=len(requests),
             tokens=int(tail_lens.sum()), route=route, kv_blocks=kv_blocks,
-            **window_fields, **self._rids_field(requests),
+            **window_fields, **self._moe_form_field(b * bucket),
+            **self._rids_field(requests),
         )
         return emitted
 
@@ -4700,6 +4734,8 @@ class LLMEngine:
                     'moe_pairs': int(moe_pairs[0]),
                     'moe_pairs_held': int(moe_pairs[1]),
                 }
+            if not chunk_entries:  # a decode window runs every slot's row
+                extra.update(self._moe_form_field(tokens.shape[1]))
             kv_blocks = self._kv_blocks(*window['context_lens'])
             if window.get('window_fields'):
                 extra.update(window['window_fields'], kv_blocks_full=kv_blocks)

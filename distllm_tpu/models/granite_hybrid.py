@@ -369,7 +369,7 @@ def _mlp(x, lp, cfg, counted, banks, li):
     """``moe(h) + shared(h)`` of one layer for ``x [T, H]`` (already
     normed); returns the sum and the layer's pair counts. ``banks`` is the
     kind's whole tree: the expert banks stay stacked, ``li`` picks the
-    layer inside the grouped matmul (``models/moe.py``)."""
+    layer inside the expert matmuls (``models/moe.py``)."""
     routed, pairs = routed_experts(
         x, lp['router']['kernel'], *(banks[n]['kernel'] for n in _BANKS),
         cfg.experts_per_token, first_expert=cfg.first_local_expert,

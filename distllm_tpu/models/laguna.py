@@ -424,7 +424,7 @@ def _mlp(x, mp, cfg, mlp_kind, counted, banks, mi):
     """The MLP block of one layer for ``x [T, H]`` (already normed);
     returns it and the layer's (routed, held) pair counts. ``banks`` is
     the sparse tree: the expert banks stay stacked, ``mi`` picks the layer
-    inside the grouped matmul (``models/moe.py``)."""
+    inside the expert matmuls (``models/moe.py``)."""
     if mlp_kind == 'dense':
         with jax.named_scope('distllm.dense_mlp'):
             out = _swiglu(

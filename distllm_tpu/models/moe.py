@@ -2,11 +2,28 @@
 
 ``routed_experts`` ranks every expert the router knows, keeps the top-k of a
 token, normalises the gate over those k, and computes only the (token,
-expert) pairs whose expert is HELD here: the pairs are sorted by expert and
-run through ``jax.lax.ragged_dot`` (a grouped matmul: each row is multiplied
-by its own expert's kernel, never by all ``E_held``). A chip that holds a
-share of the experts (``first_expert``, the bank's leading dim) adds nothing
-for the others: in a deployment their chips add it.
+expert) pairs whose expert is HELD here. A chip that holds a share of the
+experts (``first_expert``, the bank's leading dim) adds nothing for the
+others: in a deployment their chips add it.
+
+The three expert matmuls take one of two forms, chosen by ``expert_form``
+from the call's static shapes alone:
+
+* ``grouped`` (a prefill dispatch: 512-2,048 tokens): the pairs are sorted
+  by expert and run through ``jax.lax.ragged_dot`` (a grouped matmul: each
+  row is multiplied by its own expert's kernel, never by all ``E_held``),
+  then weighted in float32, gathered back and summed over k.
+* ``dense`` (a decode window: a handful of rows): every row is multiplied by
+  EVERY held expert, one batched ``dot`` a bank, with the gate zero where a
+  token did not choose an expert. Multiplying ``T`` rows by a bank costs
+  ``2 T`` operations a weight, reading the bank 2 bytes a weight, and the
+  chip does ``RIDGE_ROWS`` = 240 operations in the time it reads a byte: at
+  48-96 rows the arithmetic hides under the bank's stream, which nearly
+  every held expert needed anyway (12 pairs an expert at 96 rows over 16 of
+  32), and a plain ``dot`` streams its weights near the HBM rate where the
+  grouped kernel reads the same bytes at about half of it (``PERF.md``
+  section 6, PR 40: the sweep behind the rule's two constants). No sort, no
+  gather, no scatter; the same pairs, gates and counts.
 
 Softmax over the k kept logits equals softmax over all experts renormalised
 over the kept ones (Mixtral's published order). ``mixtral.moe_mlp`` is NOT a
@@ -29,6 +46,69 @@ def _bank(w, dtype):
     # happens here, at the point of use, so only one layer's experts
     # materialise as floats at a time (same policy as common.dense).
     return (w.dequantize() if hasattr(w, 'dequantize') else w).astype(dtype)
+
+
+# Rows at which multiplying by a bf16 weight costs what reading it does: a
+# TPU v5e does 197e12 operations and reads 819e9 bytes a second, a row
+# spends 2 operations on a weight of 2 bytes, so 197e12 / 819e9 = 240 rows.
+# A constant, not a device query: a program compiled for a described chip
+# and the tests on the CPU take the form the chip will run.
+RIDGE_ROWS = 240
+# The dense form's arithmetic may take this share of the bank's stream
+# time (120 rows), and at least this share of the held experts is expected
+# to have a pair (what the grouped form could have skipped is the rest).
+# Both from the kernel-alone sweep of PERF.md section 6 (PR 40): dense read
+# 1.9x faster at 96 rows and still ahead at 512, so the first is not a
+# crossover but a fence: at 121-128 rows (one whole 128-row tile) the TPU
+# compiler turns the WHOLE bank stack over for this dot (granite's 9-layer
+# prefill scan: three ``bf16[9, 36, 4096, 768]`` copies hoisted out of the
+# loop, 5.7 GB the chip does not have), which no constraint on the operand
+# cured without a copy of its own.
+DENSE_ARITHMETIC_SHARE = 0.5
+DENSE_MIN_HELD_SHARE = 0.7
+
+
+def expert_form(
+    tokens: int, k: int, held: int, routed: int, hidden: int, width: int
+) -> str:
+    """``'dense'`` or ``'grouped'``: the form of the three expert matmuls
+    for a call of ``tokens`` rows that keep ``k`` of ``routed`` experts,
+    ``held`` of them here as ``[hidden, width]`` kernels. Pure: static
+    shapes and the chip's ridge, nothing else.
+
+    Dense when (a) multiplying every row by every held expert stays under
+    ``DENSE_ARITHMETIC_SHARE`` of the time the banks take to stream, and
+    (b) the grouped form had little to skip: a held expert is expected to
+    have a pair with probability ``1 - (1 - k / routed) ** tokens`` (tokens
+    choose alike and independently: a sizing, not a promise).
+    """
+    weights = 3 * held * hidden * width
+    arithmetic = 2 * tokens * weights / RIDGE_ROWS  # in byte-times
+    stream = 2 * weights
+    with_pair = 1.0 - (1.0 - min(1.0, k / routed)) ** tokens
+    if (
+        arithmetic <= DENSE_ARITHMETIC_SHARE * stream
+        and with_pair >= DENSE_MIN_HELD_SHARE
+    ):
+        return 'dense'
+    return 'grouped'
+
+
+def bank_widths(params) -> tuple[int, int, int, int] | None:
+    """``(E_held, E_routed, H, I)`` of a parameter tree's routed experts
+    (the first dict that holds a ``router`` beside a ``gate`` bank, stacked
+    over layers or not), or None: what ``expert_form`` wants of a model."""
+    if not isinstance(params, dict):
+        return None
+    if 'router' in params and 'gate' in params:
+        held, hidden, width = jax.tree.leaves(params['gate'])[0].shape[-3:]
+        routed = jax.tree.leaves(params['router'])[0].shape[-1]
+        return held, routed, hidden, width
+    for child in params.values():
+        widths = bank_widths(child)
+        if widths is not None:
+            return widths
+    return None
 
 
 def routed_experts(  # distlint: traced
@@ -79,7 +159,10 @@ def routed_experts(  # distlint: traced
     tokens, k = x.shape[0], experts_per_token
     gate, up, down = _bank(gate, dtype), _bank(up, dtype), _bank(down, dtype)
     held = gate.shape[-3]
-    if layer is not None:
+    form = expert_form(
+        tokens, k, held, router_kernel.shape[-1], *gate.shape[-2:]
+    )
+    if layer is not None and form == 'grouped':
         gate, up, down = (
             w.reshape(-1, *w.shape[2:]) for w in (gate, up, down)
         )
@@ -103,34 +186,12 @@ def routed_experts(  # distlint: traced
             weights = weights * routed_scale
         local = top_idx - first_expert
         is_held = (local >= 0) & (local < held)
-        # Pairs sorted by held expert; pairs of absent experts go last,
-        # past the end of the last group, where ragged_dot computes nothing.
-        group = jnp.where(is_held, local, held).reshape(-1)
-        order = jnp.argsort(group, stable=True)
-        group_sizes = jnp.bincount(group, length=held + 1)[:held].astype(
-            jnp.int32
-        )
-        if layer is not None:
-            group_sizes = jax.lax.dynamic_update_slice(
-                jnp.zeros((gate.shape[0],), jnp.int32), group_sizes,
-                (layer * held,),
+        if form == 'dense':
+            out = _dense(x, gate, up, down, local, weights, layer)
+        else:
+            out = _grouped(
+                x, gate, up, down, local, is_held, weights, held, layer
             )
-        # Rows in whole sublane tiles of 8: the TPU's grouped matmul is
-        # refused by the compiler for other counts (12, 20, 30 rows over 324
-        # groups). The pad rows lie past the last group: never computed.
-        rows = x[jnp.pad(order // k, (0, -tokens * k % 8))]  # [T*k (+pad), H]
-        hidden = jax.nn.silu(
-            jax.lax.ragged_dot(rows, gate, group_sizes)
-        ) * jax.lax.ragged_dot(rows, up, group_sizes)
-        out = jax.lax.ragged_dot(hidden, down, group_sizes)[: tokens * k]
-        # where, not a product: rows past the last group are not computed.
-        out = jnp.where(
-            is_held.reshape(-1)[order][:, None],
-            out.astype(jnp.float32) * weights.reshape(-1)[order][:, None],
-            0.0,
-        )
-        # Back to token order, then the k pairs of a token add up.
-        out = out[jnp.argsort(order)].reshape(tokens, k, -1).sum(axis=1)
         rows_counted = (
             jnp.ones((tokens,), bool) if counted is None else counted
         )
@@ -139,3 +200,65 @@ def routed_experts(  # distlint: traced
             (is_held & rows_counted[:, None]).sum(),
         ]).astype(jnp.int32)
     return out.astype(dtype), pairs
+
+
+def _grouped(x, gate, up, down, local, is_held, weights, held, layer):
+    """The held pairs sorted by expert through the grouped matmul: float32
+    ``[T, H]``. With ``layer`` the banks are the stack's ``L * E_held``
+    groups and the layer's experts are groups ``layer * E_held`` onward,
+    the other layers' groups empty."""
+    tokens, k = local.shape
+    # Pairs sorted by held expert; pairs of absent experts go last,
+    # past the end of the last group, where ragged_dot computes nothing.
+    group = jnp.where(is_held, local, held).reshape(-1)
+    order = jnp.argsort(group, stable=True)
+    group_sizes = jnp.bincount(group, length=held + 1)[:held].astype(
+        jnp.int32
+    )
+    if layer is not None:
+        group_sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((gate.shape[0],), jnp.int32), group_sizes,
+            (layer * held,),
+        )
+    # Rows in whole sublane tiles of 8: the TPU's grouped matmul is
+    # refused by the compiler for other counts (12, 20, 30 rows over 324
+    # groups). The pad rows lie past the last group: never computed.
+    rows = x[jnp.pad(order // k, (0, -tokens * k % 8))]  # [T*k (+pad), H]
+    hidden = jax.nn.silu(
+        jax.lax.ragged_dot(rows, gate, group_sizes)
+    ) * jax.lax.ragged_dot(rows, up, group_sizes)
+    out = jax.lax.ragged_dot(hidden, down, group_sizes)[: tokens * k]
+    # where, not a product: rows past the last group are not computed.
+    out = jnp.where(
+        is_held.reshape(-1)[order][:, None],
+        out.astype(jnp.float32) * weights.reshape(-1)[order][:, None],
+        0.0,
+    )
+    # Back to token order, then the k pairs of a token add up.
+    return out[jnp.argsort(order)].reshape(tokens, k, -1).sum(axis=1)
+
+
+def _dense(x, gate, up, down, local, weights, layer):
+    """Every row through every held expert, one batched ``dot`` a bank:
+    float32 ``[T, H]``. With ``layer`` the banks are ``[L, E_held, ...]``
+    and the layer is an index into the ``dot``'s operand, never a copy."""
+    held = gate.shape[-3]
+    if layer is not None:
+        gate, up, down = (
+            jax.lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
+            for w in (gate, up, down)
+        )
+    # [T, E_held] float32: a token's gate in the column of each held
+    # expert it chose (the k kept are distinct), zero elsewhere; an expert
+    # held elsewhere matches no column.
+    w = jnp.sum(
+        jnp.where(
+            local[:, :, None] == jnp.arange(held), weights[:, :, None], 0.0
+        ),
+        axis=1,
+    )
+    hidden = jax.nn.silu(jnp.einsum('th,ehi->eti', x, gate)) * jnp.einsum(
+        'th,ehi->eti', x, up
+    )
+    out = jnp.einsum('eti,eih->eth', hidden, down)
+    return jnp.einsum('eth,te->th', out.astype(jnp.float32), w)

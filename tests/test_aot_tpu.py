@@ -18,6 +18,8 @@ module that touches the topology at import gives the workers different
 tests to collect.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -827,6 +829,7 @@ def _mistral_chunk_prefill(v5e, pool):
     ).compile()
 
 
+@functools.lru_cache(maxsize=None)
 def _granite_window(v5e, pool):
     """``granite-4.0-h-small``'s decode window (8 steps, 96 rows, 8192
     blocks a layer) with TWO attention layers among two Mamba ones: the
@@ -981,16 +984,14 @@ def lfm2_cell(v5e):
     return lfm2, cfg, params, pool, state, engine
 
 
-def test_lfm2_decode_window_addresses_the_pool(v5e, lfm2_cell):
-    """The decode window at the cell's 96 rows: the stacked pool of 512-
-    lane rows goes to the writers and to the kernel whole (no plane and no
-    pool copied, no head padded to a tile), every call takes the row walk,
-    and the state's buffers are rewritten in place."""
+@pytest.fixture(scope='module')
+def lfm2_window(v5e, lfm2_cell):
+    """The decode window at the cell's 96 rows, compiled once."""
     lfm2, cfg, params, pool, state, engine = lfm2_cell
     b, i32, f32 = engine['max_num_seqs'], jnp.int32, jnp.float32
     assert b == 96
     pools = v5e(pool, jnp.bfloat16)
-    compiled = jax.jit(
+    return jax.jit(
         lambda p, i, po, c, k, v, bt, sl, tmp, tp_, mp, tk, sd, st:
             lfm2.decode_loop(
                 p, cfg, i, po, k, v, bt, c, sl, tmp, tp_, mp, tk, sd,
@@ -1003,11 +1004,19 @@ def test_lfm2_decode_window_addresses_the_pool(v5e, lfm2_cell):
         v5e((b, 528), i32), v5e((b,), i32), v5e((b,), f32), v5e((b,), f32),
         v5e((b,), f32), v5e((b,), i32), v5e((b,), jnp.uint32), state,
     ).compile()
-    _assert_stacked_pool_is_addressed(compiled, pool)
-    _assert_decode_calls_walk(compiled)
+
+
+def test_lfm2_decode_window_addresses_the_pool(lfm2_cell, lfm2_window):
+    """The decode window at the cell's 96 rows: the stacked pool of 512-
+    lane rows goes to the writers and to the kernel whole (no plane and no
+    pool copied, no head padded to a tile), every call takes the row walk,
+    and the state's buffers are rewritten in place."""
+    pool = lfm2_cell[3]
+    _assert_stacked_pool_is_addressed(lfm2_window, pool)
+    _assert_decode_calls_walk(lfm2_window)
     # nothing as large as the weights' smallest bank is left over as a
     # temporary: the pools and the state are updated where they lie
-    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+    assert lfm2_window.memory_analysis().temp_size_in_bytes < 256 << 20
 
 
 def test_lfm2_chunk_prefill_addresses_the_pool(v5e, lfm2_cell):
@@ -1029,3 +1038,227 @@ def test_lfm2_chunk_prefill_addresses_the_pool(v5e, lfm2_cell):
     ).compile()
     _assert_stacked_pool_is_addressed(compiled, pool)
     _assert_span_calls_keep_the_grid(compiled)
+
+
+# ---- the routed experts' two forms (PR 40; models/moe.py) ----
+
+def _assert_banks_are_streamed_by_a_dot(compiled, banks) -> None:
+    """A decode window's routed experts run the dense form: (1) no grouped
+    matmul (``ragged-dot``) is left in the program; (2) the layer's bank is
+    ADDRESSED inside its stack, never copied: outside the fused
+    computations (whose instructions are not materialised) nothing but the
+    stack handed on (a parameter, the loop and its tuples, a bitcast) has a
+    result the size of a bank or of the stack. Sliced out for a kernel call
+    a bank was 100-226 MB copied a call (PR 26)."""
+    import re
+
+    text = compiled.as_text()
+    assert 'ragged-dot' not in text
+    assert ' convolution(' in text  # what a batched dot is on the TPU
+    fused = set(re.findall(r'calls=%([^,\s)]+)', text))
+    copies, computation = [], None
+    for line in text.splitlines():
+        head = re.match(r'^(?:ENTRY )?%(\S+) \(', line)
+        if head:
+            computation = head.group(1)
+            continue
+        m = re.match(r'^\s*(?:ROOT )?%(\S+) = (\S+) ([a-z\-]+)\(', line)
+        if not m or computation in fused:
+            continue
+        name, result, opcode = m.groups()
+        if opcode in ('parameter', 'get-tuple-element', 'tuple', 'while',
+                      'bitcast'):
+            continue
+        if any(_holds(result, shape) for shape in banks):
+            copies.append(f'%{name} = {result[:50]} {opcode}')
+    assert not copies, copies
+
+
+def test_granite_decode_window_streams_its_banks_densely(v5e):
+    """96 rows over 36 held experts of ``[4096, 768]``, the layer a traced
+    index of the scan over a kind's layers."""
+    compiled = _granite_window(v5e, (2, 8192, 16, _NKV * _HD))
+    _assert_banks_are_streamed_by_a_dot(
+        compiled, [(36, 4096, 768), (2, 36, 4096, 768)]
+    )
+
+
+def test_lfm2_decode_window_streams_its_banks_densely(lfm2_cell, lfm2_window):
+    """96 rows over 16 held experts of ``[2048, 1792]``, the layer a static
+    index (the layers unrolled); the 7-layer cut stacks 5 sparse layers."""
+    bank = jax.tree.leaves(lfm2_cell[2]['sparse']['gate'])[0].shape
+    assert bank == (5, 16, 2048, 1792)
+    _assert_banks_are_streamed_by_a_dot(lfm2_window, [bank[1:], bank])
+
+
+def _granite(v5e, layer_types=None):
+    """The granite cell's configuration (cut to ``layer_types`` if given):
+    module, config, parameters and state as shapes at the cell's sizes."""
+    import json
+    from pathlib import Path
+
+    from distllm_tpu.models import granite_hybrid
+
+    root = Path(__file__).resolve().parents[1]
+    hf = json.loads(
+        (root / 'benchmarks/configs/granite-4.0-h-small.json').read_text()
+    )
+    if layer_types is not None:
+        hf['layer_types'] = list(layer_types)
+        hf['num_hidden_layers'] = len(layer_types)
+    cfg = granite_hybrid.GraniteHybridConfig.from_hf_config(hf)
+    shapes = jax.eval_shape(
+        lambda: granite_hybrid.init_on_device(jax.random.PRNGKey(0), cfg)
+    )
+    params = jax.tree.map(lambda a: v5e(a.shape, a.dtype), shapes)
+    state = jax.tree.map(
+        lambda a: v5e((hf['engine']['max_num_seqs'], *a.shape), a.dtype),
+        cfg.state_spec(),
+    )
+    return granite_hybrid, cfg, params, state
+
+
+@pytest.fixture(scope='module')
+def granite_cell(v5e):
+    """Cut to four layers: an attention layer among three Mamba ones."""
+    return _granite(v5e, ('mamba', 'mamba', 'attention', 'mamba'))
+
+
+@pytest.mark.parametrize('bucket, rows', [(64, 1), (16, 4)])
+def test_granite_tail_prefill_reads_its_banks_as_they_lie(v5e, bucket, rows):
+    """A chunk tail of the granite cell at FULL depth (the nine Mamba
+    layers under one scan): its 64 rows take the dense form, and the stack
+    of banks stays where it lies. At 121-128 rows the compiler turned the
+    whole ``bf16[9, 36, 4096, 768]`` stacks over outside that scan (three
+    1.9 GB copies: the program did not fit the chip, PR 40), which a
+    three-layer cut does not show; the rule stops at 120 rows for it."""
+    from distllm_tpu.models import moe
+
+    granite_hybrid, cfg, params, state = _granite(v5e)
+    bank = jax.tree.leaves(params['mamba']['gate'])[0].shape
+    assert bank == (9, 36, 4096, 768)
+    assert moe.expert_form(bucket * rows, 10, 36, 72, 4096, 768) == 'dense'
+    assert moe.expert_form(128, 10, 36, 72, 4096, 768) == 'grouped'
+    i32 = jnp.int32
+    pools = v5e((1, 8192, 16, _NKV * _HD), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda p, ids, pos, k, v, bt, ctx, tails, st, slots:
+            granite_hybrid.prefill_paged(
+                p, cfg, ids, pos, k, v, bt, ctx, tails, st, slots,
+                attn_backend='pallas',
+            ),
+        donate_argnums=(3, 4, 8),
+    ).lower(
+        params, v5e((rows, bucket), i32), v5e((rows, bucket), i32), pools,
+        pools, v5e((rows, 256), i32), v5e((rows,), i32), v5e((rows,), i32),
+        state, v5e((rows,), i32),
+    ).compile()
+    _assert_banks_are_streamed_by_a_dot(compiled, [bank[1:], bank])
+    assert compiled.memory_analysis().temp_size_in_bytes < 512 << 20
+
+
+def _chunk_prefill_text(v5e, family, request) -> str:
+    """The lowered text of a family's ``(512, 4)`` prefill program at its
+    cell's widths."""
+    i32 = jnp.int32
+    spans = (v5e((4, 512), i32), v5e((4, 512), i32))
+    rows = (v5e((4,), i32), v5e((4,), i32))
+    kw = dict(attn_backend='pallas')
+    if family == 'granite':
+        module, cfg, params, state = request.getfixturevalue('granite_cell')
+        pools = v5e((1, 8192, 16, _NKV * _HD), jnp.bfloat16)
+        operands = (pools, pools, v5e((4, 256), i32), *rows, state,
+                    v5e((4,), i32))
+    elif family == 'lfm2':
+        module, cfg, params, pool, state, _ = request.getfixturevalue(
+            'lfm2_cell'
+        )
+        pools = v5e(pool, jnp.bfloat16)
+        operands = (pools, pools, v5e((4, 528), i32), *rows, state,
+                    v5e((4,), i32))
+    elif family == 'laguna':
+        module, cfg, params, pools, _ = request.getfixturevalue('laguna_cell')
+        operands = (pools, pools, (v5e((4, 528), i32),) * 2, *rows)
+    else:
+        module, cfg, params, planes, _, _ = request.getfixturevalue(
+            'kanana_cell'
+        )
+        operands = (planes, (), v5e((4, 528), i32), *rows)
+    if family != 'granite':
+        kw['max_table_positions'] = 8448
+    return jax.jit(
+        lambda p, ids, pos, k, v, bt, ctx, tails, *state:
+            module.prefill_paged(
+                p, cfg, ids, pos, k, v, bt, ctx, tails, *state, **kw
+            ),
+    ).lower(params, *spans, *operands).as_text()
+
+
+@pytest.mark.parametrize('family', ['granite', 'laguna', 'kanana', 'lfm2'])
+def test_chunk_prefill_keeps_the_grouped_matmul(
+    v5e, family, request, monkeypatch
+):
+    """Every ``(512, 4)`` prefill program stays on the grouped matmul:
+    the text is the one the program lowers to with the rule taken out and
+    every call sent to the grouped form."""
+    from distllm_tpu.models import moe
+
+    texts = []
+    # One call site: a Mosaic kernel's serialized body carries the lines
+    # of the frames it was traced under.
+    for rule in (moe.expert_form, lambda *shape: 'grouped'):
+        monkeypatch.setattr(moe, 'expert_form', rule)
+        texts.append(_chunk_prefill_text(v5e, family, request))
+    assert 'ragged_dot' in texts[0]
+    assert texts[0] == texts[1]
+
+
+# The sha256 (first 16 digits) of what ``routed_experts`` lowered to at PR
+# 39 (the parent of the dense form) for a 2,048-token call at each
+# family's widths and arguments, the layer a traced index into the stack.
+_GROUPED_AT_PR39 = {
+    'granite': ((10, 36, 72, 4096, 768, 9), {}, '96c0872bb46c20de'),
+    'laguna': ((8, 64, 256, 2048, 512, 19), {'routed_scale': 2.5},
+               '2ef872e1be158437'),
+    'kanana': ((6, 32, 128, 2048, 768, 23),
+               {'scoring': 'sigmoid', 'routed_scale': 2.448, 'bias': True},
+               '6166093a8262a13f'),
+    'lfm2': ((4, 16, 32, 2048, 1792, 22),
+             {'scoring': 'sigmoid', 'norm_eps': 1e-6, 'bias': True},
+             '3e3748a4953c8b30'),
+}
+
+
+@pytest.mark.parametrize('family', sorted(_GROUPED_AT_PR39))
+def test_grouped_form_lowers_to_the_parents_text(v5e, family):
+    """The grouped form is the parent's to the byte: a prefill program's
+    expert layer lowers to the text it had before the dense form came (the
+    compile cache's key, and what XLA compiles, follow from it). A change
+    of jax may move all four at once; a change of one is a change to the
+    grouped path."""
+    import hashlib
+
+    from distllm_tpu.models import moe
+
+    (k, held, routed, hidden, width, layers), kw, want = (
+        _GROUPED_AT_PR39[family]
+    )
+    kw = dict(kw)
+    biased = kw.pop('bias', False)
+    bf, f32 = jnp.bfloat16, jnp.float32
+    assert moe.expert_form(2048, k, held, routed, hidden, width) == 'grouped'
+
+    def fn(x, router, gate, up, down, bias, counted, layer):
+        return moe.routed_experts(
+            x, router, gate, up, down, k, first_expert=0, counted=counted,
+            layer=layer, select_bias=bias if biased else None, **kw,
+        )
+
+    text = jax.jit(fn).lower(
+        v5e((2048, hidden), bf), v5e((hidden, routed), bf),
+        v5e((layers, held, hidden, width), bf),
+        v5e((layers, held, hidden, width), bf),
+        v5e((layers, held, width, hidden), bf), v5e((routed,), f32),
+        v5e((2048,), jnp.bool_), v5e((), jnp.int32),
+    ).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == want

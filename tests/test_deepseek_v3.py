@@ -248,10 +248,13 @@ def _routed_experts_of_the_parent(x, router_kernel, gate, up, down, k,
 def test_softmax_callers_of_routed_experts_get_what_they_got(layer, scale):
     """Softmax with no bias (granite's call, and laguna's with a layer
     stack and a routed scale) lowers to the parent's program, text for
-    text, and gives its outputs bit for bit."""
+    text, and gives its outputs bit for bit: at a prefill dispatch's rows,
+    where the rule keeps the grouped form (a handful of rows takes the
+    dense one since PR 40, ``tests/test_moe_forms.py``)."""
     rng = np.random.default_rng(5)
     stack = () if layer is None else (3,)
-    x = jnp.asarray(rng.standard_normal((10, 16)), jnp.float32)
+    assert moe.expert_form(256, 3, 4, 8, 16, 12) == 'grouped'
+    x = jnp.asarray(rng.standard_normal((256, 16)), jnp.float32)
     router = jnp.asarray(rng.standard_normal((16, 8)), jnp.float32)
     banks = [
         jnp.asarray(rng.standard_normal((*stack, 4, *shape)), jnp.float32)
