@@ -626,6 +626,19 @@ class EngineConfig(BaseConfig):
         return v
 
 
+def bounce_slabs(rows: int, nbytes: int) -> tuple[int, list[int]]:
+    """How a leaf of ``rows`` indices along dim 0 and ``nbytes`` bytes goes
+    through the host in ``_migrate_params``: ``(slab, starts)``, ``slab``
+    indices a transfer from each of ``starts``. One index a transfer where
+    an index is a MiB or more (a layer of a stacked kernel); else slabs of
+    about 64 MiB, all of one size: the last starts at ``rows - slab``."""
+    row_bytes = max(1, nbytes // rows)
+    if row_bytes >= (1 << 20):
+        return 1, list(range(rows))
+    slab = max(1, min(rows, (64 << 20) // row_bytes))
+    return slab, sorted({min(i, rows - slab) for i in range(0, rows, slab)})
+
+
 def auto_layout_formats(params):
     """What the decode window's AOT compile asks for, leaf by leaf of the
     weights: ``Layout.AUTO``, but the device's default layout for a leaf
@@ -1593,7 +1606,9 @@ class LLMEngine:
         """Dispatch the decode window over its plan's device arrays (ids,
         positions, context_lens, block_tables, steps_left, the sampling
         rows) and fold its pools back. Returns ``(tokens, last_ids,
-        moe_pairs)``, the last None unless the family counts them."""
+        moe_pairs)``, the last None unless the family counts them (a
+        family may return a dict of named int32 counters in their place:
+        each becomes a field of the ``decode`` record)."""
         ids, pos, ctx, *rest = plan
         k, v = self._pools()
         extra = () if self.state_pool is None else (self.state_pool.state,)
@@ -1711,10 +1726,30 @@ class LLMEngine:
                     # is its own host copy; a device leaf is fetched in
                     # slices along dim 0 (a single multi-GiB d2h exhausts
                     # the backend's staging memory) and freed first.
+                    # A slice is one index of dim 0 (a layer of a stacked
+                    # kernel: ~100 MB). A leaf of many small rows (a
+                    # 261,120-row embedding, the head beside it: 10 KB and
+                    # 0.5 MB a row, two dispatches a row each way) moves
+                    # in slabs of rows instead, ~64 MiB each, the last one
+                    # laid over the end of the one before it so that
+                    # every slab has one shape.
+                    rows = leaf.shape[0]
+                    slab, starts = bounce_slabs(rows, nbytes)
                     if on_device:
                         host = np.empty(leaf.shape, leaf.dtype)
-                        for i in range(leaf.shape[0]):
-                            host[i] = np.asarray(leaf[i])
+                        if slab == 1:
+                            for i in range(rows):
+                                host[i] = np.asarray(leaf[i])
+                        else:
+                            take = jax.jit(
+                                lambda a, i: jax.lax.dynamic_slice_in_dim(
+                                    a, i, slab, 0
+                                )
+                            )
+                            for i in starts:
+                                host[i:i + slab] = np.asarray(
+                                    take(leaf, np.int32(i))
+                                )
                         leaf.delete()
                     else:
                         host = leaf
@@ -1724,15 +1759,26 @@ class LLMEngine:
                         ),
                         out_shardings=fmt,
                     )()
-                    fill = jax.jit(
-                        lambda buf, part, idx: jax.lax.dynamic_update_index_in_dim(
-                            buf, part, idx, 0
-                        ),
-                        donate_argnums=0,
-                        out_shardings=fmt,
-                    )
-                    for i in range(host.shape[0]):
-                        moved = fill(moved, host[i], np.int32(i))
+                    if slab == 1:
+                        fill = jax.jit(
+                            lambda buf, part, idx: jax.lax.dynamic_update_index_in_dim(
+                                buf, part, idx, 0
+                            ),
+                            donate_argnums=0,
+                            out_shardings=fmt,
+                        )
+                        for i in range(host.shape[0]):
+                            moved = fill(moved, host[i], np.int32(i))
+                    else:
+                        fill = jax.jit(
+                            lambda buf, part, idx: jax.lax.dynamic_update_slice_in_dim(
+                                buf, part, idx, 0
+                            ),
+                            donate_argnums=0,
+                            out_shardings=fmt,
+                        )
+                        for i in starts:
+                            moved = fill(moved, host[i:i + slab], np.int32(i))
                     del host
                     jax.block_until_ready(moved)
                 elif on_device:
@@ -4317,8 +4363,9 @@ class LLMEngine:
             'chunk_tokens': chunk_tokens,
             'chunk_plan': chunk_entries,
             'context_lens': context_arrays,
-            # A hybrid's extra: the window's (routed, held) expert pairs,
-            # fetched with its tokens.
+            # A family's extra, fetched with the window's tokens: its
+            # (routed, held) expert pairs, or a dict of named counters
+            # summed on the device over the window's steps.
             'moe_pairs': moe_pairs,
             # With a windowed cache group: what its rows held of it.
             'window_fields': window_fields,
@@ -4693,6 +4740,11 @@ class LLMEngine:
         # distlint: disable=host-sync-in-hot-path -- the window loop's ONE designed fetch point: processing happens a window late, after the next dispatch is already in flight (pipeline_depth hides this sync)
         tokens = np.asarray(window['tokens'])  # [K, B]
         moe_pairs = window.get('moe_pairs')
+        counters = None  # a family's named counters in the pairs' place
+        if isinstance(moe_pairs, dict):
+            # distlint: disable=host-sync-in-hot-path -- a few int32 the window's program wrote with the tokens fetched one line up: ready, never waited for
+            counters = {name: int(np.asarray(n)) for name, n in moe_pairs.items()}
+            moe_pairs = None
         if moe_pairs is not None:
             # distlint: disable=host-sync-in-hot-path -- two int32 the window's program wrote with the tokens fetched one line up: ready, never waited for
             moe_pairs = np.asarray(moe_pairs)
@@ -4729,6 +4781,8 @@ class LLMEngine:
                     'prefill_tokens': sum(n for *_, n, _ in chunk_entries),
                     'prefill_rows': len(chunk_entries),
                 }
+            if counters is not None:
+                extra = dict(counters)
             if moe_pairs is not None:
                 extra = {
                     'moe_pairs': int(moe_pairs[0]),

@@ -22,6 +22,7 @@ def decoder_families() -> dict:
     """
     from distllm_tpu.models import (
         deepseek_v3,
+        falcon_h1,
         gemma,
         granite_hybrid,
         laguna,
@@ -43,6 +44,7 @@ def decoder_families() -> dict:
         'laguna': (laguna.LagunaConfig, laguna),
         'deepseek_v3': (deepseek_v3.DeepseekV3Config, deepseek_v3),
         'lfm2_moe': (lfm2.Lfm2MoeConfig, lfm2),
+        'falcon_h1': (falcon_h1.FalconH1Config, falcon_h1),
     }
 
 

@@ -3,22 +3,23 @@ mixer every few layers, a routed expert layer plus a shared gated MLP in
 every layer, no positional encoding, Granite's four multipliers.
 
 Two kinds of layer, so two stacked parameter trees (``params['mamba']``,
-``params['attention']``); the forwards walk ``cfg.layer_types`` and scan each
-run of equal layers. A sequence holds two kinds of state: K/V pages for the
-attention layers (``PagedKVCache`` over ``cfg.num_paged_layers`` layers) and,
-for every Mamba layer, a fixed recurrent state (``cfg.state_spec()``): the
-SSM state ``[heads, head_dim, d_state]`` in float32 and the last
-``d_conv - 1`` columns of the convolution's input in the model's dtype. The
-state pool is a tuple of one array per Mamba layer, ``[slots, ...]``: the
-decode window updates whole buffers in place, prefill gathers and scatters
-the rows of the slots it runs.
+``params['attention']``); the forwards walk ``cfg.layer_types`` and scan each run of
+equal layers. A sequence holds two kinds of state: K/V pages for the attention layers
+(``PagedKVCache`` over ``cfg.num_paged_layers`` layers) and, for every Mamba layer, a
+fixed recurrent state (``cfg.state_spec()``): the SSM state ``[heads, head_dim,
+d_state]`` in float32 and the last ``d_conv - 1`` columns of the convolution's input
+in the model's dtype. The state pool is a tuple of one array per Mamba layer,
+``[slots, ...]``: the decode window updates whole buffers in place, prefill gathers
+and scatters the rows of the slots it runs.
 
-The attention mixers call the serving attention entry points that
-``models/mistral.py`` calls (``common.sdpa``, ``ragged_paged_attention``,
-``write_chunk_kv``, ``write_token_kv``) with ``scale=attention_multiplier``
-and no rotation. The expert layer is ``models/moe.py``: a chip may hold a
-share of the routed experts (``first_local_expert``, ``num_local_experts``)
-while the router ranks all ``num_experts``.
+The attention mixers call the serving attention entry points that ``models/mistral.py``
+calls (``common.sdpa``, ``ragged_paged_attention``, ``write_chunk_kv``,
+``write_token_kv``) with ``scale=attention_multiplier`` and no rotation. The expert
+layer is ``models/moe.py``: a chip may hold a share of the routed experts
+(``first_local_expert``, ``num_local_experts``) while the router ranks all
+``num_experts``. The Mamba-2 functions serve every family with such a mixer
+(``models/falcon_h1.py``): what they take from the config they are given, groups of
+``B, C`` and factors of the in-projection's parts among it, is said at this file's end.
 
 Equations (transformers ``models/granitemoehybrid``):
 
@@ -152,16 +153,16 @@ class GraniteHybridConfig(BaseConfig):
             )
         if hf.get('mamba_n_groups', 1) != 1:
             raise ValueError(
-                'granitemoehybrid: only mamba_n_groups 1 is implemented, '
-                f"got {hf['mamba_n_groups']}"
+                'granitemoehybrid: served with the published mamba_n_groups 1 (the '
+                f"Mamba-2 functions take more: file's end), got {hf['mamba_n_groups']}"
             )
         if hf.get('mamba_proj_bias', False) or hf.get('attention_bias', False):
             raise ValueError('granitemoehybrid: projection biases not implemented')
         d_inner = hf['mamba_n_heads'] * hf['mamba_d_head']
         if d_inner != hf.get('mamba_expand', 2) * hf['hidden_size']:
             raise ValueError(
-                'granitemoehybrid: mamba_n_heads * mamba_d_head must equal '
-                'mamba_expand * hidden_size'
+                'granitemoehybrid: mamba_n_heads * mamba_d_head must equal mamba_expand'
+                ' * hidden_size here (a family with another inner width states d_inner)'
             )
         held = hf['num_local_experts']
         return cls(
@@ -406,9 +407,9 @@ def ssd_chunked(x, dt, a, b_in, c_in, ssm0, chunk: int):  # distlint: traced
     time (the SSD form): inside a chunk as one masked matrix product, from
     chunk to chunk through the carried state. ``x [B, S, H, P]``, ``dt [B,
     S, H]`` (0 where a position does not count: the state passes through),
-    ``a [H]`` negative, ``b_in``/``c_in [B, S, N]``, ``ssm0 [B, H, P, N]``.
-    Returns ``y [B, S, H, P]`` (without the ``D`` skip) and the state after
-    the last position, float32. Any ``chunk`` gives the same numbers."""
+    ``a [H]`` negative, ``b_in``/``c_in [B, S, N]`` (one group) or ``[B, S, G, N]``
+    (head ``i`` reads group ``i // (H / G)``), ``ssm0 [B, H, P, N]``. Returns ``y [B, S,
+    H, P]`` (no ``D`` skip) and the last state, float32, the same at any ``chunk``."""
     bsz, s, h, p = x.shape
     pad = -s % chunk
     if pad:
@@ -443,8 +444,9 @@ def ssd_chunked(x, dt, a, b_in, c_in, ssm0, chunk: int):  # distlint: traced
         )
         return ssm, y
 
+    step = one_chunk if b_in.ndim == 3 else _one_chunk_grouped(a, lower)
     ssm, y = jax.lax.scan(
-        one_chunk, ssm0.astype(F32), tuple(split(t) for t in (x, dt, b_in, c_in))
+        step, ssm0.astype(F32), tuple(split(t) for t in (x, dt, b_in, c_in))
     )
     y = jnp.moveaxis(y, 0, 1).reshape(bsz, s + pad, h, p)
     return y[:, :s], ssm
@@ -454,7 +456,7 @@ def _mamba_inputs(lp, cfg, conv_window):
     """The convolution of a Mamba mixer and its split. ``conv_window`` is
     the convolution's input, ``[..., K - 1 + S, conv_dim]``: the carried
     columns, then the span's own. Returns ``x [..., S, heads, P]``, ``B``
-    and ``C [..., S, N]``, float32."""
+    and ``C [..., S, N]`` (``[..., S, G, N]`` with several groups), float32."""
     k = cfg.mamba_d_conv
     s = conv_window.shape[-2] - (k - 1)
     w = lp['conv'].astype(F32)
@@ -463,21 +465,21 @@ def _mamba_inputs(lp, cfg, conv_window):
         w[j] * jax.lax.slice_in_dim(win, j, j + s, axis=-2) for j in range(k)
     ) + lp['conv_bias'].astype(F32)
     xbc = jax.nn.silu(conv)
-    di, n = cfg.d_inner, cfg.mamba_d_state
+    di = cfg.d_inner
     x = xbc[..., :di].reshape(*xbc.shape[:-1], cfg.mamba_n_heads, cfg.mamba_d_head)
-    return x, xbc[..., di:di + n], xbc[..., di + n:]
+    return (x, *_b_and_c(xbc, cfg))
 
 
 def _mamba_out(y, z, lp, cfg, dtype):
-    """Gate first, then the norm over all of ``d_inner`` (one group), then
-    the output projection."""
+    """Gate first, then the norm over each group's channels apart (all of
+    ``d_inner`` with one group), then the output projection."""
     gated = y.reshape(*y.shape[:-2], cfg.d_inner) * jax.nn.silu(z.astype(F32))
-    normed = common.rms_norm(gated, lp['norm']['scale'], cfg.rms_norm_eps)
+    normed = _gated_norm(gated, lp['norm']['scale'], cfg)
     return common.dense(normed.astype(dtype), lp['out_proj']['kernel'])
 
 
 def _split_in_proj(h, lp, cfg):
-    proj = common.dense(h, lp['in_proj']['kernel'])
+    proj = _scale_parts(common.dense(h, lp['in_proj']['kernel']), cfg)
     di, cd = cfg.d_inner, cfg.conv_dim
     return proj[..., :di], proj[..., di:di + cd], proj[..., di + cd:]
 
@@ -521,15 +523,13 @@ def mamba_step(h, lp, cfg, ssm0, conv0, live):  # distlint: traced
         dt, a = _dt_a(dt_raw, lp)  # [B, heads]
         ssm = (
             ssm0 * jnp.exp(dt * a)[..., None, None]
-            + (dt[..., None] * x)[..., None] * b_in[:, None, None, :]
+            + (dt[..., None] * x)[..., None] * _of_heads(b_in, cfg)
         )
-        y = jnp.sum(ssm * c_in[:, None, None, :], axis=-1)
+        y = jnp.sum(ssm * _of_heads(c_in, cfg), axis=-1)
         y = y + lp['D'].astype(F32)[:, None] * x
         out = _mamba_out(y, z, lp, cfg, h.dtype)
         ssm = jnp.where(live[:, None, None, None], ssm, ssm0).astype(ssm0.dtype)
-        conv = jnp.where(
-            live[:, None, None], window[:, 1:].astype(conv0.dtype), conv0
-        )
+        conv = jnp.where(live[:, None, None], window[:, 1:].astype(conv0.dtype), conv0)
         return out, ssm, conv
 
 
@@ -849,3 +849,99 @@ def decode_loop(  # distlint: traced
         length=num_steps,
     )
     return tokens, k_cache, v_cache, ids, state, pairs
+
+
+# ------------------------------------------- Mamba-2 beyond this family's sizes
+# ``ssd_chunked``, ``mamba_span``, ``mamba_step`` and their parts serve every
+# family with a Mamba-2 mixer (``models/falcon_h1.py`` imports them) and take
+# their sizes from the config they are given: ``mamba_n_heads``,
+# ``mamba_d_head`` and ``d_inner`` (their product here; a family whose inner
+# width is its own states it), ``mamba_d_state``, ``mamba_d_conv``,
+# ``mamba_chunk_size``, ``conv_dim`` (``d_inner + 2 * groups * state``) and, where
+# the config names them, ``mamba_n_groups`` (``B`` and ``C`` come a group; head
+# ``i`` reads group ``i // (heads / groups)``, and the gated norm is taken over
+# each group's channels apart) and ``ssm_multipliers`` (five factors for the
+# in-projection's parts ``z, x, B, C, dt``, applied before the convolution). A
+# config that names neither has one group and no factors, and then each function
+# traces the very operations it traced before it learnt the rest: the group
+# count is static and each part branches on it. What the rest needs is defined
+# HERE, below every function of this family, and the block above kept its line
+# count: the paged kernel's lowered text carries the line numbers of its callers
+# (``prefill_paged``, ``_decode_core``), so nothing above them may move if this
+# family's programs are to stay the text they were (tests/test_aot_tpu.py).
+def _groups(cfg) -> int:
+    return getattr(cfg, 'mamba_n_groups', 1)
+
+
+def _b_and_c(xbc, cfg):
+    """``B`` and ``C`` of the convolved ``[x | B | C]``: ``[..., N]`` each with
+    one group, ``[..., G, N]`` with several."""
+    di, n, g = cfg.d_inner, cfg.mamba_d_state, _groups(cfg)
+    if g == 1:
+        return xbc[..., di:di + n], xbc[..., di + n:]
+    by_group = lambda t: t.reshape(*t.shape[:-1], g, n)  # noqa: E731
+    return by_group(xbc[..., di:di + g * n]), by_group(xbc[..., di + g * n:])
+
+
+def _gated_norm(gated, scale, cfg):
+    """RMS norm of ``gated [..., d_inner]`` over each group's channels apart."""
+    g = _groups(cfg)
+    if g == 1:
+        return common.rms_norm(gated, scale, cfg.rms_norm_eps)
+    return common.rms_norm(
+        gated.reshape(*gated.shape[:-1], g, cfg.d_inner // g),
+        scale.reshape(g, cfg.d_inner // g), cfg.rms_norm_eps,
+    ).reshape(gated.shape)
+
+
+def _scale_parts(proj, cfg):
+    """The in-projection's five parts ``z, x, B, C, dt`` times their factors."""
+    factors = getattr(cfg, 'ssm_multipliers', None)
+    if factors is None:
+        return proj
+    gn = _groups(cfg) * cfg.mamba_d_state
+    widths = (cfg.d_inner, cfg.d_inner, gn, gn, cfg.mamba_n_heads)
+    return proj * jnp.asarray(
+        np.repeat(np.asarray(factors, np.float32), widths), proj.dtype
+    )
+
+
+def _of_heads(t, cfg):
+    """A step's ``B`` or ``C`` against the state ``[B, heads, P, N]``: ``t [B,
+    N]`` for every head, or ``t [B, G, N]`` as the row of each head's group."""
+    if _groups(cfg) == 1:
+        return t[:, None, None, :]
+    return jnp.repeat(t, cfg.mamba_n_heads // _groups(cfg), axis=1)[:, :, None, :]
+
+
+def _one_chunk_grouped(a, lower):
+    """``ssd_chunked``'s chunk step with a score ``[Qi, Qj]`` a group; the heads
+    of a group lie together: ``[B, Q, H, P]`` is ``[B, Q, G, H / G, P]``."""
+
+    def one_chunk(ssm, xs):
+        x_c, dt_c, b_c, c_c = xs  # [B, Q, H, P], [B, Q, H], [B, Q, G, N] x2
+        (bsz, chunk, h, p), g = x_c.shape, b_c.shape[2]
+
+        def by_group(t):  # [B, Q, H, ...] -> [B, Q, G, H / G, ...]
+            return t.reshape(*t.shape[:2], g, h // g, *t.shape[3:])
+
+        cum = jnp.cumsum(dt_c * a, axis=1)  # [B, Q, H]
+        y = jnp.einsum(
+            'bqgn,bghpn->bqghp', c_c, ssm.reshape(bsz, g, h // g, p, -1)
+        ).reshape(x_c.shape) * jnp.exp(cum)[..., None]
+        diff = cum[:, :, None, :] - cum[:, None, :, :]  # [B, Qi, Qj, H]
+        decay = jnp.exp(jnp.where(lower[None, :, :, None], diff, -jnp.inf))
+        scores = jnp.einsum('bign,bjgn->bijg', c_c, b_c)  # [B, Qi, Qj, G]
+        w = (decay * dt_c[:, None, :, :]).reshape(
+            bsz, chunk, chunk, g, h // g
+        ) * scores[..., None]
+        y = y + jnp.einsum('bijgh,bjghp->bighp', w, by_group(x_c)).reshape(
+            x_c.shape
+        )
+        tail = jnp.exp(cum[:, -1:, :] - cum) * dt_c  # [B, Q, H]
+        ssm = ssm * jnp.exp(cum[:, -1])[..., None, None] + jnp.einsum(
+            'bqgh,bqghp,bqgn->bghpn', by_group(tail), by_group(x_c), b_c
+        ).reshape(ssm.shape)
+        return ssm, y
+
+    return one_chunk

@@ -27,7 +27,11 @@ E_routed`` share a token reaches, not the whole bank.
 Costs come from the engine's *actual* parameter tree — ``sum(leaf.size)``
 and ``sum(leaf.nbytes)`` over ``jax.tree.leaves`` — so quantized codes,
 migrated layouts, and MoE trees are all priced as the bytes that really
-stream, with no per-architecture formula to drift.
+stream, with no per-architecture formula to drift. One rule reads the tree's
+names: where an output head of its own (``head``) stands beside the
+embedding (``embed``), the embedding is a lookup of a row a token and is
+left out of both sides (``models/falcon_h1.py``: 1.34 B of 5.25 B held
+parameters at the cut the benchmark runs).
 
 Peaks come from a device-kind table (TPU generations), overridable with
 ``DISTLLM_PEAK_FLOPS`` / ``DISTLLM_PEAK_BW_BYTES`` for new silicon. On
@@ -176,6 +180,13 @@ class CostModel:
         weight_bytes = sum(getattr(x, 'nbytes', 0) for x in leaves)
         if experts_per_token:
             n_params -= _unreached_expert_params(params, experts_per_token)
+        if isinstance(params, dict) and 'head' in params and 'embed' in params:
+            # An output head of its own beside the embedding: the head is
+            # read whole a step, the embedding a ROW a token (a lookup, no
+            # matmul), so it is on neither side.
+            for leaf in jax.tree.leaves(params['embed']):
+                n_params -= getattr(leaf, 'size', 0)
+                weight_bytes -= getattr(leaf, 'nbytes', 0)
         if device is None:
             device = jax.devices()[0]
         peak_flops, peak_bw = device_peaks(device)

@@ -919,8 +919,8 @@ def ragged_paged_attention_pallas(
         # 7B dims while still feeding the MXU full tiles (1024 rows on a
         # latent plane's one head, above).
         span_tile = max(1, (1024 if latent else 512) // group)
-        # A power of two: with 6 queries a KV head 85 positions would be
-        # 510 rows, which Mosaic refuses (a block's rows must be a
+        # A power of two: with 6 queries a KV head 85 positions (with 5,
+        # 102) would be 510 rows, which Mosaic refuses (a block's rows: a
         # multiple of 8), and the spans' pow2 buckets must divide evenly.
         span_tile = 1 << (span_tile.bit_length() - 1)
     span_tile = min(span_tile, s)

@@ -30,13 +30,13 @@ from pathlib import Path
 from types import SimpleNamespace
 
 ROOT = Path(__file__).resolve().parents[1]
-sys.path.insert(0, str(ROOT))
+sys.path[:0] = [str(ROOT), str(ROOT / 'scripts')]  # the neighbours below
 
 import jax
 import jax.numpy as jnp
 
 from benchmarks.drivers import lfm2_closed
-from probe_deepseek_reference import patched  # scripts/ is on the path
+from probe_deepseek_reference import patched
 from distllm_tpu.models import common, lfm2
 from distllm_tpu.ops import paged_attention
 from distllm_tpu.utils import enable_compile_cache

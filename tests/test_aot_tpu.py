@@ -1262,3 +1262,89 @@ def test_grouped_form_lowers_to_the_parents_text(v5e, family):
         v5e((2048,), jnp.bool_), v5e((), jnp.int32),
     ).as_text()
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == want
+
+
+# ---- falcon_h1 (PR 41): pages AND state in every layer, 5 queries a KV head ----
+
+@pytest.fixture(scope='module')
+def falcon_h1_cell(v5e):
+    """The cell's configuration at the cut's FULL depth (6 layers, one
+    stacked tree): the parameters, the pool of every layer and the state of
+    every layer at the cell's sizes, 8192 blocks and 96 slots."""
+    import json
+    from pathlib import Path
+
+    from distllm_tpu.models import falcon_h1
+
+    root = Path(__file__).resolve().parents[1]
+    hf = json.loads(
+        (root / 'benchmarks/configs/falcon-h1-34b.json').read_text()
+    )
+    cfg = falcon_h1.FalconH1Config.from_hf_config(hf)
+    assert cfg.num_layers == 6 and cfg.num_heads // cfg.num_kv_heads == 5
+    shapes = jax.eval_shape(
+        lambda: falcon_h1.init_on_device(jax.random.PRNGKey(0), cfg)
+    )
+    params = jax.tree.map(lambda a: v5e(a.shape, a.dtype), shapes)
+    engine = hf['engine']
+    pool = (cfg.num_layers, engine['num_blocks'], 16, 512)
+    state = jax.tree.map(
+        lambda a: v5e((engine['max_num_seqs'], *a.shape), a.dtype),
+        cfg.state_spec(),
+    )
+    return falcon_h1, cfg, params, pool, state, engine
+
+
+def test_falcon_h1_decode_window_updates_pages_and_state_in_place(
+    v5e, falcon_h1_cell
+):
+    """The decode window at the cell's 96 rows and full depth: every layer
+    writes a page and a state slot in the same step. The stacked pool goes
+    to the writers and to the kernel whole, every kernel call (5 queries a
+    KV head) takes the row walk, and nothing as large as a layer's states
+    (96 x 4 MB) is left over as a temporary beside the sampler's rows."""
+    falcon_h1, cfg, params, pool, state, engine = falcon_h1_cell
+    b, i32, f32 = engine['max_num_seqs'], jnp.int32, jnp.float32
+    assert b == 96
+    pools = v5e(pool, jnp.bfloat16)
+    compiled = jax.jit(
+        lambda p, i, po, c, k, v, bt, sl, tmp, tp_, mp, tk, sd, st:
+            falcon_h1.decode_loop(
+                p, cfg, i, po, k, v, bt, c, sl, tmp, tp_, mp, tk, sd,
+                num_steps=8, attn_backend='pallas', max_table_positions=4096,
+                state=st,
+            ),
+        donate_argnums=(4, 5, 13),
+    ).lower(
+        params, v5e((b,), i32), v5e((b,), i32), v5e((b,), i32), pools, pools,
+        v5e((b, 256), i32), v5e((b,), i32), v5e((b,), f32), v5e((b,), f32),
+        v5e((b,), f32), v5e((b,), i32), v5e((b,), jnp.uint32), state,
+    ).compile()
+    _assert_stacked_pool_is_addressed(compiled, pool)
+    _assert_decode_calls_walk(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1536 << 20
+
+
+def test_falcon_h1_chunk_prefill_addresses_the_pool(v5e, falcon_h1_cell):
+    """The ``(512, 4)`` program at full depth: four rows of a 512-token
+    span through ONE scan over the six layers, the SSD spans of state 256
+    in two groups (state gathered and scattered by slot) and the grid over
+    spans at 5 queries a KV head."""
+    falcon_h1, cfg, params, pool, state, _ = falcon_h1_cell
+    i32 = jnp.int32
+    pools = v5e(pool, jnp.bfloat16)
+    compiled = jax.jit(
+        lambda p, ids, pos, k, v, bt, ctx, tails, st, slots:
+            falcon_h1.prefill_paged(
+                p, cfg, ids, pos, k, v, bt, ctx, tails, st, slots,
+                max_table_positions=4096, attn_backend='pallas',
+            ),
+        donate_argnums=(3, 4, 8),
+    ).lower(
+        params, v5e((4, 512), i32), v5e((4, 512), i32), pools, pools,
+        v5e((4, 256), i32), v5e((4,), i32), v5e((4,), i32), state,
+        v5e((4,), i32),
+    ).compile()
+    _assert_stacked_pool_is_addressed(compiled, pool)
+    _assert_span_calls_keep_the_grid(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2048 << 20
