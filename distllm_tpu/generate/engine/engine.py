@@ -1314,15 +1314,24 @@ class LLMEngine:
             self._moe_widths = moe.bank_widths(self.params)
         if self._moe_widths is not None:
             rows = cfg.max_num_seqs
-            forms = {f'decode({rows})': self._moe_form(rows)}
+            tokens = {f'decode({rows})': rows}
             for bucket in self.prefill_buckets:
                 rows = 1
                 while rows <= self._prefill_batch_cap(bucket):
-                    forms[f'prefill({bucket}, {rows})'] = self._moe_form(
-                        bucket * rows
-                    )
+                    tokens[f'prefill({bucket}, {rows})'] = bucket * rows
                     rows *= 2
+            forms = {key: self._moe_form(n) for key, n in tokens.items()}
             self.telemetry['moe_form'] = forms
+            # The grouped programs' kernel tiles (row tile, gate/up and
+            # down column tiles: ``moe.grouped_tiles``, the rule they trace
+            # with), or 'xla' where the backend keeps ``ragged_dot``.
+            self.telemetry['moe_grouped_tiles'] = {
+                key: moe.grouped_tiles(
+                    tokens[key], model_cfg.experts_per_token,
+                    *self._moe_widths[2:],
+                ) or 'xla'
+                for key, form in forms.items() if form == 'grouped'
+            }
         if self.state_pool is not None:
             with self._compile_watcher.phase(
                 'state_allocate', f'slots{cfg.max_num_seqs}', compiles=False,

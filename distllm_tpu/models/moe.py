@@ -10,9 +10,12 @@ The three expert matmuls take one of two forms, chosen by ``expert_form``
 from the call's static shapes alone:
 
 * ``grouped`` (a prefill dispatch: 512-2,048 tokens): the pairs are sorted
-  by expert and run through ``jax.lax.ragged_dot`` (a grouped matmul: each
-  row is multiplied by its own expert's kernel, never by all ``E_held``),
-  then weighted in float32, gathered back and summed over k.
+  by expert and run through a grouped matmul (each row is multiplied by its
+  own expert's kernel, never by all ``E_held``), then weighted in float32,
+  gathered back and summed over k. On a TPU the grouped matmul is the
+  repo's Pallas kernel (``ops/grouped_matmul.py``: one layer's groups, the
+  tiles that hold a held pair, tiles from ``grouped_tiles``); on any other
+  backend ``jax.lax.ragged_dot`` (``grouped_backend``).
 * ``dense`` (a decode window: a handful of rows): every row is multiplied by
   EVERY held expert, one batched ``dot`` a bank, with the gate zero where a
   token did not choose an expert. Multiplying ``T`` rows by a bank costs
@@ -39,6 +42,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from distllm_tpu.ops import grouped_matmul
 
 
 def _bank(w, dtype):
@@ -92,6 +97,27 @@ def expert_form(
     ):
         return 'dense'
     return 'grouped'
+
+
+def grouped_backend() -> str:
+    """What runs the grouped form's three matmuls: ``'pallas'`` (the
+    repo's kernel) on a TPU, ``'xla'`` (``jax.lax.ragged_dot``) elsewhere.
+    ``'interpret'`` is the kernel on the Pallas interpreter: the tests set
+    it, as they set ``'pallas'`` to compile for a described chip."""
+    return 'pallas' if jax.default_backend() == 'tpu' else 'xla'
+
+
+def grouped_tiles(
+    tokens: int, k: int, hidden: int, width: int
+) -> tuple[int, int, int] | None:
+    """The kernel's tiles for a grouped call of ``tokens`` rows that keep
+    ``k`` experts of ``[hidden, width]`` kernels (row tile, gate/up and
+    down column tiles: ``ops.grouped_matmul.grouped_tiles`` over the call's
+    pairs), or None where ``ragged_dot`` runs it. Pure but for the
+    backend."""
+    if grouped_backend() == 'xla':
+        return None
+    return grouped_matmul.grouped_tiles(tokens * k, hidden, width)
 
 
 def bank_widths(params) -> tuple[int, int, int, int] | None:
@@ -190,7 +216,8 @@ def routed_experts(  # distlint: traced
             out = _dense(x, gate, up, down, local, weights, layer)
         else:
             out = _grouped(
-                x, gate, up, down, local, is_held, weights, held, layer
+                x, gate, up, down, local, is_held, weights, held, layer,
+                grouped_tiles(tokens, k, *gate.shape[-2:]),
             )
         rows_counted = (
             jnp.ones((tokens,), bool) if counted is None else counted
@@ -202,32 +229,42 @@ def routed_experts(  # distlint: traced
     return out.astype(dtype), pairs
 
 
-def _grouped(x, gate, up, down, local, is_held, weights, held, layer):
+def _grouped(x, gate, up, down, local, is_held, weights, held, layer, tiles):
     """The held pairs sorted by expert through the grouped matmul: float32
     ``[T, H]``. With ``layer`` the banks are the stack's ``L * E_held``
-    groups and the layer's experts are groups ``layer * E_held`` onward,
-    the other layers' groups empty."""
+    groups and the layer's experts are groups ``layer * E_held`` onward:
+    the kernel (``tiles``) adds the layer to its bank index, ``ragged_dot``
+    (no tiles) takes every group, the other layers' empty."""
     tokens, k = local.shape
     # Pairs sorted by held expert; pairs of absent experts go last,
-    # past the end of the last group, where ragged_dot computes nothing.
+    # past the end of the last group, where the matmul computes nothing.
     group = jnp.where(is_held, local, held).reshape(-1)
     order = jnp.argsort(group, stable=True)
     group_sizes = jnp.bincount(group, length=held + 1)[:held].astype(
         jnp.int32
     )
-    if layer is not None:
+    if layer is not None and tiles is None:
         group_sizes = jax.lax.dynamic_update_slice(
             jnp.zeros((gate.shape[0],), jnp.int32), group_sizes,
             (layer * held,),
         )
-    # Rows in whole sublane tiles of 8: the TPU's grouped matmul is
-    # refused by the compiler for other counts (12, 20, 30 rows over 324
-    # groups). The pad rows lie past the last group: never computed.
-    rows = x[jnp.pad(order // k, (0, -tokens * k % 8))]  # [T*k (+pad), H]
-    hidden = jax.nn.silu(
-        jax.lax.ragged_dot(rows, gate, group_sizes)
-    ) * jax.lax.ragged_dot(rows, up, group_sizes)
-    out = jax.lax.ragged_dot(hidden, down, group_sizes)[: tokens * k]
+    # Rows in whole tiles: the kernel's row tile, or sublane tiles of 8 (the
+    # TPU's ragged_dot is refused by the compiler for other counts: 12, 20,
+    # 30 rows over 324 groups). The pad rows lie past the last group: never
+    # computed.
+    whole = 8 if tiles is None else tiles[0]
+    rows = x[jnp.pad(order // k, (0, -tokens * k % whole))]  # [T*k (+pad), H]
+    if tiles is None:
+        hidden = jax.nn.silu(
+            jax.lax.ragged_dot(rows, gate, group_sizes)
+        ) * jax.lax.ragged_dot(rows, up, group_sizes)
+        out = jax.lax.ragged_dot(hidden, down, group_sizes)
+    else:
+        out = grouped_matmul.expert_matmuls(
+            rows, gate, up, down, group_sizes, 0 if layer is None else layer,
+            tiles=tiles, interpret=grouped_backend() == 'interpret',
+        )
+    out = out[: tokens * k]
     # where, not a product: rows past the last group are not computed.
     out = jnp.where(
         is_held.reshape(-1)[order][:, None],

@@ -955,12 +955,10 @@ def test_ragged_kernel_compiles_at_64_wide_heads(v5e, rows, span):
         _assert_span_calls_keep_the_grid(compiled)
 
 
-@pytest.fixture(scope='module')
-def lfm2_cell(v5e):
-    """The cell's configuration cut to its first 7 layers (two attention
-    layers, every kind of layer: conv under the dense MLP, conv and
-    attention under the experts), the parameters, the pools and the state
-    at the cell's sizes: 19,200 blocks, 96 slots."""
+def _lfm2(v5e, layers=None):
+    """The ``lfm2`` cell's configuration (cut to its first ``layers`` if
+    given): module, config, parameters, the pools' shape, the state and the
+    engine's settings at the cell's sizes, 19,200 blocks and 96 slots."""
     import json
     from pathlib import Path
 
@@ -968,8 +966,9 @@ def lfm2_cell(v5e):
 
     root = Path(__file__).resolve().parents[1]
     hf = json.loads((root / 'benchmarks/configs/lfm2-8b-a1b.json').read_text())
-    hf['layer_types'] = hf['layer_types'][:7]
-    hf['num_hidden_layers'] = 7
+    if layers is not None:
+        hf['layer_types'] = hf['layer_types'][:layers]
+        hf['num_hidden_layers'] = layers
     cfg = lfm2.Lfm2MoeConfig.from_hf_config(hf)
     shapes = jax.eval_shape(
         lambda: lfm2.init_on_device(jax.random.PRNGKey(0), cfg)
@@ -982,6 +981,14 @@ def lfm2_cell(v5e):
         cfg.state_spec(),
     )
     return lfm2, cfg, params, pool, state, engine
+
+
+@pytest.fixture(scope='module')
+def lfm2_cell(v5e):
+    """The cell's configuration cut to its first 7 layers (two attention
+    layers, every kind of layer: conv under the dense MLP, conv and
+    attention under the experts)."""
+    return _lfm2(v5e, 7)
 
 
 @pytest.fixture(scope='module')
@@ -1124,21 +1131,13 @@ def granite_cell(v5e):
     return _granite(v5e, ('mamba', 'mamba', 'attention', 'mamba'))
 
 
-@pytest.mark.parametrize('bucket, rows', [(64, 1), (16, 4)])
-def test_granite_tail_prefill_reads_its_banks_as_they_lie(v5e, bucket, rows):
-    """A chunk tail of the granite cell at FULL depth (the nine Mamba
-    layers under one scan): its 64 rows take the dense form, and the stack
-    of banks stays where it lies. At 121-128 rows the compiler turned the
-    whole ``bf16[9, 36, 4096, 768]`` stacks over outside that scan (three
-    1.9 GB copies: the program did not fit the chip, PR 40), which a
-    three-layer cut does not show; the rule stops at 120 rows for it."""
-    from distllm_tpu.models import moe
-
+def _granite_full_prefill(v5e, bucket, rows):
+    """The granite cell's ``(bucket, rows)`` prefill program at FULL depth
+    (the nine Mamba layers under one scan), compiled; and the stack of
+    banks' shape."""
     granite_hybrid, cfg, params, state = _granite(v5e)
     bank = jax.tree.leaves(params['mamba']['gate'])[0].shape
     assert bank == (9, 36, 4096, 768)
-    assert moe.expert_form(bucket * rows, 10, 36, 72, 4096, 768) == 'dense'
-    assert moe.expert_form(128, 10, 36, 72, 4096, 768) == 'grouped'
     i32 = jnp.int32
     pools = v5e((1, 8192, 16, _NKV * _HD), jnp.bfloat16)
     compiled = jax.jit(
@@ -1153,8 +1152,93 @@ def test_granite_tail_prefill_reads_its_banks_as_they_lie(v5e, bucket, rows):
         pools, v5e((rows, 256), i32), v5e((rows,), i32), v5e((rows,), i32),
         state, v5e((rows,), i32),
     ).compile()
+    return compiled, bank
+
+
+@pytest.mark.parametrize('bucket, rows', [(64, 1), (16, 4)])
+def test_granite_tail_prefill_reads_its_banks_as_they_lie(v5e, bucket, rows):
+    """A chunk tail of the granite cell at FULL depth (the nine Mamba
+    layers under one scan): its 64 rows take the dense form, and the stack
+    of banks stays where it lies. At 121-128 rows the compiler turned the
+    whole ``bf16[9, 36, 4096, 768]`` stacks over outside that scan (three
+    1.9 GB copies: the program did not fit the chip, PR 40), which a
+    three-layer cut does not show; the rule stops at 120 rows for it."""
+    from distllm_tpu.models import moe
+
+    assert moe.expert_form(bucket * rows, 10, 36, 72, 4096, 768) == 'dense'
+    assert moe.expert_form(128, 10, 36, 72, 4096, 768) == 'grouped'
+    compiled, bank = _granite_full_prefill(v5e, bucket, rows)
     _assert_banks_are_streamed_by_a_dot(compiled, [bank[1:], bank])
     assert compiled.memory_analysis().temp_size_in_bytes < 512 << 20
+
+
+# The temporaries of the parent's programs (PR 41's tree, ``ragged_dot``
+# three times a layer), compiled here for the described v5e at full depth.
+_GRANITE_PREFILL_TEMP_AT_PR41 = {(512, 4): 1337857536, (128, 1): 111249408}
+
+
+@pytest.mark.parametrize('bucket, rows', sorted(_GRANITE_PREFILL_TEMP_AT_PR41))
+def test_granite_grouped_prefill_reads_its_banks_as_they_lie(
+    v5e, bucket, rows, monkeypatch
+):
+    """The grouped form over the repo's kernel (PR 42) at FULL depth, the
+    cell's largest prefill program and the ``(128, 1)`` tail behind the
+    dense form's fence: the kernel takes the stack of banks whole and adds
+    the layer in its index map, so no ``copy`` in the program has a result
+    the size of a bank or of the stack (the lesson of PR 40's fence: a cut
+    to a few layers does not show what XLA does to a stack under the full
+    scan), and the temporaries stay within 64 MB of the parent's."""
+    import re
+
+    from distllm_tpu.models import moe
+
+    monkeypatch.setattr(moe, 'grouped_backend', lambda: 'pallas')
+    assert moe.expert_form(bucket * rows, 10, 36, 72, 4096, 768) == 'grouped'
+    compiled, bank = _granite_full_prefill(v5e, bucket, rows)
+    text = compiled.as_text()
+    assert 'ragged-dot' not in text
+    copies = [
+        line.strip()[:120] for line in text.splitlines()
+        if (m := re.match(r'^\s*(?:ROOT )?%\S+ = (\S+) copy\(', line))
+        and any(_holds(m.group(1), shape) for shape in (bank[1:], bank))
+    ]
+    assert not copies, copies
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert abs(temp - _GRANITE_PREFILL_TEMP_AT_PR41[bucket, rows]) < 64 << 20
+
+
+def test_lfm2_prefill_traces_the_kernel_once(v5e, monkeypatch):
+    """``lfm2`` unrolls its 22 expert layers: the ``(512, 4)`` program's
+    text holds ONE ``expert_matmuls`` function (the jitted op, its tiles
+    static and the layer an operand) under the 22 calls of its two kinds
+    of sparse layer, and so one body of each of its two kernel calls, not
+    44: what the program pays in set-up is a shape's, not a layer's (PR
+    42; PR 37 was refused for 13.7 s of ``setup_s``)."""
+    import re
+
+    from distllm_tpu.models import moe
+
+    lfm2, cfg, params, pool, state, _ = _lfm2(v5e)  # all 24 layers
+    assert jax.tree.leaves(params['sparse']['gate'])[0].shape == (
+        22, 16, 2048, 1792
+    )
+    monkeypatch.setattr(moe, 'grouped_backend', lambda: 'pallas')
+    i32 = jnp.int32
+    pools = v5e(pool, jnp.bfloat16)
+    text = jax.jit(
+        lambda p, ids, pos, k, v, bt, ctx, tails, st, slots: lfm2.prefill_paged(
+            p, cfg, ids, pos, k, v, bt, ctx, tails, st, slots,
+            max_table_positions=8448, attn_backend='pallas',
+        ),
+    ).lower(
+        params, v5e((4, 512), i32), v5e((4, 512), i32), pools, pools,
+        v5e((4, 528), i32), v5e((4,), i32), v5e((4,), i32), state,
+        v5e((4,), i32),
+    ).as_text()
+    assert len(re.findall(r'call @lfm2_\w+_sparse_layer\(', text)) == 22
+    assert text.count('func.func private @expert_matmuls(') == 1
+    assert text.count('kernel_name = "grouped_matmul"') == 2
+    assert 'ragged_dot' not in text
 
 
 def _chunk_prefill_text(v5e, family, request) -> str:
@@ -1198,51 +1282,66 @@ def _chunk_prefill_text(v5e, family, request) -> str:
 def test_chunk_prefill_keeps_the_grouped_matmul(
     v5e, family, request, monkeypatch
 ):
-    """Every ``(512, 4)`` prefill program stays on the grouped matmul:
-    the text is the one the program lowers to with the rule taken out and
-    every call sent to the grouped form."""
+    """Every ``(512, 4)`` prefill program stays on the grouped form: the
+    text is the one the program lowers to with the rule taken out and
+    every call sent to the grouped form. On the chip (``grouped_backend``
+    says so there; the test says it here) the grouped matmul is the repo's
+    kernel (PR 42): its call is in the text and no ``ragged_dot``."""
     from distllm_tpu.models import moe
 
+    monkeypatch.setattr(moe, 'grouped_backend', lambda: 'pallas')
     texts = []
     # One call site: a Mosaic kernel's serialized body carries the lines
     # of the frames it was traced under.
     for rule in (moe.expert_form, lambda *shape: 'grouped'):
         monkeypatch.setattr(moe, 'expert_form', rule)
         texts.append(_chunk_prefill_text(v5e, family, request))
-    assert 'ragged_dot' in texts[0]
+    assert 'kernel_name = "grouped_matmul"' in texts[0]
+    assert 'ragged_dot' not in texts[0]
     assert texts[0] == texts[1]
 
 
-# The sha256 (first 16 digits) of what ``routed_experts`` lowered to at PR
-# 39 (the parent of the dense form) for a 2,048-token call at each
-# family's widths and arguments, the layer a traced index into the stack.
-_GROUPED_AT_PR39 = {
-    'granite': ((10, 36, 72, 4096, 768, 9), {}, '96c0872bb46c20de'),
+# The sha256 (first 16 digits) of what ``routed_experts`` lowers to since PR
+# 42 (the grouped matmul is the repo's kernel) for a 2,048-token call at
+# each family's widths and arguments, the layer a traced index into the
+# stack. PR 39's values (``ragged_dot`` three times, the parent of the dense
+# form) stood here until PR 42 moved them.
+_GROUPED_AT_PR42 = {
+    'granite': ((10, 36, 72, 4096, 768, 9), {}, '28671acefc2ad1af'),
     'laguna': ((8, 64, 256, 2048, 512, 19), {'routed_scale': 2.5},
-               '2ef872e1be158437'),
+               '30d162420d4c5fc2'),
     'kanana': ((6, 32, 128, 2048, 768, 23),
                {'scoring': 'sigmoid', 'routed_scale': 2.448, 'bias': True},
-               '6166093a8262a13f'),
+               '502cbb8a80b7c305'),
     'lfm2': ((4, 16, 32, 2048, 1792, 22),
              {'scoring': 'sigmoid', 'norm_eps': 1e-6, 'bias': True},
-             '3e3748a4953c8b30'),
+             '6e9aaef7e4eb5914'),
 }
 
 
-@pytest.mark.parametrize('family', sorted(_GROUPED_AT_PR39))
-def test_grouped_form_lowers_to_the_parents_text(v5e, family):
-    """The grouped form is the parent's to the byte: a prefill program's
-    expert layer lowers to the text it had before the dense form came (the
-    compile cache's key, and what XLA compiles, follow from it). A change
-    of jax may move all four at once; a change of one is a change to the
-    grouped path."""
+@pytest.mark.parametrize('family', sorted(_GROUPED_AT_PR42))
+def test_grouped_form_lowers_to_the_parents_text(v5e, family, monkeypatch):
+    """The grouped form is pinned to the byte: a prefill program's expert
+    layer lowers to the text it had at PR 42 (the compile cache's key, and
+    what XLA compiles, follow from it). PR 42 moved all four on purpose:
+    the three ``ragged_dot`` calls of PR 39's text became the kernel's two
+    calls, the sort, the gathers and the float32 combine around them as
+    they were. A Mosaic kernel's serialized body carries the checkout's
+    path and the lines of the frames it was traced under, so the two
+    bodies are left out of the hash (``tests/test_grouped_matmul.py`` holds
+    what they compute): their operands, shapes and the call's other
+    fields are in it. A change of jax may move all four at once; a change
+    of one is a change to the grouped path."""
+    import re
+
     import hashlib
 
     from distllm_tpu.models import moe
 
     (k, held, routed, hidden, width, layers), kw, want = (
-        _GROUPED_AT_PR39[family]
+        _GROUPED_AT_PR42[family]
     )
+    monkeypatch.setattr(moe, 'grouped_backend', lambda: 'pallas')
     kw = dict(kw)
     biased = kw.pop('bias', False)
     bf, f32 = jnp.bfloat16, jnp.float32
@@ -1261,6 +1360,8 @@ def test_grouped_form_lowers_to_the_parents_text(v5e, family):
         v5e((layers, held, width, hidden), bf), v5e((routed,), f32),
         v5e((2048,), jnp.bool_), v5e((), jnp.int32),
     ).as_text()
+    text, bodies = re.subn(r'\\22body\\22: \\22[^\\]*\\22', 'body', text)
+    assert bodies == 2
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == want
 
 

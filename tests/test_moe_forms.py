@@ -227,7 +227,7 @@ def test_rule_ignores_the_widths():
 
 # ---- the engine's account ----
 
-def test_engine_says_which_form_each_program_took():
+def test_engine_says_which_form_each_program_took(monkeypatch):
     from lfm2_toy import make_engine, prompt
 
     _, _, engine = make_engine()
@@ -245,6 +245,13 @@ def test_engine_says_which_form_each_program_took():
         )
         for key in forms
     }
+    # the grouped programs and no other, each with what runs its matmuls:
+    # ``ragged_dot`` on the tests' backend, the kernel's tiles on a TPU
+    tiles = engine.telemetry['moe_grouped_tiles']
+    assert set(tiles) == {k for k, form in forms.items() if form == 'grouped'}
+    assert tiles['prefill(96, 4)'] == 'xla'
+    monkeypatch.setattr(moe, 'grouped_backend', lambda: 'pallas')
+    assert moe.grouped_tiles(96 * 4, 3, 64, 24) == (128, 24, 64)
     before = engine.flight.total_recorded
     engine.generate_ids(
         [prompt(np.random.default_rng(1), 20)],
@@ -269,6 +276,7 @@ def test_engine_without_routed_experts_says_nothing():
     engine = build_engine(warm=False)
     try:
         assert 'moe_form' not in engine.telemetry
+        assert 'moe_grouped_tiles' not in engine.telemetry
         records = engine.flight.snapshot()[
             before - engine.flight.total_recorded:
         ]
