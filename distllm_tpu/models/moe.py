@@ -11,8 +11,9 @@ from the call's static shapes alone:
 
 * ``grouped`` (a prefill dispatch: 512-2,048 tokens): the pairs are sorted
   by expert and run through a grouped matmul (each row is multiplied by its
-  own expert's kernel, never by all ``E_held``), then weighted in float32,
-  gathered back and summed over k. On a TPU the grouped matmul is the
+  own expert's kernel, never by all ``E_held``), then a token's k held
+  pairs are gathered back in the rows' dtype, weighted in float32 and
+  summed in one pass (``combine``). On a TPU the grouped matmul is the
   repo's Pallas kernel (``ops/grouped_matmul.py``: one layer's groups, the
   tiles that hold a held pair, tiles from ``grouped_tiles``); on any other
   backend ``jax.lax.ragged_dot`` (``grouped_backend``).
@@ -230,11 +231,12 @@ def routed_experts(  # distlint: traced
 
 
 def _grouped(x, gate, up, down, local, is_held, weights, held, layer, tiles):
-    """The held pairs sorted by expert through the grouped matmul: float32
-    ``[T, H]``. With ``layer`` the banks are the stack's ``L * E_held``
-    groups and the layer's experts are groups ``layer * E_held`` onward:
-    the kernel (``tiles``) adds the layer to its bank index, ``ragged_dot``
-    (no tiles) takes every group, the other layers' empty."""
+    """The held pairs sorted by expert through the grouped matmul, each
+    times its gate and a token's k added up (``combine``): float32 ``[T,
+    H]``. With ``layer`` the banks are the stack's ``L * E_held`` groups and
+    the layer's experts are groups ``layer * E_held`` onward: the kernel
+    (``tiles``) adds the layer to its bank index, ``ragged_dot`` (no tiles)
+    takes every group, the other layers' empty."""
     tokens, k = local.shape
     # Pairs sorted by held expert; pairs of absent experts go last,
     # past the end of the last group, where the matmul computes nothing.
@@ -264,15 +266,26 @@ def _grouped(x, gate, up, down, local, is_held, weights, held, layer, tiles):
             rows, gate, up, down, group_sizes, 0 if layer is None else layer,
             tiles=tiles, interpret=grouped_backend() == 'interpret',
         )
-    out = out[: tokens * k]
+    # A pair's row of the sorted order, -1 where its expert is held
+    # elsewhere: that row is never computed, and never read.
+    place = jnp.where(is_held, jnp.argsort(order).reshape(tokens, k), -1)
+    return combine(out, place, weights)
+
+
+def combine(rows, place, weights):
+    """``sum_j weights[t, j] * rows[place[t, j]]`` over a token's held pairs
+    (``place`` -1: held elsewhere), float32 ``[T, H]``. ONE pass behind the
+    matmuls: the k rows of a token are gathered in the rows' dtype as ``[T,
+    k, H]``, and the gate, the ``where`` and the sum over k fuse behind the
+    gather, so that no ``[pairs, H]`` array is made in float32 (weighting
+    in sorted order first costs three such passes: ``PERF.md`` section 6,
+    PR 43)."""
+    pairs = rows[jnp.maximum(place, 0)].astype(jnp.float32)  # [T, k, H]
     # where, not a product: rows past the last group are not computed.
-    out = jnp.where(
-        is_held.reshape(-1)[order][:, None],
-        out.astype(jnp.float32) * weights.reshape(-1)[order][:, None],
-        0.0,
+    return jnp.sum(
+        jnp.where((place >= 0)[..., None], pairs * weights[..., None], 0.0),
+        axis=1,
     )
-    # Back to token order, then the k pairs of a token add up.
-    return out[jnp.argsort(order)].reshape(tokens, k, -1).sum(axis=1)
 
 
 def _dense(x, gate, up, down, local, weights, layer):

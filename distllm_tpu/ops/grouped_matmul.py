@@ -26,8 +26,9 @@ Accumulation is float32 and every result is rounded once to the rows'
 dtype at the store, where ``ragged_dot`` rounded.
 
 Rows past the last group, and the rows of a straddled tile no group owns,
-are never written: the caller discards them (``moe._grouped`` selects by
-``is_held``).
+are never written, and never read: the caller gathers the rows of held
+pairs alone (``moe.combine``; a pair held elsewhere reads row 0 and is
+discarded by a ``where``).
 """
 
 from __future__ import annotations

@@ -130,6 +130,19 @@ def paged_logits(cfg, params, tokens, n_prompt, *, chunk=8, backend='xla',
         row = window_blocks.table_row(0, np.zeros((width,), np.int32))
         return jnp.asarray(full_row[None]), jnp.asarray(row[None])
 
+    # One program a kind of dispatch, as the engine has (called bare, the
+    # model makes and compiles its jitted layers anew a call: ``_once_a_kind``).
+    prefill_chunk = jax.jit(
+        lambda params, *arrays: laguna.prefill_paged(
+            params, cfg, *arrays, max_table_positions=total,
+            attn_backend=backend,
+        )
+    )
+    decode_step = jax.jit(
+        lambda params, *arrays: laguna._decode_core(
+            params, cfg, *arrays, backend
+        )
+    )
     out = []
     for start in range(0, n_prompt, chunk):
         ntok = min(chunk, n_prompt - start)
@@ -137,20 +150,18 @@ def paged_logits(cfg, params, tokens, n_prompt, *, chunk=8, backend='xla',
         ids = np.zeros((1, chunk), np.int32)
         ids[0, :ntok] = tokens[start:start + ntok]
         positions = np.minimum(start + np.arange(chunk), total - 1)[None]
-        last, k, v = laguna.prefill_paged(
-            params, cfg, jnp.asarray(ids), jnp.asarray(positions), k, v,
+        last, k, v = prefill_chunk(
+            params, jnp.asarray(ids), jnp.asarray(positions), k, v,
             tables(), jnp.asarray([start + ntok]), jnp.asarray([ntok]),
-            max_table_positions=total, attn_backend=backend,
         )
         window_blocks.trim_behind(0, start + ntok)
     out.append(np.asarray(last[0]))
     rope = laguna._rope_tables(cfg, total)
     for pos in range(n_prompt, total):
         window_blocks.cover(0, pos, pos + 1)
-        step, k, v, _ = laguna._decode_core(
-            params, cfg, jnp.asarray([tokens[pos]]), jnp.asarray([pos]), k, v,
+        step, k, v, _ = decode_step(
+            params, jnp.asarray([tokens[pos]]), jnp.asarray([pos]), k, v,
             tables(), jnp.asarray([pos + 1]), jnp.asarray([True]), rope,
-            backend,
         )
         out.append(np.asarray(step[0]))
     return np.stack(out), window_blocks

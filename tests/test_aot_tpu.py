@@ -1172,12 +1172,15 @@ def test_granite_tail_prefill_reads_its_banks_as_they_lie(v5e, bucket, rows):
     assert compiled.memory_analysis().temp_size_in_bytes < 512 << 20
 
 
-# The temporaries of the parent's programs (PR 41's tree, ``ragged_dot``
-# three times a layer), compiled here for the described v5e at full depth.
-_GRANITE_PREFILL_TEMP_AT_PR41 = {(512, 4): 1337857536, (128, 1): 111249408}
+# The temporaries of this tree's programs, compiled here for the described
+# v5e at full depth. PR 43 re-pinned them, on purpose: with no float32
+# ``[pairs, hidden]`` array behind the kernel they fell from PR 41's
+# 1,337,857,536 and 111,249,408 bytes (``ragged_dot`` three times a layer;
+# PR 42 stayed within 64 MB of those) by 168 MB and 45 MB.
+_GRANITE_PREFILL_TEMP_AT_PR43 = {(512, 4): 1169762816, (128, 1): 66647552}
 
 
-@pytest.mark.parametrize('bucket, rows', sorted(_GRANITE_PREFILL_TEMP_AT_PR41))
+@pytest.mark.parametrize('bucket, rows', sorted(_GRANITE_PREFILL_TEMP_AT_PR43))
 def test_granite_grouped_prefill_reads_its_banks_as_they_lie(
     v5e, bucket, rows, monkeypatch
 ):
@@ -1187,7 +1190,8 @@ def test_granite_grouped_prefill_reads_its_banks_as_they_lie(
     the layer in its index map, so no ``copy`` in the program has a result
     the size of a bank or of the stack (the lesson of PR 40's fence: a cut
     to a few layers does not show what XLA does to a stack under the full
-    scan), and the temporaries stay within 64 MB of the parent's."""
+    scan), and the temporaries stay within 64 MB of the pinned ones, either
+    way: growth is what took this cell out of the chip's memory at PR 40."""
     import re
 
     from distllm_tpu.models import moe
@@ -1204,7 +1208,7 @@ def test_granite_grouped_prefill_reads_its_banks_as_they_lie(
     ]
     assert not copies, copies
     temp = compiled.memory_analysis().temp_size_in_bytes
-    assert abs(temp - _GRANITE_PREFILL_TEMP_AT_PR41[bucket, rows]) < 64 << 20
+    assert abs(temp - _GRANITE_PREFILL_TEMP_AT_PR43[bucket, rows]) < 64 << 20
 
 
 def test_lfm2_prefill_traces_the_kernel_once(v5e, monkeypatch):
@@ -1302,36 +1306,42 @@ def test_chunk_prefill_keeps_the_grouped_matmul(
 
 
 # The sha256 (first 16 digits) of what ``routed_experts`` lowers to since PR
-# 42 (the grouped matmul is the repo's kernel) for a 2,048-token call at
-# each family's widths and arguments, the layer a traced index into the
-# stack. PR 39's values (``ragged_dot`` three times, the parent of the dense
-# form) stood here until PR 42 moved them.
-_GROUPED_AT_PR42 = {
-    'granite': ((10, 36, 72, 4096, 768, 9), {}, '28671acefc2ad1af'),
+# 43 (the way back is one pass: a token's k rows gathered in bfloat16, the
+# gate, the ``where`` and the sum over k behind the gather) for a
+# 2,048-token call at each family's widths and arguments, the layer a
+# traced index into the stack. PR 42's values (the kernel's two calls in
+# front of a float32 product in sorted order, its gather back and the sum)
+# stood here until PR 43 moved them, as PR 39's (``ragged_dot`` three
+# times) had until PR 42.
+_GROUPED_AT_PR43 = {
+    'granite': ((10, 36, 72, 4096, 768, 9), {}, '472be48ae7bb2c0d'),
     'laguna': ((8, 64, 256, 2048, 512, 19), {'routed_scale': 2.5},
-               '30d162420d4c5fc2'),
+               '0c6026f94373d7c2'),
     'kanana': ((6, 32, 128, 2048, 768, 23),
                {'scoring': 'sigmoid', 'routed_scale': 2.448, 'bias': True},
-               '502cbb8a80b7c305'),
+               'a06b6ba9d9445b61'),
     'lfm2': ((4, 16, 32, 2048, 1792, 22),
              {'scoring': 'sigmoid', 'norm_eps': 1e-6, 'bias': True},
-             '6e9aaef7e4eb5914'),
+             '165852da581852f1'),
 }
 
 
-@pytest.mark.parametrize('family', sorted(_GROUPED_AT_PR42))
+@pytest.mark.parametrize('family', sorted(_GROUPED_AT_PR43))
 def test_grouped_form_lowers_to_the_parents_text(v5e, family, monkeypatch):
     """The grouped form is pinned to the byte: a prefill program's expert
-    layer lowers to the text it had at PR 42 (the compile cache's key, and
-    what XLA compiles, follow from it). PR 42 moved all four on purpose:
-    the three ``ragged_dot`` calls of PR 39's text became the kernel's two
-    calls, the sort, the gathers and the float32 combine around them as
-    they were. A Mosaic kernel's serialized body carries the checkout's
-    path and the lines of the frames it was traced under, so the two
-    bodies are left out of the hash (``tests/test_grouped_matmul.py`` holds
-    what they compute): their operands, shapes and the call's other
-    fields are in it. A change of jax may move all four at once; a change
-    of one is a change to the grouped path."""
+    layer lowers to the text it had at PR 43 (the compile cache's key, and
+    what XLA compiles, follow from it). PR 43 moved all four on purpose:
+    the float32 product in sorted order, its gather back to token order and
+    the sum over k behind the kernel's two calls became one gather of a
+    token's k rows in the rows' dtype with the gate and the sum behind it;
+    no float32 tensor of ``[pairs, hidden]`` is left (the row gather in
+    front of the kernel, bfloat16, is PR 42's still). A Mosaic kernel's
+    serialized body carries the checkout's path and the lines of the
+    frames it was traced under, so the two bodies are left out of the
+    hash (``tests/test_grouped_matmul.py`` holds what
+    they compute): their operands, shapes and the call's other fields are
+    in it. A change of jax may move all four at once; a change of one is a
+    change to the grouped path."""
     import re
 
     import hashlib
@@ -1339,7 +1349,7 @@ def test_grouped_form_lowers_to_the_parents_text(v5e, family, monkeypatch):
     from distllm_tpu.models import moe
 
     (k, held, routed, hidden, width, layers), kw, want = (
-        _GROUPED_AT_PR42[family]
+        _GROUPED_AT_PR43[family]
     )
     monkeypatch.setattr(moe, 'grouped_backend', lambda: 'pallas')
     kw = dict(kw)
@@ -1362,6 +1372,8 @@ def test_grouped_form_lowers_to_the_parents_text(v5e, family, monkeypatch):
     ).as_text()
     text, bodies = re.subn(r'\\22body\\22: \\22[^\\]*\\22', 'body', text)
     assert bodies == 2
+    assert f'tensor<{2048 * k}x{hidden}xf32>' not in text
+    assert f'tensor<2048x{k}x{hidden}xbf16>' in text
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == want
 
 

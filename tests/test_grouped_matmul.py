@@ -185,6 +185,84 @@ def test_routed_experts_on_the_kernel(monkeypatch, dtype, tokens, k, kw, layer):
     assert np.abs(want).max() > 0.01
 
 
+# ---- the way back: one gather of a token's k rows, gated and summed ----
+
+# (id, tokens, k, the share of pairs held)
+COMBINE_CASES = [
+    ('k_of_2', 32, 2, 0.5),
+    ('k_of_3', 24, 3, 0.5),
+    ('k_of_10', 16, 10, 0.5),
+    ('a_quarter_held', 40, 6, 0.25),
+    ('every_pair_held', 16, 4, 1.0),
+    ('rows_that_need_the_pad', 13, 3, 0.6),
+]
+
+
+@pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
+@pytest.mark.parametrize(
+    'tokens, k, share', [c[1:] for c in COMBINE_CASES],
+    ids=[c[0] for c in COMBINE_CASES],
+)
+def test_combine_is_the_three_passes(dtype, tokens, k, share):
+    """``moe.combine`` (PR 43: the k rows of a token gathered in the rows'
+    dtype, gated and summed in float32 behind the gather) against the three
+    float32 ``[pairs, H]`` passes it replaced (PR 42's lines: the weighted
+    product in sorted order, its gather back to token order, the sum over
+    k), to the last bit: the same products in the same order. A token with
+    no held pair reads exact zeros whatever the rows past the last group
+    hold (NaN here, and in the pad rows); one whose k pairs are all held is
+    in every case."""
+    dtype = jnp.dtype(dtype)
+    rng = np.random.default_rng(int(tokens * k + 100 * share))
+    pairs = tokens * k
+    held = rng.random((tokens, k)) < share
+    held[1], held[2] = False, True
+    # the sorted order: held pairs first (as if by expert), the rest behind
+    flat = held.reshape(-1)
+    order = np.concatenate([
+        rng.permutation(np.nonzero(flat)[0]), np.nonzero(~flat)[0]
+    ])
+    rows = rng.normal(size=(pairs + 5, H)).astype(np.float32)
+    rows[flat.sum():] = np.nan  # never computed
+    rows = jnp.asarray(rows, dtype)
+    weights = jnp.asarray(rng.random((tokens, k)), jnp.float32)
+    place = jnp.where(
+        jnp.asarray(held), jnp.argsort(jnp.asarray(order)).reshape(tokens, k),
+        -1,
+    )
+    got = moe.combine(rows, place, weights)
+    parent = jnp.where(
+        jnp.asarray(flat)[order][:, None],
+        rows[:pairs].astype(jnp.float32)
+        * weights.reshape(-1)[order][:, None],
+        0.0,
+    )[jnp.argsort(jnp.asarray(order))].reshape(tokens, k, -1).sum(axis=1)
+    assert got.shape == (tokens, H) and got.dtype == jnp.float32
+    got, parent = np.asarray(got), np.asarray(parent)
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got[1], 0.0)
+    assert np.abs(got[2]).min() > 0.0
+    np.testing.assert_array_equal(got, parent)
+
+
+def test_grouped_form_makes_no_float32_pairs(monkeypatch):
+    """The lowered grouped form holds no float32 tensor of ``[pairs, H]``
+    on either backend (the ``ragged_dot`` one here; the kernel's at the
+    cells' widths in ``tests/test_aot_tpu.py``): what is float32 is ``[T,
+    k, H]`` inside the fusion behind the gather, and ``[T, H]``."""
+    data = _inputs(jnp.bfloat16, 160)
+    monkeypatch.setattr(moe, 'expert_form', lambda *shape: 'grouped')
+    text = jax.jit(
+        lambda x: moe.routed_experts(
+            x, data['router'], data['gate'][0], data['up'][0],
+            data['down'][0], 3,
+        )
+    ).lower(data['x']).as_text()
+    assert f'tensor<480x{H}xbf16>' in text  # the rows, in sorted order
+    assert f'tensor<480x{H}xf32>' not in text
+    assert f'tensor<160x3x{H}xf32>' in text
+
+
 def test_other_backends_keep_ragged_dot(monkeypatch):
     """The tests' backend is the CPU: no tiles, and no kernel call in the
     lowered text."""
