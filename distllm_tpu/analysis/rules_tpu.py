@@ -291,6 +291,22 @@ def _isinstance_arg_names(expr: ast.AST) -> set[ast.AST]:
     return names
 
 
+def _identity_test_names(expr: ast.AST) -> set[ast.AST]:
+    """``ast.Name`` operands of an ``is`` / ``is not`` comparison: an
+    identity test (``q_lens is None``) reads which Python object a name is
+    bound to at trace time and never concretizes a tracer."""
+    names: set[ast.AST] = set()
+    for node in ast.walk(expr):
+        if isinstance(node, ast.Compare) and all(
+            isinstance(op, (ast.Is, ast.IsNot)) for op in node.ops
+        ):
+            names.update(
+                operand for operand in (node.left, *node.comparators)
+                if isinstance(operand, ast.Name)
+            )
+    return names
+
+
 def _jnp_derived_names(fn: ast.AST) -> set[str]:
     """Names bound (directly or transitively) from ``jnp.*``/``lax.*``/
     ``jax.*`` expressions inside ``fn``. Parameters are deliberately NOT
@@ -323,7 +339,10 @@ def _test_uses_traced_value(test: ast.AST, derived: set[str]) -> bool:
     a direct ``jnp.*``/``lax.*`` call, or a tracked name used as a value
     (not merely via a static attribute like ``.shape`` or an
     ``isinstance`` type dispatch)."""
-    static_bases = _static_attr_leaves(test) | _isinstance_arg_names(test)
+    static_bases = (
+        _static_attr_leaves(test) | _isinstance_arg_names(test)
+        | _identity_test_names(test)
+    )
     for node in ast.walk(test):
         if isinstance(node, ast.Call):
             root = _dotted_root(node.func)
@@ -492,11 +511,21 @@ class HostSyncInHotPathRule(Rule):
             'decode_loop',
             'prefill_paged',
         ),
+        # What every served family's window and layer walk go through (the
+        # step scan that was ``mistral.decode_loop``'s, since PR 44): traced
+        # into every decode dispatch of every family.
+        'distllm_tpu/models/common.py': (
+            'decode_window',
+            'layer_at',
+            'finish_layer',
+            'last_token',
+        ),
         # The quantize-at-write / rescale-on-append path (docs/serving.md
         # "Quantized KV cache"): these run inside every traced serving
         # dispatch that touches an int8 pool, so a stray sync here
         # serializes every window — same contract as the engine loop.
         'distllm_tpu/ops/paged_attention.py': (
+            'decode_attention',
             'quantize_kv_rows',
             '_rescale_int8_blocks',
             '_gather_kv_blocks',

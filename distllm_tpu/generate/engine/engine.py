@@ -307,16 +307,6 @@ class EngineConfig(BaseConfig):
     # value threshold and sorts nothing at any K (ops/sampling.py); a cap
     # adds a second search to the steps of the rows that have one.
     sampling_top_window: int = 0
-    # Unroll the layer scan inside decode dispatches. Decode is weight-
-    # bandwidth bound and the rolled scan's dynamic-slice of stacked MLP
-    # kernels is materialized by XLA (~3x HBM traffic on most of the
-    # weights — read off the compiled HLO on older code, not re-measured);
-    # unrolling folds the slices into the matmuls. The unrolled window compiles
-    # slower than the rolled one (about half a minute per 7B decode shape
-    # compile-only for a described v5e, scripts/aot_preflight.py), which
-    # the persistent compilation cache (utils.enable_compile_cache) pays
-    # once. Prefill keeps the rolled scan either way.
-    decode_layer_unroll: bool = True
 
     @field_validator(
         'sampling_top_window', 'prefill_chunk_tokens',
@@ -1133,7 +1123,6 @@ class LLMEngine:
                 temp, top_p, min_p, top_k, seeds, num_steps=num_steps,
                 attn_backend=attn_backend, max_table_positions=max_tables,
                 sampling_top_window=cfg.sampling_top_window,
-                layer_unroll=cfg.decode_layer_unroll,
                 **({'state': state[0]} if state else {}),
             )
 
@@ -1160,7 +1149,6 @@ class LLMEngine:
                 c_seeds, num_steps=num_steps,
                 attn_backend=attn_backend, max_table_positions=max_tables,
                 sampling_top_window=cfg.sampling_top_window,
-                layer_unroll=cfg.decode_layer_unroll,
             )
 
         self._mixed_fn = mixed_fn

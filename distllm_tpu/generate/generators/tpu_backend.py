@@ -69,11 +69,6 @@ class TpuGeneratorConfig(BaseConfig):
         'than the K-th largest logit, before top-p (0 = no cap). Not a '
         'speed setting: the sampler sorts nothing at any K.',
     )
-    decode_layer_unroll: bool | None = Field(
-        default=None,
-        description='Unroll the decode layer scan (folds stacked-weight '
-        'slices into the matmuls; longer one-time compile).',
-    )
     enable_prefix_cache: bool | None = Field(
         default=None,
         description='Automatic prefix caching: reuse KV blocks across '
@@ -276,7 +271,6 @@ class TpuGenerator:
                     for knob, value in (
                         ('decode_steps', config.decode_steps),
                         ('sampling_top_window', config.sampling_top_window),
-                        ('decode_layer_unroll', config.decode_layer_unroll),
                         ('enable_prefix_cache', config.enable_prefix_cache),
                         ('prefill_chunk_tokens', config.prefill_chunk_tokens),
                         (

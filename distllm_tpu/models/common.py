@@ -1,11 +1,21 @@
-"""Shared functional layers for the pure-JAX models.
+"""What the pure-JAX models share.
 
 Everything is a pure function over explicit parameter pytrees; per-layer
-weights are stacked on a leading axis and traversed with ``lax.scan`` so a
-48-layer model compiles one layer body instead of 48 (compile-time and
-HBM-code-size win on TPU). Attention uses ``jax.nn.dot_product_attention``
-(XLA fuses to flash-attention-style kernels on TPU); custom Pallas kernels
-live in ``distllm_tpu.ops`` and slot in via the ``attn_impl`` argument.
+weights are stacked on a leading axis.
+
+- The encoders' and decoders' layers: norms, ``dense`` (quantized kernels
+  dequantize at the point of use), activations, ``sdpa``, RoPE tables and
+  rotation, GQA's ``repeat_kv``, masks.
+- What a served decoder declares: ``PagedGroup`` and ``CacheSpec``.
+- What a served decoder does NOT write itself (docs/serving.md, "What a
+  family writes"): ``decode_window``, the decode window's step scan over a
+  family's ``_decode_core``; ``once_a_kind``, ``layer_at``, ``swiglu``,
+  ``dense_mlp``, ``finish_layer`` and ``last_token`` for a walk over
+  stacked layers unrolled; ``seeded_tree`` and ``tree_specs``, random
+  weights made on the device from a family's table of leaf shapes.
+
+Nothing here may test a family's name or ``model_type``: a helper lives
+here only where the families' copies were equal after renaming.
 """
 
 from __future__ import annotations
@@ -16,6 +26,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 
 @dataclass(frozen=True)
@@ -406,3 +417,207 @@ def causal_mask(q_len: int, kv_len: int, offset: int = 0) -> jnp.ndarray:
     q_pos = jnp.arange(q_len)[:, None] + offset
     kv_pos = jnp.arange(kv_len)[None, :]
     return kv_pos <= q_pos
+
+
+# ------------------------------------------------ a walk over stacked layers
+def layer_at(tree, i, skip=(), dynamic: bool | None = None):  # distlint: traced
+    """Layer ``i`` of a stacked tree, without the leaves ``skip`` names (a
+    sparse tree's expert banks: a slice of those would be a copy of the
+    layer's whole bank). A static ``i`` is a static slice, which folds into
+    its matmul, a traced one a dynamic slice; ``dynamic=True`` takes the
+    dynamic slice for either, so that a scan's body which a run of one
+    layer calls with a static index lowers to one text."""
+    if dynamic is None:
+        dynamic = not isinstance(i, int)
+    if dynamic:
+        pick = lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)  # noqa: E731
+    else:
+        pick = lambda a: a[i]  # noqa: E731
+    return jax.tree.map(
+        pick, {n: leaf for n, leaf in tree.items() if n not in skip}
+    )
+
+
+def once_a_kind(layer, kinds, name: str) -> dict:
+    """``kind -> layer(*kind, *arrays)`` as one jitted function a kind of
+    layer (``kinds``: tuples of strings). The serving programs walk their
+    layers unrolled, but the layers of a kind have one shape: called through
+    this, a kind is traced and lowered once a program, which calls it a
+    layer (XLA inlines the calls); unrolled text was ten seconds of Python a
+    program. ``name`` (``str.format`` over the kind) is the private
+    function's name in the lowered module, so a part of the persistent
+    compile cache's key."""
+
+    def jitted(kind):
+        def call(*arrays):
+            return layer(*kind, *arrays)
+
+        call.__name__ = name.format(*kind)
+        return jax.jit(call)
+
+    return {kind: jitted(kind) for kind in sorted(set(kinds))}
+
+
+def embed(params, dtype, input_ids):  # distlint: traced
+    """The embedding's rows of ``input_ids`` in the model's ``dtype``."""
+    return jnp.asarray(params['embed'])[input_ids].astype(jnp.dtype(dtype))
+
+
+def swiglu(x, gate, up, down):  # distlint: traced
+    return dense(silu(dense(x, gate)) * dense(x, up), down)
+
+
+def dense_mlp(x, mp):  # distlint: traced
+    """A dense layer's SwiGLU MLP, under the scope its readers find it by."""
+    with jax.named_scope('distllm.dense_mlp'):
+        return swiglu(
+            x, mp['gate']['kernel'], mp['up']['kernel'], mp['down']['kernel']
+        )
+
+
+def finish_layer(x, mixed, mp, eps: float, mlp, counted):  # distlint: traced
+    """Residual of the mixer's output, then the MLP block behind its RMS
+    norm: ``mlp(rows [T, H], counted [T])`` is the family's MLP of the
+    layer and returns the rows and the layer's counts."""
+    x = x + mixed
+    normed = rms_norm(x, mp['mlp_ln']['scale'], eps)
+    out, counts = mlp(normed.reshape(-1, normed.shape[-1]), counted.reshape(-1))
+    return x + out.reshape(x.shape), counts
+
+
+def last_token(hidden, tail_lens):  # distlint: traced
+    """``hidden [B, S, H]`` at each row's last counted position, ``[B, 1,
+    H]``: all a prefill's head reads (a pad row reads position 0)."""
+    last_idx = jnp.maximum(tail_lens - 1, 0)
+    return jnp.take_along_axis(hidden, last_idx[:, None, None], axis=1)
+
+
+# ------------------------------------------------------- the decode window
+def decode_window(  # distlint: traced
+    core, input_ids, positions, context_lens, caches: tuple, block_tables,
+    steps_left, temperature, top_p, min_p, top_k, seeds, *,
+    num_steps: int, sampling_top_window: int, counts,
+):
+    """``num_steps`` fused decode+sample steps: every family's step scan
+    (``mistral.decode_loop`` says what the operands are). ``core(ids,
+    positions, context_lens, caches, tables, live) -> (logits [B, V],
+    caches, counts)`` is one token of every row, a family's
+    ``_decode_core``; ``caches`` a tuple of the pytrees the steps update in
+    place; ``block_tables`` one table or a tuple of them, one a cache
+    group; ``counts`` the zero of the family's counter, a pytree of arrays.
+
+    A slot whose ``steps_left`` has run out is not ``live``: its table row
+    reads 0 (its K/V writes go to the trash block), its id, position and
+    context stay, and its sampled tokens are garbage the host discards;
+    ``core`` gets ``live`` to keep such a row's state and to leave it out of
+    its counts, which are summed over the steps. The token a step produces
+    sits at absolute index ``pos + 1``, which folds into its row's sampling
+    key. Returns ``(tokens [num_steps, B], caches, last_ids, counts)``."""
+    from distllm_tpu.ops.sampling import fold_row_keys, sample_tokens
+
+    def body(carry, _):
+        ids, pos, ctx, *caches, live_steps, counts = carry
+        live = live_steps > 0
+        tables = jax.tree.map(lambda bt: jnp.where(live[:, None], bt, 0), block_tables)
+        logits_, caches, step_counts = core(
+            ids, pos, ctx, tuple(caches), tables, live
+        )
+        token = sample_tokens(
+            logits_, None, temperature, top_p, min_p,
+            top_window=sampling_top_window, top_k=top_k,
+            row_keys=fold_row_keys(seeds, pos + 1),
+        )
+        ids = jnp.where(live, token, ids)
+        pos = jnp.where(live, pos + 1, pos)
+        ctx = jnp.where(live, ctx + 1, ctx)
+        live_steps = live_steps - 1
+        counts = jax.tree.map(jnp.add, counts, step_counts)
+        return (ids, pos, ctx, *caches, live_steps, counts), token
+
+    (ids, _, _, *caches, _, counts), tokens = jax.lax.scan(
+        body,
+        (
+            input_ids, positions, context_lens, *caches,
+            steps_left.astype(jnp.int32), counts,
+        ),
+        None,
+        length=num_steps,
+    )
+    return tokens, tuple(caches), ids, counts
+
+
+# ------------------------------------------------------- seeded parameters
+def seeded_tree(
+    rng: jax.Array, dtype, hidden: int, top: dict, trees: dict, wrap,
+    scales=(), leaf=None,
+) -> dict:
+    """Random parameters made on the device by one jitted program, the key
+    its ARGUMENT: normal(0, 0.02) in ``dtype`` where nothing else is said,
+    ``final_ln`` a unit scale over ``hidden``.
+
+    ``top``: ``name -> shape`` of the top-level matrices, drawn from
+    ``fold_in(key, i)`` in the dict's order. ``trees``: ``kind -> (fold,
+    count, {name: shape})``, the stacked trees: a tree's key is
+    ``fold_in(key, fold)`` and leaf ``ni`` of its SORTED names draws
+    ``[count, *shape]`` from ``fold_in(tree key, ni)``. ``scales`` names the
+    leaves that are ones; ``leaf(name, key, shape, normal)`` returns a leaf
+    that is neither that nor ``normal(key, shape)``, or None (``normal(key,
+    shape, scale=0.02, dtype=dtype)``); ``wrap(name, leaf)`` gives a tree's
+    leaf its place (``{'kernel': leaf}``). The benchmark's cells take their
+    weights from these numbers: tests/test_family_scaffold.py pins the bits."""
+    dtype = jnp.dtype(dtype)
+
+    @jax.jit
+    def build(key):
+        def normal(key, shape, scale=0.02, dtype=dtype):
+            return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
+
+        def make(name, key, shape):
+            if name in scales:
+                return jnp.ones(shape, dtype)
+            made = None if leaf is None else leaf(name, key, shape, normal)
+            return normal(key, shape) if made is None else made
+
+        params = {
+            name: normal(jax.random.fold_in(key, i), shape)
+            for i, (name, shape) in enumerate(top.items())
+        }
+        params['final_ln'] = {'scale': jnp.ones((hidden,), dtype)}
+        for kind, (fold, count, shapes) in trees.items():
+            tkey = jax.random.fold_in(key, fold)
+            params[kind] = {
+                name: wrap(
+                    name,
+                    make(name, jax.random.fold_in(tkey, ni), (count, *shape)),
+                )
+                for ni, (name, shape) in enumerate(sorted(shapes.items()))
+            }
+        return params
+
+    return build(rng)
+
+
+def tree_table(kinds, count, shapes, first: int = 8) -> dict:
+    """``seeded_tree``'s ``trees`` for the ``kinds`` that have layers: a
+    kind's fold-in number is ``first`` plus its place among ``kinds``."""
+    return {
+        kind: (first + ti, count(kind), shapes(kind))
+        for ti, kind in enumerate(kinds) if count(kind)
+    }
+
+
+def tree_specs(top: dict, trees: dict, wrap, banks=()) -> dict:
+    """``seeded_tree``'s tree as PartitionSpecs: the expert banks
+    (``(kind, name)`` pairs) over ``expert``, everything else replicated."""
+    specs = {name: P(*(None,) * len(shape)) for name, shape in top.items()}
+    specs['final_ln'] = {'scale': P()}
+    for kind, (_, _, shapes) in trees.items():
+        specs[kind] = {
+            name: wrap(
+                name,
+                P(None, 'expert', None, None) if (kind, name) in banks
+                else P(*(None,) * (len(shape) + 1)),
+            )
+            for name, shape in shapes.items()
+        }
+    return specs

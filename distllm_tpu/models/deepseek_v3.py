@@ -49,11 +49,11 @@ There is no ``params_from_hf`` yet.
 
 from __future__ import annotations
 
+import functools
 from typing import Literal
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 from distllm_tpu.models import common
 from distllm_tpu.models.moe import routed_experts
@@ -251,66 +251,32 @@ def _top_shapes(cfg: DeepseekV3Config) -> dict:
     }
 
 
+def _trees(cfg: DeepseekV3Config) -> dict:
+    return common.tree_table(
+        _TREES, cfg.count, lambda kind: _tree_shapes(cfg, kind)
+    )
+
+
 def init_on_device(rng: jax.Array, cfg: DeepseekV3Config) -> dict:
     """Random parameters made on the device in ``cfg.dtype``: normal(0,
     0.02) kernels, unit norm scales, the router's selection bias normal(0,
     0.02) in float32 (a zero buffer in the published code before training),
     one RNG call per parameter kind."""
-    dtype = jnp.dtype(cfg.dtype)
-    trees = [
-        (ti, kind, cfg.count(kind)) for ti, kind in enumerate(_TREES)
-        if cfg.count(kind)
-    ]
 
-    @jax.jit
-    def build(key):
-        def normal(key, shape, dtype=dtype):
-            return (jax.random.normal(key, shape, F32) * 0.02).astype(dtype)
+    def leaf(name, key, shape, normal):
+        return normal(key, shape, dtype=F32) if name in _F32_LEAVES else None
 
-        params = {
-            name: normal(jax.random.fold_in(key, i), shape)
-            for i, (name, shape) in enumerate(_top_shapes(cfg).items())
-        }
-        params['final_ln'] = {'scale': jnp.ones((cfg.hidden_size,), dtype)}
-        for ti, kind, count in trees:
-            tkey = jax.random.fold_in(key, 8 + ti)
-            params[kind] = {
-                name: _wrap(
-                    name,
-                    jnp.ones((count, *shape), dtype) if name in _SCALES
-                    else normal(
-                        jax.random.fold_in(tkey, ni), (count, *shape),
-                        F32 if name in _F32_LEAVES else dtype,
-                    ),
-                )
-                for ni, (name, shape) in enumerate(
-                    sorted(_tree_shapes(cfg, kind).items())
-                )
-            }
-        return params
-
-    return build(rng)
+    return common.seeded_tree(
+        rng, cfg.dtype, cfg.hidden_size, _top_shapes(cfg), _trees(cfg), _wrap,
+        _SCALES, leaf,
+    )
 
 
 def param_specs(cfg: DeepseekV3Config, params: dict | None = None) -> dict:
     """Expert banks over ``expert``, everything else replicated."""
-    specs = {
-        'embed': P(None, None), 'lm_head': P(None, None),
-        'final_ln': {'scale': P()},
-    }
-    for kind in _TREES:
-        if not cfg.count(kind):
-            continue
-        specs[kind] = {
-            name: _wrap(
-                name,
-                P(None, 'expert', None, None)
-                if kind == 'sparse' and name in _BANKS
-                else P(*(None,) * (len(shape) + 1)),
-            )
-            for name, shape in _tree_shapes(cfg, kind).items()
-        }
-    return specs
+    return common.tree_specs(
+        _top_shapes(cfg), _trees(cfg), _wrap, [('sparse', n) for n in _BANKS]
+    )
 
 
 def params_from_hf(state: dict, cfg: DeepseekV3Config) -> dict:
@@ -325,10 +291,6 @@ def params_from_hf(state: dict, cfg: DeepseekV3Config) -> dict:
 # ------------------------------------------------------------ shared parts
 def _norm(x, scale, cfg):
     return common.rms_norm(x, scale, cfg.rms_norm_eps)
-
-
-def _embed(params, cfg, input_ids):
-    return jnp.asarray(params['embed'])[input_ids].astype(jnp.dtype(cfg.dtype))
 
 
 def _rope_tables(cfg: DeepseekV3Config, max_len: int):
@@ -388,23 +350,13 @@ def _attn_out(ot, lp, cfg):
     )
 
 
-def _swiglu(x, gate, up, down):
-    return common.dense(
-        common.silu(common.dense(x, gate)) * common.dense(x, up), down
-    )
-
-
 def _mlp(x, mp, cfg, mlp_kind, counted, banks, mi):
     """The MLP block of one layer for ``x [T, H]`` (already normed);
     returns it and the layer's (routed, held) pair counts. ``banks`` is the
     sparse tree: the expert banks stay stacked, ``mi`` picks the layer
     inside the expert matmuls (``models/moe.py``)."""
     if mlp_kind == 'dense':
-        with jax.named_scope('distllm.dense_mlp'):
-            out = _swiglu(
-                x, mp['gate']['kernel'], mp['up']['kernel'], mp['down']['kernel']
-            )
-        return out, jnp.zeros((2,), jnp.int32)
+        return common.dense_mlp(x, mp), jnp.zeros((2,), jnp.int32)
     routed, pairs = routed_experts(
         x, mp['router']['kernel'], *(banks[n]['kernel'] for n in _BANKS),
         cfg.experts_per_token, first_expert=cfg.first_local_expert,
@@ -414,7 +366,7 @@ def _mlp(x, mp, cfg, mlp_kind, counted, banks, mi):
     # The shared experts: every chip of the expert axis computes them alike,
     # so they are counted once, here, whatever share of the bank is held.
     with jax.named_scope('distllm.moe'):
-        shared = _swiglu(
+        shared = common.swiglu(
             x, mp['shared_gate']['kernel'], mp['shared_up']['kernel'],
             mp['shared_down']['kernel'],
         )
@@ -423,13 +375,11 @@ def _mlp(x, mp, cfg, mlp_kind, counted, banks, mi):
 
 def _finish_layer(x, mixed, mp, cfg, mlp_kind, counted, banks, mi):
     """Residual of the attention output, then the MLP block."""
-    x = x + mixed
-    normed = _norm(x, mp['mlp_ln']['scale'], cfg)
-    mlp, pairs = _mlp(
-        normed.reshape(-1, normed.shape[-1]), mp, cfg, mlp_kind,
-        counted.reshape(-1), banks, mi,
+    return common.finish_layer(
+        x, mixed, mp, cfg.rms_norm_eps,
+        lambda rows, of_rows: _mlp(rows, mp, cfg, mlp_kind, of_rows, banks, mi),
+        counted,
     )
-    return x + mlp.reshape(x.shape), pairs
 
 
 def logits(params: dict, cfg: DeepseekV3Config, hidden: jnp.ndarray) -> jnp.ndarray:  # distlint: traced
@@ -438,34 +388,15 @@ def logits(params: dict, cfg: DeepseekV3Config, hidden: jnp.ndarray) -> jnp.ndar
     return common.dense(hidden, params['lm_head']).astype(F32)
 
 
-def _layer_at(tree, i, skip=()):
-    """Layer ``i`` (static) of a stacked tree, without the leaves ``skip``
-    names: the sparse tree's expert banks (a slice of those would be a copy
-    of the layer's whole bank)."""
-    return jax.tree.map(
-        lambda a: a[i], {n: leaf for n, leaf in tree.items() if n not in skip}
-    )
-
-
 def _mlp_layer_at(params, mlp_kind, mi):
-    return _layer_at(
+    return common.layer_at(
         params[mlp_kind], mi, skip=_BANKS if mlp_kind == 'sparse' else ()
     )
 
 
-def _once_a_kind(layer, cfg: DeepseekV3Config) -> dict:
-    """``MLP kind -> layer(mlp_kind, *arrays)`` as one jitted function a
-    kind. The serving programs walk their layers unrolled (a buffer a
-    layer), but the layers of a kind have one shape: called through this, a
-    kind is traced and lowered once and the program calls it a layer."""
-
-    def jitted(mlp_kind):
-        def deepseek_layer(*arrays):
-            return layer(mlp_kind, *arrays)
-
-        return jax.jit(deepseek_layer)
-
-    return {kind: jitted(kind) for kind in ('dense', 'sparse') if cfg.count(kind)}
+def _mlp_kinds(cfg: DeepseekV3Config) -> list[tuple[str]]:
+    """The kinds of layer ``common.once_a_kind`` jits: one an MLP kind."""
+    return [(kind,) for kind in ('dense', 'sparse') if cfg.count(kind)]
 
 
 # ----------------------------------------------------------------- forwards
@@ -497,7 +428,7 @@ def prefill_paged(  # distlint: traced
     valid = jnp.arange(s)[None, :] < tail_lens[:, None]
     cos, sin = _rope_tables(cfg, max_table_positions or cfg.max_position_embeddings)
     planes = list(k_cache)
-    x = _embed(params, cfg, input_ids)
+    x = common.embed(params, cfg.dtype, input_ids)
 
     def layer(mlp_kind, x, lp, mp, banks, mi, plane, table, cos, sin,
               positions, valid, context_lens, tail_lens):
@@ -518,36 +449,33 @@ def prefill_paged(  # distlint: traced
         )
         return x, plane
 
-    layer_of = _once_a_kind(layer, cfg)
+    layer_of = common.once_a_kind(layer, _mlp_kinds(cfg), 'deepseek_layer')
     for li in range(cfg.num_layers):
         mlp_kind, mi = cfg.mlp_of(li)
-        x, planes[li] = layer_of[mlp_kind](
-            x, _layer_at(params['attn'], li),
+        x, planes[li] = layer_of[(mlp_kind,)](
+            x, common.layer_at(params['attn'], li),
             _mlp_layer_at(params, mlp_kind, mi), params.get('sparse'),
             jnp.int32(mi), planes[li], block_tables, cos, sin, positions,
             valid, context_lens, tail_lens,
         )
     hidden = _norm(x, params['final_ln']['scale'], cfg)
-    last_idx = jnp.maximum(tail_lens - 1, 0)
-    last_hidden = jnp.take_along_axis(hidden, last_idx[:, None, None], axis=1)
+    last_hidden = common.last_token(hidden, tail_lens)
     return logits(params, cfg, last_hidden)[:, 0], tuple(planes), ()
 
 
 def _decode_core(
-    params, cfg, input_ids, positions, planes, block_tables, context_lens,
-    live, rope, attn_backend,
+    params, cfg, rope, attn_backend, input_ids, positions, context_lens,
+    caches, block_tables, live,
 ):
-    """One token of every row. The layers are walked unrolled, each with
-    static indices: a static slice of the stacked kernels folds into its
-    matmul, and a layer's plane is written in place."""
-    from distllm_tpu.ops.paged_attention import (
-        paged_attention_xla,
-        ragged_paged_attention_pallas,
-        write_token_kv,
-    )
+    """One token of every row (``common.decode_window``'s ``core`` once its
+    first four arguments are bound; ``caches`` is ``(planes,)``). The layers
+    are walked unrolled, each with static indices: a static slice of the
+    stacked kernels folds into its matmul, and a layer's plane is written in
+    place."""
+    from distllm_tpu.ops.paged_attention import decode_attention, write_token_kv
 
-    x = _embed(params, cfg, input_ids)  # [B, H]
-    planes = list(planes)
+    x = common.embed(params, cfg.dtype, input_ids)  # [B, H]
+    planes = list(caches[0])
     pairs = jnp.zeros((2,), jnp.int32)
 
     def layer(mlp_kind, x, lp, mp, banks, mi, plane, table, cos, sin,
@@ -561,35 +489,28 @@ def _decode_core(
             plane, _ = write_token_kv(
                 plane, None, row[:, 0], None, table, positions
             )
-            if attn_backend == 'xla':
-                ot = paged_attention_xla(
-                    q[:, 0], plane, None, table, context_lens,
-                    scale=cfg.softmax_scale, value_lanes=cfg.kv_lora_rank,
-                )[:, None]
-            else:
-                ot = ragged_paged_attention_pallas(
-                    q, plane, None, table, context_lens,
-                    q_positions=positions[:, None], scale=cfg.softmax_scale,
-                    interpret=attn_backend == 'interpret',
-                    value_lanes=cfg.kv_lora_rank,
-                )
+            ot = decode_attention(
+                q[:, 0], plane, None, table, context_lens, positions,
+                backend=attn_backend, scale=cfg.softmax_scale,
+                value_lanes=cfg.kv_lora_rank,
+            )[:, None]
         x, layer_pairs = _finish_layer(
             x, _attn_out(ot, lp, cfg)[:, 0], mp, cfg, mlp_kind, live, banks, mi,
         )
         return x, plane, layer_pairs
 
-    layer_of = _once_a_kind(layer, cfg)
+    layer_of = common.once_a_kind(layer, _mlp_kinds(cfg), 'deepseek_layer')
     for li in range(cfg.num_layers):
         mlp_kind, mi = cfg.mlp_of(li)
-        x, planes[li], layer_pairs = layer_of[mlp_kind](
-            x, _layer_at(params['attn'], li),
+        x, planes[li], layer_pairs = layer_of[(mlp_kind,)](
+            x, common.layer_at(params['attn'], li),
             _mlp_layer_at(params, mlp_kind, mi), params.get('sparse'),
             jnp.int32(mi), planes[li], block_tables, *rope, positions,
             context_lens, live,
         )
         pairs = pairs + layer_pairs
     hidden = _norm(x, params['final_ln']['scale'], cfg)
-    return logits(params, cfg, hidden), tuple(planes), pairs
+    return logits(params, cfg, hidden), (tuple(planes),), pairs
 
 
 def decode_loop(  # distlint: traced
@@ -611,43 +532,19 @@ def decode_loop(  # distlint: traced
     attn_backend: str = 'xla',
     max_table_positions: int | None = None,
     sampling_top_window: int = 0,
-    layer_unroll: bool = True,
 ):
     """``mistral.decode_loop``'s contract over the latent pool. A row out
     of budget writes its row to the trash block. Returns ``(tokens
     [num_steps, B], k_cache, v_cache, last_ids, moe_pairs [2])``, the last
     being the window's (routed, held) pair counts over the rows and steps
     that ran."""
-    from distllm_tpu.ops.sampling import fold_row_keys, sample_tokens
-
-    del layer_unroll, v_cache  # always unrolled; no V plane
+    del v_cache  # no V plane
     rope = _rope_tables(cfg, max_table_positions or cfg.max_position_embeddings)
-
-    def body(carry, _):
-        ids, pos, ctx, planes, live_steps, pairs = carry
-        live = live_steps > 0
-        bt_eff = jnp.where(live[:, None], block_tables, 0)
-        logits_, planes, step_pairs = _decode_core(
-            params, cfg, ids, pos, planes, bt_eff, ctx, live, rope,
-            attn_backend,
-        )
-        token = sample_tokens(
-            logits_, None, temperature, top_p, min_p,
-            top_window=sampling_top_window, top_k=top_k,
-            row_keys=fold_row_keys(seeds, pos + 1),
-        )
-        ids = jnp.where(live, token, ids)
-        pos = jnp.where(live, pos + 1, pos)
-        ctx = jnp.where(live, ctx + 1, ctx)
-        return (ids, pos, ctx, planes, live_steps - 1, pairs + step_pairs), token
-
-    (ids, _, _, planes, _, pairs), tokens = jax.lax.scan(
-        body,
-        (
-            input_ids, positions, context_lens, tuple(k_cache),
-            steps_left.astype(jnp.int32), jnp.zeros((2,), jnp.int32),
-        ),
-        None,
-        length=num_steps,
+    tokens, (planes,), ids, pairs = common.decode_window(
+        functools.partial(_decode_core, params, cfg, rope, attn_backend),
+        input_ids, positions, context_lens, (tuple(k_cache),),
+        block_tables, steps_left, temperature, top_p, min_p, top_k, seeds,
+        num_steps=num_steps, sampling_top_window=sampling_top_window,
+        counts=jnp.zeros((2,), jnp.int32),
     )
     return tokens, planes, (), ids, pairs

@@ -50,17 +50,17 @@ weight. There is no ``params_from_hf`` yet.
 
 from __future__ import annotations
 
+import functools
 from typing import Literal
 
 import jax
 import jax.numpy as jnp
-import numpy as np
-from jax.sharding import PartitionSpec as P
 
 from distllm_tpu.models import common
 from distllm_tpu.models.granite_hybrid import (
     _gather_state,
     _scatter_state,
+    mamba_leaf,
     mamba_span,
     mamba_step,
 )
@@ -246,65 +246,35 @@ def _wrap(name: str, leaf):
     return {'kernel': leaf}
 
 
+def _layout(cfg: FalconH1Config) -> tuple[dict, dict]:
+    """``common.seeded_tree``'s tables: the top-level shapes, and the one
+    tree's ``(fold-in number, layers, leaf shapes)``."""
+    h, v = cfg.hidden_size, cfg.vocab_size
+    return (
+        {'embed': (v, h), 'head': (h, v)},
+        {'layers': (2, cfg.num_layers, _layer_shapes(cfg))},
+    )
+
+
 def init_on_device(rng: jax.Array, cfg: FalconH1Config) -> dict:
     """Random parameters made on the device in ``cfg.dtype``: normal(0,
-    0.02) kernels, embedding and head, unit norm scales and ``D``, taps and
-    their bias normal(0, 0.5), ``A`` uniform in [1, 16] and ``dt``
-    log-uniform in [0.001, 0.1] (float32), one RNG call per parameter kind.
-    The published multipliers presume muP-sized weights: a driver that
-    wants the mixers' mechanisms to show scales the kinds of leaf itself."""
-    dtype = jnp.dtype(cfg.dtype)
-
-    @jax.jit
-    def build(key):
-        def normal(key, shape, scale=0.02):
-            return (jax.random.normal(key, shape, F32) * scale).astype(dtype)
-
-        def leaf(key, name, shape):
-            if name in _SCALES or name == 'D':
-                return jnp.ones(shape, dtype)
-            if name == 'A_log':
-                return jnp.log(jax.random.uniform(key, shape, F32, 1.0, 16.0))
-            if name == 'dt_bias':
-                dt = jnp.exp(jax.random.uniform(
-                    key, shape, F32, np.log(0.001), np.log(0.1)
-                ))
-                return dt + jnp.log(-jnp.expm1(-dt))  # softplus^-1
-            return normal(key, shape, 0.5 if name in ('conv', 'conv_bias') else 0.02)
-
-        lkey = jax.random.fold_in(key, 2)
-        return {
-            'embed': normal(
-                jax.random.fold_in(key, 0), (cfg.vocab_size, cfg.hidden_size)
-            ),
-            'head': {'kernel': normal(
-                jax.random.fold_in(key, 1), (cfg.hidden_size, cfg.vocab_size)
-            )},
-            'final_ln': {'scale': jnp.ones((cfg.hidden_size,), dtype)},
-            'layers': {
-                name: _wrap(name, leaf(
-                    jax.random.fold_in(lkey, ni), name, (cfg.num_layers, *shape)
-                ))
-                for ni, (name, shape) in enumerate(
-                    sorted(_layer_shapes(cfg).items())
-                )
-            },
-        }
-
-    return build(rng)
+    0.02) kernels, embedding and head, unit norm scales and ``D``, the
+    mixer's leaves by ``granite_hybrid.mamba_leaf``, one RNG call per
+    parameter kind. The published multipliers presume muP-sized weights: a
+    driver that wants the mixers' mechanisms to show scales the kinds of
+    leaf itself."""
+    params = common.seeded_tree(
+        rng, cfg.dtype, cfg.hidden_size, *_layout(cfg), _wrap,
+        (*_SCALES, 'D'), mamba_leaf,
+    )
+    return {**params, 'head': {'kernel': params['head']}}
 
 
 def param_specs(cfg: FalconH1Config, params: dict | None = None) -> dict:
     """Everything replicated: the engine refuses a mesh for a model with
     state, so there is no partitioning to state."""
-    return {
-        'embed': P(None, None), 'head': {'kernel': P(None, None)},
-        'final_ln': {'scale': P()},
-        'layers': {
-            name: _wrap(name, P(*(None,) * (len(shape) + 1)))
-            for name, shape in _layer_shapes(cfg).items()
-        },
-    }
+    specs = common.tree_specs(*_layout(cfg), _wrap)
+    return {**specs, 'head': {'kernel': specs['head']}}
 
 
 def params_from_hf(state: dict, cfg: FalconH1Config) -> dict:
@@ -490,33 +460,29 @@ def prefill_paged(  # distlint: traced
     )
     state = _scatter_state(state, 'ssm', 0, ssm, slots)
     state = _scatter_state(state, 'conv', 0, conv, slots)
-    last_idx = jnp.maximum(tail_lens - 1, 0)
-    last_x = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)
+    last_x = common.last_token(x, tail_lens)
     return _head(params, cfg, last_x)[:, 0], k_cache, v_cache, state
 
 
 def _decode_core(
-    params, cfg, input_ids, positions, k_cache, v_cache, block_tables,
-    context_lens, state, live, rope, attn_backend,
+    params, cfg, rope, attn_backend, input_ids, positions, context_lens,
+    caches, block_tables, live,
 ):
-    """One token of every row. The layers are walked unrolled: each
-    layer's state is a buffer of its own, rewritten whole and in place (row
-    ``i`` of the batch is slot ``i``), and a static slice of the stacked
-    kernels folds into its matmul. Returns ``(logits, k_cache, v_cache,
-    state, rows)``, ``rows`` the live rows: those whose slots this step
-    read and wrote (a fifth value where ``lfm2._decode_core`` has its
-    expert pairs: one toy driver steps both)."""
-    from distllm_tpu.ops.paged_attention import (
-        paged_attention_xla,
-        ragged_paged_attention_pallas,
-        write_token_kv,
-    )
+    """One token of every row (``common.decode_window``'s ``core`` once its
+    first four arguments are bound; ``caches`` is ``(k_cache, v_cache,
+    state)``). The layers are walked unrolled: each layer's state is a
+    buffer of its own, rewritten whole and in place (row ``i`` of the batch
+    is slot ``i``), and a static slice of the stacked kernels folds into its
+    matmul. The count it returns is the live rows: those whose slots this
+    step read and wrote."""
+    from distllm_tpu.ops.paged_attention import decode_attention, write_token_kv
 
     cos, sin = rope
+    k_cache, v_cache, state = caches
     x = _embed(params, cfg, input_ids)  # [B, hidden]
     ssms, convs = list(state['ssm']), list(state['conv'])
     for li in range(cfg.num_layers):
-        lp = jax.tree.map(lambda a: a[li], params['layers'])
+        lp = common.layer_at(params['layers'], li)
         h = _norm(x, lp['ln']['scale'], cfg)
         mamba, ssms[li], convs[li] = mamba_step(
             _times(h, cfg.ssm_in_multiplier), lp, cfg, ssms[li], convs[li], live
@@ -527,22 +493,15 @@ def _decode_core(
                 k_cache, v_cache, k[:, 0], v[:, 0], block_tables, positions,
                 layer=li,
             )
-            if attn_backend == 'xla':
-                attn = paged_attention_xla(
-                    q[:, 0], k_cache, v_cache, block_tables, context_lens,
-                    layer=li,
-                )
-            else:
-                attn = ragged_paged_attention_pallas(
-                    q, k_cache, v_cache, block_tables, context_lens,
-                    q_positions=positions[:, None],
-                    interpret=attn_backend == 'interpret', layer=li,
-                )[:, 0]
+            attn = decode_attention(
+                q[:, 0], k_cache, v_cache, block_tables, context_lens,
+                positions, backend=attn_backend, layer=li,
+            )
             attn = _attn_out(attn, lp, cfg)
         x = _finish_layer(x, mamba, attn, lp, cfg)
     state = {'ssm': tuple(ssms), 'conv': tuple(convs)}
     rows = jnp.sum(live, dtype=jnp.int32)
-    return _head(params, cfg, x), k_cache, v_cache, state, rows
+    return _head(params, cfg, x), (k_cache, v_cache, state), rows
 
 
 def decode_loop(  # distlint: traced
@@ -564,7 +523,6 @@ def decode_loop(  # distlint: traced
     attn_backend: str = 'xla',
     max_table_positions: int | None = None,
     sampling_top_window: int = 0,
-    layer_unroll: bool = True,
     *,
     state: dict,
 ):
@@ -575,40 +533,12 @@ def decode_loop(  # distlint: traced
     k_cache, v_cache, last_ids, state, counters)``; ``counters`` is
     ``{'state_rows': int32}``, the live rows summed over the window's
     steps: each read and wrote its slot in every layer."""
-    from distllm_tpu.ops.sampling import fold_row_keys, sample_tokens
-
-    del layer_unroll  # always unrolled
     rope = _rope_tables(cfg, max_table_positions or cfg.max_position_embeddings)
-
-    def body(carry, _):
-        ids, pos, ctx, k_cache, v_cache, state, live_steps, state_rows = carry
-        live = live_steps > 0
-        bt_eff = jnp.where(live[:, None], block_tables, 0)
-        logits_, k_cache, v_cache, state, rows = _decode_core(
-            params, cfg, ids, pos, k_cache, v_cache, bt_eff, ctx, state,
-            live, rope, attn_backend,
-        )
-        token = sample_tokens(
-            logits_, None, temperature, top_p, min_p,
-            top_window=sampling_top_window, top_k=top_k,
-            row_keys=fold_row_keys(seeds, pos + 1),
-        )
-        ids = jnp.where(live, token, ids)
-        pos = jnp.where(live, pos + 1, pos)
-        ctx = jnp.where(live, ctx + 1, ctx)
-        carry = (
-            ids, pos, ctx, k_cache, v_cache, state, live_steps - 1,
-            state_rows + rows,
-        )
-        return carry, token
-
-    (ids, _, _, k_cache, v_cache, state, _, state_rows), tokens = jax.lax.scan(
-        body,
-        (
-            input_ids, positions, context_lens, k_cache, v_cache, state,
-            steps_left.astype(jnp.int32), jnp.zeros((), jnp.int32),
-        ),
-        None,
-        length=num_steps,
+    tokens, (k_cache, v_cache, state), ids, state_rows = common.decode_window(
+        functools.partial(_decode_core, params, cfg, rope, attn_backend),
+        input_ids, positions, context_lens, (k_cache, v_cache, state),
+        block_tables, steps_left, temperature, top_p, min_p, top_k, seeds,
+        num_steps=num_steps, sampling_top_window=sampling_top_window,
+        counts=jnp.zeros((), jnp.int32),
     )
     return tokens, k_cache, v_cache, ids, state, {'state_rows': state_rows}

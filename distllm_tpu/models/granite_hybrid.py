@@ -36,12 +36,12 @@ Equations (transformers ``models/granitemoehybrid``):
 
 from __future__ import annotations
 
+import functools
 from typing import Literal
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
 
 from distllm_tpu.models import common
 from distllm_tpu.models.moe import routed_experts
@@ -231,66 +231,45 @@ def _wrap(name: str, leaf):
     return {'kernel': leaf}
 
 
+def mamba_leaf(name, key, shape, normal):
+    """``common.seeded_tree``'s rule for a Mamba-2 mixer's leaves: ``A``
+    uniform in [1, 16] and ``dt`` log-uniform in [0.001, 0.1] (the published
+    initialisation's ranges; float32), taps and their bias normal(0, 0.5)."""
+    if name == 'A_log':
+        return jnp.log(jax.random.uniform(key, shape, F32, 1.0, 16.0))
+    if name == 'dt_bias':
+        dt = jnp.exp(jax.random.uniform(
+            key, shape, F32, np.log(0.001), np.log(0.1)
+        ))
+        return dt + jnp.log(-jnp.expm1(-dt))  # softplus^-1
+    return normal(key, shape, 0.5) if name in ('conv', 'conv_bias') else None
+
+
+def _trees(cfg: GraniteHybridConfig) -> dict:
+    """``kind -> (fold-in number, layers, leaf shapes)``."""
+    return {
+        'mamba': (1, cfg.num_mamba_layers, _layer_shapes(cfg, 'mamba')),
+        'attention': (2, cfg.num_paged_layers, _layer_shapes(cfg, 'attention')),
+    }
+
+
 def init_on_device(rng: jax.Array, cfg: GraniteHybridConfig) -> dict:
     """Random parameters made on the device in ``cfg.dtype``: normal(0,
-    0.02) kernels, unit norm scales and ``D``, ``A`` uniform in [1, 16] and
-    ``dt`` log-uniform in [0.001, 0.1] (the published initialisation's
-    ranges), one RNG call per parameter kind."""
-    dtype = jnp.dtype(cfg.dtype)
-
-    @jax.jit
-    def build(key):
-        def leaf(key, name, shape):
-            if name in _SCALES or name == 'D':
-                return jnp.ones(shape, dtype)
-            if name == 'A_log':
-                return jnp.log(jax.random.uniform(key, shape, F32, 1.0, 16.0))
-            if name == 'dt_bias':
-                dt = jnp.exp(jax.random.uniform(
-                    key, shape, F32, np.log(0.001), np.log(0.1)
-                ))
-                return dt + jnp.log(-jnp.expm1(-dt))  # softplus^-1
-            scale = 0.5 if name in ('conv', 'conv_bias') else 0.02
-            return (jax.random.normal(key, shape, F32) * scale).astype(dtype)
-
-        params = {
-            'embed': leaf(
-                jax.random.fold_in(key, 0), 'embed',
-                (cfg.vocab_size, cfg.hidden_size),
-            ),
-            'final_ln': {'scale': jnp.ones((cfg.hidden_size,), dtype)},
-        }
-        for ki, (kind, count) in enumerate((
-            ('mamba', cfg.num_mamba_layers),
-            ('attention', cfg.num_paged_layers),
-        )):
-            kkey = jax.random.fold_in(key, ki + 1)
-            shapes = _layer_shapes(cfg, kind)
-            params[kind] = {
-                name: _wrap(name, leaf(
-                    jax.random.fold_in(kkey, ni), name, (count, *shape)
-                ))
-                for ni, (name, shape) in enumerate(sorted(shapes.items()))
-            }
-        return params
-
-    return build(rng)
+    0.02) kernels, unit norm scales and ``D``, the mixer's leaves by
+    ``mamba_leaf``, one RNG call per parameter kind."""
+    return common.seeded_tree(
+        rng, cfg.dtype, cfg.hidden_size,
+        {'embed': (cfg.vocab_size, cfg.hidden_size)}, _trees(cfg), _wrap,
+        (*_SCALES, 'D'), mamba_leaf,
+    )
 
 
 def param_specs(cfg: GraniteHybridConfig, params: dict | None = None) -> dict:
     """Expert banks over ``expert``, everything else replicated."""
-    specs = {'embed': P(None, None), 'final_ln': {'scale': P()}}
-    for kind in ('mamba', 'attention'):
-        specs[kind] = {
-            name: _wrap(
-                name,
-                P(None, 'expert', None, None)
-                if name in ('gate', 'up', 'down')
-                else P(*(None,) * (len(shape) + 1)),
-            )
-            for name, shape in _layer_shapes(cfg, kind).items()
-        }
-    return specs
+    return common.tree_specs(
+        {'embed': (cfg.vocab_size, cfg.hidden_size)}, _trees(cfg), _wrap,
+        [(kind, name) for kind in ('mamba', 'attention') for name in _BANKS],
+    )
 
 
 def params_from_hf(state: dict[str, np.ndarray], cfg: GraniteHybridConfig) -> dict:
@@ -376,9 +355,8 @@ def _mlp(x, lp, cfg, counted, banks, li):
         cfg.experts_per_token, first_expert=cfg.first_local_expert,
         counted=counted, layer=li,
     )
-    shared = common.dense(
-        common.silu(common.dense(x, lp['shared_gate']['kernel']))
-        * common.dense(x, lp['shared_up']['kernel']),
+    shared = common.swiglu(
+        x, lp['shared_gate']['kernel'], lp['shared_up']['kernel'],
         lp['shared_down']['kernel'],
     )
     return routed + shared, pairs
@@ -551,16 +529,6 @@ def _attn_out(attn, lp, cfg):
 
 
 # ----------------------------------------------------------------- forwards
-def _layer_at(params, kind, li):
-    """Layer ``li`` of a kind's stacked tree, inside a scan over a run's
-    indices: a slice of the run copied out for ``xs`` would hold the run's
-    expert banks twice."""
-    return jax.tree.map(
-        lambda a: jax.lax.dynamic_index_in_dim(a, li, 0, keepdims=False),
-        {n: leaf for n, leaf in params[kind].items() if n not in _BANKS},
-    )
-
-
 def _run_indices(first, count):
     return jnp.arange(first, first + count, dtype=jnp.int32)
 
@@ -611,7 +579,7 @@ def prefill(  # distlint: traced
     ks, vs, ssms, convs = [], [], [], []
 
     def mamba_layer(x, li):
-        lp = _layer_at(params, 'mamba', li)
+        lp = common.layer_at(params['mamba'], li, skip=_BANKS, dynamic=True)
         ssm0 = jnp.zeros((b, *spec['ssm'][0].shape), F32)
         conv0 = jnp.zeros((b, *spec['conv'][0].shape), spec['conv'][0].dtype)
         mixed, ssm, conv = mamba_span(
@@ -621,7 +589,7 @@ def prefill(  # distlint: traced
         return x, (ssm, conv)
 
     def attn_layer(x, li):
-        lp = _layer_at(params, 'attention', li)
+        lp = common.layer_at(params['attention'], li, skip=_BANKS, dynamic=True)
         q, k, v = _qkv(_norm(x, lp['ln']['scale'], cfg), lp, cfg)
         attn = common.sdpa(q, k, v, mask=mask, scale=cfg.attention_multiplier)
         x, _ = _finish_layer(
@@ -677,7 +645,7 @@ def prefill_paged(  # distlint: traced
 
     def mamba_layer(x, xs):
         li, ssm0, conv0 = xs
-        lp = _layer_at(params, 'mamba', li)
+        lp = common.layer_at(params['mamba'], li, skip=_BANKS, dynamic=True)
         mixed, ssm, conv = mamba_span(
             _norm(x, lp['ln']['scale'], cfg), lp, cfg, ssm0, conv0, tail_lens
         )
@@ -687,7 +655,7 @@ def prefill_paged(  # distlint: traced
     def attn_layer(carry, xs):
         x, k_cache, v_cache = carry
         li = xs
-        lp = _layer_at(params, 'attention', li)
+        lp = common.layer_at(params['attention'], li, skip=_BANKS, dynamic=True)
         q, k, v = _qkv(_norm(x, lp['ln']['scale'], cfg), lp, cfg)
         # the stacked pools whole, with the layer whose pages are meant
         k_cache, v_cache = write_chunk_kv(
@@ -723,24 +691,22 @@ def prefill_paged(  # distlint: traced
                 attn_layer, (x, k_cache, v_cache), run
             )
     hidden = _norm(x, params['final_ln']['scale'], cfg)
-    last_idx = jnp.maximum(tail_lens - 1, 0)
-    last_hidden = jnp.take_along_axis(hidden, last_idx[:, None, None], axis=1)
+    last_hidden = common.last_token(hidden, tail_lens)
     return logits(params, cfg, last_hidden)[:, 0], k_cache, v_cache, state
 
 
 def _decode_core(
-    params, cfg, input_ids, positions, k_cache, v_cache, block_tables,
-    context_lens, state, live, attn_backend,
+    params, cfg, attn_backend, input_ids, positions, context_lens, caches,
+    block_tables, live,
 ):
-    """One token of every row. The layers are walked unrolled: each Mamba
+    """One token of every row (``common.decode_window``'s ``core`` once its
+    first three arguments are bound; ``caches`` is ``(k_cache, v_cache,
+    state)``). The layers are walked unrolled: each Mamba
     layer's state is a buffer of its own, rewritten whole and in place, and
     a static slice of the stacked kernels folds into its matmul."""
-    from distllm_tpu.ops.paged_attention import (
-        paged_attention_xla,
-        ragged_paged_attention_pallas,
-        write_token_kv,
-    )
+    from distllm_tpu.ops.paged_attention import decode_attention, write_token_kv
 
+    k_cache, v_cache, state = caches
     x = _embed(params, cfg, input_ids)  # [B, H]
     ssms, convs = list(state['ssm']), list(state['conv'])
     pairs = jnp.zeros((2,), jnp.int32)
@@ -748,10 +714,7 @@ def _decode_core(
     for kind in cfg.layer_types:
         i = seen[kind]
         seen[kind] += 1
-        lp = jax.tree.map(
-            lambda a: a[i],
-            {n: leaf for n, leaf in params[kind].items() if n not in _BANKS},
-        )
+        lp = common.layer_at(params[kind], i, skip=_BANKS)
         normed = _norm(x, lp['ln']['scale'], cfg)
         if kind == 'mamba':
             mixed, ssms[i], convs[i] = mamba_step(
@@ -762,18 +725,10 @@ def _decode_core(
             k_cache, v_cache = write_token_kv(
                 k_cache, v_cache, k, v, block_tables, positions, layer=i
             )
-            if attn_backend == 'xla':
-                attn = paged_attention_xla(
-                    q, k_cache, v_cache, block_tables, context_lens,
-                    scale=cfg.attention_multiplier, layer=i,
-                )
-            else:
-                attn = ragged_paged_attention_pallas(
-                    q[:, None], k_cache, v_cache, block_tables, context_lens,
-                    q_positions=positions[:, None],
-                    scale=cfg.attention_multiplier,
-                    interpret=attn_backend == 'interpret', layer=i,
-                )[:, 0]
+            attn = decode_attention(
+                q, k_cache, v_cache, block_tables, context_lens, positions,
+                backend=attn_backend, scale=cfg.attention_multiplier, layer=i,
+            )
             mixed = _attn_out(attn, lp, cfg)
         x, layer_pairs = _finish_layer(
             x, mixed, lp, cfg, live, params[kind], i
@@ -781,7 +736,7 @@ def _decode_core(
         pairs = pairs + layer_pairs
     hidden = _norm(x, params['final_ln']['scale'], cfg)
     state = {'ssm': tuple(ssms), 'conv': tuple(convs)}
-    return logits(params, cfg, hidden), k_cache, v_cache, state, pairs
+    return logits(params, cfg, hidden), (k_cache, v_cache, state), pairs
 
 
 def decode_loop(  # distlint: traced
@@ -803,7 +758,6 @@ def decode_loop(  # distlint: traced
     attn_backend: str = 'xla',
     max_table_positions: int | None = None,
     sampling_top_window: int = 0,
-    layer_unroll: bool = True,
     *,
     state: dict,
 ):
@@ -813,40 +767,13 @@ def decode_loop(  # distlint: traced
     block and leaves its state as it is. Returns ``(tokens [num_steps, B],
     k_cache, v_cache, last_ids, state, moe_pairs [2])``, the last being the
     window's (routed, held) pair counts over the rows and steps that ran."""
-    from distllm_tpu.ops.sampling import fold_row_keys, sample_tokens
-
-    del max_table_positions, layer_unroll  # no rope table; always unrolled
-
-    def body(carry, _):
-        ids, pos, ctx, k_cache, v_cache, state, live_steps, pairs = carry
-        live = live_steps > 0
-        bt_eff = jnp.where(live[:, None], block_tables, 0)
-        logits_, k_cache, v_cache, state, step_pairs = _decode_core(
-            params, cfg, ids, pos, k_cache, v_cache, bt_eff, ctx, state,
-            live, attn_backend,
-        )
-        token = sample_tokens(
-            logits_, None, temperature, top_p, min_p,
-            top_window=sampling_top_window, top_k=top_k,
-            row_keys=fold_row_keys(seeds, pos + 1),
-        )
-        ids = jnp.where(live, token, ids)
-        pos = jnp.where(live, pos + 1, pos)
-        ctx = jnp.where(live, ctx + 1, ctx)
-        carry = (
-            ids, pos, ctx, k_cache, v_cache, state, live_steps - 1,
-            pairs + step_pairs,
-        )
-        return carry, token
-
-    (ids, _, _, k_cache, v_cache, state, _, pairs), tokens = jax.lax.scan(
-        body,
-        (
-            input_ids, positions, context_lens, k_cache, v_cache, state,
-            steps_left.astype(jnp.int32), jnp.zeros((2,), jnp.int32),
-        ),
-        None,
-        length=num_steps,
+    del max_table_positions  # no rotation: no table of positions
+    tokens, (k_cache, v_cache, state), ids, pairs = common.decode_window(
+        functools.partial(_decode_core, params, cfg, attn_backend),
+        input_ids, positions, context_lens, (k_cache, v_cache, state),
+        block_tables, steps_left, temperature, top_p, min_p, top_k, seeds,
+        num_steps=num_steps, sampling_top_window=sampling_top_window,
+        counts=jnp.zeros((2,), jnp.int32),
     )
     return tokens, k_cache, v_cache, ids, state, pairs
 
@@ -863,12 +790,9 @@ def decode_loop(  # distlint: traced
 # each group's channels apart) and ``ssm_multipliers`` (five factors for the
 # in-projection's parts ``z, x, B, C, dt``, applied before the convolution). A
 # config that names neither has one group and no factors, and then each function
-# traces the very operations it traced before it learnt the rest: the group
-# count is static and each part branches on it. What the rest needs is defined
-# HERE, below every function of this family, and the block above kept its line
-# count: the paged kernel's lowered text carries the line numbers of its callers
-# (``prefill_paged``, ``_decode_core``), so nothing above them may move if this
-# family's programs are to stay the text they were (tests/test_aot_tpu.py).
+# traces the very operations it traced before it learnt the rest
+# (tests/test_falcon_h1.py pins this family's lowered text): the group count is
+# static and each part branches on it.
 def _groups(cfg) -> int:
     return getattr(cfg, 'mamba_n_groups', 1)
 

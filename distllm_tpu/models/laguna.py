@@ -10,7 +10,7 @@ Four stacked parameter trees: two for attention (``params['full']``,
 ``o``) and two for the MLP (``params['dense']``, ``params['sparse']``). The
 dense forward walks ``cfg.layer_runs()`` and scans each run of equal layers;
 the serving programs walk the layers unrolled, with static indices, each
-layer a call of one jitted function a kind of layer (``_once_a_kind``).
+layer a call of one jitted function a kind of layer (``common.once_a_kind``).
 
 A sequence holds TWO kinds of K/V pages (``cfg.cache_spec()``): blocks of
 the full layers' pool for its whole context, and blocks of the window
@@ -53,11 +53,11 @@ names cannot be read here, and a guessed converter would be worse than none.
 
 from __future__ import annotations
 
+import functools
 from typing import Literal
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 from distllm_tpu.models import common
 from distllm_tpu.models.moe import routed_experts
@@ -142,6 +142,10 @@ class LagunaConfig(BaseConfig):
             seen[attn] += 1
             seen[mlp] += 1
         return [tuple(r) for r in runs]
+
+    def layer_kinds(self) -> list[tuple[str, str]]:
+        """``(attention kind, MLP kind)`` of every layer."""
+        return [(attn, mlp) for attn, mlp, _, _ in self.layer_indices()]
 
     def layer_indices(self) -> list[tuple[str, str, int, int]]:
         """``(attention kind, MLP kind, index in the attention tree, index
@@ -292,61 +296,26 @@ def _top_shapes(cfg: LagunaConfig) -> dict:
     }
 
 
+def _trees(cfg: LagunaConfig) -> dict:
+    return common.tree_table(
+        _TREES, cfg.count, lambda kind: _tree_shapes(cfg, kind)
+    )
+
+
 def init_on_device(rng: jax.Array, cfg: LagunaConfig) -> dict:
     """Random parameters made on the device in ``cfg.dtype``: normal(0,
     0.02) kernels, unit norm scales, one RNG call per parameter kind."""
-    dtype = jnp.dtype(cfg.dtype)
-    trees = [
-        (ti, kind, cfg.count(kind)) for ti, kind in enumerate(_TREES)
-        if cfg.count(kind)
-    ]
-
-    @jax.jit
-    def build(key):
-        def normal(key, shape):
-            return (jax.random.normal(key, shape, F32) * 0.02).astype(dtype)
-
-        params = {
-            name: normal(jax.random.fold_in(key, i), shape)
-            for i, (name, shape) in enumerate(_top_shapes(cfg).items())
-        }
-        params['final_ln'] = {'scale': jnp.ones((cfg.hidden_size,), dtype)}
-        for ti, kind, count in trees:
-            tkey = jax.random.fold_in(key, 8 + ti)
-            params[kind] = {
-                name: _wrap(
-                    name,
-                    jnp.ones((count, *shape), dtype) if name in _SCALES
-                    else normal(jax.random.fold_in(tkey, ni), (count, *shape)),
-                )
-                for ni, (name, shape) in enumerate(
-                    sorted(_tree_shapes(cfg, kind).items())
-                )
-            }
-        return params
-
-    return build(rng)
+    return common.seeded_tree(
+        rng, cfg.dtype, cfg.hidden_size, _top_shapes(cfg), _trees(cfg), _wrap,
+        _SCALES,
+    )
 
 
 def param_specs(cfg: LagunaConfig, params: dict | None = None) -> dict:
     """Expert banks over ``expert``, everything else replicated."""
-    specs = {
-        'embed': P(None, None), 'lm_head': P(None, None),
-        'final_ln': {'scale': P()},
-    }
-    for kind in _TREES:
-        if not cfg.count(kind):
-            continue
-        specs[kind] = {
-            name: _wrap(
-                name,
-                P(None, 'expert', None, None)
-                if kind == 'sparse' and name in _BANKS
-                else P(*(None,) * (len(shape) + 1)),
-            )
-            for name, shape in _tree_shapes(cfg, kind).items()
-        }
-    return specs
+    return common.tree_specs(
+        _top_shapes(cfg), _trees(cfg), _wrap, [('sparse', n) for n in _BANKS]
+    )
 
 
 def params_from_hf(state: dict, cfg: LagunaConfig) -> dict:
@@ -360,10 +329,6 @@ def params_from_hf(state: dict, cfg: LagunaConfig) -> dict:
 # ------------------------------------------------------------ shared parts
 def _norm(x, scale, cfg):
     return common.rms_norm(x, scale, cfg.rms_norm_eps)
-
-
-def _embed(params, cfg, input_ids):
-    return jnp.asarray(params['embed'])[input_ids].astype(jnp.dtype(cfg.dtype))
 
 
 def _rope_tables(cfg: LagunaConfig, max_len: int) -> dict:
@@ -414,23 +379,13 @@ def _attn_out(attn, normed, lp, cfg, kind):
     )
 
 
-def _swiglu(x, gate, up, down):
-    return common.dense(
-        common.silu(common.dense(x, gate)) * common.dense(x, up), down
-    )
-
-
 def _mlp(x, mp, cfg, mlp_kind, counted, banks, mi):
     """The MLP block of one layer for ``x [T, H]`` (already normed);
     returns it and the layer's (routed, held) pair counts. ``banks`` is
     the sparse tree: the expert banks stay stacked, ``mi`` picks the layer
     inside the expert matmuls (``models/moe.py``)."""
     if mlp_kind == 'dense':
-        with jax.named_scope('distllm.dense_mlp'):
-            out = _swiglu(
-                x, mp['gate']['kernel'], mp['up']['kernel'], mp['down']['kernel']
-            )
-        return out, jnp.zeros((2,), jnp.int32)
+        return common.dense_mlp(x, mp), jnp.zeros((2,), jnp.int32)
     routed, pairs = routed_experts(
         x, mp['router']['kernel'], *(banks[n]['kernel'] for n in _BANKS),
         cfg.experts_per_token, first_expert=cfg.first_local_expert,
@@ -439,7 +394,7 @@ def _mlp(x, mp, cfg, mlp_kind, counted, banks, mi):
     # The shared expert: every chip of the expert axis computes it alike,
     # so it is counted once, here, whatever share of the bank is held.
     with jax.named_scope('distllm.moe'):
-        shared = _swiglu(
+        shared = common.swiglu(
             x, mp['shared_gate']['kernel'], mp['shared_up']['kernel'],
             mp['shared_down']['kernel'],
         )
@@ -448,13 +403,11 @@ def _mlp(x, mp, cfg, mlp_kind, counted, banks, mi):
 
 def _finish_layer(x, mixed, mp, cfg, mlp_kind, counted, banks, mi):
     """Residual of the attention output, then the MLP block."""
-    x = x + mixed
-    normed = _norm(x, mp['mlp_ln']['scale'], cfg)
-    mlp, pairs = _mlp(
-        normed.reshape(-1, normed.shape[-1]), mp, cfg, mlp_kind,
-        counted.reshape(-1), banks, mi,
+    return common.finish_layer(
+        x, mixed, mp, cfg.rms_norm_eps,
+        lambda rows, of_rows: _mlp(rows, mp, cfg, mlp_kind, of_rows, banks, mi),
+        counted,
     )
-    return x + mlp.reshape(x.shape), pairs
 
 
 def logits(params: dict, cfg: LagunaConfig, hidden: jnp.ndarray) -> jnp.ndarray:  # distlint: traced
@@ -463,21 +416,8 @@ def logits(params: dict, cfg: LagunaConfig, hidden: jnp.ndarray) -> jnp.ndarray:
     return common.dense(hidden, params['lm_head']).astype(F32)
 
 
-def _layer_at(tree, i, skip=()):
-    """Layer ``i`` of a stacked tree, without the leaves ``skip`` names:
-    the sparse tree's expert banks (a slice of those would be a copy of the
-    layer's whole bank)."""
-    if isinstance(i, int):
-        pick = lambda a: a[i]  # noqa: E731
-    else:
-        pick = lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)  # noqa: E731
-    return jax.tree.map(
-        pick, {n: leaf for n, leaf in tree.items() if n not in skip}
-    )
-
-
 def _mlp_layer_at(params, mlp_kind, mi):
-    return _layer_at(
+    return common.layer_at(
         params[mlp_kind], mi, skip=_BANKS if mlp_kind == 'sparse' else ()
     )
 
@@ -496,26 +436,6 @@ def _run_xs(first_attn, first_mlp, count):
         jnp.arange(first_attn, first_attn + count, dtype=jnp.int32),
         jnp.arange(first_mlp, first_mlp + count, dtype=jnp.int32),
     )
-
-
-def _once_a_kind(layer, cfg: LagunaConfig) -> dict:
-    """``(attention kind, MLP kind) -> layer(attn_kind, mlp_kind, *arrays)``
-    as one jitted function a pair of kinds. The serving programs walk their
-    layers unrolled (a buffer a layer), but the layers of a kind have one
-    shape: called through this, a kind is traced and lowered once and the
-    program calls it a layer (XLA inlines the calls). Unrolled text was ten
-    seconds of Python a program before the compiler saw it."""
-
-    def jitted(attn_kind, mlp_kind):
-        def laguna_layer(*arrays):
-            return layer(attn_kind, mlp_kind, *arrays)
-
-        return jax.jit(laguna_layer)
-
-    return {
-        kinds: jitted(*kinds)
-        for kinds in {(a, m) for a, m, _, _ in cfg.layer_indices()}
-    }
 
 
 # ----------------------------------------------------------------- forwards
@@ -537,12 +457,12 @@ def apply(  # distlint: traced
     }
     positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
     rope = _rope_tables(cfg, s)
-    x = _embed(params, cfg, input_ids)
+    x = common.embed(params, cfg.dtype, input_ids)
     for attn_kind, mlp_kind, first_a, first_m, count in cfg.layer_runs():
 
         def layer(x, xs, attn_kind=attn_kind, mlp_kind=mlp_kind):
             ai, mi = xs
-            lp = _layer_at(params[attn_kind], ai)
+            lp = common.layer_at(params[attn_kind], ai)
             mp = _mlp_layer_at(params, mlp_kind, mi)
             normed = _norm(x, lp['ln']['scale'], cfg)
             q, k, v = _qkv(normed, lp, cfg, attn_kind)
@@ -593,7 +513,7 @@ def prefill_paged(  # distlint: traced
     pools = {
         g: [list(k), list(v)] for g, k, v in zip(_GROUPS, k_cache, v_cache)
     }
-    x = _embed(params, cfg, input_ids)
+    x = common.embed(params, cfg.dtype, input_ids)
 
     def layer(attn_kind, mlp_kind, x, lp, mp, banks, mi, k_buf, v_buf, table,
               cos, sin, positions, valid, context_lens, tail_lens):
@@ -616,39 +536,34 @@ def prefill_paged(  # distlint: traced
         )
         return x, k_buf, v_buf
 
-    layer_of = _once_a_kind(layer, cfg)
+    layer_of = common.once_a_kind(layer, cfg.layer_kinds(), 'laguna_layer')
     for attn_kind, mlp_kind, ai, mi in cfg.layer_indices():
         k_pool, v_pool = pools[attn_kind]
         x, k_pool[ai], v_pool[ai] = layer_of[attn_kind, mlp_kind](
-            x, _layer_at(params[attn_kind], ai),
+            x, common.layer_at(params[attn_kind], ai),
             _mlp_layer_at(params, mlp_kind, mi), params.get('sparse'),
             jnp.int32(mi), k_pool[ai], v_pool[ai], tables[attn_kind],
             *rope[attn_kind][:2], positions, valid, context_lens, tail_lens,
         )
     hidden = _norm(x, params['final_ln']['scale'], cfg)
-    last_idx = jnp.maximum(tail_lens - 1, 0)
-    last_hidden = jnp.take_along_axis(hidden, last_idx[:, None, None], axis=1)
+    last_hidden = common.last_token(hidden, tail_lens)
     return logits(params, cfg, last_hidden)[:, 0], *_pools_out(pools)
 
 
 def _decode_core(
-    params, cfg, input_ids, positions, k_cache, v_cache, block_tables,
-    context_lens, live, rope, attn_backend,
+    params, cfg, rope, attn_backend, input_ids, positions, context_lens,
+    caches, block_tables, live,
 ):
-    """One token of every row. The layers are walked unrolled, each with
-    static indices: a static slice of the stacked kernels folds into its
-    matmul, and a layer's K and V buffers are written in place."""
-    from distllm_tpu.ops.paged_attention import (
-        paged_attention_xla,
-        ragged_paged_attention_pallas,
-        write_token_kv,
-    )
+    """One token of every row (``common.decode_window``'s ``core`` once its
+    first four arguments are bound; ``caches`` is ``(k_cache, v_cache)``).
+    The layers are walked unrolled, each with static indices: a static slice
+    of the stacked kernels folds into its matmul, and a layer's K and V
+    buffers are written in place."""
+    from distllm_tpu.ops.paged_attention import decode_attention, write_token_kv
 
-    x = _embed(params, cfg, input_ids)  # [B, H]
+    x = common.embed(params, cfg.dtype, input_ids)  # [B, H]
     tables = dict(zip(_GROUPS, block_tables))
-    pools = {
-        g: [list(k), list(v)] for g, k, v in zip(_GROUPS, k_cache, v_cache)
-    }
+    pools = {g: [list(k), list(v)] for g, k, v in zip(_GROUPS, *caches)}
     pairs = jnp.zeros((2,), jnp.int32)
 
     def layer(attn_kind, mlp_kind, x, lp, mp, banks, mi, k_buf, v_buf, table,
@@ -660,36 +575,28 @@ def _decode_core(
         k = _rope(k[:, None], table_of_kind, positions[:, None])[:, 0]
         with jax.named_scope(f'distllm.attn_{attn_kind}'):
             k_buf, v_buf = write_token_kv(k_buf, v_buf, k, v, table, positions)
-            if attn_backend == 'xla':
-                attn = paged_attention_xla(
-                    q, k_buf, v_buf, table, context_lens,
-                    sliding_window=cfg.window(attn_kind),
-                )
-            else:
-                attn = ragged_paged_attention_pallas(
-                    q[:, None], k_buf, v_buf, table, context_lens,
-                    q_positions=positions[:, None],
-                    sliding_window=cfg.window(attn_kind),
-                    interpret=attn_backend == 'interpret',
-                )[:, 0]
+            attn = decode_attention(
+                q, k_buf, v_buf, table, context_lens, positions,
+                backend=attn_backend, sliding_window=cfg.window(attn_kind),
+            )
         x, layer_pairs = _finish_layer(
             x, _attn_out(attn, normed, lp, cfg, attn_kind), mp, cfg, mlp_kind,
             live, banks, mi,
         )
         return x, k_buf, v_buf, layer_pairs
 
-    layer_of = _once_a_kind(layer, cfg)
+    layer_of = common.once_a_kind(layer, cfg.layer_kinds(), 'laguna_layer')
     for attn_kind, mlp_kind, ai, mi in cfg.layer_indices():
         k_pool, v_pool = pools[attn_kind]
         x, k_pool[ai], v_pool[ai], layer_pairs = layer_of[attn_kind, mlp_kind](
-            x, _layer_at(params[attn_kind], ai),
+            x, common.layer_at(params[attn_kind], ai),
             _mlp_layer_at(params, mlp_kind, mi), params.get('sparse'),
             jnp.int32(mi), k_pool[ai], v_pool[ai], tables[attn_kind],
             *rope[attn_kind][:2], positions, context_lens, live,
         )
         pairs = pairs + layer_pairs
     hidden = _norm(x, params['final_ln']['scale'], cfg)
-    return logits(params, cfg, hidden), *_pools_out(pools), pairs
+    return logits(params, cfg, hidden), _pools_out(pools), pairs
 
 
 def decode_loop(  # distlint: traced
@@ -711,49 +618,19 @@ def decode_loop(  # distlint: traced
     attn_backend: str = 'xla',
     max_table_positions: int | None = None,
     sampling_top_window: int = 0,
-    layer_unroll: bool = True,
 ):
     """``mistral.decode_loop``'s contract over the two cache groups. A row
     out of budget writes its K/V to the trash block of both pools. Returns
     ``(tokens [num_steps, B], k_cache, v_cache, last_ids, moe_pairs [2])``,
     the last being the window's (routed, held) pair counts over the rows
     and steps that ran."""
-    from distllm_tpu.ops.sampling import fold_row_keys, sample_tokens
-
-    del layer_unroll  # always unrolled
     rope = _rope_tables(cfg, max_table_positions or cfg.max_position_embeddings)
-
-    def body(carry, _):
-        ids, pos, ctx, k_cache, v_cache, live_steps, pairs = carry
-        live = live_steps > 0
-        bt_eff = tuple(jnp.where(live[:, None], bt, 0) for bt in block_tables)
-        logits_, k_cache, v_cache, step_pairs = _decode_core(
-            params, cfg, ids, pos, k_cache, v_cache, bt_eff, ctx, live, rope,
-            attn_backend,
-        )
-        token = sample_tokens(
-            logits_, None, temperature, top_p, min_p,
-            top_window=sampling_top_window, top_k=top_k,
-            row_keys=fold_row_keys(seeds, pos + 1),
-        )
-        ids = jnp.where(live, token, ids)
-        pos = jnp.where(live, pos + 1, pos)
-        ctx = jnp.where(live, ctx + 1, ctx)
-        carry = (
-            ids, pos, ctx, k_cache, v_cache, live_steps - 1,
-            pairs + step_pairs,
-        )
-        return carry, token
-
-    (ids, _, _, k_cache, v_cache, _, pairs), tokens = jax.lax.scan(
-        body,
-        (
-            input_ids, positions, context_lens,
-            tuple(tuple(k) for k in k_cache), tuple(tuple(v) for v in v_cache),
-            steps_left.astype(jnp.int32),
-            jnp.zeros((2,), jnp.int32),
-        ),
-        None,
-        length=num_steps,
+    tokens, (k_cache, v_cache), ids, pairs = common.decode_window(
+        functools.partial(_decode_core, params, cfg, rope, attn_backend),
+        input_ids, positions, context_lens,
+        (tuple(tuple(k) for k in k_cache), tuple(tuple(v) for v in v_cache)),
+        block_tables, steps_left, temperature, top_p, min_p, top_k, seeds,
+        num_steps=num_steps, sampling_top_window=sampling_top_window,
+        counts=jnp.zeros((2,), jnp.int32),
     )
     return tokens, k_cache, v_cache, ids, pairs

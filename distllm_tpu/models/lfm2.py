@@ -7,7 +7,7 @@ bias and no shared expert; the output head is the embedding.
 ``cfg.layer_types`` says which mixer a layer has and is not periodic, so the
 serving programs walk the layers unrolled and call one jitted function a
 KIND of layer (``(mixer, mlp)``: three kinds as published), which is traced
-and lowered once (``_once_a_kind``).
+and lowered once (``common.once_a_kind``).
 
 A sequence holds two things. For every attention layer K/V pages, one
 full-context paged group over ``cfg.num_paged_layers`` layers whose rows are
@@ -44,11 +44,11 @@ yet.
 
 from __future__ import annotations
 
+import functools
 from typing import Literal
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 from distllm_tpu.models import common
 from distllm_tpu.models.moe import routed_experts
@@ -227,6 +227,16 @@ def _wrap(name: str, leaf):
     return {{'conv': 'taps', 'router_bias': 'bias'}.get(name, 'kernel'): leaf}
 
 
+def _trees(cfg: Lfm2MoeConfig) -> dict:
+    return common.tree_table(
+        _TREES, cfg.count, lambda kind: _tree_shapes(cfg, kind)
+    )
+
+
+def _top_shapes(cfg: Lfm2MoeConfig) -> dict:
+    return {'embed': (cfg.vocab_size, cfg.hidden_size)}
+
+
 def init_on_device(rng: jax.Array, cfg: Lfm2MoeConfig) -> dict:
     """Random parameters made on the device in ``cfg.dtype``: normal(0,
     0.02) kernels and embedding, unit norm scales, taps normal(0, 1 /
@@ -234,63 +244,25 @@ def init_on_device(rng: jax.Array, cfg: Lfm2MoeConfig) -> dict:
     router's selection bias normal(0, 0.05) in float32 (a zero buffer in the
     published code before training; at 0.05 it changes the kept set of most
     tokens), one RNG call per parameter kind."""
-    dtype = jnp.dtype(cfg.dtype)
-    trees = [
-        (ti, kind, cfg.count(kind)) for ti, kind in enumerate(_TREES)
-        if cfg.count(kind)
-    ]
 
-    @jax.jit
-    def build(key):
-        def normal(key, shape, scale=0.02, dtype=dtype):
-            return (jax.random.normal(key, shape, F32) * scale).astype(dtype)
+    def leaf(name, key, shape, normal):
+        if name == 'conv':
+            return normal(key, shape, cfg.conv_L_cache ** -0.5)
+        if name == 'router_bias':
+            return normal(key, shape, 0.05, F32)
+        return None
 
-        def leaf(key, name, shape):
-            if name in _SCALES:
-                return jnp.ones(shape, dtype)
-            if name == 'conv':
-                return normal(key, shape, cfg.conv_L_cache ** -0.5)
-            if name == 'router_bias':
-                return normal(key, shape, 0.05, F32)
-            return normal(key, shape)
-
-        params = {
-            'embed': normal(
-                jax.random.fold_in(key, 0), (cfg.vocab_size, cfg.hidden_size)
-            ),
-            'final_ln': {'scale': jnp.ones((cfg.hidden_size,), dtype)},
-        }
-        for ti, kind, count in trees:
-            tkey = jax.random.fold_in(key, 8 + ti)
-            params[kind] = {
-                name: _wrap(
-                    name, leaf(jax.random.fold_in(tkey, ni), name, (count, *shape))
-                )
-                for ni, (name, shape) in enumerate(
-                    sorted(_tree_shapes(cfg, kind).items())
-                )
-            }
-        return params
-
-    return build(rng)
+    return common.seeded_tree(
+        rng, cfg.dtype, cfg.hidden_size, _top_shapes(cfg), _trees(cfg), _wrap,
+        _SCALES, leaf,
+    )
 
 
 def param_specs(cfg: Lfm2MoeConfig, params: dict | None = None) -> dict:
     """Expert banks over ``expert``, everything else replicated."""
-    specs = {'embed': P(None, None), 'final_ln': {'scale': P()}}
-    for kind in _TREES:
-        if not cfg.count(kind):
-            continue
-        specs[kind] = {
-            name: _wrap(
-                name,
-                P(None, 'expert', None, None)
-                if kind == 'sparse' and name in _BANKS
-                else P(*(None,) * (len(shape) + 1)),
-            )
-            for name, shape in _tree_shapes(cfg, kind).items()
-        }
-    return specs
+    return common.tree_specs(
+        _top_shapes(cfg), _trees(cfg), _wrap, [('sparse', n) for n in _BANKS]
+    )
 
 
 def params_from_hf(state: dict, cfg: Lfm2MoeConfig) -> dict:
@@ -305,10 +277,6 @@ def params_from_hf(state: dict, cfg: Lfm2MoeConfig) -> dict:
 # ------------------------------------------------------------ shared parts
 def _norm(x, scale, cfg):
     return common.rms_norm(x, scale, cfg.norm_eps)
-
-
-def _embed(params, cfg, input_ids):
-    return jnp.asarray(params['embed'])[input_ids].astype(jnp.dtype(cfg.dtype))
 
 
 def _rope_tables(cfg: Lfm2MoeConfig, max_len: int):
@@ -380,23 +348,13 @@ def _attn_out(attn, lp, cfg):
     )
 
 
-def _swiglu(x, gate, up, down):
-    return common.dense(
-        common.silu(common.dense(x, gate)) * common.dense(x, up), down
-    )
-
-
 def _mlp(x, mp, cfg, mlp_kind, counted, banks, mi):
     """The MLP block of one layer for ``x [T, hidden]`` (already normed);
     returns it and the layer's (routed, held) pair counts. ``banks`` is the
     sparse tree: the expert banks stay stacked, ``mi`` picks the layer
     inside the expert matmuls (``models/moe.py``). No shared expert."""
     if mlp_kind == 'dense':
-        with jax.named_scope('distllm.dense_mlp'):
-            out = _swiglu(
-                x, mp['gate']['kernel'], mp['up']['kernel'], mp['down']['kernel']
-            )
-        return out, jnp.zeros((2,), jnp.int32)
+        return common.dense_mlp(x, mp), jnp.zeros((2,), jnp.int32)
     return routed_experts(
         x, mp['router']['kernel'], *(banks[n]['kernel'] for n in _BANKS),
         cfg.experts_per_token, first_expert=cfg.first_local_expert,
@@ -408,13 +366,11 @@ def _mlp(x, mp, cfg, mlp_kind, counted, banks, mi):
 
 def _finish_layer(x, mixed, mp, cfg, mlp_kind, counted, banks, mi):
     """Residual of the mixer's output, then the MLP block."""
-    x = x + mixed
-    normed = _norm(x, mp['mlp_ln']['scale'], cfg)
-    mlp, pairs = _mlp(
-        normed.reshape(-1, normed.shape[-1]), mp, cfg, mlp_kind,
-        counted.reshape(-1), banks, mi,
+    return common.finish_layer(
+        x, mixed, mp, cfg.norm_eps,
+        lambda rows, of_rows: _mlp(rows, mp, cfg, mlp_kind, of_rows, banks, mi),
+        counted,
     )
-    return x + mlp.reshape(x.shape), pairs
 
 
 def logits(params: dict, cfg: Lfm2MoeConfig, hidden: jnp.ndarray) -> jnp.ndarray:  # distlint: traced
@@ -422,38 +378,23 @@ def logits(params: dict, cfg: Lfm2MoeConfig, hidden: jnp.ndarray) -> jnp.ndarray
     return common.dense(hidden, jnp.asarray(params['embed']).T).astype(F32)
 
 
-def _layer_at(tree, i, skip=()):
-    """Layer ``i`` (static) of a stacked tree, without the leaves ``skip``
-    names: the sparse tree's expert banks (a slice of those would be a copy
-    of the layer's whole bank)."""
-    return jax.tree.map(
-        lambda a: a[i], {n: leaf for n, leaf in tree.items() if n not in skip}
-    )
-
-
 def _layer_params(params, mixer, xi, mlp_kind, mi):
     return (
-        _layer_at(params[mixer], xi),
-        _layer_at(params[mlp_kind], mi, skip=_BANKS if mlp_kind == 'sparse' else ()),
+        common.layer_at(params[mixer], xi),
+        common.layer_at(
+            params[mlp_kind], mi, skip=_BANKS if mlp_kind == 'sparse' else ()
+        ),
     )
 
 
-def _once_a_kind(layers: dict, cfg: Lfm2MoeConfig) -> dict:
-    """``(mixer, MLP kind) -> layers[mixer](mlp_kind, *arrays)`` as one
-    jitted function a kind of layer. The serving programs walk their layers
-    unrolled (a state buffer a conv layer, a static index into the stacked
-    kernels), but the layers of a kind have one shape: called through this,
-    a kind is traced and lowered once and the program calls it a layer."""
-
-    def jitted(mixer, mlp_kind):
-        def lfm2_layer(*arrays):
-            return layers[mixer](mlp_kind, *arrays)
-
-        lfm2_layer.__name__ = f'lfm2_{mixer}_{mlp_kind}_layer'
-        return jax.jit(lfm2_layer)
-
-    kinds = {(mixer, mlp) for mixer, _, mlp, _ in cfg.layer_indices()}
-    return {kind: jitted(*kind) for kind in sorted(kinds)}
+def _layer_fns(layers: dict, cfg: Lfm2MoeConfig) -> dict:
+    """``(mixer, MLP kind) -> layers[mixer](mlp_kind, *arrays)``, jitted
+    once a kind of layer."""
+    return common.once_a_kind(
+        lambda mixer, *rest: layers[mixer](*rest),
+        [(mixer, mlp) for mixer, _, mlp, _ in cfg.layer_indices()],
+        'lfm2_{}_{}_layer',
+    )
 
 
 # ----------------------------------------------------------------- forwards
@@ -471,7 +412,7 @@ def apply(  # distlint: traced
     valid = attention_mask.astype(bool)
     mask = common.causal_mask(s, s)[None, None] & valid[:, None, None, :]
     cos, sin = _rope_tables(cfg, s)
-    x = _embed(params, cfg, input_ids)
+    x = common.embed(params, cfg.dtype, input_ids)
     conv0 = jnp.zeros((b, cfg.conv_L_cache - 1, cfg.hidden_size), x.dtype)
     for mixer, xi, mlp_kind, mi in cfg.layer_indices():
         lp, mp = _layer_params(params, mixer, xi, mlp_kind, mi)
@@ -519,7 +460,7 @@ def prefill_paged(  # distlint: traced
     fresh = positions[:, 0] == 0
     cos, sin = _rope_tables(cfg, max_table_positions or cfg.max_position_embeddings)
     convs = list(state['conv'])
-    x = _embed(params, cfg, input_ids)
+    x = common.embed(params, cfg.dtype, input_ids)
 
     def conv_layer(mlp_kind, x, lp, mp, banks, mi, pool, slots, fresh,
                    tail_lens, valid):
@@ -551,7 +492,7 @@ def prefill_paged(  # distlint: traced
         )
         return x, k_cache, v_cache
 
-    layer_of = _once_a_kind({'conv': conv_layer, 'attn': attn_layer}, cfg)
+    layer_of = _layer_fns({'conv': conv_layer, 'attn': attn_layer}, cfg)
     for mixer, xi, mlp_kind, mi in cfg.layer_indices():
         shared = (
             x, *_layer_params(params, mixer, xi, mlp_kind, mi),
@@ -567,27 +508,25 @@ def prefill_paged(  # distlint: traced
                 sin, positions, valid, context_lens, tail_lens,
             )
     hidden = _norm(x, params['final_ln']['scale'], cfg)
-    last_idx = jnp.maximum(tail_lens - 1, 0)
-    last_hidden = jnp.take_along_axis(hidden, last_idx[:, None, None], axis=1)
+    last_hidden = common.last_token(hidden, tail_lens)
     state = {'conv': tuple(convs)}
     return logits(params, cfg, last_hidden)[:, 0], k_cache, v_cache, state
 
 
 def _decode_core(
-    params, cfg, input_ids, positions, k_cache, v_cache, block_tables,
-    context_lens, state, live, rope, attn_backend,
+    params, cfg, rope, attn_backend, input_ids, positions, context_lens,
+    caches, block_tables, live,
 ):
-    """One token of every row. The layers are walked unrolled: each conv
+    """One token of every row (``common.decode_window``'s ``core`` once its
+    first four arguments are bound; ``caches`` is ``(k_cache, v_cache,
+    state)``). The layers are walked unrolled: each conv
     layer's state is a buffer of its own, rewritten whole and in place
     (row ``i`` of the batch is slot ``i``), and a static slice of the
     stacked kernels folds into its matmul."""
-    from distllm_tpu.ops.paged_attention import (
-        paged_attention_xla,
-        ragged_paged_attention_pallas,
-        write_token_kv,
-    )
+    from distllm_tpu.ops.paged_attention import decode_attention, write_token_kv
 
-    x = _embed(params, cfg, input_ids)  # [B, hidden]
+    k_cache, v_cache, state = caches
+    x = common.embed(params, cfg.dtype, input_ids)  # [B, hidden]
     convs = list(state['conv'])
     pairs = jnp.zeros((2,), jnp.int32)
 
@@ -610,22 +549,16 @@ def _decode_core(
             k_cache, v_cache = write_token_kv(
                 k_cache, v_cache, k[:, 0], v[:, 0], table, positions, layer=li
             )
-            if attn_backend == 'xla':
-                attn = paged_attention_xla(
-                    q[:, 0], k_cache, v_cache, table, context_lens, layer=li
-                )
-            else:
-                attn = ragged_paged_attention_pallas(
-                    q, k_cache, v_cache, table, context_lens,
-                    q_positions=positions[:, None],
-                    interpret=attn_backend == 'interpret', layer=li,
-                )[:, 0]
+            attn = decode_attention(
+                q[:, 0], k_cache, v_cache, table, context_lens, positions,
+                backend=attn_backend, layer=li,
+            )
         x, layer_pairs = _finish_layer(
             x, _attn_out(attn, lp, cfg), mp, cfg, mlp_kind, live, banks, mi
         )
         return x, k_cache, v_cache, layer_pairs
 
-    layer_of = _once_a_kind({'conv': conv_layer, 'attn': attn_layer}, cfg)
+    layer_of = _layer_fns({'conv': conv_layer, 'attn': attn_layer}, cfg)
     for mixer, xi, mlp_kind, mi in cfg.layer_indices():
         shared = (
             x, *_layer_params(params, mixer, xi, mlp_kind, mi),
@@ -643,7 +576,7 @@ def _decode_core(
         pairs = pairs + layer_pairs
     hidden = _norm(x, params['final_ln']['scale'], cfg)
     state = {'conv': tuple(convs)}
-    return logits(params, cfg, hidden), k_cache, v_cache, state, pairs
+    return logits(params, cfg, hidden), (k_cache, v_cache, state), pairs
 
 
 def decode_loop(  # distlint: traced
@@ -665,7 +598,6 @@ def decode_loop(  # distlint: traced
     attn_backend: str = 'xla',
     max_table_positions: int | None = None,
     sampling_top_window: int = 0,
-    layer_unroll: bool = True,
     *,
     state: dict,
 ):
@@ -675,40 +607,12 @@ def decode_loop(  # distlint: traced
     block and leaves its state as it is. Returns ``(tokens [num_steps, B],
     k_cache, v_cache, last_ids, state, moe_pairs [2])``, the last being the
     window's (routed, held) pair counts over the rows and steps that ran."""
-    from distllm_tpu.ops.sampling import fold_row_keys, sample_tokens
-
-    del layer_unroll  # always unrolled
     rope = _rope_tables(cfg, max_table_positions or cfg.max_position_embeddings)
-
-    def body(carry, _):
-        ids, pos, ctx, k_cache, v_cache, state, live_steps, pairs = carry
-        live = live_steps > 0
-        bt_eff = jnp.where(live[:, None], block_tables, 0)
-        logits_, k_cache, v_cache, state, step_pairs = _decode_core(
-            params, cfg, ids, pos, k_cache, v_cache, bt_eff, ctx, state,
-            live, rope, attn_backend,
-        )
-        token = sample_tokens(
-            logits_, None, temperature, top_p, min_p,
-            top_window=sampling_top_window, top_k=top_k,
-            row_keys=fold_row_keys(seeds, pos + 1),
-        )
-        ids = jnp.where(live, token, ids)
-        pos = jnp.where(live, pos + 1, pos)
-        ctx = jnp.where(live, ctx + 1, ctx)
-        carry = (
-            ids, pos, ctx, k_cache, v_cache, state, live_steps - 1,
-            pairs + step_pairs,
-        )
-        return carry, token
-
-    (ids, _, _, k_cache, v_cache, state, _, pairs), tokens = jax.lax.scan(
-        body,
-        (
-            input_ids, positions, context_lens, k_cache, v_cache, state,
-            steps_left.astype(jnp.int32), jnp.zeros((2,), jnp.int32),
-        ),
-        None,
-        length=num_steps,
+    tokens, (k_cache, v_cache, state), ids, pairs = common.decode_window(
+        functools.partial(_decode_core, params, cfg, rope, attn_backend),
+        input_ids, positions, context_lens, (k_cache, v_cache, state),
+        block_tables, steps_left, temperature, top_p, min_p, top_k, seeds,
+        num_steps=num_steps, sampling_top_window=sampling_top_window,
+        counts=jnp.zeros((2,), jnp.int32),
     )
     return tokens, k_cache, v_cache, ids, state, pairs

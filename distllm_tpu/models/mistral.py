@@ -230,7 +230,7 @@ def _mlp_block(normed: jnp.ndarray, lp: dict, cfg) -> jnp.ndarray:
     """Per-layer MLP: dense SwiGLU, or the Mixtral MoE bank when the layer
     carries a router (pytree STRUCTURE is static under jit, so this
     branch costs nothing at trace time). One home for the block lets the
-    whole serving machinery — prefill, rolled/unrolled paged decode —
+    whole serving machinery, prefill and paged decode alike,
     serve both families (the reference's vLLM serves Mistral and Mixtral
     through one engine too)."""
     if 'router' in lp:
@@ -467,8 +467,7 @@ def prefill_paged(  # distlint: traced
         return logits(params, cfg, hidden), k_cache, v_cache
     # Only each row's last valid tail position feeds the lm_head ([B, S, V]
     # logits would waste MXU time and HBM — same policy as prefill).
-    last_idx = jnp.maximum(tail_lens - 1, 0)
-    last_hidden = jnp.take_along_axis(hidden, last_idx[:, None, None], axis=1)
+    last_hidden = common.last_token(hidden, tail_lens)
     return logits(params, cfg, last_hidden)[:, 0], k_cache, v_cache
 
 
@@ -584,74 +583,39 @@ def _forward(
 def _decode_core(
     params: dict,
     cfg: MistralConfig,
+    rope: tuple,  # (cos, sin)
+    attn_backend: str,
     input_ids: jnp.ndarray,  # [B]
     positions: jnp.ndarray,  # [B]
-    k_cache: jnp.ndarray,
-    v_cache: jnp.ndarray,
-    block_tables: jnp.ndarray,  # [B, max_blocks]
     context_lens: jnp.ndarray,  # [B]
-    cos: jnp.ndarray,
-    sin: jnp.ndarray,
-    attn_backend: str,
-    layer_unroll: bool = False,
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    caches: tuple,  # (k_cache, v_cache)
+    block_tables: jnp.ndarray,  # [B, max_blocks]
+    live=None,  # nothing here keeps a row's state or counts
+) -> tuple[jnp.ndarray, tuple, tuple]:
     """One decode step's compute, RoPE tables passed in (so a multi-step
-    scan hoists them out of the loop).
+    scan hoists them out of the loop): ``common.decode_window``'s ``core``
+    once its first four arguments are bound. Returns ``(logits, (k_cache,
+    v_cache), ())``.
 
-    ``layer_unroll=True`` unrolls the layer scan. Decode is weight-
-    bandwidth bound, and the rolled scan's per-iteration dynamic-slice of
-    the stacked MLP kernels is MATERIALIZED by XLA as a ~0.35 GB/layer
-    temp (read slab + write temp + read temp ≈ 3x traffic on 78% of the
-    weights — read off the compiled HLO on older code, 2026-07-31; not
-    re-measured, and no cell runs the rolled window: ROADMAP D2).
-    Unrolling turns those into static slices that
-    fold into the matmuls. The K/V pools are never sliced, rolled or
-    unrolled: a layer sliced out of the stacked pool for the kernel call
-    was copied out and back (64 plane copies and write-backs a step at 32
-    layers, 4.27 ms of a 29.61 ms step on the chip, PR 31), so the pool
+    The layer scan is UNROLLED. Decode is weight-bandwidth bound, and a
+    rolled scan's per-iteration dynamic-slice of the stacked MLP kernels
+    was materialized by XLA as a temp a layer (read off the compiled HLO on
+    older code, 2026-07-31; not re-measured: no cell runs a rolled window).
+    Unrolled, those are static slices that fold into the matmuls. The K/V
+    pools are never sliced: a layer sliced out of the stacked pool for the
+    kernel call was copied out and back (64 plane copies and write-backs a
+    step at 32 layers, 4.27 ms of a 29.61 ms step on the chip, PR 31), so the pool
     goes to the writer and to the kernel whole, with the layer whose
     pages are meant (``ops.paged_attention._layer_pages``). A family may
     instead hold one buffer a layer (``CacheSpec.layer_buffers``,
     ``models/laguna.py``). Prefill keeps the rolled scan: compute-bound,
     and the weights' slice traffic amortizes over the whole token batch.
     """
-    from distllm_tpu.ops.paged_attention import (
-        paged_attention_xla,
-        ragged_paged_attention_pallas,
-        write_token_kv,
-    )
+    from distllm_tpu.ops.paged_attention import decode_attention, write_token_kv
 
     alternating = (
         getattr(cfg, 'sliding_window_pattern', 'all') == 'alternating'
     )
-
-    if attn_backend == 'xla':
-
-        def attend(q, k_cache, v_cache, window_l, li):
-            return paged_attention_xla(
-                q, k_cache, v_cache, block_tables, context_lens,
-                # Traced per-layer window only for the alternating pattern;
-                # other families keep the static value so their decode HLO
-                # is unchanged.
-                sliding_window=window_l if alternating else cfg.sliding_window,
-                scale=getattr(cfg, 'query_scale', None),
-                logit_softcap=getattr(cfg, 'attn_logit_softcap', None),
-                layer=li,
-            )
-    else:
-        # A decode row is the ragged kernel's span-1 degenerate case: one
-        # query at the token's own position over the whole context. The
-        # kernel natively handles softcap / traced per-layer windows /
-        # custom scales, so every model family serves through it.
-        def attend(q, k_cache, v_cache, window_l, li):
-            return ragged_paged_attention_pallas(
-                q[:, None], k_cache, v_cache, block_tables,
-                context_lens, q_positions=positions[:, None],
-                sliding_window=window_l if alternating else cfg.sliding_window,
-                scale=getattr(cfg, 'query_scale', None),
-                logit_softcap=getattr(cfg, 'attn_logit_softcap', None),
-                interpret=attn_backend == 'interpret', layer=li,
-            )[:, 0]
 
     # int32 [L] per-layer windows (0 = global) riding the layer scan; only
     # consulted when `alternating`.
@@ -659,17 +623,16 @@ def _decode_core(
         _layer_window_flags(cfg), cfg.sliding_window or 0, 0
     ).astype(jnp.int32)
 
+    (cos, sin), (k_cache, v_cache) = rope, caches
     x = _embed_tokens(params, cfg, input_ids)  # [B, H]
 
     # The FULL caches ride the scan carry and each layer scatters its new
-    # rows into its own pages of them, in place. Rolled
-    # (layer_unroll=False): XLA aliases while-loop carries, so no second
-    # cache copy is ever materialized. Unrolled: the same chain of
+    # rows into its own pages of them, in place. Unrolled, that chain of
     # scatters sits in straight-line code, where in-place updates rely on
-    # XLA's buffer reuse instead of carry aliasing — tests/test_aot_tpu.py
-    # asserts that no op of the unrolled window has a plane as its result
-    # and every pool-sized one is the scatter, so a missed reuse cannot
-    # land silently. (Scanning the caches as xs/ys instead
+    # XLA's buffer reuse instead of a while loop's carry aliasing:
+    # tests/test_aot_tpu.py asserts that no op of the window has a plane as
+    # its result and every pool-sized one is the scatter, so a missed reuse
+    # cannot land silently. (Scanning the caches as xs/ys instead
     # allocates a full stacked output buffer: +1 GB at 7B dims, and one
     # more when a multi-step window scan wraps this — that overflowed the
     # v5e's 16 GB HBM.)
@@ -694,7 +657,16 @@ def _decode_core(
         k_cache, v_cache = write_token_kv(
             k_cache, v_cache, k, v, block_tables, positions, layer=li
         )
-        attn = attend(q, k_cache, v_cache, window_l, li)
+        attn = decode_attention(
+            q, k_cache, v_cache, block_tables, context_lens, positions,
+            backend=attn_backend, layer=li,
+            # Traced per-layer window only for the alternating pattern;
+            # other families keep the static value so their decode HLO
+            # is unchanged.
+            sliding_window=window_l if alternating else cfg.sliding_window,
+            scale=getattr(cfg, 'query_scale', None),
+            logit_softcap=getattr(cfg, 'attn_logit_softcap', None),
+        )
         attn_out = common.dense(
             attn.reshape(-1, cfg.num_heads * cfg.head_size),
             lp['o']['kernel'],
@@ -717,10 +689,10 @@ def _decode_core(
             jnp.arange(cfg.num_layers, dtype=jnp.int32),
             layer_windows,
         ),
-        unroll=cfg.num_layers if layer_unroll else 1,
+        unroll=cfg.num_layers,
     )
     hidden = _norm(x, params['final_ln']['scale'], cfg)
-    return logits(params, cfg, hidden), k_cache, v_cache
+    return logits(params, cfg, hidden), (k_cache, v_cache), ()
 
 
 def decode_step(  # distlint: traced
@@ -733,7 +705,6 @@ def decode_step(  # distlint: traced
     block_tables: jnp.ndarray,  # [B, max_blocks]
     context_lens: jnp.ndarray,  # [B] valid tokens incl. the new one
     attn_backend: str = 'xla',
-    layer_unroll: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Single-token decode over the paged KV cache.
 
@@ -746,11 +717,12 @@ def decode_step(  # distlint: traced
     kernel on the Pallas interpreter). All backends support sliding
     windows, gemma2 alternating layers, softcap, and custom scales.
     """
-    cos, sin = _rope_tables(cfg, cfg.max_position_embeddings)
-    return _decode_core(
-        params, cfg, input_ids, positions, k_cache, v_cache, block_tables,
-        context_lens, cos, sin, attn_backend, layer_unroll,
+    rope = _rope_tables(cfg, cfg.max_position_embeddings)
+    logits_, caches, _ = _decode_core(
+        params, cfg, rope, attn_backend, input_ids, positions, context_lens,
+        (k_cache, v_cache), block_tables,
     )
+    return logits_, *caches
 
 
 def decode_loop(  # distlint: traced
@@ -772,7 +744,6 @@ def decode_loop(  # distlint: traced
     attn_backend: str = 'xla',
     max_table_positions: int | None = None,
     sampling_top_window: int = 0,
-    layer_unroll: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """``num_steps`` fused decode+sample steps in ONE dispatch.
 
@@ -787,53 +758,23 @@ def decode_loop(  # distlint: traced
     (max_tokens / max_model_len): their KV writes are routed to the
     reserved trash block 0 and their later tokens are garbage the host
     discards. The scheduler must have reserved blocks for ``min(num_steps,
-    steps_left)`` extra tokens per slot.
+    steps_left)`` extra tokens per slot. The step scan, with its sampling
+    keys, is every family's: ``common.decode_window``.
 
     Returns ``(tokens [num_steps, B] int32, k_cache, v_cache, last_ids)``.
     """
-    from distllm_tpu.ops.sampling import fold_row_keys, sample_tokens
+    from functools import partial  # here: no line above ``prefill_paged`` may move
 
     # RoPE tables bounded by what positions can actually reach: the block
     # table row covers max_table_positions tokens (engine max_model_len) —
     # far smaller than the checkpoint's 32k max_position_embeddings.
-    table_len = max_table_positions or cfg.max_position_embeddings
-    cos, sin = _rope_tables(cfg, table_len)
-
-    def body(carry, _):
-        ids, pos, ctx, k_cache, v_cache, live_steps = carry
-        live = live_steps > 0
-        # Out-of-budget slots write to the trash block (row of zeros) and
-        # stop advancing; their sampled tokens are discarded host-side.
-        bt_eff = jnp.where(live[:, None], block_tables, 0)
-        logits_, k_cache, v_cache = _decode_core(
-            params, cfg, ids, pos, k_cache, v_cache, bt_eff, ctx,
-            cos, sin, attn_backend, layer_unroll,
-        )
-        # Counter-derived per-row keys: the token produced this step sits
-        # at absolute index pos + 1 (frozen slots repeat a key, but their
-        # tokens are discarded host-side anyway).
-        row_keys = fold_row_keys(seeds, pos + 1)
-        token = sample_tokens(
-            logits_, None, temperature, top_p, min_p,
-            top_window=sampling_top_window, top_k=top_k, row_keys=row_keys,
-        )
-        ids = jnp.where(live, token, ids)
-        pos = jnp.where(live, pos + 1, pos)
-        ctx = jnp.where(live, ctx + 1, ctx)
-        return (ids, pos, ctx, k_cache, v_cache, live_steps - 1), token
-
-    (ids, _, _, k_cache, v_cache, _), tokens = jax.lax.scan(
-        body,
-        (
-            input_ids,
-            positions,
-            context_lens,
-            k_cache,
-            v_cache,
-            steps_left.astype(jnp.int32),
-        ),
-        None,
-        length=num_steps,
+    rope = _rope_tables(cfg, max_table_positions or cfg.max_position_embeddings)
+    tokens, (k_cache, v_cache), ids, _ = common.decode_window(
+        partial(_decode_core, params, cfg, rope, attn_backend),
+        input_ids, positions, context_lens, (k_cache, v_cache),
+        block_tables, steps_left, temperature, top_p, min_p, top_k, seeds,
+        num_steps=num_steps, sampling_top_window=sampling_top_window,
+        counts=(),
     )
     return tokens, k_cache, v_cache, ids
 
@@ -869,7 +810,6 @@ def mixed_window(  # distlint: traced
     attn_backend: str = 'xla',
     max_table_positions: int | None = None,
     sampling_top_window: int = 0,
-    layer_unroll: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One MIXED serving window: ragged prefill-chunk rows + the fused
     decode scan in a single dispatch (docs/serving.md).
@@ -913,7 +853,7 @@ def mixed_window(  # distlint: traced
         context_lens, steps_left, temperature, top_p, min_p, top_k, seeds,
         num_steps=num_steps, attn_backend=attn_backend,
         max_table_positions=max_table_positions,
-        sampling_top_window=sampling_top_window, layer_unroll=layer_unroll,
+        sampling_top_window=sampling_top_window,
     )
     return tokens, k_cache, v_cache, last_ids, chunk_tokens
 

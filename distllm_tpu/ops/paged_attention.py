@@ -48,8 +48,8 @@ Both handle GQA (query heads grouped natively over KV heads), per-row query
 spans with ``q_lens`` padding masks, static or TRACED sliding windows
 (gemma2 alternating layers), ``logit_softcap``, custom score scales, and
 fp32 softmax/accumulation. A decode row is just the span-1 degenerate case:
-:func:`paged_attention_pallas` is a thin span-1 wrapper over the ragged
-kernel, while :func:`paged_attention_xla` keeps its own dense decode-shaped
+:func:`decode_attention` is the one way in for it, the ragged kernel at a
+span of one, while :func:`paged_attention_xla` keeps its own dense decode-shaped
 formulation (same math, separately maintained — fixes to the ragged XLA op
 do NOT automatically reach it).
 """
@@ -1354,42 +1354,38 @@ def _walk_row(r: _KernelRefs, compute, block_size, pages_per_chunk):
             r.out[h] = out.astype(r.out.dtype)
 
 
-def paged_attention_pallas(
-    q: jnp.ndarray,
+def decode_attention(  # distlint: traced
+    q: jnp.ndarray,  # [B, num_heads, head_dim]: one query a row
     k_cache: jnp.ndarray,
-    v_cache: jnp.ndarray,
-    block_tables: jnp.ndarray,
-    context_lens: jnp.ndarray,
+    v_cache: 'jnp.ndarray | None',
+    block_tables: jnp.ndarray,  # [B, max_blocks] int32
+    context_lens: jnp.ndarray,  # [B] valid tokens incl. the query's
+    positions: jnp.ndarray,  # [B] the query's position, context_lens - 1
     *,
+    backend: str,
+    layer=None,
     sliding_window: 'int | jnp.ndarray | None' = None,
     scale: float | None = None,
     logit_softcap: float | None = None,
-    pages_per_chunk: int | None = None,
-    interpret: bool = False,
-    layer=None,
+    value_lanes: int | None = None,
 ) -> jnp.ndarray:
-    """Pallas kernel twin of :func:`paged_attention_xla` — now a thin
-    span-1 wrapper over :func:`ragged_paged_attention_pallas` (a decode
-    row is the ragged kernel's degenerate case: one query at position
-    ``context_lens - 1`` over the whole context). The standalone
-    decode-only kernel this used to be is retired; its block layout
-    tripped Mosaic's "implicit dim change" lowering on some toolchains
-    (xfail-gated since ISSUE 3), which the ragged kernel's lane-friendly
-    layout avoids — ``tests/test_aot_tpu.py`` now compiles it gate-free.
-    """
-    return ragged_paged_attention_pallas(
-        q[:, None],
-        k_cache,
-        v_cache,
-        block_tables,
-        context_lens,
-        q_positions=(context_lens.astype(jnp.int32) - 1)[:, None],
-        q_lens=None,
-        sliding_window=sliding_window,
-        scale=scale,
-        logit_softcap=logit_softcap,
-        pages_per_chunk=pages_per_chunk,
-        interpret=interpret,
+    """THE way into the kernel for a decode row (every family's
+    ``_decode_core``): :func:`paged_attention_xla` under ``'xla'``, and
+    otherwise :func:`ragged_paged_attention` at a span of one, the kernel's
+    row walk (``backend`` is a RESOLVED selector value, as there). A plain
+    function: no jit and no scope of its own, so the kernel's call keeps the
+    name and the scope its caller gives it. Returns ``[B, num_heads,
+    head_dim or value_lanes]``."""
+    if backend == 'xla':
+        return paged_attention_xla(
+            q, k_cache, v_cache, block_tables, context_lens,
+            sliding_window=sliding_window, scale=scale,
+            logit_softcap=logit_softcap, value_lanes=value_lanes, layer=layer,
+        )
+    return ragged_paged_attention(
+        q[:, None], k_cache, v_cache, block_tables, context_lens,
+        positions[:, None], sliding_window=sliding_window, scale=scale,
+        logit_softcap=logit_softcap, backend=backend, value_lanes=value_lanes,
         layer=layer,
     )[:, 0]
 
@@ -1463,7 +1459,7 @@ def write_token_kv(  # distlint: traced
     k_pages = k_pages.at[block_ids, offsets].set(
         fold_heads(new_k).astype(k_pages.dtype)
     )
-    if v_pages is not None:  # distlint: disable=traced-python-branch -- a latent pool's absent V plane is static
+    if v_pages is not None:  # a latent pool has no V plane
         v_pages = v_pages.at[block_ids, offsets].set(
             fold_heads(new_v).astype(v_pages.dtype)
         )
@@ -1515,7 +1511,7 @@ def write_chunk_kv(  # distlint: traced
     k_pages = k_pages.at[flat_blocks, flat_offsets].set(
         k_flat.astype(k_pages.dtype)
     )
-    if v_pages is not None:  # distlint: disable=traced-python-branch -- a latent pool's absent V plane is static
+    if v_pages is not None:  # a latent pool has no V plane
         v_flat = fold_heads(new_v).reshape(b * s, -1)
         v_pages = v_pages.at[flat_blocks, flat_offsets].set(
             v_flat.astype(v_pages.dtype)
@@ -1634,7 +1630,7 @@ def write_prefill_kv(  # distlint: traced
     k_pages = k_pages.at[block_ids, offsets].set(
         fold_heads(k_seq).astype(k_pages.dtype)
     )
-    if v_pages is not None:  # distlint: disable=traced-python-branch -- a latent pool's absent V plane is static
+    if v_pages is not None:  # a latent pool has no V plane
         v_pages = v_pages.at[block_ids, offsets].set(
             fold_heads(v_seq).astype(v_pages.dtype)
         )

@@ -66,10 +66,6 @@ def window_args(params_tree, B, nb, R):
         sds((B,), jnp.int32), sds((B,), jnp.uint32),
     )
 
-# Match the engine's decode_layer_unroll; export
-# DISTLLM_PREFLIGHT_LAYER_UNROLL=0 to check decode_layer_unroll=False.
-_LAYER_UNROLL = os.environ.get('DISTLLM_PREFLIGHT_LAYER_UNROLL', '1') != '0'
-
 failures: list[str] = []
 
 
@@ -80,7 +76,7 @@ def compile_window(params_tree, B, nb, R, backend, label):
             mistral.decode_loop(
                 p, mcfg, i, po, k, v, bt, c, sl, tmp, tp, mp, tk, sd,
                 num_steps=16, attn_backend=backend, max_table_positions=512,
-                sampling_top_window=64, layer_unroll=_LAYER_UNROLL)
+                sampling_top_window=64)
         jitted = jax.jit(fn, donate_argnums=(4, 5),
                          in_shardings=(Format(Layout.AUTO),) + (Format(),) * 12)
         compiled = jitted.lower(*window_args(params_tree, B, nb, R)).compile()
@@ -159,7 +155,7 @@ def compile_multichip() -> None:
                 mistral.decode_loop(
                     p, mcfg, i, po, k, v, bt, c, sl, tmp, tp_, mp, tk, sd,
                     num_steps=8, attn_backend='xla', max_table_positions=4096,
-                    sampling_top_window=0, layer_unroll=_LAYER_UNROLL),
+                    sampling_top_window=0),
             donate_argnums=(4, 5),
         ).lower(
             tp_params, r((B,), jnp.int32), r((B,), jnp.int32),

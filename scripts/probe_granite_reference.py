@@ -78,9 +78,9 @@ def program_logits(params, cfg, prompt, backend, round_state):
     )
     step = jax.jit(
         lambda p, ids, pos, k, v, ctx, state: gh._decode_core(
-            p, cfg, ids, pos, k, v, table, ctx, state,
-            jnp.ones((1,), bool), backend,
-        )[:4], donate_argnums=(3, 4, 6),
+            p, cfg, backend, ids, pos, ctx, (k, v, state), table,
+            jnp.ones((1,), bool),
+        )[:2], donate_argnums=(3, 4, 6),
     )
     start = 0
     while start < len(prompt):
@@ -101,7 +101,7 @@ def program_logits(params, cfg, prompt, backend, round_state):
         if i + 1 == OUTPUT_TOKENS:
             break
         at = len(prompt) + i
-        logits, k, v, state = step(
+        logits, (k, v, state) = step(
             params, np.asarray([tokens[-1]], np.int32),
             np.asarray([at], np.int32), k, v,
             np.asarray([at + 1], np.int32), state,

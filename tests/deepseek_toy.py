@@ -106,8 +106,8 @@ def paged_logits(cfg, params, tokens, n_prompt, *, chunk=8, backend='xla',
         max_table_positions=total, attn_backend=backend,
     ))
     decode = jax.jit(lambda planes, ids, pos, ctx: module._decode_core(
-        params, cfg, ids, pos, planes, table, ctx, jnp.asarray([True]), rope,
-        backend,
+        params, cfg, rope, backend, ids, pos, ctx, (planes,), table,
+        jnp.asarray([True]),
     ))
     out = []
     for start in range(0, n_prompt, chunk):
@@ -121,7 +121,7 @@ def paged_logits(cfg, params, tokens, n_prompt, *, chunk=8, backend='xla',
         )
     out.append(np.asarray(last[0]))
     for pos in range(n_prompt, total):
-        step, planes, _ = decode(
+        step, (planes,), _ = decode(
             planes, jnp.asarray([tokens[pos]]), jnp.asarray([pos]),
             jnp.asarray([pos + 1]),
         )

@@ -131,7 +131,7 @@ def paged_logits(cfg, params, tokens, n_prompt, *, chunk=8, backend='xla',
         return jnp.asarray(full_row[None]), jnp.asarray(row[None])
 
     # One program a kind of dispatch, as the engine has (called bare, the
-    # model makes and compiles its jitted layers anew a call: ``_once_a_kind``).
+    # model makes and compiles its jitted layers anew a call: ``once_a_kind``).
     prefill_chunk = jax.jit(
         lambda params, *arrays: laguna.prefill_paged(
             params, cfg, *arrays, max_table_positions=total,
@@ -139,8 +139,8 @@ def paged_logits(cfg, params, tokens, n_prompt, *, chunk=8, backend='xla',
         )
     )
     decode_step = jax.jit(
-        lambda params, *arrays: laguna._decode_core(
-            params, cfg, *arrays, backend
+        lambda params, rope, *arrays: laguna._decode_core(
+            params, cfg, rope, backend, *arrays
         )
     )
     out = []
@@ -159,9 +159,9 @@ def paged_logits(cfg, params, tokens, n_prompt, *, chunk=8, backend='xla',
     rope = laguna._rope_tables(cfg, total)
     for pos in range(n_prompt, total):
         window_blocks.cover(0, pos, pos + 1)
-        step, k, v, _ = decode_step(
-            params, jnp.asarray([tokens[pos]]), jnp.asarray([pos]), k, v,
-            tables(), jnp.asarray([pos + 1]), jnp.asarray([True]), rope,
+        step, (k, v), _ = decode_step(
+            params, rope, jnp.asarray([tokens[pos]]), jnp.asarray([pos]),
+            jnp.asarray([pos + 1]), (k, v), tables(), jnp.asarray([True]),
         )
         out.append(np.asarray(step[0]))
     return np.stack(out), window_blocks

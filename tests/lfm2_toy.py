@@ -120,7 +120,7 @@ def paged_logits(cfg, params, rows, *, chunk=8, backend='xla', stale=None,
     )
     decode = jax.jit(
         lambda k, v, state, ids, pos, table, ctx, live: module._decode_core(
-            params, cfg, ids, pos, k, v, table, ctx, state, live, rope, backend,
+            params, cfg, rope, backend, ids, pos, ctx, (k, v, state), table, live,
         )
     )
     out = [[] for _ in rows]
@@ -144,7 +144,7 @@ def paged_logits(cfg, params, rows, *, chunk=8, backend='xla', stale=None,
         live = np.asarray([n + step < len(tokens) for tokens, n in rows])
         pos = np.asarray([min(n + step, len(tokens) - 1) for tokens, n in rows])
         ids = np.asarray([tokens[p] for (tokens, _), p in zip(rows, pos)])
-        logits, k, v, state, _ = decode(
+        logits, (k, v, state), _ = decode(
             k, v, state, jnp.asarray(ids), jnp.asarray(pos),
             jnp.asarray(np.where(live[:, None], tables, 0)),
             jnp.asarray(pos + 1), jnp.asarray(live),

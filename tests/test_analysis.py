@@ -693,6 +693,29 @@ class TestTracedPythonBranch:
         )
         assert diags == []
 
+    def test_identity_test_is_static_and_clean(self):
+        # ``q_lens is None`` (ops/paged_attention.py, an absent operand's
+        # default) reads which object the name is bound to, even where the
+        # same name is later rebound from a device expression; a VALUE test
+        # of that name is still a finding.
+        source = (
+            'import jax\nimport jax.numpy as jnp\n'
+            '@jax.jit\n'
+            'def f(x, lens=None):\n'
+            '    if lens is None:\n'
+            '        lens = jnp.full((2,), 3)\n'
+            '    if lens is not None and x is not None:\n'
+            '        x = x + lens\n'
+            '{}'
+            '    return x\n'
+        )
+        assert run_rules(source.format(''), ['traced-python-branch']) == []
+        diags = run_rules(
+            source.format('    if lens:\n        x = x * 2\n'),
+            ['traced-python-branch'],
+        )
+        assert rule_ids_of(diags) == ['traced-python-branch']
+
     def test_isinstance_dispatch_is_static_and_clean(self):
         # The QuantizedKV-vs-bare-array pytree dispatch idiom
         # (ops/paged_attention.py write paths): isinstance inspects the

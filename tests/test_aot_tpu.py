@@ -261,11 +261,10 @@ def test_encoder_attention_compiles_for_tpu(v5e):
 @pytest.mark.slow
 @pytest.mark.parametrize('backend', ['pallas', 'xla'])
 def test_decode_window_compiles_for_tpu(v5e, backend):
-    """Both scan variants must lower: rolled, and the engine-default
-    unrolled graph (whose straight-line cache updates depend on XLA
-    buffer reuse rather than while-carry aliasing). A missed reuse in the
-    unrolled body would add full-cache-sized temps on top of the rolled
-    baseline — asserted against below."""
+    """The window (its layers unrolled: straight-line cache updates that
+    depend on XLA's buffer reuse rather than on while-carry aliasing) must
+    lower, and a missed reuse in it would add full-cache-sized temps:
+    asserted against below."""
     from distllm_tpu.models import mistral
 
     # head_dim must be 128 (the Pallas kernel's DMA alignment contract).
@@ -280,41 +279,33 @@ def test_decode_window_compiles_for_tpu(v5e, backend):
     b, nb, bs, rows = 8, 64, 16, 16
     kshape = (cfg.num_layers, nb, bs, cfg.num_kv_heads * cfg.head_size)
     cache_bytes = 2 * int(np.prod(kshape)) * 2  # k + v, bf16
-    temps = {}
-    for layer_unroll in (False, True):
-        compiled = _compile(
-            mosaic_kernel=(backend == 'pallas'),
-            build=lambda un=layer_unroll: jax.jit(
-                lambda p, i, po, c, k, v, bt, sl, t, tp, mp, tk, sd,
-                       un=un:
-                    mistral.decode_loop(
-                        p, cfg, i, po, k, v, bt, c, sl, t, tp, mp, tk, sd,
-                        num_steps=4, attn_backend=backend,
-                        max_table_positions=256,
-                        sampling_top_window=16, layer_unroll=un,
-                    ),
-                donate_argnums=(4, 5),
-            ).lower(
-                params, v5e((b,), jnp.int32), v5e((b,), jnp.int32),
-                v5e((b,), jnp.int32), v5e(kshape, jnp.bfloat16),
-                v5e(kshape, jnp.bfloat16), v5e((b, rows), jnp.int32),
-                v5e((b,), jnp.int32), v5e((b,), jnp.float32),
-                v5e((b,), jnp.float32), v5e((b,), jnp.float32),
-                v5e((b,), jnp.int32), v5e((b,), jnp.uint32),
-            ).compile()
-        )
-        mem = compiled.memory_analysis()
-        temps[layer_unroll] = getattr(mem, 'temp_size_in_bytes', None)
-    if temps[True] is not None:
+    compiled = _compile(
+        mosaic_kernel=(backend == 'pallas'),
+        build=lambda: jax.jit(
+            lambda p, i, po, c, k, v, bt, sl, t, tp, mp, tk, sd:
+                mistral.decode_loop(
+                    p, cfg, i, po, k, v, bt, c, sl, t, tp, mp, tk, sd,
+                    num_steps=4, attn_backend=backend,
+                    max_table_positions=256, sampling_top_window=16,
+                ),
+            donate_argnums=(4, 5),
+        ).lower(
+            params, v5e((b,), jnp.int32), v5e((b,), jnp.int32),
+            v5e((b,), jnp.int32), v5e(kshape, jnp.bfloat16),
+            v5e(kshape, jnp.bfloat16), v5e((b, rows), jnp.int32),
+            v5e((b,), jnp.int32), v5e((b,), jnp.float32),
+            v5e((b,), jnp.float32), v5e((b,), jnp.float32),
+            v5e((b,), jnp.int32), v5e((b,), jnp.uint32),
+        ).compile()
+    )
+    temps = getattr(compiled.memory_analysis(), 'temp_size_in_bytes', None)
+    if temps is not None:
         # Unrolling must not degrade in-place cache updates to copies:
-        # each missed reuse adds a full-cache-sized temp. (The rolled
-        # variant reports ~0 temps — memory_analysis does not descend
-        # into while bodies — so the bound is absolute, not relative:
-        # activation temps at these dims are ~2.5 MB, well under one
-        # 4 MB cache copy.)
-        assert temps[True] < cache_bytes, (
-            f'unrolled temps {temps[True]} vs one cache copy '
-            f'{cache_bytes} (rolled baseline: {temps[False]})'
+        # each missed reuse adds a full-cache-sized temp. The bound is
+        # absolute: activation temps at these dims are ~2.5 MB, well under
+        # one 4 MB cache copy.
+        assert temps < cache_bytes, (
+            f'window temps {temps} vs one cache copy {cache_bytes}'
         )
 
 
@@ -802,7 +793,6 @@ def _mistral_window(v5e, pool):
             mistral.decode_loop(
                 p, cfg, i, po, k, v, bt, c, sl, tmp, tp_, mp, tk, sd,
                 num_steps=8, attn_backend='pallas', max_table_positions=4096,
-                layer_unroll=True,
             ),
         donate_argnums=(4, 5),
     ).lower(

@@ -184,7 +184,7 @@ def test_expert_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer():
             )
             total, held = total + share, held + int(pairs[1])
             assert int(pairs[0]) == 11 * cfg.experts_per_token
-        total = total + deepseek_v3._swiglu(
+        total = total + common.swiglu(
             x, mp['shared_gate']['kernel'], mp['shared_up']['kernel'],
             mp['shared_down']['kernel'],
         )

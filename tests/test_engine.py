@@ -75,7 +75,7 @@ def test_paged_attention_matches_dense(rng):
 
 
 def test_paged_attention_pallas_interpret_matches_xla(rng):
-    from distllm_tpu.ops.paged_attention import paged_attention_pallas
+    from distllm_tpu.ops.paged_attention import decode_attention
 
     k_cache, v_cache = _random_cache(rng, num_blocks=8, block_size=4)
     block_tables = jnp.asarray([[2, 5], [7, 0]], dtype=jnp.int32)
@@ -85,8 +85,9 @@ def test_paged_attention_pallas_interpret_matches_xla(rng):
         paged_attention_xla(q, k_cache, v_cache, block_tables, context_lens)
     )
     out = np.asarray(
-        paged_attention_pallas(
-            q, k_cache, v_cache, block_tables, context_lens, interpret=True
+        decode_attention(
+            q, k_cache, v_cache, block_tables, context_lens,
+            context_lens - 1, backend='interpret',
         )
     )
     np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-4)

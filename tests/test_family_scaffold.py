@@ -1,0 +1,206 @@
+"""What the served families share and no longer write themselves (PR 44):
+the seeded weights' bits, the decode window's step scan
+(``models.common.decode_window``) and the decode row's way into the kernel
+(``ops.paged_attention.decode_attention``). Small shapes, under a second a
+case; no test here judges a time."""
+
+import hashlib
+
+import deepseek_toy
+import falcon_h1_toy
+import jax
+import jax.numpy as jnp
+import laguna_toy
+import lfm2_toy
+import numpy as np
+import pytest
+import test_state_pool_granite as granite_toy
+
+from distllm_tpu.models import common, decoder_family, mistral
+from distllm_tpu.ops.paged_attention import (
+    decode_attention,
+    paged_attention_xla,
+    ragged_paged_attention_xla,
+)
+
+# ---------------------------------------------------------- the weights' bits
+# sha256 over every leaf of ``init_on_device(PRNGKey(7), cfg)`` at the
+# family's toy widths in bfloat16 (path, dtype, shape, bytes; the leaves in
+# the tree's own order), taken on the PARENT of PR 44 (commit fbc1eca): the
+# benchmark's cells take their weights from these functions
+# (``benchmarks/drivers``), so a builder that moves a fold-in number, the
+# sorted leaf order or a dtype moves every cell's numbers and its check's
+# limits. A change here is a change of the benchmark's weights: say so.
+_PARENT_BITS = {
+    'mistral': 'a46105dac73a068c35f7df089517ae05d75885ad93d3882b37e7eeb1a0ad54bc',
+    'granite': 'ac10ec6faf04689832d477db20cc03ab8ccb8f0921f154a13dc4993ae564905d',
+    'laguna': '87a49ecddf5a850869f342c39b8796cc8ac714d1147408bfcb728eef797901eb',
+    'deepseek_v3': 'feb99441d81cdc633c057ae49aa218172b90b535bf537d16b766c8e2feac9574',
+    'lfm2': '0796b9df8c5661c7868a8657a9ef349aea6d1cba60a250ebba576fecdc866252',
+    'falcon_h1': '09463b44e0d3bfd98c5429e853b43db2cd05476ead9b63cc027dab2aca85c83d',
+}
+_TOYS = {
+    'granite': granite_toy, 'laguna': laguna_toy, 'deepseek_v3': deepseek_toy,
+    'lfm2': lfm2_toy, 'falcon_h1': falcon_h1_toy,
+}
+
+
+def _toy(family):
+    if family == 'mistral':
+        return mistral, mistral.MistralConfig(
+            vocab_size=96, hidden_size=64, num_layers=2, num_heads=4,
+            num_kv_heads=2, intermediate_size=96, dtype='bfloat16',
+        )
+    hf = _TOYS[family].tiny_hf()
+    config_cls, module = decoder_family(hf['model_type'])
+    return module, config_cls.from_hf_config(hf)
+
+
+@pytest.mark.parametrize('family', sorted(_PARENT_BITS))
+def test_seeded_weights_are_the_parents_bits(family):
+    module, cfg = _toy(family)
+    assert cfg.dtype == 'bfloat16'
+    params = module.init_on_device(jax.random.PRNGKey(7), cfg)
+    digest = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+        leaf = np.asarray(leaf)
+        digest.update(
+            f'{jax.tree_util.keystr(path)} {leaf.dtype} {leaf.shape}\n'.encode()
+        )
+        digest.update(leaf.tobytes())
+    assert digest.hexdigest() == _PARENT_BITS[family]
+    # and the specs name every leaf of the tree, and nothing else
+    specs = module.param_specs(cfg)
+    is_spec = lambda x: isinstance(x, jax.sharding.PartitionSpec)  # noqa: E731
+    assert jax.tree.structure(specs, is_leaf=is_spec) == jax.tree.structure(params)
+
+
+# ------------------------------------------------------------ the step scan
+_VOCAB = 32
+
+
+def _window(counts, tables, steps_left, num_steps=4):
+    """``decode_window`` over a core whose logits peak at ``ids + 1`` (greedy
+    rows) and whose cache, a dict, adds up what each step was given: the
+    live rows' ids, every row's position and context, and column 1 of its
+    block table(s). Rows start at ids 3.., positions 10.., contexts 11..."""
+    b = len(steps_left)
+
+    def core(ids, pos, ctx, caches, tables, live):
+        (seen,) = caches
+        column = sum(table[:, 1] for table in jax.tree.leaves(tables))
+        seen = {
+            'ids': seen['ids'] + jnp.where(live, ids, 0),
+            'pos': seen['pos'] + pos, 'ctx': seen['ctx'] + ctx,
+            'table': seen['table'] + column,
+        }
+        logits = jax.nn.one_hot((ids + 1) % _VOCAB, _VOCAB) * 10.0
+        step = jax.tree.map(
+            lambda zero: jnp.full_like(zero, jnp.sum(live, dtype=zero.dtype)),
+            counts,
+        )
+        return logits, (seen,), step
+
+    rows = jnp.arange(b, dtype=jnp.int32)
+    full = lambda value, dtype: jnp.full((b,), value, dtype)  # noqa: E731
+    zeros = {name: jnp.zeros((b,), jnp.int32) for name in ('ids', 'pos', 'ctx', 'table')}
+    tokens, (seen,), last_ids, summed = common.decode_window(
+        core, rows + 3, rows + 10, rows + 11, (zeros,), tables,
+        jnp.asarray(steps_left, jnp.int32), full(0.0, jnp.float32),
+        full(1.0, jnp.float32), full(0.0, jnp.float32), full(0, jnp.int32),
+        full(5, jnp.uint32), num_steps=num_steps, sampling_top_window=0,
+        counts=counts,
+    )
+    return np.asarray(tokens), jax.tree.map(np.asarray, seen), np.asarray(last_ids), summed
+
+
+@pytest.mark.parametrize('counts', [
+    jnp.zeros((2,), jnp.int32),
+    jnp.zeros((), jnp.int32),
+    {'rows': jnp.zeros((), jnp.int32), 'pairs': jnp.zeros((2,), jnp.int32)},
+    (),
+], ids=['array', 'scalar', 'dict', 'none'])
+def test_decode_window_counts_only_live_steps(counts):
+    """Rows with 0, 2 and 9 steps left in a window of 4: a dead row keeps
+    its id, a row that runs out stops there, and the counter, whatever its
+    shape, sums the live rows of the steps that ran: 0 + 2 + 4."""
+    table = jnp.arange(3 * 5, dtype=jnp.int32).reshape(3, 5) + 1
+    tokens, seen, last_ids, summed = _window(counts, table, [0, 2, 9])
+    np.testing.assert_array_equal(last_ids, [3, 4 + 2, 5 + 4])
+    np.testing.assert_array_equal(tokens[:, 2], [6, 7, 8, 9])
+    np.testing.assert_array_equal(tokens[:2, 1], [5, 6])  # then garbage
+    # the live rows' ids alone: 4 + 5, and 5 + 6 + 7 + 8
+    np.testing.assert_array_equal(seen['ids'], [0, 9, 26])
+    for leaf in jax.tree.leaves(summed):
+        np.testing.assert_array_equal(np.asarray(leaf), np.full(leaf.shape, 6))
+    assert jax.tree.structure(summed) == jax.tree.structure(counts)
+
+
+@pytest.mark.parametrize('groups', [1, 2], ids=['one_table', 'two_tables'])
+def test_decode_window_sends_a_dead_row_to_the_trash_block(groups):
+    """What ``core`` is given a step: a row out of budget sees block 0 in
+    every cache group's table, and its position and context stay."""
+    one = jnp.arange(3 * 5, dtype=jnp.int32).reshape(3, 5) + 1
+    tables = one if groups == 1 else (one, one + 100)
+    _, seen, _, _ = _window(jnp.zeros((2,), jnp.int32), tables, [0, 2, 9])
+    # positions 10, 11, 12: the dead row's four times over, the second
+    # row's 11 + 12 and then 13 twice, the third's 12 + 13 + 14 + 15
+    np.testing.assert_array_equal(seen['pos'], [40, 49, 54])
+    np.testing.assert_array_equal(seen['ctx'], [44, 53, 58])
+    # column 1 of the table(s), once a LIVE step: rows 2, 7, 12 of ``one``
+    column = np.asarray(one[:, 1]) * groups + 100 * (groups - 1)
+    np.testing.assert_array_equal(seen['table'], column * [0, 2, 4])
+
+
+# ------------------------------------------------- a decode row's way in
+def _pools(rng, *, layers=None, row=16, blocks=12, block=4):
+    shape = (blocks, block, row) if layers is None else (layers, blocks, block, row)
+    return (
+        jnp.asarray(rng.standard_normal(shape), jnp.float32),
+        jnp.asarray(rng.standard_normal(shape), jnp.float32),
+    )
+
+
+@pytest.mark.parametrize('backend', ['xla', 'interpret'])
+@pytest.mark.parametrize('case', ['stacked_pool', 'latent_plane', 'window'])
+def test_decode_attention_is_both_twins_at_a_span_of_one(case, backend):
+    """Against ``paged_attention_xla`` (what it is under ``'xla'``) and
+    against ``ragged_paged_attention_xla`` at a span of one, with the
+    operands the families pass: a stacked pool and its layer, a latent
+    plane and its value lanes, a sliding window."""
+    rng = np.random.default_rng(11)
+    tables = jnp.asarray(rng.permutation(11)[:10].reshape(2, 5) + 1, jnp.int32)
+    ctx = jnp.asarray([19, 6], jnp.int32)
+    kw = {}
+    if case == 'stacked_pool':
+        k, v = _pools(rng, layers=3)
+        q = jnp.asarray(rng.standard_normal((2, 4, 8)), jnp.float32)
+        kw = dict(layer=2, scale=0.3)
+    elif case == 'latent_plane':
+        k, v = _pools(rng, row=256)[0], None
+        q = jnp.asarray(rng.standard_normal((2, 8, 256)), jnp.float32)
+        kw = dict(value_lanes=128, scale=0.2)
+    else:
+        k, v = _pools(rng)
+        q = jnp.asarray(rng.standard_normal((2, 4, 8)), jnp.float32)
+        kw = dict(sliding_window=5, logit_softcap=20.0)
+    got = decode_attention(q, k, v, tables, ctx, ctx - 1, backend=backend, **kw)
+    want = paged_attention_xla(q, k, v, tables, ctx, **kw)
+    span = ragged_paged_attention_xla(
+        q[:, None], k, v, tables, ctx, (ctx - 1)[:, None], **kw
+    )[:, 0]
+    assert got.shape == want.shape == (2, q.shape[1], kw.get('value_lanes', 8))
+    if backend == 'xla':
+        np.testing.assert_array_equal(got, want)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(got, span, rtol=2e-5, atol=2e-5)
+
+
+def test_decode_attention_refuses_an_unresolved_backend():
+    rng = np.random.default_rng(12)
+    k, v = _pools(rng)
+    q = jnp.zeros((1, 4, 8))
+    args = (jnp.ones((1, 2), jnp.int32), jnp.ones((1,), jnp.int32),
+            jnp.zeros((1,), jnp.int32))
+    with pytest.raises(ValueError, match="unresolved or unknown attn backend 'auto'"):
+        decode_attention(q, k, v, *args, backend='auto')

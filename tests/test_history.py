@@ -318,7 +318,7 @@ def test_engine_owns_sampler_only_when_configured():
         cfg, params, IdTokenizer(),
         EngineConfig(
             block_size=4, num_blocks=16, max_num_seqs=2, max_model_len=32,
-            prefer_native_allocator=False, decode_layer_unroll=False,
+            prefer_native_allocator=False,
             history_interval_s=0.05,
         ),
     )

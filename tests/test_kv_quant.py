@@ -28,7 +28,7 @@ from distllm_tpu.ops.paged_attention import (
     QuantizedKV,
     kv_storage_dtype,
     kv_sublane_tile,
-    paged_attention_pallas,
+    decode_attention,
     paged_attention_xla,
     quantize_kv_rows,
     resolve_attn_backend,
@@ -218,8 +218,9 @@ def test_paged_attention_pallas_interpret_matches_xla_int8(rng):
         paged_attention_xla(q, k_cache, v_cache, block_tables, context_lens)
     )
     out = np.asarray(
-        paged_attention_pallas(
-            q, k_cache, v_cache, block_tables, context_lens, interpret=True
+        decode_attention(
+            q, k_cache, v_cache, block_tables, context_lens,
+            context_lens - 1, backend='interpret',
         )
     )
     np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-4)
@@ -285,8 +286,9 @@ def test_stacked_int8_pool_is_addressed_by_layer(rng, writer, layer):
     ref = paged_attention_xla(q, want_k, want_k, bt[:1], ctx)
     for out in (
         paged_attention_xla(q, got_k, got_v, bt[:1], ctx, layer=layer),
-        paged_attention_pallas(
-            q, got_k, got_v, bt[:1], ctx, layer=layer, interpret=True
+        decode_attention(
+            q, got_k, got_v, bt[:1], ctx, ctx - 1, layer=layer,
+            backend='interpret',
         ),
     ):
         np.testing.assert_allclose(

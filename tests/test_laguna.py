@@ -23,7 +23,7 @@ import pytest
 
 from benchmarks import reference_laguna as ref
 from distllm_tpu.generate.engine.kv_cache import WindowBlocks
-from distllm_tpu.models import decoder_family, laguna
+from distllm_tpu.models import common, decoder_family, laguna
 from laguna_toy import (
     BLOCK,
     WINDOW,
@@ -301,7 +301,7 @@ def test_expert_shares_add_up_to_the_uncut_layer():
         out, pairs = laguna._mlp(
             x, mp, share, 'sparse', jnp.ones((13,), bool), held['sparse'], 0
         )
-        own_shared = laguna._swiglu(
+        own_shared = common.swiglu(
             x, mp['shared_gate']['kernel'], mp['shared_up']['kernel'],
             mp['shared_down']['kernel'],
         )

@@ -367,7 +367,7 @@ def test_kernel_at_64_wide_heads_is_its_xla_twin(span, kv_heads, group, pages_pe
 
 def test_kernel_at_64_wide_heads_addresses_a_stacked_pool_and_a_window():
     from distllm_tpu.ops.paged_attention import (
-        paged_attention_pallas,
+        decode_attention,
         paged_attention_xla,
     )
 
@@ -383,9 +383,9 @@ def test_kernel_at_64_wide_heads_addresses_a_stacked_pool_and_a_window():
         want = paged_attention_xla(
             q, k, v, tables, ctx, sliding_window=window, scale=0.2, layer=layer
         )
-        got = paged_attention_pallas(
-            q, k, v, tables, ctx, sliding_window=window, scale=0.2,
-            interpret=True, layer=layer,
+        got = decode_attention(
+            q, k, v, tables, ctx, ctx - 1, sliding_window=window, scale=0.2,
+            backend='interpret', layer=layer,
         )
         np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 

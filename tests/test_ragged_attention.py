@@ -109,7 +109,7 @@ def test_ragged_parity_softcap_and_scale(rng, softcap, scale):
 def test_ragged_parity_decode_rows(rng):
     """Span-1 rows (the decode degenerate case) match the decode op."""
     from distllm_tpu.ops.paged_attention import (
-        paged_attention_pallas,
+        decode_attention,
         paged_attention_xla,
     )
 
@@ -119,8 +119,9 @@ def test_ragged_parity_decode_rows(rng):
         ref = paged_attention_xla(
             qd, k, v, bt, ctx, sliding_window=window
         )
-        out = paged_attention_pallas(
-            qd, k, v, bt, ctx, sliding_window=window, interpret=True
+        out = decode_attention(
+            qd, k, v, bt, ctx, ctx - 1, sliding_window=window,
+            backend='interpret',
         )
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref), atol=1e-5, rtol=1e-4
@@ -346,7 +347,7 @@ def test_stacked_pool_is_addressed_by_layer(rng, layer, traced):
     read is what the layer's own plane gives, the dead row's write lands
     in THAT layer's block 0, and no other layer's bytes move."""
     from distllm_tpu.ops.paged_attention import (
-        paged_attention_pallas,
+        decode_attention,
         paged_attention_xla,
         write_chunk_kv,
         write_token_kv,
@@ -447,13 +448,11 @@ def test_stacked_pool_is_addressed_by_layer(rng, layer, traced):
         np.asarray(dec_k[layer, 0, 3]), np.asarray(tok_k[2]).reshape(-1)
     )
     ref = paged_attention_xla(q[:2, 0], one_k, one_v, bt[:2], tok_ctx[:2])
-    for reader, kwargs in (
-        (paged_attention_xla, {}),
-        (paged_attention_pallas, {'interpret': True}),
-    ):
+    for backend in ('xla', 'interpret'):
         out = run(
-            lambda k, v, layer, reader=reader, kwargs=kwargs: reader(
-                q[:2, 0], k, v, bt[:2], tok_ctx[:2], layer=layer, **kwargs
+            lambda k, v, layer, backend=backend: decode_attention(
+                q[:2, 0], k, v, bt[:2], tok_ctx[:2], tok_ctx[:2] - 1,
+                layer=layer, backend=backend,
             ), dec_k, dec_v,
         )
         np.testing.assert_allclose(

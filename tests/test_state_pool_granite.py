@@ -540,7 +540,6 @@ def _assert_programs_lower_as_the_parents(engine, cfg):
             temp, top_p, min_p, top_k, seeds, num_steps=econf.decode_steps,
             attn_backend='xla', max_table_positions=econf.max_model_len,
             sampling_top_window=econf.sampling_top_window,
-            layer_unroll=econf.decode_layer_unroll,
         )
 
     def prefill_paged_fn(params, ids, pos, k, v, bt, ctx, tails):
