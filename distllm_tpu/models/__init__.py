@@ -29,6 +29,7 @@ def decoder_families() -> dict:
         lfm2,
         mistral,
         mixtral,
+        solar_open2,
     )
 
     return {
@@ -45,6 +46,7 @@ def decoder_families() -> dict:
         'deepseek_v3': (deepseek_v3.DeepseekV3Config, deepseek_v3),
         'lfm2_moe': (lfm2.Lfm2MoeConfig, lfm2),
         'falcon_h1': (falcon_h1.FalconH1Config, falcon_h1),
+        'solar_open2': (solar_open2.SolarOpen2Config, solar_open2),
     }
 
 

@@ -621,3 +621,14 @@ def tree_specs(top: dict, trees: dict, wrap, banks=()) -> dict:
             for name, shape in shapes.items()
         }
     return specs
+
+
+# At the file's end: no line above moves (a Pallas kernel's serialized body
+# carries its callers' line numbers, ``decode_window``'s among them).
+def conv_tail(window, tail_lens, keep: int):  # distlint: traced
+    """The last ``keep`` counted rows of a causal convolution's input
+    ``window [B, keep + S, C]`` (the carried rows, then the span's own)
+    after each row's first ``tail_lens [B]`` positions: what the next span
+    starts from; carried rows where a row counts fewer than ``keep``."""
+    idx = tail_lens[:, None] + jnp.arange(keep)[None, :]
+    return jnp.take_along_axis(window, idx[..., None], axis=1)

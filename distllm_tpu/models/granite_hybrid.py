@@ -482,9 +482,9 @@ def mamba_span(h, lp, cfg, ssm0, conv0, tail_lens):  # distlint: traced
         dt = jnp.where(valid[..., None], dt, 0.0)
         y, ssm = ssd_chunked(x, dt, a, b_in, c_in, ssm0, cfg.mamba_chunk_size)
         y = y + lp['D'].astype(F32)[:, None] * x
-        # The last K - 1 counted columns: carried ones where the row is short.
-        idx = tail_lens[:, None] + jnp.arange(cfg.mamba_d_conv - 1)[None, :]
-        conv = jnp.take_along_axis(window, idx[..., None], axis=1)
+        # The last K - 1 counted columns: carried ones where the row is short
+        # (the one gather every family with a convolution's tail makes).
+        conv = common.conv_tail(window, tail_lens, cfg.mamba_d_conv - 1)
         return _mamba_out(y, z, lp, cfg, h.dtype), ssm, conv.astype(conv0.dtype)
 
 

@@ -314,8 +314,8 @@ def conv_span(h, lp, conv0, tail_lens):  # distlint: traced
     u, c_gate = _gated_inputs(h, lp)
     window = jnp.concatenate([conv0.astype(u.dtype), u], axis=1)
     y = (c_gate.astype(F32) * _taps(window, lp, s)).astype(h.dtype)
-    idx = tail_lens[:, None] + jnp.arange(conv0.shape[1])[None, :]
-    conv = jnp.take_along_axis(window, idx[..., None], axis=1)
+    # what the next span starts from (a short row keeps carried rows)
+    conv = common.conv_tail(window, tail_lens, conv0.shape[1])
     return common.dense(y, lp['out_proj']['kernel']), conv.astype(conv0.dtype)
 
 

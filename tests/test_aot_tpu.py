@@ -1451,3 +1451,72 @@ def test_falcon_h1_chunk_prefill_addresses_the_pool(v5e, falcon_h1_cell):
     _assert_stacked_pool_is_addressed(compiled, pool)
     _assert_span_calls_keep_the_grid(compiled)
     assert compiled.memory_analysis().temp_size_in_bytes < 2048 << 20
+
+
+# ---- solar_open2 (PR 45): a float32 matrix state a KDA layer, 8 queries a KV head ----
+
+def test_solar_open2_decode_window_updates_its_matrix_states_in_place(v5e, monkeypatch):
+    """The decode window at the cell's slots and full depth (one period: G
+    K K K, 40 held experts a layer) for a described v5e: the one attention
+    layer's pool goes to the kernel as it lies and its calls (8 queries a KV
+    head) take the row walk; the three matrix-state pools
+    (slots x 4 MB each) are donated and rewritten in place, so nothing as
+    large as ONE of them is left over as a temporary."""
+    import json
+    from pathlib import Path
+
+    from distllm_tpu.models import moe, solar_open2
+
+    monkeypatch.setattr(moe, 'grouped_backend', lambda: 'pallas')
+    root = Path(__file__).resolve().parents[1]
+    hf = json.loads(
+        (root / 'benchmarks/configs/solar-open2-250b.json').read_text()
+    )
+    cfg = solar_open2.SolarOpen2Config.from_hf_config(hf)
+    assert cfg.layer_indices() == [('gqa', 0), ('kda', 0), ('kda', 1), ('kda', 2)]
+    assert cfg.num_heads // cfg.num_kv_heads == 8
+    shapes = jax.eval_shape(
+        lambda: solar_open2.init_on_device(jax.random.PRNGKey(0), cfg)
+    )
+    params = jax.tree.map(lambda a: v5e(a.shape, a.dtype), shapes)
+    engine = hf['engine']
+    b, i32, f32 = engine['max_num_seqs'], jnp.int32, jnp.float32
+    pool = (1, engine['num_blocks'], 16, 1024)
+    state = jax.tree.map(
+        lambda a: v5e((b, *a.shape), a.dtype), cfg.state_spec()
+    )
+    pools = v5e(pool, jnp.bfloat16)
+    table = engine['max_model_len'] // engine['block_size']
+    compiled = jax.jit(
+        lambda p, i, po, c, k, v, bt, sl, tmp, tp_, mp, tk, sd, st:
+            solar_open2.decode_loop(
+                p, cfg, i, po, k, v, bt, c, sl, tmp, tp_, mp, tk, sd,
+                num_steps=8, attn_backend='pallas', state=st,
+            ),
+        donate_argnums=(4, 5, 13),
+    ).lower(
+        params, v5e((b,), i32), v5e((b,), i32), v5e((b,), i32), pools, pools,
+        v5e((b, table), i32), v5e((b,), i32), v5e((b,), f32), v5e((b,), f32),
+        v5e((b,), f32), v5e((b,), i32), v5e((b,), jnp.uint32), state,
+    ).compile()
+    # a stack of one layer has no plane to slice: no relayout of the pool,
+    # and the kernel reads the pool itself
+    _assert_pools_go_to_the_kernel_as_they_lie(compiled, [pool, pool[1:]])
+    # At 121 rows and over the routed experts take the grouped kernel, whose
+    # serialized bodies name what the process traced before them, the paged
+    # kernel among it: the walk is counted among the other bodies.
+    import base64
+    import re
+
+    bodies = [
+        base64.b64decode(body) for body in re.findall(
+            r'custom_call_config[^A-Za-z0-9]+body[^A-Za-z0-9]+'
+            r'([A-Za-z0-9+/=]{100,})', compiled.as_text(),
+        )
+    ]
+    paged = [body for body in bodies if b'_grouped_matmul_kernel' not in body]
+    assert paged and all(b'_walk_row' in body for body in paged)
+    grouped = moe.expert_form(b, 8, 40, 320, 4096, 1280) == 'grouped'
+    assert (len(paged) < len(bodies)) == grouped
+    one_matrix_pool = b * 64 * 128 * 128 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < one_matrix_pool

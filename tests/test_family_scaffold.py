@@ -14,6 +14,7 @@ import laguna_toy
 import lfm2_toy
 import numpy as np
 import pytest
+import solar_open2_toy
 import test_state_pool_granite as granite_toy
 
 from distllm_tpu.models import common, decoder_family, mistral
@@ -38,10 +39,13 @@ _PARENT_BITS = {
     'deepseek_v3': 'feb99441d81cdc633c057ae49aa218172b90b535bf537d16b766c8e2feac9574',
     'lfm2': '0796b9df8c5661c7868a8657a9ef349aea6d1cba60a250ebba576fecdc866252',
     'falcon_h1': '09463b44e0d3bfd98c5429e853b43db2cd05476ead9b63cc027dab2aca85c83d',
+    # taken where the family was written (PR 45), not on PR 44's parent
+    'solar_open2': 'da55a43a7e7f04fc0fc8014976cbdee6c6b7d2fc4ab6e8fe199087e2fdd52fef',
 }
 _TOYS = {
     'granite': granite_toy, 'laguna': laguna_toy, 'deepseek_v3': deepseek_toy,
     'lfm2': lfm2_toy, 'falcon_h1': falcon_h1_toy,
+    'solar_open2': solar_open2_toy,
 }
 
 
