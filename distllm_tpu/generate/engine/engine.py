@@ -1300,14 +1300,18 @@ class LLMEngine:
         self._moe_widths = None
         if getattr(model_cfg, 'first_local_expert', None) is not None:
             self._moe_widths = moe.bank_widths(self.params)
+        # the prefill programs a dispatch can run: name -> (span, rows)
+        prefills = {}
+        for bucket in self.prefill_buckets:
+            rows = 1
+            while rows <= self._prefill_batch_cap(bucket):
+                prefills[f'prefill({bucket}, {rows})'] = (bucket, rows)
+                rows *= 2
         if self._moe_widths is not None:
             rows = cfg.max_num_seqs
-            tokens = {f'decode({rows})': rows}
-            for bucket in self.prefill_buckets:
-                rows = 1
-                while rows <= self._prefill_batch_cap(bucket):
-                    tokens[f'prefill({bucket}, {rows})'] = bucket * rows
-                    rows *= 2
+            tokens = {f'decode({rows})': rows} | {
+                key: span * rows for key, (span, rows) in prefills.items()
+            }
             forms = {key: self._moe_form(n) for key, n in tokens.items()}
             self.telemetry['moe_form'] = forms
             # The grouped programs' kernel tiles (row tile, gate/up and
@@ -1320,6 +1324,12 @@ class LLMEngine:
                 ) or 'xla'
                 for key, form in forms.items() if form == 'grouped'
             }
+        # What a family says of the forms its prefill programs take (its
+        # config's ``prefill_forms``, the rules the programs themselves
+        # trace with): telemetry keys of its own, written once here.
+        family_forms = getattr(model_cfg, 'prefill_forms', None)
+        if family_forms is not None:
+            self.telemetry.update(family_forms(prefills))
         if self.state_pool is not None:
             with self._compile_watcher.phase(
                 'state_allocate', f'slots{cfg.max_num_seqs}', compiles=False,

@@ -126,6 +126,21 @@ class SolarOpen2Config(BaseConfig):
         )
         return {'kda': (matrix,) * n, 'conv': (conv,) * n}
 
+    def prefill_forms(self, programs: dict) -> dict:
+        """What the engine's telemetry says of this family's prefill
+        programs (``programs``: name -> (span, rows)): under
+        ``'kda_span_form'`` what computes the span form of the delta rule
+        in each (``ops.kda.span_form``, the rule ``kda_span`` itself traces
+        with): the kernel's (chunk, sub-block, heads a grid step), or
+        ``'xla'``."""
+        return {'kda_span_form': {
+            key: kda.span_form(
+                kda.span_backend(), rows, span, self.kda_heads,
+                self.kda_head_dim, self.kda_head_dim,
+            )
+            for key, (span, rows) in programs.items()
+        }}
+
     def cache_spec(self) -> common.CacheSpec:
         """K/V pages for the attention layers, the KDA layers' state beside
         them (they hold no pages), this module's programs and no dense

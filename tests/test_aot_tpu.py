@@ -1520,3 +1520,111 @@ def test_solar_open2_decode_window_updates_its_matrix_states_in_place(v5e, monke
     assert (len(paged) < len(bodies)) == grouped
     one_matrix_pool = b * 64 * 128 * 128 * 4
     assert compiled.memory_analysis().temp_size_in_bytes < one_matrix_pool
+
+
+# ---- the span form of the Kimi-delta rule as a kernel (PR 46) ----
+
+def _solar_open2_prefill(v5e, rows, span=512):
+    """``solar_open2.prefill_paged`` at the cell's widths (one period, 40
+    held experts) lowered for ``rows`` spans of ``span`` tokens."""
+    import json
+    from pathlib import Path
+
+    from distllm_tpu.models import solar_open2
+
+    root = Path(__file__).resolve().parents[1]
+    hf = json.loads(
+        (root / 'benchmarks/configs/solar-open2-250b.json').read_text()
+    )
+    cfg = solar_open2.SolarOpen2Config.from_hf_config(hf)
+    shapes = jax.eval_shape(
+        lambda: solar_open2.init_on_device(jax.random.PRNGKey(0), cfg)
+    )
+    params = jax.tree.map(lambda a: v5e(a.shape, a.dtype), shapes)
+    engine, i32 = hf['engine'], jnp.int32
+    pools = v5e((1, engine['num_blocks'], 16, 1024), jnp.bfloat16)
+    state = jax.tree.map(
+        lambda a: v5e((engine['max_num_seqs'], *a.shape), a.dtype),
+        cfg.state_spec(),
+    )
+    table = engine['max_model_len'] // engine['block_size']
+    return jax.jit(
+        lambda p, ids, pos, k, v, bt, cl, tl, st, sl:
+            solar_open2.prefill_paged(
+                p, cfg, ids, pos, k, v, bt, cl, tl, st, sl,
+                attn_backend='pallas',
+            ),
+        donate_argnums=(3, 4, 8),
+    ).lower(
+        params, v5e((rows, span), i32), v5e((rows, span), i32), pools, pools,
+        v5e((rows, table), i32), v5e((rows,), i32), v5e((rows,), i32), state,
+        v5e((rows,), i32),
+    )
+
+
+def test_solar_open2_prefill_hands_the_span_kernel_its_operands_as_they_lie(
+    v5e, monkeypatch
+):
+    """The cell's ``(512, 4)`` prefill program for a described v5e with the
+    span form as the kernel: Mosaic takes the kernel at the published head
+    sizes, each KDA layer calls it once, and ``q, k, v, g`` reach it as
+    ``[B, S, H d]`` straight from the fusions that make them and ``o`` leaves
+    it so: no copy or transpose of an operand stands between (the scan read
+    ``[N, B, H, C, d]`` float32 copies of all five)."""
+    import re
+
+    from distllm_tpu.models import moe
+    from distllm_tpu.ops import kda
+
+    monkeypatch.setattr(moe, 'grouped_backend', lambda: 'pallas')
+    monkeypatch.setattr(kda, 'span_backend', lambda: 'pallas')
+    text = _solar_open2_prefill(v5e, rows=4).compile().as_text()
+    defs = _hlo_defs(text)
+    calls = {
+        name: call for name, (_, opcode, call) in defs.items()
+        if opcode == 'custom-call' and name.startswith('kda_span')
+    }
+    assert len(calls) == 3
+    for call in calls.values():
+        operands = re.findall(r'%([\w.\-]+)', call.partition(')')[0])
+        assert len(operands) == 6
+        for operand in operands:
+            opcode = defs[operand][1]
+            assert opcode not in ('copy', 'transpose'), (operand, opcode)
+    moved = [
+        name for name, (result, opcode, _) in defs.items()
+        if opcode in ('copy', 'transpose') and 'f32[4,512,8192]' in result
+    ]
+    assert not moved
+
+
+def test_span_kernel_lowers_to_the_same_text_whoever_traces_it_first(v5e):
+    """A Mosaic body carries its operations' debug locations, and jax
+    caches a traced function with its first caller's stack: traced first
+    from another stack, on another thread, at these shapes and others (the
+    cell's check does so beside the engine's warm-up), the kernel lowers to
+    the bytes it lowers to alone. Otherwise the persistent compile cache
+    misses every program that holds it whenever the order flips."""
+    import threading
+
+    from distllm_tpu.ops import kda
+
+    def lowered(rows):
+        wide = v5e((rows, 512, 64, 128), jnp.float32)
+        return jax.jit(
+            lambda *a: kda.span_kernel(*a, form=(64, 32, 4))
+        ).lower(
+            wide, wide, wide, wide, v5e((rows, 512, 64), jnp.float32),
+            v5e((rows, 64, 128, 128), jnp.float32),
+        ).as_text()
+
+    alone = lowered(4)
+    jax.clear_caches()
+    other = threading.Thread(
+        target=lambda: [(lambda rows: lowered(rows))(rows) for rows in (1, 4)]
+    )
+    other.start()
+    other.join()
+    _ = jnp.where(jnp.ones((32, 1), bool), jnp.ones((32, 128)), 0.0) * 2.0
+    assert 'tpu_custom_call' in alone
+    assert lowered(4) == alone
