@@ -31,6 +31,17 @@ A layer on ``x [T, hidden]``::
          score + bias kept, kept scores over their sum, times
          routed_scaling_factor; the chip adds the pairs whose expert it holds
 
+What computes a KDA layer follows from the backend and the call's static
+shapes, by two pure rules of ``ops/kda.py``, never from a setting. The way in
+(``_kda_inputs``: q, k, v of the projections' outputs): one Pallas kernel on a
+TPU where a head is whole lane tiles of 128 and the span whole sublane tiles
+of 16 (``kda.inputs_form``: every prefill program of the published widths),
+``_qkv_xla`` everywhere else (a decode step's one position, a ragged span,
+the other backends; the kernel's definition in the tests). The recurrence:
+``kda.span_form`` for a span, ``kda_step`` for a decode step.
+``SolarOpen2Config.prefill_forms`` tells the engine's telemetry both answers
+for each prefill program.
+
 There is no ``params_from_hf`` yet.
 """
 
@@ -132,14 +143,24 @@ class SolarOpen2Config(BaseConfig):
         ``'kda_span_form'`` what computes the span form of the delta rule
         in each (``ops.kda.span_form``, the rule ``kda_span`` itself traces
         with): the kernel's (chunk, sub-block, heads a grid step), or
+        ``'xla'``; under ``'kda_inputs_form'`` what makes q, k and v of the
+        projections' output (``ops.kda.inputs_form``, ``_kda_inputs``'
+        rule): the kernel's (sequence tile, rows a step, channel tile), or
         ``'xla'``."""
-        return {'kda_span_form': {
-            key: kda.span_form(
-                kda.span_backend(), rows, span, self.kda_heads,
-                self.kda_head_dim, self.kda_head_dim,
-            )
-            for key, (span, rows) in programs.items()
-        }}
+        backend = kda.span_backend()
+        heads, head = self.kda_heads, self.kda_head_dim
+        return {
+            'kda_span_form': {
+                key: kda.span_form(backend, rows, span, heads, head, head)
+                for key, (span, rows) in programs.items()
+            },
+            'kda_inputs_form': {
+                key: kda.inputs_form(
+                    backend, rows, span, 3 * self.kda_width, self.kda_conv, head
+                )
+                for key, (span, rows) in programs.items()
+            },
+        }
 
     def cache_spec(self) -> common.CacheSpec:
         """K/V pages for the attention layers, the KDA layers' state beside
@@ -344,27 +365,78 @@ def _l2(x):
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
 
 
+def _heads(t, cfg):
+    return t.reshape(*t.shape[:-1], cfg.kda_heads, cfg.kda_head_dim)
+
+
+def _qkv_xla(window, lp, cfg, s: int):
+    """The way in as XLA programs, and its definition: the taps' sum over
+    the convolutions' whole input in float32, SiLU, q's and k's L2 norm a
+    head, q's scale. ``q, k, v [B, S, H, d]`` float32."""
+    q, k, v = (
+        _heads(t, cfg)
+        for t in jnp.split(jax.nn.silu(_taps(window, lp, s)), 3, -1)
+    )
+    return _l2(q) * cfg.kda_head_dim ** -0.5, _l2(k), v
+
+
 def _kda_inputs(u, lp, cfg, conv0):
     """What the recurrence reads of normed inputs ``u [B, S, hidden]`` that
     follow the carried convolution inputs ``conv0 [B, K - 1, 3 H d_k]``:
     ``q, k, v, g [B, S, H, d]`` and ``beta [B, S, H]`` in float32, and the
-    convolutions' whole input ``[B, K - 1 + S, 3 H d_k]``."""
+    convolutions' whole input ``[B, K - 1 + S, 3 H d_k]``. What makes ``q,
+    k, v`` of the projections' output follows from the backend and the
+    call's static shapes (``ops.kda.inputs_form``): one Pallas kernel on a
+    TPU where the head is whole lane tiles and the span whole sublane tiles
+    (every prefill program of the published widths), ``_qkv_xla``
+    everywhere else (a decode step's one position, a ragged span, the
+    other backends). The two differ by float32 rounding alone."""
     s = u.shape[1]
-    heads = lambda t: t.reshape(*t.shape[:-1], cfg.kda_heads, cfg.kda_head_dim)  # noqa: E731
-    qkv = jnp.concatenate(
-        [common.dense(u, lp[n]['kernel']) for n in 'qkv'], axis=-1
-    )
+    projected = [common.dense(u, lp[n]['kernel']) for n in 'qkv']
+    qkv = jnp.concatenate(projected, axis=-1)
     window = jnp.concatenate([conv0.astype(qkv.dtype), qkv], axis=1)
-    q, k, v = (
-        heads(t) for t in jnp.split(jax.nn.silu(_taps(window, lp, s)), 3, -1)
+    backend = kda.span_backend()
+    form = kda.inputs_form(
+        backend, u.shape[0], s, qkv.shape[-1], cfg.kda_conv, cfg.kda_head_dim
     )
-    q, k = _l2(q) * cfg.kda_head_dim ** -0.5, _l2(k)
+    if form == 'xla':
+        q, k, v = _qkv_xla(window, lp, cfg, s)
+    else:
+        q, k, v = (_heads(t, cfg) for t in kda.inputs_kernel(
+            projected, conv0, lp['conv']['taps'], form=form,
+            head=cfg.kda_head_dim, q_scale=cfg.kda_head_dim ** -0.5,
+            eps=L2_EPS, interpret=backend == 'interpret',
+        ))
     low = common.dense(common.dense(u, lp['f_a']['kernel']), lp['f_b']['kernel'])
-    g = -jnp.exp(lp['A_log'].astype(F32))[:, None] * heads(
-        jax.nn.softplus(low.astype(F32) + lp['dt_bias'].astype(F32))
+    g = -jnp.exp(lp['A_log'].astype(F32))[:, None] * _heads(
+        jax.nn.softplus(low.astype(F32) + lp['dt_bias'].astype(F32)), cfg
     )
     beta = jax.nn.sigmoid(common.dense(u, lp['b']['kernel']).astype(F32))
     return q, k, v, g, beta * (2.0 if cfg.kda_neg_eigval else 1.0), window
+
+
+def _conv_rows(window, tail_lens, keep: int):
+    """``common.conv_tail(window, tail_lens, keep)`` without the whole
+    input: a row's last ``keep`` counted rows lie among the ``keep`` carried
+    rows and the ``keep`` of the span that end at its tail, so that narrow
+    window is cut first, a static slice of the carried rows and a dynamic
+    slice a row of each projection's output (XLA gives a slice of the
+    concatenation the operand it came from), and ``conv_tail`` reads it.
+    Where the kernel makes q, k and v nothing else reads ``window``: as a
+    gather's operand it would be built, ``[B, K - 1 + S, 3 H d]``, and copied
+    to the gather's layout (0.85 ms a ``(512, 4)`` dispatch and layer; chip,
+    PR 47)."""
+    if window.shape[1] < 2 * keep:  # a span shorter than the carried rows
+        return common.conv_tail(window, tail_lens, keep)
+    first = jnp.maximum(tail_lens - keep, 0)
+    ending_at_the_tail = jnp.concatenate([
+        jnp.concatenate([
+            jax.lax.dynamic_slice(t, (b, first[b], 0), (1, keep, t.shape[-1]))
+            for b in range(t.shape[0])
+        ]) for t in jnp.split(window[:, keep:], 3, -1)  # q's, k's, v's
+    ], axis=-1)
+    near = jnp.concatenate([window[:, :keep], ending_at_the_tail], axis=1)
+    return common.conv_tail(near, jnp.minimum(tail_lens, keep), keep)
 
 
 def _kda_out(o, u, lp, cfg):
@@ -392,7 +464,7 @@ def kda_mixer_span(u, lp, cfg, state0, conv0, tail_lens):  # distlint: traced
         # A position that does not count leaves the state as it is.
         g = jnp.where(valid[..., None, None], g, 0.0)
         beta = jnp.where(valid[..., None], beta, 0.0)
-        conv = common.conv_tail(window, tail_lens, conv0.shape[1])
+        conv = _conv_rows(window, tail_lens, conv0.shape[1])
     with jax.named_scope('distllm.kda_span'):
         o, state = kda.kda_span(q, k, v, g, beta, state0)
     return _kda_out(o, u, lp, cfg), state, conv.astype(conv0.dtype)

@@ -13,7 +13,11 @@ sizes are whole lane tiles of 128 (``span_kernel``), a ``lax.scan`` over the
 chunks as an XLA program everywhere else (``_span_scan``: the other
 backends' path, odd head sizes, and the kernel's reference in the tests).
 ``benchmarks/solar_open2_bytes.py`` counts what either has to move and do,
-whatever implements it.
+whatever implements it. The way INTO the rule is here too, for a model whose
+q, k and v pass a short causal convolution, SiLU and (q, k) an L2 norm a
+head before the recurrence reads them (``inputs_kernel``, chosen by
+``inputs_form`` as the span form is by ``span_form``; its section at the end
+of the file says how).
 
 The chunk form. With ``G_t`` the running sum of ``g`` inside a chunk that
 starts from ``S_0``, and ``u_t = b_t (v_t - S_{t-1}^T (exp(g_t) k_t))`` the
@@ -423,3 +427,191 @@ def span_kernel(  # distlint: traced
             name='kda_span',
         )(q, k, v, g, beta.astype(F32), state.astype(F32))
     return o.reshape(bsz, s, h, d_v), state
+
+
+# ------------------------------------------------ the way in: q, k, v in one pass
+# What the recurrence reads of a span are not the projections' outputs but,
+# a channel, ``silu(sum_j w[j] x[t - (K - 1) + j])`` over the last K inputs
+# (the span's own behind the K - 1 rows the span before left), and of q and k
+# that vector over its norm a head. As XLA programs that is a float32 copy of
+# the whole input, the taps' shifted sum over it, and a norm's three passes:
+# 3.2 ms a (512, 4) dispatch and layer at the published widths where its own
+# bytes (bfloat16 read, float32 written) are 0.37 ms at the HBM rate (chip,
+# PR 47). The kernel reads each projection's output once and writes q, k, v
+# once; everything between stays in VMEM. The grid is (row, channel tile,
+# sequence tile), a channel tile whole heads (a norm never crosses one) and
+# the same tile of q's, k's and v's third a step; the sequence axis is the
+# last and sequential, and the rows before a tile are carried in scratch.
+# The sequence tiles the way in may take (the first that divides the span;
+# the bfloat16 sublane tile is the least), the rows a step of the loop inside
+# a tile, and the heads a grid step (the first that divides the call's).
+INPUTS_SEQ_TILES = (512, 256, 128, 64, 32, 16)
+INPUTS_ROWS_A_STEP = 128
+INPUTS_HEADS_A_STEP = (2, 1)
+_HALO = 8  # a float32 sublane tile: the rows before a tile are kept in one
+
+
+def inputs_form(
+    backend: str, rows: int, span: int, channels: int, taps: int, head: int
+) -> tuple[int, int, int] | str:
+    """The way-in kernel's ``(sequence tile, rows a step of the loop inside
+    it, channel tile)`` for a call of ``rows`` rows of ``span`` positions
+    over ``channels`` = 3 H d convolution channels of ``taps`` taps and
+    heads of ``head``, or ``'xla'`` where the model's XLA form runs it: off
+    a TPU, for a head that is not whole lane tiles of 128, for a span that
+    is not whole sublane tiles (a decode step's one position, a ragged
+    tail), and for more carried rows than one sublane tile holds. Pure, as
+    ``span_form``."""
+    del rows  # a grid axis of its own
+    if (
+        backend == 'xla' or head % 128 or channels % (3 * head)
+        or span % INPUTS_SEQ_TILES[-1] or not 2 <= taps <= _HALO + 1
+    ):
+        return 'xla'
+    heads = channels // (3 * head)
+    tile = next(n for n in INPUTS_SEQ_TILES if span % n == 0)
+    return (
+        tile, min(INPUTS_ROWS_A_STEP, tile),
+        head * next(n for n in INPUTS_HEADS_A_STEP if heads % n == 0),
+    )
+
+
+def _third_of_a_tile(
+    x_ref, c_ref, w_ref, o_ref, halo_ref, *, step, head, scale, eps
+):
+    """One third's (row, channel tile, sequence tile): ``x_ref [1, T, C]``
+    the projection's output, ``c_ref [1, K - 1, C]`` the rows carried into
+    the span, ``w_ref [K, C]`` the taps, ``halo_ref [_HALO, C]`` float32 the
+    rows before the tile: from the carried rows at a span's first tile, from
+    the tile before after it. Inside the tile a loop of ``step`` rows: the
+    taps' sum over the rows and the halo shifted down by sublane rolls,
+    SiLU, and where ``scale`` is given (q and k) each head's vector over its
+    norm, times ``scale``."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    tile, width = x_ref.shape[1:]
+    taps = w_ref.shape[0]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        # the K - 1 carried rows, at the end of the halo's tile
+        rows, halo = c_ref[0].astype(F32), jnp.zeros((_HALO, width), F32)
+        at = jax.lax.broadcasted_iota(jnp.int32, halo.shape, 0)
+        for i in range(taps - 1):
+            halo = jnp.where(at == _HALO - (taps - 1) + i, rows[i:i + 1], halo)
+        halo_ref[...] = halo
+
+    w = w_ref[...].astype(F32)
+
+    def rows_of_a_step(i, before):
+        lo = pl.multiple_of(i * step, step)
+        x = x_ref[0, pl.ds(lo, step), :].astype(F32)
+        window = jnp.concatenate([before, x], axis=0)
+        # sum_j w[j] window[t + j], oldest tap first (the XLA form's order)
+        y = w[:1] * pltpu.roll(window, taps - 1, 0)[_HALO:]
+        for j in range(1, taps - 1):
+            y = y + w[j:j + 1] * pltpu.roll(window, taps - 1 - j, 0)[_HALO:]
+        y = y + w[taps - 1:] * x
+        y = y * jax.nn.sigmoid(y)
+        if scale is None:  # v: no norm
+            o_ref[0, pl.ds(lo, step), :] = y
+        else:  # q and k: a head over its norm
+            for h in range(width // head):
+                lanes = slice(h * head, (h + 1) * head)
+                y_h = y[:, lanes]
+                normed = y_h * jax.lax.rsqrt(
+                    jnp.sum(y_h * y_h, axis=-1, keepdims=True) + eps
+                )
+                o_ref[0, pl.ds(lo, step), lanes] = (
+                    normed if scale == 1.0 else normed * scale
+                )
+        return x[step - _HALO:]
+
+    halo_ref[...] = jax.lax.fori_loop(
+        0, tile // step, rows_of_a_step, halo_ref[...]
+    )
+
+
+def _inputs_kernel(
+    x_q, x_k, x_v, c_q, c_k, c_v, w_q, w_k, w_v, q_ref, k_ref, v_ref, carried,
+    *, q_scale, **static,
+):
+    """One (row, channel tile, sequence tile) of the grid: the same tile of
+    q's, k's and v's third of the channels. The sequence axis is the last
+    and sequential: ``carried [3, _HALO, C]`` holds each third's rows before
+    the tile."""
+    _third_of_a_tile(x_q, c_q, w_q, q_ref, carried.at[0], scale=q_scale, **static)
+    _third_of_a_tile(x_k, c_k, w_k, k_ref, carried.at[1], scale=1.0, **static)
+    _third_of_a_tile(x_v, c_v, w_v, v_ref, carried.at[2], scale=None, **static)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=('form', 'head', 'q_scale', 'eps', 'interpret'),
+)
+def inputs_kernel(  # distlint: traced
+    projected, conv0, taps, *, form: tuple[int, int, int], head: int,
+    q_scale: float, eps: float, interpret: bool = False,
+):
+    """The way into the rule as one Pallas TPU kernel at ``form`` (sequence
+    tile, rows a step, channel tile: ``inputs_form``'s): of the q, k and v
+    projections' outputs ``projected = (q~, k~, v~)``, ``[B, S, H d]`` each,
+    behind the
+    carried rows ``conv0 [B, K - 1, 3 H d]`` (q's, k's, v's side by side),
+    all read as they lie in whatever dtype they come, the causal depthwise
+    convolution of ``taps [K, 3 H d]``, SiLU and, for q and k, each head's
+    L2 norm (``eps`` under the root) and q's ``q_scale``. Returns ``q, k, v
+    [B, S, H d]`` float32. Every sum, the logistic and the root are float32
+    on the vector unit; no intermediate leaves VMEM."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    tile, step, width = form
+    bsz, s, third = projected[0].shape
+    n_taps = taps.shape[0]
+    if (
+        s % tile or tile % step or step % _HALO
+        or third % width or width % head or head % 128
+        or not 2 <= n_taps <= _HALO + 1
+        or any(t.shape != (bsz, s, third) for t in projected)
+        or conv0.shape != (bsz, n_taps - 1, 3 * third)
+        or taps.shape != (n_taps, 3 * third)
+    ):
+        raise ValueError(
+            f'{form} does not tile three of {projected[0].shape} behind '
+            f'{conv0.shape} with taps {taps.shape} and heads of {head}'
+        )
+    tiles = third // width  # channel tiles a third: q's, then k's, then v's
+    # a third's own tile of the span: the projections' and the results'
+    tokens = pl.BlockSpec((1, tile, width), lambda b, c, t: (b, t, c))
+
+    def thirds(block, index):  # the same tile of each third, side by side
+        return [
+            pl.BlockSpec(block, functools.partial(index, first=i * tiles))
+            for i in range(3)
+        ]
+
+    with _one_source():
+        return pl.pallas_call(
+            functools.partial(
+                _inputs_kernel, step=step, head=head, q_scale=q_scale, eps=eps
+            ),
+            out_shape=(jax.ShapeDtypeStruct((bsz, s, third), F32),) * 3,
+            grid=(bsz, tiles, s // tile),
+            in_specs=[
+                tokens, tokens, tokens,
+                *thirds(
+                    (1, n_taps - 1, width),
+                    lambda b, c, t, first: (b, 0, first + c),
+                ),
+                *thirds((n_taps, width), lambda b, c, t, first: (0, first + c)),
+            ],
+            out_specs=[tokens, tokens, tokens],
+            scratch_shapes=[pltpu.VMEM((3, _HALO, width), F32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=('parallel', 'parallel', 'arbitrary'),
+            ),
+            interpret=interpret,
+            name='kda_inputs',
+        )(*projected, conv0, conv0, conv0, taps, taps, taps)

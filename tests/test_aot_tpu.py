@@ -1562,8 +1562,47 @@ def _solar_open2_prefill(v5e, rows, span=512):
     )
 
 
+@pytest.fixture(scope='module')
+def solar_open2_prefill_defs(v5e):
+    """The cell's ``(512, 4)`` prefill program compiled for a described v5e
+    with the family's kernels on, as ``_hlo_defs`` of its text."""
+    from distllm_tpu.models import moe
+    from distllm_tpu.ops import kda
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(moe, 'grouped_backend', lambda: 'pallas')
+        patch.setattr(kda, 'span_backend', lambda: 'pallas')
+        text = _solar_open2_prefill(v5e, rows=4).compile().as_text()
+    return _hlo_defs(text)
+
+
+def _kernel_calls(defs: dict, name: str) -> dict:
+    """``call's name -> its operands' names`` of a kernel's custom calls."""
+    import re
+
+    return {
+        call_name: re.findall(r'%([\w.\-]+)', call.partition(')')[0])
+        for call_name, (_, opcode, call) in defs.items()
+        if opcode == 'custom-call' and call_name.startswith(name)
+    }
+
+
+def _behind_the_moves(defs: dict, name: str) -> str:
+    """The instruction that made ``name``'s array, behind XLA's moves of it
+    between memories (an asynchronous copy, whole or in slices that a
+    ``ConcatBitcast`` joins): they change where it lies, not how."""
+    import re
+
+    moves = ('copy-done', 'copy-start', 'slice-done', 'slice-start', 'bitcast')
+    while True:
+        _, opcode, call = defs[name]
+        if opcode not in moves and 'ConcatBitcast' not in call:
+            return name
+        name = re.findall(r'%([\w.\-]+)', call.partition(')')[0])[0]
+
+
 def test_solar_open2_prefill_hands_the_span_kernel_its_operands_as_they_lie(
-    v5e, monkeypatch
+    solar_open2_prefill_defs,
 ):
     """The cell's ``(512, 4)`` prefill program for a described v5e with the
     span form as the kernel: Mosaic takes the kernel at the published head
@@ -1571,22 +1610,10 @@ def test_solar_open2_prefill_hands_the_span_kernel_its_operands_as_they_lie(
     ``[B, S, H d]`` straight from the fusions that make them and ``o`` leaves
     it so: no copy or transpose of an operand stands between (the scan read
     ``[N, B, H, C, d]`` float32 copies of all five)."""
-    import re
-
-    from distllm_tpu.models import moe
-    from distllm_tpu.ops import kda
-
-    monkeypatch.setattr(moe, 'grouped_backend', lambda: 'pallas')
-    monkeypatch.setattr(kda, 'span_backend', lambda: 'pallas')
-    text = _solar_open2_prefill(v5e, rows=4).compile().as_text()
-    defs = _hlo_defs(text)
-    calls = {
-        name: call for name, (_, opcode, call) in defs.items()
-        if opcode == 'custom-call' and name.startswith('kda_span')
-    }
+    defs = solar_open2_prefill_defs
+    calls = _kernel_calls(defs, 'kda_span')
     assert len(calls) == 3
-    for call in calls.values():
-        operands = re.findall(r'%([\w.\-]+)', call.partition(')')[0])
+    for operands in calls.values():
         assert len(operands) == 6
         for operand in operands:
             opcode = defs[operand][1]
@@ -1596,6 +1623,50 @@ def test_solar_open2_prefill_hands_the_span_kernel_its_operands_as_they_lie(
         if opcode in ('copy', 'transpose') and 'f32[4,512,8192]' in result
     ]
     assert not moved
+
+
+def test_solar_open2_prefill_makes_q_k_v_in_one_kernel_a_layer(
+    solar_open2_prefill_defs,
+):
+    """The same program's way into the rule (PR 47): each KDA layer calls
+    ``kda_inputs`` once; the three projections reach it straight from their
+    matmuls' fusions, in bfloat16 and with no concatenation, copy or
+    transpose between; its three results are the span kernel's first three
+    operands as they leave it; and neither the float32 passes of the XLA
+    form (``f32[4,515,24576]``, ``f32[4,512,24576]``) nor the convolutions'
+    whole input in any dtype (only the next span's rows read it) is left
+    anywhere in the program."""
+    import re
+
+    defs = solar_open2_prefill_defs
+    ways_in = _kernel_calls(defs, 'kda_inputs')
+    assert len(ways_in) == 3
+    for operands in ways_in.values():
+        assert len(operands) == 9  # q~, k~, v~; the carried rows and taps x 3
+        made_by = [_behind_the_moves(defs, name) for name in operands[:3]]
+        assert len(set(made_by)) == 3
+        for operand, maker in zip(operands[:3], made_by):
+            assert defs[operand][0].startswith('bf16[4,512,8192]'), operand
+            result, opcode, call = defs[maker]
+            # a projection's matmul, in the layout the kernel reads
+            assert opcode == 'fusion' and 'dot_general' in call, (maker, call)
+            assert result.startswith('bf16[4,512,8192]{2,1,0'), (maker, result)
+    spans = _kernel_calls(defs, 'kda_span')
+    fed = set()
+    for operands in spans.values():
+        for i, operand in enumerate(operands[:3]):
+            _, opcode, call = defs[operand]
+            assert opcode == 'get-tuple-element', (operand, opcode)
+            source = re.findall(r'%([\w.\-]+)', call)[0]
+            assert source in ways_in and f'index={i}' in call, call
+            fed.add(source)
+    assert fed == set(ways_in)
+    whole = re.compile(r'\[4,51[25],24576\]')
+    left = [
+        (name, result[:40]) for name, (result, opcode, _) in defs.items()
+        if whole.search(result) and opcode != 'parameter'
+    ]
+    assert not left
 
 
 def test_span_kernel_lowers_to_the_same_text_whoever_traces_it_first(v5e):
@@ -1626,5 +1697,39 @@ def test_span_kernel_lowers_to_the_same_text_whoever_traces_it_first(v5e):
     other.start()
     other.join()
     _ = jnp.where(jnp.ones((32, 1), bool), jnp.ones((32, 128)), 0.0) * 2.0
+    assert 'tpu_custom_call' in alone
+    assert lowered(4) == alone
+
+
+def test_inputs_kernel_lowers_to_the_same_text_whoever_traces_it_first(v5e):
+    """The way-in kernel's body is part of the compile cache's key as the
+    span kernel's is, and the cell's check traces ``_kda_inputs`` on a thread
+    beside the engine's warm-up: traced first from another stack, on another
+    thread, at these shapes and others, it lowers to the bytes it lowers to
+    alone (``kda._one_source``)."""
+    import threading
+
+    from distllm_tpu.ops import kda
+
+    def lowered(rows):
+        third = v5e((rows, 512, 8192), jnp.bfloat16)
+        return jax.jit(
+            lambda *a: kda.inputs_kernel(
+                a[:3], *a[3:], form=(512, 128, 256), head=128,
+                q_scale=128 ** -0.5, eps=1e-6,
+            )
+        ).lower(
+            third, third, third, v5e((rows, 3, 24576), jnp.bfloat16),
+            v5e((4, 24576), jnp.bfloat16),
+        ).as_text()
+
+    alone = lowered(4)
+    jax.clear_caches()
+    other = threading.Thread(
+        target=lambda: [(lambda rows: lowered(rows))(rows) for rows in (1, 4)]
+    )
+    other.start()
+    other.join()
+    _ = jax.nn.sigmoid(jnp.ones((128, 256))) * jnp.ones((1, 256))
     assert 'tpu_custom_call' in alone
     assert lowered(4) == alone
