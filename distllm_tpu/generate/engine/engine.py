@@ -756,6 +756,11 @@ class LLMEngine:
         self.cache_spec = spec
         self._refuse_unservable(spec, cfg, mesh, kv_pool_dtype)
         self._programs = importlib.import_module(spec.programs)
+        # On the prefill and decode records of a model whose stack runs
+        # several times a token: the passes, and the K/V planes they fill.
+        self._loop_fields = {} if spec.passes == 1 else {
+            'loop_passes': spec.passes, 'kv_planes': spec.paged[0].num_layers,
+        }
         self.state_pool = None
         if spec.state is not None:
             self.state_pool = StatePool(
@@ -1408,6 +1413,7 @@ class LLMEngine:
                 # Routed experts: FLOPs count the parameters a token
                 # reaches, not the whole bank.
                 experts_per_token=getattr(model, 'experts_per_token', None),
+                layer_passes=spec.passes,
             )
         except Exception as exc:
             self.telemetry['roofline_fallback'] = repr(exc)[:300]
@@ -1488,6 +1494,29 @@ class LLMEngine:
                     raise ValueError(
                         f'{setting} cannot serve a model with a latent '
                         f'cache group: {why}'
+                    )
+        if spec.passes > 1:
+            refused = {
+                'enable_mixed_batching': cfg.enable_mixed_batching
+                and 'the mixed window is the single-pass K/V family\'s '
+                'program',
+                'draft_k': bool(cfg.draft_k)
+                and 'the speculative window is the single-pass K/V '
+                'family\'s program',
+                'kv_cache_dtype=int8': int8
+                and 'a plane a pass under one scale row a block was never '
+                'held to the reference',
+                'quantization': bool(cfg.quantization)
+                and 'quantized kernels were never run through the loop '
+                'over the passes',
+                'mesh': mesh is not None
+                and 'the loop over the passes was never partitioned',
+            }
+            for setting, why in refused.items():
+                if why:
+                    raise ValueError(
+                        f'{setting} cannot serve a looped model (its stack '
+                        f'runs {spec.passes} times a token): {why}'
                     )
         if not spec.windowed:
             return
@@ -1615,7 +1644,7 @@ class LLMEngine:
         rows) and fold its pools back. Returns ``(tokens, last_ids,
         moe_pairs)``, the last None unless the family counts them (a
         family may return a dict of named int32 counters in their place:
-        each becomes a field of the ``decode`` record)."""
+        each becomes a field of the ``decode`` record, a number or a list)."""
         ids, pos, ctx, *rest = plan
         k, v = self._pools()
         extra = () if self.state_pool is None else (self.state_pool.state,)
@@ -3683,7 +3712,7 @@ class LLMEngine:
             'prefill', step, batch=len(requests),
             tokens=int(tail_lens.sum()), route=route, kv_blocks=kv_blocks,
             **window_fields, **self._moe_form_field(b * bucket),
-            **self._rids_field(requests),
+            **self._loop_fields, **self._rids_field(requests),
         )
         return emitted
 
@@ -4750,7 +4779,7 @@ class LLMEngine:
         counters = None  # a family's named counters in the pairs' place
         if isinstance(moe_pairs, dict):
             # distlint: disable=host-sync-in-hot-path -- a few int32 the window's program wrote with the tokens fetched one line up: ready, never waited for
-            counters = {name: int(np.asarray(n)) for name, n in moe_pairs.items()}
+            counters = {name: np.asarray(n).tolist() for name, n in moe_pairs.items()}
             moe_pairs = None
         if moe_pairs is not None:
             # distlint: disable=host-sync-in-hot-path -- two int32 the window's program wrote with the tokens fetched one line up: ready, never waited for
@@ -4797,6 +4826,7 @@ class LLMEngine:
                 }
             if not chunk_entries:  # a decode window runs every slot's row
                 extra.update(self._moe_form_field(tokens.shape[1]))
+            extra.update(self._loop_fields)
             kv_blocks = self._kv_blocks(*window['context_lens'])
             if window.get('window_fields'):
                 extra.update(window['window_fields'], kv_blocks_full=kv_blocks)
@@ -5292,8 +5322,8 @@ class LLMEngine:
         return {'state_slot': self.sched.slot(request.request_id)}
 
     def _kv_ends_field(self, request: Request) -> dict:
-        """For a model with a windowed or a latent cache group, or with a
-        state pool: the ids of
+        """For a model with a windowed or a latent cache group, with a
+        state pool, or whose stack runs several times: the ids of
         two blocks of the full-context group the request held when it
         finished, its
         first and the one that holds the last position it wrote (its last
@@ -5303,7 +5333,7 @@ class LLMEngine:
         left."""
         if (
             self.window_kv is None and not self.cache_spec.latent
-            and self.state_pool is None
+            and self.state_pool is None and self.cache_spec.passes == 1
         ):
             return {}
         row = self.sched.block_row(request.request_id)

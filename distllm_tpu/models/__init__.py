@@ -29,6 +29,7 @@ def decoder_families() -> dict:
         lfm2,
         mistral,
         mixtral,
+        ouro,
         solar_open2,
     )
 
@@ -47,6 +48,7 @@ def decoder_families() -> dict:
         'lfm2_moe': (lfm2.Lfm2MoeConfig, lfm2),
         'falcon_h1': (falcon_h1.FalconH1Config, falcon_h1),
         'solar_open2': (solar_open2.SolarOpen2Config, solar_open2),
+        'ouro': (ouro.OuroConfig, ouro),
     }
 
 

@@ -80,13 +80,13 @@ class CacheSpec:
     programs hand to the writers and the kernel whole, with the layer
     whose pages are meant: ``ops.paged_attention``).
     """
-
     paged: tuple[PagedGroup, ...]
     programs: str
     state: object | None = None
     program_prefix: str = ''
     dense_prefill: bool = True
     layer_buffers: bool = False
+    passes: int = 1  # runs of the stack a token, each with planes of its own
 
     @property
     def windowed(self) -> tuple[PagedGroup, ...]:

@@ -16,6 +16,7 @@ matching what the reference delegates to vLLM's ``tensor_parallel_size``.
 
 from __future__ import annotations
 
+import contextlib
 from typing import Literal
 
 import jax
@@ -301,6 +302,160 @@ def _attn_mask(attention_mask: jnp.ndarray, cfg: MistralConfig) -> jnp.ndarray:
     return causal[None, None] & attention_mask[:, None, None, :].astype(bool)
 
 
+def _unscoped(name: str):
+    """No scope: this family's programs carry no scope names. A family that
+    wants the halves of a layer found by name passes ``jax.named_scope``."""
+    return contextlib.nullcontext()
+
+
+def _mlp_half(x: jnp.ndarray, lp: dict, cfg) -> jnp.ndarray:
+    """A layer's second half: the MLP behind its norm (and before its own,
+    under sandwich norms) onto the residual."""
+    normed2 = _norm(x, lp['mlp_ln']['scale'], cfg)
+    mlp = _mlp_block(normed2, lp, cfg)
+    if getattr(cfg, 'post_norms', False):
+        mlp = _norm(mlp, lp['post_mlp_ln']['scale'], cfg)
+    return x + mlp
+
+
+def _span_layer(  # distlint: traced
+    cfg, rope, attn_backend, span, carry, lp, plane, window_l,
+    scope=_unscoped,
+):
+    """One layer over one span of every row through the paged cache:
+    ``prefill_paged``'s scan body. ``lp`` is the layer's weights and
+    ``plane`` the layer of the stacked pools it writes and reads: apart,
+    because a stack that runs several times (``models/ouro.py``) gives every
+    pass planes of its own. ``span`` is ``(positions, valid, block_tables,
+    context_lens, tail_lens)``, ``carry`` and the result ``(x, k_cache,
+    v_cache)``."""
+    from distllm_tpu.ops.paged_attention import (
+        ragged_paged_attention,
+        write_chunk_kv,
+    )
+
+    (cos, sin), (x, k_cache, v_cache) = rope, carry
+    positions, valid, block_tables, context_lens, tail_lens = span
+    alternating = (
+        getattr(cfg, 'sliding_window_pattern', 'all') == 'alternating'
+    )
+    qb = getattr(cfg, 'qmm_backend', None)
+    with scope('distllm.attn_full'):
+        normed = _norm(x, lp['attn_ln']['scale'], cfg)
+        q = common.split_heads(
+            common.dense(
+                normed, lp['q']['kernel'], lp['q'].get('bias'), qmm_backend=qb
+            ),
+            cfg.num_heads,
+        )
+        k = common.split_heads(
+            common.dense(
+                normed, lp['k']['kernel'], lp['k'].get('bias'), qmm_backend=qb
+            ),
+            cfg.num_kv_heads,
+        )
+        v = common.split_heads(
+            common.dense(
+                normed, lp['v']['kernel'], lp['v'].get('bias'), qmm_backend=qb
+            ),
+            cfg.num_kv_heads,
+        )
+        q = common.apply_rope(q, cos, sin, positions)
+        k = common.apply_rope(k, cos, sin, positions)
+        # Write the tail's K/V first, then attend over the paged cache —
+        # cached prefix and own chunk through one gather (decode's
+        # write-then-attend order, generalized to S queries). The stacked
+        # pools go to both whole, with the layer whose pages are meant: a
+        # layer sliced out would be copied out of the pool and back.
+        k_cache, v_cache = write_chunk_kv(
+            k_cache, v_cache, k, v, block_tables, positions, valid,
+            layer=plane,
+        )
+        # q_lens masks PADDING queries (XLA: onto key 0; Pallas: to exact
+        # zeros): under a sliding window a pad query past the window's
+        # reach otherwise has an all-masked score row -> NaN attention ->
+        # NaN K/V written to the TRASH block -> every later dispatch
+        # whose block-table padding gathers block 0 poisons its softmax·V
+        # contraction (0 x NaN = NaN). Valid rows are bit-identical with
+        # or without the mask.
+        attn = ragged_paged_attention(
+            q, k_cache, v_cache, block_tables, context_lens, positions,
+            q_lens=tail_lens,
+            sliding_window=(
+                window_l if alternating else cfg.sliding_window
+            ),
+            scale=getattr(cfg, 'query_scale', None),
+            logit_softcap=getattr(cfg, 'attn_logit_softcap', None),
+            backend=attn_backend, layer=plane,
+        )
+        attn_out = common.dense(
+            common.merge_heads(attn), lp['o']['kernel'], qmm_backend=qb
+        )
+        if getattr(cfg, 'post_norms', False):
+            attn_out = _norm(attn_out, lp['post_attn_ln']['scale'], cfg)
+        x = x + attn_out
+    with scope('distllm.dense_mlp'):
+        x = _mlp_half(x, lp, cfg)
+    return x, k_cache, v_cache
+
+
+def _token_layer(  # distlint: traced
+    cfg, rope, attn_backend, row, carry, lp, plane, window_l,
+    scope=_unscoped,
+):
+    """One layer over one token of every row: ``_decode_core``'s scan body,
+    with the layer's weights ``lp`` and its ``plane`` of the stacked pools
+    apart, as :func:`_span_layer` has them. ``row`` is ``(positions,
+    block_tables, context_lens)``, ``carry`` and the result ``(x, k_cache,
+    v_cache)``."""
+    from distllm_tpu.ops.paged_attention import decode_attention, write_token_kv
+
+    (cos, sin), (x, k_cache, v_cache) = rope, carry
+    positions, block_tables, context_lens = row
+    alternating = (
+        getattr(cfg, 'sliding_window_pattern', 'all') == 'alternating'
+    )
+    qb = getattr(cfg, 'qmm_backend', None)
+    with scope('distllm.attn_full'):
+        normed = _norm(x, lp['attn_ln']['scale'], cfg)
+        q = common.dense(
+            normed, lp['q']['kernel'], lp['q'].get('bias'), qmm_backend=qb
+        ).reshape(-1, cfg.num_heads, cfg.head_size)
+        k = common.dense(
+            normed, lp['k']['kernel'], lp['k'].get('bias'), qmm_backend=qb
+        ).reshape(-1, cfg.num_kv_heads, cfg.head_size)
+        v = common.dense(
+            normed, lp['v']['kernel'], lp['v'].get('bias'), qmm_backend=qb
+        ).reshape(-1, cfg.num_kv_heads, cfg.head_size)
+        # RoPE at each sequence's own position ([B, 1, N, Hd] view).
+        q = common.apply_rope(q[:, None], cos, sin, positions[:, None])[:, 0]
+        k = common.apply_rope(k[:, None], cos, sin, positions[:, None])[:, 0]
+        k_cache, v_cache = write_token_kv(
+            k_cache, v_cache, k, v, block_tables, positions, layer=plane
+        )
+        attn = decode_attention(
+            q, k_cache, v_cache, block_tables, context_lens, positions,
+            backend=attn_backend, layer=plane,
+            # Traced per-layer window only for the alternating pattern;
+            # other families keep the static value so their decode HLO
+            # is unchanged.
+            sliding_window=window_l if alternating else cfg.sliding_window,
+            scale=getattr(cfg, 'query_scale', None),
+            logit_softcap=getattr(cfg, 'attn_logit_softcap', None),
+        )
+        attn_out = common.dense(
+            attn.reshape(-1, cfg.num_heads * cfg.head_size),
+            lp['o']['kernel'],
+            qmm_backend=qb,
+        )
+        if getattr(cfg, 'post_norms', False):
+            attn_out = _norm(attn_out, lp['post_attn_ln']['scale'], cfg)
+        x = x + attn_out
+    with scope('distllm.dense_mlp'):
+        x = _mlp_half(x, lp, cfg)
+    return x, k_cache, v_cache
+
+
 def apply(  # distlint: traced
     params: dict,
     cfg: MistralConfig,
@@ -372,84 +527,22 @@ def prefill_paged(  # distlint: traced
     verify dispatch shares every numeric property of this path (the
     greedy-identity backbone of docs/speculative.md).
     """
-    from distllm_tpu.ops.paged_attention import (
-        ragged_paged_attention,
-        write_chunk_kv,
-    )
-
     b, s = input_ids.shape
     table_len = max_table_positions or cfg.max_position_embeddings
-    cos, sin = _rope_tables(cfg, table_len)
-    alternating = (
-        getattr(cfg, 'sliding_window_pattern', 'all') == 'alternating'
-    )
+    rope = _rope_tables(cfg, table_len)
     layer_windows = jnp.where(
         _layer_window_flags(cfg), cfg.sliding_window or 0, 0
     ).astype(jnp.int32)
     valid = jnp.arange(s)[None, :] < tail_lens[:, None]  # [B, S]
     x = _embed_tokens(params, cfg, input_ids)  # [B, S, H]
-    qb = getattr(cfg, 'qmm_backend', None)
+    span = (positions, valid, block_tables, context_lens, tail_lens)
 
     def layer(carry, xs):
-        x, k_cache, v_cache = carry
         lp, li, window_l = xs
-        normed = _norm(x, lp['attn_ln']['scale'], cfg)
-        q = common.split_heads(
-            common.dense(
-                normed, lp['q']['kernel'], lp['q'].get('bias'), qmm_backend=qb
-            ),
-            cfg.num_heads,
-        )
-        k = common.split_heads(
-            common.dense(
-                normed, lp['k']['kernel'], lp['k'].get('bias'), qmm_backend=qb
-            ),
-            cfg.num_kv_heads,
-        )
-        v = common.split_heads(
-            common.dense(
-                normed, lp['v']['kernel'], lp['v'].get('bias'), qmm_backend=qb
-            ),
-            cfg.num_kv_heads,
-        )
-        q = common.apply_rope(q, cos, sin, positions)
-        k = common.apply_rope(k, cos, sin, positions)
-        # Write the tail's K/V first, then attend over the paged cache —
-        # cached prefix and own chunk through one gather (decode's
-        # write-then-attend order, generalized to S queries). The stacked
-        # pools go to both whole, with the layer whose pages are meant: a
-        # layer sliced out would be copied out of the pool and back.
-        k_cache, v_cache = write_chunk_kv(
-            k_cache, v_cache, k, v, block_tables, positions, valid, layer=li
-        )
-        # q_lens masks PADDING queries (XLA: onto key 0; Pallas: to exact
-        # zeros): under a sliding window a pad query past the window's
-        # reach otherwise has an all-masked score row -> NaN attention ->
-        # NaN K/V written to the TRASH block -> every later dispatch
-        # whose block-table padding gathers block 0 poisons its softmax·V
-        # contraction (0 x NaN = NaN). Valid rows are bit-identical with
-        # or without the mask.
-        attn = ragged_paged_attention(
-            q, k_cache, v_cache, block_tables, context_lens, positions,
-            q_lens=tail_lens,
-            sliding_window=(
-                window_l if alternating else cfg.sliding_window
-            ),
-            scale=getattr(cfg, 'query_scale', None),
-            logit_softcap=getattr(cfg, 'attn_logit_softcap', None),
-            backend=attn_backend, layer=li,
-        )
-        attn_out = common.dense(
-            common.merge_heads(attn), lp['o']['kernel'], qmm_backend=qb
-        )
-        if getattr(cfg, 'post_norms', False):
-            attn_out = _norm(attn_out, lp['post_attn_ln']['scale'], cfg)
-        x = x + attn_out
-        normed2 = _norm(x, lp['mlp_ln']['scale'], cfg)
-        mlp = _mlp_block(normed2, lp, cfg)
-        if getattr(cfg, 'post_norms', False):
-            mlp = _norm(mlp, lp['post_mlp_ln']['scale'], cfg)
-        return (x + mlp, k_cache, v_cache), None
+        # the layer's weights and its plane of the pools are one index here
+        return _span_layer(
+            cfg, rope, attn_backend, span, carry, lp, li, window_l
+        ), None
 
     (x, k_cache, v_cache), _ = jax.lax.scan(
         layer,
@@ -475,9 +568,26 @@ def _forward(
     params, cfg, input_ids, attention_mask, *, collect_kv,
     mesh=None, seq_parallel=None,
 ):
-    b, s = input_ids.shape
-    cos, sin = _rope_tables(cfg, s)
+    rope = _rope_tables(cfg, input_ids.shape[1])
     x = _embed_tokens(params, cfg, input_ids)
+    x, kv = _dense_layers(
+        params, cfg, rope, x, attention_mask, collect_kv=collect_kv,
+        mesh=mesh, seq_parallel=seq_parallel,
+    )
+    hidden = _norm(x, params['final_ln']['scale'], cfg)
+    if collect_kv:
+        return hidden, kv[0], kv[1]
+    return hidden, None, None
+
+
+def _dense_layers(  # distlint: traced
+    params, cfg, rope, x, attention_mask, *, collect_kv,
+    mesh=None, seq_parallel=None,
+):
+    """The stack once over ``x [B, S, H]`` with no cache: ``_forward``
+    between the embedding and the final norm (a stack that runs several
+    times calls it a pass). Returns ``(x, (k, v) or None)``."""
+    cos, sin = rope
     use_sp = (
         seq_parallel is not None
         and mesh is not None
@@ -563,21 +673,12 @@ def _forward(
         )
         if getattr(cfg, 'post_norms', False):
             attn_out = _norm(attn_out, lp['post_attn_ln']['scale'], cfg)
-        x = x + attn_out
-        normed2 = _norm(x, lp['mlp_ln']['scale'], cfg)
-        mlp = _mlp_block(normed2, lp, cfg)
-        if getattr(cfg, 'post_norms', False):
-            mlp = _norm(mlp, lp['post_mlp_ln']['scale'], cfg)
-        x = x + mlp
+        x = _mlp_half(x + attn_out, lp, cfg)
         return x, (k, v) if collect_kv else None
 
-    x, kv = jax.lax.scan(
+    return jax.lax.scan(
         layer, x, (params['layers'], _layer_window_flags(cfg))
     )
-    hidden = _norm(x, params['final_ln']['scale'], cfg)
-    if collect_kv:
-        return hidden, kv[0], kv[1]
-    return hidden, None, None
 
 
 def _decode_core(
@@ -611,20 +712,15 @@ def _decode_core(
     ``models/laguna.py``). Prefill keeps the rolled scan: compute-bound,
     and the weights' slice traffic amortizes over the whole token batch.
     """
-    from distllm_tpu.ops.paged_attention import decode_attention, write_token_kv
-
-    alternating = (
-        getattr(cfg, 'sliding_window_pattern', 'all') == 'alternating'
-    )
-
     # int32 [L] per-layer windows (0 = global) riding the layer scan; only
-    # consulted when `alternating`.
+    # consulted under the alternating pattern.
     layer_windows = jnp.where(
         _layer_window_flags(cfg), cfg.sliding_window or 0, 0
     ).astype(jnp.int32)
 
-    (cos, sin), (k_cache, v_cache) = rope, caches
+    k_cache, v_cache = caches
     x = _embed_tokens(params, cfg, input_ids)  # [B, H]
+    row = (positions, block_tables, context_lens)
 
     # The FULL caches ride the scan carry and each layer scatters its new
     # rows into its own pages of them, in place. Unrolled, that chain of
@@ -636,50 +732,11 @@ def _decode_core(
     # allocates a full stacked output buffer: +1 GB at 7B dims, and one
     # more when a multi-step window scan wraps this — that overflowed the
     # v5e's 16 GB HBM.)
-    qb = getattr(cfg, 'qmm_backend', None)
-
     def layer(carry, xs):
-        x, k_cache, v_cache = carry
         lp, li, window_l = xs
-        normed = _norm(x, lp['attn_ln']['scale'], cfg)
-        q = common.dense(
-            normed, lp['q']['kernel'], lp['q'].get('bias'), qmm_backend=qb
-        ).reshape(-1, cfg.num_heads, cfg.head_size)
-        k = common.dense(
-            normed, lp['k']['kernel'], lp['k'].get('bias'), qmm_backend=qb
-        ).reshape(-1, cfg.num_kv_heads, cfg.head_size)
-        v = common.dense(
-            normed, lp['v']['kernel'], lp['v'].get('bias'), qmm_backend=qb
-        ).reshape(-1, cfg.num_kv_heads, cfg.head_size)
-        # RoPE at each sequence's own position ([B, 1, N, Hd] view).
-        q = common.apply_rope(q[:, None], cos, sin, positions[:, None])[:, 0]
-        k = common.apply_rope(k[:, None], cos, sin, positions[:, None])[:, 0]
-        k_cache, v_cache = write_token_kv(
-            k_cache, v_cache, k, v, block_tables, positions, layer=li
-        )
-        attn = decode_attention(
-            q, k_cache, v_cache, block_tables, context_lens, positions,
-            backend=attn_backend, layer=li,
-            # Traced per-layer window only for the alternating pattern;
-            # other families keep the static value so their decode HLO
-            # is unchanged.
-            sliding_window=window_l if alternating else cfg.sliding_window,
-            scale=getattr(cfg, 'query_scale', None),
-            logit_softcap=getattr(cfg, 'attn_logit_softcap', None),
-        )
-        attn_out = common.dense(
-            attn.reshape(-1, cfg.num_heads * cfg.head_size),
-            lp['o']['kernel'],
-            qmm_backend=qb,
-        )
-        if getattr(cfg, 'post_norms', False):
-            attn_out = _norm(attn_out, lp['post_attn_ln']['scale'], cfg)
-        x = x + attn_out
-        normed2 = _norm(x, lp['mlp_ln']['scale'], cfg)
-        mlp = _mlp_block(normed2, lp, cfg)
-        if getattr(cfg, 'post_norms', False):
-            mlp = _norm(mlp, lp['post_mlp_ln']['scale'], cfg)
-        return (x + mlp, k_cache, v_cache), None
+        return _token_layer(
+            cfg, rope, attn_backend, row, carry, lp, li, window_l
+        ), None
 
     (x, k_cache, v_cache), _ = jax.lax.scan(
         layer,
@@ -763,7 +820,7 @@ def decode_loop(  # distlint: traced
 
     Returns ``(tokens [num_steps, B] int32, k_cache, v_cache, last_ids)``.
     """
-    from functools import partial  # here: no line above ``prefill_paged`` may move
+    from functools import partial
 
     # RoPE tables bounded by what positions can actually reach: the block
     # table row covers max_table_positions tokens (engine max_model_len) —

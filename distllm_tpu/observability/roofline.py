@@ -156,7 +156,7 @@ class CostModel:
     @classmethod
     def from_params(
         cls, params, decode_steps: int, device=None, num_devices: int = 1,
-        experts_per_token: int | None = None,
+        experts_per_token: int | None = None, layer_passes: int = 1,
     ) -> 'CostModel':
         """Price the ACTUAL weight set: quantized codes, scales, migrated
         layouts — whatever is in the tree is what streams from HBM.
@@ -172,12 +172,21 @@ class CostModel:
         beside a ``router`` count at that share in ``n_params`` (the FLOPs
         side). The bytes side keeps every bank: a decode batch reaches them
         all.
+
+        ``layer_passes`` (a looped model, ``CacheSpec.passes``): a token
+        runs the stacked tree ``params['layers']`` that many times, so a
+        step streams and multiplies by its weights that often; everything
+        beside the stack counts once.
         """
         import jax
 
         leaves = jax.tree.leaves(params)
         n_params = sum(getattr(x, 'size', 0) for x in leaves)
         weight_bytes = sum(getattr(x, 'nbytes', 0) for x in leaves)
+        if layer_passes > 1:
+            for leaf in jax.tree.leaves(params['layers']):
+                n_params += getattr(leaf, 'size', 0) * (layer_passes - 1)
+                weight_bytes += getattr(leaf, 'nbytes', 0) * (layer_passes - 1)
         if experts_per_token:
             n_params -= _unreached_expert_params(params, experts_per_token)
         if isinstance(params, dict) and 'head' in params and 'embed' in params:

@@ -1733,3 +1733,80 @@ def test_inputs_kernel_lowers_to_the_same_text_whoever_traces_it_first(v5e):
     _ = jax.nn.sigmoid(jnp.ones((128, 256))) * jnp.ones((1, 256))
     assert 'tpu_custom_call' in alone
     assert lowered(4) == alone
+
+
+# ---- a looped model's planes: 192 of them under one table (PR 48) ----
+
+@pytest.fixture(scope='module')
+def ouro_cell(v5e):
+    """The ``ouro-2.6b`` configuration at FULL depth (48 layers, 4 passes:
+    PR 40's lesson, a cut in depth does not show what XLA does to a stacked
+    tree under the whole walk), the parameters as shapes, and the cell's
+    pool: 192 planes of the configuration's blocks."""
+    import json
+    from pathlib import Path
+
+    from distllm_tpu.models import ouro
+
+    root = Path(__file__).resolve().parents[1]
+    hf = json.loads((root / 'benchmarks/configs/ouro-2.6b.json').read_text())
+    cfg = ouro.OuroConfig.from_hf_config(hf).model_copy(update={'dtype': hf['dtype']})
+    assert (cfg.num_layers, cfg.total_ut_steps) == (48, 4)
+    shapes = jax.eval_shape(lambda: ouro.init_on_device(jax.random.PRNGKey(0), cfg))
+    params = jax.tree.map(lambda a: v5e(a.shape, a.dtype), shapes)
+    engine = hf['engine']
+    blocks, row = engine['num_blocks'], cfg.num_kv_heads * cfg.head_size
+    if blocks * engine['block_size'] * row == cfg.hidden_size * cfg.intermediate_size:
+        # 352 blocks make a plane the size of an MLP kernel (2048 x 5632),
+        # and the checks below tell arrays apart by their size
+        blocks -= 1
+    pool = (cfg.num_planes, blocks, engine['block_size'], row)
+    return ouro, cfg, params, pool, engine
+
+
+def test_ouro_decode_window_addresses_192_planes(v5e, ouro_cell):
+    """The decode window at the cell's rows: the passes a rolled loop around
+    the 48 unrolled layers, both pools in its carry, the plane a traced ``t
+    * L + l``. No op has a pool-sized result but the in-place write, none a
+    plane-sized one, and the kernel's decode calls (one query a KV head, a
+    folded row of 2048 lanes) take the row walk over the pool as it lies."""
+    ouro, cfg, params, pool, engine = ouro_cell
+    b, i32, f32 = engine['max_num_seqs'], jnp.int32, jnp.float32
+    tables = -(-engine['max_model_len'] // engine['block_size'])
+    pools = v5e(pool, jnp.bfloat16)
+    compiled = jax.jit(
+        lambda p, i, po, c, k, v, bt, sl, tmp, tp_, mp, tk, sd: ouro.decode_loop(
+            p, cfg, i, po, k, v, bt, c, sl, tmp, tp_, mp, tk, sd,
+            num_steps=engine['decode_steps'], attn_backend='pallas',
+            max_table_positions=engine['max_model_len'],
+        ),
+        donate_argnums=(4, 5),
+    ).lower(
+        params, v5e((b,), i32), v5e((b,), i32), v5e((b,), i32), pools, pools,
+        v5e((b, tables), i32), v5e((b,), i32), v5e((b,), f32), v5e((b,), f32),
+        v5e((b,), f32), v5e((b,), i32), v5e((b,), jnp.uint32),
+    ).compile()
+    _assert_stacked_pool_is_addressed(compiled, pool)
+    _assert_decode_calls_walk(compiled)
+    # 48 bodies and not 192: the kernel's calls of one pass
+    assert len(_kernel_schedules(compiled)) == cfg.num_layers
+
+
+def test_ouro_chunk_prefill_addresses_192_planes(v5e, ouro_cell):
+    """The ``(512, 1)`` program: a rolled layer scan inside the rolled loop
+    over the passes, the plane traced in both."""
+    ouro, cfg, params, pool, engine = ouro_cell
+    i32 = jnp.int32
+    tables = -(-engine['max_model_len'] // engine['block_size'])
+    pools = v5e(pool, jnp.bfloat16)
+    compiled = jax.jit(
+        lambda p, ids, pos, k, v, bt, ctx, tails: ouro.prefill_paged(
+            p, cfg, ids, pos, k, v, bt, ctx, tails,
+            max_table_positions=engine['max_model_len'], attn_backend='pallas',
+        ), donate_argnums=(3, 4),
+    ).lower(
+        params, v5e((1, 512), i32), v5e((1, 512), i32), pools, pools,
+        v5e((1, tables), i32), v5e((1,), i32), v5e((1,), i32),
+    ).compile()
+    _assert_stacked_pool_is_addressed(compiled, pool)
+    _assert_span_calls_keep_the_grid(compiled)

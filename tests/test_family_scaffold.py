@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import laguna_toy
 import lfm2_toy
 import numpy as np
+import ouro_toy
 import pytest
 import solar_open2_toy
 import test_state_pool_granite as granite_toy
@@ -41,11 +42,13 @@ _PARENT_BITS = {
     'falcon_h1': '09463b44e0d3bfd98c5429e853b43db2cd05476ead9b63cc027dab2aca85c83d',
     # taken where the family was written (PR 45), not on PR 44's parent
     'solar_open2': 'da55a43a7e7f04fc0fc8014976cbdee6c6b7d2fc4ab6e8fe199087e2fdd52fef',
+    # likewise (PR 48): ``mistral.init_on_device``'s tree and the exit gate
+    'ouro': '36c7c9240d95edc82dbf050dad7277afc3eff4524b7aaed06783d1a13fbe8fd3',
 }
 _TOYS = {
     'granite': granite_toy, 'laguna': laguna_toy, 'deepseek_v3': deepseek_toy,
     'lfm2': lfm2_toy, 'falcon_h1': falcon_h1_toy,
-    'solar_open2': solar_open2_toy,
+    'solar_open2': solar_open2_toy, 'ouro': ouro_toy,
 }
 
 
