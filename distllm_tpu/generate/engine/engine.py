@@ -77,6 +77,7 @@ from distllm_tpu.ops.paged_attention import (
     quantize_kv_rows,
     unfold_heads,
     walk_keys_a_step,
+    walk_pages_a_turn,
 )
 from distllm_tpu.ops.sampling import fold_row_keys, sample_tokens
 from distllm_tpu.resilience.admission import (
@@ -3829,31 +3830,42 @@ class LLMEngine:
         return sum(int(((c + (bs - 1)) // bs).sum()) for c in context_lens)
 
     def _kv_chunks(self, context_lens: np.ndarray) -> dict:
-        """``kv_chunks*``: the chunks the paged kernel's row walk fetches
-        for a decode dispatch's rows in one step of one layer of each cache
-        group: from the chunk that holds a row's sliding-window floor to the
-        one that holds its context's end (a pad row's one token is one
-        chunk). Against ``rows x ceil(table blocks / pages a chunk)`` it is
-        the share of the grid over chunks that fetched anything. Nothing
-        under a backend that has no walk. Reckoned where ``kv_blocks`` is,
-        after the step's spans have closed."""
+        """``kv_chunks*`` and ``kv_turns*``: the chunks the paged kernel's
+        row walk fetches for a decode dispatch's rows in one step of one
+        layer of each cache group, and the turns of pages within them that
+        hold a page a row sees (what its copies go by): from the one that
+        holds a row's sliding-window floor to the one that holds its
+        context's end (a pad row's one token is one chunk, one turn).
+        ``kv_chunks`` against ``rows x ceil(table blocks / pages a chunk)``
+        is the share of the grid over chunks that fetched anything;
+        ``kv_turns x keys a turn`` against ``kv_chunks x keys a step`` the
+        share of the chunks' width that holds anything. Nothing under a
+        backend that has no walk.
+        Reckoned where ``kv_blocks`` is, after the step's spans have
+        closed."""
         if not self._walk_keys:
             return {}
         fields = {}
         groups = self.cache_spec.paged
+        block = self.config.block_size
         for group in groups:
             keys = self._walk_keys[group.name]
             floor = 0
             if group.window is not None:
                 floor = np.maximum(context_lens - group.window, 0)
-            name = 'kv_chunks' if len(groups) == 1 else (
-                'kv_chunks_window' if group.window else 'kv_chunks_full'
+            suffix = '' if len(groups) == 1 else (
+                '_window' if group.window else '_full'
             )
-            fields[name] = int(
-                ((context_lens + keys - 1) // keys - floor // keys).sum()
-            )
+            for name, unit in (
+                ('kv_chunks', keys),
+                ('kv_turns', walk_pages_a_turn(keys // block) * block),
+            ):
+                fields[name + suffix] = int(
+                    ((context_lens + unit - 1) // unit - floor // unit).sum()
+                )
         if len(groups) > 1:
-            fields['kv_chunks'] = fields['kv_chunks_full']
+            for name in ('kv_chunks', 'kv_turns'):
+                fields[name] = fields[name + '_full']
         return fields
 
     def _rids_field(self, requests: list[Request]) -> dict:

@@ -779,7 +779,7 @@ def _ragged_paged_attn_kernel(
             )  # [rows, Hd]
             acc_ref[h] = acc_ref[h] * correction[:, :1] + pv
             m_ref[h] = new_m
-    if walk: return _walk_row(kernel_refs, compute, block_size, pages_per_chunk)  # noqa: E701
+    if walk: return _walk_row(kernel_refs, compute, block_size, pages_per_chunk, group, scale, logit_softcap, value_lanes, quantized)  # noqa: E701
     @pl.when(chunk_needed(c))
     def _():
         wait(c % 2)
@@ -948,13 +948,13 @@ def ragged_paged_attention_pallas(
     qg = qg.transpose(0, 2, 1, 3, 4).reshape(
         b, num_kv_heads, s * group, head_dim
     )
-    # The caches go to the kernel as they lie, head-folded [nb, bs,
-    # Nkv*Hd] (nb = L * blocks for a stacked pool: the bitcast above, and
-    # no slice of it, which would be copied for the call): inside the
-    # kernel each head is a 128-aligned lane band —
-    # the layout that keeps whole-page DMA descriptors contiguous AND
-    # per-head slices tile-aligned (see the kernel docstring for the two
-    # Mosaic rejections this designs out).
+    # The caches go to the kernel as they lie, head-folded [nb, bs, Nkv*Hd]
+    # (a stacked pool as one run of pages, never a slice): a head is a
+    # 128-aligned lane band. The walk at under a sublane tile of queries a
+    # KV head takes them, and its softmax state, STACKED (_walk_stacks).
+    state = (num_kv_heads, span_tile * group)  # [.., rows] of q, acc, m, l
+    if walk and _walk_stacks(group):  # ONE block of Nkv * group rows
+        state, qg = (1, num_kv_heads * group), qg.reshape(b, 1, -1, head_dim)
     extra_operands = []
     if quantized:
         if num_kv_heads > 128:
@@ -1006,7 +1006,7 @@ def ragged_paged_attention_pallas(
         grid=(b, num_q_tiles, num_chunks),
         in_specs=[
             pl.BlockSpec(
-                (None, num_kv_heads, rows, head_dim),
+                (None, *state, head_dim),
                 lambda i, qi, j, *_: (i, 0, qi, 0),
             ),
         ] + [pl.BlockSpec(memory_space=pl.ANY)] * (
@@ -1020,10 +1020,10 @@ def ragged_paged_attention_pallas(
             pltpu.SemaphoreType.DMA(
                 (2, pages_per_chunk, 4 if quantized else 1 if latent else 2)
             ),
-            pltpu.VMEM((num_kv_heads, rows, value_dim), jnp.float32),
-            pltpu.VMEM((num_kv_heads, rows, 128), jnp.float32),
-            pltpu.VMEM((num_kv_heads, rows, 128), jnp.float32),
-        ] + [pltpu.SMEM((1,), jnp.int32)] * walk,  # the walk's slot
+            pltpu.VMEM((*state, value_dim), jnp.float32),
+            pltpu.VMEM((*state, 128), jnp.float32),
+            pltpu.VMEM((*state, 128), jnp.float32),
+        ] + [pltpu.SMEM((2,), jnp.int32)] * walk,  # the walk's slot
     )
     out = pl.pallas_call(
         kernel,
@@ -1125,7 +1125,7 @@ class _KernelRefs(NamedTuple):
     acc: object
     m: object
     l: object  # noqa: E741 -- the online softmax's denominator
-    slot: object  # [1] int32 SMEM: the buffer slot the walk waits in next
+    slot: object  # [2] int32 SMEM: the walk's next slot, and whether it has begun
 
 
 def _kernel_refs(refs, latent, quantized, walk) -> _KernelRefs:
@@ -1144,13 +1144,16 @@ def _kernel_refs(refs, latent, quantized, walk) -> _KernelRefs:
 # WALK_MAX_KEYS, whose two slots of K (and V) pages stay within
 # WALK_BUFFER_BYTES of VMEM and whose copies within WALK_SEMAPHORES (every
 # copy in flight has a DMA semaphore of its own; 512 of them ran out of
-# the chip's 2 KB for them). A whole chunk's copies go WALK_PAGES_A_TURN a
-# loop turn. PERF.md section 6 (PR 38) has the sweep on the chip behind
-# the four.
+# the chip's 2 KB for them). Within a chunk the walk's unit is a TURN of
+# WALK_PAGES_A_TURN pages: its copies go a turn at a time, and the stacked
+# form of its softmax block a FOLD of WALK_TURNS_A_FOLD turns (512 keys at
+# blocks of 16). PERF.md section 6 has the sweeps on the chip behind the
+# four (PR 38) and behind the turn and the fold (PR 49).
 WALK_MAX_KEYS = 1024
 WALK_BUFFER_BYTES = 8 << 20
 WALK_SEMAPHORES = 256
 WALK_PAGES_A_TURN = 8
+WALK_TURNS_A_FOLD = 4
 
 
 def walk_keys_a_step(
@@ -1174,6 +1177,28 @@ def walk_keys_a_step(
     return 1 << (keys.bit_length() - 1)
 
 
+def walk_pages_a_turn(pages_per_chunk: int) -> int:
+    """Pages a turn of the row walk: the largest power of two that divides
+    a chunk's pages, at most WALK_PAGES_A_TURN (128 keys at blocks of 16,
+    256 at an int8 pool's 32: whole sublane tiles of every stored dtype)."""
+    return min(pages_per_chunk & -pages_per_chunk, WALK_PAGES_A_TURN)
+
+
+def _walk_stacks(group: int) -> bool:
+    """Whether the row walk's softmax block takes the KV heads' query rows
+    STACKED into one array, a fold of the chunk at a time
+    (:func:`_stacked_block`): where a KV head has fewer queries than a
+    sublane tile has rows. There the softmax of a head by itself runs on
+    vector registers that are mostly padding and pays its reductions and
+    its two dependent matmuls' latency once a head, so that a narrower
+    block only multiplies what a block costs (PERF.md section 6, PR 49: by
+    turns of 128 keys the per-head block took 1.1 to 3 times the whole
+    chunk's time); stacked, that cost is paid once a fold for all heads.
+    From 8 queries a head up the registers are full as they are and the
+    per-head block over the whole chunk stays."""
+    return group < 8
+
+
 def _default_keys_a_step(k_data, latent, walk) -> int:
     if walk:
         return walk_keys_a_step(
@@ -1183,7 +1208,129 @@ def _default_keys_a_step(k_data, latent, walk) -> int:
     return 512 if latent else 128
 
 
-def _walk_row(r: _KernelRefs, compute, block_size, pages_per_chunk):
+def _div(x, by):
+    """``x // by`` of two non-negative ints as ONE equation (``//`` traces
+    ten for the signs)."""
+    return jax.lax.div(x, jnp.int32(by))
+
+
+def _stacked_block(r: _KernelRefs, p_lo, p_hi, block_size, pages_per_chunk,
+                   group, scale, logit_softcap, value_lanes, quantized):
+    """The row walk's online-softmax block over the KV heads' query rows
+    STACKED (:func:`_walk_stacks`): ``block(slot, chunk)`` folds into
+    ``r.acc[0]``, ``r.m[0]``, ``r.l[0]`` (``[heads x group, ..]``) the
+    FOLDS of ``chunk`` (``WALK_TURNS_A_FOLD`` turns each, or the most that
+    divides a chunk's turns) that hold a page of ``[p_lo, p_hi)``, what the
+    grid step's row sees.
+
+    A head's scores come from ITS rows of the stacked queries (the others
+    zeroed) against its lane band of the keys, summed over the heads into
+    one ``[R, fold_keys]`` array: one mask, one pair of lane reductions and
+    one rescale a fold for all heads. A head's output rows are kept of the
+    probabilities against its band of the values. The mathematics is the
+    span schedule's ``compute``: float32 scores, maxima, sums and
+    accumulator, probabilities cast to the pages' dtype for the second
+    product, an int8 page's scale on its columns.
+    """
+    import jax.experimental.pallas as pl
+
+    seq, win = pl.program_id(0), r.window[0]
+    head_dim = r.q.shape[-1]
+    heads = r.k_buf.shape[-1] // head_dim
+    turn_pages = walk_pages_a_turn(pages_per_chunk)
+    fold_pages = turn_pages * max(  # whole folds a chunk
+        n for n in range(1, WALK_TURNS_A_FOLD + 1)
+        if pages_per_chunk // turn_pages % n == 0
+    )
+    fold_keys = fold_pages * block_size
+    row_head = _div(
+        jax.lax.broadcasted_iota(jnp.int32, (r.q.shape[1], 1), 0), group
+    )  # [R, 1]: the KV head a stacked row belongs to
+    column = jax.lax.broadcasted_iota(jnp.int32, (1, fold_keys), 1)
+
+    def page_scales(scale_buf, slot, page0, h):
+        """[1, fold_keys]: head ``h``'s scale of each key's page."""
+        col_page = _div(column, block_size)
+        vec = jnp.zeros((1, fold_keys), jnp.float32)
+        for p in range(fold_pages):  # static unroll
+            vec = jnp.where(col_page == p, scale_buf[slot, page0 + p, h], vec)
+        return vec
+
+    def fold(slot, chunk, t):
+        keys = pl.ds(pl.multiple_of(t * fold_keys, fold_keys), fold_keys)
+        q0 = r.q_start[seq]
+        # absolute key positions; every stacked row is the row's ONE query
+        kvp = chunk * (pages_per_chunk * block_size) + t * fold_keys + column
+        valid = (kvp < r.context_lens[seq]) & (kvp <= q0)
+        valid = valid & ((kvp > q0 - win) | (win <= 0))
+        q = r.q[0]  # [R, Hd]
+        scores = None
+        for h in range(heads):  # static unroll over KV heads
+            kh = r.k_buf[slot, keys, h * head_dim:(h + 1) * head_dim]
+            own = q if heads == 1 else jnp.where(
+                row_head == h, q, jnp.zeros_like(q)
+            )
+            part = jax.lax.dot_general(
+                own, kh.astype(q.dtype),
+                dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [R, C], zero outside head h's rows
+            if quantized:
+                part = part * page_scales(r.ks_buf, slot, t * fold_pages, h)
+            scores = part if scores is None else scores + part
+        scores = scores * scale
+        if logit_softcap is not None:
+            cap = jnp.float32(logit_softcap)
+            scores = jnp.tanh(scores / cap) * cap
+        scores = jnp.where(valid, scores, -jnp.inf)
+        m_prev = r.m[0]  # [R, 128] lane-replicated
+        new_m = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
+        # a fold holds a key the row sees, so ``new_m`` is finite
+        correction = jnp.exp(m_prev - new_m)  # m_prev=-inf -> 0
+        probs = jnp.exp(scores - new_m[:, :1])  # masked lanes -> 0
+        r.l[0] = r.l[0] * correction + jnp.sum(probs, axis=-1, keepdims=True)
+        out = None
+        for h in range(heads):
+            if value_lanes is not None:  # the values: the band's first lanes
+                vh = r.k_buf[slot, keys, h * head_dim:h * head_dim + value_lanes]
+            else:
+                vh = r.v_buf[slot, keys, h * head_dim:(h + 1) * head_dim]
+            weights = probs
+            if quantized:
+                weights = probs * page_scales(r.vs_buf, slot, t * fold_pages, h)
+                vh = vh.astype(q.dtype)
+            part = jax.lax.dot_general(
+                weights.astype(vh.dtype), vh,
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [R, Hd]: head h's rows are its output
+            if heads > 1:
+                part = jnp.where(row_head == h, part, 0.0)
+            out = part if out is None else out + part
+        r.acc[0] = r.acc[0] * correction[:, :1] + out
+        r.m[0] = new_m
+
+    def block(slot, chunk):
+        first = chunk * pages_per_chunk
+
+        def one(t, carry):
+            fold(slot, chunk, t)
+            return carry
+
+        jax.lax.fori_loop(
+            _div(jnp.maximum(p_lo - first, 0), fold_pages),
+            _div(
+                jnp.minimum(p_hi - first, pages_per_chunk) + fold_pages - 1,
+                fold_pages,
+            ),
+            one, 0,
+        )
+
+    return block
+
+
+def _walk_row(r: _KernelRefs, compute, block_size, pages_per_chunk, group,
+              scale, logit_softcap, value_lanes, quantized):
     """The paged kernel's schedule for a span of one (decode rows): grid
     over ROWS, and inside a grid step a loop over the chunks that row
     holds and can see, from the one with its sliding-window floor to the
@@ -1193,26 +1340,34 @@ def _walk_row(r: _KernelRefs, compute, block_size, pages_per_chunk):
     ``mistral7b`` decode call of which about 85 fetched); a row with no
     sequence iterates nothing and emits exact zeros.
 
-    Only the pages the row sees are copied: a chunk wider than what the
-    row has left moves no byte for the rest, and the mask discards what
-    the buffer still holds there. Two buffer slots alternate along the
-    whole CALL's walk (``r.slot`` carries the next one across grid
-    steps, as the buffers and their semaphores are carried): while a
-    chunk is computed the next one's copies are in flight, the row's next
-    chunk or, on its last, the first chunk of the next row that has one.
-    Only the call's first chunk is waited for with nothing behind it.
+    Within a chunk the unit is a TURN of :func:`walk_pages_a_turn` pages.
+    Only the pages the row sees are copied, the whole turns among them as
+    straight-line code a turn and the ragged run at either end by halves
+    (4, 2, 1 pages): a chunk wider than what the row has left moves no
+    byte for the rest. Two buffer slots alternate along the whole CALL's
+    walk (``r.slot`` carries the next one across grid steps, as the
+    buffers and their semaphores are carried): while a chunk is computed
+    the next one's copies are in flight, the row's next chunk or, on its
+    last, the first chunk of the next row that has one. Only the call's
+    first chunk is waited for with nothing behind it.
 
-    ``compute(slot, chunk)`` is the span schedule's own online-softmax
-    block (``_ragged_paged_attn_kernel``), traced once with a traced
-    slot.
+    The online-softmax block has two forms, chosen by :func:`_walk_stacks`
+    from the queries a KV head. Stacked (``r.q``, ``r.acc``, ``r.m``,
+    ``r.l`` hold ONE block of ``heads x group`` rows):
+    :func:`_stacked_block`, over the folds of a chunk that hold a key the
+    row sees, so that the masked rest of a part-filled chunk costs no
+    compute either. Otherwise
+    ``compute(slot, chunk)``, the span schedule's own block
+    (``_ragged_paged_attn_kernel``) over the whole chunk, traced once with
+    a traced slot: the mask discards what the buffer still holds past the
+    row's end.
     """
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     seq, rows = pl.program_id(0), pl.num_programs(0)
     win = r.window[0]
-    # pages a turn: the largest power of two that divides a chunk's pages
-    group = min(pages_per_chunk & -pages_per_chunk, WALK_PAGES_A_TURN)
+    turn_pages = walk_pages_a_turn(pages_per_chunk)
     # (pool in HBM, its two-slot buffer) of what a call has: K and V or a
     # latent plane, and an int8 pool's scale rows.
     planes, scales = (
@@ -1230,9 +1385,9 @@ def _walk_row(r: _KernelRefs, compute, block_size, pages_per_chunk):
         q0 = r.q_start[row]
         lo = jnp.where(win > 0, jnp.maximum(q0 - win + 1, 0), 0)
         hi = jnp.minimum(r.context_lens[row], q0 + 1)
-        p_lo = lo // block_size
+        p_lo = _div(lo, block_size)
         live = (r.q_lens[row] > 0) & (hi > lo)
-        return p_lo, jnp.where(live, (hi + block_size - 1) // block_size, p_lo)
+        return p_lo, jnp.where(live, _div(hi + block_size - 1, block_size), p_lo)
 
     def next_live(row):
         """The first row at or after ``row`` that sees a page (``rows``
@@ -1243,61 +1398,63 @@ def _walk_row(r: _KernelRefs, compute, block_size, pages_per_chunk):
 
         return jax.lax.while_loop(dead, lambda t: t + 1, row)
 
-    def copies(row, chunk, p_lo, p_hi, slot, act, whole_chunks=True):
-        """``act`` (start, or wait for) the copy of every page of
-        ``chunk`` within ``[p_lo, p_hi)`` into ``slot``: one contiguous
-        whole-page descriptor a page and plane, and an int8 pool's scale
-        rows beside them. A loop over the pages the row sees; a chunk it
-        sees WHOLE (every chunk of a long row but its last, and its first
-        under a window) goes ``group`` pages a turn, straight-line code the
-        compiler can schedule: on the chip a turn a page cost a latent
-        plane, whose page is 25 ns on the wire, a fifth of the call
-        (PERF.md section 6, PR 38)."""
+    def copies(row, chunk, p_lo, p_hi, slot, start):
+        """Start, or wait for, the copy of every page of ``chunk`` within
+        ``[p_lo, p_hi)`` into ``slot``: one contiguous whole-page
+        descriptor a page and plane, and an int8 pool's scale rows beside
+        them. No descriptor names a block outside that range (a windowed
+        group has given back what lies below it). The whole turns go as
+        straight-line code a turn, which the compiler can schedule; the
+        pages short of a whole turn, below the first (a window's floor
+        inside a turn) and above the last (the context's end), by halves
+        of a turn: three branches at most, each straight-line, where a
+        loop turn a page stood (PERF.md section 6, PRs 38 and 49)."""
         first = chunk * pages_per_chunk
+        # the row's places in the chunk, [lo, hi), and its whole turns
+        lo = jnp.maximum(p_lo - first, 0)
+        hi = jnp.minimum(p_hi - first, pages_per_chunk)
+        turn_lo = _div(lo + turn_pages - 1, turn_pages)
+        turn_hi = _div(hi, turn_pages)
+        head_end = jnp.minimum(hi, turn_lo * turn_pages)
+        tail = jnp.maximum(turn_hi * turn_pages, head_end)
 
-        def one(logical, p):  # ``p``: the page's place in the chunk
-            page_id = r.block_tables[row, logical]
+        def one(p):  # ``p``: the page's place in the chunk
+            # a wait reads the semaphore and the buffer's size alone
+            page_id = r.block_tables[row, first + p] if start else 0
             at = pl.ds(pl.multiple_of(p * block_size, block_size), block_size)
-            for i, (pool, buf) in enumerate(planes):
-                act(pltpu.make_async_copy(
-                    pool.at[page_id], buf.at[slot, at], r.sems.at[slot, p, i]
-                ))
-            for i, (pool, buf) in enumerate(scales, len(planes)):
-                act(pltpu.make_async_copy(
-                    pool.at[page_id], buf.at[slot, p], r.sems.at[slot, p, i]
-                ))
+            for i, (pool, buf) in enumerate((*planes, *scales)):
+                copy = pltpu.make_async_copy(
+                    pool.at[page_id], buf.at[slot, at if i < len(planes) else p],
+                    r.sems.at[slot, p, i],
+                )
+                copy.start() if start else copy.wait()
 
-        def some():
-            def page(logical, carry):
-                one(logical, logical - first)
-                return carry
+        def ragged(end, carry):
+            p = jnp.where(end == 0, lo, tail)
+            n = jnp.where(end == 0, head_end - lo, hi - tail)
+            for bit in range(1, turn_pages.bit_length()):
+                half = turn_pages >> bit  # 4, 2, 1 pages of a turn of 8
 
-            jax.lax.fori_loop(
-                jnp.maximum(p_lo, first),
-                jnp.minimum(p_hi, first + pages_per_chunk),
-                page, 0,
-            )
+                @pl.when(n & half != 0)
+                def _(p=p, half=half):
+                    for j in range(half):
+                        one(p + j)
 
-        if not whole_chunks or group == 1:
-            return some()
-        whole = (p_lo <= first) & (first + pages_per_chunk <= p_hi)
+                p = p + (n & half)
+            return carry
 
-        @pl.when(whole)
-        def _():
-            def turn(g, carry):
-                for j in range(group):
-                    one(first + g * group + j, g * group + j)
-                return carry
+        def turn(t, carry):
+            for j in range(turn_pages):
+                one(t * turn_pages + j)
+            return carry
 
-            jax.lax.fori_loop(0, pages_per_chunk // group, turn, 0)
-
-        pl.when(jnp.logical_not(whole))(some)
-
-    def start(copy):
-        copy.start()
-
-    def wait(copy):
-        copy.wait()
+        # the head exists only under a window, the tail where the row ends
+        # inside a turn: a chunk seen whole takes neither
+        jax.lax.fori_loop(
+            jnp.where(head_end > lo, 0, 1), jnp.where(hi > tail, 2, 1),
+            ragged, 0,
+        )
+        jax.lax.fori_loop(turn_lo, turn_hi, turn, 0)
 
     @pl.when(seq == 0)
     def _():
@@ -1307,20 +1464,19 @@ def _walk_row(r: _KernelRefs, compute, block_size, pages_per_chunk):
         # land there.
         for buf in (planes[-1][1], *(buf for _, buf in scales[1:])):
             buf[...] = jnp.zeros_like(buf)
-        r.slot[0] = 0
-        first = next_live(0)
-
-        @pl.when(first < rows)
-        def _():
-            p_lo, p_hi = visible(first)
-            copies(
-                first, p_lo // pages_per_chunk, p_lo, p_hi, 0, start,
-                whole_chunks=False,  # once a call: not worth its trace
-            )
+        r.slot[0] = 0  # the slot the walk waits in next
+        r.slot[1] = 0  # whether the call's first chunk has been started
 
     p_lo, p_hi = visible(seq)
-    c_lo = p_lo // pages_per_chunk
-    c_hi = (p_hi + pages_per_chunk - 1) // pages_per_chunk
+    c_lo = _div(p_lo, pages_per_chunk)
+    c_hi = _div(p_hi + pages_per_chunk - 1, pages_per_chunk)
+
+    block = compute  # (slot, chunk): the span schedule's, a head at a time
+    if _walk_stacks(group):
+        block = _stacked_block(
+            r, p_lo, p_hi, block_size, pages_per_chunk, group, scale,
+            logit_softcap, value_lanes, quantized,
+        )
 
     @pl.when(p_hi <= p_lo)
     def _():
@@ -1341,17 +1497,34 @@ def _walk_row(r: _KernelRefs, compute, block_size, pages_per_chunk):
             @pl.when(nxt < rows)
             def _():
                 n_lo, n_hi = visible(nxt)
-                n_chunk = jnp.where(nxt == seq, ci + 1, n_lo // pages_per_chunk)
-                copies(nxt, n_chunk, n_lo, n_hi, 1 - slot, start)
+                n_chunk = jnp.where(
+                    nxt == seq, ci + 1, _div(n_lo, pages_per_chunk)
+                )
+                copies(nxt, n_chunk, n_lo, n_hi, 1 - slot, True)
 
-            copies(seq, ci, p_lo, p_hi, slot, wait)
-            compute(slot, ci)
+            @pl.when(ci >= c_lo)
+            def _():
+                copies(seq, ci, p_lo, p_hi, slot, False)
+                block(slot, ci)
+
             return 1 - slot
 
-        r.slot[0] = jax.lax.fori_loop(c_lo, c_hi, chunk, r.slot[0])
-        for h in range(r.acc.shape[0]):
-            out = r.acc[h] / jnp.maximum(r.l[h][:, :1], 1e-9)
-            r.out[h] = out.astype(r.out.dtype)
+        # The call's first live row has nothing in flight: it takes one
+        # turn more, in front, that only starts its own first chunk.
+        r.slot[0] = jax.lax.fori_loop(
+            c_lo - 1 + r.slot[1], c_hi, chunk, r.slot[0]
+        )
+        r.slot[1] = 1
+        if _walk_stacks(group):  # a KV head's rows of the stacked block
+            r.acc[0] = r.acc[0] / jnp.maximum(r.l[0][:, :1], 1e-9)
+            for h in range(r.out.shape[0]):
+                r.out[h] = r.acc[0, h * group:(h + 1) * group].astype(
+                    r.out.dtype
+                )
+        else:
+            for h in range(r.acc.shape[0]):
+                out = r.acc[h] / jnp.maximum(r.l[h][:, :1], 1e-9)
+                r.out[h] = out.astype(r.out.dtype)
 
 
 def decode_attention(  # distlint: traced

@@ -117,26 +117,34 @@ def _assert_span_calls_keep_the_grid(compiled) -> None:
 
 # Mistral-7B-Instruct-v0.3 attention widths at the serving batch.
 _B, _NH, _NKV, _HD = 32, 32, 8, 128
+_7B = (_B, _NH, _NKV, 512)  # rows, heads, KV heads, the table's tokens
 
 
-@pytest.mark.parametrize('span', [1, 16], ids=['span1', 'span16'])
-@pytest.mark.parametrize(
-    'kv,block_size', [('bf16', 16), ('int8', 32)], ids=['bf16', 'int8']
-)
-def test_ragged_kernel_compiles_at_7b_widths(v5e, kv, block_size, span):
+@pytest.mark.parametrize('kv,block_size,span,widths', [
+    ('bf16', 16, 1, _7B), ('bf16', 16, 16, _7B),
+    ('int8', 32, 1, _7B), ('int8', 32, 16, _7B),
+    ('bf16', 16, 1, (16, 16, 16, 1024)),
+], ids=['bf16-span1', 'bf16-span16', 'int8-span1', 'int8-span16',
+        'ouro-span1'])
+def test_ragged_kernel_compiles_at_7b_widths(
+    v5e, kv, block_size, span, widths
+):
     """Decode (span 1) and chunk/verify (span 16) rows over a bf16 pool
     at block 16 and the int8 ``QuantizedKV`` pool at block 32 (int8 at
-    block 16 is refused by the kernel's own sublane contract)."""
+    block 16 is refused by the kernel's own sublane contract), and decode
+    rows at Ouro-2.6B's widths: 16 rows of ONE query a KV head at 16 heads,
+    2048-lane rows, 512 keys a step in four turns."""
     from distllm_tpu.ops.paged_attention import (
         QuantizedKV,
         ragged_paged_attention_pallas,
     )
 
-    num_blocks, max_blocks = 712, 512 // block_size
-    shape = (num_blocks, block_size, _NKV * _HD)  # head-folded, as stored
+    rows, nh, nkv, table_tokens = widths
+    num_blocks, max_blocks = 712, table_tokens // block_size
+    shape = (num_blocks, block_size, nkv * _HD)  # head-folded, as stored
     if kv == 'int8':
         pool = QuantizedKV(
-            v5e(shape, jnp.int8), v5e((num_blocks, _NKV), jnp.float32)
+            v5e(shape, jnp.int8), v5e((num_blocks, nkv), jnp.float32)
         )
     else:
         pool = v5e(shape, jnp.bfloat16)
@@ -145,9 +153,9 @@ def test_ragged_kernel_compiles_at_7b_widths(v5e, kv, block_size, span):
             q, k, v, bt, ctx, pos, q_lens=ql
         )
     ).lower(
-        v5e((_B, span, _NH, _HD), jnp.bfloat16), pool, pool,
-        v5e((_B, max_blocks), jnp.int32), v5e((_B,), jnp.int32),
-        v5e((_B, span), jnp.int32), v5e((_B,), jnp.int32),
+        v5e((rows, span, nh, _HD), jnp.bfloat16), pool, pool,
+        v5e((rows, max_blocks), jnp.int32), v5e((rows,), jnp.int32),
+        v5e((rows, span), jnp.int32), v5e((rows,), jnp.int32),
     ).compile()
     _assert_kernel_compiled(compiled)
     assert _kernel_schedules(compiled) == ['walk' if span == 1 else 'grid']
@@ -195,11 +203,13 @@ def _kernel_equations(span, *, rows, nh, nkv, hd, value_lanes=None):
     (dict(rows=4, nh=32, nkv=8, hd=128), 512, 1015),
     (dict(rows=4, nh=48, nkv=8, hd=128), 512, 1015),
     (dict(rows=4, nh=32, nkv=1, hd=640, value_lanes=512), 512, 880),
-], ids=['mistral16', 'mistral512', 'laguna512', 'kanana512'])
+    (dict(rows=4, nh=16, nkv=16, hd=128), 512, 1663),  # PR 48's tree
+], ids=['mistral16', 'mistral512', 'laguna512', 'kanana512', 'ouro16'])
 def test_span_kernel_traces_no_more_than_the_parent(widths, span, parent):
     """A span over one traces the parent's kernel, equation for
-    equation; the span-1 kernel (the row walk: ``compute`` traced once,
-    the page copies a loop) is smaller than it, printed beside it."""
+    equation; the span-1 kernel (the row walk: ``compute`` traced once
+    over a turn's band, the page copies straight-line a turn) is smaller
+    than it, printed beside it."""
     spans = _kernel_equations(span, **widths)
     walks = _kernel_equations(1, **widths)
     print(f'kernel jaxpr equations: span {span}: {spans}, span 1: {walks}')

@@ -137,20 +137,27 @@ _WALK_BS, _WALK_TABLE = 4, 16
 _WALK_CTX = (0, 1, 8, 9, 23, 64)
 
 
-def _walk_setup(rng, *, nh=8, nkv=2, hd=8, ctx=_WALK_CTX, num_blocks=72):
+# Blocks of 16 tokens, a chunk of 16 pages: a turn of 8 pages is 128 keys
+# and a chunk 256, as on the chip. Contexts that end on every edge of a
+# page, a turn and a chunk, and a row with no sequence between live rows.
+_EDGE_BS, _EDGE_TABLE, _EDGE_PAGES = 16, 34, 16
+_EDGE_CTX = (1, 15, 16, 17, 127, 0, 128, 129, 255, 256, 257, 513)
+
+
+def _walk_setup(rng, *, nh=8, nkv=2, hd=8, ctx=_WALK_CTX, num_blocks=72,
+                block=_WALK_BS, table=_WALK_TABLE):
     b = len(ctx)
     k, v = (
         jnp.asarray(
-            rng.normal(size=(num_blocks, _WALK_BS, nkv * hd)), jnp.float32
+            rng.normal(size=(num_blocks, block, nkv * hd)), jnp.float32
         )
         for _ in range(2)
     )
     # every row its own scattered blocks, as the paged allocator hands out
     bt = jnp.asarray(
-        rng.permutation(num_blocks - 1)[:b * _WALK_TABLE].reshape(
-            b, _WALK_TABLE
-        ) + 1 if b * _WALK_TABLE < num_blocks
-        else rng.integers(1, num_blocks, size=(b, _WALK_TABLE)), jnp.int32,
+        rng.permutation(num_blocks - 1)[:b * table].reshape(b, table) + 1
+        if b * table < num_blocks
+        else rng.integers(1, num_blocks, size=(b, table)), jnp.int32,
     )
     ctx = jnp.asarray(ctx, jnp.int32)
     pos = jnp.maximum(ctx - 1, 0)[:, None]
@@ -166,6 +173,14 @@ def _window_arg(window):
     return window
 
 
+def _edge_setup(rng, **kwargs):
+    """``_walk_setup`` at the chip's block and turn (``_EDGE_CTX``)."""
+    return _walk_setup(
+        rng, ctx=_EDGE_CTX, block=_EDGE_BS, table=_EDGE_TABLE, num_blocks=96,
+        **kwargs,
+    )
+
+
 def _assert_walk_parity(out, ref, q_lens):
     out, ref = np.asarray(out), np.asarray(ref)
     live = np.asarray(q_lens) > 0
@@ -175,18 +190,28 @@ def _assert_walk_parity(out, ref, q_lens):
 
 
 @pytest.mark.parametrize(
-    'window', [None, 6, 'traced', 'traced_zero'],
-    ids=['nowin', 'win6', 'traced', 'traced0'],
+    'window', [None, 6, 'traced', 'traced_zero', 300],
+    ids=['nowin', 'win6', 'traced', 'traced0', 'win300'],
 )
 @pytest.mark.parametrize(
-    'pages', [1, 2, 4, 8, 16, None],
-    ids=['keys4', 'keys8', 'keys16', 'keys32', 'keys64', 'rule'],
+    'pages', [1, 2, 4, 8, 16, None, 'edges', 'edges_one_chunk'],
+    ids=['keys4', 'keys8', 'keys16', 'keys32', 'keys64', 'rule',
+         'turn_edges', 'turn_edges_one_chunk'],
 )
 def test_row_walk_parity_by_keys_a_step_and_window(rng, pages, window):
     """Ragged contexts in one batch, every chunk width from one page to
     the whole table (and the rule's own, capped by the table), static and
-    traced windows that start inside a chunk."""
-    q, k, v, bt, ctx, pos, q_lens = _walk_setup(rng)
+    traced windows that start inside a chunk. ``turn_edges``: blocks of 16
+    and turns of 128 keys in chunks of 256 (and in one chunk as wide as
+    the table, 34 pages: turns of 2), contexts that end on every edge of a
+    page, a turn and a chunk, a dead row between live ones, and windows
+    whose floor lies inside a turn (6: in the turn's last page; 300: with
+    whole turns and a chunk's edge above it)."""
+    if str(pages).startswith('edges'):
+        q, k, v, bt, ctx, pos, q_lens = _edge_setup(rng)
+        pages = _EDGE_PAGES if pages == 'edges' else _EDGE_TABLE
+    else:
+        q, k, v, bt, ctx, pos, q_lens = _walk_setup(rng)
     window = _window_arg(window)
     ref = ragged_paged_attention_xla(
         q, k, v, bt, ctx, pos, q_lens=q_lens, sliding_window=window
@@ -201,21 +226,30 @@ def test_row_walk_parity_by_keys_a_step_and_window(rng, pages, window):
 @pytest.mark.parametrize('window', [None, 6], ids=['nowin', 'win6'])
 @pytest.mark.parametrize(
     'variant',
-    ['stacked', 'stacked_traced', 'latent', 'int8', 'softcap', 'scale'],
+    ['stacked', 'stacked_traced', 'latent', 'int8', 'softcap', 'scale',
+     'latent_turns', 'int8_turns', 'heads64_turns'],
 )
 def test_row_walk_parity_by_pool_and_knob(rng, variant, window):
     """One walk for every span-1 caller: a stacked pool with its layer
     (a Python int, and traced under a rolled scan), a latent plane with
     ``value_lanes``, an int8 pool with its scale rows, softcap, a
-    caller's scale."""
+    caller's scale. ``*_turns``: the pool at the chip's block and turn
+    (``_EDGE_CTX``: two turns a chunk, the contexts on their edges), and
+    64-wide heads, two to a lane tile."""
     from distllm_tpu.ops.paged_attention import QuantizedKV
 
     kwargs, jit_layer = {}, None
+    setup, pages = _walk_setup, 2
+    if variant.endswith('_turns'):
+        setup, pages = _edge_setup, _EDGE_PAGES
+        variant = variant[:-len('_turns')]
     if variant == 'latent':  # one head of 256 lanes, values its first 128
-        q, k, _, bt, ctx, pos, q_lens = _walk_setup(rng, nh=4, nkv=1, hd=256)
+        q, k, _, bt, ctx, pos, q_lens = setup(rng, nh=4, nkv=1, hd=256)
         v, kwargs = None, {'value_lanes': 128}
+    elif variant == 'heads64':
+        q, k, v, bt, ctx, pos, q_lens = setup(rng, nh=8, nkv=4, hd=64)
     else:
-        q, k, v, bt, ctx, pos, q_lens = _walk_setup(rng)
+        q, k, v, bt, ctx, pos, q_lens = setup(rng)
     if variant.startswith('stacked'):
         other_k, other_v = k[::-1], v[::-1]
         k, v = jnp.stack([other_k, k, other_v]), jnp.stack([other_v, v, k])
@@ -248,20 +282,28 @@ def test_row_walk_parity_by_pool_and_knob(rng, variant, window):
         return call(jit_layer)
 
     out = run(
-        ragged_paged_attention_pallas, pages_per_chunk=2, interpret=True
+        ragged_paged_attention_pallas, pages_per_chunk=pages, interpret=True
     )
     _assert_walk_parity(out, run(ragged_paged_attention_xla), q_lens)
 
 
+@pytest.mark.parametrize('setup', ['short', 'turn_edges'])
 @pytest.mark.parametrize(
-    'nh,nkv,hd', [(8, 2, 8), (12, 2, 8), (16, 2, 8), (32, 1, 256)],
-    ids=['group4', 'group6', 'group8', 'group32_latent'],
+    'nh,nkv,hd',
+    [(8, 2, 8), (10, 2, 8), (12, 2, 8), (16, 2, 8), (32, 1, 256),
+     (16, 16, 8)],
+    ids=['group4', 'group5', 'group6', 'group8', 'group32_latent',
+         'group1_16heads'],
 )
-def test_row_walk_parity_by_queries_a_head(rng, nh, nkv, hd):
+def test_row_walk_parity_by_queries_a_head(rng, nh, nkv, hd, setup):
     """The head shapes that take the walk in the cells: 4 (mistral7b,
-    granite), 6 and 8 (laguna's full and window layers) queries a KV
-    head, and 32 queries on one latent head (kanana)."""
-    q, k, v, bt, ctx, pos, q_lens = _walk_setup(rng, nh=nh, nkv=nkv, hd=hd)
+    granite), 5 (falcon-h1), 6 and 8 (laguna's full and window layers)
+    queries a KV head, 32 queries on one latent head (kanana), and ONE
+    query a KV head at 16 heads (ouro); each over short rows in chunks
+    of 16 keys and over the turn's and the chunk's edges
+    (``_EDGE_CTX``)."""
+    setup = _walk_setup if setup == 'short' else _edge_setup
+    q, k, v, bt, ctx, pos, q_lens = setup(rng, nh=nh, nkv=nkv, hd=hd)
     kwargs = {}
     if nkv == 1:
         v, kwargs = None, {'value_lanes': 128}
@@ -269,7 +311,8 @@ def test_row_walk_parity_by_queries_a_head(rng, nh, nkv, hd):
         q, k, v, bt, ctx, pos, q_lens=q_lens, **kwargs
     )
     out = ragged_paged_attention_pallas(
-        q, k, v, bt, ctx, pos, q_lens=q_lens, pages_per_chunk=4,
+        q, k, v, bt, ctx, pos, q_lens=q_lens,
+        pages_per_chunk=4 if k.shape[1] == _WALK_BS else _EDGE_PAGES,
         interpret=True, **kwargs,
     )
     _assert_walk_parity(out, ref, q_lens)
