@@ -33,6 +33,7 @@ import contextlib
 import importlib
 import itertools
 import math
+import threading
 import time
 from dataclasses import dataclass, field
 from enum import Enum
@@ -65,7 +66,10 @@ from distllm_tpu.models.tokenizer import bucket_ladder, pick_bucket
 from distllm_tpu.observability import instruments as _metrics
 from distllm_tpu.observability import steps as _steps
 from distllm_tpu.observability import xla_cost as _xla_cost
-from distllm_tpu.observability.flight import get_flight_recorder
+from distllm_tpu.observability.flight import (
+    get_flight_recorder,
+    get_stall_watchdog,
+)
 from distllm_tpu.observability.startup import (
     get_compile_watcher,
     record_backend_init,
@@ -77,7 +81,6 @@ from distllm_tpu.ops.paged_attention import (
     quantize_kv_rows,
     unfold_heads,
     walk_keys_a_step,
-    walk_pages_a_turn,
 )
 from distllm_tpu.ops.sampling import fold_row_keys, sample_tokens
 from distllm_tpu.resilience.admission import (
@@ -1393,6 +1396,16 @@ class LLMEngine:
         # accumulators behind roofline_summary(). Cost-model failures
         # (exotic leaf types) disable the gauges, never the engine.
         self.attribution = cfg.attribution
+        # What the stall watcher may ask from its own thread
+        # (``stall_context``): the thread that last opened a root span
+        # here, the windows dispatched and not yet fetched, and the one
+        # whose fetch is under way. Built with attribution on, the engine
+        # is watched until ``shutdown()``.
+        self._serve_thread: int | None = None
+        self._inflight = ()
+        self._fetching: dict | None = None
+        if cfg.attribution:
+            get_stall_watchdog().watch(self)
         self._cost_model = None
         self._roofline: dict[str, dict[str, float]] = {}
         # Measured executable costs from compiled.cost_analysis(), filled
@@ -3456,9 +3469,10 @@ class LLMEngine:
             # The synchronous path's host sync: the device runs the
             # prefill while the host waits here.
             step.mark('fetch')
-            tokens = np.asarray(
-                self._sample_device(last_logits, slots, step)
-            )
+            sampled = self._sample_device(last_logits, slots, step)
+            self._fetching = {'tokens': sampled}
+            tokens = np.asarray(sampled)
+            self._fetching = None
             step.mark('emit')
             emitted = []
             for i, request in enumerate(requests):
@@ -3805,9 +3819,33 @@ class LLMEngine:
         ``step()`` call or one pass of the pipelined loop: an idle gap of
         the device that runs through several phases, none of which holds
         half of it, still falls under an engine span."""
+        self._serve_thread = threading.get_ident()
         root = self._begin_step()
         root.mark('serve')
         return root
+
+    def stall_context(self) -> dict:
+        """What a stalled stretch's evidence asks of this engine, from the
+        watcher's thread (``observability/flight.py`` ``stall_evidence``):
+        the ``thread`` that serves it, the windows ``in_flight`` and
+        whether each one's tokens are ``ready`` on the device (ready and
+        still being waited for is the transfer or the host; not ready is
+        the program or the runtime), the ``unfinished`` requests, and
+        whether anything is ``compiling``. Plain reads, no lock: a count a
+        window off is as good."""
+        tokens = [
+            window.get('tokens')
+            for window in (self._fetching, *self._inflight)
+            if window is not None
+        ]
+        ready = [bool(t.is_ready()) for t in tokens if hasattr(t, 'is_ready')]
+        return {
+            'thread': self._serve_thread,
+            'in_flight': len(ready),
+            'ready': ready,
+            'unfinished': len(self._requests),
+            'compiling': self._compile_watcher.compiling(),
+        }
 
     @contextlib.contextmanager
     def _span(self, name: str):
@@ -3830,42 +3868,31 @@ class LLMEngine:
         return sum(int(((c + (bs - 1)) // bs).sum()) for c in context_lens)
 
     def _kv_chunks(self, context_lens: np.ndarray) -> dict:
-        """``kv_chunks*`` and ``kv_turns*``: the chunks the paged kernel's
-        row walk fetches for a decode dispatch's rows in one step of one
-        layer of each cache group, and the turns of pages within them that
-        hold a page a row sees (what its copies go by): from the one that
-        holds a row's sliding-window floor to the one that holds its
-        context's end (a pad row's one token is one chunk, one turn).
-        ``kv_chunks`` against ``rows x ceil(table blocks / pages a chunk)``
-        is the share of the grid over chunks that fetched anything;
-        ``kv_turns x keys a turn`` against ``kv_chunks x keys a step`` the
-        share of the chunks' width that holds anything. Nothing under a
-        backend that has no walk.
-        Reckoned where ``kv_blocks`` is, after the step's spans have
-        closed."""
+        """``kv_chunks*``: the chunks the paged kernel's row walk fetches
+        for a decode dispatch's rows in one step of one layer of each cache
+        group: from the chunk that holds a row's sliding-window floor to the
+        one that holds its context's end (a pad row's one token is one
+        chunk). Against ``rows x ceil(table blocks / pages a chunk)`` it is
+        the share of the grid over chunks that fetched anything. Nothing
+        under a backend that has no walk. Reckoned where ``kv_blocks`` is,
+        after the step's spans have closed."""
         if not self._walk_keys:
             return {}
         fields = {}
         groups = self.cache_spec.paged
-        block = self.config.block_size
         for group in groups:
             keys = self._walk_keys[group.name]
             floor = 0
             if group.window is not None:
                 floor = np.maximum(context_lens - group.window, 0)
-            suffix = '' if len(groups) == 1 else (
-                '_window' if group.window else '_full'
+            name = 'kv_chunks' if len(groups) == 1 else (
+                'kv_chunks_window' if group.window else 'kv_chunks_full'
             )
-            for name, unit in (
-                ('kv_chunks', keys),
-                ('kv_turns', walk_pages_a_turn(keys // block) * block),
-            ):
-                fields[name + suffix] = int(
-                    ((context_lens + unit - 1) // unit - floor // unit).sum()
-                )
+            fields[name] = int(
+                ((context_lens + keys - 1) // keys - floor // keys).sum()
+            )
         if len(groups) > 1:
-            for name in ('kv_chunks', 'kv_turns'):
-                fields[name] = fields[name + '_full']
+            fields['kv_chunks'] = fields['kv_chunks_full']
         return fields
 
     def _rids_field(self, requests: list[Request]) -> dict:
@@ -4126,6 +4153,7 @@ class LLMEngine:
                 raise
             return emitted
         finally:
+            self._fetching = None
             root.close()
 
     def _window_budget(self, request: Request, unacked: int, k: int) -> int:
@@ -4631,6 +4659,7 @@ class LLMEngine:
         step.mark('fetch')
         # distlint: disable=host-sync-in-hot-path -- the spec window's ONE designed fetch point: emission needs the verified tokens + accept length on host, and spec windows process synchronously (depth 1)
         tokens = np.asarray(window['tokens'])  # [B, S+1] packed
+        self._fetching = None
         window['duration_s'] = step.mark('emit') - window['t_dispatch']
         gauges = self._sched_gauges()
         emitted: list[tuple[int, int]] = []
@@ -4774,6 +4803,7 @@ class LLMEngine:
         hidden dispatch latency). Speculative windows carry a different
         token layout and acceptance rule and route to
         ``_process_spec_window``."""
+        self._fetching = window  # in flight until its tokens are here
         if window.get('spec'):
             return self._process_spec_window(window)
         # Injection site 'slow_window': the stall hazard — the sleep sits
@@ -4797,6 +4827,7 @@ class LLMEngine:
             # distlint: disable=host-sync-in-hot-path -- two int32 the window's program wrote with the tokens fetched one line up: ready, never waited for
             moe_pairs = np.asarray(moe_pairs)
         t_fetched = step.mark('emit')
+        self._fetching = None
         emitted: list[tuple[int, int]] = []
         chunk_entries = window.get('chunk_plan') or []
         if recorded:
@@ -4932,6 +4963,7 @@ class LLMEngine:
             1 if self.config.draft_k else max(1, self.config.pipeline_depth)
         )
         inflight: deque[dict] = deque()
+        self._inflight = inflight
         self._carried = None
 
         def process_one() -> None:
@@ -5030,6 +5062,7 @@ class LLMEngine:
             raise
         finally:
             root.close()
+            self._inflight, self._fetching = (), None
             self._drain_hook = None
             self._windows_behind = 0
 
@@ -5431,6 +5464,7 @@ class LLMEngine:
         return [self.tokenizer.decode(out) for out in outputs]
 
     def shutdown(self) -> None:
+        get_stall_watchdog().unwatch(self)
         if self._history_sampler is not None:
             self._history_sampler.stop()
             self._history_sampler = None

@@ -57,6 +57,8 @@ from distllm_tpu.observability.flight import (
     StallWatchdog,
     dump_debug_bundle,
     get_flight_recorder,
+    get_stall_watchdog,
+    stall_evidence,
 )
 from distllm_tpu.observability.history import (
     HistorySampler,
@@ -140,6 +142,7 @@ __all__ = [
     'get_flight_recorder',
     'get_metrics_history',
     'get_profiler_capture',
+    'get_stall_watchdog',
     'get_registry',
     'get_regression_sentinel',
     'get_trace_buffer',
@@ -157,6 +160,7 @@ __all__ = [
     'request_scope',
     'slo_status',
     'span',
+    'stall_evidence',
     'to_trace_events',
     'update_burn_gauges',
     'validate_trace_events',

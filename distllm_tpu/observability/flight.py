@@ -9,7 +9,14 @@
 - :class:`StallWatchdog` — a daemon thread that watches any monotonic
   progress function (by default the process flight ring's record count) and
   fires a callback when progress stops for ``stall_s`` seconds. The default
-  callback dumps a debug bundle; it never kills the watched work.
+  callback dumps a debug bundle; it never kills the watched work. The
+  process's own instance (:func:`get_stall_watchdog`) also watches the
+  serving threads' span edges (``steps.edges()``) for the engines
+  registered with it, and writes a ``stall`` record with its evidence for a
+  stretch that is long for its kind.
+- :func:`stall_evidence` — where the process is: every thread's stack, what
+  the kernel's counters say of a stalled thread, what each watched engine
+  has in flight. The ``stall`` record's and the bundle's ``stacks.json``.
 - :func:`dump_debug_bundle` — flight ring + metrics exposition + trace ring
   (+ best-effort ``jax.profiler`` device-memory capture) written to one
   directory, so a dead process still explains itself.
@@ -21,12 +28,20 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
+import weakref
 from collections import deque
 from pathlib import Path
 
+try:
+    import resource
+except ImportError:  # no getrusage on this platform
+    resource = None
+
 from distllm_tpu.observability import instruments as _metrics
+from distllm_tpu.observability import steps as _steps
 from distllm_tpu.observability.metrics import render_prometheus
 from distllm_tpu.observability.tracing import get_trace_buffer
 
@@ -105,6 +120,88 @@ def get_flight_recorder() -> FlightRecorder:
     return _default_recorder
 
 
+# ---------------------------------------------------------- stall evidence
+SPAN_POLL_S = 0.25  # the span watcher's period
+STALL_SAMPLES = 4  # records a stalled stretch: at detection, then as its age doubles
+_STACK_FRAMES = 12
+_STACK_THREADS = 8
+
+
+def _stacks(first: int | None = None) -> list[dict]:
+    """Name and innermost frames (``file:line function``, the path cut to
+    its last three parts) of the Python threads, ``first`` first and the
+    caller's own left out."""
+    frames = sys._current_frames()
+    frames.pop(threading.get_ident(), None)
+    names = {t.ident: t.name for t in threading.enumerate()}
+    order = sorted(frames, key=lambda ident: ident != first)
+    stacks = []
+    for ident in order[:_STACK_THREADS]:
+        lines, frame = [], frames[ident]
+        while frame is not None and len(lines) < _STACK_FRAMES:
+            code = frame.f_code
+            path = '/'.join(code.co_filename.split('/')[-3:])
+            lines.append(f'{path}:{frame.f_lineno} {code.co_name}')
+            frame = frame.f_back
+        stacks.append({'thread': names.get(ident, str(ident)), 'frames': lines})
+    return stacks
+
+
+def _counters(edge: _steps.Edge) -> dict:
+    """The running counters the kernel keeps of ``edge``'s thread and of
+    the process; a stall's evidence is their rise over the stretch. A
+    counter the platform lacks (or a thread that has ended) is left out."""
+    out = {'cpu_process_s': time.process_time()}
+    try:
+        out['cpu_thread_s'] = time.clock_gettime(edge.cpu_clock)
+    except (AttributeError, TypeError, OSError):
+        pass
+    try:
+        with open(f'/proc/self/task/{edge.native_id}/schedstat') as handle:
+            _ran_ns, waited_ns, slices = handle.read().split()
+        out['sched_delay_s'] = int(waited_ns) / 1e9
+        out['timeslices'] = int(slices)
+    except (OSError, ValueError):
+        pass
+    if resource is not None:
+        usage = resource.getrusage(resource.RUSAGE_SELF)
+        out['majflt'] = usage.ru_majflt
+        out['nivcsw'] = usage.ru_nivcsw
+    return out
+
+
+def stall_evidence(
+    edge: _steps.Edge | None = None,
+    since: dict | None = None,
+    tick_late_s: float | None = None,
+    contexts: list[dict] | None = None,
+) -> dict:
+    """Where the process is, for a ``stall`` record and a bundle's
+    ``stacks.json``: ``stacks`` of every Python thread (``edge``'s first);
+    ``tick_late_s``, how late the watcher itself woke (a frozen process
+    shows here); the rise of ``edge``'s thread's counters since the sample
+    ``since`` (``cpu_thread_s``, ``cpu_process_s``, ``sched_delay_s``,
+    ``timeslices``, ``majflt``, ``nivcsw``); and what the watched engines
+    say of themselves (``LLMEngine.stall_context``, summed over them):
+    windows ``in_flight``, whether each one's tokens are ``ready``,
+    ``unfinished`` requests, and ``compiling``."""
+    out: dict = {'stacks': _stacks(edge.ident if edge is not None else None)}
+    if tick_late_s is not None:
+        out['tick_late_s'] = round(tick_late_s, 6)
+    if edge is not None and since is not None:
+        for name, value in _counters(edge).items():
+            if name in since:
+                out[name] = round(value - since[name], 6)
+    if contexts is None:
+        contexts = get_stall_watchdog().engine_contexts()
+    if contexts:
+        out['in_flight'] = sum(c['in_flight'] for c in contexts)
+        out['ready'] = [r for c in contexts for r in c['ready']]
+        out['unfinished'] = sum(c['unfinished'] for c in contexts)
+        out['compiling'] = any(c['compiling'] for c in contexts)
+    return out
+
+
 # ------------------------------------------------------------ debug bundle
 def dump_debug_bundle(
     directory: str | Path,
@@ -124,7 +221,10 @@ def dump_debug_bundle(
     dead phase instead of arriving empty), ``history.json`` (the
     metric-history ring: the minutes BEFORE the incident, not just the
     final values), ``slo.json`` (burn-rate status + regression-sentinel
-    state), ``meta.json``
+    state), ``stacks.json`` (:func:`stall_evidence`: every thread's stack
+    and what the watched engines have in flight, beside each serving
+    thread's open span and its age — where the process IS, not only what
+    it did), ``meta.json``
     (reason/pid/time/extra), and — best-effort, when a JAX backend is
     initialized and supports it — ``device_memory.prof``
     (``jax.profiler.save_device_memory_profile``). Every piece is written
@@ -230,12 +330,31 @@ def dump_debug_bundle(
         paths['perfetto'] = str(perfetto_path)
     except Exception:
         pass
+    stacks_path = directory / 'stacks.json'
+    try:
+        now = _steps.clock()
+        stacks_path.write_text(
+            json.dumps(
+                {
+                    **stall_evidence(),
+                    'spans': [
+                        {
+                            'thread': e.thread, 'span': e.span, 'seq': e.seq,
+                            'root': e.root, 'age_s': round(now - e.t, 6),
+                        }
+                        for e in _steps.edges()
+                    ],
+                },
+                default=str,
+            )
+        )
+        paths['stacks'] = str(stacks_path)
+    except Exception:
+        pass
     # Optional device-memory capture: only when jax is already imported
     # (importing it here could initialize a backend inside a dying
     # process) and the backend supports the profiler.
     try:  # pragma: no cover - backend-dependent
-        import sys
-
         jax = sys.modules.get('jax')
         if jax is not None:
             prof_path = directory / 'device_memory.prof'
@@ -279,6 +398,18 @@ class StallWatchdog:
     Fires at most ``max_fires`` times (default 1) per arm; ``beat()``
     force-marks progress for work that is alive but quiet. Use as a
     context manager around the work, or ``start()``/``stop()`` manually.
+
+    An instance that engines are registered with (``watch``; the process's
+    own, :func:`get_stall_watchdog`) also reads the serving threads' span
+    edges every period: a stretch older than ``steps.stall_threshold_s``
+    (a hole between spans only while a watched engine that serves on the
+    thread has unfinished work) is flagged on its edge, so that the thread
+    books its seconds on the step's record (``stalled_s``), and written to
+    the flight ring as a ``stall`` record with :func:`stall_evidence`:
+    ``sample`` 0 at detection, one more each time the age doubles,
+    ``STALL_SAMPLES`` at most. It runs from the first ``watch`` to the last
+    ``unwatch`` (engines are held weakly: one that is dropped without a
+    ``shutdown()`` leaves no thread behind either).
     """
 
     def __init__(
@@ -307,6 +438,105 @@ class StallWatchdog:
         self._beats = 0
         self._stop_event = threading.Event()
         self._thread: threading.Thread | None = None
+        # The engines whose serving threads' spans are watched, and of each
+        # thread's stretch what the watcher has seen: its edge, the
+        # counters' last sample and the last one at or before the edge,
+        # the stall records written.
+        self._watch_lock = threading.Lock()
+        self._engines = weakref.WeakSet()  # guarded by self._watch_lock
+        self._watches_spans = False  # from the first ``watch`` on
+        self._stretches: dict[int, dict] = {}
+        self._tick_late_s = 0.0
+
+    # ------------------------------------------------------ span watching
+    def watch(self, engine) -> None:
+        """Watch the spans of ``engine``'s serving thread from now on
+        (starts the thread with the first engine)."""
+        with self._watch_lock:
+            self._engines.add(engine)
+            self._watches_spans = True
+            if self._thread is None:
+                self.start()
+
+    def unwatch(self, engine) -> None:
+        """``engine`` shut down: the thread stops with the last one."""
+        with self._watch_lock:
+            watched = engine in self._engines
+            self._engines.discard(engine)
+            last = watched and not self._engines
+        if last:
+            self.stop()
+            with self._watch_lock:  # an engine built while it stopped
+                if self._engines and self._thread is None:
+                    self.start()
+
+    def engine_contexts(self) -> list[dict]:
+        """``stall_context()`` of every watched engine that gives one."""
+        with self._watch_lock:
+            engines = list(self._engines)
+        contexts = []
+        for engine in engines:
+            try:
+                contexts.append(engine.stall_context())
+            except Exception:
+                pass  # an engine half torn down has nothing to say
+        return contexts
+
+    def _watch_spans(self) -> None:
+        """One reading of the edge table (``steps.edges()``)."""
+        now = _steps.clock()
+        alive = {t.ident for t in threading.enumerate()}
+        for edge in _steps.edges():
+            if edge.ident not in alive:
+                _steps.forget(edge.ident)
+                self._stretches.pop(edge.ident, None)
+                continue
+            t_edge, span, seq = edge.t, edge.span, edge.seq
+            seen = self._stretches.get(edge.ident)
+            counters = _counters(edge)
+            if seen is None or seen['t_edge'] != t_edge:
+                # The edge moved since the last reading: the sample taken
+                # then is the last one at or before it.
+                seen = self._stretches[edge.ident] = {
+                    't_edge': t_edge, 'samples': 0, 'next_age_s': 0.0,
+                    'since': seen['last'] if seen is not None else counters,
+                }
+            seen['last'] = counters
+            age_s = now - t_edge
+            if (
+                seen['samples'] >= STALL_SAMPLES
+                or age_s < seen['next_age_s']
+                or age_s <= _steps.stall_threshold_s(span, now)
+            ):
+                continue
+            contexts = self.engine_contexts()
+            if span is None and not any(
+                c['unfinished'] and c['thread'] == edge.ident for c in contexts
+            ):
+                continue  # nobody waits for this thread: an idle server
+            evidence = stall_evidence(
+                edge, seen['since'], self._tick_late_s, contexts
+            )
+            if seen['samples'] == 0:
+                edge.excused = bool(evidence.get('compiling'))
+                edge.flagged = t_edge
+                # A compile is a stretch with a known cause and a record of
+                # its own (``compile``): kept, neither logged nor counted.
+                if not evidence.get('compiling'):
+                    _metrics.WATCHDOG_STALLS.inc()
+                    _metrics.log_event(
+                        f'[{self.name}] thread {edge.thread} has been in '
+                        f'{"distllm:" + span if span else "no span"} for '
+                        f'{age_s:.2f}s (seq {seq})',
+                        component='watchdog',
+                    )
+            _default_recorder.record(
+                'stall', seq=seq, span=span, thread=edge.thread,
+                t_edge_s=round(t_edge, 6), t_s=round(now, 6),
+                age_s=round(age_s, 6), sample=seen['samples'], **evidence,
+            )
+            seen['samples'] += 1
+            seen['next_age_s'] = 2 * age_s
 
     def beat(self) -> None:
         """Mark progress explicitly (for work the ring cannot see)."""
@@ -336,7 +566,23 @@ class StallWatchdog:
     def _run(self) -> None:
         last = (self._progress_fn(), self._beats)
         last_change = time.monotonic()
-        while not self._stop_event.wait(self._poll_s):
+        while True:
+            asleep = _steps.clock()
+            if self._stop_event.wait(self._poll_s):
+                return
+            self._tick_late_s = max(
+                0.0, _steps.clock() - asleep - self._poll_s
+            )
+            if self._watches_spans:
+                with self._watch_lock:
+                    if not self._engines:  # every engine shut down or dropped
+                        self._thread = None
+                        self._stretches.clear()
+                        return
+                try:
+                    self._watch_spans()
+                except Exception:
+                    pass  # the watcher must survive a reading it cannot make
             try:
                 current = (self._progress_fn(), self._beats)
             except Exception:
@@ -367,8 +613,9 @@ class StallWatchdog:
 
     def stop(self) -> None:
         self._stop_event.set()
-        if self._thread is not None:
-            self._thread.join(timeout=5)
+        thread = self._thread  # a span watcher may end itself meanwhile
+        if thread is not None:
+            thread.join(timeout=5)
             self._thread = None
 
     def __enter__(self) -> 'StallWatchdog':
@@ -376,3 +623,15 @@ class StallWatchdog:
 
     def __exit__(self, *exc_info) -> None:
         self.stop()
+
+
+_span_watchdog = StallWatchdog(
+    float('inf'), poll_s=SPAN_POLL_S, name='span-watchdog'
+)
+
+
+def get_stall_watchdog() -> StallWatchdog:
+    """The process's span watcher: ring growth never fires it (``stall_s``
+    is infinite); the engines built with ``attribution`` on register with
+    it and it runs while one of them lives."""
+    return _span_watchdog

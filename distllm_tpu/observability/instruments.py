@@ -463,6 +463,11 @@ FLIGHT_KINDS = frozenset({
     'regression',  # runtime sentinel firing: a live history window
                    # degraded past threshold vs the BENCH baseline
                    # envelope (metric/baseline/live/window_s fields)
+    'stall',    # a serving thread's stretch between two span edges that
+                # the span watcher found long for its kind (flight.py
+                # StallWatchdog): seq/span/thread/t_edge_s/t_s/age_s/
+                # sample and stall_evidence's stacks, counters and the
+                # engines' in_flight/ready/unfinished/compiling
 })
 
 # Catalog of serving-path step spans (observability/steps.py), beside
@@ -688,7 +693,8 @@ for _reason in ('no_baseline', 'empty'):
 # -------------------------------------------------- watchdog / debug bundle
 WATCHDOG_STALLS = _registry.counter(
     'distllm_watchdog_stalls_total',
-    'StallWatchdog firings (no observed progress for the stall window).',
+    'StallWatchdog firings (no observed progress for the stall window, '
+    'or a serving thread\'s stretch between span edges long for its kind).',
 )
 DEBUG_BUNDLES = _registry.counter(
     'distllm_debug_bundles_total',

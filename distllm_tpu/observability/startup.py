@@ -121,6 +121,11 @@ class _Pending(threading.local):
 
 
 _pending = _Pending()
+# Threads inside one of jax's three compile stages (trace, lowering,
+# backend compile), each with how many it has open: what another thread
+# may ask (``CompileWatcher.compiling``).
+_STAGES = (_TRACE, _LOWER, _BACKEND_COMPILE)
+_in_stage: dict[int, int] = {}
 
 
 def _on_event(event: str, **kwargs) -> None:
@@ -131,11 +136,20 @@ def _on_event(event: str, **kwargs) -> None:
 
 def _on_scalar(event: str, value, **kwargs) -> None:
     # jax announces a timed extent at its start with a scalar.
+    if event in _STAGES:
+        ident = threading.get_ident()
+        _in_stage[ident] = _in_stage.get(ident, 0) + 1
     if event == _TRACE:
         _pending.tracing += 1
 
 
 def _on_duration(event: str, seconds: float, **kwargs) -> None:
+    if event in _STAGES:
+        ident = threading.get_ident()
+        if _in_stage.get(ident, 0) <= 1:
+            _in_stage.pop(ident, None)
+        else:
+            _in_stage[ident] -= 1
     if event == _TRACE:
         if _pending.tracing:
             _pending.tracing -= 1
@@ -466,6 +480,13 @@ class CompileWatcher:
             ).observe(t1 - t0)
             if cache_hit:
                 _metrics.COMPILE_CACHE_HITS.inc()
+
+    def compiling(self) -> bool:
+        """Whether a phase is open, or any thread stands in one of jax's
+        compile stages (trace, lowering, backend compile), right now: what
+        a stall's evidence asks from another thread."""
+        with self._lock:
+            return bool(self._open) or bool(_in_stage)
 
     def state(self) -> dict:
         """Snapshot for debug bundles: the completed phases, the program

@@ -1297,8 +1297,7 @@ def test_mixed_exception_recovery_rolls_back_inflight_chunk_spans(
 # ------------------------------------------------- the row walk's counter
 def _walked_chunks(contexts, keys, window=None, block=4):
     """Chunks a row walk fetches for rows at ``contexts``, counted page by
-    page: the chunks that hold a page the row's one query sees (with a
-    turn's keys for ``keys``, the turns it copies and computes)."""
+    page: the chunks that hold a page the row's one query sees."""
     total = 0
     for ctx in contexts:
         lo = max(int(ctx) - window, 0) if window else 0
@@ -1354,25 +1353,15 @@ def test_decode_records_count_the_chunks_the_walk_fetches(
             assert fields[names[group]] == _walked_chunks(
                 contexts, 8, window
             ), (group, contexts)
-            # a turn is ONE of a chunk's two pages here: 4 keys
-            turns = names[group].replace('chunks', 'turns')
-            assert fields[turns] == _walked_chunks(contexts, 4, window), (
-                group, contexts,
-            )
-            assert fields[names[group]] <= fields[turns] <= (
-                2 * fields[names[group]]
-            )
         if family == 'laguna':
             assert fields['kv_chunks'] == fields['kv_chunks_full']
-            assert fields['kv_turns'] == fields['kv_turns_full']
     records = engine.flight.snapshot()[before - engine.flight.total_recorded:]
     decodes = [r for r in records if r['kind'] == 'decode']
     assert [
-        {k: v for k, v in r.items() if k.startswith(('kv_chunks', 'kv_turns'))}
+        {k: v for k, v in r.items() if k.startswith('kv_chunks')}
         for r in decodes
     ] == [fields for _, fields in seen]
-    # the rows' last chunks are part-filled: some turn of theirs is not run
-    assert any(r['kv_turns'] < 2 * r['kv_chunks'] for r in decodes)
+    assert not any(k.startswith('kv_turns') for r in decodes for k in r)
     # a chunk holds two pages: the walk fetches fewer chunks than blocks,
     # and no more than one a block
     assert all(0 < r['kv_chunks'] <= r['kv_blocks'] for r in decodes)
@@ -1380,16 +1369,16 @@ def test_decode_records_count_the_chunks_the_walk_fetches(
 
 
 @pytest.mark.parametrize('family', ['mistral', 'laguna'])
-def test_turns_of_known_contexts_reckoned_by_hand(family, monkeypatch):
-    """``kv_turns*`` for a dispatch of known contexts, by hand: chunks of
-    16 keys (four pages of 4) in turns of 8 (two pages). Rows at 1, 8, 9,
-    16, 17 and 40 tokens walk 1, 1, 2, 2, 3 and 5 turns of 1, 1, 1, 1, 2
-    and 3 chunks; under a window of 12 the last row's floor, 28, is in its
-    turn 3 and its chunk 1: 2 turns of 2 chunks."""
+def test_chunks_of_known_contexts_reckoned_by_hand(family, monkeypatch):
+    """``kv_chunks*`` for a dispatch of known contexts, by hand: chunks of
+    16 keys (four pages of 4). Rows at 1, 8, 9, 16, 17 and 40 tokens walk
+    1, 1, 1, 1, 2 and 3 chunks; under a window of 12 the last row's floor,
+    28, is in its chunk 1: 2 chunks. Nothing else rides the records beside
+    them (``kv_turns*`` went with PR 50: no sound metric could be made of
+    it)."""
     from distllm_tpu.ops import paged_attention
 
     monkeypatch.setattr(paged_attention, 'WALK_MAX_KEYS', 16)
-    monkeypatch.setattr(paged_attention, 'WALK_PAGES_A_TURN', 2)
     if family == 'mistral':
         _, _, engine = _tiny_engine(attn_backend='interpret', max_model_len=96)
     else:
@@ -1399,11 +1388,10 @@ def test_turns_of_known_contexts_reckoned_by_hand(family, monkeypatch):
         _, _, engine = make_engine(attn_backend='interpret')
     fields = engine._kv_chunks(np.array([1, 8, 9, 16, 17, 40]))
     if family == 'mistral':
-        assert fields == {'kv_chunks': 9, 'kv_turns': 14}
+        assert fields == {'kv_chunks': 9}
     else:
         assert fields == {
-            'kv_chunks_full': 9, 'kv_turns_full': 14, 'kv_chunks': 9,
-            'kv_turns': 14, 'kv_chunks_window': 8, 'kv_turns_window': 11,
+            'kv_chunks_full': 9, 'kv_chunks': 9, 'kv_chunks_window': 8,
         }
     engine.shutdown()
 

@@ -591,6 +591,312 @@ def test_stall_watchdog_default_dumps_bundle(tmp_path):
     assert instruments.WATCHDOG_STALLS.value == stalls_before + 1
 
 
+# ------------------------------------------------- stalled stretches of spans
+class _Watched:
+    """What the span watcher asks of an engine, with no engine behind it."""
+
+    def __init__(self, unfinished=1, compiling=False):
+        import threading
+
+        self.context = {
+            'thread': threading.get_ident(), 'in_flight': 1, 'ready': [True],
+            'unfinished': unfinished, 'compiling': compiling,
+        }
+
+    def stall_context(self):
+        return dict(self.context)
+
+
+@pytest.fixture
+def span_dog(monkeypatch):
+    """A span watcher of the test's own, quick to poll and to call a
+    stretch long, over a clean slate of typical seconds."""
+    from distllm_tpu.observability import flight, steps
+
+    monkeypatch.setattr(steps, 'STALL_FLOOR_S', 0.2)
+    monkeypatch.setattr(steps, '_typical', {})
+    monkeypatch.setattr(steps, '_longest', {})
+    dog = StallWatchdog(float('inf'), poll_s=0.05, name='test-span-dog')
+    monkeypatch.setattr(flight, '_span_watchdog', dog)
+    yield dog
+    dog.stop()
+    steps.abandon()
+
+
+def _stalls(before):
+    from distllm_tpu.observability import get_flight_recorder
+
+    ring = get_flight_recorder()
+    grew = ring.total_recorded - before
+    return [r for r in ring.snapshot()[-grew:] if r['kind'] == 'stall'] if grew else []
+
+
+def _nap_in_a_span(seconds):
+    time.sleep(seconds)
+
+
+def test_a_span_held_open_is_one_stall_record_with_its_evidence(span_dog, capsys):
+    from distllm_tpu.observability import get_flight_recorder, instruments, steps
+
+    engine = _Watched()
+    span_dog.watch(engine)
+    before = get_flight_recorder().total_recorded
+    counted = instruments.WATCHDOG_STALLS.value
+    step = steps.StepSpan(seq=990001)
+    step.mark('plan')
+    t_fetch = step.mark('fetch')
+    _nap_in_a_span(0.7)
+    step.close()
+    records = _stalls(before)
+    # detection near 0.2-0.25 s, again when that age has doubled; the
+    # third reading would need 0.8 s or more
+    assert [r['sample'] for r in records] == [0, 1]
+    first, second = records
+    assert first['span'] == 'fetch' and first['seq'] == 990001
+    assert first['thread'] == 'MainThread'
+    assert first['t_edge_s'] == round(t_fetch, 6)
+    assert first['t_s'] - first['t_edge_s'] == pytest.approx(first['age_s'], abs=1e-5)
+    assert 0.2 < first['age_s'] < 0.45
+    assert second['age_s'] >= 2 * first['age_s']
+    assert second['t_edge_s'] == first['t_edge_s']
+    # the stalled thread's stack first, innermost frame first
+    stack = first['stacks'][0]
+    assert stack['thread'] == 'MainThread'
+    assert stack['frames'][0].endswith(' _nap_in_a_span')
+    assert 'test_observability.py:' in stack['frames'][0]
+    assert len(stack['frames']) <= 12 and len(first['stacks']) <= 8
+    assert 0.0 <= first['tick_late_s'] < 0.5
+    # the kernel's counters over the stretch, where the platform has them:
+    # a sleeping thread burns next to nothing
+    assert 0.0 <= first['cpu_process_s'] < 0.5
+    if 'cpu_thread_s' in first:
+        assert 0.0 <= first['cpu_thread_s'] < 0.2
+    if 'sched_delay_s' in first:
+        assert first['sched_delay_s'] >= 0.0 and first['timeslices'] >= 0
+    # the engine's say
+    assert first['in_flight'] == 1 and first['ready'] == [True]
+    assert first['unfinished'] == 1 and first['compiling'] is False
+    # what it cost is on the step's record, the whole stretch
+    assert step.fields()['stalled_s'] == pytest.approx(0.7, abs=0.1)
+    assert step.seconds['fetch_s'] >= step.seconds['stalled_s']
+    # counted and logged once
+    assert instruments.WATCHDOG_STALLS.value == counted + 1
+    out = capsys.readouterr().out
+    assert out.count('[test-span-dog]') == 1 and 'distllm:fetch' in out
+    # the flagged stretch did not feed what is typical of a fetch
+    assert steps._typical.get('fetch', 0.0) < 0.2
+
+
+def test_a_compile_is_recorded_and_neither_counted_nor_logged(span_dog, capsys):
+    from distllm_tpu.observability import get_flight_recorder, instruments, steps
+
+    engine = _Watched(compiling=True)
+    span_dog.watch(engine)
+    before = get_flight_recorder().total_recorded
+    counted = instruments.WATCHDOG_STALLS.value
+    step = steps.StepSpan(seq=990002)
+    step.mark('decode')
+    _nap_in_a_span(0.35)
+    step.close()
+    (record,) = _stalls(before)
+    assert record['compiling'] is True and record['span'] == 'decode'
+    assert step.fields()['stalled_s'] == pytest.approx(0.35, abs=0.1)
+    assert instruments.WATCHDOG_STALLS.value == counted
+    assert '[test-span-dog]' not in capsys.readouterr().out
+
+
+def test_a_long_stretch_is_long_for_its_kind(span_dog):
+    """Eight times what stretches in the span typically take, where that
+    is over the floor: a span whose stretches run 0.05 s is not stalled at
+    0.3 s, one whose stretches run microseconds is."""
+    from distllm_tpu.observability import get_flight_recorder, steps
+
+    engine = _Watched()  # held: the watcher keeps engines weakly
+    span_dog.watch(engine)
+    before = get_flight_recorder().total_recorded
+    for seq in range(990010, 990016):
+        step = steps.StepSpan(seq=seq)
+        step.mark('fetch')
+        time.sleep(0.05)
+        step.close()
+    assert steps.stall_threshold_s('fetch') >= 0.39
+    assert steps.stall_threshold_s('emit') == 0.2
+    slow = steps.StepSpan(seq=990016)
+    slow.mark('fetch')
+    time.sleep(0.3)
+    slow.mark('emit')
+    time.sleep(0.3)
+    slow.close()
+    (record,) = _stalls(before)
+    assert record['span'] == 'emit'
+    assert slow.fields()['stalled_s'] == pytest.approx(0.3, abs=0.1)
+
+
+def test_a_wait_that_recurs_is_the_programs_not_a_stall(span_dog):
+    """A span's longest stretch is remembered for a while (compiles left
+    out): the second round of the same wait is under twice the first and
+    no stall; one well over it is."""
+    from distllm_tpu.observability import get_flight_recorder, steps
+
+    engine = _Watched()
+    span_dog.watch(engine)
+    before = get_flight_recorder().total_recorded
+
+    def fetch(seq, seconds):
+        step = steps.StepSpan(seq=seq)
+        step.mark('fetch')
+        time.sleep(seconds)
+        step.close()
+        return step.fields()
+
+    for seq in range(990036, 990040):  # what a fetch typically takes
+        fetch(seq, 0.01)
+    assert fetch(990040, 0.4)['stalled_s'] == pytest.approx(0.4, abs=0.1)
+    assert 0.7 < steps.stall_threshold_s('fetch') <= 0.9  # twice, fading
+    assert 'stalled_s' not in fetch(990041, 0.4)
+    assert fetch(990042, 1.1)['stalled_s'] == pytest.approx(1.1, abs=0.1)
+    assert [r['seq'] for r in _stalls(before) if r['sample'] == 0] == [
+        990040, 990042,
+    ]
+    # a compile's stretch teaches nothing
+    engine.context['compiling'] = True
+    step = steps.StepSpan(seq=990043)
+    step.mark('decode')
+    time.sleep(0.4)
+    step.close()
+    assert steps.stall_threshold_s('decode') == 0.2
+
+
+def test_a_hole_is_a_stall_only_while_somebody_waits(span_dog):
+    """A thread between two spans with nothing unfinished is an idle
+    server; with a request unfinished the hole is a stall, and its seconds
+    go to the next step that opens on the thread."""
+    from distllm_tpu.observability import get_flight_recorder, steps
+
+    engine = _Watched(unfinished=0)
+    span_dog.watch(engine)
+    before = get_flight_recorder().total_recorded
+    step = steps.StepSpan(seq=990019)
+    step.mark('plan')
+    step.close()
+    time.sleep(0.4)  # between two calls, nothing unfinished
+    assert _stalls(before) == []
+    assert None not in steps._typical  # nor does idling teach what is typical
+    root = steps.StepSpan(seq=990020)
+    root.mark('serve')
+    step = steps.StepSpan(seq=990021)
+    step.mark('plan')
+    step.close()
+    assert 'stalled_s' not in step.fields()
+    engine.context['unfinished'] = 2
+    time.sleep(0.4)
+    step = steps.StepSpan(seq=990022)
+    step.mark('plan')
+    step.close()
+    root.close()
+    (record,) = _stalls(before)
+    assert record['span'] is None and record['sample'] == 0
+    assert record['seq'] == 990021  # the step whose span closed last
+    assert record['unfinished'] == 2
+    fields = step.fields()
+    assert fields['stalled_s'] == pytest.approx(0.4, abs=0.1)
+    assert fields['serve_self_s'] >= fields['stalled_s']
+    # another thread's engine is not this thread's waiting
+    engine.context['thread'] = -1
+    before = get_flight_recorder().total_recorded
+    time.sleep(0.4)
+    assert _stalls(before) == []
+
+
+def test_the_span_watcher_lives_from_the_first_engine_to_the_last(span_dog):
+    import gc
+    import threading
+
+    def alive():
+        return [t for t in threading.enumerate() if t.name == 'test-span-dog']
+
+    first, second = _Watched(), _Watched()
+    assert not alive()
+    span_dog.watch(first)
+    span_dog.watch(second)
+    assert len(alive()) == 1
+    span_dog.unwatch(first)
+    assert len(alive()) == 1
+    span_dog.unwatch(second)  # the last shutdown() stops it
+    assert not alive()
+    span_dog.watch(first)  # and a later engine starts it again
+    assert len(alive()) == 1
+    del first  # dropped without a shutdown(): held weakly
+    gc.collect()
+    deadline = time.monotonic() + 2.0
+    while alive() and time.monotonic() < deadline:
+        time.sleep(0.02)
+    assert not alive()
+    assert span_dog.engine_contexts() == []
+
+
+def test_an_engine_slowed_by_the_fault_writes_a_stall_record(span_dog):
+    """The injected stall ``slow_window`` sleeps where a wedged fetch
+    would: the record's first stack names it, and the engine says what it
+    had in flight."""
+    from serving_smoke import build_engine
+
+    from distllm_tpu.generate.engine import SamplingParams
+    from distllm_tpu.observability import get_stall_watchdog
+    from distllm_tpu.resilience.faults import get_fault_injector
+
+    engine = build_engine(warm=False)
+    assert get_stall_watchdog() is span_dog
+    assert engine in span_dog._engines
+    params = SamplingParams(temperature=0.0, max_tokens=6)
+    engine.generate_ids([[1, 2, 3, 4]], params)  # compiles the window
+    before = engine.flight.total_recorded
+    injector = get_fault_injector()
+    injector.arm('slow_window', times=1, delay_s=0.5)
+    try:
+        engine.generate_ids([[5, 6, 7, 8]], params)
+    finally:
+        injector.disarm()
+    stalls = [r for r in _stalls(before) if not r['compiling']]
+    assert [r['sample'] for r in stalls][:1] == [0]
+    record = stalls[0]
+    assert any(' maybe_sleep' in f for f in record['stacks'][0]['frames'][:2])
+    assert any(' _process_window' in f for f in record['stacks'][0]['frames'])
+    assert record['in_flight'] >= 1 and len(record['ready']) == record['in_flight']
+    assert record['unfinished'] == 1
+    grew = engine.flight.total_recorded - before
+    steps_with = [
+        r for r in engine.flight.snapshot()[-grew:] if r.get('stalled_s')
+    ]
+    assert sum(r['stalled_s'] for r in steps_with) == pytest.approx(0.5, abs=0.1)
+    engine.shutdown()
+    assert engine not in span_dog._engines
+    assert not [
+        t for t in __import__('threading').enumerate()
+        if t.name == 'test-span-dog'
+    ]
+
+
+def test_debug_bundle_says_where_the_process_is(tmp_path):
+    from distllm_tpu.observability import steps
+
+    step = steps.StepSpan(seq=990030)
+    step.mark('fetch')
+    try:
+        paths = dump_debug_bundle(tmp_path / 'bundle', reason='stacks')
+    finally:
+        step.close()
+    stacks = json.loads((tmp_path / 'bundle' / 'stacks.json').read_text())
+    assert paths['stacks'].endswith('stacks.json')
+    mine = [s for s in stacks['stacks'] if s['thread'] == 'MainThread']
+    # the caller's own thread is whoever dumps the bundle: left out, as the
+    # watcher's is from its record
+    assert mine == []
+    spans = [s for s in stacks['spans'] if s['thread'] == 'MainThread']
+    assert spans and spans[0]['span'] == 'fetch' and spans[0]['seq'] == 990030
+    assert spans[0]['age_s'] >= 0.0
+
+
 # ---------------------------------------------------------------- log_event
 def test_log_event_prints_and_counts(capsys):
     counter = get_registry().get('distllm_log_messages_total')

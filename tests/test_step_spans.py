@@ -169,6 +169,235 @@ def test_a_step_inside_admit_nests_there_and_plan_keeps_preempt():
     assert steps.current() is None
 
 
+# ------------------------------------------------- edges another thread reads
+def _on_a_fresh_thread(body):
+    """Run ``body`` on a thread that has opened no span yet; returns what
+    it returns (an assertion that fails there fails the test)."""
+    import threading
+
+    out = []
+
+    def run():
+        # an ended thread's ident can be handed out again, its edge with it
+        steps.forget(threading.get_ident())
+        try:
+            out.append(('ok', body()))
+        except BaseException as exc:  # noqa: BLE001 - re-raised below
+            out.append(('raised', exc))
+
+    thread = threading.Thread(target=run, name='span-test-thread')
+    thread.start()
+    thread.join(20)
+    assert out, 'the thread did not end'
+    verdict, value = out[0]
+    if verdict == 'raised':
+        raise value
+    return value
+
+
+def _where(edge):
+    return edge.span, edge.seq, edge.root
+
+
+@time_limit(30)
+def test_edge_table_follows_marks_pauses_nesting_abandon_and_a_root():
+    """The module's table says, to any thread, where a thread's spans
+    stand and since when: every ``mark``/``pause``/``close``/``inside``
+    /``abandon`` moves its one entry, with the clock read the span made."""
+    import threading
+
+    def body():
+        ident = threading.get_ident()
+        assert ident not in {e.ident for e in steps.edges()}
+        root = steps.StepSpan(seq=100)
+        t_root = root.mark('serve')
+        edge = next(e for e in steps.edges() if e.ident == ident)
+        assert edge.thread == 'span-test-thread'
+        assert edge.native_id == threading.get_native_id()
+        assert _where(edge) == (None, None, True) and edge.t == t_root
+        step = steps.StepSpan(seq=101)
+        t_admit = step.mark('admit')
+        assert _where(edge) == ('admit', 101, True) and edge.t == t_admit
+        # a step inside admit is the innermost; closing it uncovers admit
+        inner = steps.StepSpan(seq=102)
+        inner.mark('plan')
+        assert _where(edge) == ('plan', 102, True)
+        t_inner = inner.close()
+        assert _where(edge) == ('admit', 101, True) and edge.t == t_inner
+        step.mark('plan')
+        with step.inside('preempt'):
+            assert _where(edge) == ('preempt', 101, True)
+        assert _where(edge) == ('plan', 101, True)
+        step.mark('decode')
+        # paused (the window in flight): a hole under the root, which
+        # remembers the step whose span closed last
+        t_paused = step.pause()
+        assert _where(edge) == (None, 101, True) and edge.t == t_paused
+        step.mark('fetch')
+        assert _where(edge) == ('fetch', 101, True)
+        step.close()
+        assert _where(edge) == (None, 101, True)
+        # a dispatch that raised midway: abandon closes what it left open
+        broken = steps.StepSpan(seq=103)
+        broken.mark('plan')
+        broken._push('preempt', steps.clock())
+        steps.abandon()
+        assert _where(edge) == (None, 103, False)  # the root went too
+        assert edge.t >= root.t0
+        # a span with no root around it (a lone tier span)
+        lone = steps.StepSpan(seq=104)
+        lone.mark('promote')
+        assert _where(edge) == ('promote', 104, False)
+        t_end = lone.close()
+        assert _where(edge) == (None, 104, False) and edge.t == t_end
+        assert edge.flagged is None and edge.carry is None
+        return ident
+
+    ident = _on_a_fresh_thread(body)
+    assert ident in {e.ident for e in steps.edges()}
+    steps.forget(ident)  # what the watcher does for a thread that ended
+    assert ident not in {e.ident for e in steps.edges()}
+
+
+@time_limit(30)
+def test_a_hole_under_the_root_is_the_next_records_serve_self_s():
+    """``serve_self_s``: the seconds under an open root and under no other
+    span since the last record that carried the field. With the children's
+    own fields it adds up to the root's extent: nothing of the thread's
+    time under a root is in no field."""
+
+    def body():
+        root = steps.StepSpan(seq=200)
+        root.mark('serve')
+        time.sleep(0.02)  # the loop's own lines before the first step
+        first = steps.StepSpan(seq=201)
+        first.mark('plan')
+        time.sleep(0.01)
+        first.mark('decode')
+        first.pause()
+        time.sleep(0.03)  # a hole: the window in flight
+        first.mark('fetch')
+        first.close()
+        one = first.fields()
+        time.sleep(0.01)
+        second = steps.StepSpan(seq=202)
+        second.mark('admit')
+        nested = steps.StepSpan(seq=203)  # inside admit: its record is first
+        nested.mark('plan')
+        time.sleep(0.01)
+        nested.close()
+        inside = nested.fields()
+        second.close()
+        two = second.fields()
+        time.sleep(0.01)  # behind the last record: left for the next one
+        root.close()
+        left = steps._local.edge.self_s
+        return root, one, inside, two, left
+
+    root, one, inside, two, left = _on_a_fresh_thread(body)
+    assert one['serve_self_s'] == pytest.approx(0.05, abs=0.02)
+    assert one['serve_self_s'] >= 0.05
+    # the next record to be written takes the hole, whichever step's it is
+    assert inside['serve_self_s'] == pytest.approx(0.01, abs=0.01)
+    assert two['serve_self_s'] == 0.0  # admit was open all the while
+    assert left >= 0.01
+    children = sum(
+        seconds for record in (one, two)  # the nested step's lie in admit_s
+        for name, seconds in record.items() if name in CHILD_FIELDS
+    )
+    selves = one['serve_self_s'] + inside['serve_self_s'] + two['serve_self_s']
+    assert children + selves + left == pytest.approx(
+        root.t1 - root.t0, abs=1e-3
+    )
+    assert 'stalled_s' not in one and 'stalled_s' not in two
+
+
+@time_limit(30)
+def test_attribution_off_writes_no_edge():
+    import threading
+
+    def body():
+        root = steps.StepSpan(seq=300, annotate=False)
+        root.mark('serve')
+        step = steps.StepSpan(seq=301, annotate=False)
+        step.mark('plan')
+        assert steps.current() == ('plan', 301)
+        step.close()
+        root.close()
+        assert 'serve_self_s' not in step.fields()
+        assert not hasattr(steps._local, 'edge')
+        ident = threading.get_ident()
+        assert ident not in {e.ident for e in steps.edges()}
+
+    _on_a_fresh_thread(body)
+
+
+@time_limit(60)
+def test_an_engine_with_attribution_off_is_not_watched_and_writes_no_field():
+    from distllm_tpu.observability.flight import get_stall_watchdog
+
+    def body():
+        engine = _engine(attribution=False)
+        assert engine not in get_stall_watchdog()._engines
+        before = engine.flight.total_recorded
+        engine.generate_ids(
+            _prompts((5,)), SamplingParams(temperature=0.0, max_tokens=4)
+        )
+        records = [
+            r for r in _since(engine, before) if r['kind'] in STEP_KINDS
+        ]
+        assert records
+        assert not any('serve_self_s' in r or 'seq' in r for r in records)
+        assert not hasattr(steps._local, 'edge')
+        engine.shutdown()
+
+    _on_a_fresh_thread(body)
+
+
+@time_limit(120)
+def test_engine_records_partition_the_serving_threads_time_under_the_root():
+    """Over one pipelined call, the step records' top-level fields and
+    their ``serve_self_s`` add up to the call's root span, which is open
+    from the loop's start to its end."""
+    engine = _engine()
+    engine.generate_ids(  # compiles: a call of its own
+        _prompts((5, 9)), SamplingParams(temperature=0.0, max_tokens=9)
+    )
+    edge = steps._local.edge
+    assert engine in get_watched()
+    before = engine.flight.total_recorded
+    edge.self_s = 0.0
+    t0 = steps.clock()
+    engine.generate_ids(
+        _prompts((5, 9, 17)), SamplingParams(temperature=0.0, max_tokens=9)
+    )
+    t1 = steps.clock()
+    records = [r for r in _since(engine, before) if r['kind'] in STEP_KINDS]
+    assert all('serve_self_s' in r for r in records)
+    windows = [r for r in records if r['kind'] != 'prefill']
+    under_root = sum(
+        r.get(f, 0.0) for r in windows for f in CHILD_FIELDS
+    ) + sum(r['serve_self_s'] for r in records) + edge.self_s
+    # a window's admit_s holds its prefill steps; what the call spends
+    # outside the root (building requests, collecting outputs) is the rest
+    assert under_root <= t1 - t0 + 1e-3
+    assert under_root >= 0.8 * (t1 - t0)
+    stat = engine.stall_context()
+    assert stat['unfinished'] == 0 and stat['in_flight'] == 0
+    assert stat['ready'] == [] and stat['compiling'] is False
+    import threading
+
+    assert stat['thread'] == threading.get_ident()
+    engine.shutdown()
+    assert engine not in get_watched()
+
+
+def get_watched():
+    from distllm_tpu.observability.flight import get_stall_watchdog
+
+    return set(get_stall_watchdog()._engines)
+
+
 # -------------------------------------------------------- step records
 def _check_step_records(records):
     step_records = [r for r in records if r['kind'] in STEP_KINDS]
