@@ -760,6 +760,14 @@ class LLMEngine:
         self.cache_spec = spec
         self._refuse_unservable(spec, cfg, mesh, kv_pool_dtype)
         self._programs = importlib.import_module(spec.programs)
+        # A family may hold some leaves otherwise for its programs than its
+        # public tree does (``deepseek_v3.serving_params``: a layer an
+        # array where a stack's slices would be written out every step);
+        # everything below takes the tree leaf by leaf. Without the
+        # function the tree is served as it was given.
+        serving_params = getattr(self._programs, 'serving_params', None)
+        if serving_params is not None:
+            self.params = serving_params(self.params, own=own_params)
         # On the prefill and decode records of a model whose stack runs
         # several times a token: the passes, and the K/V planes they fill.
         self._loop_fields = {} if spec.passes == 1 else {
@@ -1749,6 +1757,7 @@ class LLMEngine:
         flat_params, treedef = jax.tree.flatten(self.params)
         flat_formats = treedef.flatten_up_to(formats)
         migrated = []
+        relayout = {}
         moved_bytes = 0
         # Device-side relayout needs source + target live at once; for the
         # stacked MLP kernels (3.8 GiB each at 7B dims) that overflows HBM
@@ -1839,10 +1848,15 @@ class LLMEngine:
                     # bench run 5 — the cached auto-layout window then
                     # rejects the params at dispatch). XLA always honors
                     # out_shardings; donation bounds the transient to the
-                    # target buffer.
-                    moved = jax.jit(
-                        lambda a: a, donate_argnums=0, out_shardings=fmt
-                    )(leaf)
+                    # target buffer. One jitted identity a layout: the
+                    # leaves of one shape that ask for it (a kernel held a
+                    # layer an array brings 24) share one compile.
+                    layout = str(fmt.layout)
+                    if layout not in relayout:
+                        relayout[layout] = jax.jit(
+                            lambda a: a, donate_argnums=0, out_shardings=fmt
+                        )
+                    moved = relayout[layout](leaf)
                     moved_bytes += nbytes
                     if moved_bytes > (1 << 30):
                         jax.block_until_ready(moved)

@@ -422,11 +422,11 @@ def causal_mask(q_len: int, kv_len: int, offset: int = 0) -> jnp.ndarray:
 # ------------------------------------------------ a walk over stacked layers
 def layer_at(tree, i, skip=(), dynamic: bool | None = None):  # distlint: traced
     """Layer ``i`` of a stacked tree, without the leaves ``skip`` names (a
-    sparse tree's expert banks: a slice of those would be a copy of the
-    layer's whole bank). A static ``i`` is a static slice, which folds into
-    its matmul, a traced one a dynamic slice; ``dynamic=True`` takes the
-    dynamic slice for either, so that a scan's body which a run of one
-    layer calls with a static index lowers to one text."""
+    bank: its slice would be a copy). A static ``i`` is a static slice, a traced
+    one (or ``dynamic=True``: a scan's body and a run of one layer lower to one
+    text) a dynamic slice. A static slice folds into its dot only until the
+    compiler merges the layers' slices into one fusion that writes each out: a
+    leaf held a layer an array (``unstack``, a tuple) gives element ``i``."""
     if dynamic is None:
         dynamic = not isinstance(i, int)
     if dynamic:
@@ -434,7 +434,7 @@ def layer_at(tree, i, skip=(), dynamic: bool | None = None):  # distlint: traced
     else:
         pick = lambda a: a[i]  # noqa: E731
     return jax.tree.map(
-        pick, {n: leaf for n, leaf in tree.items() if n not in skip}
+        pick, {n: t for n, t in tree.items() if n not in skip}, is_leaf=_a_tuple
     )
 
 
@@ -632,3 +632,31 @@ def conv_tail(window, tail_lens, keep: int):  # distlint: traced
     starts from; carried rows where a row counts fewer than ``keep``."""
     idx = tail_lens[:, None] + jnp.arange(keep)[None, :]
     return jnp.take_along_axis(window, idx[..., None], axis=1)
+
+
+def _a_tuple(leaf) -> bool:
+    return isinstance(leaf, tuple)
+
+
+def unstack(stack, own: bool = False) -> tuple:
+    """A stacked leaf ``[L, ...]`` as a tuple of its ``L`` layers, each an
+    array of its own (``layer_at`` picks from either): the form a family's
+    ``serving_params`` gives a leaf whose static slices the compiler would
+    otherwise write out every step of an unrolled window. The layers are
+    taken one at a time by one jitted program a shape (a host array's are
+    its views); ``own`` deletes the stack behind the last of them, so that
+    the two forms are both alive only for that long. Under a layer SCAN the
+    index is traced (and under ``dynamic=True`` taken as if it were) and a
+    tuple cannot be indexed by it: such a walk keeps its stacks."""
+    if not isinstance(stack, jax.Array):
+        return tuple(stack[i] for i in range(stack.shape[0]))
+    layers = tuple(_layer_of(stack, i) for i in range(stack.shape[0]))
+    if own:
+        jax.block_until_ready(layers)
+        stack.delete()
+    return layers
+
+
+@jax.jit
+def _layer_of(stack, i):
+    return jax.lax.dynamic_index_in_dim(stack, i, 0, keepdims=False)

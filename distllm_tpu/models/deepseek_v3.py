@@ -288,6 +288,31 @@ def params_from_hf(state: dict, cfg: DeepseekV3Config) -> dict:
     )
 
 
+# The attention kernels that the serving programs read a layer an array:
+# the decode window walks its layers unrolled, and the compiler merges the
+# 24 static slices of each of these three stacks into fusions that write
+# every layer's kernel out again each step (805 MB read and nearly all of it
+# written at the cell's depth, 2.1 ms of a 24.1 ms step; ``PERF.md`` section
+# 6, PR 51). ``o`` and ``kv_a`` are sliced the same way and fold into their
+# dots; ``tests/test_aot_tpu.py`` holds every window's text to the rule.
+_PER_LAYER = ('q', 'k_up', 'v_up')
+
+
+def serving_params(params: dict, own: bool = False) -> dict:
+    """The tree the serving programs read, of the public tree
+    (``init_on_device``'s, ``param_specs``'): the same arrays, with each
+    stack of ``_PER_LAYER`` a tuple of its layers (``common.unstack``), so
+    that ``common.layer_at`` hands a layer's kernel on and no program
+    slices one. The engine calls this once, before it compiles; ``own``
+    (the engine owns ``params``) deletes each stack as its layers stand,
+    without it the caller's stacks live on beside them (805 MB at the
+    cell's widths). ``params`` itself is not changed."""
+    attn = dict(params['attn'])
+    for name in _PER_LAYER:
+        attn[name] = {'kernel': common.unstack(attn[name]['kernel'], own)}
+    return {**params, 'attn': attn}
+
+
 # ------------------------------------------------------------ shared parts
 def _norm(x, scale, cfg):
     return common.rms_norm(x, scale, cfg.rms_norm_eps)
@@ -469,8 +494,9 @@ def _decode_core(
 ):
     """One token of every row (``common.decode_window``'s ``core`` once its
     first four arguments are bound; ``caches`` is ``(planes,)``). The layers
-    are walked unrolled, each with static indices: a static slice of the
-    stacked kernels folds into its matmul, and a layer's plane is written in
+    are walked unrolled, each with static indices: a static slice of a
+    stacked kernel folds into its matmul, the kernels of ``_PER_LAYER`` are
+    a layer an array in the serving form, and a layer's plane is written in
     place."""
     from distllm_tpu.ops.paged_attention import decode_attention, write_token_kv
 

@@ -617,6 +617,104 @@ def _assert_pools_go_to_the_kernel_as_they_lie(compiled, buffers) -> None:
     assert pools_read >= 2  # a K and a V at the least
 
 
+def _hlo_computations(text: str) -> tuple[dict, str]:
+    """``(name -> the lines of its body, the entry's name)`` of a compiled
+    program's text."""
+    import re
+
+    bodies, entry, into = {}, None, None
+    for line in text.splitlines():
+        m = re.match(r'^(ENTRY )?%(\S+) \(.*\{\s*$', line)
+        if m:
+            into = bodies.setdefault(m.group(2), [])
+            entry = m.group(2) if m.group(1) else entry
+        elif line.startswith('}'):
+            into = None
+        elif into is not None:
+            into.append(line)
+    return bodies, entry
+
+
+_CALLED = r'(?:body|condition|to_apply|calls|\w+_computations?)=\{?((?:%[^\s,)}]+(?:, )?)+)'
+
+
+def _weight_slices_in_the_step_scan(text: str, params) -> list:
+    """Every op in a loop's body (the computations reached from a ``while``
+    of the entry: the step scan, what it calls and the loops inside it, and
+    no fused computation) that MAKES an array of a weight's shape: a
+    ``fusion``, ``copy``, ``slice``, ``dynamic-slice`` or ``transpose``
+    with a result, or a tuple's member, that has the dimensions of a leaf of
+    ``params`` or of one layer of a stacked leaf (a matrix of a MiB or more;
+    axes of 1 left aside). A fusion whose root is a ``bitcast`` makes
+    nothing, and one that holds a matmul makes its product (``solar``'s 128
+    rows by 8192 are also a low-rank kernel's shape). ``[(op, results of
+    that shape)]``.
+
+    A weight is read by the dot that multiplies by it, where it lies. An op
+    of this list reads a layer's kernel out of its stack and writes it down
+    again every step: the compiler merges the static slices that an unrolled
+    walk takes of one stacked leaf into one multi-output fusion, and a slice
+    inside such a fusion can no longer be an operand of its dot (``PERF.md``
+    section 6, PR 51). The cure is ``models.common.unstack``."""
+    import re
+
+    def dims(shape):
+        return tuple(int(d) for d in shape if int(d) != 1)
+
+    weights = set()
+    for leaf in jax.tree.leaves(params):
+        for shape in (leaf.shape, leaf.shape[1:]):
+            size = int(np.prod(shape)) * jnp.dtype(leaf.dtype).itemsize
+            if len(dims(shape)) >= 2 and size >= 1 << 20:
+                weights.add(dims(shape))
+    bodies, entry = _hlo_computations(text)
+    defs = {name: _hlo_defs('\n'.join(lines)) for name, lines in bodies.items()}
+
+    def called(instructions, opcodes=None):
+        return [
+            name for _, opcode, call in instructions.values()
+            if (opcode != 'fusion' if opcodes is None else opcode in opcodes)
+            for group in re.findall(_CALLED, call)
+            for name in re.findall(r'%([^\s,)}]+)', group)
+        ]
+
+    loops = called(defs[entry], ('while',))
+    reached = set()
+    while loops:
+        name = loops.pop()
+        if name not in reached:
+            reached.add(name)
+            loops += called(defs[name])
+    found = []
+    for comp in sorted(reached):
+        for name, (result, opcode, call) in defs[comp].items():
+            if opcode not in ('fusion', 'copy', 'slice', 'dynamic-slice', 'transpose'):
+                continue
+            held = [
+                f'{dtype}[{shape}]'
+                for dtype, shape in re.findall(r'(\w+)\[([0-9,]+)\]', result)
+                if dims(shape.split(',')) in weights
+            ]
+            if held and opcode == 'fusion':
+                (callee,) = called({name: (result, opcode, call)}, ('fusion',))
+                fused = _hlo_defs('\n'.join(bodies[callee])).values()
+                root = [op for _, op, _ in fused][-1]
+                if root == 'bitcast' or any(
+                    op in ('convolution', 'dot') for _, op, _ in fused
+                ):
+                    continue
+            if held:
+                found.append((f'%{name} = {opcode}', held))
+    return found
+
+
+def _assert_no_weight_is_sliced_in_the_step_scan(compiled, params) -> None:
+    found = _weight_slices_in_the_step_scan(compiled.as_text(), params)
+    assert not found, [
+        f'{op}: {len(held)} x {held[0]}' for op, held in found
+    ]
+
+
 @pytest.fixture(scope='module')
 def laguna_cell(v5e):
     """The laguna cell's configuration cut to one period of layers (one
@@ -648,7 +746,9 @@ def laguna_cell(v5e):
     return laguna, cfg, params, pools, buffers
 
 
-def test_laguna_decode_window_reads_the_pools_as_they_lie(v5e, laguna_cell):
+@pytest.fixture(scope='module')
+def laguna_window(v5e, laguna_cell):
+    """The decode window at the cell's 48 rows, compiled once."""
     laguna, cfg, params, pools, buffers = laguna_cell
     b, i32, f32 = 48, jnp.int32, jnp.float32
 
@@ -658,13 +758,16 @@ def test_laguna_decode_window_reads_the_pools_as_they_lie(v5e, laguna_cell):
             num_steps=8, attn_backend='pallas', max_table_positions=8448,
         )
 
-    compiled = jax.jit(window_fn, donate_argnums=(4, 5)).lower(
+    return jax.jit(window_fn, donate_argnums=(4, 5)).lower(
         params, v5e((b,), i32), v5e((b,), i32), v5e((b,), i32), pools, pools,
         (v5e((b, 528), i32),) * 2, v5e((b,), i32), v5e((b,), f32),
         v5e((b,), f32), v5e((b,), f32), v5e((b,), i32), v5e((b,), jnp.uint32),
     ).compile()
-    _assert_pools_go_to_the_kernel_as_they_lie(compiled, buffers)
-    _assert_decode_calls_walk(compiled)
+
+
+def test_laguna_decode_window_reads_the_pools_as_they_lie(laguna_cell, laguna_window):
+    _assert_pools_go_to_the_kernel_as_they_lie(laguna_window, laguna_cell[4])
+    _assert_decode_calls_walk(laguna_window)
 
 
 def test_laguna_chunk_prefill_reads_the_pools_as_they_lie(v5e, laguna_cell):
@@ -689,7 +792,8 @@ def test_laguna_chunk_prefill_reads_the_pools_as_they_lie(v5e, laguna_cell):
 @pytest.fixture(scope='module')
 def kanana_cell(v5e):
     """The cell's configuration cut to three layers (the dense one and two
-    sparse), the parameters and the planes at the cell's sizes."""
+    sparse), the parameters in the form the engine serves from
+    (``deepseek_v3.serving_params``) and the planes at the cell's sizes."""
     import json
     from pathlib import Path
 
@@ -699,9 +803,9 @@ def kanana_cell(v5e):
     hf = json.loads((root / 'benchmarks/configs/kanana-2-30b-a3b.json').read_text())
     hf['num_hidden_layers'] = 3
     cfg = deepseek_v3.DeepseekV3Config.from_hf_config(hf)
-    shapes = jax.eval_shape(
-        lambda: deepseek_v3.init_on_device(jax.random.PRNGKey(0), cfg)
-    )
+    shapes = jax.eval_shape(lambda: deepseek_v3.serving_params(
+        deepseek_v3.init_on_device(jax.random.PRNGKey(0), cfg)
+    ))
     params = jax.tree.map(lambda a: v5e(a.shape, a.dtype), shapes)
     plane = (hf['engine']['num_blocks'], 16, cfg.stored_row)
     return deepseek_v3, cfg, params, (v5e(plane, jnp.bfloat16),) * 3, plane, hf['engine']
@@ -792,6 +896,7 @@ def _mistral_7b(v5e, num_layers):
     return mistral, cfg, jax.tree.map(lambda a: v5e(a.shape, a.dtype), shapes)
 
 
+@functools.lru_cache(maxsize=None)
 def _mistral_window(v5e, pool):
     """The 7B decode window (8 steps, 32 rows, the layers unrolled) over
     ``mistral7b.batch_generate``'s 640 blocks a layer."""
@@ -1408,19 +1513,15 @@ def falcon_h1_cell(v5e):
     return falcon_h1, cfg, params, pool, state, engine
 
 
-def test_falcon_h1_decode_window_updates_pages_and_state_in_place(
-    v5e, falcon_h1_cell
-):
-    """The decode window at the cell's 96 rows and full depth: every layer
-    writes a page and a state slot in the same step. The stacked pool goes
-    to the writers and to the kernel whole, every kernel call (5 queries a
-    KV head) takes the row walk, and nothing as large as a layer's states
-    (96 x 4 MB) is left over as a temporary beside the sampler's rows."""
+@pytest.fixture(scope='module')
+def falcon_h1_window(v5e, falcon_h1_cell):
+    """The decode window at the cell's 96 rows and full depth, compiled
+    once."""
     falcon_h1, cfg, params, pool, state, engine = falcon_h1_cell
     b, i32, f32 = engine['max_num_seqs'], jnp.int32, jnp.float32
     assert b == 96
     pools = v5e(pool, jnp.bfloat16)
-    compiled = jax.jit(
+    return jax.jit(
         lambda p, i, po, c, k, v, bt, sl, tmp, tp_, mp, tk, sd, st:
             falcon_h1.decode_loop(
                 p, cfg, i, po, k, v, bt, c, sl, tmp, tp_, mp, tk, sd,
@@ -1433,7 +1534,18 @@ def test_falcon_h1_decode_window_updates_pages_and_state_in_place(
         v5e((b, 256), i32), v5e((b,), i32), v5e((b,), f32), v5e((b,), f32),
         v5e((b,), f32), v5e((b,), i32), v5e((b,), jnp.uint32), state,
     ).compile()
-    _assert_stacked_pool_is_addressed(compiled, pool)
+
+
+def test_falcon_h1_decode_window_updates_pages_and_state_in_place(
+    falcon_h1_cell, falcon_h1_window
+):
+    """The decode window at the cell's 96 rows and full depth: every layer
+    writes a page and a state slot in the same step. The stacked pool goes
+    to the writers and to the kernel whole, every kernel call (5 queries a
+    KV head) takes the row walk, and nothing as large as a layer's states
+    (96 x 4 MB) is left over as a temporary beside the sampler's rows."""
+    compiled = falcon_h1_window
+    _assert_stacked_pool_is_addressed(compiled, falcon_h1_cell[3])
     _assert_decode_calls_walk(compiled)
     assert compiled.memory_analysis().temp_size_in_bytes < 1536 << 20
 
@@ -1465,19 +1577,16 @@ def test_falcon_h1_chunk_prefill_addresses_the_pool(v5e, falcon_h1_cell):
 
 # ---- solar_open2 (PR 45): a float32 matrix state a KDA layer, 8 queries a KV head ----
 
-def test_solar_open2_decode_window_updates_its_matrix_states_in_place(v5e, monkeypatch):
-    """The decode window at the cell's slots and full depth (one period: G
-    K K K, 40 held experts a layer) for a described v5e: the one attention
-    layer's pool goes to the kernel as it lies and its calls (8 queries a KV
-    head) take the row walk; the three matrix-state pools
-    (slots x 4 MB each) are donated and rewritten in place, so nothing as
-    large as ONE of them is left over as a temporary."""
+@pytest.fixture(scope='module')
+def solar_open2_window(v5e):
+    """``(compiled, parameters, pool, rows)``: the decode window at the
+    cell's slots and full depth (one period: G K K K, 40 held experts a
+    layer), compiled once with the family's kernels on."""
     import json
     from pathlib import Path
 
     from distllm_tpu.models import moe, solar_open2
 
-    monkeypatch.setattr(moe, 'grouped_backend', lambda: 'pallas')
     root = Path(__file__).resolve().parents[1]
     hf = json.loads(
         (root / 'benchmarks/configs/solar-open2-250b.json').read_text()
@@ -1497,18 +1606,33 @@ def test_solar_open2_decode_window_updates_its_matrix_states_in_place(v5e, monke
     )
     pools = v5e(pool, jnp.bfloat16)
     table = engine['max_model_len'] // engine['block_size']
-    compiled = jax.jit(
-        lambda p, i, po, c, k, v, bt, sl, tmp, tp_, mp, tk, sd, st:
-            solar_open2.decode_loop(
-                p, cfg, i, po, k, v, bt, c, sl, tmp, tp_, mp, tk, sd,
-                num_steps=8, attn_backend='pallas', state=st,
-            ),
-        donate_argnums=(4, 5, 13),
-    ).lower(
-        params, v5e((b,), i32), v5e((b,), i32), v5e((b,), i32), pools, pools,
-        v5e((b, table), i32), v5e((b,), i32), v5e((b,), f32), v5e((b,), f32),
-        v5e((b,), f32), v5e((b,), i32), v5e((b,), jnp.uint32), state,
-    ).compile()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(moe, 'grouped_backend', lambda: 'pallas')
+        compiled = jax.jit(
+            lambda p, i, po, c, k, v, bt, sl, tmp, tp_, mp, tk, sd, st:
+                solar_open2.decode_loop(
+                    p, cfg, i, po, k, v, bt, c, sl, tmp, tp_, mp, tk, sd,
+                    num_steps=8, attn_backend='pallas', state=st,
+                ),
+            donate_argnums=(4, 5, 13),
+        ).lower(
+            params, v5e((b,), i32), v5e((b,), i32), v5e((b,), i32), pools,
+            pools, v5e((b, table), i32), v5e((b,), i32), v5e((b,), f32),
+            v5e((b,), f32), v5e((b,), f32), v5e((b,), i32),
+            v5e((b,), jnp.uint32), state,
+        ).compile()
+    return compiled, params, pool, b
+
+
+def test_solar_open2_decode_window_updates_its_matrix_states_in_place(solar_open2_window):
+    """The decode window at the cell's slots and full depth for a described
+    v5e: the one attention layer's pool goes to the kernel as it lies and
+    its calls (8 queries a KV head) take the row walk; the three
+    matrix-state pools (slots x 4 MB each) are donated and rewritten in
+    place, so nothing as large as ONE of them is left over as a temporary."""
+    from distllm_tpu.models import moe
+
+    compiled, _, pool, b = solar_open2_window
     # a stack of one layer has no plane to slice: no relayout of the pool,
     # and the kernel reads the pool itself
     _assert_pools_go_to_the_kernel_as_they_lie(compiled, [pool, pool[1:]])
@@ -1774,17 +1898,14 @@ def ouro_cell(v5e):
     return ouro, cfg, params, pool, engine
 
 
-def test_ouro_decode_window_addresses_192_planes(v5e, ouro_cell):
-    """The decode window at the cell's rows: the passes a rolled loop around
-    the 48 unrolled layers, both pools in its carry, the plane a traced ``t
-    * L + l``. No op has a pool-sized result but the in-place write, none a
-    plane-sized one, and the kernel's decode calls (one query a KV head, a
-    folded row of 2048 lanes) take the row walk over the pool as it lies."""
+@pytest.fixture(scope='module')
+def ouro_window(v5e, ouro_cell):
+    """The decode window at the cell's rows and full depth, compiled once."""
     ouro, cfg, params, pool, engine = ouro_cell
     b, i32, f32 = engine['max_num_seqs'], jnp.int32, jnp.float32
     tables = -(-engine['max_model_len'] // engine['block_size'])
     pools = v5e(pool, jnp.bfloat16)
-    compiled = jax.jit(
+    return jax.jit(
         lambda p, i, po, c, k, v, bt, sl, tmp, tp_, mp, tk, sd: ouro.decode_loop(
             p, cfg, i, po, k, v, bt, c, sl, tmp, tp_, mp, tk, sd,
             num_steps=engine['decode_steps'], attn_backend='pallas',
@@ -1796,10 +1917,18 @@ def test_ouro_decode_window_addresses_192_planes(v5e, ouro_cell):
         v5e((b, tables), i32), v5e((b,), i32), v5e((b,), f32), v5e((b,), f32),
         v5e((b,), f32), v5e((b,), i32), v5e((b,), jnp.uint32),
     ).compile()
-    _assert_stacked_pool_is_addressed(compiled, pool)
-    _assert_decode_calls_walk(compiled)
+
+
+def test_ouro_decode_window_addresses_192_planes(ouro_cell, ouro_window):
+    """The decode window at the cell's rows: the passes a rolled loop around
+    the 48 unrolled layers, both pools in its carry, the plane a traced ``t
+    * L + l``. No op has a pool-sized result but the in-place write, none a
+    plane-sized one, and the kernel's decode calls (one query a KV head, a
+    folded row of 2048 lanes) take the row walk over the pool as it lies."""
+    _assert_stacked_pool_is_addressed(ouro_window, ouro_cell[3])
+    _assert_decode_calls_walk(ouro_window)
     # 48 bodies and not 192: the kernel's calls of one pass
-    assert len(_kernel_schedules(compiled)) == cfg.num_layers
+    assert len(_kernel_schedules(ouro_window)) == ouro_cell[1].num_layers
 
 
 def test_ouro_chunk_prefill_addresses_192_planes(v5e, ouro_cell):
@@ -1820,3 +1949,138 @@ def test_ouro_chunk_prefill_addresses_192_planes(v5e, ouro_cell):
     ).compile()
     _assert_stacked_pool_is_addressed(compiled, pool)
     _assert_span_calls_keep_the_grid(compiled)
+
+
+# ---- no weight is sliced inside the step scan (PR 51) ----
+
+def _kanana_window(v5e, cell, params, layers=None):
+    """The ``kanana`` decode window over ``params`` as the engine compiles
+    it (``_compile_auto_layout``): ``auto_layout_formats`` for the weights."""
+    from jax.experimental.layout import Format
+
+    from distllm_tpu.generate.engine.engine import auto_layout_formats
+
+    deepseek_v3, cfg, _, planes, _, engine = cell
+    if layers is not None:
+        cfg = cfg.model_copy(update={'num_layers': layers})
+        planes = planes[:1] * layers
+    b, i32, f32 = engine['max_num_seqs'], jnp.int32, jnp.float32
+    bare = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), params)
+
+    def window_fn(params, ids, pos, ctx, k, v, bt, steps_left, *sampling):
+        return deepseek_v3.decode_loop(
+            params, cfg, ids, pos, k, v, bt, ctx, steps_left, *sampling,
+            num_steps=8, attn_backend='pallas', max_table_positions=8448,
+        )
+
+    return jax.jit(
+        window_fn, donate_argnums=(4, 5),
+        in_shardings=(auto_layout_formats(bare),) + (Format(),) * 12,
+    ).lower(
+        bare, v5e((b,), i32), v5e((b,), i32), v5e((b,), i32), planes, (),
+        v5e((b, 528), i32), v5e((b,), i32), v5e((b,), f32), v5e((b,), f32),
+        v5e((b,), f32), v5e((b,), i32), v5e((b,), jnp.uint32),
+    ).compile()
+
+
+def _kanana_stacks(cell, layers=None):
+    """The family's public tree (stacks) at the cell's widths, as shapes."""
+    deepseek_v3, cfg = cell[:2]
+    if layers is not None:
+        cfg = cfg.model_copy(update={'num_layers': layers})
+    return jax.eval_shape(
+        lambda: deepseek_v3.init_on_device(jax.random.PRNGKey(0), cfg)
+    )
+
+
+def _window_and_params(family, v5e, request):
+    """``(compiled decode window, its parameter tree)`` of a family, the
+    windows this file builds at their cut depths (default layouts but
+    ``kanana``'s, which is compiled as the engine compiles it)."""
+    if family == 'kanana':
+        cell = request.getfixturevalue('kanana_cell')
+        return _kanana_window(v5e, cell, cell[2]), cell[2]
+    if family == 'solar_open2':
+        return request.getfixturevalue('solar_open2_window')[:2]
+    if family in ('mistral', 'granite'):
+        build = {'mistral': _mistral_window, 'granite': _granite_window}[family]
+        compiled = build(v5e, (2, {'mistral': 640, 'granite': 8192}[family], 16, _NKV * _HD))
+        return compiled, compiled.args_info[0][0]
+    cell = request.getfixturevalue(f'{family}_cell')
+    return request.getfixturevalue(f'{family}_window'), cell[2]
+
+
+def _sliced(what: str):
+    return pytest.mark.xfail(strict=True, reason=(
+        f'{what}: written down for the next writer, each a claim in its own '
+        "cell with its own traced pair (PERF.md section 7); a cure turns the "
+        'case red until this mark goes'
+    ))
+
+
+@pytest.mark.parametrize('family', [
+    'kanana', 'laguna', 'ouro', 'solar_open2', 'mistral',
+    pytest.param('lfm2', marks=_sliced(
+        'two multi-output fusions at the cut\'s two attention layers, 2 x '
+        'bf16[1,2048,2048] (8 MB each) and 2 x bf16[1,2048,512] (2 MB each), '
+        'one result of each in VMEM: 10 MB a layer a step, 60 MB at the '
+        'cell\'s six attention layers if none stays in VMEM'
+    )),
+    pytest.param('falcon_h1', marks=_sliced(
+        'six single-result fusions bf16[1,5120,2560] (the attention q '
+        'kernels, 26 MB each) and six bf16[1,5120,512] (5 MB each) at the '
+        'cut\'s 6 layers, every result in VMEM here (a read the dot no '
+        'longer makes itself): 189 MB a step written back only if one leaves VMEM'
+    )),
+    pytest.param('granite', marks=_sliced(
+        'one fusion of 2 x bf16[1,4096,4096] (32 MB each, both in HBM) with '
+        'two attention layers in the stack: 64 MB read and written a step; '
+        'the cell\'s cut has one attention layer and nothing to slice'
+    )),
+])
+def test_decode_window_slices_no_weight(v5e, request, family):
+    """An unrolled window takes each layer's kernels out of their stacks by
+    static slices, and a static slice folds into its dot only until the
+    compiler merges the layers' slices of one leaf into one fusion: then
+    every layer's kernel is read and written down again each step (the
+    ``kanana`` window's q, k-up and v-up kernels, 805 MB and 2.2 ms of a 24
+    ms step at 24 layers; PR 51). No op in any window's step scan makes an
+    array of a weight's shape; a family that shows one holds that leaf a
+    layer an array (``common.unstack``)."""
+    _assert_no_weight_is_sliced_in_the_step_scan(
+        *_window_and_params(family, v5e, request)
+    )
+
+
+def test_the_stacked_kanana_window_is_what_the_check_is_for(v5e, kanana_cell):
+    """The same window over the family's PUBLIC tree, the stacks the parent
+    served from: one fusion a leaf of q, k-up and v-up, each with all three
+    layers' kernels as its results, at the 3-layer cut."""
+    stacks = _kanana_stacks(kanana_cell)
+    found = _weight_slices_in_the_step_scan(
+        _kanana_window(v5e, kanana_cell, stacks).as_text(), stacks
+    )
+    assert sorted((len(held), held[0]) for _, held in found) == [
+        (3, 'bf16[1,2048,6144]'), (3, 'bf16[1,512,4096]'), (3, 'bf16[1,512,4096]'),
+    ]
+    assert all(op.endswith('= fusion') for op, _ in found)
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize('form', ['serving', 'stacks'])
+def test_full_depth_kanana_window_slices_no_weight(v5e, kanana_cell, form):
+    """The cell's 24 layers (17-19 s a compile): a cut shows the pattern,
+    the full depth its cost. Over the stacks six fusions, 19 + 5 results a
+    leaf, most of them written to HBM (``S(1)`` marks the few in VMEM); over
+    the serving form none, and no multi-output fusion of a weight's slices."""
+    deepseek_v3 = kanana_cell[0]
+    params = _kanana_stacks(kanana_cell, 24)
+    if form == 'serving':
+        params = jax.eval_shape(deepseek_v3.serving_params, params)
+    text = _kanana_window(v5e, kanana_cell, params, 24).as_text()
+    found = _weight_slices_in_the_step_scan(text, params)
+    if form == 'serving':
+        assert not found, found
+        return
+    assert sorted(len(held) for _, held in found) == [5, 5, 5, 19, 19, 19]
+    assert sum(len(held) for _, held in found) == 3 * 24
