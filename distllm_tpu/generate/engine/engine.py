@@ -3752,24 +3752,31 @@ class LLMEngine:
         fields for the group."""
         blocks = self.window_blocks
         tables = np.zeros((rows, self.max_blocks_per_seq), np.int32)
-        freed = live = 0
+        freed = live = under = 0
         for i, (request, start, ntok) in enumerate(spans):
             if request is None or ntok <= 0:
                 continue
             live += 1
+            under += start + ntok <= blocks.window
             freed += blocks.cover(request.request_id, start, start + ntok)
             blocks.table_row(request.request_id, tables[i])
-        return tables, self._window_fields(tables, live, freed)
+        return tables, self._window_fields(tables, live, freed, under)
 
-    @staticmethod
-    def _window_fields(tables: np.ndarray, live: int, freed: int) -> dict:
+    def _window_fields(
+        self, tables: np.ndarray, live: int, freed: int, under: int
+    ) -> dict:
         """``kv_blocks_window``: the windowed group's blocks a dispatch's
         rows hold, a row without a sequence counted for the trash block it
-        reads, as ``kv_blocks`` counts it."""
+        reads, as ``kv_blocks`` counts it; ``kv_window_pool_blocks``: the
+        group's pool, less the trash block; ``rows_under_window``: the live
+        rows whose context is no longer than the window (their window
+        layers read all of it)."""
         return {
             'kv_blocks_window': int(np.count_nonzero(tables))
             + tables.shape[0] - live,
             'window_blocks_freed': freed,
+            'kv_window_pool_blocks': self.window_blocks.num_blocks - 1,
+            'rows_under_window': int(under),
         }
 
     def _release_window_blocks(self, rid: int) -> None:
@@ -4309,7 +4316,7 @@ class LLMEngine:
         window_blocks = self.window_blocks
         if window_blocks is not None:
             window_tables = np.zeros_like(block_tables)
-            window_freed = window_live = 0
+            window_freed = window_live = window_under = 0
         plan: list[tuple[int, int, int]] = []
         any_steps = False
         for slot, request in running:
@@ -4323,6 +4330,7 @@ class LLMEngine:
             if window_blocks is not None and steps:
                 # The window's queries sit at total - 1 onward, one a step.
                 window_live += 1
+                window_under += total <= window_blocks.window
                 window_freed += window_blocks.cover(
                     rid, total - 1, total - 1 + steps
                 )
@@ -4355,7 +4363,7 @@ class LLMEngine:
         if window_blocks is not None:
             host_arrays.append(window_tables)  # never beside a chunk plan
             window_fields = self._window_fields(
-                window_tables, window_live, window_freed
+                window_tables, window_live, window_freed, window_under
             )
         if chunk_plan:
             chunk_arrays = self._build_chunk_arrays(chunk_plan)
@@ -5389,7 +5397,9 @@ class LLMEngine:
         token was never fed). Both are freed right after this record; the
         pool keeps what a freed block held until its next holder writes it,
         which is how the benchmark's check reads the K/V a finished request
-        left."""
+        left. With a windowed group, ``kv_window_first_index``,
+        ``kv_window_first_block`` and ``kv_window_tail_block`` beside them:
+        that group's ends (``WindowBlocks.ends``)."""
         if (
             self.window_kv is None and not self.cache_spec.latent
             and self.state_pool is None and self.cache_spec.passes == 1
@@ -5400,7 +5410,10 @@ class LLMEngine:
         if not row or written < 1:
             return {}
         tail = min((written - 1) // self.config.block_size, len(row) - 1)
-        return {'kv_first_block': row[0], 'kv_tail_block': row[tail]}
+        ends = {'kv_first_block': row[0], 'kv_tail_block': row[tail]}
+        if self.window_blocks is not None:
+            ends.update(self.window_blocks.ends(request.request_id, tail))
+        return ends
 
     # -------------------------------------------------------------- offline
     def generate_ids(

@@ -1342,6 +1342,21 @@ class WindowBlocks:
     def held(self, rid: int) -> int:
         return len(self._rows.get(rid, ()))
 
+    def ends(self, rid: int, tail: int) -> dict:
+        """The two ends of what ``rid`` holds, for a finished request's
+        flight record: the lowest table index it still holds with its
+        block (the window's lower edge), and the block at index ``tail``
+        (of the last position it wrote). Empty where it holds neither."""
+        row = self._rows.get(rid)
+        if not row or tail not in row:
+            return {}
+        first = min(row)
+        return {
+            'kv_window_first_index': first,
+            'kv_window_first_block': row[first],
+            'kv_window_tail_block': row[tail],
+        }
+
     @property
     def num_free(self) -> int:
         return len(self._free)

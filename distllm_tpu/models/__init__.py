@@ -30,6 +30,7 @@ def decoder_families() -> dict:
         mistral,
         mixtral,
         ouro,
+        smallthinker,
         solar_open2,
     )
 
@@ -49,6 +50,7 @@ def decoder_families() -> dict:
         'falcon_h1': (falcon_h1.FalconH1Config, falcon_h1),
         'solar_open2': (solar_open2.SolarOpen2Config, solar_open2),
         'ouro': (ouro.OuroConfig, ouro),
+        'smallthinker': (smallthinker.SmallThinkerConfig, smallthinker),
     }
 
 

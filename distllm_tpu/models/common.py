@@ -660,3 +660,92 @@ def unstack(stack, own: bool = False) -> tuple:
 @jax.jit
 def _layer_of(stack, i):
     return jax.lax.dynamic_index_in_dim(stack, i, 0, keepdims=False)
+
+
+# ------------------------------------------- a walk over two cache groups
+# A decoder whose layers are of two attention kinds, full and windowed, each
+# with a cache group of its own (``models/laguna.py``,
+# ``models/smallthinker.py``): the serving programs take a pair of each cache
+# operand in this order, a group's ``k_cache`` a tuple of one buffer a layer.
+CACHE_GROUPS = ('full', 'window')
+
+
+def layer_runs(kinds, trees=None) -> list[tuple]:
+    """``(*kind, first index in each of the layer's trees, count)`` of every
+    run of equal consecutive layers. ``kinds[l]`` is layer ``l``'s kind, a
+    tuple of names; ``trees[l]`` names the stacked trees the layer's
+    parameters lie in (the kind itself where None), a layer's index in a
+    tree being the count of earlier layers that lie in it."""
+    trees = kinds if trees is None else trees
+    runs: list[list] = []
+    seen: dict = {}
+    for kind, of_layer in zip(kinds, trees):
+        firsts = [seen.get(tree, 0) for tree in of_layer]
+        if runs and runs[-1][0] == kind:
+            runs[-1][2] += 1
+        else:
+            runs.append([kind, firsts, 1])
+        for tree in of_layer:
+            seen[tree] = seen.get(tree, 0) + 1
+    return [(*kind, *firsts, count) for kind, firsts, count in runs]
+
+
+def layer_indices(runs) -> list[tuple]:
+    """``(*kind, index in each of the layer's trees)`` of every layer of
+    ``layer_runs``' runs (a kind names as many things as a layer has
+    trees)."""
+    out = []
+    for *names, count in runs:
+        kind, firsts = names[: len(names) // 2], names[len(names) // 2:]
+        out += [(*kind, *(first + i for first in firsts)) for i in range(count)]
+    return out
+
+
+def run_indices(*firsts_and_count):
+    """A run's indices into its trees, one ``arange`` a tree: a scan's xs."""
+    *firsts, count = firsts_and_count
+    return tuple(
+        jnp.arange(first, first + count, dtype=jnp.int32) for first in firsts
+    )
+
+
+def walk_cache_groups(  # distlint: traced
+    layer, layers, name: str, x, k_cache, v_cache, block_tables, weights,
+    rest, counts=None,
+):
+    """The serving programs' walk over the layers of two cache groups,
+    unrolled, each layer a call of one jitted function a kind
+    (``once_a_kind``) with static indices: a static slice of the stacked
+    kernels folds into its matmul, and a layer's K and V buffers are written
+    in place.
+
+    ``layers``: ``(*kind, index in the group's tree, index in the second
+    tree)`` of every layer (``layer_indices``), ``kind[0]`` the layer's
+    cache group. ``layer(*kind, x, *weights(kind, ai, mi), k_buf, v_buf,
+    table, *rest(kind))`` returns ``(x, k_buf, v_buf)`` and, with ``counts``
+    (the zero of the family's counter), the layer's counts behind them.
+    ``k_cache``, ``v_cache`` and ``block_tables`` are pairs in
+    ``CACHE_GROUPS``' order. Returns ``(x, k_cache, v_cache)`` with the
+    caches as the pairs they came in as, and the summed counts where
+    asked."""
+    tables = dict(zip(CACHE_GROUPS, block_tables))
+    pools = {
+        g: [list(k), list(v)] for g, k, v in zip(CACHE_GROUPS, k_cache, v_cache)
+    }
+    kinds = [entry[:-2] for entry in layers]
+    layer_of = once_a_kind(layer, kinds, name)
+    for *kind, ai, mi in layers:
+        kind = tuple(kind)
+        k_pool, v_pool = pools[kind[0]]
+        x, k_pool[ai], v_pool[ai], *counted = layer_of[kind](
+            x, *weights(kind, ai, mi), k_pool[ai], v_pool[ai], tables[kind[0]],
+            *rest(kind),
+        )
+        if counts is not None:
+            counts = counts + counted[0]
+    out = (
+        x,
+        tuple(tuple(pools[g][0]) for g in CACHE_GROUPS),
+        tuple(tuple(pools[g][1]) for g in CACHE_GROUPS),
+    )
+    return out if counts is None else (*out, counts)

@@ -66,7 +66,7 @@ from distllm_tpu.utils import BaseConfig
 F32 = jnp.float32
 # layer_types entry -> the attention tree (and cache group) of the layer
 _ATTN_KINDS = {'full_attention': 'full', 'sliding_attention': 'window'}
-_GROUPS = ('full', 'window')  # the order of the cache operands' entries
+_GROUPS = common.CACHE_GROUPS  # the order of the cache operands' entries
 _BANKS = ('gate', 'up', 'down')
 
 
@@ -131,30 +131,15 @@ class LagunaConfig(BaseConfig):
         """``(attention kind, MLP kind, first index in the attention tree,
         first index in the MLP tree, count)`` of every run of equal
         consecutive layers."""
-        runs: list[list] = []
-        seen = dict.fromkeys((*_GROUPS, 'dense', 'sparse'), 0)
-        for layer, mlp in enumerate(self.mlp_layer_types):
-            attn = self.attn_kind(layer)
-            if runs and runs[-1][:2] == [attn, mlp]:
-                runs[-1][4] += 1
-            else:
-                runs.append([attn, mlp, seen[attn], seen[mlp], 1])
-            seen[attn] += 1
-            seen[mlp] += 1
-        return [tuple(r) for r in runs]
-
-    def layer_kinds(self) -> list[tuple[str, str]]:
-        """``(attention kind, MLP kind)`` of every layer."""
-        return [(attn, mlp) for attn, mlp, _, _ in self.layer_indices()]
+        return common.layer_runs([
+            (self.attn_kind(layer), mlp)
+            for layer, mlp in enumerate(self.mlp_layer_types)
+        ])
 
     def layer_indices(self) -> list[tuple[str, str, int, int]]:
         """``(attention kind, MLP kind, index in the attention tree, index
         in the MLP tree)`` of every layer."""
-        return [
-            (attn, mlp, first_a + i, first_m + i)
-            for attn, mlp, first_a, first_m, count in self.layer_runs()
-            for i in range(count)
-        ]
+        return common.layer_indices(self.layer_runs())
 
     def cache_spec(self) -> common.CacheSpec:
         """Two paged groups: the full layers' blocks hold whole contexts,
@@ -422,22 +407,6 @@ def _mlp_layer_at(params, mlp_kind, mi):
     )
 
 
-def _pools_out(pools) -> tuple:
-    """``(k_cache, v_cache)`` as the programs return them: a tuple a group
-    of one buffer a layer."""
-    return (
-        tuple(tuple(pools[g][0]) for g in _GROUPS),
-        tuple(tuple(pools[g][1]) for g in _GROUPS),
-    )
-
-
-def _run_xs(first_attn, first_mlp, count):
-    return (
-        jnp.arange(first_attn, first_attn + count, dtype=jnp.int32),
-        jnp.arange(first_mlp, first_mlp + count, dtype=jnp.int32),
-    )
-
-
 # ----------------------------------------------------------------- forwards
 def apply(  # distlint: traced
     params: dict,
@@ -476,7 +445,9 @@ def apply(  # distlint: traced
             )
             return x, None
 
-        x, _ = jax.lax.scan(layer, x, _run_xs(first_a, first_m, count))
+        x, _ = jax.lax.scan(
+            layer, x, common.run_indices(first_a, first_m, count)
+        )
     return _norm(x, params['final_ln']['scale'], cfg)
 
 
@@ -509,10 +480,6 @@ def prefill_paged(  # distlint: traced
     s = input_ids.shape[1]
     valid = jnp.arange(s)[None, :] < tail_lens[:, None]
     rope = _rope_tables(cfg, max_table_positions or cfg.max_position_embeddings)
-    tables = dict(zip(_GROUPS, block_tables))
-    pools = {
-        g: [list(k), list(v)] for g, k, v in zip(_GROUPS, k_cache, v_cache)
-    }
     x = common.embed(params, cfg.dtype, input_ids)
 
     def layer(attn_kind, mlp_kind, x, lp, mp, banks, mi, k_buf, v_buf, table,
@@ -536,18 +503,28 @@ def prefill_paged(  # distlint: traced
         )
         return x, k_buf, v_buf
 
-    layer_of = common.once_a_kind(layer, cfg.layer_kinds(), 'laguna_layer')
-    for attn_kind, mlp_kind, ai, mi in cfg.layer_indices():
-        k_pool, v_pool = pools[attn_kind]
-        x, k_pool[ai], v_pool[ai] = layer_of[attn_kind, mlp_kind](
-            x, common.layer_at(params[attn_kind], ai),
-            _mlp_layer_at(params, mlp_kind, mi), params.get('sparse'),
-            jnp.int32(mi), k_pool[ai], v_pool[ai], tables[attn_kind],
-            *rope[attn_kind][:2], positions, valid, context_lens, tail_lens,
-        )
+    x, k_cache, v_cache = common.walk_cache_groups(
+        layer, cfg.layer_indices(), 'laguna_layer', x, k_cache, v_cache,
+        block_tables, functools.partial(_layer_weights, params),
+        lambda kind: (
+            *rope[kind[0]][:2], positions, valid, context_lens, tail_lens,
+        ),
+    )
     hidden = _norm(x, params['final_ln']['scale'], cfg)
     last_hidden = common.last_token(hidden, tail_lens)
-    return logits(params, cfg, last_hidden)[:, 0], *_pools_out(pools)
+    return logits(params, cfg, last_hidden)[:, 0], k_cache, v_cache
+
+
+def _layer_weights(params, kind, ai, mi):
+    """A layer's operands of the serving walk: its attention and MLP
+    parameters, the sparse tree (its banks stay stacked) and the layer's
+    index in it."""
+    attn_kind, mlp_kind = kind
+    return (
+        common.layer_at(params[attn_kind], ai),
+        _mlp_layer_at(params, mlp_kind, mi), params.get('sparse'),
+        jnp.int32(mi),
+    )
 
 
 def _decode_core(
@@ -562,9 +539,6 @@ def _decode_core(
     from distllm_tpu.ops.paged_attention import decode_attention, write_token_kv
 
     x = common.embed(params, cfg.dtype, input_ids)  # [B, H]
-    tables = dict(zip(_GROUPS, block_tables))
-    pools = {g: [list(k), list(v)] for g, k, v in zip(_GROUPS, *caches)}
-    pairs = jnp.zeros((2,), jnp.int32)
 
     def layer(attn_kind, mlp_kind, x, lp, mp, banks, mi, k_buf, v_buf, table,
               cos, sin, positions, context_lens, live):
@@ -585,18 +559,14 @@ def _decode_core(
         )
         return x, k_buf, v_buf, layer_pairs
 
-    layer_of = common.once_a_kind(layer, cfg.layer_kinds(), 'laguna_layer')
-    for attn_kind, mlp_kind, ai, mi in cfg.layer_indices():
-        k_pool, v_pool = pools[attn_kind]
-        x, k_pool[ai], v_pool[ai], layer_pairs = layer_of[attn_kind, mlp_kind](
-            x, common.layer_at(params[attn_kind], ai),
-            _mlp_layer_at(params, mlp_kind, mi), params.get('sparse'),
-            jnp.int32(mi), k_pool[ai], v_pool[ai], tables[attn_kind],
-            *rope[attn_kind][:2], positions, context_lens, live,
-        )
-        pairs = pairs + layer_pairs
+    x, k_cache, v_cache, pairs = common.walk_cache_groups(
+        layer, cfg.layer_indices(), 'laguna_layer', x, *caches, block_tables,
+        functools.partial(_layer_weights, params),
+        lambda kind: (*rope[kind[0]][:2], positions, context_lens, live),
+        counts=jnp.zeros((2,), jnp.int32),
+    )
     hidden = _norm(x, params['final_ln']['scale'], cfg)
-    return logits(params, cfg, hidden), _pools_out(pools), pairs
+    return logits(params, cfg, hidden), (k_cache, v_cache), pairs
 
 
 def decode_loop(  # distlint: traced

@@ -41,6 +41,8 @@ yet, which is why a hybrid model refuses a mesh.
 
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 
@@ -138,6 +140,96 @@ def bank_widths(params) -> tuple[int, int, int, int] | None:
     return None
 
 
+ACTIVATIONS = {'silu': jax.nn.silu, 'relu': jax.nn.relu}
+
+
+class Ranking(NamedTuple):
+    """What the expert matmuls take of the router (``rank_experts``)."""
+
+    weights: jnp.ndarray  # [T, k] float32: the kept gates, scaled
+    local: jnp.ndarray  # [T, k]: a kept expert's index in the held bank
+    is_held: jnp.ndarray  # [T, k] bool: whether that index is in the bank
+    # The grouped form alone (None in the dense form):
+    order: jnp.ndarray | None  # [T * k]: the pairs sorted by held expert
+    group_sizes: jnp.ndarray | None  # int32: rows a group of the matmul
+
+
+def rank_experts(  # distlint: traced
+    x: jnp.ndarray,  # [T, H]: what the ROUTER reads
+    router_kernel: jnp.ndarray,  # [H, E_routed]
+    experts_per_token: int,
+    bank_shape: tuple[int, ...],  # of ``gate``: [(L,) E_held, H, I]
+    first_expert: int = 0,
+    layer=None,
+    routed_scale: float = 1.0,
+    scoring: str = 'softmax',
+    select_bias: jnp.ndarray | None = None,
+    norm_eps: float = 1e-20,
+) -> Ranking:
+    """The ranking as a step of its own: the router's logits, the k kept, the
+    gates, which of them are held in a bank of ``bank_shape`` and, where
+    ``expert_form`` says ``grouped`` for these shapes, the pairs' sort and
+    the group sizes. ``routed_experts`` makes it itself, inside its scope,
+    unless it is handed one (``ranking=``): a family whose router reads
+    another tensor than its experts calls this where that tensor is, under
+    a scope of its own, with the arguments it gives ``routed_experts``."""
+    form = expert_form(
+        x.shape[0], experts_per_token, bank_shape[-3],
+        router_kernel.shape[-1], *bank_shape[-2:],
+    )
+    return _rank(
+        x, router_kernel, experts_per_token, bank_shape, first_expert, layer,
+        routed_scale, scoring, select_bias, norm_eps, form,
+    )
+
+
+def _rank(
+    x, router_kernel, k, bank_shape, first_expert, layer, routed_scale,
+    scoring, select_bias, norm_eps, form,
+) -> Ranking:
+    if scoring not in ('softmax', 'sigmoid'):
+        raise ValueError(f'scoring must be softmax or sigmoid, got {scoring!r}')
+    if scoring == 'softmax' and select_bias is not None:
+        raise ValueError('a selection bias is implemented for sigmoid scoring')
+    held = bank_shape[-3]
+    logits = jnp.einsum(
+        'th,he->te', x.astype(jnp.float32),
+        router_kernel.astype(jnp.float32),
+    )
+    if scoring == 'softmax':
+        top_logits, top_idx = jax.lax.top_k(logits, k)
+        weights = jax.nn.softmax(top_logits, axis=-1)  # [T, k] float32
+    else:
+        scores = jax.nn.sigmoid(logits)
+        chosen_by = scores if select_bias is None else (
+            scores + select_bias.astype(jnp.float32)
+        )
+        _, top_idx = jax.lax.top_k(chosen_by, k)
+        kept = jnp.take_along_axis(scores, top_idx, axis=-1)
+        weights = kept / (kept.sum(axis=-1, keepdims=True) + norm_eps)
+    if routed_scale != 1.0:
+        weights = weights * routed_scale
+    local = top_idx - first_expert
+    is_held = (local >= 0) & (local < held)
+    if form == 'dense':
+        return Ranking(weights, local, is_held, None, None)
+    # Pairs sorted by held expert; pairs of absent experts go last,
+    # past the end of the last group, where the matmul computes nothing.
+    group = jnp.where(is_held, local, held).reshape(-1)
+    order = jnp.argsort(group, stable=True)
+    group_sizes = jnp.bincount(group, length=held + 1)[:held].astype(
+        jnp.int32
+    )
+    if layer is not None and grouped_backend() == 'xla':
+        # ``ragged_dot`` takes every group of the stack: the other layers'
+        # are empty (the kernel adds the layer to its bank index instead).
+        group_sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((bank_shape[0] * held,), jnp.int32), group_sizes,
+            (layer * held,),
+        )
+    return Ranking(weights, local, is_held, order, group_sizes)
+
+
 def routed_experts(  # distlint: traced
     x: jnp.ndarray,  # [T, H]
     router_kernel: jnp.ndarray,  # [H, E_routed]
@@ -152,6 +244,8 @@ def routed_experts(  # distlint: traced
     scoring: str = 'softmax',
     select_bias: jnp.ndarray | None = None,  # [E_routed] float32
     norm_eps: float = 1e-20,
+    activation: str = 'silu',
+    ranking: 'Ranking | None' = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """``sum_e g_e expert_e(x)`` over the held experts among a token's top-k.
 
@@ -177,48 +271,47 @@ def routed_experts(  # distlint: traced
     published normaliser: 1e-20 for DeepSeek-V3, the default, 1e-6 for
     ``lfm2_moe``). ``'softmax'`` with no bias is softmax over the k kept
     logits, as it was.
+
+    ``activation`` (``'silu'`` | ``'relu'``, static) is the non-linearity on
+    an expert's gate product, in all three forms. ``ranking`` is
+    ``rank_experts``' result for these banks where the caller ranked ahead
+    (a router that reads another tensor than the experts do:
+    ``models/smallthinker.py``); the router's operands are then not read
+    here (``router_kernel`` may be None) and the form is the one the ranking
+    was made for. Without it the ranking is made here, from ``x``.
     """
-    if scoring not in ('softmax', 'sigmoid'):
-        raise ValueError(f'scoring must be softmax or sigmoid, got {scoring!r}')
-    if scoring == 'softmax' and select_bias is not None:
-        raise ValueError('a selection bias is implemented for sigmoid scoring')
+    if activation not in ACTIVATIONS:
+        raise ValueError(
+            f'activation must be one of {sorted(ACTIVATIONS)}, got {activation!r}'
+        )
     dtype = x.dtype
     tokens, k = x.shape[0], experts_per_token
     gate, up, down = _bank(gate, dtype), _bank(up, dtype), _bank(down, dtype)
-    held = gate.shape[-3]
-    form = expert_form(
-        tokens, k, held, router_kernel.shape[-1], *gate.shape[-2:]
-    )
+    bank_shape = gate.shape
+    if ranking is None:
+        form = expert_form(
+            tokens, k, bank_shape[-3], router_kernel.shape[-1],
+            *bank_shape[-2:],
+        )
+    else:  # the form the ranking was made for
+        form = 'dense' if ranking.order is None else 'grouped'
     if layer is not None and form == 'grouped':
         gate, up, down = (
             w.reshape(-1, *w.shape[2:]) for w in (gate, up, down)
         )
     with jax.named_scope('distllm.moe'):
-        logits = jnp.einsum(
-            'th,he->te', x.astype(jnp.float32),
-            router_kernel.astype(jnp.float32),
-        )
-        if scoring == 'softmax':
-            top_logits, top_idx = jax.lax.top_k(logits, k)
-            weights = jax.nn.softmax(top_logits, axis=-1)  # [T, k] float32
-        else:
-            scores = jax.nn.sigmoid(logits)
-            chosen_by = scores if select_bias is None else (
-                scores + select_bias.astype(jnp.float32)
+        if ranking is None:
+            ranking = _rank(
+                x, router_kernel, k, bank_shape, first_expert, layer,
+                routed_scale, scoring, select_bias, norm_eps, form,
             )
-            _, top_idx = jax.lax.top_k(chosen_by, k)
-            kept = jnp.take_along_axis(scores, top_idx, axis=-1)
-            weights = kept / (kept.sum(axis=-1, keepdims=True) + norm_eps)
-        if routed_scale != 1.0:
-            weights = weights * routed_scale
-        local = top_idx - first_expert
-        is_held = (local >= 0) & (local < held)
+        weights, local, is_held = ranking[:3]
         if form == 'dense':
-            out = _dense(x, gate, up, down, local, weights, layer)
+            out = _dense(x, gate, up, down, local, weights, layer, activation)
         else:
             out = _grouped(
-                x, gate, up, down, local, is_held, weights, held, layer,
-                grouped_tiles(tokens, k, *gate.shape[-2:]),
+                x, gate, up, down, ranking,
+                grouped_tiles(tokens, k, *gate.shape[-2:]), layer, activation,
             )
         rows_counted = (
             jnp.ones((tokens,), bool) if counted is None else counted
@@ -230,26 +323,16 @@ def routed_experts(  # distlint: traced
     return out.astype(dtype), pairs
 
 
-def _grouped(x, gate, up, down, local, is_held, weights, held, layer, tiles):
+def _grouped(x, gate, up, down, ranking, tiles, layer, activation):
     """The held pairs sorted by expert through the grouped matmul, each
     times its gate and a token's k added up (``combine``): float32 ``[T,
     H]``. With ``layer`` the banks are the stack's ``L * E_held`` groups and
     the layer's experts are groups ``layer * E_held`` onward: the kernel
     (``tiles``) adds the layer to its bank index, ``ragged_dot`` (no tiles)
-    takes every group, the other layers' empty."""
-    tokens, k = local.shape
-    # Pairs sorted by held expert; pairs of absent experts go last,
-    # past the end of the last group, where the matmul computes nothing.
-    group = jnp.where(is_held, local, held).reshape(-1)
-    order = jnp.argsort(group, stable=True)
-    group_sizes = jnp.bincount(group, length=held + 1)[:held].astype(
-        jnp.int32
-    )
-    if layer is not None and tiles is None:
-        group_sizes = jax.lax.dynamic_update_slice(
-            jnp.zeros((gate.shape[0],), jnp.int32), group_sizes,
-            (layer * held,),
-        )
+    takes every group, the other layers' empty (``_rank`` made the sizes
+    so)."""
+    weights, _, is_held, order, group_sizes = ranking
+    tokens, k = is_held.shape
     # Rows in whole tiles: the kernel's row tile, or sublane tiles of 8 (the
     # TPU's ragged_dot is refused by the compiler for other counts: 12, 20,
     # 30 rows over 324 groups). The pad rows lie past the last group: never
@@ -257,7 +340,7 @@ def _grouped(x, gate, up, down, local, is_held, weights, held, layer, tiles):
     whole = 8 if tiles is None else tiles[0]
     rows = x[jnp.pad(order // k, (0, -tokens * k % whole))]  # [T*k (+pad), H]
     if tiles is None:
-        hidden = jax.nn.silu(
+        hidden = ACTIVATIONS[activation](
             jax.lax.ragged_dot(rows, gate, group_sizes)
         ) * jax.lax.ragged_dot(rows, up, group_sizes)
         out = jax.lax.ragged_dot(hidden, down, group_sizes)
@@ -265,6 +348,7 @@ def _grouped(x, gate, up, down, local, is_held, weights, held, layer, tiles):
         out = grouped_matmul.expert_matmuls(
             rows, gate, up, down, group_sizes, 0 if layer is None else layer,
             tiles=tiles, interpret=grouped_backend() == 'interpret',
+            activation=activation,
         )
     # A pair's row of the sorted order, -1 where its expert is held
     # elsewhere: that row is never computed, and never read.
@@ -288,7 +372,7 @@ def combine(rows, place, weights):
     )
 
 
-def _dense(x, gate, up, down, local, weights, layer):
+def _dense(x, gate, up, down, local, weights, layer, activation):
     """Every row through every held expert, one batched ``dot`` a bank:
     float32 ``[T, H]``. With ``layer`` the banks are ``[L, E_held, ...]``
     and the layer is an index into the ``dot``'s operand, never a copy."""
@@ -307,8 +391,8 @@ def _dense(x, gate, up, down, local, weights, layer):
         ),
         axis=1,
     )
-    hidden = jax.nn.silu(jnp.einsum('th,ehi->eti', x, gate)) * jnp.einsum(
-        'th,ehi->eti', x, up
-    )
+    hidden = ACTIVATIONS[activation](
+        jnp.einsum('th,ehi->eti', x, gate)
+    ) * jnp.einsum('th,ehi->eti', x, up)
     out = jnp.einsum('eti,eih->eth', hidden, down)
     return jnp.einsum('eth,te->th', out.astype(jnp.float32), w)

@@ -3,9 +3,10 @@
 ``expert_matmuls`` is what ``models/moe.py``'s grouped form runs on a TPU
 where ``jax.lax.ragged_dot`` stood three times: rows sorted by expert are
 multiplied by their own expert's ``gate`` and ``up`` kernels in one pass
-(``silu(g) * u``), then by its ``down`` kernel. XLA's grouped matmul behind
-``ragged_dot`` ran at a fifth of the chip's bf16 peak on these shapes
-(``PERF.md`` section 6, PR 42), and was handed two things it need not be:
+(``silu(g) * u``, or ``relu(g) * u``), then by its ``down`` kernel. XLA's
+grouped matmul behind ``ragged_dot`` ran at a fifth of the chip's bf16 peak
+on these shapes (``PERF.md`` section 6, PR 42), and was handed two things it
+need not be:
 
 * the WHOLE layer stack as ``L x E_held`` groups, all but one layer's
   empty, so that the bank is never sliced. Here the bank is that stack
@@ -123,12 +124,14 @@ def _schedule(group_sizes, rows: int, row_tile: int):
 
 
 def _grouped_matmul_kernel(
-    first_group, ends, group_of, tile_of, rows, *refs, row_tile, gated
+    first_group, ends, group_of, tile_of, rows, *refs, row_tile, gated,
+    activation='silu',
 ):
     """One (column tile, step) of the grid: the step's row tile times its
     group's bank tile, stored into the rows the group owns. ``gated`` (two
-    banks: gate, up) stores ``silu(g) * u`` with ``g`` and ``u`` rounded to
-    the rows' dtype first, as two ``ragged_dot`` results were."""
+    banks: gate, up) stores ``act(g) * u`` (``activation``: ``'silu'`` or
+    ``'relu'``) with ``g`` and ``u`` rounded to the rows' dtype first, as
+    two ``ragged_dot`` results were."""
     import jax.experimental.pallas as pl
 
     del first_group
@@ -149,14 +152,18 @@ def _grouped_matmul_kernel(
     ]
     if gated:
         g, u = (p.astype(jnp.float32) for p in products)
-        result = (g * jax.nn.sigmoid(g) * u).astype(out.dtype)
+        if activation == 'relu':
+            result = (jnp.maximum(g, 0.0) * u).astype(out.dtype)
+        else:
+            result = (g * jax.nn.sigmoid(g) * u).astype(out.dtype)
     else:
         (result,) = products
     out[...] = jnp.where(owned, result, out[...])
 
 
 def _grouped_matmul(
-    rows, banks, schedule, first_group, row_tile, columns, interpret
+    rows, banks, schedule, first_group, row_tile, columns, interpret,
+    activation='silu',
 ):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -178,7 +185,8 @@ def _grouped_matmul(
     )
     return pl.pallas_call(
         functools.partial(
-            _grouped_matmul_kernel, row_tile=row_tile, gated=len(banks) == 2
+            _grouped_matmul_kernel, row_tile=row_tile, gated=len(banks) == 2,
+            activation=activation,
         ),
         out_shape=jax.ShapeDtypeStruct(
             (rows.shape[0], out_columns), rows.dtype
@@ -213,7 +221,9 @@ def _grouped_matmul(
     )(first_group, ends, group_of, tile_of, rows, *banks)
 
 
-@functools.partial(jax.jit, static_argnames=('tiles', 'interpret'))
+@functools.partial(
+    jax.jit, static_argnames=('tiles', 'interpret', 'activation')
+)
 def expert_matmuls(  # distlint: traced
     rows: jnp.ndarray,  # [M, K], sorted by group; M in whole row tiles
     gate: jnp.ndarray,  # [G, K, N]: E_held groups, or a stack's L x E_held
@@ -224,19 +234,20 @@ def expert_matmuls(  # distlint: traced
     *,
     tiles: tuple[int, int, int],
     interpret: bool = False,
+    activation: str = 'silu',
 ) -> jnp.ndarray:
-    """``(silu(rows @ gate_e) * (rows @ up_e)) @ down_e`` for the rows of
-    each group ``e``: ``[M, K]`` in the rows' dtype, rows past the last
-    group unwritten. One ``jax.jit`` with the tiles static and the layer
-    an operand: a program that calls it from 22 layers traces and lowers
-    it once."""
+    """``(act(rows @ gate_e) * (rows @ up_e)) @ down_e`` for the rows of
+    each group ``e`` (``activation``: ``'silu'`` or ``'relu'``): ``[M, K]``
+    in the rows' dtype, rows past the last group unwritten. One ``jax.jit``
+    with the tiles static and the layer an operand: a program that calls it
+    from 22 layers traces and lowers it once."""
     row_tile, up_columns, down_columns = tiles
     held = group_sizes.shape[0]
     schedule = _schedule(group_sizes, rows.shape[0], row_tile)
     first_group = (jnp.asarray(layer, jnp.int32) * held).reshape(1)
     hidden = _grouped_matmul(
         rows, (gate, up), schedule, first_group, row_tile, up_columns,
-        interpret,
+        interpret, activation,
     )
     return _grouped_matmul(
         hidden, (down,), schedule, first_group, row_tile, down_columns,

@@ -1369,16 +1369,17 @@ def _chunk_prefill_text(v5e, family, request) -> str:
         pools = v5e(pool, jnp.bfloat16)
         operands = (pools, pools, v5e((4, 528), i32), *rows, state,
                     v5e((4,), i32))
-    elif family == 'laguna':
-        module, cfg, params, pools, _ = request.getfixturevalue('laguna_cell')
-        operands = (pools, pools, (v5e((4, 528), i32),) * 2, *rows)
+    elif family in ('laguna', 'smallthinker'):
+        module, cfg, params, pools, _ = request.getfixturevalue(f'{family}_cell')
+        width = 528 if family == 'laguna' else 1024
+        operands = (pools, pools, (v5e((4, width), i32),) * 2, *rows)
     else:
         module, cfg, params, planes, _, _ = request.getfixturevalue(
             'kanana_cell'
         )
         operands = (planes, (), v5e((4, 528), i32), *rows)
     if family != 'granite':
-        kw['max_table_positions'] = 8448
+        kw['max_table_positions'] = 16384 if family == 'smallthinker' else 8448
     return jax.jit(
         lambda p, ids, pos, k, v, bt, ctx, tails, *state:
             module.prefill_paged(
@@ -1387,7 +1388,9 @@ def _chunk_prefill_text(v5e, family, request) -> str:
     ).lower(params, *spans, *operands).as_text()
 
 
-@pytest.mark.parametrize('family', ['granite', 'laguna', 'kanana', 'lfm2'])
+@pytest.mark.parametrize(
+    'family', ['granite', 'laguna', 'kanana', 'lfm2', 'smallthinker']
+)
 def test_chunk_prefill_keeps_the_grouped_matmul(
     v5e, family, request, monkeypatch
 ):
@@ -2019,7 +2022,7 @@ def _sliced(what: str):
 
 
 @pytest.mark.parametrize('family', [
-    'kanana', 'laguna', 'ouro', 'solar_open2', 'mistral',
+    'kanana', 'laguna', 'ouro', 'solar_open2', 'mistral', 'smallthinker',
     pytest.param('lfm2', marks=_sliced(
         'two multi-output fusions at the cut\'s two attention layers, 2 x '
         'bf16[1,2048,2048] (8 MB each) and 2 x bf16[1,2048,512] (2 MB each), '
@@ -2084,3 +2087,113 @@ def test_full_depth_kanana_window_slices_no_weight(v5e, kanana_cell, form):
         return
     assert sorted(len(held) for _, held in found) == [5, 5, 5, 19, 19, 19]
     assert sum(len(held) for _, held in found) == 3 * 24
+
+
+# ---- smallthinker (PR 52): two cache groups at a window of 4096, 7 queries
+# a KV head, the ranking ahead of attention ----
+
+@pytest.mark.parametrize('rows, span', [(48, 1), (4, 512)], ids=['walk', 'span512'])
+@pytest.mark.parametrize('window', [4096, None], ids=['win4096', 'nowin'])
+def test_ragged_kernel_compiles_at_7_queries_a_head(v5e, rows, span, window):
+    """28 query heads on 4 KV heads of 128 over the cell's window pool and
+    a table to 16k tokens: the decode walk takes the stacked block over 28
+    query rows (not a whole number of 8-row sublane tiles), the span
+    schedule a tile of 64 positions x 7."""
+    from distllm_tpu.ops.paged_attention import ragged_paged_attention_pallas
+
+    pool = v5e((12509, 16, 4 * 128), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda q, k, v, bt, ctx, pos, ql: ragged_paged_attention_pallas(
+            q, k, v, bt, ctx, pos, q_lens=ql, sliding_window=window
+        )
+    ).lower(
+        v5e((rows, span, 28, 128), jnp.bfloat16), pool, pool,
+        v5e((rows, 1024), jnp.int32), v5e((rows,), jnp.int32),
+        v5e((rows, span), jnp.int32), v5e((rows,), jnp.int32),
+    ).compile()
+    _assert_kernel_compiled(compiled)
+    assert _kernel_schedules(compiled) == ['walk' if span == 1 else 'grid']
+
+
+@pytest.fixture(scope='module')
+def smallthinker_cell(v5e):
+    """The smallthinker cell's configuration at its own depth (16 layers:
+    4 full, 12 window), the parameters and the pools at the cell's sizes:
+    22000 blocks and the engine's own 12509."""
+    import json
+    from pathlib import Path
+
+    from distllm_tpu.models import smallthinker
+
+    root = Path(__file__).resolve().parents[1]
+    hf = json.loads(
+        (root / 'benchmarks/configs/smallthinker-21b-a3b.json').read_text()
+    )
+    cfg = smallthinker.SmallThinkerConfig.from_hf_config(hf)
+    shapes = jax.eval_shape(
+        lambda: smallthinker.serving_params(
+            smallthinker.init_on_device(jax.random.PRNGKey(0), cfg)
+        )
+    )  # the tree the engine serves from: q, k and v a layer an array
+    params = jax.tree.map(lambda a: v5e(a.shape, a.dtype), shapes)
+    rows = hf['engine']['max_num_seqs']
+    buffers = [
+        (blocks, 16, cfg.num_kv_heads * cfg.head_dim)
+        for blocks in (hf['engine']['num_blocks'], 1 + rows * 258 + 4 * 31)
+    ]
+    pools = tuple(
+        (v5e(shape, jnp.bfloat16),) * cfg.count(kind)
+        for kind, shape in zip(('full', 'window'), buffers)
+    )
+    return smallthinker, cfg, params, pools, buffers
+
+
+@pytest.fixture(scope='module')
+def smallthinker_window(v5e, smallthinker_cell):
+    """The decode window at the cell's 48 rows and depth, compiled once."""
+    smallthinker, cfg, params, pools, buffers = smallthinker_cell
+    b, i32, f32 = 48, jnp.int32, jnp.float32
+
+    def window_fn(params, ids, pos, ctx, k, v, bt, steps_left, *sampling):
+        return smallthinker.decode_loop(
+            params, cfg, ids, pos, k, v, bt, ctx, steps_left, *sampling,
+            num_steps=8, attn_backend='pallas', max_table_positions=16384,
+        )
+
+    return jax.jit(window_fn, donate_argnums=(4, 5)).lower(
+        params, v5e((b,), i32), v5e((b,), i32), v5e((b,), i32), pools, pools,
+        (v5e((b, 1024), i32),) * 2, v5e((b,), i32), v5e((b,), f32),
+        v5e((b,), f32), v5e((b,), f32), v5e((b,), i32), v5e((b,), jnp.uint32),
+    ).compile()
+
+
+def test_smallthinker_decode_window_reads_the_pools_as_they_lie(
+    smallthinker_cell, smallthinker_window
+):
+    """No pool-sized result but the scatters, every kernel call the row
+    walk, and the programs fit the chip beside weights and pools."""
+    _assert_pools_go_to_the_kernel_as_they_lie(
+        smallthinker_window, smallthinker_cell[4]
+    )
+    _assert_decode_calls_walk(smallthinker_window)
+    memory = smallthinker_window.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14.5 * 2**30
+
+
+def test_smallthinker_chunk_prefill_reads_the_pools_as_they_lie(v5e, smallthinker_cell):
+    """The ``(512, 4)`` program at the cell's depth: four rows of a
+    512-token span (``test_chunk_prefill_keeps_the_grouped_matmul`` holds
+    its experts to the grouped kernel)."""
+    smallthinker, cfg, params, pools, buffers = smallthinker_cell
+    i32 = jnp.int32
+    compiled = jax.jit(
+        lambda params, ids, pos, k, v, bt, ctx, tails: smallthinker.prefill_paged(
+            params, cfg, ids, pos, k, v, bt, ctx, tails,
+            max_table_positions=16384, attn_backend='pallas',
+        ), donate_argnums=(3, 4),
+    ).lower(
+        params, v5e((4, 512), i32), v5e((4, 512), i32), pools, pools,
+        (v5e((4, 1024), i32),) * 2, v5e((4,), i32), v5e((4,), i32),
+    ).compile()
+    _assert_pools_go_to_the_kernel_as_they_lie(compiled, buffers)
+    _assert_span_calls_keep_the_grid(compiled)

@@ -15,6 +15,7 @@ import lfm2_toy
 import numpy as np
 import ouro_toy
 import pytest
+import smallthinker_toy
 import solar_open2_toy
 import test_state_pool_granite as granite_toy
 
@@ -44,11 +45,14 @@ _PARENT_BITS = {
     'solar_open2': 'da55a43a7e7f04fc0fc8014976cbdee6c6b7d2fc4ab6e8fe199087e2fdd52fef',
     # likewise (PR 48): ``mistral.init_on_device``'s tree and the exit gate
     'ouro': '36c7c9240d95edc82dbf050dad7277afc3eff4524b7aaed06783d1a13fbe8fd3',
+    # likewise (PR 52): two attention trees and the expert tree of every layer
+    'smallthinker': '0bf2f299e74957cf2880c00ce10b7d639da3f6878242ae2e8c5a26fafe22a4fa',
 }
 _TOYS = {
     'granite': granite_toy, 'laguna': laguna_toy, 'deepseek_v3': deepseek_toy,
     'lfm2': lfm2_toy, 'falcon_h1': falcon_h1_toy,
     'solar_open2': solar_open2_toy, 'ouro': ouro_toy,
+    'smallthinker': smallthinker_toy,
 }
 
 

@@ -284,3 +284,114 @@ def test_engine_without_routed_experts_says_nothing():
         assert not any('moe_form' in r for r in records)
     finally:
         engine.shutdown()
+
+
+# ---- the activation and the ranking as a step of its own (PR 52) ----
+def _loop_over_experts(data, k, first_expert, activation, layer=None):
+    """A plain loop over the held experts in float64: a token's k largest
+    router logits, softmax over them, each held expert's
+    ``(act(x G) * (x U)) D`` times its gate."""
+    act = {
+        'relu': lambda g: np.maximum(g, 0.0),
+        'silu': lambda g: g / (1.0 + np.exp(-g)),
+    }[activation]
+    x = np.asarray(data['x'], np.float64)
+    logits = x @ np.asarray(data['router'], np.float64)
+    kept = np.argsort(-logits, axis=-1)[:, :k]
+    top = np.take_along_axis(logits, kept, -1)
+    gates = np.exp(top - top.max(-1, keepdims=True))
+    gates /= gates.sum(-1, keepdims=True)
+    banks = [np.asarray(data[n], np.float64) for n in ('gate', 'up', 'down')]
+    if layer is not None:
+        banks = [b[layer] for b in banks]
+    else:
+        banks = [b[0] for b in banks]
+    out = np.zeros_like(x)
+    for e in range(HELD):
+        g_e = np.where(kept == first_expert + e, gates, 0.0).sum(-1)
+        y = (act(x @ banks[0][e]) * (x @ banks[1][e])) @ banks[2][e]
+        out += g_e[:, None] * y
+    return out
+
+
+@pytest.mark.parametrize('activation', ['relu', 'silu'])
+@pytest.mark.parametrize('form, backend, layer', [
+    ('dense', 'xla', None), ('dense', 'xla', 2), ('grouped', 'xla', None),
+    ('grouped', 'xla', 1), ('grouped', 'interpret', None),
+    ('grouped', 'interpret', 2),
+], ids=['dense', 'dense-layer', 'ragged_dot', 'ragged_dot-layer', 'kernel',
+        'kernel-layer'])
+def test_activation_in_every_form_is_the_loop_over_experts(
+    monkeypatch, activation, form, backend, layer
+):
+    """``activation`` reaches the dense einsum, ``ragged_dot``'s twin and
+    the kernel's epilogue (the Pallas interpreter)."""
+    data = _inputs(jnp.float32, 24)
+    monkeypatch.setattr(moe, 'expert_form', lambda *shape: form)
+    monkeypatch.setattr(moe, 'grouped_backend', lambda: backend)
+    banks = [data[n] if layer is not None else data[n][0] for n in ('gate', 'up', 'down')]
+    out, pairs = moe.routed_experts(
+        data['x'], data['router'], *banks, 3, first_expert=4, layer=layer,
+        activation=activation,
+    )
+    want = _loop_over_experts(data, 3, 4, activation, layer)
+    np.testing.assert_allclose(np.asarray(out), want, atol=2e-5, rtol=2e-5)
+    assert int(pairs[0]) == 24 * 3
+    other = _loop_over_experts(
+        data, 3, 4, 'silu' if activation == 'relu' else 'relu', layer
+    )
+    assert np.abs(np.asarray(out) - other).max() > 0.05  # and not the other
+
+
+def test_an_unknown_activation_is_refused_by_name():
+    data = _inputs(jnp.float32, 8)
+    with pytest.raises(ValueError, match="activation must be one of.*'gelu'"):
+        moe.routed_experts(
+            data['x'], data['router'], data['gate'][0], data['up'][0],
+            data['down'][0], 2, activation='gelu',
+        )
+
+
+@pytest.mark.parametrize('form', ['dense', 'grouped'])
+@pytest.mark.parametrize(
+    'tokens, kw, layer', [c[1:] for c in CASES], ids=[c[0] for c in CASES]
+)
+def test_ranking_alone_is_what_routed_experts_made_inline(
+    monkeypatch, form, tokens, kw, layer
+):
+    """``rank_experts`` on the same input, handed back as ``ranking=``, gives
+    the bits ``routed_experts`` gives when it ranks itself (the result the
+    function had inline before the ranking was taken apart: the table of
+    ``test_dense_form_is_the_grouped_form`` holds that one to the other
+    form); handed a ranking made from ANOTHER tensor it follows that one."""
+    kw = dict(kw)
+    data = _inputs(jnp.float32, tokens)
+    k = 2 if kw.get('first_expert') else 3
+    if kw.pop('bias', False):
+        kw['select_bias'] = data['bias']
+    if kw.pop('counted', False):
+        kw['counted'] = data['counted']
+    banks = [data[n][0] for n in ('gate', 'up', 'down')]
+    if layer is not None:
+        banks = [data[n] for n in ('gate', 'up', 'down')]
+        kw['layer'] = layer[1] if layer[0] == 'static' else jnp.int32(layer[1])
+    monkeypatch.setattr(moe, 'expert_form', lambda *shape: form)
+    inline = moe.routed_experts(data['x'], data['router'], *banks, k, **kw)
+    rank_kw = {n: v for n, v in kw.items() if n != 'counted'}
+    ranking = moe.rank_experts(
+        data['x'], data['router'], k, banks[0].shape, **rank_kw
+    )
+    assert (ranking.order is None) == (form == 'dense')
+    handed = moe.routed_experts(
+        data['x'], None, *banks, k, ranking=ranking, **kw
+    )
+    for got, want in zip(handed, inline):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # Ranked from another tensor, the experts still read ``x``.
+    other = moe.rank_experts(
+        data['x'][::-1], data['router'], k, banks[0].shape, **rank_kw
+    )
+    elsewhere = moe.routed_experts(
+        data['x'], None, *banks, k, ranking=other, **kw
+    )
+    assert np.abs(np.asarray(elsewhere[0]) - np.asarray(inline[0])).max() > 1e-3

@@ -318,6 +318,47 @@ def test_row_walk_parity_by_queries_a_head(rng, nh, nkv, hd, setup):
     _assert_walk_parity(out, ref, q_lens)
 
 
+@pytest.mark.parametrize('span', [1, 512], ids=['span1', 'span512'])
+@pytest.mark.parametrize('window', [4096, None], ids=['win4096', 'nowin'])
+def test_parity_at_7_queries_a_head_around_a_window_of_4096(rng, span, window):
+    """SmallThinker's attention: 28 query heads on 4 KV heads of 128, blocks
+    of 16, a window of 4096 (and none: the full
+    layers). Rows that stay under the window, that cross it (a decode row
+    at its very edge; a 512-token span that starts under it and ends past
+    it) and that lie well past it, a row's 7 query rows a head never a
+    whole sublane tile: the walk's stacked block over 28 rows at span 1, the
+    span schedule's tile of 64 positions x 7 at 512."""
+    nh, nkv, hd, block = 28, 4, 128, 16
+    contexts = (1000, 4096, 4097, 4300, 9000) if span == 1 else (600, 4300, 5100)
+    tables = [-(-c // block) for c in contexts]
+    num_blocks = 1 + sum(tables)
+    k, v = (
+        jnp.asarray(rng.normal(size=(num_blocks, block, nkv * hd)), jnp.float32)
+        for _ in range(2)
+    )
+    bt = np.zeros((len(contexts), max(tables)), np.int32)
+    ids = rng.permutation(num_blocks - 1) + 1
+    for row, n in enumerate(tables):
+        bt[row, :n], ids = ids[:n], ids[n:]
+    ctx = jnp.asarray(contexts, jnp.int32)
+    pos = ctx[:, None] - span + jnp.arange(span)[None]
+    q = jnp.asarray(rng.normal(size=(len(contexts), span, nh, hd)), jnp.float32)
+    q_lens = jnp.full((len(contexts),), span, jnp.int32)
+    args = (q, k, v, jnp.asarray(bt), ctx, pos)
+    ref = ragged_paged_attention_xla(
+        *args, q_lens=q_lens, sliding_window=window
+    )
+    out = ragged_paged_attention_pallas(
+        *args, q_lens=q_lens, sliding_window=window, interpret=True
+    )
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(ref), atol=2e-5, rtol=1e-4
+    )
+    if window:  # and the window is read: without it the past rows differ
+        full = ragged_paged_attention_xla(*args, q_lens=q_lens)
+        assert np.abs(np.asarray(full) - np.asarray(ref))[-1].max() > 1e-3
+
+
 def _kernel_call(span):
     """The ``pallas_call`` equation of a traced call at ``span``."""
     q, k, v, bt, ctx, _, _ = _walk_setup(np.random.default_rng(0))
