@@ -11,6 +11,7 @@ import functools
 import jax
 import numpy as np
 
+from benchmarks import reference_falcon_h1 as ref
 from distllm_tpu.generate.engine.engine import EngineConfig, LLMEngine
 from distllm_tpu.models import falcon_h1
 import lfm2_toy
@@ -93,7 +94,64 @@ def paged_logits(cfg, params, rows, **kw):
 def reference_logits(params, hf, tokens, first):
     """The plain reference's logits at positions ``first`` onward of one
     row ``tokens``."""
-    from benchmarks import reference_falcon_h1 as ref
-
     at = np.arange(first, len(tokens))[None]
     return ref.falcon_h1_logits(params, hf, np.asarray(tokens)[None], at)[0]
+
+
+# ------------------------------------------ the row of the engine's contract
+def token_gap(params, hf, ids, at, out):
+    return ref.token_gaps(ref.falcon_h1_logits(params, hf, ids, at), [out]).max()
+
+
+def _after_greedy(engine, params, records, lengths, backend):
+    # Every one of the 3 layers holds pages AND both kinds of state.
+    pool = engine.telemetry['state_pool']
+    assert pool['slots'] == 4 and pool['bytes_per_slot'] == 3 * (3 * 88 + 4 * 6 * 16) * 4
+    assert sorted((leaf['count'], leaf['shape']) for leaf in pool['leaves']) == [
+        (3, [3, 88]), (3, [4, 6, 16]),
+    ]
+    assert engine.telemetry['kv_pools']['kv']['block_shape'] == [BLOCK, 8]
+    assert engine.telemetry['kv_pools']['kv']['layers'] == 3
+    (request,) = [r for r in records if r['kind'] == 'request']
+    assert {'state_slot', 'kv_first_block', 'kv_tail_block'} <= set(request)
+    windows = [r for r in records if r['kind'] == 'decode']
+    assert windows and all({'kv_blocks', 'state_rows'} <= set(r) for r in windows)
+    assert all('moe_pairs' not in r for r in windows)
+    # one live row: a step of it reads and writes its slot once
+    assert sum(r['state_rows'] for r in windows) == sum(r['tokens'] for r in windows) == 6
+
+
+def _check_left(engine, hf, params, fed, record):
+    """In the first layer, and something in the last."""
+    _, held = ref.forward(params, hf, np.asarray(fed)[None], [[0]])
+    want_ssm, want_conv, want_k, want_v = held[0]
+    state, slot = engine.state_pool.state, record['state_slot']
+    assert ref.content_error(state['ssm'][0][slot], want_ssm) < 1e-5
+    assert ref.content_error(state['conv'][0][slot], want_conv) < 1e-5
+    assert np.asarray(state['ssm'][2][slot]).any()
+    lfm2_toy.check_pages(engine, record, want_k, want_v, len(fed))
+
+
+def _check_sampled(engine, records):
+    windows = [r for r in records if r['kind'] == 'decode']
+    # every decoded token is one live row of one step
+    assert sum(r['state_rows'] for r in windows) == sum(r['tokens'] for r in windows)
+
+
+ENGINE_CASES = dict(
+    refusal='cannot serve a hybrid',
+    # a layer that holds pages AND state changes none of it
+    refused=('enable_prefix_cache', 'enable_mixed_batching', 'draft_k',
+             'kv_cache_dtype=int8', 'quantization'),
+    greedy=[(n, (n,), 'xla') for n in (1, 2, 3, 8, 20)],
+    after_greedy=_after_greedy,
+    left=dict(seed=3, lengths=(6, 19, 11), max_tokens=13, check=_check_left),
+    turnover=True,
+    reuse=(1, 2, 4, 13),  # fewer tokens than taps, one span, and chunks
+    preempt=dict(seed=3, n=12, num_blocks=11),
+    sampled=dict(
+        seed=4, lengths=(9, 30, 3),
+        sampling=dict(temperature=0.7, top_p=0.9, max_tokens=9), check=_check_sampled,
+    ),
+    warm_prompt=10,
+)

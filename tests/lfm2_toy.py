@@ -5,11 +5,18 @@ state, rows of unequal tails in one dispatch, then decode steps) so that its
 LOGITS can be held against the plain reference."""
 
 import functools
+import io
+import json
+import runpy
+import sys
+from contextlib import redirect_stdout
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from benchmarks import reference_lfm2 as ref
 from distllm_tpu.generate.engine.engine import EngineConfig, LLMEngine
 from distllm_tpu.models import lfm2
 
@@ -152,3 +159,105 @@ def paged_logits(cfg, params, rows, *, chunk=8, backend='xla', stale=None,
         for i in np.flatnonzero(live):
             out[i].append(np.asarray(logits[i]))
     return [np.stack(o) for o in out], (k, v, state)
+
+
+ROOT = Path(__file__).resolve().parent.parent
+
+
+@functools.cache
+def load_probe(script) -> dict:
+    """The globals of ``scripts/<script>``: the wrong programs a cell's
+    limits have to catch (its arms) and its ``check``."""
+    sys.path.insert(0, str(ROOT / 'scripts'))  # it imports its neighbours
+    try:
+        return runpy.run_path(str(ROOT / 'scripts' / script))
+    finally:
+        sys.path.remove(str(ROOT / 'scripts'))
+
+
+def cell_check(script, config):
+    """``arm -> result line`` of a cell's own check (its driver's greedy calls
+    through ``LLMEngine``, then the reference) at toy size (``config``, under
+    ``benchmarks/tests``), on an engine built as that arm of
+    ``scripts/<script>`` says."""
+    model = json.loads((ROOT / 'benchmarks/tests' / config).read_text())
+
+    @functools.cache
+    def run(arm):
+        out = io.StringIO()
+        with redirect_stdout(out):
+            load_probe(script)['check'](model, [3000000123], [arm])
+        return json.loads(out.getvalue().strip().splitlines()[-1])
+
+    return run
+
+
+# ------------------------------------------ the row of the engine's contract
+def token_gap(params, hf, ids, at, out):
+    return ref.token_gaps(ref.lfm2_logits(params, hf, ids, at), [out]).max()
+
+
+def check_pages(engine, record, want_k, want_v, n_fed, layer=0):
+    """A layer's K and V in the first and the last block a finished request
+    held against the reference's rows ``[n_fed, lanes]``."""
+    at = (n_fed - 1) // BLOCK * BLOCK
+    for pool, want in ((engine.kv.k, want_k), (engine.kv.v, want_v)):
+        first = np.asarray(pool[layer][np.asarray([record['kv_first_block']])])[0]
+        assert ref.content_error(first, want[:BLOCK]) < 1e-5
+        tail = np.asarray(pool[layer][np.asarray([record['kv_tail_block']])])[0]
+        assert ref.content_error(tail[:n_fed - at], want[at:]) < 1e-5
+
+
+def _after_greedy(engine, params, records, lengths, backend):
+    # 4 conv layers x [2, 64] float32 a slot, one kind of leaf.
+    assert engine.telemetry['state_pool'] == {
+        'slots': 4, 'bytes': 4 * 4 * 2 * 64 * 4, 'bytes_per_slot': 4 * 2 * 64 * 4,
+        'leaves': [{'count': 4, 'shape': [2, 64], 'dtype': 'float32'}],
+    }
+    assert engine.telemetry['kv_pools']['kv']['block_shape'] == [BLOCK, 2 * 16]
+    assert engine.telemetry['kv_pools']['kv']['layers'] == 2
+    (request,) = [r for r in records if r['kind'] == 'request']
+    assert {'state_slot', 'kv_first_block', 'kv_tail_block'} <= set(request)
+    windows = [r for r in records if r['kind'] == 'decode']
+    assert windows and all(
+        {'kv_blocks', 'moe_pairs', 'moe_pairs_held'} <= set(r) for r in windows
+    )
+    # 4 sparse layers x 3 picks a token
+    assert sum(r['moe_pairs'] for r in windows) == 12 * sum(
+        r['tokens'] for r in windows
+    )
+    if backend == 'interpret':
+        assert all('kv_chunks' in r for r in windows)
+        assert engine.telemetry['kv_walk_keys'] == {'kv': 96}
+
+
+def _check_left(engine, hf, params, fed, record):
+    want = ref.first_conv_inputs(params, hf, fed[-2:])
+    got = engine.state_pool.state['conv'][0][record['state_slot']]
+    assert ref.content_error(got, want) < 1e-5
+    want_k, want_v = ref.first_attn_kv(params, hf, fed, np.arange(len(fed)))
+    check_pages(engine, record, want_k, want_v, len(fed))
+
+
+def _check_sampled(engine, records):
+    windows = [r for r in records if r['kind'] == 'decode']
+    assert windows and all(0 < r['moe_pairs_held'] < r['moe_pairs'] for r in windows)
+
+
+ENGINE_CASES = dict(
+    refusal='cannot serve a hybrid',
+    refused=('enable_prefix_cache', 'host_kv_tier_bytes', 'enable_mixed_batching',
+             'draft_k', 'kv_cache_dtype=int8', 'quantization'),
+    greedy=[(n, (n,), 'xla') for n in (1, 2, 5, 8, 20)] + [(20, (20,), 'interpret')],
+    after_greedy=_after_greedy,
+    left=dict(seed=3, lengths=(6, 19, 11), max_tokens=13, check=_check_left),
+    windows=(1, ((6, 3), (19, 11))),
+    reuse=(1, 4, 13),  # one token, one span, and chunks
+    preempt=dict(seed=3, n=12, num_blocks=11),
+    sampled=dict(
+        hf_over=dict(num_experts=4, num_routed_experts=8), seed=4,
+        lengths=(9, 30, 3), sampling=dict(temperature=0.7, top_p=0.9, max_tokens=9),
+        check=_check_sampled,
+    ),
+    warm_prompt=10,
+)

@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from benchmarks import reference_ouro as ref
 from distllm_tpu.generate.engine.engine import EngineConfig, LLMEngine
 from distllm_tpu.models import mistral, ouro
 
@@ -161,7 +162,58 @@ def paged_logits(cfg, params, rows, *, chunk=8, backend='xla', module=ouro):
 def reference_logits(params, hf, tokens, first, **kw):
     """The plain reference's logits at positions ``first`` onward of one
     row ``tokens``."""
-    from benchmarks import reference_ouro as ref
-
     at = np.arange(first, len(tokens))[None]
     return ref.forward(params, hf, np.asarray(tokens)[None], at, **kw)['logits'][0]
+
+
+# ------------------------------------------ the row of the engine's contract
+def token_gap(params, hf, ids, at, out):
+    return ref.token_gaps(ref.forward(params, hf, ids, at)['logits'], [out]).max()
+
+
+def _after_greedy(engine, params, records, lengths, backend):
+    assert engine.telemetry['loop_window_form'] == 'passes_rolled_layers_unrolled'
+    assert engine.kv.pool_shape[0] == 12  # one pool of T * L planes
+    windows = [r for r in records if r['kind'] == 'decode']
+    prefills = [r for r in records if r['kind'] == 'prefill']
+    assert windows and prefills
+    for r in windows + prefills:
+        assert r['loop_passes'] == 4 and r['kv_planes'] == 12
+    assert all(r['route'] in ('paged', 'chunk') for r in prefills)
+    # at the published threshold every decoded token's head reads the last pass
+    exits = np.sum([r['loop_exit_pass'] for r in windows], axis=0)
+    np.testing.assert_array_equal(exits, [0, 0, 0, sum(r['tokens'] for r in windows)])
+    # the step records price four sweeps of the stack
+    stack = sum(leaf.size for leaf in jax.tree.leaves(params['layers']))
+    rest = sum(leaf.size for leaf in jax.tree.leaves(params)) - stack
+    assert engine._cost_model.n_params == 4 * stack + rest
+
+
+def _check_left(engine, hf, params, fed, record):
+    """In a plane of the first pass and in one of the last."""
+    planes = (0, 2, 9, 11)  # pass 0's first and last layer, pass 3's
+    want = ref.forward(params, hf, np.asarray(fed)[None], [[0]], planes=planes)
+    at = (len(fed) - 1) // BLOCK * BLOCK
+    for plane in planes:
+        for pool, rows in zip((engine.kv.k, engine.kv.v), want['planes'][plane]):
+            first = np.asarray(pool[plane][np.asarray([record['kv_first_block']])])[0]
+            assert ref.content_error(first, rows[0, :BLOCK]) < 1e-5
+            tail = np.asarray(pool[plane][np.asarray([record['kv_tail_block']])])[0]
+            assert ref.content_error(tail[:len(fed) - at], rows[0, at:]) < 1e-5
+
+
+ENGINE_CASES = dict(
+    refusal='cannot serve a looped model',
+    refused=('enable_mixed_batching', 'draft_k', 'kv_cache_dtype=int8', 'quantization'),
+    greedy=[(n, (n,), 'xla') for n in (1, 3, 8, 20)],
+    after_greedy=_after_greedy,
+    left=dict(seed=3, lengths=(6, 19, 11), max_tokens=13, check=_check_left),
+    turnover=True,
+    preempt=dict(seed=3, n=12, num_blocks=11, roomy=True),
+    sampled=dict(
+        seed=4, lengths=(9, 30, 3),
+        sampling=dict(temperature=0.5, top_p=0.95, max_tokens=9),
+    ),
+    warm_prompt=10,
+    unnamed=('ouro',),
+)

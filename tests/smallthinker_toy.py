@@ -9,6 +9,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from benchmarks import reference_smallthinker as ref
+from benchmarks.drivers import smallthinker_closed
 from distllm_tpu.generate.engine.engine import EngineConfig, LLMEngine
 from distllm_tpu.generate.engine.kv_cache import WindowBlocks, window_bound
 from distllm_tpu.models import smallthinker
@@ -135,3 +137,91 @@ def paged_logits(cfg, params, tokens, n_prompt, *, chunk=8, backend='xla',
         )
         out.append(np.asarray(step[0]))
     return np.stack(out), (k, v), full_row, window_blocks
+
+
+# ------------------------------------------ the row of the engine's contract
+def token_gap(params, hf, ids, at, out):
+    return ref.smallthinker_token_gaps(params, hf, ids, at, [out])[0].max()
+
+
+def _after_greedy(engine, params, records, lengths, backend):
+    assert engine.window_blocks.num_held == 0  # everything went back
+    assert engine.window_blocks.freed_total > 0
+    assert engine.telemetry['attn_backend'] == backend
+
+
+def _check_left(engine, hf, params, tokens, record):
+    """The cell's own page check: layer 0's K and V in the full group's
+    first and tail block, layer 1's in the window group's first HELD block
+    (the window's lower edge) and tail block, against the reference's keys
+    and values; pages rolled by a slot read as wrong."""
+    ends = smallthinker_closed._ends(record, BLOCK)
+    first_held = ends['window'][1][0]
+    assert first_held <= max(0, len(tokens) - WINDOW) and min(ends['window'][0]) >= 1
+    if len(tokens) > WINDOW + BLOCK:
+        assert first_held > 0  # blocks behind the window went back
+    pools = {'full': engine.kv, 'window': engine.window_kv}
+    pages = {
+        group: (at, *(
+            np.asarray(side[0][np.asarray(blocks)], np.float32)
+            for side in (pools[group].k, pools[group].v)
+        ))
+        for group, (blocks, at) in ends.items()
+    }
+    _, kept = ref.smallthinker_logits(
+        params, hf, [tokens], [[len(tokens) - 1]], keep=(0, 1), fields=('k', 'v'),
+    )
+    errors = smallthinker_closed._page_errors(pages, kept[0], len(tokens))
+    assert max(errors.values()) < 1e-5
+    rolled = {
+        g: (at, np.roll(k, 1, axis=1), np.roll(v, 1, axis=1))
+        for g, (at, k, v) in pages.items()
+    }
+    wrong = smallthinker_closed._page_errors(rolled, kept[0], len(tokens))
+    assert min(wrong.values()) > 0.5
+
+
+def _check_sampled(engine, records):
+    """The windowed pool's size and the rows under the window on the records."""
+    pools = engine.telemetry['kv_pools']
+    assert (pools['full']['layers'], pools['window']['layers']) == (2, 6)
+    assert pools['window']['window'] == WINDOW
+    steps = [r for r in records if r['kind'] in ('prefill', 'decode')]
+    assert steps and all(
+        r['kv_window_pool_blocks'] == engine.window_blocks.num_blocks - 1
+        and 0 <= r['rows_under_window'] <= r['batch']
+        and r['kv_blocks_window'] <= r['kv_window_pool_blocks'] for r in steps
+    )
+    decodes = [r for r in steps if r['kind'] == 'decode' and r['batch'] == 3]
+    # Two rows under the window at first, one once the second has crossed.
+    assert [r['rows_under_window'] for r in decodes][0] == 2
+    assert [r['rows_under_window'] for r in decodes][-1] == 1
+    # 8 layers x 3 picks a token; half the experts are held.
+    windows = [r for r in steps if r['kind'] == 'decode']
+    assert sum(r['moe_pairs'] for r in windows) == 24 * sum(r['tokens'] for r in windows)
+    assert all(0 < r['moe_pairs_held'] < r['moe_pairs'] for r in windows)
+    assert {r['moe_form'] for r in windows} == {'dense'}
+
+
+ENGINE_CASES = dict(
+    refusal='cannot serve a model with a windowed',
+    refused=('enable_prefix_cache', 'host_kv_tier_bytes', 'enable_mixed_batching',
+             'draft_k', 'kv_cache_dtype=int8', 'quantization'),
+    # rows that stay under the window of 24, cross it while they decode and
+    # start past it, prefilled in chunks of 8
+    greedy=[(1, (5, 17, 61), 'xla'), (1, (17,), 'interpret')],
+    greedy_tokens=14,
+    after_greedy=_after_greedy,
+    # under, across, past
+    left=dict(seed=8, lengths=(40, 13, 25), max_tokens=12, check=_check_left),
+    windows=(3, ((30, 3), (7, 17))),
+    # 18 usable blocks of 4 tokens; two rows of 30 + 20 tokens need 26.
+    preempt=dict(seed=4, n=30, num_blocks=19, roomy=True),
+    # 9 + 10 stays under 24; 20 + 10 crosses it; 50 is past it.
+    sampled=dict(
+        hf_over=dict(moe_num_primary_experts=4, num_routed_experts=8), seed=2,
+        lengths=(9, 20, 50), sampling=dict(temperature=0.7, top_p=0.9, max_tokens=10),
+        check=_check_sampled,
+    ),
+    warm_prompt=20,
+)

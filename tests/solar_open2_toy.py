@@ -13,6 +13,7 @@ import types
 import jax
 import numpy as np
 
+from benchmarks import reference_solar_open2 as ref
 from distllm_tpu.generate.engine.engine import EngineConfig, LLMEngine
 from distllm_tpu.models import solar_open2
 import lfm2_toy
@@ -104,24 +105,83 @@ def paged_logits(cfg, params, rows, **kw):
 def reference_logits(params, hf, tokens, first):
     """The plain reference's logits at positions ``first`` onward of one
     row ``tokens``."""
-    from benchmarks import reference_solar_open2 as ref
-
     at = np.arange(first, len(tokens))[None]
     return ref.solar_open2_logits(params, hf, np.asarray(tokens)[None], at)[0]
 
 
-@functools.cache
 def load_probe() -> dict:
     """``scripts/probe_solar_open2_reference.py``'s globals: the wrong
     programs the cell's limits have to catch (``_beta_half``,
     ``_scalar_decay``, ``_softmax_scoring``, ``arm``) and its ``check``."""
-    import runpy
-    import sys
-    from pathlib import Path
+    return lfm2_toy.load_probe('probe_solar_open2_reference.py')
 
-    root = Path(__file__).resolve().parent.parent
-    sys.path.insert(0, str(root / 'scripts'))  # it imports its neighbours
-    try:
-        return runpy.run_path(str(root / 'scripts/probe_solar_open2_reference.py'))
-    finally:
-        sys.path.remove(str(root / 'scripts'))
+
+# ------------------------------------------ the row of the engine's contract
+def token_gap(params, hf, ids, at, out):
+    return ref.token_gaps(ref.solar_open2_logits(params, hf, ids, at), [out]).max()
+
+
+def _after_greedy(engine, params, records, lengths, backend):
+    # 3 KDA layers hold a matrix state and the convolutions' rows, the 2
+    # attention layers pages; the engine read all of it from cache_spec().
+    pool = engine.telemetry['state_pool']
+    assert pool['slots'] == 4 and pool['bytes_per_slot'] == 3 * (3 * 72 + 3 * 8 * 8) * 4
+    assert sorted((leaf['count'], leaf['shape']) for leaf in pool['leaves']) == [
+        (3, [3, 8, 8]), (3, [3, 72]),
+    ]
+    assert engine.telemetry['kv_pools']['kv']['block_shape'] == [BLOCK, 8]
+    assert engine.telemetry['kv_pools']['kv']['layers'] == 2
+    (request,) = [r for r in records if r['kind'] == 'request']
+    assert {'state_slot', 'kv_first_block', 'kv_tail_block'} <= set(request)
+    windows = [r for r in records if r['kind'] == 'decode']
+    fields = {'kv_blocks', 'state_rows', 'moe_pairs', 'moe_pairs_held'}
+    assert windows and all(fields <= set(r) for r in windows)
+    # one live row: a step of it reads and writes its slot once, and routes
+    # 2 experts in each of 5 layers, all 8 held
+    steps = sum(r['tokens'] for r in windows)
+    assert steps == 6 == sum(r['state_rows'] for r in windows)
+    assert sum(r['moe_pairs'] for r in windows) == steps * 2 * 5
+    assert sum(r['moe_pairs_held'] for r in windows) == steps * 2 * 5
+    prefills = [r for r in records if r['kind'] == 'prefill']
+    assert prefills and all(r['route'] in ('paged', 'chunk') for r in prefills)
+    assert sum(r['tokens'] for r in prefills) == sum(lengths)
+
+
+def _check_left(engine, hf, params, fed, record):
+    _, held = ref.forward(params, hf, np.asarray(fed)[None], [[0]])
+    state, slot = engine.state_pool.state, record['state_slot']
+    for xi, (want_state, want_conv) in enumerate(held[0]['kda']):  # all three
+        assert ref.content_error(state['kda'][xi][slot], want_state) < 1e-5
+        assert ref.content_error(state['conv'][xi][slot], want_conv) < 1e-5
+    lfm2_toy.check_pages(engine, record, *held[0]['gqa'][0], len(fed))
+
+
+def _check_sampled(engine, records):
+    windows = [r for r in records if r['kind'] == 'decode']
+    # every decoded token is one live row of one step; half the router's
+    # experts are held, so some pairs are and some are not
+    tokens = sum(r['tokens'] for r in windows)
+    assert sum(r['state_rows'] for r in windows) == tokens
+    assert sum(r['moe_pairs'] for r in windows) == tokens * 2 * 5
+    assert 0 < sum(r['moe_pairs_held'] for r in windows) < tokens * 2 * 5
+
+
+ENGINE_CASES = dict(
+    refusal='cannot serve a hybrid',
+    refused=('enable_prefix_cache', 'enable_mixed_batching', 'draft_k',
+             'kv_cache_dtype=int8', 'quantization'),
+    # fewer tokens than taps, one span, a span's end, and several spans
+    greedy=[(n, (n,), 'xla') for n in (1, 2, 3, 8, 20)],
+    after_greedy=_after_greedy,
+    left=dict(seed=3, lengths=(6, 19, 11), max_tokens=13, check=_check_left),
+    turnover=True,
+    reuse=(1, 2, 4, 13),  # fewer tokens than taps, one span, and chunks
+    preempt=dict(seed=3, n=12, num_blocks=11),
+    sampled=dict(
+        hf_over=dict(n_routed_experts=4, num_routed_experts=8), seed=4,
+        lengths=(9, 30, 3), sampling=dict(temperature=0.7, top_p=0.9, max_tokens=9),
+        check=_check_sampled,
+    ),
+    warm_prompt=10,
+    unnamed=('solar', 'kda'),
+)

@@ -23,7 +23,7 @@ from distllm_tpu.generate.engine.engine import SamplingParams
 from distllm_tpu.models.tokenizer import pick_bucket
 from distllm_tpu.resilience.faults import get_fault_injector
 
-from test_state_pool_granite import make_engine as _granite_engine
+from granite_toy import make_engine as _granite_engine
 from test_step_spans import _engine as _mistral_engine
 from test_step_spans import _prompts, _since, time_limit
 

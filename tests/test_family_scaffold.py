@@ -8,6 +8,7 @@ import hashlib
 
 import deepseek_toy
 import falcon_h1_toy
+import granite_toy
 import jax
 import jax.numpy as jnp
 import laguna_toy
@@ -17,7 +18,6 @@ import ouro_toy
 import pytest
 import smallthinker_toy
 import solar_open2_toy
-import test_state_pool_granite as granite_toy
 
 from distllm_tpu.models import common, decoder_family, mistral
 from distllm_tpu.ops.paged_attention import (
@@ -84,6 +84,21 @@ def test_seeded_weights_are_the_parents_bits(family):
     specs = module.param_specs(cfg)
     is_spec = lambda x: isinstance(x, jax.sharding.PartitionSpec)  # noqa: E731
     assert jax.tree.structure(specs, is_leaf=is_spec) == jax.tree.structure(params)
+
+
+@pytest.mark.parametrize('family', [
+    name for name, toy in _TOYS.items()
+    if 'unnamed' in getattr(toy, 'ENGINE_CASES', ())
+])
+def test_no_line_of_the_engine_names_the_family(family):
+    from pathlib import Path
+
+    import distllm_tpu.generate.engine as engine_package
+
+    words = _TOYS[family].ENGINE_CASES['unnamed']
+    for path in Path(engine_package.__file__).parent.glob('*.py'):
+        text = path.read_text().lower()
+        assert not any(word in text for word in words), path.name
 
 
 # ------------------------------------------------------------ the step scan

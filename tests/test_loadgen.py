@@ -240,20 +240,25 @@ def test_loadgen_cli_reports_history_excerpt():
     """scripts/loadgen.py (ISSUE 18 satellite): the CLI owns the process
     history sampler for its run, and the JSON report line carries the
     compact ``loadgen_history_*`` excerpt — the sampled tok/s series plus
-    the SLO burn-rate gauges — not just end-of-run aggregates."""
+    the SLO burn-rate gauges — not just end-of-run aggregates.
+
+    The shortest run that yields the excerpt: four requests through one slot
+    (warm-up compiles one batch size), and a tick every 50 ms of a run of
+    under half a second, so that a sampler thread a loaded machine starves
+    still ticks twice (at 0.2 s the run ended after two ticks, or one)."""
     env = dict(os.environ)
     env.update(JAX_PLATFORMS='cpu')
     proc = subprocess.run(
         [
             sys.executable, str(REPO / 'scripts' / 'loadgen.py'),
-            '--small', '--requests', '8', '--rate', '50', '--slo', '2.0',
-            '--history-interval', '0.2',
+            '--small', '--requests', '4', '--rate', '50', '--slo', '2.0',
+            '--max-num-seqs', '1', '--history-interval', '0.05',
         ],
         capture_output=True, text=True, timeout=300, cwd=REPO, env=env,
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
     fragment = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert fragment['loadgen_requests'] == 8
+    assert fragment['loadgen_requests'] == 4
     assert fragment['loadgen_history_window_s'] == 60.0
     assert fragment['loadgen_history_samples'] >= 2
     assert fragment['loadgen_history_tok_s'] > 0

@@ -3,7 +3,7 @@ hold some leaves a layer an array for its programs
 (``deepseek_v3.serving_params``, ``common.unstack``, ``common.layer_at``),
 the engine asks for that form once, and no number moves: the same arrays,
 the same logits, the same tokens. What the form is FOR is in the compiled
-text (``tests/test_aot_tpu.py::test_decode_window_slices_no_weight``)."""
+text (``tests/test_aot_windows.py::test_decode_window_slices_no_weight``)."""
 
 import jax
 import jax.numpy as jnp

@@ -1,6 +1,8 @@
 """granitemoehybrid on the CPU at tiny widths: the program (models/
 granite_hybrid.py, models/moe.py) against the plain reference
-(benchmarks/reference_granite.py), seeded weights.
+(benchmarks/reference_granite.py), seeded weights; and what of the engine is
+this family's alone (what every family's engine owes:
+``test_engine_families.py``).
 
 Tolerances. The program and the reference are both float32 here, so they
 differ only by the order of sums (chunked SSD against step-by-step
@@ -11,10 +13,9 @@ step, compounding) or a score scale of 1/sqrt(head) instead of
 these sizes, which the tests of the perturbed program below show.
 
 The file's name sorts it after ``test_startup_attribution.py`` on purpose:
-it is the longest file of the suite, and started beside ``test_history.py``
-(as ``test_granite_hybrid.py`` would be under ``--dist loadfile``) its
-compiles begin between the two arms of that file's timing sentinel, which
-then failed in two whole runs of three.
+started beside ``test_history.py`` (as ``test_granite_hybrid.py`` would be
+under ``--dist loadfile``) its compiles begin between the two arms of that
+file's timing sentinel, which then failed in two whole runs of three.
 """
 
 import jax
@@ -24,47 +25,23 @@ import numpy as np
 import pytest
 
 from benchmarks import reference_granite as ref
+from distllm_tpu.generate.engine.engine import (
+    EngineConfig,
+    LLMEngine,
+    SamplingParams,
+)
 from distllm_tpu.models import granite_hybrid as gh
 from distllm_tpu.models.moe import routed_experts
-
-LAYERS = ('mamba', 'mamba', 'attention', 'mamba')
-
-
-def tiny_hf(**over) -> dict:
-    hf = {
-        'model_type': 'granitemoehybrid', 'vocab_size': 64, 'hidden_size': 32,
-        'layer_types': list(LAYERS), 'num_hidden_layers': len(LAYERS),
-        'num_attention_heads': 4, 'num_key_value_heads': 2,
-        'mamba_n_heads': 8, 'mamba_d_head': 8, 'mamba_d_state': 16,
-        'mamba_d_conv': 4, 'mamba_chunk_size': 8, 'mamba_expand': 2,
-        'mamba_n_groups': 1, 'intermediate_size': 16,
-        'shared_intermediate_size': 24, 'num_local_experts': 8,
-        'num_experts_per_tok': 3, 'embedding_multiplier': 12.0,
-        'attention_multiplier': 0.25, 'residual_multiplier': 0.22,
-        'logits_scaling': 4.0, 'rms_norm_eps': 1e-5,
-        'position_embedding_type': 'nope', 'tie_word_embeddings': True,
-    }
-    hf.update(over)
-    return hf
-
-
-def tiny(seed=0, **over):
-    hf = tiny_hf(**over)
-    cfg = gh.GraniteHybridConfig.from_hf_config(hf).model_copy(
-        update={'dtype': 'float32'}
-    )
-    params = gh.init_on_device(jax.random.PRNGKey(seed), cfg)
-    # Larger kernels than 0.02 so that every mechanism moves the logits.
-    params = jax.tree.map(
-        lambda a: a * 4.0 if a.ndim >= 3 and a.shape[-1] > 1 else a, params
-    )
-    return hf, cfg, params
-
-
-def spread(a, b):
-    """Largest difference as a share of the reference's spread."""
-    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-    return float(np.abs(a - b).max() / b.std())
+from granite_toy import (
+    LAYERS,
+    NoTokenizer,
+    left_errors,
+    make_engine,
+    prompt,
+    spread,
+    tiny,
+    tiny_hf,
+)
 
 
 def test_config_reads_published_keys_and_the_share():
@@ -294,232 +271,30 @@ def test_params_from_hf_layout():
 
 
 # ------------------------------------------------------------ the engine
-from benchmarks.reference import token_gaps  # noqa: E402
-from distllm_tpu.generate.engine.engine import (  # noqa: E402
-    EngineConfig,
-    LLMEngine,
-    SamplingParams,
-)
+def test_a_bfloat16_state_pool_is_told_apart_by_two_orders():
+    """What the benchmark's second limit reads (the float32 pool:
+    ``test_engine_families.py``, ``left``): a pool that keeps the state in
+    bfloat16, the precision below the one the module states, reads 1e-3 and
+    more where the module's own reads under 1e-5."""
+    from test_engine_families import finished
 
-
-class _NoTokenizer:
-    eos_id = None
-
-
-def make_engine(seed=0, hf_over=None, **over):
-    hf, cfg, params = tiny(seed, **(hf_over or {}))
-    settings = dict(
-        block_size=4, num_blocks=64, max_num_seqs=4, max_model_len=96,
-        prefill_chunk_tokens=8, decode_steps=4, attn_backend='xla',
-        enable_prefix_cache=False,
-    )
-    settings.update(over)
-    engine = LLMEngine(cfg, params, _NoTokenizer(), EngineConfig(**settings))
-    return hf, params, engine
-
-
-def assert_teacher_forced(hf, params, prompts, outputs, limit=1e-3):
-    """Every generated token is the reference's greedy token given the same
-    history, or within ``limit`` standard deviations of it (float32 on both
-    sides: a tie is the one way to differ)."""
-    width = max(len(p) + len(o) for p, o in zip(prompts, outputs))
-    ids = np.zeros((len(prompts), width), np.int32)
-    for row, (p, o) in enumerate(zip(prompts, outputs)):
-        ids[row, :len(p) + len(o)] = list(p) + list(o)
-    logits = ref.granite_logits(params, hf, ids)
-    gaps = token_gaps(logits, [len(p) for p in prompts], outputs)
-    assert max(gaps) < limit, gaps
-
-
-def _prompt(rng, n):
-    return [int(t) for t in rng.integers(0, 64, n)]
-
-
-# (b) prefill of n tokens then m decode steps, through the state pool and
-# the paged KV, against the reference's full forward at n + m: n shorter
-# than, equal to and 2.5 times the prefill chunk (8).
-@pytest.mark.parametrize('n', [5, 8, 20])
-@pytest.mark.parametrize('backend', ['xla', 'interpret'])
-def test_prefill_then_decode_carries_state(n, backend):
-    if backend == 'interpret' and n != 20:
-        pytest.skip('one length through the Pallas interpreter is enough')
-    hf, params, engine = make_engine(attn_backend=backend)
-    prompt = _prompt(np.random.default_rng(n), n)
-    out = engine.generate_ids(
-        [prompt], SamplingParams(temperature=0.0, max_tokens=7)
-    )
-    assert len(out[0]) == 7
-    assert_teacher_forced(hf, params, [prompt], out)
-    assert engine.telemetry['state_pool_slots'] == 4
-    # 3 Mamba layers x (8 x 8 x 16 float32 + 3 x 96 float32) a slot.
-    assert engine.telemetry['state_pool_bytes'] == 4 * 3 * (1024 + 288) * 4
-
-
-# (e) through LLMEngine.generate_ids.
-def test_rows_of_different_lengths_finish_at_different_windows():
-    hf, params, engine = make_engine()
-    rng = np.random.default_rng(1)
-    prompts = [_prompt(rng, 6), _prompt(rng, 19)]
-    rids = [
-        engine.add_request(prompts[0], SamplingParams(temperature=0.0, max_tokens=3)),
-        engine.add_request(prompts[1], SamplingParams(temperature=0.0, max_tokens=11)),
-    ]
-    got = {rid: [] for rid in rids}
-    while engine.has_unfinished:
-        for rid, token in engine.step():
-            got[rid].append(token)
-    outputs = [got[rid] for rid in rids]
-    assert [len(o) for o in outputs] == [3, 11]
-    assert_teacher_forced(hf, params, prompts, outputs)
-
-
-@pytest.mark.parametrize('pool_dtype', ['float32', 'bfloat16'])
-def test_the_state_a_finished_request_left_is_the_references(pool_dtype):
-    """What the benchmark's second limit reads: the ``request`` record
-    names the slot a request held when it finished, the pool keeps what the
-    slot held, and that is the reference's SSM state after everything but
-    the request's last token. A pool that keeps the state in bfloat16 (the
-    precision below the one the module states) is told apart by two orders."""
     hf, cfg, params = tiny(0)
-    if pool_dtype == 'bfloat16':
-        class Bf16Pool(type(cfg)):
-            def state_spec(self):
-                spec = super().state_spec()
-                return {**spec, 'ssm': tuple(
-                    jax.ShapeDtypeStruct(s.shape, jnp.bfloat16) for s in spec['ssm']
-                )}
-        cfg = Bf16Pool(**cfg.model_dump())
-    engine = LLMEngine(cfg, params, _NoTokenizer(), EngineConfig(
+
+    class Bf16Pool(type(cfg)):
+        def state_spec(self):
+            spec = super().state_spec()
+            return {**spec, 'ssm': tuple(
+                jax.ShapeDtypeStruct(s.shape, jnp.bfloat16) for s in spec['ssm']
+            )}
+
+    engine = LLMEngine(Bf16Pool(**cfg.model_dump()), params, NoTokenizer(), EngineConfig(
         block_size=4, num_blocks=64, max_num_seqs=4, max_model_len=96,
         prefill_chunk_tokens=8, decode_steps=4, attn_backend='xla',
         enable_prefix_cache=False,
     ))
-    rng = np.random.default_rng(3)
-    prompts = [_prompt(rng, 6), _prompt(rng, 19), _prompt(rng, 11)]
-    before = engine.flight.total_recorded
-    outputs = engine.generate_ids(
-        prompts, SamplingParams(temperature=0.0, max_tokens=13)
-    )
-    records = sorted(
-        (r for r in engine.flight.snapshot()[before - engine.flight.total_recorded:]
-         if r['kind'] == 'request'), key=lambda r: r['request_id'],
-    )
-    slots = [r['state_slot'] for r in records]
-    assert sorted(slots) == [0, 1, 2]
-    width = max(len(p) + len(o) for p, o in zip(prompts, outputs))
-    ids = np.zeros((3, width), np.int32)
-    for row, (p, o) in enumerate(zip(prompts, outputs)):
-        ids[row, :len(p) + len(o)] = list(p) + list(o)
-    fed = [len(p) + len(o) - 1 for p, o in zip(prompts, outputs)]
-    _, want = ref.granite_forward(params, hf, ids, fed)
-    got = [np.asarray(leaf[np.asarray(slots)]) for leaf in engine.state_pool.state['ssm']]
-    errors = ref.state_errors(got, want)
-    slow = ref.slow_head_state_error(
-        got[0], want[0], params['mamba']['dt_bias'][0], params['mamba']['A_log'][0]
-    )
-    assert len(errors) == 3 and len(ref.slow_heads(
-        params['mamba']['dt_bias'][0], params['mamba']['A_log'][0])) == 1  # of 8 heads
-    if pool_dtype == 'float32':
-        assert max(errors) < 1e-5 and slow < 1e-5, (errors, slow)
-    else:
+    for fed, record in finished('granite', engine, 3, (6, 19, 11), 13):
+        errors, slow = left_errors(engine, hf, params, fed, record)
         assert min(errors) > 1e-3 and slow > 1e-3, (errors, slow)
-
-
-def test_a_reused_slot_does_not_leak_stale_state():
-    hf, params, engine = make_engine(max_num_seqs=1)
-    rng = np.random.default_rng(2)
-    sampling = SamplingParams(temperature=0.0, max_tokens=6)
-    first = _prompt(rng, 17)
-    engine.generate_ids([first], sampling)
-    # The one slot now holds the first request's state; the next request
-    # takes it, alone and after a call that left the pipeline empty.
-    for n in (4, 13):  # one span, and chunks
-        later = _prompt(rng, n)
-        out = engine.generate_ids([later], sampling)
-        assert_teacher_forced(hf, params, [later], out)
-
-
-def test_a_preempted_request_is_admitted_again_from_zero_state():
-    # 10 usable blocks of 4 tokens; two rows of 12 + 20 tokens need 16.
-    from distllm_tpu.observability import instruments
-
-    hf, params, engine = make_engine(num_blocks=11, max_num_seqs=2)
-    # As if finished requests had used none of their budgets: the
-    # look-ahead then admits both rows, and the pool runs short under them.
-    engine._ewma['budget_use'] = 0.0
-    before = instruments.SCHED_PREEMPTIONS.value
-    rng = np.random.default_rng(3)
-    prompts = [_prompt(rng, 12), _prompt(rng, 12)]
-    outputs = engine.generate_ids(
-        prompts, SamplingParams(temperature=0.0, max_tokens=20)
-    )
-    assert [len(o) for o in outputs] == [20, 20]
-    assert instruments.SCHED_PREEMPTIONS.value > before
-    assert_teacher_forced(hf, params, prompts, outputs)
-
-
-def test_sampled_generation_and_records():
-    hf, params, engine = make_engine(
-        hf_over=dict(num_local_experts=4, num_routed_experts=8)
-    )
-    rng = np.random.default_rng(4)
-    before = engine.flight.total_recorded
-    outputs = engine.generate_ids(
-        [_prompt(rng, 9), _prompt(rng, 30), _prompt(rng, 3)],
-        SamplingParams(temperature=0.7, top_p=0.9, max_tokens=9),
-    )
-    assert [len(o) for o in outputs] == [9, 9, 9]
-    grew = engine.flight.total_recorded - before
-    records = engine.flight.snapshot()[-grew:]
-    windows = [r for r in records if r['kind'] == 'decode']
-    assert windows and all(
-        0 < r['moe_pairs_held'] < r['moe_pairs']
-        for r in windows
-    )
-    # 4 layers x 3 picks a token: every decode token routes 12 pairs.
-    assert sum(r['moe_pairs'] for r in windows) == 12 * sum(
-        r['tokens'] for r in windows
-    )
-    prefills = [r for r in records if r['kind'] == 'prefill']
-    assert prefills and all(
-        r['route'] in ('paged', 'chunk') for r in prefills
-    )
-
-
-# (f) each refusal raises, naming the setting.
-@pytest.mark.parametrize('setting, over', [
-    ('enable_prefix_cache', dict(enable_prefix_cache=True)),
-    ('host_kv_tier_bytes', dict(enable_prefix_cache=True, host_kv_tier_bytes=1 << 20)),
-    ('enable_mixed_batching', dict(enable_mixed_batching=True)),
-    ('draft_k', dict(draft_k=2)),
-    ('kv_cache_dtype=int8', dict(kv_cache_dtype='int8')),
-    ('quantization', dict(quantization='int8')),
-])
-def test_hybrid_refuses_what_needs_state_snapshots(setting, over):
-    if setting == 'host_kv_tier_bytes':
-        setting = 'enable_prefix_cache'  # a tier needs the cache: first refusal
-    with pytest.raises(ValueError, match=f'{setting} cannot serve a hybrid'):
-        make_engine(**over)
-
-
-def test_hybrid_refuses_a_mesh():
-    from jax.sharding import Mesh
-
-    mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1), ('expert', 'model'))
-    hf, cfg, params = tiny(0)
-    with pytest.raises(ValueError, match='mesh cannot serve a hybrid'):
-        LLMEngine(
-            cfg, params, _NoTokenizer(),
-            EngineConfig(block_size=4, num_blocks=16, max_num_seqs=2), mesh=mesh,
-        )
-
-
-def test_hybrid_warmup_compiles_every_shape_and_serves_after():
-    hf, params, engine = make_engine(max_model_len=32, max_num_seqs=2)
-    engine.warmup()
-    prompt = _prompt(np.random.default_rng(6), 10)
-    out = engine.generate_ids([prompt], SamplingParams(temperature=0.0, max_tokens=5))
-    assert_teacher_forced(hf, params, [prompt], out)
 
 
 def _assert_programs_lower_as_the_parents(engine, cfg):
@@ -578,7 +353,7 @@ def test_mistral_pool_and_programs_are_what_they_were():
     )
     params = mistral.init_on_device(jax.random.PRNGKey(0), cfg)
     engine = LLMEngine(
-        cfg, params, _NoTokenizer(),
+        cfg, params, NoTokenizer(),
         EngineConfig(block_size=4, num_blocks=32, max_num_seqs=2,
                      max_model_len=64, prefill_chunk_tokens=8),
     )
@@ -609,7 +384,7 @@ def test_mistral_pool_and_programs_are_what_they_were():
     jax.monitoring.register_event_duration_secs_listener(on_duration)
     rng = np.random.default_rng(0)
     out = engine.generate_ids(
-        [_prompt(rng, 5), _prompt(rng, 21)],
+        [prompt(rng, 5), prompt(rng, 21)],
         SamplingParams(temperature=0.0, max_tokens=6),
     )
     assert [len(o) for o in out] == [6, 6]
