@@ -108,6 +108,11 @@ class SamplingParams:
     seed: int | None = None
     max_tokens: int = 2000
     stop_token_ids: tuple[int, ...] = ()
+    # A model that decides a block of positions together
+    # (``CacheSpec.block``): a denoise step decides EVERY masked position
+    # whose confidence is over this where those are more than its schedule's
+    # count; None is the static rule (docs/serving.md "Blocks of positions").
+    unmask_threshold: float | None = None
 
 
 # Sentinel returned by _dispatch_window when nothing can be dispatched
@@ -246,6 +251,9 @@ class Request:
     # the error text.
     finish_reason: str = ''
     error: str | None = None
+    # Where blocks of positions are decided together: per output token the
+    # denoise step it was decided at (on the 'request' flight record).
+    decided_at: list[int] = field(default_factory=list)
 
     @property
     def num_tokens(self) -> int:
@@ -311,9 +319,14 @@ class EngineConfig(BaseConfig):
     # value threshold and sorts nothing at any K (ops/sampling.py); a cap
     # adds a second search to the steps of the rows that have one.
     sampling_top_window: int = 0
+    # A model that decides a block of positions together: denoise forwards
+    # a block (a static of the one compiled window); 0 = the block's length,
+    # a position a forward. Every block takes one more forward, which
+    # commits its K/V. Read for no other model.
+    denoise_steps: int = 0
 
     @field_validator(
-        'sampling_top_window', 'prefill_chunk_tokens',
+        'sampling_top_window', 'prefill_chunk_tokens', 'denoise_steps',
         'max_window_prefill_tokens', 'draft_k', 'host_kv_tier_bytes',
         'disk_kv_tier_bytes', 'max_dispatch_retries', 'peer_kv_timeout_ms',
     )
@@ -759,6 +772,10 @@ class LLMEngine:
         spec = model_cfg.cache_spec()
         self.cache_spec = spec
         self._refuse_unservable(spec, cfg, mesh, kv_pool_dtype)
+        # Positions a sequence decides together (1: a token a forward), and
+        # the denoise forwards a block of them takes.
+        self._block = spec.block
+        self._denoise_steps = cfg.denoise_steps or spec.block
         self._programs = importlib.import_module(spec.programs)
         # A family may hold some leaves otherwise for its programs than its
         # public tree does (``deepseek_v3.serving_params``: a layer an
@@ -1140,8 +1157,18 @@ class LLMEngine:
                 temp, top_p, min_p, top_k, seeds, num_steps=num_steps,
                 attn_backend=attn_backend, max_table_positions=max_tables,
                 sampling_top_window=cfg.sampling_top_window,
-                **({'state': state[0]} if state else {}),
+                **window_extra(state),
             )
+
+        def window_extra(state):
+            # A block model's last operand is its rows' unmask thresholds,
+            # its denoise steps a static; a hybrid's its state pool.
+            if spec.block > 1:
+                return {
+                    'unmask_threshold': state[0],
+                    'denoise_steps': self._denoise_steps,
+                }
+            return {'state': state[0]} if state else {}
 
         window_fn.__name__ = f'{prefix}window_fn'
         # The pools a window updates in place: K, V and a hybrid's state.
@@ -1326,7 +1353,7 @@ class LLMEngine:
                 rows *= 2
         if self._moe_widths is not None:
             rows = cfg.max_num_seqs
-            tokens = {f'decode({rows})': rows} | {
+            tokens = {f'decode({rows})': rows * self._block} | {
                 key: span * rows for key, (span, rows) in prefills.items()
             }
             forms = {key: self._moe_form(n) for key, n in tokens.items()}
@@ -1540,6 +1567,8 @@ class LLMEngine:
                         f'{setting} cannot serve a looped model (its stack '
                         f'runs {spec.passes} times a token): {why}'
                     )
+        if spec.block > 1:
+            LLMEngine._refuse_for_blocks(spec, cfg, mesh, int8)
         if not spec.windowed:
             return
         if spec.paged[0].window is not None or len(spec.paged) > 2:
@@ -1575,6 +1604,64 @@ class LLMEngine:
                 raise ValueError(
                     f'{setting} cannot serve a model with a windowed cache '
                     f'group: {why}'
+                )
+
+    @staticmethod
+    def _refuse_for_blocks(spec, cfg: EngineConfig, mesh, int8: bool) -> None:
+        """What was never held to the reference for a model that decides a
+        block of positions together, each refused by name with its reason,
+        and the sizes its windows, pages and spans must come in."""
+        block = spec.block
+        sizes = {
+            'decode_steps': cfg.decode_steps,
+            'block_size': cfg.block_size,
+            'max_model_len': cfg.max_model_len,
+            'prefill_chunk_tokens': cfg.prefill_chunk_tokens,
+        }
+        for setting, size in sizes.items():
+            if size % block:
+                raise ValueError(
+                    f'{setting}={size} cannot serve a model that decides '
+                    f'blocks of {block} positions: windows, pages, contexts '
+                    'and prefill spans hold whole blocks'
+                )
+        if not 0 <= cfg.denoise_steps <= block:
+            raise ValueError(
+                f'denoise_steps={cfg.denoise_steps} cannot serve a model '
+                f'that decides blocks of {block} positions: a step decides '
+                'at least one'
+            )
+        refused = {
+            'cache groups': (
+                len(spec.paged) > 1 or spec.latent or spec.state is not None
+                or spec.passes > 1 or spec.layer_buffers
+            ) and 'the block window is written for one stacked K/V pool',
+            'draft_k': bool(cfg.draft_k)
+            and 'a block is decided by its own forwards, not verified '
+            'against a draft',
+            'enable_mixed_batching': cfg.enable_mixed_batching
+            and 'the mixed window is the one-token K/V family\'s program',
+            'enable_prefix_cache': cfg.enable_prefix_cache
+            and 'a cached KV block shared under the block-causal mask was '
+            'never held to the reference',
+            'host_kv_tier_bytes': bool(cfg.host_kv_tier_bytes)
+            and 'a promoted KV block under the block-causal mask was never '
+            'held to the reference',
+            'defer_prefill': cfg.defer_prefill
+            and 'a prefill yields no token to defer',
+            'kv_cache_dtype=int8': int8
+            and 'a block rewritten by its commit would rescale its page '
+            'twice',
+            'quantization': bool(cfg.quantization)
+            and 'the family\'s parameter trees have no quantized route',
+            'mesh': mesh is not None
+            and 'the grouped expert matmul has no partitioning',
+        }
+        for setting, why in refused.items():
+            if why:
+                raise ValueError(
+                    f'{setting} cannot serve a model that decides blocks of '
+                    f'{block} positions together: {why}'
                 )
 
     def _build_window_group(self, group, pool) -> None:
@@ -1679,6 +1766,11 @@ class LLMEngine:
             self.state_pool.state = more.pop(0)
         return tokens, last_ids, more[0] if more else None
 
+    def _ids_shape(self, rows: int) -> tuple[int, ...]:
+        """Of a window's ``ids`` operand: a row's last token, or for a model
+        that decides blocks the given tokens of its first block."""
+        return (rows,) if self._block == 1 else (rows, self._block)
+
     def _put(self, x):
         """Host value → device array, replicated over the mesh under TP."""
         if self._replicated is not None:
@@ -1716,7 +1808,7 @@ class LLMEngine:
         pools = jax.tree.map(lambda pool: pool.spec(), groups)
         shapes = (
             spec(self.params),
-            sds((b,), i32),  # ids
+            sds(self._ids_shape(b), i32),  # ids
             sds((b,), i32),  # positions
             sds((b,), i32),  # context_lens
             pools,
@@ -1731,6 +1823,8 @@ class LLMEngine:
         )
         if self.state_pool is not None:
             shapes += (self.state_pool.spec(),)
+        if self._block > 1:
+            shapes += (sds((b,), f32),)  # unmask thresholds
         jitted = jax.jit(
             window_fn,
             donate_argnums=self._window_donate,
@@ -2019,9 +2113,9 @@ class LLMEngine:
                                 (b,), self.config.max_num_seqs, np.int32
                             )) if self.state_pool is not None else None,
                         )
-                        np.asarray(
-                            self._sample_device(pg_logits, [None] * b)
-                        )
+                        if self._block == 1:  # a block's prefill samples nothing
+                            pg_logits = self._sample_device(pg_logits, [None] * b)
+                        np.asarray(pg_logits)
                 if b >= cap:
                     break
                 b *= 2
@@ -2092,7 +2186,7 @@ class LLMEngine:
             scope=self._compile_scope,
         ):
             tokens, _, _ = self._call_decode_window(
-                self._put(np.zeros((bsz,), np.int32)),
+                self._put(np.zeros(self._ids_shape(bsz), np.int32)),
                 self._put(np.zeros((bsz,), np.int32)),
                 self._put(np.ones((bsz,), np.int32)),
                 self._group_tables(self._put(
@@ -2104,6 +2198,10 @@ class LLMEngine:
                 self._put(np.zeros((bsz,), np.float32)),
                 self._put(np.zeros((bsz,), np.int32)),
                 self._put(np.zeros((bsz,), np.uint32)),
+                *(
+                    [self._put(np.ones((bsz,), np.float32))]  # thresholds
+                    if self._block > 1 else []
+                ),
             )
             self._merge_ids(
                 self._put(np.zeros((bsz,), np.int32)),
@@ -2301,13 +2399,13 @@ class LLMEngine:
             return
         if (
             self.state_pool is not None or self.window_kv is not None
-            or self.cache_spec.latent
+            or self.cache_spec.latent or self._block > 1
         ):
             self.telemetry.setdefault(
                 'xla_cost_skipped', 'hybrid programs are not priced'
                 if self.state_pool is not None
-                else 'programs over several cache groups, or over a latent '
-                'one, are not priced'
+                else 'programs over several cache groups, over a latent one '
+                'or over blocks of positions are not priced'
             )
             return
         cfg = self.config
@@ -2791,8 +2889,10 @@ class LLMEngine:
             or tokens == request.admit_tokens
         ):
             # Its prefill is still to run (or rides mixed windows, or
-            # waits for a promotion) and emits one token of the budget.
-            length, steps = tokens + 1, left - 1
+            # waits for a promotion) and emits one token of the budget
+            # (none where blocks of positions are decided together).
+            first = int(self._block == 1)
+            length, steps = tokens + first, left - first
         else:
             length, steps = tokens + unacked, left - unacked
         kept = request.num_borrowed_blocks
@@ -3346,7 +3446,7 @@ class LLMEngine:
         progress resets to the cached prefix: re-writing already-written
         positions is idempotent, so the retry is exact."""
         for request in requests:
-            request.prefill_target = request.num_tokens
+            request.prefill_target = max(1, self._prefill_end(request))
             request.prefill_sent = request.num_cached_tokens
             request.prefill_done = request.num_cached_tokens
             if request.request_id not in self._pending_prefill:
@@ -3539,7 +3639,9 @@ class LLMEngine:
         whole: dict[int, list[Request]] = {}
         chunked: list[Request] = []
         for request in requests:
-            tail = request.num_tokens - request.num_cached_tokens
+            tail = self._prefill_end(request) - request.num_cached_tokens
+            if tail <= 0:
+                continue  # under one block: its first window is given it all
             if chunk and tail > chunk:
                 chunked.append(request)
             else:
@@ -3553,15 +3655,23 @@ class LLMEngine:
                     (
                         r,
                         r.num_cached_tokens,
-                        r.num_tokens - r.num_cached_tokens,
+                        self._prefill_end(r) - r.num_cached_tokens,
                     )
                     for r in batch
                 ]
                 emitted.extend(
-                    self._dispatch_prefill_paged(spans, bucket, defer_to)
+                    self._dispatch_prefill_paged(
+                        spans, bucket, defer_to, sample=self._block == 1
+                    )
                 )
         emitted.extend(self._run_prefill_chunked(chunked, defer_to))
         return emitted
+
+    def _prefill_end(self, request: Request) -> int:
+        """The tokens a request's prefill covers: all it has, or, where
+        blocks of positions are decided together, the whole blocks of them
+        (the rest are the given tokens of its first window's first block)."""
+        return request.num_tokens - request.num_tokens % self._block
 
     def _run_prefill_chunked(
         self, requests: list[Request], defer_to=None
@@ -3608,8 +3718,8 @@ class LLMEngine:
             while pending:
                 groups: dict[tuple[int, bool], list] = {}
                 for request, start in pending.values():
-                    ntok = min(chunk, request.num_tokens - start)
-                    final = start + ntok >= request.num_tokens
+                    ntok = min(chunk, self._prefill_end(request) - start)
+                    final = start + ntok >= self._prefill_end(request)
                     bucket = pick_bucket(ntok, self.prefill_buckets)
                     groups.setdefault((bucket, final), []).append(
                         (request, start, ntok)
@@ -3633,8 +3743,8 @@ class LLMEngine:
                         _metrics.ENGINE_PREFILL_CHUNK_TOKENS.observe(ntok)
                     emitted.extend(
                         self._dispatch_prefill_paged(
-                            batch, bucket, defer_to, sample=final,
-                            route='chunk',
+                            batch, bucket, defer_to,
+                            sample=final and self._block == 1, route='chunk',
                         )
                     )
                     for request, start, ntok in batch:
@@ -4004,6 +4114,7 @@ class LLMEngine:
                 batch=batch,
                 draft_tokens=extra.get('draft_tokens', 0),
                 prefill_tokens=extra.get('prefill_tokens', 0),
+                **self._block_cost_fields(kind, extra),
             )
             if cost is not None:
                 mfu, bw_util = self._cost_model.utilization(cost, duration_s)
@@ -4064,6 +4175,19 @@ class LLMEngine:
             **(gauges if gauges is not None else self._sched_gauges()),
             **extra,
         )
+
+    def _block_cost_fields(self, kind: str, extra: dict) -> dict:
+        """What a decode window of a model that decides blocks of positions
+        costs, from its forwards and positions and not from its tokens: the
+        weight passes its program ran (``S + 1`` a block of the window) and
+        the positions its live rows' forwards computed."""
+        if self._block == 1 or kind != 'decode' or 'forwards' not in extra:
+            return {}
+        blocks = self.config.decode_steps // self._block
+        return {
+            'weight_passes': blocks * (self._denoise_steps + 1),
+            'positions': extra['forwards'] * self._block,
+        }
 
     def roofline_snapshot(self) -> dict[str, dict[str, float]]:
         """Copy of the raw per-kind roofline accumulators — pass a prior
@@ -4183,7 +4307,27 @@ class LLMEngine:
         request's prefill tail is still riding mixed windows."""
         if not self._decode_ready(request):
             return 0
+        if self._block > 1:
+            return self._block_cover(request, unacked, k)[1]
         return max(0, min(k, self._budget_left(request) - unacked))
+
+    def _block_cover(
+        self, request: Request, unacked: int, k: int
+    ) -> tuple[int, int, int]:
+        """For a model that decides blocks of positions together:
+        ``(positions the request's next window covers, tokens it emits,
+        given tokens of its first block)``. A window covers whole blocks
+        from the last whole block the request's tokens fill; the tokens
+        past that one (a prompt's remainder, or what a preempted request
+        had decided of its last block) are given in the first block, and
+        the last block of a budget is decided whole, then cut."""
+        block = self._block
+        room = self._budget_left(request) - unacked
+        if room <= 0 or not self._decode_ready(request):
+            return 0, 0, 0
+        given = (request.num_tokens + unacked) % block
+        covered = block * min(k // block, -(-(room + given) // block))
+        return covered, min(covered - given, room), given
 
     def _budget_left(self, request: Request) -> int:
         """Tokens the request may still emit (those in flight included)
@@ -4214,6 +4358,11 @@ class LLMEngine:
                 # preempt) for rows that write nothing.
                 continue
             unacked = self._unacked.get(rid, 0)
+            if self._block > 1:
+                # to the end of the last block the window decides
+                covered, _, given = self._block_cover(request, unacked, k)
+                reserve[rid] = max(1, unacked - given + covered)
+                continue
             reserve[rid] = max(
                 1, unacked + self._window_budget(request, unacked, k)
             )
@@ -4302,11 +4451,15 @@ class LLMEngine:
             return _DRAIN
 
         b = self.config.max_num_seqs
-        ids = np.zeros((b,), np.int32)
+        ids = np.zeros(self._ids_shape(b), np.int32)
         positions = np.zeros((b,), np.int32)
         context_lens = np.ones((b,), np.int32)
         block_tables = np.zeros((b, self.max_blocks_per_seq), np.int32)
         steps_left = np.zeros((b,), np.int32)
+        # Blocks of positions: a row's threshold (1.0: the static rule), and
+        # the given tokens its first block starts with, by rid.
+        thresholds = np.ones((b,), np.float32)
+        given_of: dict[int, int] = {}
         temperature = np.zeros((b,), np.float32)
         top_p = np.ones((b,), np.float32)
         min_p = np.zeros((b,), np.float32)
@@ -4322,7 +4475,12 @@ class LLMEngine:
         for slot, request in running:
             rid = request.request_id
             unacked = self._unacked.get(rid, 0)
-            steps = self._window_budget(request, unacked, k)
+            if self._block > 1:
+                # The window covers whole blocks (``steps_left``); what it
+                # emits (``steps``) is less by what its first block is given.
+                covered, steps, given = self._block_cover(request, unacked, k)
+            else:
+                steps = self._window_budget(request, unacked, k)
             total = request.num_tokens + unacked
             positions[slot] = total - 1
             context_lens[slot] = total
@@ -4335,13 +4493,21 @@ class LLMEngine:
                     rid, total - 1, total - 1 + steps
                 )
                 window_blocks.table_row(rid, window_tables[slot])
-            steps_left[slot] = steps
+            steps_left[slot] = covered if self._block > 1 else steps
             temperature[slot] = request.params.temperature
             top_p[slot] = request.params.top_p
             min_p[slot] = request.params.min_p
             top_k[slot] = request.params.top_k
             seeds[slot] = request.sample_seed
-            if unacked == 0:
+            if self._block > 1:
+                if given and steps:
+                    given_of[rid] = given
+                    ids[slot, :given] = (
+                        request.prompt_ids + request.output_ids
+                    )[-given:]
+                if request.params.unmask_threshold is not None:
+                    thresholds[slot] = request.params.unmask_threshold
+            elif unacked == 0:
                 ids[slot] = (
                     request.output_ids[-1]
                     if request.output_ids
@@ -4365,6 +4531,8 @@ class LLMEngine:
             window_fields = self._window_fields(
                 window_tables, window_live, window_freed, window_under
             )
+        if self._block > 1:
+            host_arrays.append(thresholds)  # never beside either
         if chunk_plan:
             chunk_arrays = self._build_chunk_arrays(chunk_plan)
             context_arrays.append(chunk_arrays[3])
@@ -4387,7 +4555,8 @@ class LLMEngine:
         ) = devs[:11]
         if window_blocks is not None:
             block_tables_dev = (block_tables_dev, devs[11])
-        if carried_ids is not None:
+        if carried_ids is not None and self._block == 1:
+            # (a block starts masked: nothing of the window before is read)
             ids_dev = self._merge_ids(carried_ids, override_dev, ids_dev)
         chunk_tokens = None
         moe_pairs = None
@@ -4442,6 +4611,7 @@ class LLMEngine:
                 min_p_dev,
                 top_k_dev,
                 seeds_dev,
+                *(devs[11:12] if self._block > 1 else ()),  # the thresholds
             )
         for _, rid, steps in plan:
             if steps:
@@ -4467,6 +4637,9 @@ class LLMEngine:
             'moe_pairs': moe_pairs,
             # With a windowed cache group: what its rows held of it.
             'window_fields': window_fields,
+            # Blocks of positions: rid -> the given tokens its rows of
+            # ``tokens`` start with (not emitted).
+            'given': given_of,
             # The step's span so far (admit/plan/put/dispatch), completed
             # with fetch and emit when _process_window syncs the tokens.
             'step': step,
@@ -4841,9 +5014,13 @@ class LLMEngine:
         tokens = np.asarray(window['tokens'])  # [K, B]
         moe_pairs = window.get('moe_pairs')
         counters = None  # a family's named counters in the pairs' place
+        decided_at = None  # per token, where blocks are decided together
         if isinstance(moe_pairs, dict):
             # distlint: disable=host-sync-in-hot-path -- a few int32 the window's program wrote with the tokens fetched one line up: ready, never waited for
-            counters = {name: np.asarray(n).tolist() for name, n in moe_pairs.items()}
+            counters = {name: np.asarray(n).tolist() for name, n in moe_pairs.items() if name != 'decided_at'}
+            if 'decided_at' in moe_pairs:
+                # distlint: disable=host-sync-in-hot-path -- written with the tokens fetched above: ready, never waited for
+                decided_at = np.asarray(moe_pairs['decided_at'])
             moe_pairs = None
         if moe_pairs is not None:
             # distlint: disable=host-sync-in-hot-path -- two int32 the window's program wrote with the tokens fetched one line up: ready, never waited for
@@ -4865,13 +5042,16 @@ class LLMEngine:
             request = self._requests[rid]
             if request.state is not RequestState.RUNNING:
                 continue  # preempted while idle; will re-prefill
-            for i in range(steps):
+            skip = window.get('given', {}).get(rid, 0)
+            for i in range(skip, skip + steps):
                 token = int(tokens[i, slot])
+                if decided_at is not None:
+                    request.decided_at.append(int(decided_at[i, slot]))
                 self._emit_token(request, token)
                 emitted.append((rid, token))
                 if rid not in self._requests:
-                    self._stats['overshoot_tokens'] += steps - i - 1
-                    _metrics.ENGINE_OVERSHOOT_TOKENS.inc(steps - i - 1)
+                    self._stats['overshoot_tokens'] += skip + steps - i - 1
+                    _metrics.ENGINE_OVERSHOOT_TOKENS.inc(skip + steps - i - 1)
                     break  # finished mid-window
         emitted.extend(self._process_chunk_entries(window))
         step.close()
@@ -4884,13 +5064,16 @@ class LLMEngine:
                 }
             if counters is not None:
                 extra = dict(counters)
+                if 'forwards' in counters:
+                    _metrics.DENOISE_FORWARDS.inc(counters['forwards'])
+                    _metrics.BLOCK_POSITIONS_DECIDED.inc(counters['decided'])
             if moe_pairs is not None:
                 extra = {
                     'moe_pairs': int(moe_pairs[0]),
                     'moe_pairs_held': int(moe_pairs[1]),
                 }
             if not chunk_entries:  # a decode window runs every slot's row
-                extra.update(self._moe_form_field(tokens.shape[1]))
+                extra.update(self._moe_form_field(tokens.shape[1] * self._block))
             extra.update(self._loop_fields)
             kv_blocks = self._kv_blocks(*window['context_lens'])
             if window.get('window_fields'):
@@ -5378,7 +5561,21 @@ class LLMEngine:
             if request.t_first_token else None,
             **self._state_slot_field(request),
             **self._kv_ends_field(request),
+            **self._blocks_field(request),
         )
+
+    def _blocks_field(self, request: Request) -> dict:
+        """Where blocks of positions are decided together: the blocks the
+        request's output took (its time to first token is its prefill plus
+        the first window of them) and, per output token, the denoise step
+        it was decided at."""
+        if self._block == 1:
+            return {}
+        given = len(request.prompt_ids) % self._block
+        return {
+            'blocks': -(-(given + len(request.output_ids)) // self._block),
+            'decided_at': list(request.decided_at),
+        }
 
     def _state_slot_field(self, request: Request) -> dict:
         """The slot of a hybrid's state pool a request held when it
@@ -5403,10 +5600,16 @@ class LLMEngine:
         if (
             self.window_kv is None and not self.cache_spec.latent
             and self.state_pool is None and self.cache_spec.passes == 1
+            and self._block == 1
         ):
             return {}
         row = self.sched.block_row(request.request_id)
         written = len(request.prompt_ids) + len(request.output_ids) - 1
+        if self._block > 1:
+            # Every token it emitted was committed with its block: the tail
+            # is the page of the last WHOLE block of the tokens it has (a
+            # last block cut by the budget has positions nobody was given).
+            written = (written + 1) // self._block * self._block
         if not row or written < 1:
             return {}
         tail = min((written - 1) // self.config.block_size, len(row) - 1)

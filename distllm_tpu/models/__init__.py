@@ -30,6 +30,7 @@ def decoder_families() -> dict:
         mistral,
         mixtral,
         ouro,
+        sdar,
         smallthinker,
         solar_open2,
     )
@@ -51,6 +52,7 @@ def decoder_families() -> dict:
         'solar_open2': (solar_open2.SolarOpen2Config, solar_open2),
         'ouro': (ouro.OuroConfig, ouro),
         'smallthinker': (smallthinker.SmallThinkerConfig, smallthinker),
+        'sdar_moe': (sdar.SdarConfig, sdar),
     }
 
 

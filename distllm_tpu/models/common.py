@@ -75,10 +75,9 @@ class CacheSpec:
     ``prefill`` (whole prompts, K/V scattered afterwards) may serve short
     fresh prompts; without it every prefill takes the paged route.
     ``layer_buffers``: a group's ``k_cache`` and ``v_cache`` are tuples of
-    one ``[num_blocks, block_size, N_kv * Hd]`` buffer a layer, which the
-    programs write in place, and not one ``[L, ...]`` array (which its
-    programs hand to the writers and the kernel whole, with the layer
-    whose pages are meant: ``ops.paged_attention``).
+    one ``[num_blocks, block_size, N_kv * Hd]`` buffer a layer, written in
+    place, and not one ``[L, ...]`` array (which goes to the writers and
+    the kernel whole, with the layer meant: ``ops.paged_attention``).
     """
     paged: tuple[PagedGroup, ...]
     programs: str
@@ -87,6 +86,7 @@ class CacheSpec:
     dense_prefill: bool = True
     layer_buffers: bool = False
     passes: int = 1  # runs of the stack a token, each with planes of its own
+    block: int = 1  # positions a sequence decides together (models/sdar.py)
 
     @property
     def windowed(self) -> tuple[PagedGroup, ...]:

@@ -46,6 +46,15 @@ ENGINE_OVERSHOOT_TOKENS = _registry.counter(
     'distllm_engine_overshoot_tokens_total',
     'Post-EOS tokens discarded by the pipelined one-window-late design.',
 )
+DENOISE_FORWARDS = _registry.counter(
+    'distllm_denoise_forwards_total',
+    'Forwards of a live row\'s block (denoise steps and the commit) in the '
+    'decode windows of a model that decides blocks of positions together.',
+)
+BLOCK_POSITIONS_DECIDED = _registry.counter(
+    'distllm_block_positions_decided_total',
+    'Positions decided in live rows\' blocks by those windows.',
+)
 ENGINE_PREFILL_BATCH = _registry.histogram(
     'distllm_engine_prefill_batch_size',
     'Requests per batched prefill dispatch (padding rows excluded).',

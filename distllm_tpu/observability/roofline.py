@@ -211,6 +211,8 @@ class CostModel:
         batch: int = 0,
         draft_tokens: int = 0,
         prefill_tokens: int = 0,
+        weight_passes: int | None = None,
+        positions: int | None = None,
     ) -> StepCost | None:
         """Cost of one recorded step, or ``None`` for kinds with no
         dispatch behind them (``request``/``preempt``/``event``).
@@ -219,12 +221,22 @@ class CostModel:
         - ``decode``/``mixed``: ``decode_steps`` weight passes (the fused
           scan re-reads the weights every step, frozen slots included);
           FLOPs cover generated tokens plus any ridden chunk positions.
+        - ``weight_passes`` and ``positions`` given (a ``decode`` window of
+          a model that decides blocks of positions): that many weight passes
+          and that many positions computed, whatever the tokens emitted.
         - ``spec``: ONE weight pass scoring every row's span —
           ``batch + draft_tokens`` positions (plus ridden chunks) — the
           whole speculative trade made visible: decode-scan bytes down by
           ``decode_steps``x, FLOPs up by the span width.
         """
         two_np = 2.0 * self.n_params
+        if weight_passes is not None:
+            # A window whose forwards outnumber or undercut its tokens (a
+            # model that decides blocks of positions together): a forward
+            # reads the weights once and computes its rows' positions.
+            return StepCost(
+                two_np * (positions or 0), self.weight_bytes * weight_passes
+            )
         if kind == 'prefill':
             return StepCost(two_np * tokens, self.weight_bytes)
         if kind in ('decode', 'mixed'):

@@ -366,7 +366,7 @@ def ragged_paged_attention_xla(  # distlint: traced
     scale: float | None = None,
     logit_softcap: float | None = None,
     value_lanes: int | None = None,
-    layer=None,
+    layer=None, block_length: int = 1,
 ) -> jnp.ndarray:
     """Ragged per-row-query-length attention over paged KV — the shared
     op of prefix-cache tail prefill, chunked prefill, mixed
@@ -426,7 +426,7 @@ def ragged_paged_attention_xla(  # distlint: traced
 
         scores = softcap(scores, logit_softcap)
     kv_pos = jnp.arange(max_blocks * block_size)[None, None, :]  # [1, 1, T]
-    qp = q_positions[:, :, None]  # [B, S, 1]
+    qp = _block_ceiling(q_positions, block_length)[:, :, None]  # [B, S, 1]
     valid = (kv_pos < context_lens[:, None, None]) & (kv_pos <= qp)
     if sliding_window is not None:
         # Same window semantics as the dense prefill mask: query at
@@ -523,7 +523,7 @@ def _ragged_paged_attn_kernel(
     scale: float,
     logit_softcap: float | None,
     quantized: bool = False,
-    value_lanes: int | None = None, walk: bool = False,
+    value_lanes: int | None = None, walk: bool = False, block_length: int = 1,
 ):
     """The SPAN schedule, grid (B, q_tiles, kv_chunks): one row × one query
     tile × one chunk of KV pages per step (a span of one: ``_walk_row``).
@@ -597,7 +597,7 @@ def _ragged_paged_attn_kernel(
     lo = jnp.where(win > 0, jnp.maximum(q0 + span_off - win + 1, 0), 0)
     hi = jnp.minimum(ctx, q0 + jnp.minimum(q_len, span_off + span_tile))
     tile_active = q_len > span_off
-
+    if block_length > 1: hi = jnp.minimum(ctx, _block_ceiling(q0 + jnp.minimum(q_len, span_off + span_tile) - 1, block_length) + 1)  # noqa: E701
     def chunk_needed(ci):
         start = ci * chunk_tokens
         return tile_active & (start < hi) & ((ci + 1) * chunk_tokens > lo)
@@ -700,9 +700,9 @@ def _ragged_paged_attn_kernel(
             jnp.int32, (1, chunk_tokens), 1
         )  # [1, C] absolute key positions
         valid = (kvp < ctx) & (kvp <= qp) & (span_idx < q_len)
-        # Sliding window: query at position p sees keys in (p - win, p];
-        # win <= 0 disables (gemma2 alternating layers ride a traced
-        # per-layer window where 0 means global).
+        if block_length > 1: valid = (kvp < ctx) & (kvp <= _block_ceiling(qp, block_length)) & (span_idx < q_len)  # noqa: E701
+        # Sliding window: a query at p sees keys in (p - win, p]; win <= 0
+        # disables (a traced per-layer window where 0 means global).
         valid = valid & ((kvp > qp - win) | (win <= 0))
 
         if quantized:
@@ -822,7 +822,7 @@ def ragged_paged_attention_pallas(
     span_tile: int | None = None,
     interpret: bool = False,
     value_lanes: int | None = None,
-    layer=None,
+    layer=None, block_length: int = 1,
 ) -> jnp.ndarray:
     """Fused Pallas TPU kernel twin of :func:`ragged_paged_attention_xla`.
 
@@ -987,7 +987,7 @@ def ragged_paged_attention_pallas(
             None if logit_softcap is None else float(logit_softcap)
         ),
         quantized=quantized,
-        value_lanes=value_lanes if latent else None, walk=walk,
+        value_lanes=value_lanes if latent else None, walk=walk, block_length=block_length,
     )
     kv_scratch = [
         pltpu.VMEM(
@@ -1063,7 +1063,7 @@ def ragged_paged_attention(
     *,
     backend: str = 'xla',
     value_lanes: int | None = None,
-    layer=None,
+    layer=None, block_length: int = 1,
 ) -> jnp.ndarray:
     """THE serving attention callsite: dispatch one ragged paged span
     batch through the selected backend.
@@ -1084,7 +1084,7 @@ def ragged_paged_attention(
             q, k_cache, v_cache, block_tables, context_lens, q_positions,
             q_lens=q_lens, sliding_window=sliding_window, scale=scale,
             logit_softcap=logit_softcap, interpret=backend == 'interpret',
-            value_lanes=value_lanes, layer=layer,
+            value_lanes=value_lanes, layer=layer, block_length=block_length,
         )
     if backend != 'xla':
         raise ValueError(
@@ -1095,7 +1095,7 @@ def ragged_paged_attention(
     return ragged_paged_attention_xla(
         q, k_cache, v_cache, block_tables, context_lens, q_positions,
         q_lens=q_lens, sliding_window=sliding_window, scale=scale,
-        logit_softcap=logit_softcap, value_lanes=value_lanes, layer=layer,
+        logit_softcap=logit_softcap, value_lanes=value_lanes, layer=layer, block_length=block_length,
     )
 
 
@@ -1916,3 +1916,19 @@ def _half_tile_heads(
         [out[:, :, :, 0, :, :head_dim], out[:, :, :, 1, :, head_dim:]], axis=3
     )
     return out.reshape(b, s, num_heads, head_dim)
+
+
+def _block_ceiling(positions, block_length: int):
+    """The last key a query at ``positions`` sees: itself under the causal
+    ceiling (``block_length`` 1, the positions as they came: nothing is
+    traced), else the last position of its block of ``block_length``, ``(p
+    // block_length + 1) * block_length - 1``: the BLOCK-CAUSAL mask of a
+    model that decides a block's positions together (``models/sdar.py``).
+    ``ragged_paged_attention`` and both its twins take ``block_length`` for
+    prefill spans (a static; the kernel's tile ceiling and its mask read it
+    in the lines they had, so a causal program lowers to the text it had); a
+    span of one and 64-wide heads keep the causal ceiling. Defined at the
+    file's end: no line above moves."""
+    if block_length == 1:
+        return positions
+    return (positions // block_length + 1) * block_length - 1

@@ -336,3 +336,83 @@ def verify_spans(  # distlint: traced
         jnp.cumprod(accept.astype(jnp.int32), axis=-1), axis=-1
     ).astype(jnp.int32)
     return jnp.concatenate([out, accept_len[:, None]], axis=-1)
+
+
+# --------------------------------------------- unmasking a block's positions
+# Generation by diffusion over blocks (``models/sdar.py``): a forward gives a
+# candidate and a confidence for every position of a row's block, and a rule
+# decides which masked positions take their candidate. At the file's end: no
+# line above moves.
+def sample_tokens_confidence(  # distlint: traced
+    logits: jnp.ndarray,  # [R, V] fp32: a row a POSITION
+    temperature: jnp.ndarray,  # [R]
+    top_p: jnp.ndarray,  # [R] (1.0 disables)
+    min_p: jnp.ndarray,  # [R] (0.0 disables)
+    top_window: int = 0,
+    top_k: jnp.ndarray | None = None,  # [R] int32 (0 disables)
+    row_keys: jax.Array | None = None,  # [R] keys from fold_row_keys
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """:func:`sample_tokens`' twin that also returns the probability the
+    kept token had: ``(tokens [R] int32, confidence [R] float32)``.
+
+    A sampled row (``temperature > 0``) draws from its filtered distribution
+    (:func:`filter_logits`: temperature, rank cap, top-p, min-p) and its
+    confidence is the drawn token's probability UNDER THAT distribution (the
+    kept set renormalised). A greedy row takes the ``argmax`` and its
+    confidence is that token's softmax probability over the whole vocabulary
+    at temperature 1: the filtered distribution of a greedy row is a point
+    mass, which would rank nothing. A batch with no sampled row neither
+    filters nor draws, as in :func:`sample_tokens`, whose bits stay its own.
+    """
+    logits = logits.astype(jnp.float32)
+    top = jnp.max(logits, axis=-1)
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    # softmax at the argmax: 1 / sum(exp(x - max))
+    greedy_conf = 1.0 / jnp.sum(jnp.exp(logits - top[:, None]), axis=-1)
+
+    def draw():
+        filtered = filter_logits(
+            logits, temperature, top_p, min_p, top_k=top_k,
+            top_window=top_window,
+        )
+        choice = jax.vmap(jax.random.categorical)(row_keys, filtered)
+        kept = jnp.take_along_axis(filtered, choice[:, None], axis=-1)[:, 0]
+        conf = jnp.exp(kept - jax.scipy.special.logsumexp(filtered, axis=-1))
+        sampled = temperature > 0
+        return (
+            jnp.where(sampled, choice.astype(jnp.int32), greedy),
+            jnp.where(sampled, conf, greedy_conf),
+        )
+
+    return jax.lax.cond(
+        jnp.any(temperature > 0), draw, lambda: (greedy, greedy_conf)
+    )
+
+
+def select_unmask(  # distlint: traced
+    confidence: jnp.ndarray,  # [R, B] float32
+    masked: jnp.ndarray,  # [R, B] bool: positions still undecided
+    count,  # int or int32 scalar: positions the schedule decides this step
+    threshold: jnp.ndarray | None = None,  # [R] float32, or None
+) -> jnp.ndarray:
+    """The positions a denoise step decides, ``[R, B]`` bool: among a row's
+    masked positions the ``count`` of highest confidence, ties to the lower
+    position (``low_confidence_static``); with a ``threshold`` every masked
+    position whose confidence is OVER it where those are more than ``count``
+    (``low_confidence_dynamic``; a threshold of 1.0 or more never is). A
+    row with fewer masked positions than ``count`` decides them all. A
+    block is a handful of positions: the ranks are the pairwise
+    comparisons, no sort."""
+    c = jnp.where(masked, confidence, -jnp.inf)
+    idx = jnp.arange(c.shape[-1])
+    ahead = (c[:, None, :] > c[:, :, None]) | (
+        (c[:, None, :] == c[:, :, None]) & (idx[None, :] < idx[:, None])[None]
+    )  # [R, i, j]: j goes before i
+    rank = jnp.sum(ahead & masked[:, None, :], axis=-1)
+    chosen = masked & (rank < count)
+    if threshold is None:
+        return chosen
+    over = masked & (confidence > threshold[:, None])
+    return jnp.where(
+        (jnp.sum(over, axis=-1) > count)[:, None], over, chosen
+    )

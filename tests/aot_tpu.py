@@ -949,3 +949,53 @@ def smallthinker_window(v5e, smallthinker_cell):
         (v5e((b, 1024), i32),) * 2, v5e((b,), i32), v5e((b,), f32),
         v5e((b,), f32), v5e((b,), f32), v5e((b,), i32), v5e((b,), jnp.uint32),
     ).compile()
+
+
+@pytest.fixture(scope='module')
+def sdar_cell(v5e):
+    """The sdar cell's configuration at its own depth (all 48 layers, 16 of
+    128 experts a layer), the parameters and the stacked pool at the cell's
+    2560 blocks."""
+    import json
+    from pathlib import Path
+
+    from distllm_tpu.models import sdar
+
+    root = Path(__file__).resolve().parents[1]
+    hf = json.loads(
+        (root / 'benchmarks/configs/sdar-30b-a3b-chat.json').read_text()
+    )
+    cfg = sdar.SdarConfig.from_hf_config(hf)
+    shapes = jax.eval_shape(
+        lambda: sdar.init_on_device(jax.random.PRNGKey(0), cfg)
+    )
+    params = jax.tree.map(lambda a: v5e(a.shape, a.dtype), shapes)
+    pool = (
+        cfg.num_layers, hf['engine']['num_blocks'], 16,
+        cfg.num_kv_heads * cfg.head_dim,
+    )
+    return sdar, cfg, params, v5e(pool, jnp.bfloat16), pool, hf['engine']
+
+
+@pytest.fixture(scope='module')
+def sdar_window(v5e, sdar_cell):
+    """The block window at the cell's 48 rows and depth (two blocks of 4
+    positions, 4 denoise forwards and a commit each), compiled once."""
+    sdar, cfg, params, pool, _, engine = sdar_cell
+    b, i32, f32 = engine['max_num_seqs'], jnp.int32, jnp.float32
+
+    def window_fn(params, ids, pos, ctx, k, v, bt, steps_left, *rest):
+        return sdar.decode_loop(
+            params, cfg, ids, pos, k, v, bt, ctx, steps_left, *rest[:5],
+            num_steps=engine['decode_steps'], attn_backend='pallas',
+            max_table_positions=engine['max_model_len'],
+            denoise_steps=engine['denoise_steps'], unmask_threshold=rest[5],
+        )
+
+    tables = engine['max_model_len'] // engine['block_size']
+    return jax.jit(window_fn, donate_argnums=(4, 5)).lower(
+        params, v5e((b, cfg.block_length), i32), v5e((b,), i32),
+        v5e((b,), i32), pool, pool, v5e((b, tables), i32), v5e((b,), i32),
+        v5e((b,), f32), v5e((b,), f32), v5e((b,), f32), v5e((b,), i32),
+        v5e((b,), jnp.uint32), v5e((b,), f32),
+    ).compile()

@@ -11,6 +11,7 @@ from aot_tpu import (  # noqa: F401 -- fixtures, asked for by name
     kanana_cell,
     laguna_cell,
     ouro_cell,
+    sdar_cell,
     smallthinker_cell,
     v5e,
 )
@@ -243,3 +244,25 @@ def test_smallthinker_chunk_prefill_reads_the_pools_as_they_lie(v5e, smallthinke
     ).compile()
     _assert_pools_go_to_the_kernel_as_they_lie(compiled, buffers)
     _assert_span_calls_keep_the_grid(compiled)
+
+
+def test_sdar_prefill_span_is_block_causal_on_the_grid(v5e, sdar_cell):
+    """The ``(512, 4)`` program at the cell's depth under the span
+    schedule's ``block_length`` 4: the stacked pool addressed, never sliced,
+    and every kernel call the grid."""
+    sdar, cfg, params, pool, shape, engine = sdar_cell
+    i32 = jnp.int32
+    tables = engine['max_model_len'] // engine['block_size']
+    compiled = jax.jit(
+        lambda params, ids, pos, k, v, bt, ctx, tails: sdar.prefill_paged(
+            params, cfg, ids, pos, k, v, bt, ctx, tails,
+            max_table_positions=engine['max_model_len'], attn_backend='pallas',
+        ), donate_argnums=(3, 4),
+    ).lower(
+        params, v5e((4, 512), i32), v5e((4, 512), i32), pool, pool,
+        v5e((4, tables), i32), v5e((4,), i32), v5e((4,), i32),
+    ).compile()
+    _assert_stacked_pool_is_addressed(compiled, shape)
+    _assert_span_calls_keep_the_grid(compiled)
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.3 * 2**30

@@ -535,3 +535,40 @@ def test_ragged_kernel_compiles_at_7_queries_a_head(v5e, rows, span, window):
     ).compile()
     _assert_kernel_compiled(compiled)
     assert _kernel_schedules(compiled) == ['walk' if span == 1 else 'grid']
+
+
+# ---- sdar (PR 54): a block of 4 positions folded into a group of 8 through
+# the row walk, and prefill spans under a block-causal ceiling ----
+
+@pytest.mark.parametrize('rows, span, heads, block_length', [
+    (48, 1, 128, 1), (4, 512, 32, 4), (4, 512, 32, 1),
+], ids=['walk_32_a_head', 'span512_block4', 'span512_causal'])
+def test_ragged_kernel_compiles_for_blocks_of_positions(
+    v5e, rows, span, heads, block_length
+):
+    """Over the sdar cell's stacked pool of 48 layers: the decode walk at 32
+    queries a KV head (128 query rows on 4 KV heads: the per-head block), the
+    span schedule's tile of 64 positions x 8 under ``block_length`` 4; and
+    ``block_length`` 1 lowers to the text a call that never names it does."""
+    from distllm_tpu.ops.paged_attention import ragged_paged_attention_pallas
+
+    pool = v5e((48, 2560, 16, 4 * 128), jnp.bfloat16)
+    shapes = (
+        v5e((rows, span, heads, 128), jnp.bfloat16), pool, pool,
+        v5e((rows, 128), jnp.int32), v5e((rows,), jnp.int32),
+        v5e((rows, span), jnp.int32), v5e((rows,), jnp.int32),
+    )
+
+    def call(**kw):
+        return jax.jit(
+            lambda q, k, v, bt, ctx, pos, ql: ragged_paged_attention_pallas(
+                q, k, v, bt, ctx, pos, q_lens=ql, layer=jnp.int32(7), **kw
+            )
+        ).lower(*shapes)
+
+    # Both from ONE line: a Mosaic body carries its callers' locations.
+    lowered, plain = [call(**kw) for kw in ({'block_length': block_length}, {})]
+    compiled = lowered.compile()
+    _assert_kernel_compiled(compiled)
+    assert _kernel_schedules(compiled) == ['walk' if span == 1 else 'grid']
+    assert (lowered.as_text() == plain.as_text()) == (block_length == 1)

@@ -18,6 +18,8 @@ from aot_tpu import (  # noqa: F401 -- fixtures, asked for by name
     lfm2_window,
     ouro_cell,
     ouro_window,
+    sdar_cell,
+    sdar_window,
     smallthinker_cell,
     smallthinker_window,
     solar_open2_window,
@@ -319,3 +321,15 @@ def test_smallthinker_decode_window_reads_the_pools_as_they_lie(
     _assert_decode_calls_walk(smallthinker_window)
     memory = smallthinker_window.memory_analysis()
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14.5 * 2**30
+
+
+def test_sdar_block_window_reads_the_pool_as_it_lies(sdar_cell, sdar_window):
+    """The block window at all 48 layers: no pool-sized result but the
+    scatters, every attention call the row walk (a block folded into the
+    group: 32 queries a KV head), no layer's bank copied out of its stack,
+    and the program fits the chip beside weights and pool."""
+    _, _, params, _, pool, _ = sdar_cell
+    _assert_stacked_pool_is_addressed(sdar_window, pool)
+    _assert_decode_calls_walk(sdar_window)
+    memory = sdar_window.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.3 * 2**30

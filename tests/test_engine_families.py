@@ -20,8 +20,8 @@ module for each (family, settings); cases that fill or starve one build
 their own.
 
 Under ``--dist loadfile`` a file is one worker's chain, so the suite runs as
-three: this file over ``FAMILIES``, and ``test_engine_families_2.py`` and
-``_3.py``, which import every case from here and name other families
+four: this file over ``FAMILIES``, and ``test_engine_families_2.py``,
+``_3.py`` and ``_4.py``, which import every case from here and name other families
 (``pytest_generate_tests`` reads the collecting module's ``FAMILIES``). A new
 family's name goes where the chain is shortest (a chain none of whose
 families lists a case reports that case once, as skipped).
@@ -42,7 +42,8 @@ from distllm_tpu.generate.engine.engine import (
 FAMILIES = ('granite', 'ouro')
 TOYS = {
     name: importlib.import_module(f'{name}_toy') for name in
-    ('granite', 'lfm2', 'falcon_h1', 'solar_open2', 'ouro', 'smallthinker')
+    ('granite', 'lfm2', 'falcon_h1', 'solar_open2', 'ouro', 'smallthinker',
+     'sdar')
 }
 GREEDY = dict(temperature=0.0)
 REFUSED = {

@@ -16,6 +16,7 @@ import lfm2_toy
 import numpy as np
 import ouro_toy
 import pytest
+import sdar_toy
 import smallthinker_toy
 import solar_open2_toy
 
@@ -47,12 +48,14 @@ _PARENT_BITS = {
     'ouro': '36c7c9240d95edc82dbf050dad7277afc3eff4524b7aaed06783d1a13fbe8fd3',
     # likewise (PR 52): two attention trees and the expert tree of every layer
     'smallthinker': '0bf2f299e74957cf2880c00ce10b7d639da3f6878242ae2e8c5a26fafe22a4fa',
+    # likewise (PR 54): one tree of layers with the heads' q and k norms
+    'sdar': 'a61c0217f4e6d51eef47e085f5cd5ec12d6418f771a07af2a2a6c45110227a6e',
 }
 _TOYS = {
     'granite': granite_toy, 'laguna': laguna_toy, 'deepseek_v3': deepseek_toy,
     'lfm2': lfm2_toy, 'falcon_h1': falcon_h1_toy,
     'solar_open2': solar_open2_toy, 'ouro': ouro_toy,
-    'smallthinker': smallthinker_toy,
+    'smallthinker': smallthinker_toy, 'sdar': sdar_toy,
 }
 
 

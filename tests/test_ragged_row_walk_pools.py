@@ -5,6 +5,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from distllm_tpu.ops.paged_attention import (
     ragged_paged_attention_pallas,
@@ -106,3 +107,70 @@ def test_row_walk_parity_by_queries_a_head(rng, nh, nkv, hd, setup):
         interpret=True, **kwargs,
     )
     _assert_walk_parity(out, ref, q_lens)
+
+
+# ---- sdar (PR 54): a block-causal ceiling for prefill spans, and a block of
+# 4 positions folded into a group of 8 through the row walk ----
+
+@pytest.mark.parametrize('block_length', [1, 4])
+@pytest.mark.parametrize('span, start', [(16, 0), (64, 32), (24, 8)])
+def test_span_parity_under_a_block_causal_ceiling(rng, block_length, span, start):
+    """A prefill span under ``block_length``: a query at ``p`` sees the keys
+    before the end of its block, ``(p // B + 1) * B``, in the kernel's tile
+    ceiling and its mask as in the XLA twin; 1 is the causal ceiling. Spans
+    of whole blocks behind cached whole blocks (what ``models/sdar.py``
+    prefills), several query tiles, and rows padded past their length."""
+    nh, nkv, hd, block = 8, 2, 128, 16
+    lens = (span, span - 8, 8)
+    tables = [-(-(start + span) // block)] * len(lens)
+    num_blocks = 1 + sum(tables)
+    k, v = (
+        jnp.asarray(rng.normal(size=(num_blocks, block, nkv * hd)), jnp.float32)
+        for _ in range(2)
+    )
+    bt = (1 + np.arange(num_blocks - 1, dtype=np.int32)).reshape(len(lens), -1)
+    ctx = jnp.asarray([start + n for n in lens], jnp.int32)
+    pos = jnp.broadcast_to(start + jnp.arange(span)[None], (len(lens), span))
+    q = jnp.asarray(rng.normal(size=(len(lens), span, nh, hd)), jnp.float32)
+    q_lens = jnp.asarray(lens, jnp.int32)
+    args = (q, k, v, jnp.asarray(bt), ctx, pos)
+    ref = ragged_paged_attention_xla(*args, q_lens=q_lens, block_length=block_length)
+    out = ragged_paged_attention_pallas(
+        *args, q_lens=q_lens, block_length=block_length, interpret=True,
+        span_tile=16,
+    )
+    for row, n in enumerate(lens):  # pad queries: zeros here, key 0 there
+        np.testing.assert_allclose(
+            np.asarray(out)[row, :n], np.asarray(ref)[row, :n], atol=2e-5, rtol=1e-4
+        )
+    causal = ragged_paged_attention_xla(*args, q_lens=q_lens)
+    differs = np.abs(np.asarray(causal) - np.asarray(ref))[0].max(axis=(1, 2))
+    if block_length == 1:
+        assert differs.max() == 0.0
+    else:  # a block's last query sees what the causal one does; its first more
+        assert differs[block_length - 1::block_length].max() < 1e-6
+        assert differs[0::block_length].min() > 1e-4
+
+
+def test_row_walk_parity_at_32_queries_a_head(rng):
+    """A block of 4 positions folded into a group of 8 (``models/sdar.py``'s
+    denoise forward): 128 query rows on 4 KV heads of 128, every row seeing
+    its whole context: the walk's per-head block over 32 rows."""
+    nh, nkv, hd, block = 128, 4, 128, 16
+    contexts = (20, 515, 1030, 64)
+    tables = [-(-c // block) for c in contexts]
+    num_blocks = 1 + sum(tables)
+    k, v = (
+        jnp.asarray(rng.normal(size=(num_blocks, block, nkv * hd)), jnp.float32)
+        for _ in range(2)
+    )
+    bt = np.zeros((len(contexts), max(tables)), np.int32)
+    ids = rng.permutation(num_blocks - 1) + 1
+    for row, n in enumerate(tables):
+        bt[row, :n], ids = ids[:n], ids[n:]
+    ctx = jnp.asarray(contexts, jnp.int32)
+    q = jnp.asarray(rng.normal(size=(len(contexts), 1, nh, hd)), jnp.float32)
+    args = (q, k, v, jnp.asarray(bt), ctx, ctx[:, None] - 1)
+    ref = ragged_paged_attention_xla(*args)
+    out = ragged_paged_attention_pallas(*args, interpret=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=1e-4)
