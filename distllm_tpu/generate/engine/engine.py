@@ -80,6 +80,7 @@ from distllm_tpu.ops.paged_attention import (
     fold_heads,
     quantize_kv_rows,
     unfold_heads,
+    walk_block_form,
     walk_keys_a_step,
 )
 from distllm_tpu.ops.sampling import fold_row_keys, sample_tokens
@@ -1335,6 +1336,27 @@ class LLMEngine:
                 for group, kv in zip(spec.paged, (self.kv, self.window_kv))
             }
             self.telemetry['kv_walk_keys'] = dict(self._walk_keys)
+        # The form of the walk's softmax block in each group's decode calls
+        # (``ops.paged_attention.walk_block``, the rule the kernel traces
+        # with: KV heads and queries a head, a block of positions folded
+        # in); ``walk_block*`` on the decode records, named as ``kv_chunks*``.
+        self._walk_block_fields = {}
+        if self._walk_keys:
+            heads = model_cfg.num_heads  # a count, or one a group's name
+            forms = {
+                group.name: walk_block_form(
+                    (heads(group.name) if callable(heads) else heads)
+                    * spec.block,
+                    group.stored_row or model_cfg.head_size,
+                    kv.pool_shape[-1],
+                )
+                for group, kv in zip(spec.paged, (self.kv, self.window_kv))
+            }
+            self.telemetry['walk_block'] = forms
+            self._walk_block_fields = {
+                self._group_field('walk_block', group): forms[group.name]
+                for group in spec.paged
+            }
         # The form of the routed experts' matmuls in each program a
         # dispatch can run (``models.moe.expert_form``, the rule the
         # programs themselves trace with: static shapes alone). A config
@@ -3998,6 +4020,13 @@ class LLMEngine:
         bs = self.config.block_size
         return sum(int(((c + (bs - 1)) // bs).sum()) for c in context_lens)
 
+    def _group_field(self, stem: str, group) -> str:
+        """A step record's field of one cache group: ``stem`` where a model
+        has one block table, ``<stem>_full`` / ``<stem>_window`` with two."""
+        if len(self.cache_spec.paged) == 1:
+            return stem
+        return f'{stem}_window' if group.window else f'{stem}_full'
+
     def _kv_chunks(self, context_lens: np.ndarray) -> dict:
         """``kv_chunks*``: the chunks the paged kernel's row walk fetches
         for a decode dispatch's rows in one step of one layer of each cache
@@ -4016,10 +4045,7 @@ class LLMEngine:
             floor = 0
             if group.window is not None:
                 floor = np.maximum(context_lens - group.window, 0)
-            name = 'kv_chunks' if len(groups) == 1 else (
-                'kv_chunks_window' if group.window else 'kv_chunks_full'
-            )
-            fields[name] = int(
+            fields[self._group_field('kv_chunks', group)] = int(
                 ((context_lens + keys - 1) // keys - floor // keys).sum()
             )
         if len(groups) > 1:
@@ -5080,6 +5106,7 @@ class LLMEngine:
                 extra.update(window['window_fields'], kv_blocks_full=kv_blocks)
             if not chunk_entries:  # a mixed window's rows ride a span program
                 extra.update(self._kv_chunks(window['context_lens'][0]))
+                extra.update(self._walk_block_fields)
             self._record_step(
                 'mixed' if chunk_entries else 'decode',
                 step,

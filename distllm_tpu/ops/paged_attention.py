@@ -950,11 +950,11 @@ def ragged_paged_attention_pallas(
     )
     # The caches go to the kernel as they lie, head-folded [nb, bs, Nkv*Hd]
     # (a stacked pool as one run of pages, never a slice): a head is a
-    # 128-aligned lane band. The walk at under a sublane tile of queries a
-    # KV head takes them, and its softmax state, STACKED (_walk_stacks).
+    # 128-aligned lane band. Where walk_block says so the walk takes its
+    # softmax state STACKED, and the queries too unless a head's are tiles.
     state = (num_kv_heads, span_tile * group)  # [.., rows] of q, acc, m, l
-    if walk and _walk_stacks(group):  # ONE block of Nkv * group rows
-        state, qg = (1, num_kv_heads * group), qg.reshape(b, 1, -1, head_dim)
+    if walk and walk_block(num_kv_heads, group)[0] == 'stacked':  # ONE block of Nkv * group rows
+        state, qg = (1, num_kv_heads * group), qg.reshape(b, 1, -1, head_dim) if group < 8 else qg
     extra_operands = []
     if quantized:
         if num_kv_heads > 128:
@@ -1006,7 +1006,7 @@ def ragged_paged_attention_pallas(
         grid=(b, num_q_tiles, num_chunks),
         in_specs=[
             pl.BlockSpec(
-                (None, *state, head_dim),
+                (None, *qg.shape[1:3], head_dim) if walk else (None, *state, head_dim),
                 lambda i, qi, j, *_: (i, 0, qi, 0),
             ),
         ] + [pl.BlockSpec(memory_space=pl.ANY)] * (
@@ -1147,13 +1147,16 @@ def _kernel_refs(refs, latent, quantized, walk) -> _KernelRefs:
 # the chip's 2 KB for them). Within a chunk the walk's unit is a TURN of
 # WALK_PAGES_A_TURN pages: its copies go a turn at a time, and the stacked
 # form of its softmax block a FOLD of WALK_TURNS_A_FOLD turns (512 keys at
-# blocks of 16). PERF.md section 6 has the sweeps on the chip behind the
-# four (PR 38) and behind the turn and the fold (PR 49).
+# blocks of 16), or the whole chunk where the heads' stacked rows are at
+# most WALK_WHOLE_CHUNK_ROWS (:func:`walk_block`). PERF.md section 6 has
+# the sweeps on the chip behind the four (PR 38), behind the turn and the
+# fold (PR 49) and behind the fold from 8 queries a head up (PR 55).
 WALK_MAX_KEYS = 1024
 WALK_BUFFER_BYTES = 8 << 20
 WALK_SEMAPHORES = 256
 WALK_PAGES_A_TURN = 8
 WALK_TURNS_A_FOLD = 4
+WALK_WHOLE_CHUNK_ROWS = 32
 
 
 def walk_keys_a_step(
@@ -1184,19 +1187,78 @@ def walk_pages_a_turn(pages_per_chunk: int) -> int:
     return min(pages_per_chunk & -pages_per_chunk, WALK_PAGES_A_TURN)
 
 
-def _walk_stacks(group: int) -> bool:
-    """Whether the row walk's softmax block takes the KV heads' query rows
-    STACKED into one array, a fold of the chunk at a time
-    (:func:`_stacked_block`): where a KV head has fewer queries than a
-    sublane tile has rows. There the softmax of a head by itself runs on
-    vector registers that are mostly padding and pays its reductions and
-    its two dependent matmuls' latency once a head, so that a narrower
-    block only multiplies what a block costs (PERF.md section 6, PR 49: by
-    turns of 128 keys the per-head block took 1.1 to 3 times the whole
-    chunk's time); stacked, that cost is paid once a fold for all heads.
-    From 8 queries a head up the registers are full as they are and the
-    per-head block over the whole chunk stays."""
-    return group < 8
+def walk_block(heads: int, group: int) -> 'tuple[str, int | None]':
+    """The form of the row walk's softmax block and the turns it folds at a
+    time, from a call's static shapes alone: its KV heads (128-lane bands of
+    a pool row: two 64-wide heads are one) and the queries a KV head (a
+    block of positions folded in, as ``models/sdar.py`` does, counts). Not
+    a setting and not a family's name. ``('per_head', None)``: the span
+    schedule's ``compute``, a KV head at a time over the whole chunk.
+    ``('stacked', turns)``: ONE softmax for all heads' rows
+    (:func:`_stacked_block`), ``turns`` turns of 8 pages a fold (None: the
+    whole chunk), over the folds that hold a key the row sees.
+
+    The cost model. A softmax block pays two lane reductions, a rescale of
+    the accumulator and the latency of two dependent matmuls whatever its
+    width, so what counts is how often a row pays them: per head it is
+    ``heads`` times a chunk, stacked once a fold. The vector unit's work on
+    the scores is the same either way (``heads x group x keys`` elements),
+    and so is the matrix unit's, provided a head's products take its rows
+    alone:
+
+    * under 8 queries a head (a head's rows are no whole sublane tile) a
+      head's scores come from ALL stacked rows, the others zeroed, summed
+      over the heads: ``heads`` times the useful products, on a matrix unit
+      that the few rows leave idle anyway. 4 turns a fold (PR 49's sweep:
+      ``mistral7b`` 0.104 / 0.095 / 0.093 / 0.104 ms at 1 / 2 / 4 / 8).
+    * from 8 up, where the queries a head are whole sublane tiles of 8 and
+      there is more than one head, a head's scores are ITS rows against its
+      band and the heads' arrays are laid one under the other (whole tiles:
+      no data moves). Summed over zeroed rows instead the stacked form LOST
+      there (``sdar`` 0.168 ms for the per-head block's 0.166).
+    * one head (a latent plane) has nothing to stack, and narrower folds
+      only multiply the fixed cost (``kanana`` 0.372 per head, 0.377-0.711
+      by folds); queries a head that are no whole tiles (none in a cell)
+      keep the per-head block unmeasured.
+
+    The fold: 4 turns (512 keys) let a row skip the half of a chunk below
+    its window's floor or past its context's end; the whole chunk pays the
+    fixed costs once. Up to ``WALK_WHOLE_CHUNK_ROWS`` stacked rows (a ``[32,
+    1024]`` float32 score array is half the vector registers) the whole
+    chunk is faster; above, the two are level where rows see whole chunks
+    and 4 turns win under a window. Kernel alone on one v5e chip, ms a call,
+    per head / stacked at 1 / 2 / 4 / 8 turns (PERF.md section 6, PR 55):
+
+    ==============================  ========  =====  =====  =========  =========
+    shape (rows, heads x queries)   per head  1      2      4          8
+    ==============================  ========  =====  =====  =========  =========
+    ``lfm2`` (96, 4 x 8)            1.283     1.700  1.310  1.117      **1.034**
+    ``laguna`` window (48, 8 x 8)   0.300     0.212  0.182  **0.175**  0.197
+    ``solar`` (128, 8 x 8)          4.017     4.438  3.770  **3.719**  3.711
+    ``sdar`` (48, 4 x 32)           0.166     0.180  0.150  **0.134**  0.132
+    ==============================  ========  =====  =====  =========  =========
+
+    Some of a block's heads stacked (2 or 4 of 8) lost to all of them at
+    every shape."""
+    if group < 8:
+        return 'stacked', WALK_TURNS_A_FOLD
+    if heads == 1 or group % 8:
+        return 'per_head', None
+    if heads * group <= WALK_WHOLE_CHUNK_ROWS:
+        return 'stacked', None
+    return 'stacked', WALK_TURNS_A_FOLD
+
+
+def walk_block_form(query_heads: int, head_dim: int, row_lanes: int) -> str:
+    """:func:`walk_block`'s form (``'stacked'`` or ``'per_head'``) for a
+    decode call of ``query_heads`` queries a row (a block of positions
+    folded in counts) over a pool whose rows are ``row_lanes`` wide, as the
+    kernel's wrapper reckons its heads: a latent plane's row is ONE head
+    (``head_dim`` is the row), two 64-wide heads share a 128-lane band. For
+    the engine's telemetry."""
+    band = 128 if _pairs_heads(head_dim, row_lanes) else head_dim
+    heads = row_lanes // band
+    return walk_block(heads, query_heads // heads)[0]
 
 
 def _default_keys_a_step(k_data, latent, walk) -> int:
@@ -1215,36 +1277,42 @@ def _div(x, by):
 
 
 def _stacked_block(r: _KernelRefs, p_lo, p_hi, block_size, pages_per_chunk,
-                   group, scale, logit_softcap, value_lanes, quantized):
+                   group, turns, scale, logit_softcap, value_lanes, quantized):
     """The row walk's online-softmax block over the KV heads' query rows
-    STACKED (:func:`_walk_stacks`): ``block(slot, chunk)`` folds into
+    STACKED (:func:`walk_block`): ``block(slot, chunk)`` folds into
     ``r.acc[0]``, ``r.m[0]``, ``r.l[0]`` (``[heads x group, ..]``) the
-    FOLDS of ``chunk`` (``WALK_TURNS_A_FOLD`` turns each, or the most that
-    divides a chunk's turns) that hold a page of ``[p_lo, p_hi)``, what the
-    grid step's row sees.
+    FOLDS of ``chunk`` (``turns`` turns each, or the most that divides a
+    chunk's turns; None: the whole chunk) that hold a page of ``[p_lo,
+    p_hi)``, what the grid step's row sees.
 
-    A head's scores come from ITS rows of the stacked queries (the others
-    zeroed) against its lane band of the keys, summed over the heads into
-    one ``[R, fold_keys]`` array: one mask, one pair of lane reductions and
-    one rescale a fold for all heads. A head's output rows are kept of the
-    probabilities against its band of the values. The mathematics is the
-    span schedule's ``compute``: float32 scores, maxima, sums and
-    accumulator, probabilities cast to the pages' dtype for the second
-    product, an int8 page's scale on its columns.
+    The heads' scores make one ``[R, fold_keys]`` array: one mask, one pair
+    of lane reductions and one rescale a fold for all heads. Where the
+    queries arrive a head (``r.q`` is ``[heads, group, Hd]``: a head's rows
+    are whole sublane tiles) a head's scores are its rows against its lane
+    band of the keys, the heads' arrays laid one under the other, and its
+    output its rows of the probabilities against its band of the values.
+    Where they arrive stacked (``[1, R, Hd]``: under 8 queries a head) a
+    head's scores come from ITS rows of the stacked queries (the others
+    zeroed), summed over the heads, and its output rows are kept of ALL
+    probabilities against its band. The mathematics is the span schedule's
+    ``compute``: float32 scores, maxima, sums and accumulator, probabilities
+    cast to the pages' dtype for the second product, an int8 page's scale
+    on its columns.
     """
     import jax.experimental.pallas as pl
 
     seq, win = pl.program_id(0), r.window[0]
     head_dim = r.q.shape[-1]
     heads = r.k_buf.shape[-1] // head_dim
+    a_head = r.q.shape[0] > 1  # the queries arrive a head, not stacked
     turn_pages = walk_pages_a_turn(pages_per_chunk)
-    fold_pages = turn_pages * max(  # whole folds a chunk
-        n for n in range(1, WALK_TURNS_A_FOLD + 1)
+    fold_pages = pages_per_chunk if turns is None else turn_pages * max(
+        n for n in range(1, turns + 1)  # whole folds a chunk
         if pages_per_chunk // turn_pages % n == 0
     )
     fold_keys = fold_pages * block_size
     row_head = _div(
-        jax.lax.broadcasted_iota(jnp.int32, (r.q.shape[1], 1), 0), group
+        jax.lax.broadcasted_iota(jnp.int32, (r.acc.shape[1], 1), 0), group
     )  # [R, 1]: the KV head a stacked row belongs to
     column = jax.lax.broadcasted_iota(jnp.int32, (1, fold_keys), 1)
 
@@ -1263,21 +1331,29 @@ def _stacked_block(r: _KernelRefs, p_lo, p_hi, block_size, pages_per_chunk,
         kvp = chunk * (pages_per_chunk * block_size) + t * fold_keys + column
         valid = (kvp < r.context_lens[seq]) & (kvp <= q0)
         valid = valid & ((kvp > q0 - win) | (win <= 0))
-        q = r.q[0]  # [R, Hd]
-        scores = None
+        q = None if a_head else r.q[0]  # [R, Hd]
+        scores, parts = None, []
         for h in range(heads):  # static unroll over KV heads
             kh = r.k_buf[slot, keys, h * head_dim:(h + 1) * head_dim]
-            own = q if heads == 1 else jnp.where(
-                row_head == h, q, jnp.zeros_like(q)
-            )
+            if a_head:
+                own = r.q[h]  # [group, Hd]
+            else:
+                own = q if heads == 1 else jnp.where(
+                    row_head == h, q, jnp.zeros_like(q)
+                )
             part = jax.lax.dot_general(
-                own, kh.astype(q.dtype),
+                own, kh.astype(own.dtype),
                 dimension_numbers=(((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )  # [R, C], zero outside head h's rows
+            )  # [group, C], or [R, C] that is zero outside head h's rows
             if quantized:
                 part = part * page_scales(r.ks_buf, slot, t * fold_pages, h)
-            scores = part if scores is None else scores + part
+            if a_head:
+                parts.append(part)
+            else:
+                scores = part if scores is None else scores + part
+        if a_head:
+            scores = jnp.concatenate(parts, axis=0)
         scores = scores * scale
         if logit_softcap is not None:
             cap = jnp.float32(logit_softcap)
@@ -1289,24 +1365,29 @@ def _stacked_block(r: _KernelRefs, p_lo, p_hi, block_size, pages_per_chunk,
         correction = jnp.exp(m_prev - new_m)  # m_prev=-inf -> 0
         probs = jnp.exp(scores - new_m[:, :1])  # masked lanes -> 0
         r.l[0] = r.l[0] * correction + jnp.sum(probs, axis=-1, keepdims=True)
-        out = None
+        out, parts = None, []
         for h in range(heads):
             if value_lanes is not None:  # the values: the band's first lanes
                 vh = r.k_buf[slot, keys, h * head_dim:h * head_dim + value_lanes]
             else:
                 vh = r.v_buf[slot, keys, h * head_dim:(h + 1) * head_dim]
-            weights = probs
+            weights = probs[h * group:(h + 1) * group] if a_head else probs
             if quantized:
-                weights = probs * page_scales(r.vs_buf, slot, t * fold_pages, h)
-                vh = vh.astype(q.dtype)
+                weights = weights * page_scales(r.vs_buf, slot, t * fold_pages, h)
+                vh = vh.astype(r.q.dtype)
             part = jax.lax.dot_general(
                 weights.astype(vh.dtype), vh,
                 dimension_numbers=(((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )  # [R, Hd]: head h's rows are its output
-            if heads > 1:
-                part = jnp.where(row_head == h, part, 0.0)
-            out = part if out is None else out + part
+            )  # [group, Hd], or [R, Hd] of which head h's rows are its output
+            if a_head:
+                parts.append(part)
+            else:
+                if heads > 1:
+                    part = jnp.where(row_head == h, part, 0.0)
+                out = part if out is None else out + part
+        if a_head:
+            out = jnp.concatenate(parts, axis=0)
         r.acc[0] = r.acc[0] * correction[:, :1] + out
         r.m[0] = new_m
 
@@ -1351,16 +1432,17 @@ def _walk_row(r: _KernelRefs, compute, block_size, pages_per_chunk, group,
     last, the first chunk of the next row that has one. Only the call's
     first chunk is waited for with nothing behind it.
 
-    The online-softmax block has two forms, chosen by :func:`_walk_stacks`
-    from the queries a KV head. Stacked (``r.q``, ``r.acc``, ``r.m``,
-    ``r.l`` hold ONE block of ``heads x group`` rows):
+    The online-softmax block has two forms, chosen by :func:`walk_block`
+    from the KV heads and the queries a KV head. Stacked (``r.acc``,
+    ``r.m``, ``r.l`` hold ONE block of ``heads x group`` rows, and ``r.q``
+    too unless a head's rows are whole sublane tiles):
     :func:`_stacked_block`, over the folds of a chunk that hold a key the
     row sees, so that the masked rest of a part-filled chunk costs no
-    compute either. Otherwise
-    ``compute(slot, chunk)``, the span schedule's own block
-    (``_ragged_paged_attn_kernel``) over the whole chunk, traced once with
-    a traced slot: the mask discards what the buffer still holds past the
-    row's end.
+    compute either. Otherwise (one head, or queries a head from 8 up that
+    are no whole tiles) ``compute(slot, chunk)``, the span schedule's own
+    block (``_ragged_paged_attn_kernel``) over the whole chunk, traced once
+    with a traced slot: the mask discards what the buffer still holds past
+    the row's end.
     """
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -1472,9 +1554,10 @@ def _walk_row(r: _KernelRefs, compute, block_size, pages_per_chunk, group,
     c_hi = _div(p_hi + pages_per_chunk - 1, pages_per_chunk)
 
     block = compute  # (slot, chunk): the span schedule's, a head at a time
-    if _walk_stacks(group):
+    form, turns = walk_block(r.k_buf.shape[-1] // r.q.shape[-1], group)
+    if form == 'stacked':
         block = _stacked_block(
-            r, p_lo, p_hi, block_size, pages_per_chunk, group, scale,
+            r, p_lo, p_hi, block_size, pages_per_chunk, group, turns, scale,
             logit_softcap, value_lanes, quantized,
         )
 
@@ -1515,7 +1598,7 @@ def _walk_row(r: _KernelRefs, compute, block_size, pages_per_chunk, group,
             c_lo - 1 + r.slot[1], c_hi, chunk, r.slot[0]
         )
         r.slot[1] = 1
-        if _walk_stacks(group):  # a KV head's rows of the stacked block
+        if form == 'stacked':  # a KV head's rows of the stacked block
             r.acc[0] = r.acc[0] / jnp.maximum(r.l[0][:, :1], 1e-9)
             for h in range(r.out.shape[0]):
                 r.out[h] = r.acc[0, h * group:(h + 1) * group].astype(
@@ -1871,7 +1954,8 @@ def _half_tile_heads(
     band is its own head's score (the zeros meet the neighbour's lanes);
     its weighted sum over the band's values holds its head's output in its
     head's half, and the other half (the neighbour's values under this
-    head's weights) is dropped here. The MXU contracts and emits 128 lanes
+    head's weights) is dropped here (the second head's half rolled down,
+    then the lower half of every row). The MXU contracts and emits 128 lanes
     a pass either way, the softmax rows are the ``num_heads`` rows a
     kernel of 64-wide bands would have, and a page crosses HBM once. Both
     schedules (the grid over spans and the row walk) and
@@ -1912,10 +1996,14 @@ def _half_tile_heads(
         logit_softcap=logit_softcap, pages_per_chunk=pages_per_chunk,
         span_tile=span_tile, interpret=interpret,
     ).reshape(b, s, tiles, 2, group, 2 * head_dim)
-    out = jnp.stack(
-        [out[:, :, :, 0, :, :head_dim], out[:, :, :, 1, :, head_dim:]], axis=3
-    )
-    return out.reshape(b, s, num_heads, head_dim)
+    # A band's second head has its output in the band's upper half: rolled
+    # down by a head, every head's is the lower half of its rows. (Taken as
+    # two half-tile slices stacked, ``[..., 0, :, :64]`` and ``[..., 1, :,
+    # 64:]``, XLA's TPU compiler made ONE bitcast of the kernel's result,
+    # which reads the lower half of EVERY row: PERF.md section 7, PR 55.)
+    second = jnp.arange(2).reshape(1, 1, 1, 2, 1, 1) == 1
+    out = jnp.where(second, jnp.roll(out, -head_dim, axis=-1), out)
+    return out[..., :head_dim].reshape(b, s, num_heads, head_dim)
 
 
 def _block_ceiling(positions, block_length: int):

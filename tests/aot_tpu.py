@@ -73,30 +73,75 @@ def _assert_kernel_compiled(compiled) -> None:
     )
 
 
-def _kernel_schedules(compiled) -> list:
-    """``'walk'`` or ``'grid'`` for each paged-attention call of a
-    compiled program: a serialized Mosaic body names the functions its
-    source lines are in, and only the row walk's names ``_walk_row``."""
+def _kernel_modules(text: str, debug_info: bool = True) -> list:
+    """``(name, text)`` of the Mosaic module of every Pallas call in a
+    lowered or compiled program's text: the serialized body parsed and
+    printed, with the locations its operations carry or without. (The raw
+    bytes also name what the process traced BEFORE the kernel, so a search
+    of them tells kernels apart only while no other kernel was traced
+    first: read the module.)"""
     import base64
     import re
 
-    bodies = re.findall(
-        r'custom_call_config[^A-Za-z0-9]+body[^A-Za-z0-9]+'
-        r'([A-Za-z0-9+/=]{100,})',
-        compiled.as_text(),
-    )
-    bodies = [base64.b64decode(body) for body in bodies]
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    modules = []
+    for body in re.findall(
+        r'body\\*(?:22|")\s*:\s*\\*(?:22|")([A-Za-z0-9+/=]{100,})', text
+    ):
+        with mlir.make_ir_context() as context:  # jax's dialects and Mosaic's
+            tpu.register_dialect(context)
+            context.allow_unregistered_dialects = True
+            module = ir.Module.parse(base64.b64decode(body))
+            attributes = module.operation.attributes
+            name = ''  # a module may have none
+            if 'sym_name' in attributes:
+                name = ir.StringAttr(attributes['sym_name']).value
+            modules.append(
+                (name, module.operation.get_asm(enable_debug_info=debug_info))
+            )
+    return modules
+
+
+def _kernel_kinds(compiled) -> list:
+    """``'grid'``, ``'walk:stacked'`` or ``'walk:per_head'`` for each
+    paged-attention call of a compiled program: the locations of a Mosaic
+    module name the functions its source lines are in, only the row walk's
+    names ``_walk_row``, and only a walk with the stacked softmax block
+    ``_stacked_block``."""
     return [
-        'walk' if b'_walk_row' in body else 'grid'
-        for body in bodies if b'_ragged_paged_attn_kernel' in body
+        'grid' if '_walk_row' not in module
+        else 'walk:stacked' if '_stacked_block' in module else 'walk:per_head'
+        for name, module in _kernel_modules(compiled.as_text())
+        if name == '_ragged_paged_attn_kernel'
     ]
 
 
-def _assert_decode_calls_walk(compiled) -> None:
+def _kernel_programs(lowered_text: str) -> list:
+    """The Mosaic module of every Pallas call of a LOWERED text, printed
+    with its debug locations dropped. The raw text carries file, line and
+    function of each operation's ten innermost frames, its callers' among
+    them, so it moves with any line above a kernel; this is the program
+    alone."""
+    return [module for _, module in _kernel_modules(lowered_text, False)]
+
+
+def _kernel_schedules(compiled) -> list:
+    """``'walk'`` or ``'grid'`` for each paged-attention call of a
+    compiled program (``_kernel_kinds`` without the walk's block form)."""
+    return [kind.split(':')[0] for kind in _kernel_kinds(compiled)]
+
+
+def _assert_decode_calls_walk(compiled, blocks=None) -> None:
     """Every paged-attention call of a decode window is a span of one
-    and takes the row walk."""
-    schedules = _kernel_schedules(compiled)
-    assert schedules and set(schedules) == {'walk'}, schedules
+    and takes the row walk; with ``blocks``, the set of forms its softmax
+    blocks take (``'stacked'``, ``'per_head'``)."""
+    kinds = _kernel_kinds(compiled)
+    assert kinds and {k.split(':')[0] for k in kinds} == {'walk'}, kinds
+    if blocks is not None:
+        assert {k.split(':')[1] for k in kinds} == set(blocks), kinds
 
 
 def _assert_span_calls_keep_the_grid(compiled) -> None:

@@ -35,6 +35,7 @@ from aot_tpu import (
     _assert_span_calls_keep_the_grid,
     _compile,
     _kernel_equations,
+    _kernel_programs,
     _kernel_schedules,
 )
 
@@ -537,6 +538,43 @@ def test_ragged_kernel_compiles_at_7_queries_a_head(v5e, rows, span, window):
     assert _kernel_schedules(compiled) == ['walk' if span == 1 else 'grid']
 
 
+# What PR 55 must not move: a walk under 8 queries a KV head and a span
+# over one. sha256 of the kernel's program as PR 54's tree lowers it
+# (``_kernel_programs``: locations dropped, so this file's and the kernel
+# file's lines may move and a changed operation may not).
+@pytest.mark.parametrize('rows, span, nh, nkv, table, program', [
+    (32, 1, 32, 8, 256,
+     '0be73ec3ca3822ee5e69ac3bb86ea4709f945b9afed51086761367ea275bcd19'),
+    (48, 1, 28, 4, 1024,
+     '048a28d6a6228629663a2dc3010761055a622fd50ba549274f4595bfc034be66'),
+    (4, 512, 32, 8, 256,
+     'e5b747121b8a059e6e51bac038c920983d3b60f28e761f84c0d1b9fcd92d0743'),
+], ids=['mistral7b_walk', 'smallthinker_walk', 'span512'])
+def test_walks_under_8_and_spans_lower_to_the_parents_program(
+    v5e, rows, span, nh, nkv, table, program
+):
+    """The rule that gives 8 queries a head and more the stacked block
+    changes nothing below them (``mistral7b``'s 8 x 4 and ``smallthinker``'s
+    4 x 7 stacked rows: same fold, same constants, operation for operation)
+    and nothing in the span schedule."""
+    import hashlib
+
+    from distllm_tpu.ops.paged_attention import ragged_paged_attention_pallas
+
+    pool = v5e((4096, 16, nkv * _HD), jnp.bfloat16)
+    text = jax.jit(
+        lambda q, k, v, bt, ctx, pos, ql: ragged_paged_attention_pallas(
+            q, k, v, bt, ctx, pos, q_lens=ql
+        )
+    ).lower(
+        v5e((rows, span, nh, _HD), jnp.bfloat16), pool, pool,
+        v5e((rows, table), jnp.int32), v5e((rows,), jnp.int32),
+        v5e((rows, span), jnp.int32), v5e((rows,), jnp.int32),
+    ).as_text()
+    (kernel,) = _kernel_programs(text)
+    assert hashlib.sha256(kernel.encode()).hexdigest() == program
+
+
 # ---- sdar (PR 54): a block of 4 positions folded into a group of 8 through
 # the row walk, and prefill spans under a block-causal ceiling ----
 
@@ -547,7 +585,8 @@ def test_ragged_kernel_compiles_for_blocks_of_positions(
     v5e, rows, span, heads, block_length
 ):
     """Over the sdar cell's stacked pool of 48 layers: the decode walk at 32
-    queries a KV head (128 query rows on 4 KV heads: the per-head block), the
+    queries a KV head (128 query rows on 4 KV heads: the stacked block, the
+    queries a head, since PR 55), the
     span schedule's tile of 64 positions x 8 under ``block_length`` 4; and
     ``block_length`` 1 lowers to the text a call that never names it does."""
     from distllm_tpu.ops.paged_attention import ragged_paged_attention_pallas

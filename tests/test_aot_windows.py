@@ -37,6 +37,7 @@ from aot_tpu import (
     _granite_window,
     _kanana_stacks,
     _kanana_window,
+    _kernel_modules,
     _kernel_schedules,
     _mistral_chunk_prefill,
     _mistral_window,
@@ -46,7 +47,9 @@ from aot_tpu import (
 
 def test_laguna_decode_window_reads_the_pools_as_they_lie(laguna_cell, laguna_window):
     _assert_pools_go_to_the_kernel_as_they_lie(laguna_window, laguna_cell[4])
-    _assert_decode_calls_walk(laguna_window)
+    # 6 queries a KV head in the full layers and 8 in the window layers:
+    # both stacked since PR 55
+    _assert_decode_calls_walk(laguna_window, blocks={'stacked'})
 
 
 def test_decode_window_reads_the_planes_as_they_lie(v5e, kanana_cell):
@@ -67,7 +70,7 @@ def test_decode_window_reads_the_planes_as_they_lie(v5e, kanana_cell):
         v5e((b,), f32), v5e((b,), i32), v5e((b,), jnp.uint32),
     ).compile()
     _assert_pools_go_to_the_kernel_as_they_lie(compiled, [plane])
-    _assert_decode_calls_walk(compiled)
+    _assert_decode_calls_walk(compiled, blocks={'per_head'})  # ONE head
     # the decode calls of the kernel, as the roofline metric's pattern
     # names them: [rows, 1 KV head, 32 queries, 512 value lanes]
     assert f'bf16[{b},1,32,512]' in compiled.as_text()
@@ -132,14 +135,35 @@ def test_stacked_pool_programs_copy_no_pool(v5e, program):
 def test_lfm2_decode_window_addresses_the_pool(lfm2_cell, lfm2_window):
     """The decode window at the cell's 96 rows: the stacked pool of 512-
     lane rows goes to the writers and to the kernel whole (no plane and no
-    pool copied, no head padded to a tile), every call takes the row walk,
-    and the state's buffers are rewritten in place."""
+    pool copied, no head padded to a tile), every call takes the row walk
+    with the stacked block (4 bands x 8 queries), and the state's buffers
+    are rewritten in place."""
     pool = lfm2_cell[3]
     _assert_stacked_pool_is_addressed(lfm2_window, pool)
-    _assert_decode_calls_walk(lfm2_window)
+    _assert_decode_calls_walk(lfm2_window, blocks={'stacked'})
     # nothing as large as the weights' smallest bank is left over as a
     # temporary: the pools and the state are updated where they lie
     assert lfm2_window.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
+def test_lfm2_way_back_from_the_kernel_keeps_both_halves(lfm2_window):
+    """Two 64-wide heads share a band, and the second one's output is the
+    band's UPPER 64 lanes. Taken as two half-tile slices stacked, XLA's TPU
+    compiler made one ``bitcast`` of the kernel's ``[96, 4, 8, 128]`` result
+    into 64-lane rows, the lower half of every row (wrong for half the
+    heads, on the chip alone: PERF.md section 7, PR 55). No call's result
+    goes into a 64-lane shape by a bare bitcast."""
+    import re
+
+    text = lfm2_window.as_text()
+    calls = re.findall(
+        r'(%distllm\.attn_full[\w.]*) = bf16\[96,4,8,128\]\S* custom-call', text
+    )
+    assert calls
+    for call in calls:
+        assert not re.search(
+            r'bf16\[[0-9,]*,64\]\S* bitcast\(' + re.escape(call) + r'\)', text
+        ), call
 
 
 def test_granite_decode_window_streams_its_banks_densely(v5e):
@@ -185,22 +209,16 @@ def test_solar_open2_decode_window_updates_its_matrix_states_in_place(solar_open
     # a stack of one layer has no plane to slice: no relayout of the pool,
     # and the kernel reads the pool itself
     _assert_pools_go_to_the_kernel_as_they_lie(compiled, [pool, pool[1:]])
-    # At 121 rows and over the routed experts take the grouped kernel, whose
-    # serialized bodies name what the process traced before them, the paged
-    # kernel among it: the walk is counted among the other bodies.
-    import base64
-    import re
-
-    bodies = [
-        base64.b64decode(body) for body in re.findall(
-            r'custom_call_config[^A-Za-z0-9]+body[^A-Za-z0-9]+'
-            r'([A-Za-z0-9+/=]{100,})', compiled.as_text(),
-        )
+    # At 121 rows and over the routed experts take the grouped kernel: the
+    # paged kernel's calls are told from its by their modules' names.
+    modules = _kernel_modules(compiled.as_text())
+    paged = [
+        module for name, module in modules
+        if name == '_ragged_paged_attn_kernel'
     ]
-    paged = [body for body in bodies if b'_grouped_matmul_kernel' not in body]
-    assert paged and all(b'_walk_row' in body for body in paged)
+    assert paged and all('_walk_row' in module for module in paged)
     grouped = moe.expert_form(b, 8, 40, 320, 4096, 1280) == 'grouped'
-    assert (len(paged) < len(bodies)) == grouped
+    assert (len(paged) < len(modules)) == grouped
     one_matrix_pool = b * 64 * 128 * 128 * 4
     assert compiled.memory_analysis().temp_size_in_bytes < one_matrix_pool
 
@@ -326,10 +344,11 @@ def test_smallthinker_decode_window_reads_the_pools_as_they_lie(
 def test_sdar_block_window_reads_the_pool_as_it_lies(sdar_cell, sdar_window):
     """The block window at all 48 layers: no pool-sized result but the
     scatters, every attention call the row walk (a block folded into the
-    group: 32 queries a KV head), no layer's bank copied out of its stack,
-    and the program fits the chip beside weights and pool."""
+    group: 32 queries a KV head, the stacked block), no layer's bank copied
+    out of its stack, and the program fits the chip beside weights and
+    pool."""
     _, _, params, _, pool, _ = sdar_cell
     _assert_stacked_pool_is_addressed(sdar_window, pool)
-    _assert_decode_calls_walk(sdar_window)
+    _assert_decode_calls_walk(sdar_window, blocks={'stacked'})
     memory = sdar_window.memory_analysis()
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.3 * 2**30

@@ -442,7 +442,9 @@ def test_decode_records_count_the_chunks_the_walk_fetches(
     """``kv_chunks*`` on ``decode`` records is what the rows' contexts
     give, group by group, and ``telemetry['kv_walk_keys']`` names the keys
     a step each pool's walk takes (the rule's, here held to 8 keys, two
-    pages, so that rows span several chunks at toy lengths)."""
+    pages, so that rows span several chunks at toy lengths);
+    ``telemetry['walk_block']`` and ``walk_block*`` on the records name the
+    form of each group's softmax block, the kernel's own rule's answer."""
     from distllm_tpu.ops import paged_attention
 
     monkeypatch.setattr(paged_attention, 'WALK_MAX_KEYS', 8)
@@ -462,6 +464,18 @@ def test_decode_records_count_the_chunks_the_walk_fetches(
         rng = np.random.default_rng(3)
         prompts = [prompt(rng, 41), prompt(rng, 9)]
     assert engine.telemetry['kv_walk_keys'] == dict.fromkeys(windows, 8)
+    heads, kv_heads = engine.model_cfg.num_heads, engine.model_cfg.num_kv_heads
+    blocks = {
+        group: paged_attention.walk_block(
+            kv_heads, (heads(group) if callable(heads) else heads) // kv_heads
+        )[0]
+        for group in windows
+    }
+    assert engine.telemetry['walk_block'] == blocks
+    block_fields = {
+        names[group].replace('kv_chunks', 'walk_block'): form
+        for group, form in blocks.items()
+    }
 
     seen = []
     reckon = engine._kv_chunks
@@ -490,6 +504,10 @@ def test_decode_records_count_the_chunks_the_walk_fetches(
         {k: v for k, v in r.items() if k.startswith('kv_chunks')}
         for r in decodes
     ] == [fields for _, fields in seen]
+    assert [
+        {k: v for k, v in r.items() if k.startswith('walk_block')}
+        for r in decodes
+    ] == [block_fields] * len(decodes)
     assert not any(k.startswith('kv_turns') for r in decodes for k in r)
     # a chunk holds two pages: the walk fetches fewer chunks than blocks,
     # and no more than one a block
@@ -530,6 +548,7 @@ def test_no_walk_no_chunk_count():
     ``kv_chunks`` on the records."""
     _, _, engine = _tiny_engine(attn_backend='xla')
     assert 'kv_walk_keys' not in engine.telemetry
+    assert 'walk_block' not in engine.telemetry
     before = engine.flight.total_recorded
     engine.generate_ids(
         [[1, 2, 3]], SamplingParams(temperature=0.0, max_tokens=6)
@@ -537,6 +556,7 @@ def test_no_walk_no_chunk_count():
     records = engine.flight.snapshot()[before - engine.flight.total_recorded:]
     decodes = [r for r in records if r['kind'] == 'decode']
     assert decodes and not any(
-        'kv_chunks' in r or 'kv_turns' in r for r in decodes
+        'kv_chunks' in r or 'kv_turns' in r or 'walk_block' in r
+        for r in decodes
     )
     engine.shutdown()

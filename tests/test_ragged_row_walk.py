@@ -1,6 +1,7 @@
 """The row walk, the ragged paged-attention kernel's schedule for a span of
 one (decode rows), against the XLA twin: by keys a step and window, which
-spans walk, the keys-a-step rule, and the stacked pool addressed by layer.
+spans walk, the keys-a-step rule, the rule of the softmax block's form, and
+the stacked pool addressed by layer.
 By pool, knob and queries a head: ``test_ragged_row_walk_pools.py``."""
 
 import numpy as np
@@ -171,6 +172,62 @@ def test_walk_keys_a_step_rule(lanes, dtype, planes, block, keys):
     copies = planes + 2 * (dtype == 'int8')
     assert held <= WALK_BUFFER_BYTES
     assert 2 * copies * (got // block) <= WALK_SEMAPHORES
+
+
+@pytest.mark.parametrize(
+    'heads,group,form,turns',
+    [
+        (8, 4, 'stacked', 4),  # mistral7b (both cells), granite
+        (16, 1, 'stacked', 4),  # ouro
+        (4, 5, 'stacked', 4),  # falcon-h1
+        (8, 6, 'stacked', 4),  # laguna's full layers
+        (4, 7, 'stacked', 4),  # smallthinker, both groups
+        (8, 8, 'stacked', 4),  # laguna's window layers, solar: 64 rows
+        (4, 8, 'stacked', None),  # lfm2's four bands of paired heads: 32 rows
+        (4, 32, 'stacked', 4),  # sdar: a block of 4 positions x 8
+        (1, 32, 'per_head', None),  # kanana's one latent head
+        (2, 12, 'per_head', None),  # from 8 up and no whole sublane tiles
+        (2, 16, 'stacked', None),
+        (2, 24, 'stacked', 4),
+    ],
+)
+def test_walk_block_rule(heads, group, form, turns):
+    """The form of the walk's softmax block and the turns a fold, from the
+    KV heads and the queries a KV head of every cell's decode calls:
+    stacked wherever a head's softmax by itself would pay the block's fixed
+    cost once a head, the whole chunk a fold up to 32 stacked rows of whole
+    sublane tiles."""
+    from distllm_tpu.ops.paged_attention import (
+        WALK_WHOLE_CHUNK_ROWS,
+        walk_block,
+    )
+
+    assert walk_block(heads, group) == (form, turns)
+    assert (form == 'stacked' and turns is None) == (
+        heads > 1 and group % 8 == 0
+        and heads * group <= WALK_WHOLE_CHUNK_ROWS
+    )
+
+
+@pytest.mark.parametrize(
+    'query_heads,head_dim,row_lanes,form',
+    [
+        (32, 128, 1024, 'stacked'),  # mistral7b
+        (32, 64, 512, 'stacked'),  # lfm2: 8 KV heads of 64 are 4 bands x 8
+        (128, 128, 512, 'stacked'),  # sdar: 32 heads x a block of 4
+        (32, 640, 640, 'per_head'),  # kanana: the row is one head
+        (64, 128, 1024, 'stacked'),  # solar, laguna's window layers
+        (24, 128, 256, 'per_head'),  # 12 queries a head
+    ],
+)
+def test_walk_block_form_reckons_heads_as_the_kernel_does(
+    query_heads, head_dim, row_lanes, form
+):
+    """What the engine's telemetry names (``walk_block``) is the rule's
+    answer for the heads the kernel's wrapper makes of a pool row."""
+    from distllm_tpu.ops.paged_attention import walk_block_form
+
+    assert walk_block_form(query_heads, head_dim, row_lanes) == form
 
 
 @pytest.mark.parametrize('layer', [0, 1, 2], ids=['first', 'middle', 'last'])
