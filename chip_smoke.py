@@ -33,7 +33,7 @@ printed as one JSON line (``{"phase": ..., "ok": ...}``):
   widths, then asserts on the engine: every prompt admitted whole (one
   near ``max_model_len``, prefilled in chunks), a second prompt that hits
   the first one's prefix and prefills its own tail, Pallas attention, no
-  ``*_fallback`` telemetry, native scheduler and allocator.
+  ``*_fallback`` telemetry, native scheduler.
 
 ``--chips 4`` runs ONLY the across-chips phases and what they are compared
 with: ``tp4`` (``tensor_parallel_size: 4`` against the one-chip engine:
@@ -1034,7 +1034,6 @@ def phase_serve(seed: int) -> dict:
 
     from distllm_tpu import chat_server
     from distllm_tpu.chat import ChatAppConfig
-    from distllm_tpu.generate.engine import kv_cache
     from distllm_tpu.registry import registry
 
     model_dir = WORK / 'mistral'
@@ -1115,9 +1114,7 @@ def phase_serve(seed: int) -> dict:
     check(not fallbacks, f'fallback telemetry: '
           f'{ {k: telemetry[k] for k in fallbacks} }')
     scheduler = type(engine.sched._inner).__name__
-    allocator = type(kv_cache.make_allocator(16)).__name__
     check(scheduler == 'NativeScheduler', f'scheduler is {scheduler}')
-    check(allocator == 'NativeBlockAllocator', f'allocator is {allocator}')
     hit_blocks = int(engine.prefix_cache.stats['hit_blocks'])
     check(hit_blocks > 0, 'the prefix cache counted no hit')
 
@@ -1146,7 +1143,6 @@ def phase_serve(seed: int) -> dict:
         'attn_backend': telemetry.get('attn_backend'),
         'telemetry': telemetry,
         'scheduler': scheduler,
-        'allocator': allocator,
         'prefix_cache_hit_blocks': hit_blocks,
         'compiled_before': compiled_before,
         'compiled_after': compiled_after,

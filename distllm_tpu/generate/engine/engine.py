@@ -801,20 +801,9 @@ class LLMEngine:
         # weight-layout migration below, so migration headroom isn't
         # squeezed by an idle 1-6 GiB of zeros.
         def pool(group, num_blocks):
-            return PagedKVCache(
-                num_layers=group.num_layers,
-                num_blocks=num_blocks,
-                block_size=cfg.block_size,
-                num_kv_heads=model_cfg.num_kv_heads,
-                head_dim=model_cfg.head_size,
-                dtype=kv_pool_dtype,
-                sharding=kv_sharding,
-                lazy=True,
-                layer_buffers=spec.layer_buffers,
-                # A latent group declares its row; a K/V group's is the
-                # model's ``num_kv_heads * head_size``.
-                row=group.stored_row,
-                value_lanes=group.value_lanes,
+            return PagedKVCache.for_group(
+                group, model_cfg, num_blocks, cfg.block_size,
+                dtype=kv_pool_dtype, sharding=kv_sharding, lazy=True,
             )
 
         self.kv = pool(spec.paged[0], cfg.num_blocks)
@@ -1656,7 +1645,7 @@ class LLMEngine:
         refused = {
             'cache groups': (
                 len(spec.paged) > 1 or spec.latent or spec.state is not None
-                or spec.passes > 1 or spec.layer_buffers
+                or spec.passes > 1
             ) and 'the block window is written for one stacked K/V pool',
             'draft_k': bool(cfg.draft_k)
             and 'a block is decided by its own forwards, not verified '

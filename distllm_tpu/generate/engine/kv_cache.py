@@ -1,4 +1,4 @@
-"""Paged KV cache: HBM block pool, block allocator, automatic prefix cache.
+"""Paged KV cache: HBM block pool, automatic prefix cache, KV tiers.
 
 The TPU replacement for vLLM's paged KV memory management (SURVEY.md
 section 2.4 N1): K/V live as ``[L, num_blocks, block_size, N_kv * Hd]``
@@ -9,9 +9,8 @@ of block ids. Block 0 is the reserved TRASH block — padded scatter writes
 land there. A block OUTSIDE the pool (a tier's entry, a ``.kvblock`` payload,
 what a host reader gets) has the logical ``[.., block_size, N_kv, Hd]``.
 
-The allocator is the C++ free-list/refcount implementation in
-``distllm_tpu/native/block_allocator.cpp`` (ctypes), with a drop-in Python
-fallback when no compiler is available.
+Who holds which block is the scheduler's account (``engine/scheduler.py``;
+a windowed group's is :class:`WindowBlocks`): there is no free list here.
 
 :class:`PrefixCache` is the automatic prefix cache (SGLang-style radix
 reuse over full paged blocks; docs/prefix_caching.md): a token-block
@@ -53,7 +52,6 @@ that invariant.
 
 from __future__ import annotations
 
-import ctypes
 import hashlib
 import json
 import os
@@ -62,102 +60,11 @@ import time
 from collections import OrderedDict
 from pathlib import Path
 from dataclasses import dataclass, field
-from typing import Protocol, Sequence
+from typing import Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-
-class BlockAllocator(Protocol):
-    def alloc(self) -> int: ...
-
-    def free(self, block_id: int) -> None: ...
-
-    def incref(self, block_id: int) -> None: ...
-
-    @property
-    def num_free(self) -> int: ...
-
-
-class PyBlockAllocator:
-    """Pure-Python free-list allocator (fallback; same semantics as C++)."""
-
-    def __init__(self, num_blocks: int) -> None:
-        if num_blocks < 2:
-            raise ValueError('need >= 2 blocks (block 0 is reserved)')
-        self._free = list(range(num_blocks - 1, 0, -1))
-        self._refcount = [0] * num_blocks
-        self._refcount[0] = 1  # trash block, never free
-
-    def alloc(self) -> int:
-        if not self._free:
-            return -1
-        block_id = self._free.pop()
-        self._refcount[block_id] = 1
-        return block_id
-
-    def incref(self, block_id: int) -> None:
-        assert self._refcount[block_id] > 0
-        self._refcount[block_id] += 1
-
-    def free(self, block_id: int) -> None:
-        assert self._refcount[block_id] > 0, f'double free of block {block_id}'
-        self._refcount[block_id] -= 1
-        if self._refcount[block_id] == 0:
-            self._free.append(block_id)
-
-    @property
-    def num_free(self) -> int:
-        return len(self._free)
-
-
-class NativeBlockAllocator:
-    """ctypes wrapper over the C++ allocator."""
-
-    def __init__(self, num_blocks: int) -> None:
-        from distllm_tpu.native import build_library
-
-        so_path = build_library('block_allocator.cpp')
-        if so_path is None:
-            raise RuntimeError('native allocator unavailable')
-        lib = ctypes.CDLL(str(so_path))
-        lib.ba_create.restype = ctypes.c_void_p
-        lib.ba_create.argtypes = [ctypes.c_int32]
-        for fn in ('ba_alloc', 'ba_incref', 'ba_free', 'ba_num_free'):
-            getattr(lib, fn).restype = ctypes.c_int32
-        lib.ba_alloc.argtypes = [ctypes.c_void_p]
-        lib.ba_num_free.argtypes = [ctypes.c_void_p]
-        lib.ba_incref.argtypes = [ctypes.c_void_p, ctypes.c_int32]
-        lib.ba_free.argtypes = [ctypes.c_void_p, ctypes.c_int32]
-        lib.ba_destroy.argtypes = [ctypes.c_void_p]
-        handle = lib.ba_create(num_blocks)
-        if not handle:
-            raise RuntimeError(f'ba_create({num_blocks}) failed')
-        self._lib = lib
-        self._handle = handle
-
-    def alloc(self) -> int:
-        return int(self._lib.ba_alloc(self._handle))
-
-    def incref(self, block_id: int) -> None:
-        if self._lib.ba_incref(self._handle, block_id) < 0:
-            raise ValueError(f'incref of unallocated block {block_id}')
-
-    def free(self, block_id: int) -> None:
-        if self._lib.ba_free(self._handle, block_id) < 0:
-            raise ValueError(f'double free of block {block_id}')
-
-    @property
-    def num_free(self) -> int:
-        return int(self._lib.ba_num_free(self._handle))
-
-    def __del__(self) -> None:  # pragma: no cover - interpreter teardown
-        lib = getattr(self, '_lib', None)
-        handle = getattr(self, '_handle', None)
-        if lib is not None and handle:
-            lib.ba_destroy(handle)
-            self._handle = None
 
 
 def hash_block_tokens(
@@ -953,24 +860,6 @@ class HostKVTier:
             return self._bytes
 
 
-def make_allocator(num_blocks: int, prefer_native: bool = True) -> BlockAllocator:
-    if prefer_native:
-        try:
-            return NativeBlockAllocator(num_blocks)
-        except (RuntimeError, OSError) as exc:
-            # The Python twin is a designed drop-in (same policy, same
-            # tests), but WHICH allocator served must never be a silent
-            # guess in a perf investigation.
-            from distllm_tpu.observability.instruments import log_event
-
-            log_event(
-                f'[engine] native block allocator unavailable '
-                f'({exc!r:.120}); using the Python fallback',
-                component='engine',
-            )
-    return PyBlockAllocator(num_blocks)
-
-
 class _PoolView:
     """``PagedKVCache.k`` / ``.v``: what a HOST reader indexes, a layer and
     then block ids, ``kv.k[layer][block_ids]``, giving those blocks on the
@@ -1012,7 +901,7 @@ class _PoolView:
 
     def _gather(self, pool, layer: int, block_ids):
         """The blocks ``block_ids`` of ``layer`` as the pool stores them."""
-        if self._cache.layer_buffers:
+        if self._cache.latent:  # a plane a layer
             return pool[layer][block_ids]
         return jax.tree.map(lambda c: c[layer, block_ids], pool)
 
@@ -1021,10 +910,9 @@ class PagedKVCache:
     """Device-resident paged K/V arrays (pure container).
 
     ``k_pool`` and ``v_pool`` are the arrays the serving programs take and
-    give back, stored ``pool_shape = [L, num_blocks, block_size, N_kv *
-    Hd]`` (a tuple of ``pool_shape[1:]`` buffers with ``layer_buffers``);
-    ``shape`` stays the logical 5-tuple, and ``k`` / ``v`` are the host
-    reader's view (:class:`_PoolView`).
+    give back, each ONE stacked array ``pool_shape = [L, num_blocks,
+    block_size, N_kv * Hd]``; ``shape`` stays the logical 5-tuple, and ``k``
+    / ``v`` are the host reader's view (:class:`_PoolView`).
 
     Block *accounting* — who owns which block, admission, preemption — is
     the scheduler's job (``engine/scheduler.py`` over the native C++ core);
@@ -1041,9 +929,10 @@ class PagedKVCache:
 
     With ``row`` (a latent group, ``models.common.PagedGroup``) a layer
     holds ONE plane of ``[num_blocks, block_size, row]`` (``row`` already in
-    whole lane tiles): ``k_pool`` is that plane, ``v_pool`` is ``()``, and
+    whole lane tiles): ``k_pool`` is the tuple of the layers' planes (not
+    stacked: ``models/deepseek_v3.py`` says why), ``v_pool`` is ``()``, and
     the host's ``v`` view reads the first ``value_lanes`` lanes of ``k``'s
-    rows. ``shape`` is the plane's, one KV head of ``row``.
+    rows. ``shape`` is the planes', one KV head of ``row``.
     """
 
     def __init__(
@@ -1056,7 +945,6 @@ class PagedKVCache:
         dtype: str = 'bfloat16',
         sharding=None,
         lazy: bool = False,
-        layer_buffers: bool = False,
         row: int | None = None,
         value_lanes: int | None = None,
     ) -> None:
@@ -1068,18 +956,11 @@ class PagedKVCache:
         self.pool_shape = (
             num_layers, num_blocks, block_size, num_kv_heads * head_dim
         )
-        # One buffer a layer (``models.common.CacheSpec.layer_buffers``):
-        # ``k_pool`` and ``v_pool`` are then tuples of ``pool_shape[1:]``
-        # arrays.
-        self.layer_buffers = layer_buffers
         self.dtype = jnp.dtype(dtype)
         self.quantized = self.dtype == jnp.dtype(jnp.int8)
-        if (layer_buffers or self.latent) and (
-            self.quantized or sharding is not None
-        ):
+        if self.latent and (self.quantized or sharding is not None):
             raise ValueError(
-                'a pool of one buffer a layer, or of latent rows, has no '
-                'int8 and no sharded form yet'
+                'a pool of latent rows has no int8 and no sharded form yet'
             )
         # Symmetric per-block-per-KV-head scales: one fp32 per (layer,
         # block, kv head), for K and V independently (the two pool arrays
@@ -1094,6 +975,19 @@ class PagedKVCache:
         if not lazy:
             self.allocate()
 
+    @classmethod
+    def for_group(
+        cls, group, model_cfg, num_blocks: int, block_size: int, **kw
+    ) -> 'PagedKVCache':
+        """The pool of one paged group (``models.common.PagedGroup``) of a
+        model: a latent group declares its row, a K/V group's is the
+        model's ``num_kv_heads * head_size``."""
+        return cls(
+            group.num_layers, num_blocks, block_size, model_cfg.num_kv_heads,
+            model_cfg.head_size, row=group.stored_row,
+            value_lanes=group.value_lanes, **kw,
+        )
+
     @property
     def k(self) -> _PoolView:
         return _PoolView(self, self.k_pool)
@@ -1107,7 +1001,7 @@ class PagedKVCache:
     def _zeros(self):
         from distllm_tpu.ops.paged_attention import QuantizedKV
 
-        if self.layer_buffers:
+        if self.latent:
             return tuple(
                 jnp.zeros(self.pool_shape[1:], dtype=self.dtype)
                 for _ in range(self.pool_shape[0])
@@ -1143,12 +1037,10 @@ class PagedKVCache:
 
     def spec(self, plane: str = 'k'):
         """Shape/dtype pytree for one pool array (AOT compilation input):
-        a bare ShapeDtypeStruct, or a QuantizedKV of them when int8; ``()``
-        for the V plane a latent pool does not have."""
-        if self.latent and plane == 'v':
-            return ()
-        if self.layer_buffers:
-            return (
+        a bare ShapeDtypeStruct, or a QuantizedKV of them when int8; a
+        latent pool's planes, and ``()`` for the V plane it does not have."""
+        if self.latent:
+            return () if plane == 'v' else (
                 jax.ShapeDtypeStruct(self.pool_shape[1:], self.dtype),
             ) * self.pool_shape[0]
         data = jax.ShapeDtypeStruct(self.pool_shape, self.dtype)

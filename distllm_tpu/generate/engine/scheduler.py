@@ -840,8 +840,8 @@ def make_scheduler(
         try:
             return NativeScheduler(num_blocks, block_size, max_num_seqs)
         except (RuntimeError, OSError) as exc:
-            # Same contract as kv_cache.make_allocator: the Python twin
-            # is a tested drop-in, but the substitution is never silent.
+            # The Python twin is a tested drop-in, but WHICH scheduler
+            # served must never be a silent guess in a perf investigation.
             from distllm_tpu.observability.instruments import log_event
 
             log_event(

@@ -74,17 +74,17 @@ class CacheSpec:
     (``jit_<prefix>window_fn``). ``dense_prefill``: whether the module's
     ``prefill`` (whole prompts, K/V scattered afterwards) may serve short
     fresh prompts; without it every prefill takes the paged route.
-    ``layer_buffers``: a group's ``k_cache`` and ``v_cache`` are tuples of
-    one ``[num_blocks, block_size, N_kv * Hd]`` buffer a layer, written in
-    place, and not one ``[L, ...]`` array (which goes to the writers and
-    the kernel whole, with the layer meant: ``ops.paged_attention``).
+    A K/V group's ``k_cache`` and ``v_cache`` are each ONE stacked array
+    ``[L, num_blocks, block_size, N_kv * Hd]``, handed to the writers and
+    the kernel whole with the layer meant, never sliced
+    (``ops.paged_attention``); a latent group's ``k_cache`` is a tuple of a
+    plane ``[num_blocks, block_size, row]`` a layer, its ``v_cache`` ``()``.
     """
     paged: tuple[PagedGroup, ...]
     programs: str
     state: object | None = None
     program_prefix: str = ''
     dense_prefill: bool = True
-    layer_buffers: bool = False
     passes: int = 1  # runs of the stack a token, each with planes of its own
     block: int = 1  # positions a sequence decides together (models/sdar.py)
 
@@ -666,7 +666,7 @@ def _layer_of(stack, i):
 # A decoder whose layers are of two attention kinds, full and windowed, each
 # with a cache group of its own (``models/laguna.py``,
 # ``models/smallthinker.py``): the serving programs take a pair of each cache
-# operand in this order, a group's ``k_cache`` a tuple of one buffer a layer.
+# operand in this order, a group's ``k_cache`` its stacked pool.
 CACHE_GROUPS = ('full', 'window')
 
 
@@ -715,37 +715,37 @@ def walk_cache_groups(  # distlint: traced
 ):
     """The serving programs' walk over the layers of two cache groups,
     unrolled, each layer a call of one jitted function a kind
-    (``once_a_kind``) with static indices: a static slice of the stacked
-    kernels folds into its matmul, and a layer's K and V buffers are written
-    in place.
+    (``once_a_kind``) with static indices into the weights (a static slice
+    of the stacked kernels folds into its matmul) and the layer's index in
+    its group's pool a traced scalar: the pool goes in and comes back whole,
+    written in that layer's pages.
 
     ``layers``: ``(*kind, index in the group's tree, index in the second
     tree)`` of every layer (``layer_indices``), ``kind[0]`` the layer's
-    cache group. ``layer(*kind, x, *weights(kind, ai, mi), k_buf, v_buf,
-    table, *rest(kind))`` returns ``(x, k_buf, v_buf)`` and, with ``counts``
-    (the zero of the family's counter), the layer's counts behind them.
-    ``k_cache``, ``v_cache`` and ``block_tables`` are pairs in
+    cache group. ``layer(*kind, x, *weights(kind, ai, mi), k_cache, v_cache,
+    li, table, *rest(kind))`` returns ``(x, k_cache, v_cache)`` and, with
+    ``counts`` (the zero of the family's counter), the layer's counts behind
+    them. ``k_cache``, ``v_cache`` and ``block_tables`` are pairs in
     ``CACHE_GROUPS``' order. Returns ``(x, k_cache, v_cache)`` with the
     caches as the pairs they came in as, and the summed counts where
     asked."""
     tables = dict(zip(CACHE_GROUPS, block_tables))
-    pools = {
-        g: [list(k), list(v)] for g, k, v in zip(CACHE_GROUPS, k_cache, v_cache)
-    }
+    pools = dict(zip(CACHE_GROUPS, zip(k_cache, v_cache)))
     kinds = [entry[:-2] for entry in layers]
     layer_of = once_a_kind(layer, kinds, name)
     for *kind, ai, mi in layers:
         kind = tuple(kind)
-        k_pool, v_pool = pools[kind[0]]
-        x, k_pool[ai], v_pool[ai], *counted = layer_of[kind](
-            x, *weights(kind, ai, mi), k_pool[ai], v_pool[ai], tables[kind[0]],
-            *rest(kind),
+        group = kind[0]
+        x, k_pool, v_pool, *counted = layer_of[kind](
+            x, *weights(kind, ai, mi), *pools[group], jnp.int32(ai),
+            tables[group], *rest(kind),
         )
+        pools[group] = (k_pool, v_pool)
         if counts is not None:
             counts = counts + counted[0]
     out = (
         x,
-        tuple(tuple(pools[g][0]) for g in CACHE_GROUPS),
-        tuple(tuple(pools[g][1]) for g in CACHE_GROUPS),
+        tuple(pools[g][0] for g in CACHE_GROUPS),
+        tuple(pools[g][1] for g in CACHE_GROUPS),
     )
     return out if counts is None else (*out, counts)

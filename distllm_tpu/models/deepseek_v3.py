@@ -8,7 +8,12 @@ A cached token is ONE row a layer, ``[c (kv_lora_rank) | k_r
 (qk_rope_head_dim)]`` after ``kv_a_layernorm`` and after the rotation, and
 every query head attends over it: ``cfg.cache_spec()`` declares one latent
 paged group (``common.PagedGroup.row``), whose pool is one plane a layer and
-has no V plane (values are the row's first ``kv_lora_rank`` lanes). Both
+has no V plane (values are the row's first ``kv_lora_rank`` lanes). The
+planes are a tuple, not the one stacked array a K/V group's pool is: beside
+this family's weights a chain of unrolled writes into ONE 4.7 GB array reads
+to XLA's rematerialization as two such arrays, over the chip's memory, and it
+recomputes the q projection in 22 layers of every prefill (``PERF.md``
+section 6, PR 56; the cures tried are there). Both
 serving programs attend in the ABSORBED form through the ragged paged
 kernel, one shared KV head with ``num_heads`` queries on it::
 
@@ -144,7 +149,6 @@ class DeepseekV3Config(BaseConfig):
             programs=__name__,
             program_prefix='deepseek_',
             dense_prefill=False,
-            layer_buffers=True,
         )
 
     @classmethod

@@ -16,11 +16,10 @@ A sequence holds TWO kinds of K/V pages (``cfg.cache_spec()``): blocks of
 the full layers' pool for its whole context, and blocks of the window
 layers' pool for what a query can still see. The serving programs take a
 pair of each cache operand, ``(full, window)``: ``k_cache``, ``v_cache`` and
-``block_tables``; a group's ``k_cache`` is a tuple of one buffer a layer
-(``CacheSpec.layer_buffers``), each written in place. (The form was chosen
-when a layer sliced out of a stacked pool was copied out and back; a
-stacked pool is now addressed by layer, ``ops.paged_attention``, and
-either form is free of copies.) Attention goes through the entry points ``models/
+``block_tables``; a group's ``k_cache`` is its stacked pool ``[L_kind,
+num_blocks_kind, block_size, N_kv * Hd]``, handed whole to the writers and
+the kernel with the layer whose pages are meant and written in place
+(``ops.paged_attention``). Attention goes through the entry points ``models/
 mistral.py`` calls (``common.sdpa``, ``ragged_paged_attention``,
 ``write_chunk_kv``, ``write_token_kv``) with a static window per kind; the
 routed experts are ``models/moe.py``: a chip may hold a share of them
@@ -152,7 +151,6 @@ class LagunaConfig(BaseConfig):
             programs=__name__,
             program_prefix='laguna_',
             dense_prefill=False,
-            layer_buffers=True,
         )
 
     @classmethod
@@ -456,7 +454,7 @@ def prefill_paged(  # distlint: traced
     cfg: LagunaConfig,
     input_ids: jnp.ndarray,  # [B, S] tokens of the span (padded)
     positions: jnp.ndarray,  # [B, S] absolute positions
-    k_cache,  # (full, window): per layer [num_blocks_kind, block_size, N_kv * Hd]
+    k_cache,  # (full, window): [L_kind, num_blocks_kind, block_size, N_kv * Hd]
     v_cache,
     block_tables,  # (full, window): [B, max_blocks] each
     context_lens: jnp.ndarray,  # [B] valid tokens incl. this span
@@ -471,7 +469,7 @@ def prefill_paged(  # distlint: traced
     row's window table still names (entries behind them are the trash
     block, never fetched). Returns ``(last_logits [B, V] float32, k_cache,
     v_cache)``, the caches as the pairs they came in as. The layers are
-    walked unrolled: each layer's K and V pool is a buffer of its own."""
+    walked unrolled, each writing its own pages of its group's pool."""
     from distllm_tpu.ops.paged_attention import (
         ragged_paged_attention,
         write_chunk_kv,
@@ -482,26 +480,27 @@ def prefill_paged(  # distlint: traced
     rope = _rope_tables(cfg, max_table_positions or cfg.max_position_embeddings)
     x = common.embed(params, cfg.dtype, input_ids)
 
-    def layer(attn_kind, mlp_kind, x, lp, mp, banks, mi, k_buf, v_buf, table,
-              cos, sin, positions, valid, context_lens, tail_lens):
+    def layer(attn_kind, mlp_kind, x, lp, mp, banks, mi, k_cache, v_cache, li,
+              table, cos, sin, positions, valid, context_lens, tail_lens):
         normed = _norm(x, lp['ln']['scale'], cfg)
         q, k, v = _qkv(normed, lp, cfg, attn_kind)
         q = _rope(q, (cos, sin, 2 * cos.shape[-1]), positions)
         k = _rope(k, (cos, sin, 2 * cos.shape[-1]), positions)
         with jax.named_scope(f'distllm.attn_{attn_kind}'):
-            k_buf, v_buf = write_chunk_kv(
-                k_buf, v_buf, k, v, table, positions, valid
+            # the stacked pools whole, with the layer whose pages are meant
+            k_cache, v_cache = write_chunk_kv(
+                k_cache, v_cache, k, v, table, positions, valid, layer=li
             )
             attn = ragged_paged_attention(
-                q, k_buf, v_buf, table, context_lens, positions,
+                q, k_cache, v_cache, table, context_lens, positions,
                 q_lens=tail_lens, sliding_window=cfg.window(attn_kind),
-                backend=attn_backend,
+                backend=attn_backend, layer=li,
             )
         x, _ = _finish_layer(
             x, _attn_out(attn, normed, lp, cfg, attn_kind), mp, cfg, mlp_kind,
             valid, banks, mi,
         )
-        return x, k_buf, v_buf
+        return x, k_cache, v_cache
 
     x, k_cache, v_cache = common.walk_cache_groups(
         layer, cfg.layer_indices(), 'laguna_layer', x, k_cache, v_cache,
@@ -533,31 +532,34 @@ def _decode_core(
 ):
     """One token of every row (``common.decode_window``'s ``core`` once its
     first four arguments are bound; ``caches`` is ``(k_cache, v_cache)``).
-    The layers are walked unrolled, each with static indices: a static slice
-    of the stacked kernels folds into its matmul, and a layer's K and V
-    buffers are written in place."""
+    The layers are walked unrolled, each with static indices into the
+    weights: a static slice of the stacked kernels folds into its matmul,
+    and a layer's pages of its group's pool are written in place."""
     from distllm_tpu.ops.paged_attention import decode_attention, write_token_kv
 
     x = common.embed(params, cfg.dtype, input_ids)  # [B, H]
 
-    def layer(attn_kind, mlp_kind, x, lp, mp, banks, mi, k_buf, v_buf, table,
-              cos, sin, positions, context_lens, live):
+    def layer(attn_kind, mlp_kind, x, lp, mp, banks, mi, k_cache, v_cache, li,
+              table, cos, sin, positions, context_lens, live):
         normed = _norm(x, lp['ln']['scale'], cfg)
         q, k, v = _qkv(normed, lp, cfg, attn_kind)
         table_of_kind = (cos, sin, 2 * cos.shape[-1])
         q = _rope(q[:, None], table_of_kind, positions[:, None])[:, 0]
         k = _rope(k[:, None], table_of_kind, positions[:, None])[:, 0]
         with jax.named_scope(f'distllm.attn_{attn_kind}'):
-            k_buf, v_buf = write_token_kv(k_buf, v_buf, k, v, table, positions)
+            k_cache, v_cache = write_token_kv(
+                k_cache, v_cache, k, v, table, positions, layer=li
+            )
             attn = decode_attention(
-                q, k_buf, v_buf, table, context_lens, positions,
+                q, k_cache, v_cache, table, context_lens, positions,
                 backend=attn_backend, sliding_window=cfg.window(attn_kind),
+                layer=li,
             )
         x, layer_pairs = _finish_layer(
             x, _attn_out(attn, normed, lp, cfg, attn_kind), mp, cfg, mlp_kind,
             live, banks, mi,
         )
-        return x, k_buf, v_buf, layer_pairs
+        return x, k_cache, v_cache, layer_pairs
 
     x, k_cache, v_cache, pairs = common.walk_cache_groups(
         layer, cfg.layer_indices(), 'laguna_layer', x, *caches, block_tables,
@@ -597,8 +599,7 @@ def decode_loop(  # distlint: traced
     rope = _rope_tables(cfg, max_table_positions or cfg.max_position_embeddings)
     tokens, (k_cache, v_cache), ids, pairs = common.decode_window(
         functools.partial(_decode_core, params, cfg, rope, attn_backend),
-        input_ids, positions, context_lens,
-        (tuple(tuple(k) for k in k_cache), tuple(tuple(v) for v in v_cache)),
+        input_ids, positions, context_lens, (tuple(k_cache), tuple(v_cache)),
         block_tables, steps_left, temperature, top_p, min_p, top_k, seeds,
         num_steps=num_steps, sampling_top_window=sampling_top_window,
         counts=jnp.zeros((2,), jnp.int32),

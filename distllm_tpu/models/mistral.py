@@ -707,9 +707,9 @@ def _decode_core(
     kernel call was copied out and back (64 plane copies and write-backs a
     step at 32 layers, 4.27 ms of a 29.61 ms step on the chip, PR 31), so the pool
     goes to the writer and to the kernel whole, with the layer whose
-    pages are meant (``ops.paged_attention._layer_pages``). A family may
-    instead hold one buffer a layer (``CacheSpec.layer_buffers``,
-    ``models/laguna.py``). Prefill keeps the rolled scan: compute-bound,
+    pages are meant (``ops.paged_attention._layer_pages``): the form of
+    every family's K/V pool (``models.common.CacheSpec``).
+    Prefill keeps the rolled scan: compute-bound,
     and the weights' slice traffic amortizes over the whole token batch.
     """
     # int32 [L] per-layer windows (0 = global) riding the layer scan; only

@@ -12,7 +12,7 @@ expert layer of every layer (``params['sparse']``). The dense forward scans
 each run of equal layers (``common.layer_runs``); the serving programs are
 ``common.walk_cache_groups``, the walk over two cache groups that
 ``models/laguna.py`` takes too: a pair of each cache operand, ``(full,
-window)``, a group's ``k_cache`` a tuple of one buffer a layer.
+window)``, a group's ``k_cache`` its stacked pool, addressed by layer.
 
 The expert layer is ``models/moe.py``: the ranking (``moe.rank_experts``:
 router logits, top-k, gates, and for the grouped form the pairs' sort) is
@@ -129,7 +129,6 @@ class SmallThinkerConfig(BaseConfig):
             programs=__name__,
             program_prefix='smallthinker_',
             dense_prefill=False,
-            layer_buffers=True,
         )
 
     @classmethod
@@ -441,7 +440,7 @@ def prefill_paged(  # distlint: traced
     cfg: SmallThinkerConfig,
     input_ids: jnp.ndarray,  # [B, S] tokens of the span (padded)
     positions: jnp.ndarray,  # [B, S] absolute positions
-    k_cache,  # (full, window): per layer [num_blocks_kind, block_size, N_kv * Hd]
+    k_cache,  # (full, window): [L_kind, num_blocks_kind, block_size, N_kv * Hd]
     v_cache,
     block_tables,  # (full, window): [B, max_blocks] each
     context_lens: jnp.ndarray,  # [B] valid tokens incl. this span
@@ -463,22 +462,22 @@ def prefill_paged(  # distlint: traced
     rope = _rope_table(cfg, max_table_positions or cfg.max_position_embeddings)
     x = common.embed(params, cfg.dtype, input_ids)
 
-    def layer(group, rotation, x, lp, mp, banks, mi, k_buf, v_buf, table,
-              cos, sin, positions, valid, context_lens, tail_lens):
+    def layer(group, rotation, x, lp, mp, banks, mi, k_cache, v_cache, li,
+              table, cos, sin, positions, valid, context_lens, tail_lens):
         u = _norm(x, lp['ln']['scale'], cfg)
         ranking = _rank(u, mp, banks, cfg, mi)
         q, k, v = _qkv(u, lp, cfg, rotation, cos, sin, positions)
         with jax.named_scope(f'distllm.attn_{group}'):
-            k_buf, v_buf = write_chunk_kv(
-                k_buf, v_buf, k, v, table, positions, valid
+            k_cache, v_cache = write_chunk_kv(
+                k_cache, v_cache, k, v, table, positions, valid, layer=li
             )
             attn = ragged_paged_attention(
-                q, k_buf, v_buf, table, context_lens, positions,
+                q, k_cache, v_cache, table, context_lens, positions,
                 q_lens=tail_lens, sliding_window=cfg.window(group),
-                backend=attn_backend,
+                backend=attn_backend, layer=li,
             )
         x, _ = _finish_layer(x, attn, lp, mp, banks, cfg, mi, ranking, valid)
-        return x, k_buf, v_buf
+        return x, k_cache, v_cache
 
     x, k_cache, v_cache = common.walk_cache_groups(
         layer, cfg.layer_indices(), 'smallthinker_layer', x, k_cache, v_cache,
@@ -500,8 +499,8 @@ def _decode_core(
 
     x = common.embed(params, cfg.dtype, input_ids)  # [B, H]
 
-    def layer(group, rotation, x, lp, mp, banks, mi, k_buf, v_buf, table,
-              cos, sin, positions, context_lens, live):
+    def layer(group, rotation, x, lp, mp, banks, mi, k_cache, v_cache, li,
+              table, cos, sin, positions, context_lens, live):
         u = _norm(x, lp['ln']['scale'], cfg)
         ranking = _rank(u, mp, banks, cfg, mi)
         q, k, v = (
@@ -510,15 +509,18 @@ def _decode_core(
             )
         )
         with jax.named_scope(f'distllm.attn_{group}'):
-            k_buf, v_buf = write_token_kv(k_buf, v_buf, k, v, table, positions)
+            k_cache, v_cache = write_token_kv(
+                k_cache, v_cache, k, v, table, positions, layer=li
+            )
             attn = decode_attention(
-                q, k_buf, v_buf, table, context_lens, positions,
+                q, k_cache, v_cache, table, context_lens, positions,
                 backend=attn_backend, sliding_window=cfg.window(group),
+                layer=li,
             )
         x, pairs = _finish_layer(
             x, attn, lp, mp, banks, cfg, mi, ranking, live
         )
-        return x, k_buf, v_buf, pairs
+        return x, k_cache, v_cache, pairs
 
     x, k_cache, v_cache, pairs = common.walk_cache_groups(
         layer, cfg.layer_indices(), 'smallthinker_layer', x, *caches,
@@ -556,8 +558,7 @@ def decode_loop(  # distlint: traced
     rope = _rope_table(cfg, max_table_positions or cfg.max_position_embeddings)
     tokens, (k_cache, v_cache), ids, pairs = common.decode_window(
         functools.partial(_decode_core, params, cfg, rope, attn_backend),
-        input_ids, positions, context_lens,
-        (tuple(tuple(k) for k in k_cache), tuple(tuple(v) for v in v_cache)),
+        input_ids, positions, context_lens, (tuple(k_cache), tuple(v_cache)),
         block_tables, steps_left, temperature, top_p, min_p, top_k, seeds,
         num_steps=num_steps, sampling_top_window=sampling_top_window,
         counts=jnp.zeros((2,), jnp.int32),

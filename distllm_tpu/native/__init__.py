@@ -18,7 +18,7 @@ _BUILD_DIR = _NATIVE_DIR / '_build'
 
 
 def build_library(source_name: str) -> Path | None:
-    """Compile ``source_name`` (e.g. ``block_allocator.cpp``) to a cached .so.
+    """Compile ``source_name`` (e.g. ``scheduler.cpp``) to a cached .so.
 
     Returns the .so path, or None when compilation is unavailable/fails.
     The cache key includes the source hash so edits rebuild automatically.

@@ -156,7 +156,7 @@ def _layer_pages(k_cache, v_cache, layer):  # distlint: traced
     pool) as the runs of pages the ops below index, and the id of
     ``layer``'s block 0 in such a run.
 
-    A layer's own buffer (rank 3, ``CacheSpec.layer_buffers``) is that
+    One layer's run of pages (rank 3, the ops' own unit) is that
     run already: it comes back as it is with ``base`` None, and nothing
     is added to a block id. A STACKED pool ``[L, blocks, block_size,
     folded]`` is addressed, never sliced: a slice handed to the kernel (a
@@ -446,32 +446,6 @@ def ragged_paged_attention_xla(  # distlint: traced
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum('bkgst,btkd->bskgd', probs, v.astype(jnp.float32))
     return out.reshape(b, s, num_heads, v.shape[-1]).astype(q.dtype)
-
-
-def paged_prefill_attention_xla(  # distlint: traced
-    q: jnp.ndarray,  # [B, S, num_heads, head_dim] tail queries
-    k_cache: jnp.ndarray,  # [num_blocks, block_size, num_kv_heads * head_dim]
-    v_cache: jnp.ndarray,
-    block_tables: jnp.ndarray,  # [B, max_blocks] int32
-    context_lens: jnp.ndarray,  # [B] total valid tokens incl. the tail
-    q_positions: jnp.ndarray,  # [B, S] absolute position of each query
-    sliding_window: 'int | jnp.ndarray | None' = None,
-    scale: float | None = None,
-    logit_softcap: float | None = None,
-) -> jnp.ndarray:
-    """Multi-query attention over paged KV: prefix-cache / chunked prefill
-    tail queries attending to cached history + themselves.
-
-    Now a thin alias of :func:`ragged_paged_attention_xla` (every tail row
-    is a ragged span; ``q_lens`` stays ``None`` so the emitted HLO — and
-    bit pattern — is unchanged from the pre-ragged op; padding-row logits
-    are garbage the caller discards).
-    """
-    return ragged_paged_attention_xla(
-        q, k_cache, v_cache, block_tables, context_lens, q_positions,
-        q_lens=None, sliding_window=sliding_window, scale=scale,
-        logit_softcap=logit_softcap,
-    )
 
 
 def _ragged_paged_attn_kernel(

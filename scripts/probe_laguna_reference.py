@@ -65,8 +65,8 @@ def _int8_rows(rows):
 
 
 def _int8_writer(write):
-    def rounded(k_pool, v_pool, k, v, *rest):
-        return write(k_pool, v_pool, _int8_rows(k), _int8_rows(v), *rest)
+    def rounded(k_pool, v_pool, k, v, *rest, **kw):
+        return write(k_pool, v_pool, _int8_rows(k), _int8_rows(v), *rest, **kw)
 
     return rounded
 

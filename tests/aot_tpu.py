@@ -233,6 +233,29 @@ def _holds_a_scatter(text: str, call: str) -> bool:
     return ' scatter(' in body
 
 
+def _assert_no_matmul_is_recomputed(compiled) -> None:
+    """XLA's rematerialization runs when ITS count of a program's memory
+    passes the chip's, and an unrolled chain of writes into one multi-GB
+    pool reads to it as two such pools: what it then moves behind a write
+    is free, a matmul it duplicates is not (``PERF.md`` section 6, PR 56).
+    No instruction it made (``%name.remat``, ``.remat2``) is a matmul or a
+    fusion that holds one."""
+    import re
+
+    text = compiled.as_text()
+    recomputed = [
+        f'%{name} = {result[:40]} {opcode}'
+        for name, (result, opcode, call) in _hlo_defs(text).items()
+        if re.search(r'\.remat\d*$', name) and (
+            opcode == 'convolution' or opcode == 'fusion' and ' convolution(' in
+            text.partition(
+                '\n%' + re.search(r'calls=%(\S+?)[,\s]', call + ' ').group(1) + ' ('
+            )[2].partition('\n}')[0]
+        )
+    ]
+    assert not recomputed, recomputed
+
+
 def _assert_pools_go_to_the_kernel_as_they_lie(compiled, buffers) -> None:
     """No relayout of a pool-sized array, and the paged kernel reads the
     pools themselves: (1) no ``reshape``, ``copy`` or ``transpose`` whose
@@ -375,11 +398,10 @@ def _assert_no_weight_is_sliced_in_the_step_scan(compiled, params) -> None:
     ]
 
 
-@pytest.fixture(scope='module')
-def laguna_cell(v5e):
-    """The laguna cell's configuration cut to one period of layers (one
-    full layer, three window layers; the dense MLP and three sparse), the
-    parameters and the pools at the cell's sizes: 9600 and 1757 blocks."""
+def _laguna(v5e, layers):
+    """The laguna cell's configuration cut to its first ``layers``: module,
+    config, parameters, and each group's stacked pool and its shape at the
+    cell's sizes, 9600 and 1757 blocks a layer."""
     import json
     from pathlib import Path
 
@@ -387,29 +409,34 @@ def laguna_cell(v5e):
 
     root = Path(__file__).resolve().parents[1]
     hf = json.loads((root / 'benchmarks/configs/laguna-xs.2.json').read_text())
-    hf['num_hidden_layers'] = 4
+    hf['num_hidden_layers'] = layers
     for key in ('layer_types', 'mlp_layer_types', 'num_attention_heads_per_layer'):
-        hf[key] = hf[key][:4]
+        hf[key] = hf[key][:layers]
     cfg = laguna.LagunaConfig.from_hf_config(hf)
     shapes = jax.eval_shape(
         lambda: laguna.init_on_device(jax.random.PRNGKey(0), cfg)
     )
     params = jax.tree.map(lambda a: v5e(a.shape, a.dtype), shapes)
-    buffers = [
-        (blocks, 16, cfg.num_kv_heads * cfg.head_dim)
-        for blocks in (9600, 1757)
+    shapes = [
+        (cfg.count(kind), blocks, 16, cfg.num_kv_heads * cfg.head_dim)
+        for kind, blocks in (('full', 9600), ('window', 1757))
     ]
-    pools = tuple(
-        (v5e(shape, jnp.bfloat16),) * cfg.count(kind)
-        for kind, shape in zip(('full', 'window'), buffers)
-    )
-    return laguna, cfg, params, pools, buffers
+    pools = tuple(v5e(shape, jnp.bfloat16) for shape in shapes)
+    return laguna, cfg, params, pools, shapes
+
+
+@pytest.fixture(scope='module')
+def laguna_cell(v5e):
+    """One period of layers (one full layer, three window layers; the dense
+    MLP and three sparse): the full group's stack of one has no plane to
+    slice, ``_laguna(v5e, 8)`` has two."""
+    return _laguna(v5e, 4)
 
 
 @pytest.fixture(scope='module')
 def laguna_window(v5e, laguna_cell):
     """The decode window at the cell's 48 rows, compiled once."""
-    laguna, cfg, params, pools, buffers = laguna_cell
+    laguna, cfg, params, pools, _ = laguna_cell
     b, i32, f32 = 48, jnp.int32, jnp.float32
 
     def window_fn(params, ids, pos, ctx, k, v, bt, steps_left, *sampling):
@@ -452,8 +479,9 @@ def kanana_cell(v5e):
 def _assert_stacked_pool_is_addressed(compiled, pool) -> None:
     """A stacked pool ``[L, blocks, block_size, folded]`` is addressed,
     never sliced: (1) no instruction's result is the size of a layer's
-    plane; (2) every instruction whose result is the size of the pool is
-    the pool handed on (a parameter, the loop and its tuples, a bitcast,
+    plane (a stack of one layer IS its plane: nothing to slice); (2) every
+    instruction whose result is the size of the pool is the pool handed
+    on (a parameter, the loop and its tuples, a bitcast,
     the compiler's own staging of a small pool through its fast memory) or
     the in-place write (a ``scatter``, alone or in a fusion); (3) each
     kernel's K and V operand is the pool itself behind bitcasts."""
@@ -462,7 +490,7 @@ def _assert_stacked_pool_is_addressed(compiled, pool) -> None:
     planes = [
         f'%{name} = {result[:40]} {opcode}'
         for name, (result, opcode, _) in defs.items()
-        if _holds(result, pool[1:])
+        if pool[0] > 1 and _holds(result, pool[1:])
     ]
     assert not planes, planes
 
@@ -478,6 +506,27 @@ def _assert_stacked_pool_is_addressed(compiled, pool) -> None:
     ]
     assert not others, others
     _assert_pools_go_to_the_kernel_as_they_lie(compiled, [pool])
+
+
+def _chunk_prefill(v5e, cell, rows, tables, max_table_positions):
+    """The ``(512, rows)`` span program of a family whose cell is
+    ``(module, config, parameters, pools, ...)``: ``rows`` rows of a
+    512-token span over ``tables`` (one table's width, or a pair's)."""
+    module, cfg, params, pools = cell[:4]
+    i32 = jnp.int32
+    if cfg.cache_spec().latent:  # one group of planes, no V plane
+        k, v, tables = pools, (), v5e((rows, tables), i32)
+    else:  # two cache groups: a pair of each operand
+        k, v, tables = pools, pools, (v5e((rows, tables), i32),) * 2
+    return jax.jit(
+        lambda params, ids, pos, k, v, bt, ctx, tails: module.prefill_paged(
+            params, cfg, ids, pos, k, v, bt, ctx, tails,
+            max_table_positions=max_table_positions, attn_backend='pallas',
+        ), donate_argnums=(3, 4),
+    ).lower(
+        params, v5e((rows, 512), i32), v5e((rows, 512), i32), k, v, tables,
+        v5e((rows,), i32), v5e((rows,), i32),
+    ).compile()
 
 
 def _mistral_7b(v5e, num_layers):
@@ -944,11 +993,10 @@ def _kanana_stacks(cell, layers=None):
     )
 
 
-@pytest.fixture(scope='module')
-def smallthinker_cell(v5e):
+def _smallthinker(v5e):
     """The smallthinker cell's configuration at its own depth (16 layers:
-    4 full, 12 window), the parameters and the pools at the cell's sizes:
-    22000 blocks and the engine's own 12509."""
+    4 full, 12 window), the parameters and each group's stacked pool at the
+    cell's sizes: 22000 blocks a layer and the engine's own 12509."""
     import json
     from pathlib import Path
 
@@ -966,21 +1014,26 @@ def smallthinker_cell(v5e):
     )  # the tree the engine serves from: q, k and v a layer an array
     params = jax.tree.map(lambda a: v5e(a.shape, a.dtype), shapes)
     rows = hf['engine']['max_num_seqs']
-    buffers = [
-        (blocks, 16, cfg.num_kv_heads * cfg.head_dim)
-        for blocks in (hf['engine']['num_blocks'], 1 + rows * 258 + 4 * 31)
+    shapes = [
+        (cfg.count(kind), blocks, 16, cfg.num_kv_heads * cfg.head_dim)
+        for kind, blocks in (
+            ('full', hf['engine']['num_blocks']),
+            ('window', 1 + rows * 258 + 4 * 31),
+        )
     ]
-    pools = tuple(
-        (v5e(shape, jnp.bfloat16),) * cfg.count(kind)
-        for kind, shape in zip(('full', 'window'), buffers)
-    )
-    return smallthinker, cfg, params, pools, buffers
+    pools = tuple(v5e(shape, jnp.bfloat16) for shape in shapes)
+    return smallthinker, cfg, params, pools, shapes
+
+
+@pytest.fixture(scope='module')
+def smallthinker_cell(v5e):
+    return _smallthinker(v5e)
 
 
 @pytest.fixture(scope='module')
 def smallthinker_window(v5e, smallthinker_cell):
     """The decode window at the cell's 48 rows and depth, compiled once."""
-    smallthinker, cfg, params, pools, buffers = smallthinker_cell
+    smallthinker, cfg, params, pools, _ = smallthinker_cell
     b, i32, f32 = 48, jnp.int32, jnp.float32
 
     def window_fn(params, ids, pos, ctx, k, v, bt, steps_left, *sampling):

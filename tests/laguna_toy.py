@@ -109,15 +109,14 @@ def paged_logits(cfg, params, tokens, n_prompt, *, chunk=8, backend='xla',
     width = -(-total // BLOCK)
 
     def pool(layers, blocks):
-        shape = (blocks, BLOCK, cfg.num_kv_heads * cfg.head_dim)  # head-folded
+        # stacked over the group's layers, a token's heads folded
+        shape = (layers, blocks, BLOCK, cfg.num_kv_heads * cfg.head_dim)
         if int8:
-            return tuple(
-                QuantizedKV(
-                    jnp.zeros(shape, jnp.int8),
-                    jnp.zeros((blocks, cfg.num_kv_heads), jnp.float32),
-                ) for _ in range(layers)
+            return QuantizedKV(
+                jnp.zeros(shape, jnp.int8),
+                jnp.zeros((layers, blocks, cfg.num_kv_heads), jnp.float32),
             )
-        return tuple(jnp.zeros(shape, jnp.float32) for _ in range(layers))
+        return jnp.zeros(shape, jnp.float32)
 
     k = (pool(cfg.count('full'), full_blocks),
          pool(cfg.count('window'), window_blocks.num_blocks))

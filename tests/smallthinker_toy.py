@@ -91,8 +91,9 @@ def paged_logits(cfg, params, tokens, n_prompt, *, chunk=8, backend='xla',
     width = -(-total // BLOCK)
 
     def pool(layers, blocks):
-        shape = (blocks, BLOCK, cfg.num_kv_heads * cfg.head_dim)  # head-folded
-        return tuple(jnp.zeros(shape, jnp.float32) for _ in range(layers))
+        # stacked over the group's layers, a token's heads folded
+        shape = (layers, blocks, BLOCK, cfg.num_kv_heads * cfg.head_dim)
+        return jnp.zeros(shape, jnp.float32)
 
     k = (pool(cfg.count('full'), full_blocks),
          pool(cfg.count('window'), window_blocks.num_blocks))

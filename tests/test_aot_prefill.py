@@ -2,6 +2,7 @@
 chunk prefills: the pools and planes go to the kernel as they lie, and the
 span form of the Kimi-delta rule is handed its operands as they lie."""
 
+import numpy as np
 import pytest
 
 jax = pytest.importorskip('jax')
@@ -16,9 +17,13 @@ from aot_tpu import (  # noqa: F401 -- fixtures, asked for by name
     v5e,
 )
 from aot_tpu import (
+    _assert_no_matmul_is_recomputed,
     _assert_pools_go_to_the_kernel_as_they_lie,
     _assert_span_calls_keep_the_grid,
     _assert_stacked_pool_is_addressed,
+    _chunk_prefill,
+    _laguna,
+    _smallthinker,
     _behind_the_moves,
     _hlo_defs,
     _kernel_calls,
@@ -27,36 +32,44 @@ from aot_tpu import (
 
 def test_laguna_chunk_prefill_reads_the_pools_as_they_lie(v5e, laguna_cell):
     """The ``(512, 4)`` program: four rows of a 512-token span."""
-    laguna, cfg, params, pools, buffers = laguna_cell
-    i32 = jnp.int32
-    compiled = jax.jit(
-        lambda params, ids, pos, k, v, bt, ctx, tails: laguna.prefill_paged(
-            params, cfg, ids, pos, k, v, bt, ctx, tails,
-            max_table_positions=8448, attn_backend='pallas',
-        ), donate_argnums=(3, 4),
-    ).lower(
-        params, v5e((4, 512), i32), v5e((4, 512), i32), pools, pools,
-        (v5e((4, 528), i32),) * 2, v5e((4,), i32), v5e((4,), i32),
-    ).compile()
-    _assert_pools_go_to_the_kernel_as_they_lie(compiled, buffers)
+    compiled = _chunk_prefill(v5e, laguna_cell, 4, 528, 8448)
+    for pool in laguna_cell[4]:  # each group's stacked pool, by layer
+        _assert_stacked_pool_is_addressed(compiled, pool)
     _assert_span_calls_keep_the_grid(compiled)
 
 
 def test_chunk_prefill_reads_the_planes_as_they_lie(v5e, kanana_cell):
     """The ``(512, 4)`` program: four rows of a 512-token span, 16384
     queries on the one KV head a row."""
-    deepseek_v3, cfg, params, planes, plane, _ = kanana_cell
-    i32 = jnp.int32
-    compiled = jax.jit(
-        lambda params, ids, pos, k, v, bt, ctx, tails: deepseek_v3.prefill_paged(
-            params, cfg, ids, pos, k, v, bt, ctx, tails,
-            max_table_positions=8448, attn_backend='pallas',
-        ), donate_argnums=(3, 4),
-    ).lower(
-        params, v5e((4, 512), i32), v5e((4, 512), i32), planes, (),
-        v5e((4, 528), i32), v5e((4,), i32), v5e((4,), i32),
-    ).compile()
-    _assert_pools_go_to_the_kernel_as_they_lie(compiled, [plane])
+    compiled = _chunk_prefill(v5e, kanana_cell, 4, 528, 8448)
+    _assert_pools_go_to_the_kernel_as_they_lie(compiled, [kanana_cell[4]])
+    _assert_span_calls_keep_the_grid(compiled)
+
+
+# The programs of the two families that walk two cache groups unrolled, the
+# layer a traced scalar of a kind's jitted function (``once_a_kind``), over
+# the cells' own pools (168 MB of pool or more: a smaller one the compiler
+# stages whole through the chip's fast memory, ``test_aot_windows.py``):
+# ``laguna`` at two periods, so that the full group too has a plane to slice
+# (2 x 315 MB and 6 x 58 MB), ``smallthinker`` at its depth (4 x 360 MB,
+# 12 x 205 MB).
+@pytest.mark.parametrize('cell,tables,max_table_positions', [
+    (lambda v5e: _laguna(v5e, 8), 528, 8448),
+    (_smallthinker, 1024, 16384),
+], ids=['laguna', 'smallthinker'])
+def test_one_row_chunk_prefill_addresses_the_stacked_pools(
+    v5e, cell, tables, max_table_positions
+):
+    """The ``(512, 1)`` span program, a one-row tail as the cells dispatch
+    it: every group's stacked pool goes to the writers and to the kernel
+    whole, no plane and no pool copied (``test_aot_windows.py::
+    test_stacked_pool_is_addressed_not_sliced`` for the families that scan
+    their layers), and the kernel's calls keep the grid over chunks."""
+    of = cell(v5e)
+    compiled = _chunk_prefill(v5e, of, 1, tables, max_table_positions)
+    for pool in of[4]:  # the two groups' shapes
+        assert pool[0] > 1 and np.prod(pool) * 2 >= 168e6
+        _assert_stacked_pool_is_addressed(compiled, pool)
     _assert_span_calls_keep_the_grid(compiled)
 
 
@@ -231,19 +244,11 @@ def test_smallthinker_chunk_prefill_reads_the_pools_as_they_lie(v5e, smallthinke
     """The ``(512, 4)`` program at the cell's depth: four rows of a
     512-token span (``test_chunk_prefill_keeps_the_grouped_matmul`` holds
     its experts to the grouped kernel)."""
-    smallthinker, cfg, params, pools, buffers = smallthinker_cell
-    i32 = jnp.int32
-    compiled = jax.jit(
-        lambda params, ids, pos, k, v, bt, ctx, tails: smallthinker.prefill_paged(
-            params, cfg, ids, pos, k, v, bt, ctx, tails,
-            max_table_positions=16384, attn_backend='pallas',
-        ), donate_argnums=(3, 4),
-    ).lower(
-        params, v5e((4, 512), i32), v5e((4, 512), i32), pools, pools,
-        (v5e((4, 1024), i32),) * 2, v5e((4,), i32), v5e((4,), i32),
-    ).compile()
-    _assert_pools_go_to_the_kernel_as_they_lie(compiled, buffers)
+    compiled = _chunk_prefill(v5e, smallthinker_cell, 4, 1024, 16384)
+    for pool in smallthinker_cell[4]:  # each group's stacked pool
+        _assert_stacked_pool_is_addressed(compiled, pool)
     _assert_span_calls_keep_the_grid(compiled)
+    _assert_no_matmul_is_recomputed(compiled)
 
 
 def test_sdar_prefill_span_is_block_causal_on_the_grid(v5e, sdar_cell):

@@ -363,8 +363,8 @@ def test_window_and_prefill_agree_on_a_narrow_gate_layout(v5e):
 
     def pools(*layers_blocks):
         return tuple(
-            (v5e((blocks, block, cfg.num_kv_heads * cfg.head_dim),
-                 jnp.bfloat16),) * layers
+            v5e((layers, blocks, block, cfg.num_kv_heads * cfg.head_dim),
+                jnp.bfloat16)
             for layers, blocks in layers_blocks
         )
 

@@ -46,7 +46,8 @@ from aot_tpu import (
 
 
 def test_laguna_decode_window_reads_the_pools_as_they_lie(laguna_cell, laguna_window):
-    _assert_pools_go_to_the_kernel_as_they_lie(laguna_window, laguna_cell[4])
+    for pool in laguna_cell[4]:  # each group's stacked pool, addressed by layer
+        _assert_stacked_pool_is_addressed(laguna_window, pool)
     # 6 queries a KV head in the full layers and 8 in the window layers:
     # both stacked since PR 55
     _assert_decode_calls_walk(laguna_window, blocks={'stacked'})
@@ -86,8 +87,10 @@ def test_decode_window_reads_the_planes_as_they_lie(v5e, kanana_cell):
     (_granite_window, (2, 8192, 16, _NKV * _HD)),
 ], ids=['mistral_decode_window', 'mistral_chunk_prefill', 'granite_decode_window'])
 def test_stacked_pool_is_addressed_not_sliced(v5e, program, pool):
-    """A family whose pool stays stacked hands it to the writers and to the
-    paged kernel WHOLE, with the layer whose pages are meant. Sliced out
+    """A family hands its stacked pool to the writers and to the paged
+    kernel WHOLE, with the layer whose pages are meant (the two families
+    that walk two groups unrolled: ``test_aot_prefill.py::
+    test_one_row_chunk_prefill_addresses_the_stacked_pools``). Sliced out
     for the kernel call (a custom call wants its operand materialised), a
     layer's plane was copied out of the pool and written back: 128 plane
     fusions and 66 pool-sized ones a step of ``mistral7b``'s window, 4.27
@@ -333,9 +336,8 @@ def test_smallthinker_decode_window_reads_the_pools_as_they_lie(
 ):
     """No pool-sized result but the scatters, every kernel call the row
     walk, and the programs fit the chip beside weights and pools."""
-    _assert_pools_go_to_the_kernel_as_they_lie(
-        smallthinker_window, smallthinker_cell[4]
-    )
+    for pool in smallthinker_cell[4]:  # each group's stacked pool
+        _assert_stacked_pool_is_addressed(smallthinker_window, pool)
     _assert_decode_calls_walk(smallthinker_window)
     memory = smallthinker_window.memory_analysis()
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14.5 * 2**30

@@ -342,7 +342,7 @@ def test_from_hf_config_reads_the_published_keys():
     spec = cfg.cache_spec()
     assert [(g.name, g.num_layers, g.window, g.row, g.value_lanes, g.stored_row)
             for g in spec.paged] == [('latent', 3, None, 136, 128, 256)]
-    assert spec.latent and spec.layer_buffers and not spec.dense_prefill
+    assert spec.latent and not spec.dense_prefill
     assert spec.program_prefix == 'deepseek_' and spec.state is None
     assert decoder_family('deepseek_v3') == (deepseek_v3.DeepseekV3Config, deepseek_v3)
     with pytest.raises(NotImplementedError, match='no converter'):

@@ -1,5 +1,5 @@
 """The engine's units: paged attention and the pool's writers, sampling
-(exact and windowed), the block allocators and the pool's container, and the
+(exact and windowed), the pool's container, and the
 engine's writers and readers over the stacked pool with a layer named."""
 
 import numpy as np
@@ -9,11 +9,7 @@ import jax
 import jax.numpy as jnp
 
 from distllm_tpu.generate.engine import EngineConfig, LLMEngine, SamplingParams
-from distllm_tpu.generate.engine.kv_cache import (
-    NativeBlockAllocator,
-    PagedKVCache,
-    PyBlockAllocator,
-)
+from distllm_tpu.generate.engine.kv_cache import PagedKVCache
 from distllm_tpu.models import mistral
 from distllm_tpu.ops.paged_attention import (
     paged_attention_xla,
@@ -158,26 +154,7 @@ def test_sampling_min_p_restricts_support():
     assert len(set(toks.tolist())) == 2  # still samples, not greedy
 
 
-# -------------------------------------------------------------- allocator
-@pytest.mark.parametrize('cls', [PyBlockAllocator, NativeBlockAllocator])
-def test_block_allocator(cls):
-    try:
-        alloc = cls(8)
-    except RuntimeError:
-        pytest.skip('native toolchain unavailable')
-    assert alloc.num_free == 7  # block 0 reserved
-    blocks = [alloc.alloc() for _ in range(7)]
-    assert 0 not in blocks
-    assert alloc.alloc() == -1  # exhausted
-    alloc.incref(blocks[0])
-    alloc.free(blocks[0])
-    assert alloc.num_free == 0  # still referenced
-    alloc.free(blocks[0])
-    assert alloc.num_free == 1
-    with pytest.raises((AssertionError, ValueError)):
-        alloc.free(blocks[0])  # double free
-
-
+# ------------------------------------------------------ the pool container
 def test_paged_kv_cache_container():
     """Pure device-array container (block accounting lives in the scheduler)."""
     kv = PagedKVCache(
@@ -192,62 +169,48 @@ def test_paged_kv_cache_container():
     assert kv.hbm_bytes == 2 * 2 * 8 * 4 * 2 * 4 * 4
 
 
-def _layer_of(pool, layer, layer_buffers):
-    if layer_buffers:
-        return pool[layer]
-    return jax.tree.map(lambda c: c[layer], pool)
-
-
-def _with_layer(pool, layer, buf, layer_buffers):
-    if layer_buffers:
-        return tuple(buf if i == layer else b for i, b in enumerate(pool))
-    return jax.tree.map(lambda c, b: c.at[layer].set(b), pool, buf)
-
-
-@pytest.mark.parametrize('form', ['stacked', 'layer_buffers', 'int8'])
+@pytest.mark.parametrize('form', ['stacked', 'int8'])
 @pytest.mark.parametrize('writer', ['token', 'chunk', 'prefill'])
 def test_writers_fold_the_new_rows_and_the_host_view_unfolds_blocks(
     rng, writer, form
 ):
     """Each writer folds the NEW rows (``[.., N_kv, Hd]``) into the pool's
-    ``N_kv * Hd`` rows; what the host's view gives back for a layer and
-    block ids is the rows in their logical shape, for both pool forms and
+    ``N_kv * Hd`` rows, in the pages of the layer named, of the stacked
+    pool handed whole; what the host's view gives back for a layer and
+    block ids is the rows in their logical shape, for the float pool and
     the int8 container (a ``QuantizedKV`` of such blocks and their
     scales)."""
     from distllm_tpu.ops.paged_attention import QuantizedKV, write_chunk_kv
 
-    layer_buffers = form == 'layer_buffers'
     kv = PagedKVCache(
         num_layers=2, num_blocks=6, block_size=4, num_kv_heads=2, head_dim=8,
         dtype='int8' if form == 'int8' else 'float32',
-        layer_buffers=layer_buffers,
     )
     assert jax.tree.leaves(kv.k_pool)[0].shape[-2:] == (4, 16)  # folded
     rows = rng.normal(size=(8, 2, 8)).astype(np.float32)
     row = jnp.asarray([3, 5, 0, 0], jnp.int32)  # 8 tokens into blocks 3, 5
-    k_l = _layer_of(kv.k_pool, 1, layer_buffers)
-    v_l = _layer_of(kv.v_pool, 1, layer_buffers)
+    k, v = kv.k_pool, kv.v_pool
     if writer == 'token':
         for t in range(8):
-            k_l, v_l = write_token_kv(
-                k_l, v_l, jnp.asarray(rows[t:t + 1]),
+            k, v = write_token_kv(
+                k, v, jnp.asarray(rows[t:t + 1]),
                 jnp.asarray(2 * rows[t:t + 1]), row[None],
-                jnp.asarray([t], jnp.int32),
+                jnp.asarray([t], jnp.int32), layer=1,
             )
     elif writer == 'chunk':
         for start in (0, 4):
-            k_l, v_l = write_chunk_kv(
-                k_l, v_l, jnp.asarray(rows[None, start:start + 4]),
+            k, v = write_chunk_kv(
+                k, v, jnp.asarray(rows[None, start:start + 4]),
                 jnp.asarray(2 * rows[None, start:start + 4]), row[None],
                 jnp.arange(start, start + 4)[None], jnp.ones((1, 4), bool),
+                layer=1,
             )
     else:
-        k_l, v_l = write_prefill_kv(
-            k_l, v_l, jnp.asarray(rows), jnp.asarray(2 * rows), row,
-            jnp.int32(8),
+        k, v = write_prefill_kv(
+            k, v, jnp.asarray(rows), jnp.asarray(2 * rows), row,
+            jnp.int32(8), layer=1,
         )
-    kv.k_pool = _with_layer(kv.k_pool, 1, k_l, layer_buffers)
-    kv.v_pool = _with_layer(kv.v_pool, 1, v_l, layer_buffers)
+    kv.k_pool, kv.v_pool = k, v
 
     want = rows.reshape(2, 4, 2, 8)  # [blocks, block_size, N_kv, Hd]
     got_k, got_v = kv.k[1][[3, 5]], kv.v[1][[3, 5]]
@@ -272,16 +235,15 @@ def test_writers_fold_the_new_rows_and_the_host_view_unfolds_blocks(
     assert not untouched.any()  # the other layer
 
 
-@pytest.mark.parametrize('layer_buffers', [False, True], ids=['stacked', 'layer_buffers'])
-def test_host_view_gathers_the_blocks_asked_for_and_no_buffer(layer_buffers):
+def test_host_view_gathers_the_blocks_asked_for_and_no_plane():
     """``kv.k[layer][block_ids]`` is a gather of those blocks and a reshape
     of the gathered blocks: nothing it computes is the size of a layer's
-    buffer (the laguna cell's pools fill 91% of the device)."""
+    plane (the laguna cell's pools fill 91% of the device)."""
     from distllm_tpu.generate.engine.kv_cache import _PoolView
 
     kv = PagedKVCache(
         num_layers=3, num_blocks=64, block_size=4, num_kv_heads=2, head_dim=8,
-        dtype='float32', layer_buffers=layer_buffers,
+        dtype='float32',
     )
     ids = np.asarray([[7, 9], [1, 63]])
     view = _PoolView(kv, kv.k_pool)

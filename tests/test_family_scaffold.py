@@ -20,7 +20,7 @@ import sdar_toy
 import smallthinker_toy
 import solar_open2_toy
 
-from distllm_tpu.models import common, decoder_family, mistral
+from distllm_tpu.models import common, decoder_families, decoder_family, mistral
 from distllm_tpu.ops.paged_attention import (
     decode_attention,
     paged_attention_xla,
@@ -102,6 +102,45 @@ def test_no_line_of_the_engine_names_the_family(family):
     for path in Path(engine_package.__file__).parent.glob('*.py'):
         text = path.read_text().lower()
         assert not any(word in text for word in words), path.name
+
+
+# ------------------------------------------------------- one form of pool
+@pytest.mark.parametrize('model_type', sorted(decoder_families()))
+def test_every_kv_group_is_one_stacked_pool(model_type):
+    """Whatever a family's ``cache_spec()`` declares, each K/V group's
+    pool is ONE array a plane over the group's layers, ``[L, blocks,
+    block_size, row]`` (a ``QuantizedKV`` of such data and its scales for an
+    int8 pool): the form the writers, the kernel, the mesh and the int8
+    container are written for. The next family is held to it here. A latent
+    group's is a plane a layer and no V plane (``models/deepseek_v3.py``)."""
+    from distllm_tpu.generate.engine.kv_cache import PagedKVCache
+    from distllm_tpu.ops.paged_attention import QuantizedKV
+
+    config_cls, _ = decoder_family(model_type)
+    toys = {toy.tiny_hf()['model_type']: toy for toy in _TOYS.values()}
+    cfg = (
+        config_cls.from_hf_config(toys[model_type].tiny_hf())
+        if model_type in toys else config_cls()
+    )
+    spec = cfg.cache_spec()
+    assert spec.paged
+    for group in spec.paged:
+        if spec.latent:
+            kv = PagedKVCache.for_group(group, cfg, 8, 4, lazy=True)
+            assert kv.spec('v') == () and [p.shape for p in kv.spec('k')] == [
+                (8, 4, group.stored_row)
+            ] * group.num_layers
+            continue
+        for dtype in ('bfloat16', 'int8'):
+            # the engine's own way to a group's pool
+            kv = PagedKVCache.for_group(group, cfg, 8, 4, dtype=dtype, lazy=True)
+            for plane in (kv.spec('k'), kv.spec('v')):
+                if dtype == 'int8':
+                    assert isinstance(plane, QuantizedKV)
+                    assert plane.scale.shape == (group.num_layers, 8, cfg.num_kv_heads)
+                    plane = plane.data
+                assert isinstance(plane, jax.ShapeDtypeStruct)
+                assert plane.shape == (group.num_layers, 8, 4, plane.shape[-1])
 
 
 # ------------------------------------------------------------ the step scan

@@ -126,7 +126,9 @@ def test_records_and_telemetry_carry_the_groups():
     }
     assert pools['window']['layers'] == 4 and pools['window']['window'] == WINDOW
     assert pools['window']['blocks'] == engine.window_blocks.num_blocks
-    assert isinstance(engine.kv.k_pool, tuple) and len(engine.window_kv.k_pool) == 4
+    # each group's pool ONE stacked array over its layers
+    assert engine.kv.k_pool.shape == engine.kv.pool_shape
+    assert engine.window_kv.k_pool.shape == engine.window_kv.pool_shape
     # K and V planes both, in both groups (no group declares a latent row).
     assert len(engine.kv.v_pool) == 2 and len(engine.window_kv.v_pool) == 4
     assert not engine.kv.latent and not engine.window_kv.latent

@@ -82,7 +82,7 @@ def test_config_reads_the_published_keys():
     assert [(g.name, g.num_layers, g.window) for g in spec.paged] == [
         ('full', 10, None), ('window', 30, 512),
     ]
-    assert spec.state is None and not spec.dense_prefill and spec.layer_buffers
+    assert spec.state is None and not spec.dense_prefill
     # K/V groups both: neither declares a row of its own.
     assert not spec.latent and all(g.stored_row is None for g in spec.paged)
 
@@ -331,9 +331,10 @@ def test_serving_programs_lower_each_kind_of_layer_once():
     called a layer, and not one copy of its text a layer."""
     hf, cfg, params = tiny()
     pools = tuple(
-        (jnp.zeros((9, BLOCK, cfg.num_kv_heads * cfg.head_dim), jnp.float32),)
-        * cfg.count(kind)
-        for kind in ('full', 'window')
+        jnp.zeros(
+            (cfg.count(kind), 9, BLOCK, cfg.num_kv_heads * cfg.head_dim),
+            jnp.float32,
+        ) for kind in ('full', 'window')
     )
     tables = (jnp.zeros((1, 8), jnp.int32),) * 2
     text = jax.jit(

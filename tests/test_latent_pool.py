@@ -106,8 +106,8 @@ def test_writers_write_one_plane():
 
 
 def test_a_pool_container_of_latent_rows():
-    kv = PagedKVCache(2, 8, 4, 99, 99, dtype='float32', layer_buffers=True,
-                      row=256, value_lanes=128)
+    kv = PagedKVCache(2, 8, 4, 99, 99, dtype='float32', row=256,
+                      value_lanes=128)
     assert kv.shape == (2, 8, 4, 1, 256) and kv.pool_shape == (2, 8, 4, 256)
     assert kv.v_pool == () and kv.hbm_bytes == 2 * 8 * 4 * 256 * 4
     assert kv.spec() == (jax.ShapeDtypeStruct((8, 4, 256), jnp.float32),) * 2

@@ -364,9 +364,8 @@ def test_serving_programs_lower_each_kind_of_layer_once():
     functions, called 2 and 6 times."""
     _, cfg, params = tiny()
     sds = jax.ShapeDtypeStruct
-    pool = lambda n: tuple(  # noqa: E731
-        sds((9, BLOCK, cfg.num_kv_heads * cfg.head_dim), jnp.float32)
-        for _ in range(n)
+    pool = lambda n: sds(  # noqa: E731
+        (n, 9, BLOCK, cfg.num_kv_heads * cfg.head_dim), jnp.float32
     )
     pools = (pool(cfg.count('full')), pool(cfg.count('window')))
     i32 = jnp.int32
